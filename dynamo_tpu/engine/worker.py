@@ -50,7 +50,7 @@ from ..perf.steptrace import LiveRoofline
 from ..runtime import DistributedRuntime, new_instance_id
 from ..runtime.logging import get_logger
 from ..runtime.metrics import KV_USAGE
-from .model_runner import ModelRunner, RunnerConfig
+from .model_runner import ModelRunner, RunnerConfig, compiling
 from .scheduler import InferenceScheduler
 
 log = get_logger("engine.worker")
@@ -265,6 +265,9 @@ class TpuWorker:
         # device-ms total) behind dynamo_mfu/dynamo_roofline_fraction.
         self._roofline: Optional[LiveRoofline] = None
         self._roof_prev: Optional[tuple] = None
+        # Set by the scheduler thread when an exception escapes a step;
+        # main() exits non-zero once teardown has run.
+        self.engine_failure: Optional[BaseException] = None
 
     async def start(self) -> None:
         """prepare + serve in one go (normal startup). Snapshot-gated
@@ -540,7 +543,54 @@ class TpuWorker:
         except Exception:  # noqa: BLE001 — processors are optional;
             # a tokenizer-less deployment still serves
             self.scheduler.logits_tokenizer = None
+        self.scheduler.on_fatal = self._on_engine_fatal
+        self._report_engine()
         self.scheduler.start()
+
+    def _report_engine(self) -> None:
+        """State at start-up what this engine runs on and which path
+        each hot-path slot took (log line + dynamo_engine_info)."""
+        from ..native import get_native
+        from ..runtime.metrics import ENGINE_INFO
+
+        paths = self.runner.kernel_paths()
+        native = get_native() is not None
+        # Built here, not at the first metrics tick: a device nobody has
+        # peaks for (perf/steptrace.py detect_chip) fails the start, once.
+        self._roofline = LiveRoofline(
+            self.model_config,
+            num_chips=int(self.mesh.devices.size),
+            weight_bytes_per_param={"int8": 1.0, "int4": 0.53125}.get(
+                self.runner_config.weight_dtype, 2.0),
+            kv_dtype_bytes=1 if self.runner_config.kv_dtype == "int8" else 2,
+        )
+        log.info("engine on %s %r devices=%s: decode_attention=%s "
+                 "spec_attention=%s weight_matmul=%s%s native=%s",
+                 paths["platform"], paths["device_kind"],
+                 paths["device_ids"], paths["decode_attention"],
+                 paths["spec_attention"], paths["weight_matmul"],
+                 (f" q4_layout={paths['q4_layout']}"
+                  if "q4_layout" in paths else ""), native)
+        ENGINE_INFO.labels(
+            worker=f"{self.instance_id:x}", platform=paths["platform"],
+            device_kind=paths["device_kind"],
+            devices=",".join(str(d) for d in paths["device_ids"]),
+            decode_attention=paths["decode_attention"],
+            spec_attention=paths["spec_attention"],
+            weight_matmul=paths["weight_matmul"],
+            q4_layout=paths.get("q4_layout", ""),
+            native=str(native).lower()).set(1)
+
+    def _on_engine_fatal(self, exc: BaseException) -> None:
+        """Scheduler-thread callback: the engine loop has ended and
+        failed its requests. Resolve the shutdown event so main() tears
+        down and exits non-zero instead of idling registered."""
+        from ..runtime.signals import request_shutdown
+
+        self.engine_failure = exc
+        if self._loop is not None:
+            self._loop.call_soon_threadsafe(
+                request_shutdown, f"engine thread died: {exc!r}")
 
     async def serve(self) -> None:
         """Connect endpoints + publish the card (requires self.runtime;
@@ -1362,6 +1412,27 @@ class TpuWorker:
 
                 request_shutdown("drain control verb")
 
+    def _publish_engine_gauges(self) -> None:
+        """Tokens processed and per-chip device memory (docs/metrics.md:
+        dynamo_engine_tokens, dynamo_device_hbm_bytes)."""
+        from ..runtime.metrics import DEVICE_HBM_BYTES, ENGINE_TOKENS
+
+        worker = f"{self.instance_id:x}"
+        stats = self.scheduler.stats
+        ENGINE_TOKENS.labels(worker=worker, kind="prefill").set(
+            stats.prefill_tokens)
+        ENGINE_TOKENS.labels(worker=worker, kind="decode").set(
+            stats.decode_tokens)
+        for device in self.mesh.local_devices:
+            mem = device.memory_stats() or {}
+            for kind, key in (("in_use", "bytes_in_use"),
+                              ("peak", "peak_bytes_in_use"),
+                              ("limit", "bytes_limit")):
+                if key in mem:
+                    DEVICE_HBM_BYTES.labels(
+                        worker=worker, device=str(device.id),
+                        kind=kind).set(mem[key])
+
     def _publish_spec_metrics(self) -> None:
         """Mirror the scheduler's speculative-decoding totals onto the
         dynamo_spec_* families (docs/metrics.md): counters advance by the
@@ -1409,16 +1480,6 @@ class TpuWorker:
             STEP_HOST_MS.labels(phase=sample.kind).observe(sample.host_ms)
         HOST_BOUND.labels(worker=worker).set(1.0 if trace.host_bound
                                              else 0.0)
-        if self._roofline is None:
-            wb = {"int8": 1.0, "int4": 0.53125}.get(
-                self.runner_config.weight_dtype, 2.0)
-            self._roofline = LiveRoofline(
-                self.model_config,
-                num_chips=int(self.mesh.devices.size),
-                weight_bytes_per_param=wb,
-                kv_dtype_bytes=1 if self.runner_config.kv_dtype == "int8"
-                else 2,
-            )
         stats = self.scheduler.stats
         cur = (stats.prefill_tokens, stats.decode_tokens,
                getattr(self.runner, "decode_steps", 0),
@@ -1469,6 +1530,7 @@ class TpuWorker:
                 metrics = self._load_metrics()
                 KV_USAGE.labels(worker=f"{self.instance_id:x}").set(
                     metrics.kv_usage)
+                self._publish_engine_gauges()
                 if self.scheduler.spec_enabled:
                     self._publish_spec_metrics()
                 try:
@@ -1505,6 +1567,19 @@ class TpuWorker:
                 embedding=[float(x) for x in vec],
             ).to_wire()
             return
+        if request.annotations.get("canary"):
+            busy = compiling(self.scheduler.thread_ident)
+            if busy is not None:
+                # Found on the v5e (PR 21): a program outside the warm
+                # set compiles for minutes at 7B, on the engine thread,
+                # under the first request that needs it. Canaries queued
+                # behind it timed out and the health manager
+                # deregistered a healthy worker mid-request. A thread
+                # inside a compile is slow, not wedged: answer for it.
+                log.info("canary answered while the engine compiles "
+                         "%s (%.0fs so far)", *busy)
+                yield EngineOutput(finish_reason="stop").to_wire()
+                return
         # W3C trace context: the wire header (first-class, ctx.traceparent)
         # wins; the annotation side-channel keeps legacy peers working.
         traceparent = None
@@ -1882,6 +1957,14 @@ def build_arg_parser():
     parser.add_argument("--tp", type=int, default=1)
     parser.add_argument("--dp", type=int, default=1)
     parser.add_argument("--sp", type=int, default=1)
+    parser.add_argument("--replicas", type=int, default=1,
+                        help="serve N independent replicas of the model "
+                             "from this process, replica i on local chips "
+                             "[i*k, (i+1)*k) with k = dp*tp*sp. A chip "
+                             "belongs to the process that opened it, so "
+                             "one process per host places a worker on "
+                             "each chip; every replica registers as its "
+                             "own instance and routers see N workers")
     parser.add_argument("--multihost", default=None, metavar="R/N@HOST:PORT",
                         help="span this worker across N host processes via "
                              "jax.distributed (one global mesh). Rank 0 is "
@@ -1928,6 +2011,16 @@ def build_arg_parser():
                         choices=["think", "deepseek-r1", "granite",
                                  "harmony", "gpt-oss"])
     return parser
+
+
+def _exit_if_engine_died(workers: list) -> None:
+    """After teardown: an engine loop that ended on an exception failed
+    its requests in-band; the process must not read as a clean exit."""
+    dead = [w for w in workers if w.engine_failure is not None]
+    if dead:
+        raise SystemExit(
+            "engine thread died: "
+            + "; ".join(repr(w.engine_failure) for w in dead))
 
 
 async def main(argv: Optional[list[str]] = None) -> None:
@@ -2051,6 +2144,11 @@ async def main(argv: Optional[list[str]] = None) -> None:
         log.info("model ref %r -> source=%s served=%s", args.model_ref,
                  record.source, record.served_model_name)
 
+    if args.replicas > 1 and (args.mode == "comesh" or args.multihost
+                              or snapshot.enabled):
+        raise SystemExit("--replicas places whole workers on this host's "
+                         "chips; it does not combine with --mode comesh, "
+                         "--multihost or snapshot-gated startup")
     if args.mode == "comesh":
         # Co-meshed disagg: one process, prefill + decode pools on disjoint
         # sub-meshes, KV handoff over ICI (engine/ici_transfer.py). The
@@ -2145,34 +2243,54 @@ async def main(argv: Optional[list[str]] = None) -> None:
             await decode_worker.close()
             await prefill_worker.close()
             await runtime.shutdown()
+        _exit_if_engine_died([decode_worker, prefill_worker])
         return
 
-    worker = TpuWorker(
-        runtime,
-        model_name=args.model,
-        model_path=args.model_path,
-        served_name=args.served_model_name,
-        namespace=args.namespace,
-        component=component,
-        mode=args.mode,
-        runner_config=RunnerConfig(
-            page_size=args.page_size, num_pages=args.num_pages,
-            max_batch=args.max_batch,
-            max_pages_per_seq=args.max_pages_per_seq,
-            max_loras=args.max_loras, lora_rank=args.lora_rank,
-            kv_dtype=args.kv_dtype,
-            weight_dtype=args.weight_dtype,
-        ),
-        mesh_config=MeshConfig(dp=args.dp, tp=args.tp, sp=args.sp),
-        kvbm_config=kvbm_config,
-        step_channel=step_channel,
-        tool_parser=args.tool_call_parser,
-        reasoning_parser=args.reasoning_parser,
-        lora_adapters=dict(s.split("=", 1) for s in args.lora),
-        weight_service=(args.weight_service
-                        or _env("DYNT_WEIGHT_SERVICE") or None),
-        weights_from_peer=args.weights_from_peer,
-    )
+    mesh_config = MeshConfig(dp=args.dp, tp=args.tp, sp=args.sp)
+
+    def build_worker(mesh) -> TpuWorker:
+        return TpuWorker(
+            runtime,
+            model_name=args.model,
+            model_path=args.model_path,
+            served_name=args.served_model_name,
+            namespace=args.namespace,
+            component=component,
+            mode=args.mode,
+            runner_config=RunnerConfig(
+                page_size=args.page_size, num_pages=args.num_pages,
+                max_batch=args.max_batch,
+                max_pages_per_seq=args.max_pages_per_seq,
+                max_loras=args.max_loras, lora_rank=args.lora_rank,
+                kv_dtype=args.kv_dtype,
+                weight_dtype=args.weight_dtype,
+            ),
+            mesh_config=mesh_config,
+            mesh=mesh,
+            kvbm_config=kvbm_config,
+            step_channel=step_channel,
+            tool_parser=args.tool_call_parser,
+            reasoning_parser=args.reasoning_parser,
+            lora_adapters=dict(s.split("=", 1) for s in args.lora),
+            weight_service=(args.weight_service
+                            or _env("DYNT_WEIGHT_SERVICE") or None),
+            weights_from_peer=args.weights_from_peer,
+        )
+
+    # Replica i takes local chips [i*k, (i+1)*k); one replica is the
+    # front of the list, as make_mesh always placed it.
+    import jax
+
+    k = mesh_config.num_devices
+    devices = jax.devices()
+    if len(devices) < args.replicas * k:
+        raise SystemExit(
+            f"--replicas {args.replicas} x {k} chip(s) needs "
+            f"{args.replicas * k} devices, have {len(devices)}")
+    workers = [build_worker(make_mesh(
+        mesh_config, devices=devices[i * k:(i + 1) * k]))
+        for i in range(args.replicas)]
+    worker = workers[0]
     if snapshot.enabled:
         await worker.prepare()
         snapshot.engine_ready()
@@ -2218,9 +2336,30 @@ async def main(argv: Optional[list[str]] = None) -> None:
             # must not depend on it
             log.exception("checkpoint record registration failed")
     else:
-        await worker.start()
+        # Replicas build side by side: XLA compiles off the GIL, so N
+        # engines warm in about the time of one.
+        await asyncio.gather(*(w.start() for w in workers))
+
+    async def drain_all(reason: str = "control") -> dict:
+        # Replicas are independent, so their ladders run side by side
+        # under the one deadline; a failed drain must not skip the rest.
+        reports = await asyncio.gather(
+            *(w.drain(reason) for w in workers), return_exceptions=True)
+        out = {}
+        for w, report in zip(workers, reports):
+            if isinstance(report, BaseException):
+                log.exception("graceful drain failed (instance=%x)",
+                              w.instance_id, exc_info=report)
+                report = {"error": "drain failed; see log"}
+            out[f"{w.instance_id:x}"] = report
+        return out
+
+    if len(workers) > 1 and getattr(runtime, "status_server",
+                                    None) is not None:
+        # Per-worker registrations are last-wins; POST /drain must
+        # vacate every replica.
+        runtime.status_server.register_drain(drain_all)
     from ..runtime import HealthCheckManager
-    from ..runtime.config import env
 
     health = HealthCheckManager(runtime,
                                 canary_wait_time=env("DYNT_CANARY_WAIT_SECS"))
@@ -2231,10 +2370,9 @@ async def main(argv: Optional[list[str]] = None) -> None:
         # Departure ladder BEFORE teardown: in-flight streams hand off
         # their KV state to peers (or replay) instead of dying with the
         # endpoints (docs/fault-tolerance.md).
-        try:
-            await worker.drain("shutdown-signal")
-        except Exception:  # noqa: BLE001 — teardown proceeds regardless
-            log.exception("graceful drain failed")
+        await drain_all("shutdown-signal")
         await health.close()
-        await worker.close()
+        for w in workers:
+            await w.close()
         await runtime.shutdown()
+    _exit_if_engine_died(workers)

@@ -122,8 +122,9 @@ def coldstart_phases(cfg: MockerConfig) -> dict[str, float]:
     }
 
 
-# Step-time coefficients FIT FROM MEASURED silicon (BASELINE.md r3/r4
-# decode probe, scripts/bench_probe.py on a real v5e chip):
+# Step-time coefficients fit from one 2026-07 decode probe on a v5e
+# (the r3/r4 tables of `git show 6b5a9d4:BASELINE.md`; the probe script
+# left with them at PR 21) and not rechecked since — ROADMAP C6:
 #   us/step = decode_base + decode_us_per_seq * batch
 #             + decode_us_per_kv_block * active_kv_blocks
 # Least-squares over the ctx~0 floor points (bs 8/16/32 -> 2580/3298/

@@ -104,7 +104,7 @@ def repack_params_q4(params: dict, version: int | None = None) -> dict:
     """Host-side pack-layout migration of an already-quantized int4
     pytree (checkpoint / weight-service load path): every {"q4","qs4",
     "qz4"} leaf whose layout differs from the target (None = the
-    DYNT_Q4_VARIANT policy, auto = v2 wherever well-formed) is repacked
+    DYNT_Q4_VARIANT policy; auto = v1) is repacked
     via ops.q4_linear.repack_q4_leaf. Scale/zero rows are untouched and
     the code transform is a nibble bijection, so v1 checkpoints load
     bit-exactly (v1 -> v2 -> v1 roundtrips identically). Leaves already

@@ -108,96 +108,116 @@ def param_axes(config: ModelConfig) -> dict:
     return axes
 
 
-def init_params(key: jax.Array, config: ModelConfig) -> dict:
+def _init_dense(k: jax.Array, shape, fan_in: int, dtype) -> jax.Array:
+    return (jax.random.normal(k, shape, dtype=jnp.float32)
+            * (1.0 / math.sqrt(fan_in))).astype(dtype)
+
+
+def init_layer_params(k: jax.Array, config: ModelConfig,
+                      layer_idx: int) -> dict:
+    """One layer of `init_params` (same values for the same key). Its
+    own entry point so a 7B engine can initialise and quantise a layer
+    at a time instead of compiling, and holding, the whole bf16 tree."""
     dtype = jnp.dtype(config.dtype)
     h, hd = config.hidden, config.head_dim
     qh, kh, m = config.n_q_heads, config.n_kv_heads, config.mlp_hidden
-    keys = jax.random.split(key, config.n_layers + 2)
 
     def dense(k, shape, fan_in):
-        return (jax.random.normal(k, shape, dtype=jnp.float32)
-                * (1.0 / math.sqrt(fan_in))).astype(dtype)
+        return _init_dense(k, shape, fan_in, dtype)
 
-    def layer(k, layer_idx):
-        ks = jax.random.split(k, 15)
-        if config.is_mla:
-            dc = config.mla_kv_lora_rank
-            nhd = config.mla_nope_head_dim
-            rhd = config.mla_rope_head_dim
-            vhd = config.mla_v_head_dim
-            p = {
-                "attn_norm": jnp.ones((h,), dtype),
-                "w_dkv": dense(ks[1], (h, dc), h),
-                "w_kr": dense(ks[2], (h, rhd), h),
-                "kv_norm": jnp.ones((dc,), dtype),
-                "w_uk": dense(ks[10], (dc, qh, nhd), dc),
-                "w_uv": dense(ks[11], (dc, qh, vhd), dc),
-                "wo": dense(ks[3], (qh, vhd, h), qh * vhd),
-            }
-            if config.mla_q_lora_rank:
-                qr = config.mla_q_lora_rank
-                p["w_dq"] = dense(ks[0], (h, qr), h)
-                p["q_a_norm"] = jnp.ones((qr,), dtype)
-                p["w_uq"] = dense(ks[12], (qr, qh, nhd + rhd), qr)
-            else:
-                p["wq"] = dense(ks[0], (h, qh, nhd + rhd), h)
+    ks = jax.random.split(k, 15)
+    if config.is_mla:
+        dc = config.mla_kv_lora_rank
+        nhd = config.mla_nope_head_dim
+        rhd = config.mla_rope_head_dim
+        vhd = config.mla_v_head_dim
+        p = {
+            "attn_norm": jnp.ones((h,), dtype),
+            "w_dkv": dense(ks[1], (h, dc), h),
+            "w_kr": dense(ks[2], (h, rhd), h),
+            "kv_norm": jnp.ones((dc,), dtype),
+            "w_uk": dense(ks[10], (dc, qh, nhd), dc),
+            "w_uv": dense(ks[11], (dc, qh, vhd), dc),
+            "wo": dense(ks[3], (qh, vhd, h), qh * vhd),
+        }
+        if config.mla_q_lora_rank:
+            qr = config.mla_q_lora_rank
+            p["w_dq"] = dense(ks[0], (h, qr), h)
+            p["q_a_norm"] = jnp.ones((qr,), dtype)
+            p["w_uq"] = dense(ks[12], (qr, qh, nhd + rhd), qr)
         else:
-            p = {
-                "attn_norm": jnp.ones((h,), dtype),
-                "wq": dense(ks[0], (h, qh, hd), h),
-                "wk": dense(ks[1], (h, kh, hd), h),
-                "wv": dense(ks[2], (h, kh, hd), h),
-                "wo": dense(ks[3], (qh, hd, h), qh * hd),
-            }
+            p["wq"] = dense(ks[0], (h, qh, nhd + rhd), h)
+    else:
+        p = {
+            "attn_norm": jnp.ones((h,), dtype),
+            "wq": dense(ks[0], (h, qh, hd), h),
+            "wk": dense(ks[1], (h, kh, hd), h),
+            "wv": dense(ks[2], (h, kh, hd), h),
+            "wo": dense(ks[3], (qh, hd, h), qh * hd),
+        }
+    p.update({
+        "mlp_norm": jnp.ones((h,), dtype),
+        "w_gate": dense(ks[4], (h, m), h),
+        "w_up": dense(ks[5], (h, m), h),
+        "w_down": dense(ks[6], (m, h), m),
+    })
+    if config.qk_norm:
+        p["q_norm"] = jnp.ones((hd,), dtype)
+        p["k_norm"] = jnp.ones((hd,), dtype)
+    if config.is_gptoss:
+        e, em = config.n_experts, config.expert_mlp_hidden or m
+        for name in ("w_gate", "w_up", "w_down"):
+            p.pop(name, None)  # experts replace the dense MLP
         p.update({
-            "mlp_norm": jnp.ones((h,), dtype),
-            "w_gate": dense(ks[4], (h, m), h),
-            "w_up": dense(ks[5], (h, m), h),
-            "w_down": dense(ks[6], (m, h), m),
+            "bq": dense(ks[7], (qh, hd), h) * 0.02,
+            "bk": dense(ks[8], (kh, hd), h) * 0.02,
+            "bv": dense(ks[9], (kh, hd), h) * 0.02,
+            "bo": dense(ks[10], (h,), h) * 0.02,
+            "sinks": dense(ks[11], (qh,), 1),
+            "router": dense(ks[12], (h, e), h),
+            "router_bias": jnp.zeros((e,), dtype),
+            "e_gate_up": dense(ks[13], (e, h, 2 * em), h),
+            "e_gate_up_bias": jnp.zeros((e, 2 * em), dtype),
+            "e_down": dense(ks[14], (e, em, h), em),
+            "e_down_bias": jnp.zeros((e, h), dtype),
         })
-        if config.qk_norm:
-            p["q_norm"] = jnp.ones((hd,), dtype)
-            p["k_norm"] = jnp.ones((hd,), dtype)
-        if config.is_gptoss:
-            e, em = config.n_experts, config.expert_mlp_hidden or m
-            for name in ("w_gate", "w_up", "w_down"):
-                p.pop(name, None)  # experts replace the dense MLP
-            p.update({
-                "bq": dense(ks[7], (qh, hd), h) * 0.02,
-                "bk": dense(ks[8], (kh, hd), h) * 0.02,
-                "bv": dense(ks[9], (kh, hd), h) * 0.02,
-                "bo": dense(ks[10], (h,), h) * 0.02,
-                "sinks": dense(ks[11], (qh,), 1),
-                "router": dense(ks[12], (h, e), h),
-                "router_bias": jnp.zeros((e,), dtype),
-                "e_gate_up": dense(ks[13], (e, h, 2 * em), h),
-                "e_gate_up_bias": jnp.zeros((e, 2 * em), dtype),
-                "e_down": dense(ks[14], (e, em, h), em),
-                "e_down_bias": jnp.zeros((e, h), dtype),
-            })
-            return p
-        if config.layer_is_moe(layer_idx):
-            e, em = config.n_experts, config.expert_mlp_hidden or m
-            p["router"] = dense(ks[7], (h, e), h)
-            if config.moe_scoring == "sigmoid":
-                p["e_bias"] = jnp.zeros((e,), jnp.float32)
-            p["e_gate"] = dense(ks[8], (e, h, em), h)
-            p["e_up"] = dense(ks[9], (e, h, em), h)
-            p["e_down"] = dense(ks[7], (e, em, h), em)
-            if config.n_shared_experts:
-                sm = config.n_shared_experts * em
-                p["s_gate"] = dense(ks[12], (h, sm), h)
-                p["s_up"] = dense(ks[13], (h, sm), h)
-                p["s_down"] = dense(ks[14], (sm, h), sm)
         return p
+    if config.layer_is_moe(layer_idx):
+        e, em = config.n_experts, config.expert_mlp_hidden or m
+        p["router"] = dense(ks[7], (h, e), h)
+        if config.moe_scoring == "sigmoid":
+            p["e_bias"] = jnp.zeros((e,), jnp.float32)
+        p["e_gate"] = dense(ks[8], (e, h, em), h)
+        p["e_up"] = dense(ks[9], (e, h, em), h)
+        p["e_down"] = dense(ks[7], (e, em, h), em)
+        if config.n_shared_experts:
+            sm = config.n_shared_experts * em
+            p["s_gate"] = dense(ks[12], (h, sm), h)
+            p["s_up"] = dense(ks[13], (h, sm), h)
+            p["s_down"] = dense(ks[14], (sm, h), sm)
+    return p
 
+
+def init_top_params(k_embed: jax.Array, k_head: jax.Array,
+                    config: ModelConfig) -> dict:
+    """Everything in `init_params` outside the layer list."""
+    dtype = jnp.dtype(config.dtype)
+    h = config.hidden
     params = {
-        "embed": dense(keys[0], (config.vocab_size, h), h),
+        "embed": _init_dense(k_embed, (config.vocab_size, h), h, dtype),
         "final_norm": jnp.ones((h,), dtype),
-        "layers": [layer(keys[i + 1], i) for i in range(config.n_layers)],
     }
     if not config.tie_embeddings:
-        params["lm_head"] = dense(keys[-1], (h, config.vocab_size), h)
+        params["lm_head"] = _init_dense(k_head, (h, config.vocab_size), h,
+                                        dtype)
+    return params
+
+
+def init_params(key: jax.Array, config: ModelConfig) -> dict:
+    keys = jax.random.split(key, config.n_layers + 2)
+    params = init_top_params(keys[0], keys[-1], config)
+    params["layers"] = [init_layer_params(keys[i + 1], config, i)
+                        for i in range(config.n_layers)]
     return params
 
 

@@ -20,7 +20,12 @@ weight tile:
 
 Two pack layouts coexist, selected by DYNT_Q4_VARIANT at quantize time
 and dispatched by the packed dtype (uint8 = v1, int8 = v2 — the version
-travels with the leaf, jit-static, no extra pytree field):
+travels with the leaf, jit-static, no extra pytree field). `auto` is v1:
+Mosaic (jax 0.9.0, v5e) refuses v2's int8 nibble shifts (`arith.shli`
+on i8 vectors does not legalize), so v2 as designed has never run on a
+chip; its kernel now widens to int32 first, which compiles but gives up
+the one-convert unpack v2 was built for. Which layout is faster on the
+chip is not measured (ROADMAP A5 decides and deletes the loser):
 
 v1 (half-block, uint8): within each group, byte row r holds code row r
   in its LOW nibble and code row r + group//2 in its HIGH nibble.
@@ -62,6 +67,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kernel_path
+
 # Preferred contracted rows per quantization group (the packed layout
 # bakes the group in — see module docstring). 256 measured fastest on
 # v5e (706 tok/s decode at 7B vs 615 at group 128 — BASELINE.md r5);
@@ -101,12 +108,13 @@ def _group_for(k: int) -> int:
 def resolve_pack_version(k: int, group: int | None = None,
                          strict: bool = True) -> int:
     """Pack layout for a weight with contracted size `k` under the
-    DYNT_Q4_VARIANT policy: auto = v2 wherever the global half-split is
-    well-formed (K divides 2*group), v1 otherwise; v1/v2 force the
-    layout. Forcing v2 on an incompatible K raises when `strict` (the
-    quantizer must not mis-pack) and falls back to v1 otherwise (the
-    load-time repack keeps such leaves as they are). An unknown mode
-    ALWAYS raises — a typo'd knob must not silently pick a layout."""
+    DYNT_Q4_VARIANT policy: auto = v1 (the layout that has run on the
+    chip — module docstring); v1/v2 force the layout. Forcing v2 on a K
+    whose global half-split is not well-formed (K must divide 2*group)
+    raises when `strict` (the quantizer must not mis-pack) and falls
+    back to v1 otherwise (the load-time repack keeps such leaves as
+    they are). An unknown mode ALWAYS raises — a typo'd knob must not
+    silently pick a layout."""
     from ..runtime.config import env
 
     g = group or _group_for(k)
@@ -114,19 +122,16 @@ def resolve_pack_version(k: int, group: int | None = None,
     if mode not in ("auto", "v1", "v2"):
         raise ValueError(
             f"unknown DYNT_Q4_VARIANT {mode!r} (expected auto|v1|v2)")
-    v2_ok = k % (2 * g) == 0
-    if mode == "v1":
+    if mode != "v2":
         return PACK_V1
-    if mode == "v2":
-        if not v2_ok:
-            if strict:
-                raise ValueError(
-                    f"DYNT_Q4_VARIANT=v2 needs K % (2*group) == 0 "
-                    f"(K={k}, group={g}); this weight only supports the "
-                    "v1 half-block layout")
-            return PACK_V1
-        return PACK_V2
-    return PACK_V2 if v2_ok else PACK_V1
+    if k % (2 * g):
+        if strict:
+            raise ValueError(
+                f"DYNT_Q4_VARIANT=v2 needs K % (2*group) == 0 "
+                f"(K={k}, group={g}); this weight only supports the "
+                "v1 half-block layout")
+        return PACK_V1
+    return PACK_V2
 
 # Leaf name -> number of LEADING contracted axes (same registry shape as
 # q8_linear.QUANT_LEAVES; shared by the quantizer and model plumbing).
@@ -297,15 +302,8 @@ def repack_q4_leaf(leaf: dict, version: int | None = None) -> dict:
     return {"q4": out.reshape(q4.shape), "qs4": qs4, "qz4": leaf["qz4"]}
 
 
-def _compiler_params():
-    """Mosaic compiler params across jax versions (CompilerParams landed
-    after TPUCompilerParams; interpret mode ignores them either way)."""
-    semantics = ("parallel", "parallel", "arbitrary")
-    if hasattr(pltpu, "CompilerParams"):
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    if hasattr(pltpu, "TPUCompilerParams"):
-        return pltpu.TPUCompilerParams(dimension_semantics=semantics)
-    return None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _q4_matmul_kernel(group, gk, x_ref, wp_ref, s_ref, z_ref, o_ref,
@@ -352,10 +350,10 @@ def _q4_matmul_kernel_v2(group, gh, x_lo_ref, x_hi_ref, wp_ref,
     """v2: the packed tile's nibbles ARE contracted order (low nibbles =
     `gh` whole groups of the low K-half, high nibbles = the matching
     groups of the high K-half), so each k-step is two full-width dots.
-    Unpack rides the q8 idiom — two int8 shifts (sign-extending the
-    biased nibbles), ONE convert per tile — and the per-group scale
-    rides the weight tile while the zero-point (incl. the -8 bias
-    absorbed by the signed codes) folds into one small
+    The signed nibbles sign-extend by shifts — on an int32 widen, since
+    Mosaic has no int8 vector shifts (module docstring) — and the
+    per-group scale rides the weight tile while the zero-point (incl.
+    the -8 bias absorbed by the signed codes) folds into one small
     [bm, gh] x [gh, bn] dot per tile."""
     k = pl.program_id(2)
     kb2 = group * gh
@@ -364,9 +362,11 @@ def _q4_matmul_kernel_v2(group, gh, x_lo_ref, x_hi_ref, wp_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    w8 = wp_ref[:]  # [kb2, bn] int8: two signed nibbles per byte
-    lo = jnp.right_shift(jnp.left_shift(w8, 4), 4)  # sign-extended low
-    hi = jnp.right_shift(w8, 4)                     # arithmetic shift
+    # [kb2, bn] int8, two signed nibbles per byte; the widen
+    # sign-extends, so arithmetic shifts recover both.
+    w32 = wp_ref[:].astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(w32, 28), 28)
+    hi = jnp.right_shift(w32, 4)
     bn = o_ref.shape[1]
     for x_ref, s_ref, z_ref, codes in (
             (x_lo_ref, s_lo_ref, z_lo_ref, lo),
@@ -464,8 +464,12 @@ def q4_matmul(x: jax.Array, q4: jax.Array, scale: jax.Array,
             raise ValueError(
                 f"q4_matmul: the v2 layout needs an even gk (got {gk})")
     else:
+        # v2 holds its whole k-block unpacked (int32 nibbles, the scale
+        # tile and the scaled bf16 tile) where v1 loops group by group:
+        # past 4 groups per step that exceeds the v5e's scoped VMEM.
+        limit = 4 if version == PACK_V2 else 32
         gk = 1
-        while gk < 32 and k % (group * gk * 2) == 0:
+        while gk < limit and k % (group * gk * 2) == 0:
             gk *= 2
     # Mosaic requires the sublane block dim to divide 8 or equal the
     # array dim: give the per-group rows a unit middle axis so each
@@ -494,7 +498,7 @@ def q4_matmul(x: jax.Array, q4: jax.Array, scale: jax.Array,
             out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
             out_shape=jax.ShapeDtypeStruct((mp, n), x.dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            compiler_params=_compiler_params(),
+            compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
         )(x, x, q4, s3, s3, z3, z3)
         return out[:m]
@@ -511,7 +515,7 @@ def q4_matmul(x: jax.Array, q4: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mp, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(x, q4, s3, z3)
     return out[:m]
@@ -543,15 +547,6 @@ def q4_matmul_ref(x: jax.Array, q4: jax.Array, scale: jax.Array,
         x, w.astype(x.dtype), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     return acc.astype(x.dtype)
-
-
-def _use_pallas() -> bool:
-    from ..runtime.config import env
-
-    mode = env("DYNT_Q4_MATMUL") or "auto"
-    if mode == "xla":
-        return False
-    return mode == "pallas" or jax.default_backend() == "tpu"
 
 
 def q4_einsum(spec: str, x: jax.Array, q4: jax.Array, qs4: jax.Array,
@@ -586,9 +581,10 @@ def q4_einsum(spec: str, x: jax.Array, q4: jax.Array, qs4: jax.Array,
         w2 = q4  # wo is stored flat [K//2, h] (pack blocks span heads)
     else:
         raise ValueError(f"q4_einsum does not support spec {spec!r}")
-    if _use_pallas():
-        out = q4_matmul(x2, w2, qs4, qz4,
-                        interpret=jax.default_backend() != "tpu")
-    else:
+    path = kernel_path("DYNT_Q4_MATMUL")
+    if path == "xla":
         out = q4_matmul_ref(x2, w2, qs4, qz4)
+    else:
+        out = q4_matmul(x2, w2, qs4, qz4,
+                        interpret=path == "interpret")
     return out.reshape(out_shape)

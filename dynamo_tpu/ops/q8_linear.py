@@ -29,6 +29,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kernel_path
+
 # Leaf name -> number of LEADING contracted axes (the rest are output
 # axes carrying the per-channel scale). Shared by the quantizer and the
 # sharding-tree transform (models/quantize.py).
@@ -134,15 +136,6 @@ def q8_matmul_ref(x: jax.Array, wq: jax.Array,
     return (acc * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def _use_pallas() -> bool:
-    from ..runtime.config import env
-
-    mode = env("DYNT_Q8_MATMUL") or "auto"
-    if mode == "xla":
-        return False
-    return mode == "pallas" or jax.default_backend() == "tpu"
-
-
 def q8_einsum(spec: str, x: jax.Array, q8: jax.Array,
               qs: jax.Array) -> jax.Array:
     """Quantized drop-in for the transformer's dense einsums: reshape to
@@ -176,11 +169,12 @@ def q8_einsum(spec: str, x: jax.Array, q8: jax.Array,
         s2 = qs
     else:
         raise ValueError(f"q8_einsum does not support spec {spec!r}")
-    if _use_pallas():
-        out = q8_matmul(x2, w2, s2,
-                        interpret=jax.default_backend() != "tpu")
-    else:
+    path = kernel_path("DYNT_Q8_MATMUL")
+    if path == "xla":
         out = q8_matmul_ref(x2, w2, s2)
+    else:
+        out = q8_matmul(x2, w2, s2,
+                        interpret=path == "interpret")
     return out.reshape(out_shape)
 
 

@@ -90,14 +90,11 @@ class MultihostConfig:
 
 
 def initialize(cfg: MultihostConfig) -> None:
-    """jax.distributed.initialize with the platform override applied first
-    (must run before the first backend touch). On the CPU backend the
-    cross-process collectives implementation is gloo."""
+    """jax.distributed.initialize (must run before the first backend
+    touch). On the CPU backend the cross-process collectives
+    implementation is gloo."""
     import jax
 
-    from .mesh import apply_platform_override
-
-    apply_platform_override()
     platforms = jax.config.jax_platforms or ""
     if "cpu" in platforms:
         jax.config.update("jax_cpu_collectives_implementation", "gloo")

@@ -136,9 +136,10 @@ _register("DYNT_Q4_GROUP", "256", _str,
           "GPTQ/AWQ-convention groups, slightly better quality)")
 _register("DYNT_Q4_VARIANT", "auto", _str,
           "Packed-int4 layout the quantizer emits (docs/quantization.md):"
-          " auto (v2 wherever K divides 2*group, else v1) | v1 "
+          " auto (= v1, the layout that has run on the chip) | v1 "
           "(half-block per group, uint8) | v2 (VPU-swizzled global "
-          "half-split with signed codes, int8). The kernel dispatches "
+          "half-split with signed codes, int8; needs K to divide "
+          "2*group). The kernel dispatches "
           "on the packed dtype; checkpoints repack transparently at "
           "load (scripts/q4_repack.py migrates offline)")
 _register("DYNT_WEIGHT_SERVICE", "", _str,
@@ -191,9 +192,6 @@ _register("DYNT_LOGGING_JSONL", False, _bool,
 # Engine
 _register("DYNT_KV_BLOCK_SIZE", 16, _int,
           "Tokens per KV block (block-hash granularity and paged-KV page size)")
-_register("DYNT_JAX_PLATFORM", "", _str,
-          "Force the jax platform for engine processes (e.g. 'cpu'); wins "
-          "over a sitecustomize-frozen JAX_PLATFORMS")
 _register("DYNT_COMPILE_CACHE_DIR", "/tmp/dynamo_tpu_jax_cache", _str,
           "Persistent XLA compilation cache dir")
 _register("DYNT_COMPILE_CACHE_STORE", "", _str,

@@ -298,6 +298,41 @@ JIT_COMPILES = Counter(
     "when the compile fired (unscoped = outside any runner entry)",
     ["fn"], registry=REGISTRY,
 )
+JIT_COMPILE_SECONDS = Counter(
+    "dynamo_jit_compile_seconds_total",
+    "Seconds spent in the XLA backend compiles that "
+    "dynamo_jit_compiles_total counts, by the same entry point — what "
+    "a cold start (or a retrace under traffic) costs in time",
+    ["fn"], registry=REGISTRY,
+)
+# What the engine behind a worker actually runs on (engine/worker.py
+# start-up report): the device, the kernel path each hot-path slot took
+# and whether the native extension loaded — so a reference kernel, the
+# Pallas interpreter or a CPU can never serve unnoticed — plus the two
+# per-replica readings a multi-replica host is checked by.
+ENGINE_INFO = Gauge(
+    "dynamo_engine_info",
+    "Constant 1; the labels name this worker's devices and the path "
+    "(pallas | interpret | xla | einsum | custom) decode attention, "
+    "spec attention and the weight matmul resolved to, the int4 pack "
+    "layout served (empty unless int4) and whether dynamo_tpu._native "
+    "loaded",
+    ["worker", "platform", "device_kind", "devices", "decode_attention",
+     "spec_attention", "weight_matmul", "q4_layout", "native"],
+    registry=REGISTRY,
+)
+ENGINE_TOKENS = Gauge(
+    "dynamo_engine_tokens",
+    "Tokens this worker's engine has processed since start, by kind "
+    "(prefill | decode)",
+    ["worker", "kind"], registry=REGISTRY,
+)
+DEVICE_HBM_BYTES = Gauge(
+    "dynamo_device_hbm_bytes",
+    "Device memory of each chip in this worker's mesh as the backend "
+    "reports it (in_use | peak | limit); absent where it reports none",
+    ["worker", "device", "kind"], registry=REGISTRY,
+)
 # Session tier (dynamo_tpu/session/): prompt-cache pins and
 # session-affinity routing at planet scale — the gauges prove the store
 # stays bounded under millions of sessions, the counters show whether
@@ -402,8 +437,9 @@ FEDERATION_EVAC_SESSIONS = Counter(
 # Device-time attribution plane (perf/steptrace.py, "dynaprof"): every
 # scheduler step decomposed into host vs device burn, the per-request
 # device-time TTFT, and the live roofline comparison against the
-# analytical model (profiler/timing_model.py) — the metrics that retire
-# the tunnel-RTT hypothesis with data (docs/observability.md).
+# analytical model (profiler/timing_model.py) — the metrics that say
+# whether a step's time was the host's or the device's
+# (docs/observability.md).
 STEP_DEVICE_MS = Histogram(
     "dynamo_step_device_ms",
     "Per-step device window (dispatch submitted -> drain complete) in "

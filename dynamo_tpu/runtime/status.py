@@ -43,7 +43,7 @@ async def profile_response(request: web.Request) -> web.Response:
     engine's dispatch scopes carry StepTraceAnnotation marks
     (perf/steptrace.py), so the capture attributes device ops to
     decode/prefill/spec phases. 409 while another capture runs; 503
-    when the local jax has no profiler."""
+    in a process that has not imported JAX (it is never imported here)."""
     try:
         duration = float(request.query.get(
             "duration_ms", env("DYNT_PROF_DEFAULT_MS")))
@@ -55,14 +55,19 @@ async def profile_response(request: web.Request) -> web.Response:
         return web.json_response(
             {"error": "a profile capture is already running"}, status=409)
     try:
-        try:
-            from jax import profiler
-        except Exception as exc:  # noqa: BLE001 — jax-free process
-            return web.json_response(
-                {"error": f"jax.profiler unavailable: {exc!r}"},
-                status=503)
         import os
+        import sys
         import uuid
+
+        if "jax" not in sys.modules:
+            # A frontend or launcher that never imported JAX has no
+            # device to trace — and importing it here would open the
+            # chip in a process that must not hold it (a chip belongs
+            # to one process; the worker next door would lose it).
+            return web.json_response(
+                {"error": "this process runs no JAX engine; capture on "
+                          "a worker's status port"}, status=503)
+        from jax import profiler
 
         # Unique per capture (sub-second repeats must not share a dir —
         # the returned manifest has to identify THIS capture's files).

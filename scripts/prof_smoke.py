@@ -7,8 +7,7 @@ run a short burst of chat requests, then assert
 
   * the per-request decomposition invariant — every ok timeline's
     queue + host + device components sum to within tolerance of its
-    measured TTFT (the attributable TTFT that retires the tunnel-RTT
-    hypothesis),
+    measured TTFT (the attributable TTFT),
   * `dynamo_ttft_device_ms` exported with a `trace_id` exemplar on the
     OpenMetrics scrape,
   * `/debug/profile` runs an on-demand jax.profiler capture and

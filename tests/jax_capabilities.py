@@ -1,47 +1,19 @@
 """Capability probes for environment-dependent tier-1 tests.
 
-Some tier-1 tests exercise code written against a newer JAX surface
-than every environment carries — `jax.shard_map` (the top-level export)
-and `jax.experimental.pallas.tpu.CompilerParams` (renamed from
-`TPUCompilerParams`), plus tests that spawn whole worker processes or
-need wall-clock headroom a loaded single-vCPU runner cannot give. On
-such environments those tests fail for reasons that have nothing to do
-with the code under test, and a red tier-1 run stops meaning anything.
-
-These probes pin each dependence explicitly: the test skips — visibly,
-with the capability named in the reason — instead of failing, and on
-an environment that HAS the capability the test still runs and still
-gates. Probe the capability, never the version string: a backport or a
-rename makes version comparisons lie.
+One JAX is installed (0.9.0, here and on the chip machine), so nothing
+probes its surface. What still varies is the host: tests that spawn
+whole worker processes need wall-clock headroom a single-core runner
+cannot give, and fail there for reasons that have nothing to do with
+the code under test. The probe pins that dependence explicitly: the
+test skips — visibly, with the capability named in the reason —
+instead of failing, and on a host that HAS it the test still runs and
+still gates.
 """
 
 import os
 
 import pytest
 
-
-def _has_shard_map() -> bool:
-    import jax
-
-    return hasattr(jax, "shard_map")
-
-
-def _has_pallas_compiler_params() -> bool:
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except Exception:  # noqa: BLE001 — no pallas at all is also "no"
-        return False
-    return hasattr(pltpu, "CompilerParams")
-
-
-requires_shard_map = pytest.mark.skipif(
-    not _has_shard_map(),
-    reason="this jax build has no top-level jax.shard_map export")
-
-requires_pallas_compiler_params = pytest.mark.skipif(
-    not _has_pallas_compiler_params(),
-    reason="this jax build has no pallas.tpu.CompilerParams "
-           "(pre-rename TPUCompilerParams)")
 
 def _usable_cpus() -> int:
     try:

@@ -297,7 +297,6 @@ class TestGangE2E:
                 "DYNT_DISCOVERY_PATH": disc,
                 "DYNT_LOG_LEVEL": "INFO",
                 "JAX_PLATFORMS": "cpu",
-                "DYNT_JAX_PLATFORM": "cpu",
                 "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
                 "DYNT_SYSTEM_ENABLED": "false",
             },

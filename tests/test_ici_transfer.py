@@ -31,7 +31,6 @@ from dynamo_tpu.llm.protocols import (
 from dynamo_tpu.ops.block_copy import gather_kv_blocks
 from dynamo_tpu.runtime import DistributedRuntime
 from dynamo_tpu.runtime.push_router import PushRouter
-from jax_capabilities import requires_shard_map
 
 
 def _request(tokens, max_tokens=6, temperature=0.0):
@@ -228,7 +227,6 @@ class TestBridgeE2E:
 
 # engine/ici_transfer.py's collective-permute form calls jax.shard_map
 # directly (ici_transfer.py:232).
-@requires_shard_map
 class TestPpermuteHandoff:
     def test_pages_move_rank0_to_rank1(self):
         """Union-mesh collective-permute form: rank 0's src pages land in

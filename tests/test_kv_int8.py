@@ -22,7 +22,6 @@ from dynamo_tpu.models.transformer import (
     paged_attention_decode_xla,
     quantize_kv,
 )
-from jax_capabilities import requires_pallas_compiler_params
 
 
 class TestQuantize:
@@ -106,7 +105,6 @@ class TestForwardWithInt8Cache:
         assert kv_q8[1].dtype == jnp.bfloat16
 
 
-@requires_pallas_compiler_params
 class TestPoolKernelQ8:
     def _case(self, b=4, qh=8, kh=4, hd=64, ps=8, n_pages=32, max_pages=6,
               seed=5):

@@ -201,7 +201,6 @@ class TestMultihostKvbmE2E:
         env = dict(os.environ)
         env.update({
             "JAX_PLATFORMS": "cpu",
-            "DYNT_JAX_PLATFORM": "cpu",
             "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
             "PYTHONPATH": REPO,
             "DYNT_DISCOVERY_BACKEND": "file",

@@ -142,8 +142,9 @@ class TestMockerEngine:
 
 
 class TestTimingFidelity:
-    """The v5e timing preset must reproduce the REAL chip's measured
-    step times (scripts/bench_probe.py table, BASELINE.md) within 20%
+    """The v5e timing preset must reproduce the step times its one
+    chip probe measured (the table below, from the r3/r4 sections of
+    `git show 6b5a9d4:BASELINE.md`) within 20%
     — the bar for planner/SLA validation against the mocker (ref:
     lib/mocker vllm core.rs timing model fidelity)."""
 

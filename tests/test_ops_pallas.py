@@ -26,10 +26,6 @@ from dynamo_tpu.ops.layout import (
     universal_to_layered,
     universal_to_nhd,
 )
-from jax_capabilities import (
-    requires_pallas_compiler_params,
-    requires_shard_map,
-)
 
 
 def _make_case(b=4, qh=8, kh=4, hd=64, ps=8, n_pages=32, max_pages=6,
@@ -65,7 +61,6 @@ def _oracle(q, k_pages, v_pages, block_tables, kv_lens):
     return out.reshape(b, qh, hd)
 
 
-@requires_pallas_compiler_params
 class TestPagedDecodeAttention:
     def test_matches_oracle_fp32(self):
         q, kp, vp, bt, kl = _make_case()
@@ -130,16 +125,7 @@ class TestPagedDecodeAttentionPartial:
     attention, and the partials must be foldable (the contract the
     deferred-write combine in forward_decode relies on)."""
 
-    @staticmethod
-    def _guard():
-        from dynamo_tpu.ops.paged_attention import pltpu
-
-        if not hasattr(pltpu, "CompilerParams"):
-            pytest.skip("this jax predates pltpu.CompilerParams "
-                        "(kernel tests run where the env is current)")
-
     def test_normalized_partials_match_oracle(self):
-        self._guard()
         from dynamo_tpu.ops.paged_attention import (
             paged_decode_attention_partial,
         )
@@ -159,7 +145,6 @@ class TestPagedDecodeAttentionPartial:
         flash rescale over the partials of the first two pages and the
         last two pages must reproduce attention over the full history —
         the exact combine forward_decode's deferred-write path runs."""
-        self._guard()
         from dynamo_tpu.ops.paged_attention import (
             paged_decode_attention_partial,
         )
@@ -187,7 +172,6 @@ class TestPagedDecodeAttentionPartial:
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
-@requires_pallas_compiler_params
 class TestPagedAttentionDecodeFused:
     """The deferred-write Pallas path (history partials + in-register
     current token) vs paged_attention_decode_xla as oracle."""
@@ -294,7 +278,6 @@ class TestPagedAttentionDecodeFused:
             rtol=1e-5, atol=1e-5)
 
 
-@requires_pallas_compiler_params
 class TestPagedAttentionDecodePool:
     """The production TPU decode path: whole-pool chunked-DMA kernel
     (paged_decode_attention_pool + combine) vs paged_attention_decode_xla
@@ -404,8 +387,6 @@ class TestPagedAttentionDecodePool:
             rtol=1e-5, atol=1e-5)
 
 
-@requires_pallas_compiler_params
-@requires_shard_map
 class TestPagedAttentionDecodePoolTp:
     """The pool kernel under tensor parallelism (VERDICT r2 weak #3):
     shard_map over the kv-head axis, each shard streaming its local pool

@@ -12,11 +12,9 @@ import jax.numpy as jnp
 from dynamo_tpu.models import forward, get_config, init_params, make_kv_cache
 from dynamo_tpu.models.transformer import make_pp_prefill
 from dynamo_tpu.parallel import MeshConfig, make_mesh
-from jax_capabilities import requires_shard_map
 
 # The whole pp plane is built on jax.shard_map (the gpipe loop shards
 # microbatches over the pp mesh axis).
-pytestmark = requires_shard_map
 
 
 def _inputs(m=2, mb=2, t=8, vocab=512, seed=0):

@@ -12,34 +12,6 @@ import pytest
 
 from dynamo_tpu.models import get_config
 
-_GUARD: dict = {}
-
-
-def _kernel_guard():
-    """Skip the kernel tiers where even interpret-mode Pallas cannot
-    run. Unlike the sibling kernel tests' hasattr(CompilerParams) guard,
-    this PROBES: ops/q4_linear carries a TPUCompilerParams compat shim,
-    so the parity tier runs on the older jax tier-1 uses too."""
-    if "err" not in _GUARD:
-        try:
-            import jax.numpy as jnp
-
-            from dynamo_tpu.ops.q4_linear import (
-                q4_matmul,
-                quantize_weight_q4,
-            )
-
-            qw = quantize_weight_q4(jnp.zeros((128, 128)), 1)
-            q4_matmul(jnp.zeros((1, 128)), qw["q4"], qw["qs4"],
-                      qw["qz4"], interpret=True)
-            _GUARD["err"] = None
-        except Exception as exc:  # noqa: BLE001 — any failure = old env
-            _GUARD["err"] = repr(exc)
-    if _GUARD["err"]:
-        pytest.skip("this jax cannot run interpret-mode Pallas "
-                    f"({_GUARD['err']}); kernel tests run where the "
-                    "env is current")
-
 
 class TestQ4Pack:
     def test_pack_roundtrip(self):
@@ -96,7 +68,6 @@ class TestQ4Pack:
 
         # The kernel's rank-1 zero-point fold must survive the huge
         # zero-points these groups produce (z ~ -lo/eps for constants).
-        _kernel_guard()
         from dynamo_tpu.ops.q4_linear import q4_matmul, q4_matmul_ref
 
         mixed = jnp.concatenate([const[:128], pos[:128]], axis=0)
@@ -131,7 +102,6 @@ class TestQ4Matmul:
     @pytest.mark.parametrize("m,k,n", [(8, 512, 512), (3, 1024, 512),
                                        (33, 384, 1536), (16, 128, 128)])
     def test_kernel_matches_reference(self, m, k, n):
-        _kernel_guard()
         from dynamo_tpu.ops.q4_linear import q4_matmul, q4_matmul_ref
 
         x, _, qw = self._case(m, k, n)
@@ -227,8 +197,8 @@ class TestQ4PackV2:
             resolve_pack_version,
         )
 
-        # auto: v2 wherever the global half-split is well-formed
-        assert resolve_pack_version(512, 256) == PACK_V2
+        # auto: v1, the layout that has run on the chip
+        assert resolve_pack_version(512, 256) == PACK_V1
         assert resolve_pack_version(256, 256) == PACK_V1  # K == group
         assert resolve_pack_version(128, 128) == PACK_V1
         monkeypatch.setenv("DYNT_Q4_VARIANT", "v1")
@@ -251,11 +221,11 @@ class TestQ4PackV2:
 
         rng = np.random.default_rng(1)
         w = jnp.asarray(rng.standard_normal((512, 128)), jnp.float32)
-        assert pack_version(quantize_weight_q4(w, 1)["q4"]) == 2  # auto
-        assert pack_version(quantize_weight_q4(w, 1, version=1)["q4"]) == 1
+        assert pack_version(quantize_weight_q4(w, 1)["q4"]) == 1  # auto
+        assert pack_version(quantize_weight_q4(w, 1, version=2)["q4"]) == 2
         small = jnp.asarray(rng.standard_normal((128, 128)), jnp.float32)
-        # small-K fallback: auto keeps v1 where the half-split is not
-        # well-formed; forcing v2 raises instead of mis-packing
+        # forcing v2 where the half-split is not well-formed raises
+        # instead of mis-packing
         assert pack_version(quantize_weight_q4(small, 1)["q4"]) == 1
         with pytest.raises(ValueError, match="v2"):
             quantize_weight_q4(small, 1, version=2)
@@ -354,7 +324,7 @@ class TestQ4PackV2:
         params = {"embed": np.zeros((8, 4), np.float32),
                   "layers": [{"wq": leaf, "attn_norm": norm}],
                   "lm_head": dict(leaf)}
-        out = repack_params_q4(params)  # auto -> v2 for K=512
+        out = repack_params_q4(params, version=2)
         assert out["layers"][0]["wq"]["q4"].dtype == np.int8
         assert out["lm_head"]["q4"].dtype == np.int8
         assert out["layers"][0]["attn_norm"] is norm
@@ -365,7 +335,7 @@ class TestQ4PackV2:
                                      out["layers"][0]["wq"]["qz4"])),
             np.asarray(dequantize_q4(leaf["q4"], leaf["qs4"],
                                      leaf["qz4"])))
-        again = repack_params_q4(out)
+        again = repack_params_q4(out, version=2)
         assert again["layers"][0]["wq"] is out["layers"][0]["wq"]
 
 
@@ -395,7 +365,6 @@ class TestQ4VariantParity:
         (33, 2048, 256),  # padded M, deep contraction
     ])
     def test_variant_matches_reference(self, version, m, k, n):
-        _kernel_guard()
         from dynamo_tpu.ops.q4_linear import q4_matmul, q4_matmul_ref
 
         x, qw = self._case(m, k, n, version)
@@ -408,7 +377,6 @@ class TestQ4VariantParity:
     @pytest.mark.parametrize("version,gk", [(1, 1), (1, 2), (1, 4),
                                             (2, 2), (2, 4)])
     def test_forced_gk(self, version, gk):
-        _kernel_guard()
         from dynamo_tpu.ops.q4_linear import q4_matmul, q4_matmul_ref
 
         x, qw = self._case(5, 2048, 256, version)
@@ -421,7 +389,6 @@ class TestQ4VariantParity:
     def test_small_k_fallback_group(self):
         """K below the preferred group: the group falls back to a
         divisor and auto stays on v1 — the fallback still matches."""
-        _kernel_guard()
         from dynamo_tpu.ops.q4_linear import (
             pack_version,
             q4_matmul,
@@ -439,7 +406,6 @@ class TestQ4VariantParity:
     def test_constant_group_zero_point_edge_v2(self):
         """The v2 rank-1 fold (zs = (z - 8) * s) must survive the huge
         zero-points constant/one-sided groups produce."""
-        _kernel_guard()
         import jax.numpy as jnp
 
         from dynamo_tpu.ops.q4_linear import (
@@ -464,7 +430,6 @@ class TestQ4VariantParity:
     def test_einsum_specs_v2_including_flat_wo(self):
         """q4_einsum carries the layout version (dtype-encoded) through
         every projection spec — including the flat multi-axis wo."""
-        _kernel_guard()
         import jax.numpy as jnp
 
         from dynamo_tpu.ops.q4_linear import (
@@ -704,8 +669,8 @@ class TestRunnerQ4Repack:
                 for name, leaf in r1.params["layers"][0].items()
             }],
         }
-        monkeypatch.delenv("DYNT_Q4_VARIANT", raising=False)
-        r2 = self._runner(config, params=host)  # auto -> repack to v2
+        monkeypatch.setenv("DYNT_Q4_VARIANT", "v2")
+        r2 = self._runner(config, params=host)  # repacked to the target
         v2_layer = r2.params["layers"][0]
         assert all(pack_version(v2_layer[n]["q4"]) == 2
                    for n in ("wq", "wo", "w_down"))

@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.models import get_config
-from jax_capabilities import requires_pallas_compiler_params
 
 
 class TestQ8Matmul:
@@ -24,7 +23,6 @@ class TestQ8Matmul:
         qw = quantize_weight(w, 1)
         return x, w, qw
 
-    @requires_pallas_compiler_params
     @pytest.mark.parametrize("m,k,n", [(8, 512, 512), (3, 1024, 512),
                                        (33, 512, 1536)])
     def test_kernel_matches_reference(self, m, k, n):

@@ -90,7 +90,6 @@ class TestWorkerModelRef:
                 env = dict(os.environ)
                 env.update({"DYNT_DISCOVERY_BACKEND": "file",
                             "DYNT_DISCOVERY_PATH": disc,
-                            "DYNT_JAX_PLATFORM": "cpu",
                             "JAX_PLATFORMS": "cpu",
                             "DYNT_SYSTEM_ENABLED": "0"})
                 proc = subprocess.Popen(

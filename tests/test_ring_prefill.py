@@ -7,11 +7,9 @@ import pytest
 from dynamo_tpu.engine.model_runner import ModelRunner, RunnerConfig
 from dynamo_tpu.models import get_config
 from dynamo_tpu.parallel import MeshConfig, make_mesh
-from jax_capabilities import requires_shard_map
 
 # Ring prefill rotates KV shards over the sp mesh axis via
 # jax.shard_map + ppermute.
-pytestmark = requires_shard_map
 
 
 def _make_runner(mesh_cfg):

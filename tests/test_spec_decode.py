@@ -427,14 +427,6 @@ class TestSpecKernelInterpret:
     """Interpret-mode Pallas verification-kernel tests on CPU against
     the XLA reference attention path."""
 
-    @pytest.fixture(autouse=True)
-    def _require_pallas(self):
-        from jax.experimental.pallas import tpu as pltpu
-
-        if not hasattr(pltpu, "CompilerParams"):
-            pytest.skip("this jax predates pltpu.CompilerParams "
-                        "(kernel tests need the current pallas API)")
-
     @pytest.mark.parametrize("t", [1, 3, 5])
     def test_spec_kernel_matches_xla_oracle(self, t):
         import jax.numpy as jnp
