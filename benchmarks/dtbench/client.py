@@ -132,7 +132,10 @@ class Client:
                     self.send(req, due, f"{tag}-{req.index}")))
 
         head = asyncio.ensure_future(dispatcher())
-        await asyncio.sleep(max(0.0, stop_at - time.monotonic()))
+        # until stop_at, or sooner where the requests run out before it
+        # (--trace 2 ends its tail's traffic with the capture)
+        await asyncio.wait([head],
+                           timeout=max(0.0, stop_at - time.monotonic()))
         head.cancel()
         await self._run_until(tasks + [head], stop_at)
 

@@ -139,11 +139,15 @@ class Fleet:
         raise FleetError(f"{model} not listed after {deadline_s:.0f}s:\n"
                          f"{tail(self.logs['worker'])}")
 
-    def profile(self, duration_ms: int) -> dict:
+    def profile(self, duration_ms: int, xplane_only: bool = False) -> dict:
         """The worker's own /debug/profile: a device trace with the
-        program's StepTraceAnnotation spans on the same clock."""
+        program's StepTraceAnnotation spans on the same clock. With
+        `xplane_only` (--trace 2) the worker is asked to write the
+        `.xplane.pb`, which is all the reduction reads, and to skip the
+        conversion to `trace.json.gz`, which is most of what a stop costs."""
         status, body = http_get(
-            f"{self.status}/debug/profile?duration_ms={duration_ms}",
+            f"{self.status}/debug/profile?duration_ms={duration_ms}"
+            + ("&export=xplane" if xplane_only else ""),
             timeout=duration_ms / 1e3 + 120)
         if status != 200:
             raise FleetError(f"/debug/profile -> {status}: {body[:200]!r}")

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The benchmark: one cell, one run.
 
-    python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1|2>
 
 A run is a new process tree on a machine that holds the cell's chip:
 
@@ -16,6 +16,30 @@ A run is a new process tree on a machine that holds the cell's chip:
 and its last line of standard output is the contract's JSON object. With
 `--trace 1` the worker's /debug/profile captures a few seconds of steady
 state inside the window and the line carries the per-layer metrics.
+
+`--trace 2` is one run that measures, then traces (`trace_in_run` in
+BENCHMARK.json). Up to the moment the window closes it is a `--trace 0`
+run: the same set-up, warm-up, ramp, window and scrapes around it, no
+profiler anywhere, and `correct`, `attempted`, `failed` and every
+end-to-end number come from that closed window. The same traffic then
+simply does not stop: in a tail after the window the worker's
+/debug/profile is started and stopped once and that trace thrown away
+(the first start's cost falls into no number), then it captures
+CAPTURE_MS of the steady state. The tail's traffic lasts as long as the
+traced span does (the profiler's start, which the program reports, plus
+CAPTURE_MS and a margin), not as long as the capture takes to come back:
+collecting and writing the trace takes several times the span and needs
+no traffic, so what is in flight runs to its end meanwhile (the worker is
+asked for the `.xplane.pb` alone, `export=xplane`: the reduction reads
+nothing else). The tail's requests lie in no window. The last line carries the end-to-end
+and the per-layer metrics side by side, `device.busy_s`/`window_s` of the
+capture and `breakdown`. Readers of counters and of the client's
+timelines get the measured window (untraced); readers of the trace get the
+tail's capture. The report line before it says what tracing cost while it
+was on (`tail`: the clients' tokens/s during the capture beside the
+window's). The program's counters and stage histograms are always on; its
+profiler spans (`prefill`/`decode` steps, `sched.*` sections) exist only
+inside a capture, so /debug/profile is the one control.
 
 This process never imports JAX: a chip belongs to one process.
 
@@ -59,6 +83,15 @@ EXIT_FAILED, EXIT_NO_CHIP, EXIT_NO_PROGRAM, EXIT_REHEARSAL = 1, 2, 3, 10
 
 MODEL_WAIT_SECS = 900.0
 CAPTURE_MS = 2500  # one capture of steady state per traced run
+# --trace 2: the profiler's first start and stop in the worker are taken
+# and thrown away before the capture that is read. The tail's traffic
+# covers the traced span and a margin (the request's way to the worker,
+# and a start that takes longer than the thrown-away one did); it ends at
+# the latest TAIL_MAX_SECS after the window, and requests for that long
+# are generated (the generator is prefix-stable in its count).
+PROFILER_WARM_MS = 50
+TRACED_SPAN_MARGIN_SECS = 2.0
+TAIL_MAX_SECS = 45.0
 
 
 def log(msg: str) -> None:
@@ -240,12 +273,17 @@ class Run:
 
     async def traffic_window(self, client: Client, seed: int,
                              seconds: float, tag: str,
-                             capture: bool = False) -> dict:
+                             capture: bool = False,
+                             trace_after: bool = False) -> dict:
         """Ramp, then `seconds` of the cell's traffic. Returns the
-        window's bounds on this clock and what was scraped around it."""
+        window's bounds on this clock and what was scraped around it.
+        `capture` traces inside the window (--trace 1); `trace_after`
+        leaves the window alone and keeps the same traffic going behind
+        it until a capture there is done (--trace 2)."""
         mix = self.plan.mix
         ramp = float(mix["ramp_seconds"])
-        total = ramp + seconds
+        tail = TAIL_MAX_SECS if trace_after else 0.0
+        total = ramp + seconds + tail
         if mix["loop"] == "closed":
             callers = (self.serve["max_batch"] if mix["callers"] == "max_batch"
                        else int(mix["callers"]))
@@ -257,6 +295,13 @@ class Run:
         t0 = start + ramp
         stop = t0 + seconds
         out: dict = {"t0": t0, "seconds": seconds}
+
+        def until_the_tail_is_done():
+            for req in reqs:
+                if "tail_done" in out or time.monotonic() >= out.get(
+                        "traffic_until", stop + tail):
+                    return
+                yield req
 
         async def around_window() -> None:
             await asyncio.sleep(max(0.0, t0 - time.monotonic()))
@@ -270,17 +315,51 @@ class Run:
                 out["mid"] = await asyncio.to_thread(self.fleet.scrape)
             await asyncio.sleep(max(0.0, stop - time.monotonic()))
             out["after"] = await asyncio.to_thread(self.fleet.scrape)
+            if trace_after:
+                try:
+                    await asyncio.to_thread(self.trace_the_tail, out)
+                finally:
+                    out["tail_done"] = time.monotonic()
 
         side = asyncio.ensure_future(around_window())
         if mix["loop"] == "closed":
             await client.closed_loop(
-                reqs, callers, stop, tag,
+                until_the_tail_is_done(), callers, stop + tail, tag,
                 float(mix.get("start_spread_seconds", 0)) / callers)
         else:
-            await client.open_loop(reqs, start, stop, tag)
+            await client.open_loop(until_the_tail_is_done(), start,
+                                   stop + tail, tag)
         await side
         self.fleet.check_alive()
         return out
+
+    def trace_the_tail(self, out: dict) -> None:
+        """--trace 2, behind the closed window: start and stop the
+        worker's profiler once and throw that trace away, then capture
+        CAPTURE_MS of the traffic that is still running. `capture_at` to
+        `capture_end` is the traced span where the program says how long
+        its profiler took to start (else the whole call, as --trace 1
+        has it, with the traffic kept up throughout)."""
+        t = time.monotonic()
+        warm = self.fleet.profile(PROFILER_WARM_MS, xplane_only=True)
+        shutil.rmtree(warm["trace_dir"], ignore_errors=True)
+        span = CAPTURE_MS / 1e3
+        out["capture_at"] = time.monotonic()
+        out["profiler_warm_s"] = out["capture_at"] - t
+        if "start_s" in warm:
+            out["traffic_until"] = (out["capture_at"] + warm["start_s"]
+                                    + span + TRACED_SPAN_MARGIN_SECS)
+        out["capture"] = self.fleet.profile(CAPTURE_MS, xplane_only=True)
+        out["capture_returned"] = time.monotonic()
+        if "traffic_until" in out:
+            out["capture_end"] = (out["capture_at"]
+                                  + out["capture"]["start_s"] + span)
+            if out["capture_end"] > out["traffic_until"]:
+                log("the tail's traffic ended before the traced span did: "
+                    "the capture's numbers are of a draining batch")
+        else:
+            out["capture_end"] = out["capture_returned"]
+        out["end"] = self.fleet.scrape()
 
     # -- the check --------------------------------------------------------
 
@@ -347,21 +426,55 @@ class Run:
                 os.path.join(self.scratch, "reference.log")))
         return load_json(out_path)
 
-    def reduce_trace(self, capture: dict) -> dict:
+    def start_reduction(self, capture: dict) -> subprocess.Popen:
         """Trace -> numbers, in a child held to the CPU."""
         files = [os.path.join(capture["trace_dir"], f)
                  for f in capture["files"] if f.endswith(".xplane.pb")]
         if not files:
             raise FleetError(f"the capture holds no .xplane.pb: {capture}")
-        out_path = os.path.join(self.scratch, "trace.json")
-        child = subprocess.run(
+        return subprocess.Popen(
             [sys.executable, os.path.join(HERE, "dtbench", "trace_reduce.py"),
-             files[0], out_path], cwd=ROOT,
-            env=dict(self.env, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=600)
+             files[0], os.path.join(self.scratch, "trace.json")], cwd=ROOT,
+            env=dict(self.env, JAX_PLATFORMS="cpu"), stdout=subprocess.PIPE,
+            stderr=subprocess.PIPE, text=True)
+
+    def reduced_trace(self, child: subprocess.Popen) -> dict:
+        try:
+            _out, err = child.communicate(timeout=600)
+        except subprocess.TimeoutExpired:
+            child.kill()
+            child.communicate()
+            raise
         if child.returncode:
-            raise FleetError("trace reduction failed: " + child.stderr[-800:])
-        return load_json(out_path)
+            raise FleetError("trace reduction failed: " + err[-800:])
+        return load_json(os.path.join(self.scratch, "trace.json"))
+
+    def tail_report(self, window: dict, timelines: list) -> dict:
+        """What tracing cost while it was on (--trace 2): the clients'
+        tokens/s while the profiler ran (its thrown-away first start and
+        stop, then the traced span), to set beside the window's
+        `out_tok_s`, and how long the capture took to come back. Also the
+        window's mean TTFT (the line has percentiles), which the program's
+        stage means should add up to."""
+        def out_tok_s(a: float, b: float) -> float:
+            return stats.window_summary(timelines, a, b - a)[
+                "metrics"]["out_tok_s"]
+
+        a, b = window["capture_at"], window["capture_end"]
+        t0, t1 = window["t0"], window["t0"] + window["seconds"]
+        ttft = [(t.first - t.due) * 1e3 for t in timelines
+                if t.ok and t.first is not None and t0 <= t.end < t1]
+        return {"window_ttft_mean_ms": sum(ttft) / max(1, len(ttft)),
+                "profiler_warm_s": window["profiler_warm_s"],
+                "profiler_warm_out_tok_s": out_tok_s(
+                    a - window["profiler_warm_s"], a),
+                "traced_span_s": b - a, "traced_out_tok_s": out_tok_s(a, b),
+                "traffic_covered_the_span": b <= window.get(
+                    "traffic_until", b),
+                "capture_s": window["capture_returned"] - a,
+                "capture_start_s": window["capture"].get("start_s"),
+                "capture_stop_s": window["capture"].get("stop_s"),
+                "tail_s": window["tail_done"] - t1}
 
 
 def verdict(plan: Plan, numbers: dict, counts_ok: bool) -> tuple[bool, list]:
@@ -385,7 +498,7 @@ async def one_run(run: Run, args) -> dict:
     async with Client(run.fleet.base, run.model) as client:
         window = await run.traffic_window(
             client, args.seed, args.seconds, f"s{args.seed}",
-            capture=bool(args.trace))
+            capture=args.trace == 1, trace_after=args.trace == 2)
         timelines = client.timelines
     setup_s = window["t0"] - T_START
     summary = stats.window_summary(timelines, window["t0"], args.seconds)
@@ -418,7 +531,7 @@ def main() -> int:
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--seconds", type=float, default=None)
-    parser.add_argument("--trace", type=int, default=0, choices=(0, 1))
+    parser.add_argument("--trace", type=int, default=0, choices=(0, 1, 2))
     parser.add_argument("--benchmark-json",
                         default=os.path.join(ROOT, "BENCHMARK.json"))
     parser.add_argument("--rehearse-cpu", action="store_true",
@@ -461,7 +574,8 @@ def main() -> int:
             return modes.main(run, args, log)
         result = asyncio.run(one_run(run, args))
         window, summary = result["window"], result["summary"]
-        peak = hbm(window["after"], "peak")
+        # the peak of the whole run: the tail's last scrape where there is one
+        peak = hbm(window.get("end", window["after"]), "peak")
         shutdown = run.fleet.stop()
         log(f"servers stopped: {shutdown}")
     except (FleetError, OSError, subprocess.TimeoutExpired) as exc:
@@ -483,7 +597,10 @@ def main() -> int:
                      for s, p in zip(samples, picked)))
     numbers: dict = {}
     controls: dict = {}
+    reducing = None
     try:
+        if args.trace == 2:  # on the CPU, beside the reference on the chip
+            reducing = run.start_reduction(window["capture"])
         if samples and all(s["served"] for s in samples):
             ref = run.reference([{"label": tag, "samples": samples,
                                   "control": bool(args.control)}])
@@ -492,15 +609,29 @@ def main() -> int:
             log(f"reference: {ref['seconds']:.1f}s on {ref['device']}")
             for name, row in controls.items():
                 log(f"control {name}: {json.dumps(row)}")
-        trace = (run.reduce_trace(window["capture"])
-                 if args.trace else None)
+        if args.trace == 1:
+            reducing = run.start_reduction(window["capture"])
+        trace = run.reduced_trace(reducing) if reducing else None
+        if args.trace == 2 and not args.rehearse_cpu:
+            # reduced: the trace itself is not kept (a rehearsal's is,
+            # in its scratch directory, to be looked at)
+            shutil.rmtree(window["capture"]["trace_dir"], ignore_errors=True)
     except (FleetError, OSError, subprocess.TimeoutExpired) as exc:
         log(f"FAILED after the window: {type(exc).__name__}: "
             f"{str(exc)[-1500:]}")
+        if reducing is not None and reducing.poll() is None:
+            reducing.kill()
+            reducing.communicate()
         return EXIT_FAILED
     correct, rows = verdict(plan, numbers, counts_ok)
 
     metrics: dict = {}
+    if args.trace != 1:
+        values = dict(summary["metrics"], setup_s=result["setup_s"])
+        for m in plan.metrics("end_to_end"):
+            if m["name"] in values:
+                metrics[m["name"]] = {"value": values[m["name"]],
+                                      "unit": m["unit"]}
     if args.trace:
         ctx = {"config": plan.config, "mix": plan.mix, "cell": plan.cell,
                "peaks": plan.peaks.get(run.engine.get("device_kind")),
@@ -517,12 +648,6 @@ def main() -> int:
             value = Plan.reader(m["name"])(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-    else:
-        values = dict(summary["metrics"], setup_s=result["setup_s"])
-        for m in plan.metrics("end_to_end"):
-            if m["name"] in values:
-                metrics[m["name"]] = {"value": values[m["name"]],
-                                      "unit": m["unit"]}
 
     device = {"platform": run.engine.get("platform"),
               "kind": run.engine.get("device_kind"),
@@ -547,6 +672,8 @@ def main() -> int:
                   "compared": rows, "controls": controls},
         "shutdown": shutdown,
     }
+    if args.trace == 2:
+        report["tail"] = run.tail_report(window, result["timelines"])
     print(json.dumps(report))
     for row in rows:
         print(f"compared {row['number']}: {row['value']} against limit "

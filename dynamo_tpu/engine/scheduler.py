@@ -38,7 +38,7 @@ from typing import Callable, Optional
 import numpy as np
 
 from ..llm.protocols import EngineOutput, PreprocessedRequest
-from ..perf.steptrace import StepTrace
+from ..perf.steptrace import StepTrace, annotation
 from ..runtime.flight_recorder import get_recorder
 from ..runtime.logging import get_logger
 from ..tokens import TokenBlockSequence, compute_block_hashes
@@ -47,6 +47,13 @@ from .pages import PageAllocation, PagePool
 from .spec import BlockLookahead, NGramProposer, SlotSpec, propose_for
 
 log = get_logger("engine.scheduler")
+
+
+def _section(name: str):
+    """A named host section of the loop (`sched.*`) on the profiler's
+    clock: a capture's idle gaps are named by it. No-op outside a
+    profiler session (perf/steptrace.py `annotation`)."""
+    return annotation(name, section=True)
 
 
 def _observe_preempt(instance: str, event: str) -> None:
@@ -184,6 +191,15 @@ class SchedulerStats:
     # finished prefilling.
     prefill_batched_steps: int = 0
     disagg_streamed_pages: int = 0
+    # Counts at the boundaries the tokens are counted at
+    # (dynamo_engine_launches, dynamo_kv_reserved_page_ms;
+    # docs/metrics.md): prefill programs and fused decode blocks
+    # launched (device decode steps are the runner's decode_steps), and
+    # the sum over committed steps of pages held by sequences in a slot
+    # x the step's wall ms.
+    prefill_launches: int = 0
+    decode_block_launches: int = 0
+    reserved_page_ms: float = 0.0
     # Speculative decoding (dynamo_spec_* metrics; docs/metrics.md):
     # proposed/accepted count MINED drafts only (static-shape padding is
     # excluded), spec_ema is the mean acceptance EMA over the slots that
@@ -455,6 +471,14 @@ class InferenceScheduler:
                 total += seq.kv_len
         return total
 
+    def reserved_pages(self) -> int:
+        """Pages allocated to sequences that hold a slot: what live
+        sequences have reserved of the pool, prefix-cache residue left
+        out (dynamo_kv_usage_ratio counts that too). Zero once every
+        sequence is reaped."""
+        return sum(len(seq.alloc.cached_pages) + len(seq.alloc.new_pages)
+                   for seq in list(self._slots) if seq is not None)
+
     def lora_in_flight(self, lora_slot: int) -> int:
         """Sequences (admitted, waiting, or just submitted) still bound to
         an adapter slot. Scheduler-thread only (run via run_in_step): drains
@@ -474,14 +498,17 @@ class InferenceScheduler:
         try:
             while not self._stop:
                 self._drain_control()
-                self._drain_incoming()
+                with _section("sched.drain_incoming"):
+                    self._drain_incoming()
                 progressed = self._step()
                 if not progressed:
                     # Idle: gap work has no dispatch/drain window to ride
                     # — run it here so offload/transfer gathers never
                     # stall on an idle engine.
-                    self._drain_gap()
-                    self._wake.wait(timeout=0.05)
+                    with _section("sched.gap"):
+                        self._drain_gap()
+                    with _section("sched.idle"):
+                        self._wake.wait(timeout=0.05)
                     self._wake.clear()
         except Exception as exc:  # noqa: BLE001 — the engine's last
             # boundary: a compiler refusal or device error inside a step
@@ -1125,7 +1152,8 @@ class InferenceScheduler:
         # Preemption/resume only on this first admit pass: no decode
         # block is in flight yet, so a victim's pages can be gathered
         # and released without racing a pending drain.
-        admitted = self._admit(allow_preempt=True)
+        with _section("sched.admit"):
+            admitted = self._admit(allow_preempt=True)
         # Deferred prefill tokens from the PREVIOUS iteration: their
         # device work was queued before this iteration's dispatches, so
         # by the time we materialize them below the result is (nearly)
@@ -1140,23 +1168,31 @@ class InferenceScheduler:
         prefill_tokens = self._prefill_some()
         # Overlap window: arrivals that landed during dispatch are
         # admitted while the device is still stepping the decode block.
-        self._drain_incoming()
-        late = self._admit()
+        with _section("sched.drain_incoming"):
+            self._drain_incoming()
+        with _section("sched.admit"):
+            late = self._admit()
         admitted += late
         # Gap work (KVBM offload gathers, streaming transfer gathers)
         # runs HERE — the decode block is in flight on device, the host
         # would otherwise idle until the drain, and the dispatched device
         # ops queue behind the block so they never delay it.
-        self._drain_gap()
+        with _section("sched.gap"):
+            self._drain_gap()
         # "blocks" handles are genuinely in flight here; a "count" handle
         # means _decode_single already read back (host-sampling path).
         if pending is not None and pending[0] == "blocks" and late:
             self.stats.admitted_during_inflight += late
         finalized = 0
-        for seq, tok_dev in ripe:
-            finalized += self._finalize_prefill(seq, tok_dev)
+        with _section("sched.finalize_prefill"):
+            for seq, tok_dev in ripe:
+                finalized += self._finalize_prefill(seq, tok_dev)
         decode_tokens = self._drain_decode(pending)
-        self._reap_finished()
+        # Pages the step's sequences held while it ran: taken before the
+        # reap returns the finished ones' (at most max_batch slots).
+        reserved = self.reserved_pages()
+        with _section("sched.reap"):
+            self._reap_finished()
         if prefill_tokens or decode_tokens or admitted or finalized:
             self.stats.steps += 1
             self.stats.prefill_tokens += prefill_tokens
@@ -1164,6 +1200,8 @@ class InferenceScheduler:
             self.stats.prefill_tokens_last_step = prefill_tokens
             self.stats.decode_tokens_last_step = decode_tokens
             self.stats.last_step_wall_ms = (time.monotonic() - start) * 1e3
+            self.stats.reserved_page_ms += (
+                reserved * self.stats.last_step_wall_ms)
             sample = self.steptrace.commit(self.stats.last_step_wall_ms)
             self.stats.device_ms_last_step = sample.device_ms
             self.stats.host_ms_last_step = sample.host_ms
@@ -1200,6 +1238,7 @@ class InferenceScheduler:
                     seq.prefill_submit_ts = time.monotonic()
             # The ring step materializes its samples in-call: one
             # blocking device window covering the whole batched pass.
+            self.stats.prefill_launches += 1
             with self.steptrace.sync("prefill", self.stats.steps) as rsc:
                 result = self.runner.prefill_ring_batch(
                     [np.asarray(s.request.token_ids[: s.prompt_len],  # dynalint: disable=DL201 -- host token list to int32, no device transfer
@@ -1234,6 +1273,21 @@ class InferenceScheduler:
         # low-MFU small-model prefill (VERDICT item 10: a [1, chunk]
         # forward at 0.6B leaves the MXU idle; [B, chunk] restores the
         # arithmetic intensity without spending more step-time budget).
+        with _section("sched.prefill_prep"):
+            work = self._prefill_work(budget)
+        if not work:
+            return 0
+        if len(work) > 1 and self._can_batch_prefill(work):
+            return self._prefill_batch(work)
+        total = 0
+        for seq, chunk in work:
+            total += self._prefill_single(seq, chunk)
+        return total
+
+    def _prefill_work(self, budget: int) -> list:
+        """This iteration's (sequence, chunk) rows in slot order, filling
+        the shared token budget; stamps `prefill_start` on a sequence's
+        first chunk."""
         work: list[tuple[_Seq, int]] = []
         spent = 0
         for seq in self._slots:
@@ -1256,14 +1310,7 @@ class InferenceScheduler:
                 get_recorder().stamp(seq.record_id, "prefill_start")
             work.append((seq, chunk))
             spent += chunk
-        if not work:
-            return 0
-        if len(work) > 1 and self._can_batch_prefill(work):
-            return self._prefill_batch(work)
-        total = 0
-        for seq, chunk in work:
-            total += self._prefill_single(seq, chunk)
-        return total
+        return work
 
     def _can_batch_prefill(self, work: list) -> bool:
         """Cross-sequence chunk batching requires a runner with the
@@ -1304,6 +1351,7 @@ class InferenceScheduler:
                  else self.steptrace.sync("prefill", self.stats.steps))
         if seq.prefill_submit_ts is None:
             seq.prefill_submit_ts = time.monotonic()
+        self.stats.prefill_launches += 1
         with scope:
             token = self.runner.prefill_chunk(
                 tokens, seq.prefill_pos, seq.block_table,
@@ -1345,17 +1393,18 @@ class InferenceScheduler:
         finals = [seq.prefill_pos + chunk >= seq.prompt_len
                   for seq, chunk in work]
         rows = []
-        for seq, chunk in work:
-            tokens = np.asarray(  # dynalint: disable=DL201 -- host token list to int32, no device transfer
-                seq.request.token_ids[
-                    seq.prefill_pos : seq.prefill_pos + chunk],
-                np.int32,
-            )
-            s = seq.request.sampling
-            rows.append((tokens, seq.prefill_pos, seq.block_table,
-                         seq.prefill_pos + chunk,
-                         (s.temperature, s.top_p, s.top_k, seq.seed),
-                         seq.lora_idx))
+        with _section("sched.prefill_prep"):
+            for seq, chunk in work:
+                tokens = np.asarray(  # dynalint: disable=DL201 -- host token list to int32, no device transfer
+                    seq.request.token_ids[
+                        seq.prefill_pos : seq.prefill_pos + chunk],
+                    np.int32,
+                )
+                s = seq.request.sampling
+                rows.append((tokens, seq.prefill_pos, seq.block_table,
+                             seq.prefill_pos + chunk,
+                             (s.temperature, s.top_p, s.top_k, seq.seed),
+                             seq.lora_idx))
         want_samples = any(
             final and seq.request.sampling.logprobs
             for final, (seq, _) in zip(finals, work))
@@ -1363,6 +1412,7 @@ class InferenceScheduler:
         for seq, _chunk in work:
             if seq.prefill_submit_ts is None:
                 seq.prefill_submit_ts = now
+        self.stats.prefill_launches += 1
         with self.steptrace.dispatch("prefill", self.stats.steps):
             toks_dev = self.runner.prefill_chunk_batch(
                 rows, want_samples=want_samples)
@@ -1537,28 +1587,29 @@ class InferenceScheduler:
         # token is produced through the host path.)
         if not ready:
             return None
-        self._active[:] = False
-        # Neutralize params of inactive slots: sample()'s runtime gate
-        # skips the full-vocab truncation sort only when NO slot truncates,
-        # and a finished top_k/top_p request must not keep forcing the
-        # expensive branch from a stale slot.
-        self._temp[:] = 0.0
-        self._top_p[:] = 1.0
-        self._top_k[:] = 0
-        for seq in ready:
-            i = seq.slot
-            self._tokens[i] = seq.last_token
-            self._positions[i] = seq.kv_len - 1  # position of last_token
-            self._tables[i] = seq.block_table
-            self._kv_lens[i] = seq.kv_len
-            self._active[i] = True
-            s = seq.request.sampling
-            self._temp[i] = s.temperature
-            self._top_p[i] = s.top_p
-            self._top_k[i] = s.top_k
-            self._seeds[i] = seq.seed
-            self._steps[i] = len(seq.generated)
-            self._lora_idx[i] = seq.lora_idx
+        with _section("sched.decode_prep"):
+            self._active[:] = False
+            # Neutralize params of inactive slots: sample()'s runtime
+            # gate skips the full-vocab truncation sort only when NO slot
+            # truncates, and a finished top_k/top_p request must not keep
+            # forcing the expensive branch from a stale slot.
+            self._temp[:] = 0.0
+            self._top_p[:] = 1.0
+            self._top_k[:] = 0
+            for seq in ready:
+                i = seq.slot
+                self._tokens[i] = seq.last_token
+                self._positions[i] = seq.kv_len - 1  # position of last_token
+                self._tables[i] = seq.block_table
+                self._kv_lens[i] = seq.kv_len
+                self._active[i] = True
+                s = seq.request.sampling
+                self._temp[i] = s.temperature
+                self._top_p[i] = s.top_p
+                self._top_k[i] = s.top_k
+                self._seeds[i] = seq.seed
+                self._steps[i] = len(seq.generated)
+                self._lora_idx[i] = seq.lora_idx
         want_logprobs = any(s.request.sampling.logprobs for s in ready)
         want_logits = any(s.processors for s in ready)
         spec = self._maybe_dispatch_spec(ready, want_logprobs, want_logits)
@@ -1605,6 +1656,7 @@ class InferenceScheduler:
                         lora_idx=self._lora_idx, return_device=True,
                     )
                     device_blocks.append(toks_dev)
+            self.stats.decode_block_launches += depth
             return ("blocks", device_blocks, ready, block)
         return ("count",
                 self._decode_single(ready, tables, want_logprobs,
@@ -1636,13 +1688,15 @@ class InferenceScheduler:
         for seq in ready:
             seq.device_decode_ms += drain.device_ms
         count = 0
-        for toks_k in blocks_np:
-            for step in range(block):
-                for seq in ready:
-                    if seq.finished or seq.cancelled:
-                        continue  # EOS/stop inside: discard the rest
-                    self._append_token(seq, int(toks_k[step][seq.slot]))
-                    count += 1
+        with _section("sched.emit"):
+            for toks_k in blocks_np:
+                for step in range(block):
+                    for seq in ready:
+                        if seq.finished or seq.cancelled:
+                            continue  # EOS/stop inside: discard the rest
+                        self._append_token(seq,
+                                           int(toks_k[step][seq.slot]))
+                        count += 1
         return count
 
     # -- speculative decoding (engine/spec.py; docs/speculative-decoding.md)

@@ -56,6 +56,25 @@ from .scheduler import InferenceScheduler
 log = get_logger("engine.worker")
 
 
+def observe_stages(timeline, request, prefill_only: bool = False) -> None:
+    """A request's closed flight-recorder timeline, observed into
+    dynamo_stage_duration_seconds{stage} where it closes: ingress (only
+    when the frontend's arrival time rode the annotations), queue,
+    prefill_wait, prefill, decode (runtime/flight_recorder.py
+    `stage_durations`; docs/observability.md)."""
+    from ..runtime.flight_recorder import stage_durations
+    from ..runtime.metrics import STAGE_DURATION
+
+    received_at = request.annotations.get("received_at")
+    if isinstance(received_at, bool) \
+            or not isinstance(received_at, (int, float)):
+        received_at = None
+    for stage, seconds in stage_durations(
+            timeline.phases, received_at, prefill_only).items():
+        STAGE_DURATION.labels(stage=stage,
+                              model=request.model).observe(seconds)
+
+
 class KvEventBuffer:
     """Thread-safe KV event buffer: the scheduler thread records stored /
     removed page hashes; an async drain task batches them onto the event
@@ -1413,9 +1432,16 @@ class TpuWorker:
                 request_shutdown("drain control verb")
 
     def _publish_engine_gauges(self) -> None:
-        """Tokens processed and per-chip device memory (docs/metrics.md:
-        dynamo_engine_tokens, dynamo_device_hbm_bytes)."""
-        from ..runtime.metrics import DEVICE_HBM_BYTES, ENGINE_TOKENS
+        """Tokens processed, programs launched, page-time reserved and
+        per-chip device memory (docs/metrics.md: dynamo_engine_tokens,
+        dynamo_engine_launches, dynamo_kv_reserved_page_ms,
+        dynamo_device_hbm_bytes)."""
+        from ..runtime.metrics import (
+            DEVICE_HBM_BYTES,
+            ENGINE_LAUNCHES,
+            ENGINE_TOKENS,
+            KV_RESERVED_PAGE_MS,
+        )
 
         worker = f"{self.instance_id:x}"
         stats = self.scheduler.stats
@@ -1423,6 +1449,13 @@ class TpuWorker:
             stats.prefill_tokens)
         ENGINE_TOKENS.labels(worker=worker, kind="decode").set(
             stats.decode_tokens)
+        for kind, count in (
+                ("prefill", stats.prefill_launches),
+                ("decode_block", stats.decode_block_launches),
+                ("decode_step", getattr(self.runner, "decode_steps", 0))):
+            ENGINE_LAUNCHES.labels(worker=worker, kind=kind).set(count)
+        KV_RESERVED_PAGE_MS.labels(worker=worker).set(
+            stats.reserved_page_ms)
         for device in self.mesh.local_devices:
             mem = device.memory_stats() or {}
             for kind, key in (("in_use", "bytes_in_use"),
@@ -1460,8 +1493,9 @@ class TpuWorker:
 
     def _publish_steptrace_metrics(self) -> None:
         """Publish the device-time attribution plane (perf/steptrace.py):
-        per-step device/host histograms from the samples buffered since
-        the last drain, the host-bound verdict, and the live MFU /
+        per-step device/host histograms, the steps' wall and its
+        measured parts (prep, dispatch, drain_wait) from the samples
+        buffered since the last drain, the host-bound verdict, and the live MFU /
         roofline-fraction gauges computed from this interval's work via
         the analytical TimingModel."""
         from ..runtime.metrics import (
@@ -1470,14 +1504,23 @@ class TpuWorker:
             ROOFLINE_FRACTION,
             STEP_DEVICE_MS,
             STEP_HOST_MS,
+            STEP_PART_MS,
         )
 
         trace = self.scheduler.steptrace
         worker = f"{self.instance_id:x}"
+        parts = {"wall": 0.0, "prep": 0.0, "dispatch": 0.0,
+                 "drain_wait": 0.0}
         for sample in trace.drain_samples():
             for phase, ms in sample.device_by_phase.items():
                 STEP_DEVICE_MS.labels(phase=phase).observe(ms)
             STEP_HOST_MS.labels(phase=sample.kind).observe(sample.host_ms)
+            parts["wall"] += sample.wall_ms
+            parts["prep"] += sample.prep_ms
+            parts["dispatch"] += sample.dispatch_ms
+            parts["drain_wait"] += sample.drain_ms
+        for part, ms in parts.items():
+            STEP_PART_MS.labels(part=part).inc(ms)
         HOST_BOUND.labels(worker=worker).set(1.0 if trace.host_bound
                                              else 0.0)
         stats = self.scheduler.stats
@@ -1816,6 +1859,7 @@ class TpuWorker:
                         dev_ms,
                         exemplar={"trace_id": timeline.trace_id}
                         if timeline.trace_id else None)
+                observe_stages(timeline, request, prefill_only)
             self._record_phase_trace(tracer, worker_span, timeline,
                                      prefill_only)
             worker_span.end(ok=status == "ok")

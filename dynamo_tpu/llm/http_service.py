@@ -538,6 +538,10 @@ class HttpService:
         tp = span.traceparent or request.headers.get("traceparent")
         if tp:
             preprocessed.annotations["traceparent"] = tp
+        if received is not None:
+            # The worker closes the `ingress` stage from it (frontend,
+            # router, request plane): docs/observability.md.
+            preprocessed.annotations["received_at"] = received
         current_trace_id.set(_trace_id_of(preprocessed) or None)
         get_recorder().start(preprocessed.request_id,
                              model=preprocessed.model,

@@ -24,7 +24,8 @@ construction (host is the residual of the measured device window), and
 Measurement contract: dispatch scopes stamp at submit start/end and
 enter a `jax.profiler.StepTraceAnnotation` (so an on-demand
 `/debug/profile` capture attributes device ops to engine phases); drain
-scopes stamp at drain-complete. A phase's per-step device window runs
+scopes stamp at drain-complete and mark the blocked readback as the
+host section `sched.drain_wait` in such a capture. A phase's per-step device window runs
 from ITS OWN submit end this step to its drain end — a readback of work
 submitted last step (deferred prefill tokens) contributes only its
 blocked-wait slice, keeping every window inside the step wall.
@@ -51,14 +52,21 @@ PHASES = ("decode", "prefill", "spec")
 HOST_BOUND_STEPS = 8
 
 
-def annotation(phase: str, step: Optional[int] = None):
+def annotation(phase: str, step: Optional[int] = None,
+               section: bool = False):
     """`jax.profiler.StepTraceAnnotation` scope for one engine dispatch
-    — a no-op unless a profiler trace is active, and a nullcontext for
-    consumers without jax (the mocker's CI installs none)."""
+    or, with `section`, a plain `TraceAnnotation` for a named host
+    section of the scheduler loop (`sched.admit`, `sched.drain_wait`,
+    ...: docs/observability.md), so an on-demand capture names what the
+    host was doing on the device trace's clock — a no-op unless a
+    profiler trace is active, and a nullcontext for consumers without
+    jax (the mocker's CI installs none)."""
     try:
         from jax import profiler
     except ImportError:
         return contextlib.nullcontext()
+    if section:
+        return profiler.TraceAnnotation(phase)
     if step is None:
         return profiler.StepTraceAnnotation(phase)
     return profiler.StepTraceAnnotation(phase, step_num=step)
@@ -147,13 +155,16 @@ class _DrainScope:
         self._trace = trace
         self._phase = phase
         self._anchored = anchored
+        self._ann = annotation("sched.drain_wait", section=True)
         self.device_ms = 0.0
 
     def __enter__(self) -> "_DrainScope":
         self._start = self._trace._clock()
+        self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self._ann.__exit__(exc_type, exc, tb)
         end = self._trace._clock()
         anchor = self._start
         if self._anchored:

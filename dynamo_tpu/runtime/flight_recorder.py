@@ -41,6 +41,34 @@ log = get_logger("flight_recorder")
 PHASES = ("received", "queued", "scheduled", "prefill_start",
           "first_token", "finished")
 
+# A closed timeline as stages of dynamo_stage_duration_seconds
+# (docs/observability.md): each is end - start of two stamps of one
+# request id. `ingress` starts at the frontend's arrival time, which
+# rides the request annotations ("received_at") to the worker.
+STAGES = (("queue", "received", "scheduled"),
+          ("prefill_wait", "scheduled", "prefill_start"),
+          ("prefill", "prefill_start", "first_token"),
+          ("decode", "first_token", "finished"))
+
+
+def stage_durations(phases: dict, received_at: Optional[float] = None,
+                    prefill_only: bool = False) -> dict:
+    """{stage: seconds} for every stage whose two stamps the timeline
+    holds. `ingress` only when the frontend's arrival time came with the
+    request; both clocks are time.time(), so across hosts it is good to
+    their clock sync (never negative here). A prefill-only leg never
+    decodes: its first_token -> finished is the transfer hand-off."""
+    out = {}
+    if received_at is not None and "received" in phases:
+        out["ingress"] = max(0.0, phases["received"] - received_at)
+    for stage, start, end in STAGES:
+        if stage == "decode" and prefill_only:
+            continue
+        if start in phases and end in phases:
+            out[stage] = max(0.0, phases[end] - phases[start])
+    return out
+
+
 # Inflight entries older than this are presumed leaked (a peer that
 # stamped but never finished — e.g. a prefill pool whose decode side
 # died) and retired so the inflight map stays bounded.

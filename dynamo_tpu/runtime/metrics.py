@@ -327,6 +327,23 @@ ENGINE_TOKENS = Gauge(
     "(prefill | decode)",
     ["worker", "kind"], registry=REGISTRY,
 )
+ENGINE_LAUNCHES = Gauge(
+    "dynamo_engine_launches",
+    "Device programs this worker's engine has launched since start, by "
+    "kind: prefill (prefill programs), decode_block (fused decode "
+    "blocks), decode_step (device decode steps: a fused block of k "
+    "counts k). With dynamo_engine_tokens: tokens per prefill launch "
+    "and useful rows per decode step",
+    ["worker", "kind"], registry=REGISTRY,
+)
+KV_RESERVED_PAGE_MS = Gauge(
+    "dynamo_kv_reserved_page_ms",
+    "Sum over committed steps of (pages allocated to sequences that "
+    "hold a slot, prefix-cache residue left out) x the step's wall ms. "
+    "Over the growth of dynamo_step_part_ms_total{part=wall} it is the "
+    "time-weighted mean of pages reserved by live sequences",
+    ["worker"], registry=REGISTRY,
+)
 DEVICE_HBM_BYTES = Gauge(
     "dynamo_device_hbm_bytes",
     "Device memory of each chip in this worker's mesh as the backend "
@@ -455,6 +472,15 @@ STEP_HOST_MS = Histogram(
     ["phase"], registry=REGISTRY,
     buckets=(0.05, 0.2, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0,
              500.0, 2000.0),
+)
+STEP_PART_MS = Counter(
+    "dynamo_step_part_ms_total",
+    "The committed steps' wall time in ms, summed (part=wall), and its "
+    "measured parts: prep (step start -> first dispatch submit), "
+    "dispatch (host time inside runner submit calls), drain_wait (the "
+    "blocked readback slice of the device window). A part's share of a "
+    "window is its growth over that of wall",
+    ["part"], registry=REGISTRY,
 )
 TTFT_DEVICE_MS = Histogram(
     "dynamo_ttft_device_ms",

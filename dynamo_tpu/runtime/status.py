@@ -35,15 +35,53 @@ log = get_logger("status")
 _PROFILE_LOCK = threading.Lock()
 
 
+def _stop_trace_xplane_only(trace_dir: str) -> None:
+    """Stop the running profiler session and write its `.xplane.pb`
+    where `jax.profiler.stop_trace` would, without the `trace.json.gz`
+    that jax converts every capture into as well (most of what a stop
+    costs; nothing but the old trace viewer reads it). jax offers the
+    two steps only on its session object, so where that is not as
+    expected the capture is stopped the public way."""
+    import os
+    import socket
+
+    from jax import profiler
+
+    try:
+        from jax._src import profiler as jax_profiler
+
+        state = jax_profiler._profile_state
+        with state.lock:
+            xspace = state.profile_session.stop()
+            state.reset()
+    except AttributeError:
+        profiler.stop_trace()
+        return
+    run_dir = os.path.join(trace_dir, "plugins", "profile",
+                           time.strftime("%Y_%m_%d_%H_%M_%S"))
+    os.makedirs(run_dir, exist_ok=True)
+    with open(os.path.join(run_dir, socket.gethostname() + ".xplane.pb"),
+              "wb") as f:
+        f.write(xspace)
+
+
 async def profile_response(request: web.Request) -> web.Response:
     """Shared /debug/profile responder (status server + opt-in
     frontend): run `jax.profiler.start_trace` / `stop_trace` for
     ?duration_ms= (default DYNT_PROF_DEFAULT_MS, clamped to
     DYNT_PROF_MAX_MS) and answer with the capture directory. The
-    engine's dispatch scopes carry StepTraceAnnotation marks
-    (perf/steptrace.py), so the capture attributes device ops to
-    decode/prefill/spec phases. 409 while another capture runs; 503
-    in a process that has not imported JAX (it is never imported here)."""
+    engine's dispatch scopes carry StepTraceAnnotation marks and the
+    scheduler's host sections TraceAnnotation marks (`sched.*`,
+    perf/steptrace.py), so the capture attributes device ops to
+    decode/prefill/spec phases and idle gaps to what the host was
+    doing. The answer says what the capture cost: `start_s` (the
+    profiler's start: the traced span begins that long after the
+    request came) and `stop_s` (collecting and writing the trace,
+    which is nearly all of it). `&export=xplane` writes the
+    `.xplane.pb` alone (what XProf and a reduction read) and skips
+    jax's conversion to `trace.json.gz`, the larger and less steady
+    part of a stop. 409 while another capture runs; 503 in a process
+    that has not imported JAX (it is never imported here)."""
     try:
         duration = float(request.query.get(
             "duration_ms", env("DYNT_PROF_DEFAULT_MS")))
@@ -78,16 +116,23 @@ async def profile_response(request: web.Request) -> web.Response:
         # start/stop serialize trace buffers to disk — seconds for a
         # long capture — and must never freeze the serving event loop
         # (token streams, /health, the metrics drain all live on it).
+        t_start = time.monotonic()
         try:
             await asyncio.to_thread(profiler.start_trace, trace_dir)
         except Exception as exc:  # noqa: BLE001 — backend refused
             return web.json_response(
                 {"error": f"start_trace failed: {exc!r}"}, status=503)
+        start_s = time.monotonic() - t_start
         try:
             await asyncio.sleep(duration / 1e3)
         finally:
+            t_stop = time.monotonic()
             try:
-                await asyncio.to_thread(profiler.stop_trace)
+                if request.query.get("export") == "xplane":
+                    await asyncio.to_thread(_stop_trace_xplane_only,
+                                            trace_dir)
+                else:
+                    await asyncio.to_thread(profiler.stop_trace)
             except Exception as exc:  # noqa: BLE001 — a failed stop
                 # still ends the session server-side; report it
                 return web.json_response(
@@ -105,6 +150,8 @@ async def profile_response(request: web.Request) -> web.Response:
         return web.json_response({
             "trace_dir": trace_dir,
             "duration_ms": duration,
+            "start_s": round(start_s, 3),
+            "stop_s": round(time.monotonic() - t_stop, 3),
             "files": sorted(files),
         })
     finally:

@@ -32,8 +32,11 @@ def load(*parts):
 
 def test_keys_names_and_units():
     b = bench()
-    assert set(b) == {"command", "paths", "run_seconds", "configs",
-                      "workloads", "end_to_end", "per_layer"}
+    assert set(b) - {"trace_in_run"} == {
+        "command", "paths", "run_seconds", "configs", "workloads",
+        "end_to_end", "per_layer"}
+    # one run that measures, then traces (--trace 2): true or absent
+    assert b.get("trace_in_run", True) is True
     assert 1 <= b["run_seconds"] <= 51 and isinstance(b["run_seconds"], int)
     assert b["paths"] == ["benchmarks", "tests/bench"]
     for group in ("configs", "workloads", "end_to_end", "per_layer"):

@@ -4,6 +4,7 @@ children, warm-up, window, recording, reference child, trace reduction,
 the last line. A rehearsal can never read as a chip result: platform cpu
 on the line, exit code 10."""
 
+import glob
 import json
 import os
 import subprocess
@@ -49,7 +50,8 @@ def rehearse(tmp_path, trace, seed, env=None, traffic="rehearsal"):
 
 @pytest.mark.slow
 @pytest.mark.parametrize("trace,traffic", [(0, "rehearsal"),
-                                           (1, "rehearsal-open")])
+                                           (1, "rehearsal-open"),
+                                           (2, "rehearsal")])
 def test_a_rehearsed_run_prints_the_contracts_line(tmp_path, trace, traffic):
     out, line, lines = rehearse(tmp_path, trace, 2**31 + 77, traffic=traffic)
     assert out.returncode == 10, out.stderr[-2000:]
@@ -59,16 +61,55 @@ def test_a_rehearsed_run_prints_the_contracts_line(tmp_path, trace, traffic):
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 5
     names = set(line["metrics"])
+    end_to_end = {"out_tok_s", "tpot_p95_ms", "setup_s"}
     if trace:
         assert {"sched_host_share_pct", "window_compiles",
                 "kv_pool_live_pct", "preempts", "ttft_p50_ms",
-                "ttft_p95_ms", "tpot_p50_ms"} <= names
-        assert "out_tok_s" not in names and "tpot_p95_ms" not in names
+                "ttft_p95_ms", "tpot_p50_ms",
+                # counted inside the worker (PR 26)
+                "ingress_mean_ms", "queue_wait_mean_ms",
+                "prefill_wait_mean_ms", "prefill_span_mean_ms",
+                "decode_rows_mean", "prefill_launch_tokens_mean",
+                "kv_reserved_pct", "runner_dispatch_share_pct"} <= names
         assert {"busy_s", "window_s"} <= set(line["device"])
         assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
-    else:
-        assert names == {"out_tok_s", "tpot_p95_ms", "setup_s"}
-        assert all(v["value"] > 0 for v in line["metrics"].values())
+    if trace == 1:
+        assert not names & end_to_end
+    elif trace == 0:
+        assert names == end_to_end
+    if trace != 1:
+        assert all(line["metrics"][n]["value"] > 0 for n in end_to_end)
+    if trace == 2:
+        # one run that measures, then traces: both kinds side by side,
+        # the window's numbers taken before the profiler ever started
+        assert end_to_end < names
+        report = json.loads(lines[0])
+        tail = report["tail"]
+        assert tail["capture_s"] >= 2.5 and tail["profiler_warm_s"] > 0
+        # the traffic did not stop with the window: it lasted the traced
+        # span, which is shorter than the capture took to come back
+        assert tail["traced_out_tok_s"] > 0
+        assert tail["traffic_covered_the_span"] is True
+        assert 2.5 <= tail["traced_span_s"] <= tail["capture_s"]
+        assert tail["capture_start_s"] >= 0 and tail["capture_stop_s"] > 0
+        assert 0 < line["metrics"]["decode_rows_mean"]["value"] <= 4
+        assert 0 < line["metrics"]["kv_reserved_pct"]["value"] <= 100
+        # the scheduler's sections are in the capture's host plane (a
+        # rehearsal keeps its capture; a chip run deletes it once reduced)
+        from dtbench import trace_reduce
+
+        found = glob.glob(os.path.join(
+            ROOT, ".bench_cache", "run", "tiny." + traffic, "profile",
+            "**", "*.xplane.pb"), recursive=True)
+        assert len(found) == 1, found  # the profiler's warm-up is gone
+        host = {e[2] for plane in trace_reduce.read_planes(found[0])
+                if plane["name"].startswith("/host:")
+                for ln in plane["lines"] for e in ln["events"]}
+        assert {"sched.drain_incoming", "sched.admit", "sched.decode_prep",
+                "sched.prefill_prep", "sched.gap", "sched.finalize_prefill",
+                "sched.drain_wait", "sched.emit", "sched.reap", "decode",
+                "prefill"} <= host, sorted(
+            n for n in host if not n.startswith("$"))[:60]
     # every number compared is printed beside its limit
     assert any(ln.startswith("compared gap_max:") for ln in lines)
 

@@ -508,9 +508,23 @@ class TestDeviceTtftE2E:
 
 
 class TestProfileEndpoint:
+    @pytest.mark.parametrize("query,extra", [
+        ("", {"trace.json.gz"}), ("&export=xplane", set())])
     def test_capture_returns_artifact(self, run, tmp_path,
-                                      monkeypatch):
+                                      monkeypatch, query, extra):
+        """The capture holds this process's named sections, and the
+        answer says what starting and stopping the profiler cost;
+        `export=xplane` writes the .xplane.pb and nothing beside it."""
         monkeypatch.setenv("DYNT_PROF_DIR", str(tmp_path))
+        import jax.numpy as jnp
+
+        from dynamo_tpu.perf.steptrace import annotation
+
+        async def work():
+            for _ in range(30):
+                with annotation("sched.probe", section=True):
+                    jnp.zeros(8).block_until_ready()
+                await asyncio.sleep(0.005)
 
         async def body():
             from dynamo_tpu.runtime.status import SystemStatusServer
@@ -519,10 +533,13 @@ class TestProfileEndpoint:
             await server.start()
             base = f"http://127.0.0.1:{server.port}"
             async with aiohttp.ClientSession() as session:
+                busy = asyncio.ensure_future(work())
                 async with session.get(
-                        f"{base}/debug/profile?duration_ms=60") as resp:
+                        f"{base}/debug/profile?duration_ms=60"
+                        + query) as resp:
                     body_json = await resp.json()
                     status = resp.status
+                await busy
             await server.close()
             return status, body_json
 
@@ -531,7 +548,19 @@ class TestProfileEndpoint:
         assert body_json["trace_dir"].startswith(str(tmp_path))
         import os
 
+        from jax.profiler import ProfileData
+
         assert os.path.isdir(body_json["trace_dir"])
+        xplane = [f for f in body_json["files"] if f.endswith(".xplane.pb")]
+        assert xplane, body_json["files"]
+        names = {e.name for plane in ProfileData.from_file(os.path.join(
+            body_json["trace_dir"], xplane[0])).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+        assert "sched.probe" in names
+        assert body_json["start_s"] >= 0 and body_json["stop_s"] > 0
+        assert {f.split(".", 1)[1] for f in body_json["files"]} == {
+            "xplane.pb"} | extra
 
     def test_bad_duration_rejected(self, run, monkeypatch):
         async def body():
