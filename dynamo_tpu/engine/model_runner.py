@@ -207,10 +207,10 @@ def _default_spec_attention_fn(mesh: Mesh):
 def _default_decode_attention_fn(mesh: Mesh):
     """History-attention kernel for the DEFERRED-write decode path.
 
-    On TPU the XLA page gather lowers to scatter-shaped HLO an order of
-    magnitude off the HBM roofline (measured: the gather alone accounted
-    for ~90% of decode step time); the whole-pool chunked-DMA Pallas kernel
-    streams only the owned pages with no per-layer slice copies.
+    On TPU the XLA page gather reads every table's full extent through
+    scatter-shaped HLO; the whole-pool chunked-DMA Pallas kernel streams
+    only the owned pages of active rows, with no per-layer slice copies
+    (what it costs per step: PERF.md, `paged_attn_roofline_pct`).
 
     Mesh coverage: single device runs the kernel directly; a tp-only mesh
     runs it per-shard via shard_map over the kv-head axis (each shard

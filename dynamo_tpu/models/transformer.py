@@ -843,6 +843,11 @@ def forward_decode(
     b = tokens.shape[0]
     pos2 = positions[:, None]
     attn_fn = decode_attention_fn or paged_attention_decode_xla
+    # A slot that is not active (finished, or waiting for its prefill)
+    # keeps its last sequence's length and table; its row's result is
+    # thrown away, so the attention call sees it as an empty history and
+    # streams nothing for it.
+    attn_lens = jnp.where(active, kv_lens, 0)
     x = params["embed"][tokens][:, None, :]  # [B, 1, H]
     ks, vs = [], []
     for layer_idx, lp in enumerate(params["layers"]):
@@ -861,7 +866,7 @@ def forward_decode(
         q = rope(q, pos2, config.rope_theta)
         k = rope(k, pos2, config.rope_theta)
         attn = attn_fn(
-            q, kv_cache, layer_idx, block_tables, kv_lens, k, v)
+            q, kv_cache, layer_idx, block_tables, attn_lens, k, v)
         ks.append(k)
         vs.append(v)
         attn_out = _mm("btqd,qdh->bth", attn, lp["wo"])
@@ -960,6 +965,7 @@ def forward_spec(
     assert not config.is_mla
     b, t = tokens.shape
     attn_fn = spec_attention_fn or paged_attention_spec_xla
+    attn_lens = jnp.where(active, kv_lens, 0)  # as in forward_decode
     x = params["embed"][tokens]  # [B, T, H]
     ks, vs = [], []
     for layer_idx, lp in enumerate(params["layers"]):
@@ -978,7 +984,7 @@ def forward_spec(
         q = rope(q, positions, config.rope_theta)
         k = rope(k, positions, config.rope_theta)
         attn = attn_fn(
-            q, kv_cache, layer_idx, block_tables, kv_lens, k, v)
+            q, kv_cache, layer_idx, block_tables, attn_lens, k, v)
         ks.append(k)
         vs.append(v)
         attn_out = _mm("btqd,qdh->bth", attn, lp["wo"])

@@ -294,6 +294,84 @@ def paged_decode_attention_partial(
     return acc, m[..., 0], l[..., 0]
 
 
+# A DMA chunk holds at most this many tokens of one sequence: a 64-page
+# table of 16-token pages is two grid steps, a 128-page one four. The
+# flash update takes the chunk whole while its score tile [kh*g, tok*kh]
+# stays within _SCORE_ELEMS (mistral's 32 rows x 8 kv heads: 512 tokens),
+# and in equal blocks where the spec fold's rows would outgrow it. Large
+# blocks are what the chip rewards (my chip run, PR 27: 185 us a layer at
+# 128-token blocks, 147 us at 512), the scheduler overlapping one part's
+# matmuls with another's conversions.
+_CHUNK_TOKENS = 512
+_SCORE_ELEMS = 32 * 4096
+
+
+def _largest_divisor(n: int, cap: int) -> int:
+    """Largest divisor of `n` that is <= `cap` (>= 1)."""
+    d = max(1, min(n, cap))
+    while n % d:
+        d -= 1
+    return d
+
+
+def _div(x, d: int):
+    """x // d for a static positive d; a shift where d is a power of two
+    (every geometry that reaches Mosaic), so no vector division is
+    lowered."""
+    return x >> (d.bit_length() - 1) if d & (d - 1) == 0 else x // d
+
+
+def _mod(x, d: int):
+    return x & (d - 1) if d & (d - 1) == 0 else x % d
+
+
+def _next_chunk(lengths_ref, bi, ci, *, bk: int, n_chunks: int,
+                batch_size: int):
+    """The (row, chunk) whose DMA the grid step (bi, ci) starts: the next
+    chunk of this row while it lies inside the history AND inside the
+    grid, else chunk 0 of the next row with a history; row == batch_size
+    when nothing is left. Bounded by the grid as well as by the length, a
+    length past the table width in use (a stale slot) cannot start a copy
+    that no grid step awaits."""
+    def advance_b():
+        nb = jax.lax.fori_loop(
+            0, batch_size,
+            lambda _, cur: jnp.where(
+                jnp.logical_and(
+                    cur < batch_size,
+                    lengths_ref[jnp.clip(cur, 0, batch_size - 1)] == 0),
+                cur + 1, cur),
+            bi + 1)
+        return nb, jnp.int32(0)
+
+    more = jnp.logical_and(ci + 1 < n_chunks,
+                           (ci + 1) * bk < lengths_ref[bi])
+    return jax.lax.cond(more, lambda: (bi, ci + 1), advance_b)
+
+
+def _token_scale_row(sc, kh: int):
+    """[n_tok, lanes] lane-broadcast per-token scales (tokens on sublanes,
+    as the pool stores them) -> f32 [1, n_tok * kh] with entry t*kh + h =
+    scale[t]: the column order of a score tile over the flattened
+    [n_tok * kh, hd] chunk. One selector matmul instead of a transpose:
+    the tile is masked to its block diagonal (lane l keeps token l // kh
+    of each group of `per` tokens) and the groups are summed by a 0/1
+    matrix; one nonzero term per output, so the result is exact."""
+    n_tok, lanes = sc.shape
+    per = _largest_divisor(n_tok, max(1, lanes // kh))  # tokens a lane tile
+    width = per * kh
+    groups = n_tok // per
+    tok = jax.lax.broadcasted_iota(jnp.int32, (n_tok, width), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (n_tok, width), 1)
+    diag = jnp.where(_div(lane, kh) == _mod(tok, per), sc[:, :width], 0)
+    pick = (_div(jax.lax.broadcasted_iota(jnp.int32, (groups, n_tok), 1),
+                 per)
+            == jax.lax.broadcasted_iota(jnp.int32, (groups, n_tok), 0))
+    rows = jnp.dot(pick.astype(sc.dtype), diag,
+                   preferred_element_type=jnp.float32)  # [groups, width]
+    return jnp.concatenate([rows[j:j + 1] for j in range(groups)], axis=1)
+
+
 def _pool_decode_kernel(
     # scalar prefetch
     lengths_ref,  # [B] int32 HISTORY lengths (current token excluded)
@@ -303,114 +381,105 @@ def _pool_decode_kernel(
     init_ref,  # [1] int32 (1 until the first DMA was issued)
     # inputs + outputs + scratch, order depending on `quantized` —
     # unpacked below (Pallas passes refs positionally)
-    q_ref,  # [1, kh, g, hd] (block for this b)
+    q_ref,  # [1, kh*g, hd] (block for this b; rows head-major)
     pool_ref,  # FULL [L, 2, P, ps, kh, hd] in HBM (memory_space=ANY)
     *rest,
     pages_per_chunk: int,
+    block_pages: int,
     max_pages: int,
     batch_size: int,
     quantized: bool = False,
 ):
     """Flash decode over the paged HISTORY reading the WHOLE pool ref.
 
-    Why this shape (vs blocking pages through BlockSpec index maps):
-      * the pool stays in HBM and the kernel DMAs only owned pages — an
-        XLA-level `kv_cache[layer]` slice materializes a copy per layer
-        per step because custom calls can't fuse slicing (measured ~4ms of
-        pure copies per decode step);
-      * one DMA moves a page for ALL kv heads (the pool's page-major
-        layout), so there is no per-head grid dim re-reading pages;
-      * chunks of `pages_per_chunk` pages amortize per-iteration overhead
-        and double-buffer against compute (the technique of the public
-        jax paged_attention_kernel, adapted to page-major pools, layer
-        indexing, and unnormalized partials for deferred cache writes).
+    Why this shape:
+      * the pool stays in HBM and the kernel DMAs only owned pages of
+        rows that have a history: an XLA-level `kv_cache[layer]` slice
+        would materialize a copy per layer per step (a custom call cannot
+        fuse the slice), and a row whose length is 0 (the caller masks
+        every inactive slot to 0) costs a grid step that does nothing;
+      * one DMA moves a page's K and V for ALL kv heads (the pool's
+        page-major layout), into a double-buffered chunk of
+        `pages_per_chunk` pages; within a chunk only the blocks of
+        `block_pages` pages that hold history are copied, awaited and
+        computed, so a large chunk (few grid steps) streams no more than
+        a small one would;
+      * all heads in one MXU pass, no per-head gather: the block
+        [tok, kh, hd] is read as [tok*kh, hd] (free: a token's kh rows
+        are consecutive) and scored against all kh*g query rows at once,
+        S = Q K^T [kh*g, tok*kh]. Entries whose row and column belong to
+        different kv heads are masked to -inf together with the length
+        mask, so softmax and P V give each head exactly its own result.
+        The kh-fold extra MXU work is free here (the MXU idles on 4-row
+        matmuls); what it buys is 128-row weight tiles and no relayout;
+      * matmul operands in the query's dtype (bf16 on the chip), f32
+        accumulation; softmax statistics, accumulator and the
+        unnormalized (acc, m, l) partials in f32. The probabilities are
+        rounded once, to the operand dtype, for P V.
 
-    `quantized` (static) adds an int8 path: pages stream as int8 (HALF
-    the HBM traffic of bf16) plus per-token head-shared bf16 scale rows
-    ([ps, LANES], lane-broadcast so the per-page DMA slice is
-    tiling-aligned), dequantized elementwise in VMEM right before the
-    flash accumulation.
+    `quantized` (static): pages stream as int8 (half the bytes of bf16)
+    plus per-token head-shared bf16 scale rows ([ps, LANES],
+    lane-broadcast so a page's DMA slice is tiling-aligned). The codes
+    convert to the operand dtype exactly; the K scale multiplies the f32
+    scores and the V scale the f32 probabilities (`_token_scale_row`),
+    never the [tok*kh, hd] tiles.
     """
     if quantized:
         (scale_ref,  # FULL bf16 [L, 2, P, ps, LANES] in HBM (ANY)
          acc_ref, m_out_ref, l_out_ref,
-         k_buf, v_buf,  # [2, C, ps, kh, hd] double-buffered page chunks
-         ks_buf, vs_buf,  # [2, C, ps, LANES] lane-broadcast scales
-         k_sems, v_sems, m_ref, l_ref, o_ref) = rest
+         kv_buf,  # [2, 2, C, ps, kh, hd] (slot, K|V) page chunks
+         sc_buf,  # [2, 2, C, ps, LANES] lane-broadcast scales
+         sems, m_ref, l_ref, o_ref) = rest
     else:
-        scale_ref = ks_buf = vs_buf = None
-        (acc_ref,  # [1, kh, g, hd] f32 unnormalized accumulator
-         m_out_ref,  # [1, kh, g, 128] f32
-         l_out_ref,  # [1, kh, g, 128] f32
-         k_buf, v_buf,  # [2, C, ps, kh, hd] double-buffered page chunks
-         k_sems, v_sems,  # DMA semaphores (2,)
-         m_ref, l_ref,  # [kh, g, 128] f32
-         o_ref) = rest  # [kh, g, hd] f32
+        scale_ref = sc_buf = None
+        (acc_ref,  # [1, kh*g, hd] f32 unnormalized accumulator
+         m_out_ref,  # [1, kh*g, 128] f32
+         l_out_ref,  # [1, kh*g, 128] f32
+         kv_buf,  # [2, 2, C, ps, kh, hd]
+         sems,  # DMA semaphores (2,): one per slot
+         m_ref, l_ref,  # [kh*g, 128] f32
+         o_ref) = rest  # [kh*g, hd] f32
     b = pl.program_id(0)
     i = pl.program_id(1)
     n_chunks = pl.num_programs(1)
-    ps = k_buf.shape[2]
+    ps, kh, hd = kv_buf.shape[3:]
+    rows = q_ref.shape[1]
+    g = rows // kh
     bk = pages_per_chunk * ps
+    block_tok = block_pages * ps
+    n_blocks = pages_per_chunk // block_pages
     layer = layer_ref[0]
     length = lengths_ref[b]
 
-    def start_copy(bi, ci, slot):
-        # Chunk ci of sequence bi into buffer `slot`; one async copy per
-        # page, covering every kv head of that page.
+    def chunk_copies(bi, ci, slot, fn):
+        # Chunk ci of row bi <-> buffer `slot`: one copy per page for K
+        # and V together (and one for both scale rows), block by block;
+        # a block past the row's history is neither started nor awaited.
+        # Start and wait rebuild the same descriptors under the same
+        # predicates (the wait consumes the slot semaphore's byte count).
         base = bi * max_pages + ci * pages_per_chunk
-        copies = []
-        for j in range(pages_per_chunk):
-            page = tables_ref[base + j]
-            copies.append(pltpu.make_async_copy(
-                pool_ref.at[layer, 0, page], k_buf.at[slot, j],
-                k_sems.at[slot]))
-            copies.append(pltpu.make_async_copy(
-                pool_ref.at[layer, 1, page], v_buf.at[slot, j],
-                v_sems.at[slot]))
-            if quantized:
-                copies.append(pltpu.make_async_copy(
-                    scale_ref.at[layer, 0, page], ks_buf.at[slot, j],
-                    k_sems.at[slot]))
-                copies.append(pltpu.make_async_copy(
-                    scale_ref.at[layer, 1, page], vs_buf.at[slot, j],
-                    v_sems.at[slot]))
-        for c in copies:
-            c.start()
+        left = lengths_ref[bi] - ci * bk
+
+        def block(u):
+            for j in range(u * block_pages, (u + 1) * block_pages):
+                page = tables_ref[base + j]
+                fn(pltpu.make_async_copy(
+                    pool_ref.at[layer, :, page], kv_buf.at[slot, :, j],
+                    sems.at[slot]))
+                if quantized:
+                    fn(pltpu.make_async_copy(
+                        scale_ref.at[layer, :, page], sc_buf.at[slot, :, j],
+                        sems.at[slot]))
+
+        block(0)  # the chunk is only touched when its first block is live
+        for u in range(1, n_blocks):
+            pl.when(u * block_tok < left)(functools.partial(block, u))
+
+    def start_copy(bi, ci, slot):
+        chunk_copies(bi, ci, slot, lambda c: c.start())
 
     def wait_copy(bi, ci, slot):
-        # Recreate the same descriptors and wait (the public kernel's
-        # pattern: wait consumes the per-slot semaphore byte count).
-        base = bi * max_pages + ci * pages_per_chunk
-        for j in range(pages_per_chunk):
-            page = tables_ref[base + j]
-            pltpu.make_async_copy(pool_ref.at[layer, 0, page],
-                                  k_buf.at[slot, j], k_sems.at[slot]).wait()
-            pltpu.make_async_copy(pool_ref.at[layer, 1, page],
-                                  v_buf.at[slot, j], v_sems.at[slot]).wait()
-            if quantized:
-                pltpu.make_async_copy(scale_ref.at[layer, 0, page],
-                                      ks_buf.at[slot, j],
-                                      k_sems.at[slot]).wait()
-                pltpu.make_async_copy(scale_ref.at[layer, 1, page],
-                                      vs_buf.at[slot, j],
-                                      v_sems.at[slot]).wait()
-
-    def next_active(bi, ci):
-        """First active (b, chunk) after (bi, ci) — sequences with zero
-        history are skipped entirely."""
-        def advance_b():
-            nb = jax.lax.fori_loop(
-                0, batch_size,
-                lambda _, cur: jnp.where(
-                    jnp.logical_and(
-                        cur < batch_size,
-                        lengths_ref[jnp.clip(cur, 0, batch_size - 1)] == 0),
-                    cur + 1, cur),
-                bi + 1)
-            return nb, jnp.int32(0)
-
-        return jax.lax.cond((ci + 1) * bk < length,
-                            lambda: (bi, ci + 1), advance_b)
+        chunk_copies(bi, ci, slot, lambda c: c.wait())
 
     active = i * bk < length
 
@@ -428,7 +497,8 @@ def _pool_decode_kernel(
     @pl.when(active)
     def _compute():
         slot = buf_idx_ref[0]
-        nb, ni = next_active(b, i)
+        nb, ni = _next_chunk(lengths_ref, b, i, bk=bk, n_chunks=n_chunks,
+                             batch_size=batch_size)
 
         @pl.when(nb < batch_size)
         def _prefetch():
@@ -437,47 +507,60 @@ def _pool_decode_kernel(
             buf_idx_ref[0] = nslot
 
         wait_copy(b, i, slot)
-        q = q_ref[0].astype(jnp.float32)  # [kh, g, hd]
-        kh = k_buf.shape[3]
-        k = k_buf[slot].astype(jnp.float32).reshape(bk, kh, -1)
-        v = v_buf[slot].astype(jnp.float32).reshape(bk, kh, -1)
-        hd_ = k.shape[-1]
-        if quantized:
-            # [C, ps, LANES] -> [bk, LANES]: lane-broadcast per-token
-            # scalars; sliced to hd (identity on the TPU-eligible
-            # hd == LANES geometry — the dispatcher gates on it; narrower
-            # hd only occurs in interpret mode).
-            ks = ks_buf[slot].astype(jnp.float32).reshape(bk, -1)[:, :hd_]
-            vs = vs_buf[slot].astype(jnp.float32).reshape(bk, -1)[:, :hd_]
-        scale = 1.0 / math.sqrt(q.shape[-1])
-        pos = i * bk + jax.lax.broadcasted_iota(
-            jnp.int32, (q.shape[1], bk), 1)  # [g, t]
-        # Static per-head loop: Mosaic's matmul wants matching batch-dim
-        # layouts, so run kh small GQA matmuls instead of one batched one.
-        for h in range(kh):
-            qh_ = q[h]  # [g, hd]
-            kh_ = k[:, h, :]  # [t, hd]
-            vh_ = v[:, h, :]
+        q = q_ref[0]  # [kh*g, hd], the matmul operand dtype
+        cols = block_tok * kh
+        sm_scale = 1.0 / math.sqrt(hd)
+        # Column c of a score tile is (token c // kh, kv head c % kh);
+        # row r belongs to kv head r // g. Static but for the length.
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        row_head = sum((row >= h * g).astype(jnp.int32)
+                       for h in range(1, kh))  # r // g without a division
+        same_head = _mod(col, kh) == row_head  # [kh*g, cols]
+        col_tok = _div(col, kh)  # [1, cols]
+
+        def flat(x):
+            # [pages, ps, kh, hd] -> [tok*kh, hd] in the operand dtype: a
+            # token's kh rows are consecutive, so merging the leading
+            # dims moves nothing; int8 codes convert exactly.
+            return x.reshape(cols, hd).astype(q.dtype)
+
+        def flash_block(u):
+            pages = pl.ds(u * block_pages, block_pages)
+            k = flat(kv_buf[slot, 0, pages])
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [kh*g, cols]
             if quantized:
-                kh_ = kh_ * ks  # elementwise dequant
-                vh_ = vh_ * vs
-            scores = jax.lax.dot_general(
-                qh_, kh_, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [g, t]
-            scores = jnp.where(pos < length, scores, -jnp.inf)
-            m_prev = m_ref[h, :, 0:1]  # [g, 1]
-            l_prev = l_ref[h, :, 0:1]
-            m_cur = jnp.max(scores, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            probs = jnp.exp(scores - m_new)
+                ks = _token_scale_row(
+                    sc_buf[slot, 0, pages].reshape(block_tok, -1), kh)
+                s = s * (ks * sm_scale)
+            else:
+                s = s * sm_scale
+            live = col_tok < length - (i * bk + u * block_tok)
+            s = jnp.where(jnp.logical_and(same_head, live), s, -jnp.inf)
+            m_prev = m_ref[:, 0:1]  # [kh*g, 1]
+            l_prev = l_ref[:, 0:1]
+            # finite: the block's first token is live for every head
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            if quantized:
+                p = p * _token_scale_row(
+                    sc_buf[slot, 1, pages].reshape(block_tok, -1), kh)
             pv = jax.lax.dot_general(
-                probs, vh_, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [g, hd]
-            o_ref[h] = o_ref[h] * alpha + pv
-            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+                p.astype(q.dtype), flat(kv_buf[slot, 1, pages]),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [kh*g, hd]
+            o_ref[...] = o_ref[...] * alpha + pv
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+        flash_block(0)
+        for u in range(1, n_blocks):
+            pl.when(i * bk + u * block_tok < length)(
+                functools.partial(flash_block, u))
 
     @pl.when(i == n_chunks - 1)
     def _finish():
@@ -500,72 +583,69 @@ def paged_decode_attention_pool(
     kv_lens_hist: jax.Array,  # [B] int32 history length (current excluded)
     kv_scales=None,  # bf16 [L, 2, P, ps, LANES] for an int8 pool
     *,
-    pages_per_chunk: int = 8,
+    pages_per_chunk: int | None = None,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Chunked-DMA flash partials over the paged history; see
-    _pool_decode_kernel for why this reads the full pool. Returns
-    (acc, m, l) unnormalized for the deferred current-token combine.
-    With `kv_scales`, the pool is int8 and the kernel dequantizes in
-    VMEM (the q8 path)."""
+    _pool_decode_kernel for what it streams and how it scores. Returns
+    (acc, m, l) unnormalized for the deferred current-token combine. With
+    `kv_scales` the pool is int8 (the q8 path). A row with history 0 is
+    skipped: callers pass 0 for every slot that is not active.
+
+    `pages_per_chunk` is a cap on the DMA chunk; left None it follows
+    from the static table width (`_CHUNK_TOKENS`), so a sequence is a
+    few grid steps whatever width the scheduler bucketed it to."""
     quantized = kv_scales is not None
     b, qh, hd = q.shape
     ps, kh = kv_pool.shape[3], kv_pool.shape[4]
-    group = qh // kh
     max_pages = block_tables.shape[1]
-    ppc = min(pages_per_chunk, max_pages)
-    while max_pages % ppc:
-        ppc -= 1
+    ppc = _largest_divisor(
+        max_pages, pages_per_chunk or max(1, _CHUNK_TOKENS // ps))
+    block_pages = _largest_divisor(
+        ppc, max(1, _SCORE_ELEMS // (qh * kh * ps)))
     n_chunks = max_pages // ppc
-    qg = q.reshape(b, kh, group, hd)
 
     def q_map(bi, ci, *refs):
         del ci, refs
-        return (bi, 0, 0, 0)
+        return (bi, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, kh, group, hd), q_map),
+        pl.BlockSpec((1, qh, hd), q_map),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
-    scratch = [
-        pltpu.VMEM((2, ppc, ps, kh, hd), kv_pool.dtype),
-        pltpu.VMEM((2, ppc, ps, kh, hd), kv_pool.dtype),
-    ]
-    operands = [qg, kv_pool]
+    scratch = [pltpu.VMEM((2, 2, ppc, ps, kh, hd), kv_pool.dtype)]
+    operands = [q, kv_pool]
     if quantized:
         in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        scratch += [
-            pltpu.VMEM((2, ppc, ps, kv_scales.shape[-1]), kv_scales.dtype),
-            pltpu.VMEM((2, ppc, ps, kv_scales.shape[-1]), kv_scales.dtype),
-        ]
+        scratch.append(pltpu.VMEM((2, 2, ppc, ps, kv_scales.shape[-1]),
+                                  kv_scales.dtype))
         operands.append(kv_scales)
     scratch += [
         pltpu.SemaphoreType.DMA((2,)),
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.VMEM((kh, group, 128), jnp.float32),
-        pltpu.VMEM((kh, group, 128), jnp.float32),
-        pltpu.VMEM((kh, group, hd), jnp.float32),
+        pltpu.VMEM((qh, 128), jnp.float32),
+        pltpu.VMEM((qh, 128), jnp.float32),
+        pltpu.VMEM((qh, hd), jnp.float32),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(b, n_chunks),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, kh, group, hd), q_map),
-            pl.BlockSpec((1, kh, group, 128), q_map),
-            pl.BlockSpec((1, kh, group, 128), q_map),
+            pl.BlockSpec((1, qh, hd), q_map),
+            pl.BlockSpec((1, qh, 128), q_map),
+            pl.BlockSpec((1, qh, 128), q_map),
         ],
         scratch_shapes=scratch,
     )
     acc, m, l = pl.pallas_call(
         functools.partial(_pool_decode_kernel, pages_per_chunk=ppc,
-                          max_pages=max_pages, batch_size=b,
-                          quantized=quantized),
+                          block_pages=block_pages, max_pages=max_pages,
+                          batch_size=b, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, kh, group, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, group, 128), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, group, 128), jnp.float32),
+            jax.ShapeDtypeStruct((b, qh, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, qh, 128), jnp.float32),
+            jax.ShapeDtypeStruct((b, qh, 128), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
@@ -577,7 +657,9 @@ def paged_decode_attention_pool(
       jnp.zeros((1,), jnp.int32),  # double-buffer slot
       jnp.ones((1,), jnp.int32),  # init flag
       *operands)
-    return acc, m[..., 0], l[..., 0]
+    group = qh // kh
+    return (acc.reshape(b, kh, group, hd),
+            m[..., 0].reshape(b, kh, group), l[..., 0].reshape(b, kh, group))
 
 
 def paged_attention_decode_fused(
@@ -601,6 +683,14 @@ def paged_attention_decode_fused(
         block_tables, kv_lens - 1, interpret=interpret,
     )  # acc [B, kh, g, hd] f32; m, l [B, kh, g]
     return _combine_current(q, acc, m, l, k_cur, v_cur)
+
+
+def _q8_needs_xla(values, scales, interpret: bool) -> bool:
+    """An int8 pool whose head_dim is not the scale lane width (128): the
+    one int8 geometry Mosaic has compiled and the chip has checked is
+    head_dim == 128; others take the XLA dequant path."""
+    return (scales is not None and not interpret
+            and values.shape[5] != scales.shape[-1])
 
 
 def _combine_current(q, acc, m, l, k_cur, v_cur):
@@ -631,41 +721,37 @@ def paged_attention_decode_pool(
     k_cur: jax.Array,  # [B, 1, kh, hd]
     v_cur: jax.Array,
     *,
-    pages_per_chunk: int = 8,
+    pages_per_chunk: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Deferred-write decode attention via the whole-pool chunked-DMA
     kernel — the production TPU path: no per-layer pool slices (no copies),
-    one DMA per page covering all kv heads, double-buffered against the
-    flash compute. Drop-in for `transformer.paged_attention_decode_xla`.
-    An int8 (values, scales) cache takes the q8 kernel: half the page DMA
-    bytes, dequantization fused into the VMEM flash loop."""
-    if isinstance(kv_cache, tuple):
-        values, scales = kv_cache
-        hd_ = values.shape[5]
-        if hd_ != scales.shape[-1] and not interpret:
-            # The elementwise dequant needs head_dim == the scale lane
-            # width (128); other geometries take the XLA dequant path.
-            from ..models.transformer import paged_attention_decode_xla
+    one DMA per page for K and V of all kv heads, double-buffered against
+    the flash compute, all heads scored in one bf16 MXU pass over the
+    flattened chunk (`_pool_decode_kernel`). Drop-in for
+    `transformer.paged_attention_decode_xla`. A row whose `kv_lens` is 0
+    or 1 has no history and is skipped: `forward_decode` passes 0 for
+    every slot that is not active. `pages_per_chunk` caps the DMA chunk;
+    None sizes it from the table width. An int8 (values, scales) cache
+    takes the q8 path: half the page bytes, the per-token scales applied
+    to the scores and the probabilities."""
+    values, scales = (kv_cache if isinstance(kv_cache, tuple)
+                      else (kv_cache, None))
+    if _q8_needs_xla(values, scales, interpret):
+        from ..models.transformer import paged_attention_decode_xla
 
-            return paged_attention_decode_xla(q, kv_cache, layer,
-                                              block_tables, kv_lens,
-                                              k_cur, v_cur)
-        acc, m, l = paged_decode_attention_pool(
-            q[:, 0], values, layer, block_tables,
-            jnp.maximum(kv_lens - 1, 0), kv_scales=scales,
-            pages_per_chunk=pages_per_chunk, interpret=interpret,
-        )
-        return _combine_current(q, acc, m, l, k_cur, v_cur)
+        return paged_attention_decode_xla(q, kv_cache, layer, block_tables,
+                                          kv_lens, k_cur, v_cur)
     acc, m, l = paged_decode_attention_pool(
-        q[:, 0], kv_cache, layer, block_tables,
-        jnp.maximum(kv_lens - 1, 0),
+        q[:, 0], values, layer, block_tables,
+        jnp.maximum(kv_lens - 1, 0), kv_scales=scales,
         pages_per_chunk=pages_per_chunk, interpret=interpret,
     )
     return _combine_current(q, acc, m, l, k_cur, v_cur)
 
 
-def make_paged_attention_decode_pool_tp(mesh, *, pages_per_chunk: int = 8,
+def make_paged_attention_decode_pool_tp(mesh, *,
+                                        pages_per_chunk: int | None = None,
                                         interpret: bool = False):
     """Whole-pool decode kernel under tensor parallelism: shard_map over
     the kv-head axis, so each tp shard streams ONLY its local slice of the
@@ -806,38 +892,31 @@ def paged_attention_spec_pool(
     k_cur: jax.Array,  # [B, T, kh, hd]
     v_cur: jax.Array,
     *,
-    pages_per_chunk: int = 8,
+    pages_per_chunk: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Speculative verification via the whole-pool chunked-DMA kernel —
     the production TPU path: one dispatch streams each owned page ONCE
     for all T candidate positions (the entire point of speculation on a
     memory-bound decode: k extra scores ride along for free). int8
-    (values, scales) pools take the q8 variant with in-VMEM dequant,
-    same as single-token decode. Drop-in for
+    (values, scales) pools take the q8 path, same as single-token
+    decode. Drop-in for
     `transformer.paged_attention_spec_xla` in `forward_spec`."""
     t = q.shape[1]
     kh = k_cur.shape[2]
     qf = _fold_chunk(q, kh)
-    if isinstance(kv_cache, tuple):
-        values, scales = kv_cache
-        if values.shape[5] != scales.shape[-1] and not interpret:
-            from ..models.transformer import paged_attention_spec_xla
+    values, scales = (kv_cache if isinstance(kv_cache, tuple)
+                      else (kv_cache, None))
+    if _q8_needs_xla(values, scales, interpret):
+        from ..models.transformer import paged_attention_spec_xla
 
-            return paged_attention_spec_xla(q, kv_cache, layer,
-                                            block_tables, kv_lens,
-                                            k_cur, v_cur)
-        acc, m, l = paged_decode_attention_pool(
-            qf, values, layer, block_tables,
-            jnp.maximum(kv_lens - 1, 0), kv_scales=scales,
-            pages_per_chunk=pages_per_chunk, interpret=interpret,
-        )
-    else:
-        acc, m, l = paged_decode_attention_pool(
-            qf, kv_cache, layer, block_tables,
-            jnp.maximum(kv_lens - 1, 0),
-            pages_per_chunk=pages_per_chunk, interpret=interpret,
-        )
+        return paged_attention_spec_xla(q, kv_cache, layer, block_tables,
+                                        kv_lens, k_cur, v_cur)
+    acc, m, l = paged_decode_attention_pool(
+        qf, values, layer, block_tables,
+        jnp.maximum(kv_lens - 1, 0), kv_scales=scales,
+        pages_per_chunk=pages_per_chunk, interpret=interpret,
+    )
     acc, m, l = _unfold_chunk(acc, m, l, t)
     return _combine_chunk(q, acc, m, l, k_cur, v_cur)
 

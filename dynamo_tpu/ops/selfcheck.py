@@ -110,13 +110,14 @@ def _attention_cases(qh, kh, hd, interpret, tag=""):
                 lambda: paged_attention_decode_xla(*args))
 
     cases = []
-    # Table widths 8 and 16 are the scheduler's first two power-of-two
-    # buckets: one DMA chunk, then two (the double-buffer hand-off).
+    # Table widths 8 and 64 are two of the scheduler's power-of-two
+    # buckets: one DMA chunk of the kernel, then two (the double-buffer
+    # hand-off inside a row as well as between rows).
     # An int8 pool needs its kv heads in whole (4,128) tiles; the runner
     # refuses the geometry (ModelRunner: int8 KV under tp), so a shard
     # that thin is not reachable.
     kinds = (False, True) if kh % 4 == 0 or interpret else (False,)
-    for width in (8, 16):
+    for width in (8, 64):
         for quantized in kinds:
             kind = "q8" if quantized else "bf16"
             cases.append((f"decode_pool{tag}/{kind}/w{width}",

@@ -106,6 +106,13 @@ class TestForwardWithInt8Cache:
 
 
 class TestPoolKernelQ8:
+    """float32 queries against an int8 pool: the kernel's operands hold
+    the codes and the bf16 scales exactly, Q K^T is summed over codes and
+    scaled once per score, so it differs from the XLA dequant oracle by
+    float32 rounding alone."""
+
+    TOL = 3e-6
+
     def _case(self, b=4, qh=8, kh=4, hd=64, ps=8, n_pages=32, max_pages=6,
               seed=5):
         rng = np.random.default_rng(seed)
@@ -136,7 +143,7 @@ class TestPoolKernelQ8:
             want = paged_attention_decode_xla(q, kv_q8, layer, bt, kl,
                                               kc, vc)
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       rtol=2e-5, atol=2e-5)
+                                       rtol=self.TOL, atol=self.TOL)
 
     def test_q8_kernel_tp2_matches_oracle(self):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -156,7 +163,7 @@ class TestPoolKernelQ8:
         got = fn(q, (qv, qs), 1, bt, kl, kc, vc)
         want = paged_attention_decode_xla(q, (qv, qs), 1, bt, kl, kc, vc)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
+                                   rtol=self.TOL, atol=self.TOL)
 
 
 class TestRunnerInt8:

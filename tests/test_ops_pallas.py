@@ -278,6 +278,11 @@ class TestPagedAttentionDecodeFused:
             rtol=1e-5, atol=1e-5)
 
 
+# forward_decode through the pool kernel against the XLA path: exact for
+# a float32 model; a bf16 model's kernel rounds q's dtype into P V.
+_MODEL_DTYPES = [("float32", 2e-4), ("bfloat16", 6e-2)]
+
+
 class TestPagedAttentionDecodePool:
     """The production TPU decode path: whole-pool chunked-DMA kernel
     (paged_decode_attention_pool + combine) vs paged_attention_decode_xla
@@ -351,9 +356,13 @@ class TestPagedAttentionDecodePool:
                                    np.asarray(want, np.float32),
                                    rtol=5e-2, atol=5e-2)
 
-    def test_forward_decode_with_pool_kernel_matches_xla(self):
+    @pytest.mark.parametrize("dtype,tol", _MODEL_DTYPES)
+    def test_forward_decode_with_pool_kernel_matches_xla(self, dtype, tol):
         """Whole forward_decode equality on a real model config — the
-        integration the runner wires on TPU."""
+        integration the runner wires on TPU. A float32 model is exact;
+        a bf16 model rounds its probabilities to bf16 for P V (the
+        kernel's matmul operands are in the model's dtype)."""
+        import dataclasses
         import functools
 
         from dynamo_tpu.models import get_config, init_params, make_kv_cache
@@ -362,7 +371,7 @@ class TestPagedAttentionDecodePool:
             paged_attention_decode_pool,
         )
 
-        cfg = get_config("tiny-test")
+        cfg = dataclasses.replace(get_config("tiny-test"), dtype=dtype)
         params = init_params(jax.random.PRNGKey(0), cfg)
         rng = np.random.default_rng(0)
         kv = make_kv_cache(cfg, 32, 4)
@@ -381,10 +390,198 @@ class TestPagedAttentionDecodePool:
                 interpret=True))
         np.testing.assert_allclose(np.asarray(logits_p),
                                    np.asarray(logits_x),
-                                   rtol=2e-4, atol=2e-4)
+                                   rtol=tol, atol=tol)
+        kv_tol = 1e-5 if dtype == "float32" else tol  # later layers' K/V
         np.testing.assert_allclose(
             np.asarray(kv_p, np.float32), np.asarray(kv_x, np.float32),
-            rtol=1e-5, atol=1e-5)
+            rtol=kv_tol, atol=kv_tol)
+
+
+def _pool_case(rng, *, b, kh, g, hd, ps, max_pages, kind, t=1):
+    """A paged pool with distinct pages per row. `kind`: "int8" is the q8
+    pool with float32 queries (codes and scales exact in the kernel's
+    float32 operands, so the comparison is tight), "bf16" a bf16 pool
+    with bf16 queries (the chip's operand dtype)."""
+    from dynamo_tpu.models.transformer import quantize_kv
+
+    n_pages = 1 + b * max_pages
+    raw = rng.normal(size=(2, 2, n_pages, ps, kh, hd))
+    qdt = jnp.float32 if kind == "int8" else jnp.bfloat16
+    kv = (quantize_kv(jnp.asarray(raw, jnp.float32)) if kind == "int8"
+          else jnp.asarray(raw, jnp.bfloat16))
+    q = jnp.asarray(rng.normal(size=(b, t, kh * g, hd)), qdt)
+    kc = jnp.asarray(rng.normal(size=(b, t, kh, hd)), qdt)
+    vc = jnp.asarray(rng.normal(size=(b, t, kh, hd)), qdt)
+    bt = jnp.asarray(
+        1 + rng.permutation(n_pages - 1).reshape(b, max_pages), jnp.int32)
+    return q, kv, bt, kc, vc
+
+
+# int8: float32 operands, a reordered sum only; bf16: P rounded to bf16.
+_POOL_TOL = {"int8": 5e-6, "bf16": 3e-2}
+
+
+class TestPagedAttentionDecodePoolGrid:
+    """The flattened-heads kernel against the XLA oracle over what it
+    adapts to: kv heads a shard or a model can have, the GQA group (20 =
+    group 4 x 5 speculative positions, the fold of
+    paged_attention_spec_pool), both pool kinds, and the chunk the table
+    width gives (None: the 256-token table as one chunk, cut in blocks
+    where kh*g rows would outgrow the score tile) or a cap (two chunks,
+    four chunks)."""
+
+    @pytest.mark.parametrize("ppc", [None, 16, 8])
+    @pytest.mark.parametrize("kind", ["int8", "bf16"])
+    @pytest.mark.parametrize("g", [1, 4, 20])
+    @pytest.mark.parametrize("kh", [2, 4, 8])
+    def test_matches_xla(self, kh, g, kind, ppc):
+        from dynamo_tpu.models.transformer import paged_attention_decode_xla
+        from dynamo_tpu.ops.paged_attention import (
+            paged_attention_decode_pool,
+        )
+
+        ps, max_pages = 8, 32
+        q, kv, bt, kc, vc = _pool_case(
+            np.random.default_rng(kh * 100 + g), b=4, kh=kh, g=g, hd=32,
+            ps=ps, max_pages=max_pages, kind=kind)
+        # empty, inside the first block, across the block boundary of a
+        # chunk (129 history tokens), a full table
+        kl = jnp.asarray([1, 40, 130, ps * max_pages], jnp.int32)
+        got = paged_attention_decode_pool(
+            q, kv, 1, bt, kl, kc, vc, pages_per_chunk=ppc, interpret=True)
+        want = paged_attention_decode_xla(q, kv, 1, bt, kl, kc, vc)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=_POOL_TOL[kind], atol=_POOL_TOL[kind])
+
+    @pytest.mark.parametrize("kind", ["int8", "bf16"])
+    def test_spec_fold_matches_xla(self, kind):
+        """T = 5 chunk queries folded into the group dim (g = 20 at
+        group 4) through the same kernel body."""
+        from dynamo_tpu.models.transformer import paged_attention_spec_xla
+        from dynamo_tpu.ops.paged_attention import paged_attention_spec_pool
+
+        q, kv, bt, kc, vc = _pool_case(
+            np.random.default_rng(9), b=3, kh=4, g=4, hd=32, ps=8,
+            max_pages=32, kind=kind, t=5)
+        kl = jnp.asarray([1, 77, 200], jnp.int32)
+        got = paged_attention_spec_pool(q, kv, 0, bt, kl, kc, vc,
+                                        interpret=True)
+        want = paged_attention_spec_xla(q, kv, 0, bt, kl, kc, vc)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=_POOL_TOL[kind], atol=_POOL_TOL[kind])
+
+
+class TestPoolKernelStaleSlots:
+    """Fault 1 (PERF.md section 6, PR 25): a free slot keeps its last
+    sequence's length and table. Unmasked, the kernel streamed its
+    history every layer of every step, and a stale length past the table
+    width in use made it start a DMA that no grid step awaits, which
+    halts the device. The interpreter runs a DMA where it is started and
+    has no semaphores to leave nonzero, so it cannot show the halt; what
+    these tests pin is the two causes."""
+
+    @pytest.mark.parametrize("entry", ["decode", "spec"])
+    def test_inactive_rows_reach_attention_as_empty_history(self, entry):
+        from dynamo_tpu.models import get_config, init_params, make_kv_cache
+        from dynamo_tpu.models.transformer import (
+            forward_decode,
+            forward_spec,
+            paged_attention_decode_xla,
+            paged_attention_spec_xla,
+        )
+
+        cfg = get_config("tiny-test")
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        kv = make_kv_cache(cfg, 32, 4)
+        bt = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8], [9, 10, 11, 12]],
+                         jnp.int32)
+        # row 1 is a free slot: its length is stale, and past its table
+        kv_lens = jnp.asarray([7, 4000, 11], jnp.int32)
+        active = jnp.asarray([True, False, True])
+        seen = []
+
+        def recording(oracle):
+            def fn(q, cache, layer, tables, lens, k, v):
+                seen.append(np.asarray(lens))
+                return oracle(q, cache, layer, tables, lens, k, v)
+            return fn
+
+        if entry == "decode":
+            forward_decode(
+                params, cfg, jnp.asarray([3, 5, 7], jnp.int32), kv_lens - 1,
+                kv, bt, kv_lens, active,
+                decode_attention_fn=recording(paged_attention_decode_xla))
+        else:
+            positions = (kv_lens - 1)[:, None] + jnp.arange(2)[None, :]
+            forward_spec(
+                params, cfg, jnp.asarray([[3, 4], [5, 6], [7, 8]], jnp.int32),
+                positions, kv, bt, kv_lens, active,
+                spec_attention_fn=recording(paged_attention_spec_xla))
+        assert len(seen) == cfg.n_layers
+        for lens in seen:
+            history = np.maximum(lens - 1, 0)
+            assert history[1] == 0  # the free slot streams nothing
+            np.testing.assert_array_equal(lens[[0, 2]], [7, 11])
+
+    @pytest.mark.parametrize("lengths,bk,n_chunks", [
+        ([900, 0, 300, 2000], 128, 8),  # the last row's length is stale
+        ([5000, 0, 0, 0], 256, 4),  # ... and nothing follows it
+        ([100, 5000, 0, 700], 512, 2),
+        ([0, 0, 0, 0], 128, 8),
+        ([1024, 1024, 1024, 1024], 512, 2),
+    ])
+    def test_every_started_chunk_is_awaited_inside_the_grid(
+            self, lengths, bk, n_chunks):
+        """Walk the grid as the kernel does: a grid step (b, i) is active
+        when chunk i holds history, waits for its own chunk and starts
+        the copy `_next_chunk` names. The copies started must be exactly
+        the chunks the active steps wait for, in order, and none may lie
+        past the grid, whatever the lengths say (2000 and 5000 here are
+        past the table width, n_chunks * bk)."""
+        from dynamo_tpu.ops.paged_attention import _next_chunk
+
+        lens = jnp.asarray(lengths, jnp.int32)
+        batch = len(lengths)
+        started, awaited = [], []
+        for b in range(batch):
+            for i in range(n_chunks):
+                if i * bk >= lengths[b]:
+                    continue
+                if not started:
+                    started.append((b, i))  # the kernel's `_first`
+                awaited.append((b, i))
+                nb, ni = _next_chunk(lens, jnp.int32(b), jnp.int32(i),
+                                     bk=bk, n_chunks=n_chunks,
+                                     batch_size=batch)
+                if int(nb) < batch:
+                    assert 0 <= int(ni) < n_chunks
+                    started.append((int(nb), int(ni)))
+        assert started == awaited
+
+    @pytest.mark.parametrize("kind", ["int8", "bf16"])
+    def test_length_past_the_table_width(self, kind):
+        """A row whose length exceeds its table (a stale slot the caller
+        did not mask) attends its whole table and no more; the rows
+        around it, a zero-history row after it included, match the
+        oracle."""
+        from dynamo_tpu.models.transformer import paged_attention_decode_xla
+        from dynamo_tpu.ops.paged_attention import (
+            paged_attention_decode_pool,
+        )
+
+        ps, max_pages = 8, 16
+        q, kv, bt, kc, vc = _pool_case(
+            np.random.default_rng(3), b=4, kh=4, g=2, hd=32, ps=ps,
+            max_pages=max_pages, kind=kind)
+        kl = jnp.asarray([50, 4000, 1, 4000], jnp.int32)
+        got = paged_attention_decode_pool(q, kv, 0, bt, kl, kc, vc,
+                                          pages_per_chunk=4, interpret=True)
+        want = paged_attention_decode_xla(q, kv, 0, bt, kl, kc, vc)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=_POOL_TOL[kind], atol=_POOL_TOL[kind])
 
 
 class TestPagedAttentionDecodePoolTp:
@@ -430,9 +627,12 @@ class TestPagedAttentionDecodePoolTp:
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        rtol=2e-5, atol=2e-5)
 
-    def test_forward_decode_tp2_matches_xla(self):
+    @pytest.mark.parametrize("dtype,tol", _MODEL_DTYPES)
+    def test_forward_decode_tp2_matches_xla(self, dtype, tol):
         """Whole forward_decode under a tp=2 mesh with the sharded kernel —
         the exact integration the runner wires on multi-chip TPU."""
+        import dataclasses
+
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from dynamo_tpu.models import get_config, init_params
@@ -444,7 +644,7 @@ class TestPagedAttentionDecodePoolTp:
         from dynamo_tpu.models import param_axes
 
         mesh = self._mesh(2)
-        cfg = get_config("tiny-test")
+        cfg = dataclasses.replace(get_config("tiny-test"), dtype=dtype)
         params = init_params(jax.random.PRNGKey(0), cfg)
         params = jax.tree.map(jax.device_put, params,
                               param_shardings(mesh, param_axes(cfg)))
@@ -466,10 +666,11 @@ class TestPagedAttentionDecodePoolTp:
                 mesh, pages_per_chunk=2, interpret=True))
         np.testing.assert_allclose(np.asarray(logits_p),
                                    np.asarray(logits_x),
-                                   rtol=2e-4, atol=2e-4)
+                                   rtol=tol, atol=tol)
+        kv_tol = 1e-5 if dtype == "float32" else tol  # later layers' K/V
         np.testing.assert_allclose(
             np.asarray(kv_p, np.float32), np.asarray(kv_x, np.float32),
-            rtol=1e-5, atol=1e-5)
+            rtol=kv_tol, atol=kv_tol)
 
     def test_runner_selects_tp_kernel(self, monkeypatch):
         """The gate: DYNT_ATTENTION=pallas on a tp-only mesh selects the

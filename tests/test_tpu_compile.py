@@ -1,0 +1,69 @@
+"""The whole-pool decode attention kernel compiled by Mosaic for a TPU
+v5e that is described, not attached, at the widths the chip runs.
+
+Interpret mode proves the Python and says nothing about the compiler:
+an unaligned slice, a reshape Mosaic cannot lay out or too much VMEM is
+refused only here (or on the chip, at the price of a chip call). Nothing
+runs, so these tests say nothing about results or times. The topology is
+described inside a fixture, never while a module is imported: only one
+process may hold the TPU's library, and every xdist worker imports every
+test file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+PAGE, LANES, HEAD_DIM, ROWS, LAYERS = 16, 128, 128, 32, 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler on this box
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (kv heads, query rows a kv head, int8 pool, table width): the benchmark
+# cell (mistral-7b: 8 kv heads, group 4, int8, 64-page tables), its
+# narrowest and widest tables, a bf16 pool, the spec fold (group 4 x 5
+# positions), and one shard of `--tp 4` / `--tp 2`.
+CASES = {
+    "q8-w64": (8, 4, True, 64),
+    "q8-w8": (8, 4, True, 8),
+    "q8-w128": (8, 4, True, 128),
+    "bf16-w64": (8, 4, False, 64),
+    "q8-spec5-w64": (8, 20, True, 64),
+    "bf16-tp4-shard-w64": (2, 4, False, 64),
+    "q8-tp2-shard-w64": (4, 4, True, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_pool_decode_kernel_compiles_for_v5e(one_chip, case):
+    from dynamo_tpu.ops.paged_attention import paged_decode_attention_pool
+
+    kh, g, quantized, width = CASES[case]
+    n_pages = ROWS * width + 1
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    args = [
+        shape((ROWS, kh * g, HEAD_DIM), jnp.bfloat16),
+        shape((LAYERS, 2, n_pages, PAGE, kh, HEAD_DIM),
+              jnp.int8 if quantized else jnp.bfloat16),
+        shape((), jnp.int32),
+        shape((ROWS, width), jnp.int32),
+        shape((ROWS,), jnp.int32),
+    ]
+    if quantized:
+        args.append(shape((LAYERS, 2, n_pages, PAGE, LANES), jnp.bfloat16))
+    compiled = paged_decode_attention_pool.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
