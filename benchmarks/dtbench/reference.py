@@ -1,5 +1,39 @@
-"""The plain reference: a dense decoder-only transformer in `jax.numpy`,
-float32, `highest` matmul precision; no kernels, no cache, no batching.
+"""The reference child, and the dense architecture's plain reference.
+
+Run as a child process once the server has exited (the chip is free only
+then): `python reference.py <job.json> <out.json>`. `main` is the one
+program for every configuration: the loop over sets and controls, `gaps`,
+`compare` and the output file are shared, so `correct` means one thing.
+What differs by architecture is the forward pass, and a configuration
+brings its own by name.
+
+**A named reference** (`reference.module` in the configuration's file: a
+path to a file under the benchmark's `paths`, say
+`benchmarks/references/<name>.py`) is loaded here and asked for
+
+    logits_for(samples, cfg, pad_to, lower=None)
+        -> list of float32 arrays [n_served, vocab], one per sample
+
+`samples` are {"prompt": ids, "served": ids}; row i of a sample's array
+holds the logits at the position that predicted served token i, from one
+full forward over prompt + served tokens padded to `pad_to`. `cfg` is
+the whole configuration file, nested keys too (`rope_scaling`, `serve`,
+`check`), with the `reference` object's keys on top, as the dense
+reference has them. `lower` is one entry of `check.controls`: ONE stated
+precision a step down, named by keys the module itself defines. Such a
+module
+
+  * imports nothing of the program and takes nothing the program made;
+  * computes in float32 under `jax.default_matmul_precision("highest")`,
+    with no kernels, no cache and no batching, layer by layer so that
+    one layer's weights are all that is live beside the activations;
+  * states its own seeded-weights recipe at its top, as this file does
+    below, and its tests hold the program to it.
+
+Absent the key, the reference is the one in this file: **a dense
+decoder-only transformer** in `jax.numpy`, float32, `highest` matmul
+precision; no kernels, no cache, no batching. It reads the flat `config`
+of the job (the file's scalars), not the whole file.
 
 It imports nothing of the program and takes nothing the program made. The
 equations are the published ones (pre-norm residual blocks, RMSNorm,
@@ -18,9 +52,6 @@ recipe of its own and not read from the server:
     and `z = round(-min / s)` (docs/quantization.md states the layout);
     the reference multiplies by the dequantised float32 matrix.
 
-Run as a child process once the server has exited (the chip is free only
-then): `python reference.py <job.json> <out.json>`.
-
 A control is the same forward with ONE stated precision taken a step down
 (`controls` in the job, each by name): K and V rounded per token to fewer
 bits, or matmul inputs rounded to int8 or fp8, or weights to int8 per
@@ -30,6 +61,7 @@ the limits have to fail the weakest. Never part of a benchmark run.
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import math
 import sys
@@ -257,22 +289,38 @@ def compare(ref_logits: list[np.ndarray], tokens: list[np.ndarray]) -> dict:
     }
 
 
+def named(path: str | None):
+    """The `logits_for` a job's `module` names, or this file's."""
+    if path is None:
+        return logits_for
+    spec = importlib.util.spec_from_file_location("named_reference", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.logits_for
+
+
 def main(argv: list[str]) -> int:
     job = json.load(open(argv[1]))
     t0 = time.monotonic()
     device = jax.devices()[0]
     cfg, pad_to = job["config"], int(job["pad_to"])
+    module = job.get("module")
+    forward = named(module)
+    if module is not None:  # the whole file, nested keys too
+        cfg = {**job["file"], **cfg}
     result = {"device": {"platform": device.platform,
-                         "kind": device.device_kind}, "sets": []}
+                         "kind": device.device_kind},
+              "module": module or "dtbench/reference.py (dense)",
+              "sets": []}
     for entry in job["sets"]:
         samples = entry["samples"]
-        ref = logits_for(samples, cfg, pad_to)
+        ref = forward(samples, cfg, pad_to)
         served = [np.asarray(s["served"]) for s in samples]
         row = {"label": entry.get("label"), "served": compare(ref, served)}
         if entry.get("control"):
             row["controls"] = {}
             for name, lower in job["controls"].items():
-                low = logits_for(samples, cfg, pad_to, lower)
+                low = forward(samples, cfg, pad_to, lower)
                 row["controls"][name] = compare(
                     ref, [lg.argmax(-1) for lg in low])
         result["sets"].append(row)

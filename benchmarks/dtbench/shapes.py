@@ -4,11 +4,31 @@ The least a step must move or compute, never what an implementation
 happens to touch: a roofline share built on these cannot pass 100% unless
 the time is wrong. `cfg` is a configuration file of `configs/` (the
 source's keys plus `reference.weights` and `serve.kv_dtype`).
+
+This module counts a dense decoder-only transformer (grouped-query
+attention, one SwiGLU block a layer). A configuration of another
+architecture (a latent cache, routed experts) names a module of its own
+under the key `shapes` of its file: a path to a file under the
+benchmark's `paths`, say `benchmarks/shapes/<name>.py`. `run.py` hands
+the readers that module as `ctx["shapes"]`, and this one where the key is
+absent. The interface is what the readers call (`INTERFACE` below), each
+taking the whole configuration file:
+
+    weight_bytes_per_step(cfg)             bytes of weights a decode step reads
+    kv_bytes_per_token(cfg)                bytes a cached token holds, all layers
+    decode_step_bytes(cfg, live_tokens)    the least one decode step reads
+    attention_step_bytes(cfg, live_tokens) the least its attention kernels read
+    flops_per_token(cfg, context)          multiply-adds x 2 for one token
+
+It is loaded in `run.py`'s own process, which never imports JAX (a chip
+belongs to one process): plain Python over the file's numbers.
 """
 
 from __future__ import annotations
 
 Q4_GROUP = 256
+INTERFACE = ("weight_bytes_per_step", "kv_bytes_per_token",
+             "decode_step_bytes", "attention_step_bytes", "flops_per_token")
 
 
 def matmul_params(cfg: dict) -> dict:

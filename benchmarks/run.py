@@ -56,11 +56,13 @@ Other modes, for benchmark PRs (none prints a result line):
 from __future__ import annotations
 
 import argparse
+import ast
 import asyncio
 import importlib.util
 import json
 import os
 import random
+import re
 import shutil
 import subprocess
 import sys
@@ -92,6 +94,9 @@ CAPTURE_MS = 2500  # one capture of steady state per traced run
 PROFILER_WARM_MS = 50
 TRACED_SPAN_MARGIN_SECS = 2.0
 TAIL_MAX_SECS = 45.0
+# what a path the configuration's file names is made of (BENCHMARK.json's
+# contract for a file under `paths`)
+NAMED_PATH = re.compile(r"^[A-Za-z0-9_.\-][A-Za-z0-9_.\-/]{0,199}$")
 
 
 def log(msg: str) -> None:
@@ -104,10 +109,35 @@ def load_json(path: str) -> dict:
         return json.load(f)
 
 
+def load_module(name: str, path: str):
+    """A file of the benchmark, loaded by the name something gave it."""
+    spec = importlib.util.spec_from_file_location(
+        re.sub(r"[^A-Za-z0-9_]", "_", name), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 class Plan:
     """What BENCHMARK.json says about one cell, resolved to files: found
     by name, so a new cell, configuration, mix or per-layer metric is new
-    files and new entries and no edit here."""
+    files and new entries and no edit here. That holds for a
+    configuration of another architecture too: what depends on the
+    architecture is named by the configuration's own file, each a path
+    from BENCHMARK.json's directory to a file under its `paths`, and
+    absent means the dense code that is here:
+
+      reference.module   its plain reference (the contract is at the top
+                         of dtbench/reference.py; absent: the dense one)
+      shapes             its byte and operation counts (the interface is
+                         in dtbench/shapes.py's docstring; absent: that
+                         module), which every reader gets as ctx["shapes"]
+      serve.worker_args  further flags for its worker, after the seven
+                         every worker gets (absent: none)
+
+    All of it is resolved here, before any child starts: a missing file
+    or a module without its interface is a SystemExit that names the
+    configuration, the key and the path."""
 
     def __init__(self, bench_path: str, workload: str) -> None:
         self.bench = load_json(bench_path)
@@ -118,11 +148,118 @@ class Plan:
         self.cell = cells[workload]
         entry = {c["name"]: c for c in self.bench["configs"]}[
             self.cell["config"]]
-        root = os.path.dirname(os.path.abspath(bench_path))
-        self.config = load_json(os.path.join(root, entry["file"]))
+        self.root = os.path.dirname(os.path.abspath(bench_path))
+        self.config = load_json(os.path.join(self.root, entry["file"]))
         self.mix = load_json(os.path.join(
             HERE, "mixes", self.cell["traffic"] + ".json"))
         self.peaks = load_json(os.path.join(HERE, "peaks.json"))
+        self.reference_module = self.named_reference()
+        self.shapes = self.named_shapes()
+        self.worker_args = self.config["serve"].get("worker_args", [])
+        if not (isinstance(self.worker_args, list) and all(
+                isinstance(a, str) for a in self.worker_args)):
+            self.refuse("serve.worker_args", self.worker_args,
+                        "is not a list of strings")
+
+    def refuse(self, key: str, value, why: str):
+        raise SystemExit(
+            f"benchmarks/run.py: configuration {self.cell['config']!r}, "
+            f"key {key!r}: {value!r} {why}; nothing was run and there is "
+            "no result")
+
+    def named_file(self, key: str, rel) -> str:
+        """The file a key of the configuration names: a relative path
+        under one of BENCHMARK.json's `paths`, so that the yardstick
+        stays where later PRs cannot change it."""
+        if not (isinstance(rel, str) and NAMED_PATH.match(rel)
+                and rel.endswith(".py") and ".." not in rel.split("/")):
+            self.refuse(key, rel, "is no relative path of a .py file")
+        if not any(rel.startswith(p.rstrip("/") + "/")
+                   for p in self.bench["paths"]):
+            self.refuse(key, rel, f"lies under none of the benchmark's "
+                                  f"paths {self.bench['paths']}")
+        path = os.path.join(self.root, rel)
+        if not os.path.isfile(path):
+            self.refuse(key, rel, f"is missing ({path})")
+        return path
+
+    def named_reference(self) -> str | None:
+        """The path of the configuration's own reference, or None for the
+        dense one. It runs in the reference child (it imports JAX, and
+        this process may not), so its interface is read off its source."""
+        rel = self.config["reference"].get("module")
+        if rel is None:
+            return None
+        path = self.named_file("reference.module", rel)
+        with open(path) as f:
+            try:
+                tree = ast.parse(f.read(), path)
+            except SyntaxError as exc:
+                self.refuse("reference.module", rel, f"does not parse: {exc}")
+        if not any(isinstance(node, ast.FunctionDef)
+                   and node.name == "logits_for" for node in tree.body):
+            self.refuse("reference.module", rel,
+                        f"defines no logits_for(samples, cfg, pad_to, "
+                        f"lower=None) ({path})")
+        return path
+
+    def named_shapes(self):
+        """The configuration's own counts, loaded here (readers run in
+        this process: it imports no JAX), or dtbench.shapes."""
+        rel = self.config.get("shapes")
+        if rel is None:
+            return shapes
+        path = self.named_file("shapes", rel)
+        had_jax = "jax" in sys.modules
+        module = load_module("shapes_of_" + self.cell["config"], path)
+        if "jax" in sys.modules and not had_jax:
+            self.refuse("shapes", rel, "imports JAX, and this process "
+                                       "may not: a chip belongs to one")
+        missing = [fn for fn in shapes.INTERFACE
+                   if not callable(getattr(module, fn, None))]
+        if missing:
+            self.refuse("shapes", rel, f"lacks {missing} ({path})")
+        return module
+
+    def worker_flags(self) -> list[str]:
+        """The worker's command line after `-m dynamo_tpu.worker`: seven
+        flags every configuration states, then its own."""
+        serve = self.config["serve"]
+        return ["--model", serve["model"],
+                "--weight-dtype", serve["weight_dtype"],
+                "--kv-dtype", serve["kv_dtype"],
+                "--page-size", str(serve["page_size"]),
+                "--num-pages", str(serve["num_pages"]),
+                "--max-batch", str(serve["max_batch"]),
+                "--max-pages-per-seq", str(serve["max_pages_per_seq"]),
+                *self.worker_args]
+
+    def reference_job(self, sets: list[dict]) -> dict:
+        """What the reference child is handed. `config` is what the dense
+        reference reads: the file's scalars with the `reference` object's
+        keys on top. `file` is the whole file, nested keys too, which a
+        named module gets merged under `config`; `module` is its path."""
+        cfg = dict(self.config)
+        ref = cfg.pop("reference")
+        return {
+            "config": {**{k: v for k, v in cfg.items()
+                          if not isinstance(v, (dict, list))}, **ref},
+            "pad_to": -(-int(self.mix["max_total_tokens"]) // 256) * 256,
+            "controls": self.config["check"]["controls"],
+            "sets": sets,
+            "file": self.config,
+            "module": self.reference_module,
+        }
+
+    def context(self, **of_the_run) -> dict:
+        """What a per-layer reader is handed: the cell's files, the
+        configuration's counts, the client's arithmetic, the other
+        readers, and what the run measured."""
+        ctx = {"config": self.config, "mix": self.mix, "cell": self.cell,
+               "shapes": self.shapes, "stats": stats, "layer": Plan.layer,
+               **of_the_run}
+        ctx["read"] = lambda name: Plan.reader(name)(ctx)
+        return ctx
 
     def metrics(self, group: str) -> list[dict]:
         name = self.cell["name"]
@@ -132,12 +269,8 @@ class Plan:
     @staticmethod
     def layer(metric: str):
         """The module layers/<metric>.py, loaded by the metric's name."""
-        path = os.path.join(HERE, "layers", metric + ".py")
-        spec = importlib.util.spec_from_file_location(
-            "layer_" + metric.replace(".", "_").replace("-", "_"), path)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        return module
+        return load_module("layer_" + metric,
+                           os.path.join(HERE, "layers", metric + ".py"))
 
     @staticmethod
     def reader(metric: str):
@@ -195,14 +328,8 @@ class Run:
         if rehearse:
             env["JAX_PLATFORMS"] = "cpu"
         self.env = env
-        args = ["--model", self.model,
-                "--weight-dtype", self.serve["weight_dtype"],
-                "--kv-dtype", self.serve["kv_dtype"],
-                "--page-size", str(self.serve["page_size"]),
-                "--num-pages", str(self.serve["num_pages"]),
-                "--max-batch", str(self.serve["max_batch"]),
-                "--max-pages-per-seq", str(self.serve["max_pages_per_seq"])]
-        self.fleet = Fleet(ROOT, env, self.scratch, args, self.record_path)
+        self.fleet = Fleet(ROOT, env, self.scratch, plan.worker_flags(),
+                           self.record_path)
         self.engine: dict = {}
         self.warm_report: dict = {}
 
@@ -403,19 +530,10 @@ class Run:
 
     def reference(self, sets: list[dict]) -> dict:
         """The reference child. The servers have exited: the chip is free."""
-        cfg = dict(self.plan.config)
-        ref = cfg.pop("reference")
-        job = {
-            "config": {**{k: v for k, v in cfg.items()
-                          if not isinstance(v, (dict, list))}, **ref},
-            "pad_to": -(-int(self.plan.mix["max_total_tokens"]) // 256) * 256,
-            "controls": self.plan.config["check"]["controls"],
-            "sets": sets,
-        }
         job_path = os.path.join(self.scratch, "reference_job.json")
         out_path = os.path.join(self.scratch, "reference_out.json")
         with open(job_path, "w") as f:
-            json.dump(job, f)
+            json.dump(self.plan.reference_job(sets), f)
         with open(os.path.join(self.scratch, "reference.log"), "w") as err:
             child = subprocess.run(
                 [sys.executable, os.path.join(HERE, "dtbench", "reference.py"),
@@ -424,7 +542,10 @@ class Run:
         if child.returncode:
             raise FleetError("reference child failed:\n" + tail(
                 os.path.join(self.scratch, "reference.log")))
-        return load_json(out_path)
+        out = load_json(out_path)
+        log(f"reference {out['module']}: {out['seconds']:.1f}s on "
+            f"{out['device']}")
+        return out
 
     def start_reduction(self, capture: dict) -> subprocess.Popen:
         """Trace -> numbers, in a child held to the CPU."""
@@ -606,7 +727,6 @@ def main() -> int:
                                   "control": bool(args.control)}])
             numbers = ref["sets"][0]["served"]
             controls = ref["sets"][0].get("controls", {})
-            log(f"reference: {ref['seconds']:.1f}s on {ref['device']}")
             for name, row in controls.items():
                 log(f"control {name}: {json.dumps(row)}")
         if args.trace == 1:
@@ -633,13 +753,10 @@ def main() -> int:
                 metrics[m["name"]] = {"value": values[m["name"]],
                                       "unit": m["unit"]}
     if args.trace:
-        ctx = {"config": plan.config, "mix": plan.mix, "cell": plan.cell,
-               "peaks": plan.peaks.get(run.engine.get("device_kind")),
-               "window": window, "timelines": result["timelines"],
-               "trace": trace, "shapes": shapes, "stats": stats,
-               "client": summary["metrics"]}
-        ctx["read"] = lambda name: Plan.reader(name)(ctx)
-        ctx["layer"] = Plan.layer
+        ctx = plan.context(
+            peaks=plan.peaks.get(run.engine.get("device_kind")),
+            window=window, timelines=result["timelines"], trace=trace,
+            client=summary["metrics"])
         if ctx["peaks"] is None and not args.rehearse_cpu:
             log(f"FAILED: no peaks for device kind "
                 f"{run.engine.get('device_kind')!r} in peaks.json")
@@ -659,6 +776,7 @@ def main() -> int:
     report = {
         "workload": plan.cell["name"], "seed": args.seed,
         "seconds": args.seconds, "trace": args.trace,
+        "worker_flags": plan.worker_flags(),
         "setup_s": result["setup_s"], "summary": {
             k: v for k, v in summary.items() if k != "metrics"},
         "client_metrics": summary["metrics"],
@@ -675,14 +793,22 @@ def main() -> int:
     if args.trace == 2:
         report["tail"] = run.tail_report(window, result["timelines"])
     print(json.dumps(report))
-    for row in rows:
-        print(f"compared {row['number']}: {row['value']} against limit "
-              f"{row['limit']} -> {'within' if row['within'] else 'OUTSIDE'}")
     line = {"correct": bool(correct), "attempted": summary["attempted"],
             "failed": summary["failed"], "metrics": metrics,
             "device": device}
     if trace is not None:
         line["breakdown"] = trace["breakdown"]
+    # each number compared beside its limit: on standard output as it
+    # was, as the last lines of standard error, and last in the line
+    line["compared"] = {row["number"]: {"value": row["value"],
+                                        "limit": row["limit"]}
+                        for row in rows}
+    for row in rows:
+        said = (f"compared {row['number']}: {row['value']} against limit "
+                f"{row['limit']} -> "
+                f"{'within' if row['within'] else 'OUTSIDE'}")
+        print(said)
+        print(said, file=sys.stderr)
     print(json.dumps(line), flush=True)
     if args.rehearse_cpu:
         return EXIT_REHEARSAL

@@ -101,7 +101,10 @@ def test_cells_configs_and_files():
     ("mistral-7b-w4kv8", "mistral-7b"), ("tiny-test", "tiny-test")])
 def test_a_configuration_file_states_what_the_program_runs(name, preset):
     """The file's sizes are the program's preset's: the reference is
-    built from the file, the server from the preset."""
+    built from the file, the server from the preset. This is the dense
+    architecture's own test (the keys it reads are the dense block's); a
+    configuration of another architecture brings the like in a test file
+    of its own, beside the reference and the counts it names."""
     from dynamo_tpu.models.config import get_config
 
     body, cfg = load("configs", name + ".json"), get_config(preset)
@@ -120,6 +123,58 @@ def test_a_configuration_file_states_what_the_program_runs(name, preset):
     assert body["reference"]["dtype"] == cfg.dtype
     want = {"int4": "int4", "model": "model"}[body["serve"]["weight_dtype"]]
     assert body["reference"]["weights"] == want
+
+
+def configuration_files():
+    """Every configuration BENCHMARK.json has, every file under
+    benchmarks/configs/, and the fixture that names all three keys."""
+    files = {c["file"] for c in bench()["configs"]}
+    files |= {"benchmarks/configs/" + f
+              for f in os.listdir(os.path.join(BENCH, "configs"))}
+    return sorted(files | {"tests/bench/named/config.json"})
+
+
+@pytest.mark.parametrize("config_file", configuration_files())
+def test_what_a_configuration_names_is_there_and_under_the_paths(config_file):
+    """`reference.module`, `shapes` and `serve.worker_args`: each named
+    file exists, is named as a file under `paths` must be and lies under
+    one of them; the counts expose the readers' five functions without
+    JAX; the reference defines `logits_for`. (`Plan` refuses the same at
+    run time; this holds what is committed to it.)"""
+    import ast
+
+    from dtbench import shapes
+
+    with open(os.path.join(ROOT, config_file)) as f:
+        body = json.load(f)
+    named = {"reference.module": body["reference"].get("module"),
+             "shapes": body.get("shapes")}
+    for key, rel in named.items():
+        if rel is None:
+            continue
+        assert PATH.match(rel) and not rel.startswith("/"), (key, rel)
+        assert ".." not in rel.split("/") and rel.endswith(".py")
+        assert any(rel.startswith(p + "/") for p in bench()["paths"]), rel
+        assert os.path.isfile(os.path.join(ROOT, rel)), (key, rel)
+    if named["shapes"]:
+        code = ("import sys, importlib.util as u; "
+                f"spec = u.spec_from_file_location('counts', {named['shapes']!r}); "
+                "m = u.module_from_spec(spec); spec.loader.exec_module(m); "
+                f"ok = all(callable(getattr(m, fn, None)) for fn in {shapes.INTERFACE!r}); "
+                "raise SystemExit(0 if ok and 'jax' not in sys.modules else 1)")
+        assert subprocess.run([sys.executable, "-c", code],
+                              cwd=ROOT).returncode == 0
+    if named["reference.module"]:
+        with open(os.path.join(ROOT, named["reference.module"])) as f:
+            tree = ast.parse(f.read())
+        assert "logits_for" in [n.name for n in tree.body
+                                if isinstance(n, ast.FunctionDef)]
+    args = body["serve"].get("worker_args", [])
+    assert isinstance(args, list) and all(isinstance(a, str) for a in args)
+    assert shapes.INTERFACE == (
+        "weight_bytes_per_step", "kv_bytes_per_token", "decode_step_bytes",
+        "attention_step_bytes", "flops_per_token")
+    assert all(callable(getattr(shapes, fn)) for fn in shapes.INTERFACE)
 
 
 def test_importing_the_harness_leaves_jax_out():

@@ -17,13 +17,13 @@ from bench_paths import BENCH, ROOT
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-def rehearse(tmp_path, trace, seed, env=None, traffic="rehearsal"):
+def rehearse(tmp_path, trace, seed, env=None, traffic="rehearsal",
+             config_file="benchmarks/configs/tiny-test.json"):
     bench = {
         "command": ["python3", "benchmarks/run.py"],
         "paths": ["benchmarks", "tests/bench"], "run_seconds": 5,
         "configs": [{"name": "tiny-test", "source": "the program's preset",
-                     "file": "benchmarks/configs/tiny-test.json",
-                     "reduced": [], "why": "toy"}],
+                     "file": config_file, "reduced": [], "why": "toy"}],
         "workloads": [{"name": "tiny." + traffic, "config": "tiny-test",
                        "traffic": traffic, "chips": 1, "why": "toy"}],
     }
@@ -37,6 +37,8 @@ def rehearse(tmp_path, trace, seed, env=None, traffic="rehearsal"):
         json.dump(bench, f)
     if not os.path.exists(tmp_path / "benchmarks"):
         os.symlink(BENCH, tmp_path / "benchmarks")
+        os.makedirs(tmp_path / "tests")
+        os.symlink(HERE, tmp_path / "tests" / "bench")
     out = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--benchmark-json",
          str(tmp_path / "BENCHMARK.json"), "--workload", "tiny." + traffic,
@@ -56,7 +58,7 @@ def test_a_rehearsed_run_prints_the_contracts_line(tmp_path, trace, traffic):
     out, line, lines = rehearse(tmp_path, trace, 2**31 + 77, traffic=traffic)
     assert out.returncode == 10, out.stderr[-2000:]
     assert set(line) - {"breakdown"} == {"correct", "attempted", "failed",
-                                         "metrics", "device"}
+                                         "metrics", "device", "compared"}
     assert line["device"]["platform"] == "cpu"
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 5
@@ -110,8 +112,37 @@ def test_a_rehearsed_run_prints_the_contracts_line(tmp_path, trace, traffic):
                 "sched.drain_wait", "sched.emit", "sched.reap", "decode",
                 "prefill"} <= host, sorted(
             n for n in host if not n.startswith("$"))[:60]
-    # every number compared is printed beside its limit
+    # every number compared is printed beside its limit: on standard
+    # output, as the last lines of standard error, and last in the line
     assert any(ln.startswith("compared gap_max:") for ln in lines)
+    assert [ln.split(":")[0] for ln in out.stderr.strip().splitlines()[-2:]
+            ] == ["compared gap_max", "compared gap_mean"]
+    assert list(line)[-1] == "compared"
+    assert set(line["compared"]) == {"gap_max", "gap_mean"}
+    assert line["compared"]["gap_max"]["limit"] == 0.25
+    assert 0 <= line["compared"]["gap_max"]["value"] <= 0.25
+    # the dense reference decided it, and the run's log says so
+    assert "reference dtbench/reference.py (dense):" in out.stderr
+
+
+@pytest.mark.slow
+def test_a_configuration_is_served_and_checked_through_what_it_names(
+        tmp_path):
+    """The fixture of test_bench_named.py through a whole run: its worker
+    gets its further flags, and `correct` is decided by the reference it
+    names (every served token reads exactly 0.125 below that stand-in's
+    best, where the dense reference reads 0 to 0.06 on tiny-test)."""
+    out, line, lines = rehearse(
+        tmp_path, 2, 7, config_file="tests/bench/named/config.json")
+    assert out.returncode == 10, out.stderr[-2000:]
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["compared"] == {
+        "gap_max": {"value": 0.125, "limit": 0.25},
+        "gap_mean": {"value": 0.125, "limit": 0.2}}
+    assert f"reference {tmp_path}/tests/bench/named/reference.py:" in (
+        out.stderr)
+    assert json.loads(lines[0])["worker_flags"][-4:] == [
+        "--max-pages-per-seq", "64", "--kvbm-host-blocks", "16"]
 
 
 @pytest.mark.slow

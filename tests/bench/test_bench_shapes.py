@@ -33,8 +33,9 @@ def test_mistral_7b_int4_int8():
 
 
 def test_a_bf16_configuration_by_hand():
-    """The bf16 branch, on Qwen3-4B's published sizes (tied 152k head):
-    the configuration PERF.md's Open questions keep for a later cell."""
+    """The bf16 branch, on a public dense 4B model's sizes (Qwen3-4B's:
+    tied 152k head), which PR 25 served on the chip and took out again
+    (PERF.md, Findings)."""
     cfg = {"hidden_size": 2560, "intermediate_size": 9728,
            "num_hidden_layers": 36, "num_attention_heads": 32,
            "num_key_value_heads": 8, "head_dim": 128, "vocab_size": 151936,
