@@ -40,6 +40,10 @@ from .sampler import sample, sample_with_logprobs
 log = get_logger("engine.runner")
 
 DEFAULT_PREFILL_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048)
+# Rows of `ModelRunner.moe_counts`: the expert layers' statistics are kept
+# apart for prefill launches (thousands of tokens: every expert is
+# touched) and decode steps (a token a row: few are).
+MOE_PHASES = ("prefill", "decode")
 
 # -- compile observability ---------------------------------------------------
 # Runtime cross-check for the dynajit DJ1xx static pass: every XLA
@@ -264,6 +268,19 @@ class ModelRunner:
             None if self._attention_user_supplied or model_config.is_gptoss
             or model_config.is_mla
             else _default_spec_attention_fn(mesh))
+        # A hybrid stack (models/hybrid.py): Mamba-2 state per slot
+        # beside the KV pages, dropless experts told which they hold.
+        self._hybrid = model_config.is_hybrid
+        if self._hybrid:
+            self._check_hybrid(model_config, runner_config, mesh)
+            # No bucket under one chunk of the scan (published: 128): a
+            # shorter launch reads the same weights, and every bucket
+            # less is a row of programs less to compile (`prewarm`).
+            self.config = runner_config = dataclasses.replace(
+                runner_config, prefill_buckets=tuple(
+                    b for b in runner_config.prefill_buckets
+                    if b >= model_config.ssm_chunk
+                    or b == runner_config.prefill_buckets[-1]))
         axes = param_axes(model_config)
         if runner_config.weight_dtype not in ("model", "int8", "int4"):
             raise ValueError(
@@ -396,6 +413,26 @@ class ModelRunner:
             )
         self.kv_cache = kv_init()
         self._rep = NamedSharding(mesh, P())  # replicated host inputs
+        self.state = None
+        # expert statistics (models/hybrid.moe_mixer) of launches whose
+        # readback nobody waits for, with the phase each belongs to;
+        # `moe_stats` folds the ones that are ready into `moe_counts`
+        # [phase (MOE_PHASES), tokens per held expert..., dropped,
+        # touched, calls]
+        self._moe_pending: list = []
+        self.moe_counts = None
+        if self._hybrid:
+            from ..models.hybrid import make_state_cache, moe_stats_size
+            from ..ops import kernel_path
+
+            self._ssm_path = kernel_path("DYNT_SSM")
+            self._gmm_path = kernel_path("DYNT_MOE_GMM")
+            self.state = jax.jit(
+                lambda: make_state_cache(model_config,
+                                         runner_config.max_batch),
+                out_shardings=self._rep)()
+            self.moe_counts = np.zeros(
+                (len(MOE_PHASES), moe_stats_size(model_config)), np.int64)
         self.lora_pack = None
         if runner_config.max_loras > 0:
             from ..models.transformer import init_lora_pack
@@ -416,6 +453,101 @@ class ModelRunner:
         self._embed_fns: dict[int, callable] = {}
         self._zero_embeds: dict[int, jax.Array] = {}  # per-bucket, mm only
         self.decode_steps = 0
+
+    @staticmethod
+    def _check_hybrid(cfg: ModelConfig, rc: RunnerConfig, mesh: Mesh):
+        """What a hybrid stack cannot run with yet, refused at start by
+        name: never a wrong answer later."""
+        from ..models.hybrid import hybrid_refusals
+
+        hybrid_refusals(cfg, rc.weight_dtype, rc.kv_dtype, mesh.devices.size)
+        if rc.max_loras:
+            raise ValueError(f"--max-loras: no adapter targets on {cfg.name} "
+                             f"(layers {cfg.layer_pattern})")
+
+    def _launch(self, fn, args, kwargs=None, phase: str = "decode"):
+        """Run one compiled step. The caches go in donated behind the
+        params and come back first; a hybrid step's last output is its
+        experts' statistics, kept on the device until ready."""
+        cache = (self.kv_cache, self.state) if self._hybrid \
+            else self.kv_cache
+        cache, *rest = fn(self.params, cache, *args, **(kwargs or {}))
+        if self._hybrid:
+            self.kv_cache, self.state = cache
+            self._moe_pending.append((MOE_PHASES.index(phase), rest.pop()))
+        else:
+            self.kv_cache = cache
+        return rest
+
+    def _outs(self, *rest):
+        """out_shardings of a compiled step: the caches it took donated
+        (the KV pool, or (pool, per-slot state)), `rest`, and a hybrid
+        step's moe stats. Read off the runner at build time, since
+        `reshard` replaces the shardings."""
+        if not self._hybrid:
+            return (self._kv_sharding, *rest)
+        return ((self._kv_sharding, self._rep), *rest, self._rep)
+
+    def moe_stats(self):
+        """By phase (a row of MOE_PHASES each): tokens each held expert
+        has computed, slots dropped (always 0: the layer is dropless),
+        held experts touched and expert-layer calls, summed over launches
+        whose results have landed. Never waits for the device. None for
+        a model without routed dropless experts."""
+        if self.moe_counts is None:
+            return None
+        while self._moe_pending and self._moe_pending[0][1].is_ready():
+            phase, stats = self._moe_pending.pop(0)
+            self.moe_counts[phase] += np.asarray(stats)  # dynalint: disable=DL201 -- is_ready() above: nothing to wait for; a few dozen ints per launch # dynajit: disable=DJ201 -- same: the launch has completed
+        return self.moe_counts
+
+    def _decode_model(self):
+        """The model's one-token step as (params, cache, tokens,
+        positions, block_tables, kv_lens, active, lora, lora_idx) ->
+        (cache, logits [B, 1, V], extra outputs)."""
+        cfg = self.model_config
+        attention_fn = self._attention_fn
+        with_lora = self.lora_pack is not None
+        # Deferred-write decode (2 batched scatters per step for all layers
+        # instead of 2 per layer) measured ~12x faster than the unified
+        # path with the Pallas flash-decode kernel on v5e — it is the
+        # default. A USER-SUPPLIED attention_fn still wins (tests inject
+        # reference kernels); MLA keeps the unified path (its latent cache
+        # is a single stack, so the scatter count is already minimal).
+        fast_decode = (not cfg.is_mla and not cfg.is_gptoss
+                       and not self._attention_user_supplied)
+
+        def hybrid(params, cache, tokens, positions, block_tables, kv_lens,
+                   active, lora, lora_idx):
+            from ..models.hybrid import forward_hybrid_decode
+
+            kv, state = cache
+            kv, state, logits, stats = forward_hybrid_decode(
+                params, cfg, tokens, positions, kv, state, block_tables,
+                kv_lens, active,
+                decode_attention_fn=self._decode_attention_fn,
+                ssm_path=self._ssm_path, gmm_path=self._gmm_path)
+            return (kv, state), logits, (stats,)
+
+        def one(params, kv, tokens, positions, block_tables, kv_lens,
+                active, lora, lora_idx):
+            if not fast_decode:
+                kv, logits = forward(
+                    params, cfg, tokens[:, None], positions[:, None], kv,
+                    block_tables, kv_lens, valid=active[:, None],
+                    attention_fn=attention_fn,
+                    lora=lora if with_lora else None, lora_idx=lora_idx,
+                )
+            else:
+                kv, logits = forward_decode(
+                    params, cfg, tokens, positions, kv, block_tables,
+                    kv_lens, active, lora=lora if with_lora else None,
+                    lora_idx=lora_idx,
+                    decode_attention_fn=self._decode_attention_fn,
+                )
+            return kv, logits, ()
+
+        return hybrid if self._hybrid else one
 
     def _init_random_params(self, seed: int) -> dict:
         """`init_params` from the seed (same values), built and — for
@@ -449,10 +581,10 @@ class ModelRunner:
 
         keys = jax.random.split(jax.random.PRNGKey(seed), cfg.n_layers + 2)
         params = top_init_fn()(keys[0], keys[-1])
-        layer_fns: dict[bool, callable] = {}  # one program per layer kind
+        layer_fns: dict = {}  # one program per layer kind
         params["layers"] = []
         for i in range(cfg.n_layers):
-            kind = cfg.layer_is_moe(i)
+            kind = cfg.layer_kind(i) if cfg.is_hybrid else cfg.layer_is_moe(i)
             if kind not in layer_fns:
                 layer_fns[kind] = layer_init_fn(i)
             params["layers"].append(layer_fns[kind](keys[i + 1]))
@@ -465,7 +597,9 @@ class ModelRunner:
         `chip_smoke.py` checks, so a reference kernel or the interpreter
         can never serve unnoticed. Unquantized weights have one
         implementation (`einsum`); `custom` is a caller-supplied
-        attention_fn."""
+        attention_fn. A hybrid stack adds its two kernels: the decode
+        state update (DYNT_SSM) and the experts' grouped matmul
+        (DYNT_MOE_GMM)."""
         from ..ops import kernel_path
 
         def attention(fn) -> str:
@@ -489,6 +623,9 @@ class ModelRunner:
                 for layer in self.params["layers"]
                 for leaf in layer.values() if isinstance(leaf, dict)}
             paths["q4_layout"] = "+".join(f"v{v}" for v in sorted(versions))
+        if self._hybrid:
+            paths["ssm_update"] = self._ssm_path
+            paths["expert_gmm"] = self._gmm_path
         devices = list(self.mesh.devices.flat)
         paths["platform"] = devices[0].platform
         paths["device_kind"] = devices[0].device_kind
@@ -519,33 +656,7 @@ class ModelRunner:
 
     def _build_decode(self, with_logprobs: bool = False,
                       with_logits: bool = False):
-        cfg = self.model_config
-        attention_fn = self._attention_fn
-        with_lora = self.lora_pack is not None
-
-        # Deferred-write decode (2 batched scatters per step for all layers
-        # instead of 2 per layer) measured ~12x faster than the unified
-        # path with the Pallas flash-decode kernel on v5e — it is the
-        # default. A USER-SUPPLIED attention_fn still wins (tests inject
-        # reference kernels); MLA keeps the unified path (its latent cache
-        # is a single stack, so the scatter count is already minimal).
-        fast_decode = (not cfg.is_mla and not cfg.is_gptoss
-                       and not self._attention_user_supplied)
-
-        def one(params, kv, tokens, positions, block_tables, kv_lens,
-                active, lora, lora_idx):
-            if not fast_decode:
-                return forward(
-                    params, cfg, tokens[:, None], positions[:, None], kv,
-                    block_tables, kv_lens, valid=active[:, None],
-                    attention_fn=attention_fn,
-                    lora=lora if with_lora else None, lora_idx=lora_idx,
-                )
-            return forward_decode(
-                params, cfg, tokens, positions, kv, block_tables, kv_lens,
-                active, lora=lora if with_lora else None, lora_idx=lora_idx,
-                decode_attention_fn=self._decode_attention_fn,
-            )
+        one = self._decode_model()
 
         def step(params, kv, tokens, positions, block_tables, kv_lens,
                  active, temperature, top_p, top_k, seeds, step_idx,
@@ -553,8 +664,9 @@ class ModelRunner:
             # step_idx: [B] per-slot generated-token index, so a fixed
             # request seed reproduces its stream independent of what other
             # requests the engine is running.
-            kv, logits = one(params, kv, tokens, positions, block_tables,
-                             kv_lens, active, lora, lora_idx)
+            kv, logits, extra = one(params, kv, tokens, positions,
+                                    block_tables, kv_lens, active, lora,
+                                    lora_idx)
             if with_logits:
                 # Logits-processor escape hatch: ship the raw rows to
                 # host alongside the device-sampled tokens; the scheduler
@@ -564,70 +676,54 @@ class ModelRunner:
                 next_tokens = sample(
                     logits[:, 0, :], temperature, top_p, top_k, seeds,
                     step_idx)
-                return kv, next_tokens, logits[:, 0, :].astype(jnp.float32)
+                return (kv, next_tokens,
+                        logits[:, 0, :].astype(jnp.float32), *extra)
             if with_logprobs:
                 next_tokens, lp, top_ids, top_lps = sample_with_logprobs(
                     logits[:, 0, :], temperature, top_p, top_k, seeds,
                     step_idx)
-                return kv, next_tokens, lp, top_ids, top_lps
+                return (kv, next_tokens, lp, top_ids, top_lps, *extra)
             # Hot path: no full-vocab log_softmax/top_k and only [B] int32
             # crosses device->host (the per-token latency discipline,
             # SURVEY section 7).
             next_tokens = sample(
                 logits[:, 0, :], temperature, top_p, top_k, seeds, step_idx)
-            return kv, next_tokens
+            return (kv, next_tokens, *extra)
 
-        if with_logits:
-            shard = (self._kv_sharding, self._rep, self._rep)
-        elif with_logprobs:
-            shard = (self._kv_sharding, self._rep, self._rep, self._rep,
-                     self._rep)
-        else:
-            shard = (self._kv_sharding, self._rep)
-        return jax.jit(step, donate_argnums=(1,), out_shardings=shard)
+        n_rest = 2 if with_logits else 4 if with_logprobs else 1
+        return jax.jit(step, donate_argnums=(1,),
+                       out_shardings=self._outs(*[self._rep] * n_rest))
 
     def _build_decode_multi(self, k: int):
         """K decode steps inside ONE jit call via lax.scan: a single
         host<->device round trip produces K tokens per slot. This is the
         TPU answer to per-token dispatch latency (multi-step scheduling in
-        vLLM terms): it removes K-1 host syncs per block."""
-        cfg = self.model_config
-        attention_fn = self._attention_fn
-        with_lora = self.lora_pack is not None
+        vLLM terms): it removes K-1 host syncs per block. A hybrid
+        model's per-slot state rides the carry with the pool."""
+        one = self._decode_model()
 
         def multi(params, kv, tokens, positions, block_tables, kv_lens,
                   active, temperature, top_p, top_k, seeds, step_idx,
                   lora=None, lora_idx=None):
-            fast_decode = (not cfg.is_mla and not cfg.is_gptoss
-                           and not self._attention_user_supplied)
-
             def body(carry, _):
-                kv, toks, pos, lens, sidx = carry
-                if not fast_decode:
-                    kv, logits = forward(
-                        params, cfg, toks[:, None], pos[:, None], kv,
-                        block_tables, lens, valid=active[:, None],
-                        attention_fn=attention_fn,
-                        lora=lora if with_lora else None, lora_idx=lora_idx,
-                    )
-                else:
-                    kv, logits = forward_decode(
-                        params, cfg, toks, pos, kv, block_tables, lens,
-                        active, lora=lora if with_lora else None,
-                        lora_idx=lora_idx,
-                        decode_attention_fn=self._decode_attention_fn,
-                    )
+                kv, toks, pos, lens, sidx, acc = carry
+                kv, logits, extra = one(params, kv, toks, pos,
+                                        block_tables, lens, active, lora,
+                                        lora_idx)
                 nxt = sample(logits[:, 0, :], temperature, top_p, top_k,
                              seeds, sidx)
-                return (kv, nxt, pos + 1, lens + 1, sidx + 1), nxt
+                acc = tuple(a + e for a, e in zip(acc, extra))
+                return (kv, nxt, pos + 1, lens + 1, sidx + 1, acc), nxt
 
-            (kv, *_), toks_k = jax.lax.scan(
-                body, (kv, tokens, positions, kv_lens, step_idx),
+            acc0 = ((jnp.zeros(self.moe_counts.shape[1], jnp.int32),)
+                    if self._hybrid else ())
+            (kv, *_, acc), toks_k = jax.lax.scan(
+                body, (kv, tokens, positions, kv_lens, step_idx, acc0),
                 None, length=k)
-            return kv, toks_k  # [K, B]
+            return (kv, toks_k, *acc)  # toks_k: [K, B]
 
         return jax.jit(multi, donate_argnums=(1,),
-                       out_shardings=(self._kv_sharding, self._rep))
+                       out_shardings=self._outs(self._rep))
 
     def decode_multi(
         self,
@@ -662,7 +758,7 @@ class ModelRunner:
         if steps is None:
             steps = np.zeros(len(tokens), np.int32)
         args = [
-            self.params, self.kv_cache, jnp.asarray(tokens, jnp.int32),
+            jnp.asarray(tokens, jnp.int32),
             jnp.asarray(positions, jnp.int32),
             jnp.asarray(block_tables, jnp.int32),
             jnp.asarray(kv_lens, jnp.int32), jnp.asarray(active, bool),
@@ -676,7 +772,7 @@ class ModelRunner:
                 lora_idx = np.zeros(len(tokens), np.int32)
             args += [self.lora_pack, jnp.asarray(lora_idx, jnp.int32)]
         with compile_scope("decode_multi"):
-            self.kv_cache, toks_k = fn(*args)
+            (toks_k,) = self._launch(fn, args)
         self.last_decode_sample = (None, None, None)
         if return_device:
             return toks_k
@@ -692,7 +788,7 @@ class ModelRunner:
         from different attention semantics would silently diverge from
         the non-speculative stream."""
         cfg = self.model_config
-        return (not cfg.is_mla and not cfg.is_gptoss
+        return (not cfg.is_mla and not cfg.is_gptoss and not cfg.is_hybrid
                 and not self._attention_user_supplied)
 
     def _build_decode_spec(self, t: int, with_logits: bool = False):
@@ -770,6 +866,7 @@ class ModelRunner:
              np.asarray(drafts, np.int32)], axis=1)
         pos2 = (np.asarray(positions, np.int32)[:, None]
                 + np.arange(t, dtype=np.int32)[None, :])
+        assert not self._hybrid, "speculation is refused for hybrid stacks"
         args = [
             self.params, self.kv_cache, jnp.asarray(chunk),
             jnp.asarray(pos2),
@@ -807,29 +904,41 @@ class ModelRunner:
 
         def step(params, kv, tokens, positions, block_table, kv_lens,
                  valid, last_idx, temperature, top_p, top_k, seeds,
-                 lora=None, lora_idx=None, extra_embeds=None):
-            kv, logits = forward(
-                params, cfg, tokens, positions, kv, block_table, kv_lens,
-                valid=valid, attention_fn=attention_fn,
-                lora=lora if with_lora else None, lora_idx=lora_idx,
-                extra_embeds=extra_embeds if with_mm else None,
-                extra_mask=((tokens == cfg.image_token_id)
-                            if with_mm else None),
-            )
-            last = jnp.take_along_axis(
-                logits, last_idx[:, None, None], axis=1
-            )[:, 0, :]  # [1, V]
+                 lora=None, lora_idx=None, extra_embeds=None, slots=None):
+            if self._hybrid:
+                from ..models.hybrid import forward_hybrid
+
+                # logits of each row's last valid position only: a
+                # [rows x T, vocab] float32 never exists on this path
+                kv, state = kv
+                kv, state, last, stats = forward_hybrid(
+                    params, cfg, tokens, positions, kv, state, slots,
+                    block_table, kv_lens, valid, last_idx,
+                    attention_fn=attention_fn, gmm_path=self._gmm_path)
+                kv, extra = (kv, state), (stats,)
+            else:
+                kv, logits = forward(
+                    params, cfg, tokens, positions, kv, block_table,
+                    kv_lens, valid=valid, attention_fn=attention_fn,
+                    lora=lora if with_lora else None, lora_idx=lora_idx,
+                    extra_embeds=extra_embeds if with_mm else None,
+                    extra_mask=((tokens == cfg.image_token_id)
+                                if with_mm else None),
+                )
+                last = jnp.take_along_axis(
+                    logits, last_idx[:, None, None], axis=1
+                )[:, 0, :]  # [1, V]
+                extra = ()
             # Unconditional here, unlike decode: one [1, V] log_softmax per
             # CHUNK is noise next to the chunk forward, and the extra host
             # transfer is a handful of floats. Decode pays this per token,
             # hence its gated _decode_fn/_decode_fn_lp split.
             token, lp, top_ids, top_lps = sample_with_logprobs(
                 last, temperature, top_p, top_k, seeds, jnp.int32(0))
-            return kv, token, lp, top_ids, top_lps
+            return (kv, token, lp, top_ids, top_lps, *extra)
 
         return jax.jit(step, donate_argnums=(1,),
-                       out_shardings=(self._kv_sharding, self._rep,
-                                      self._rep, self._rep, self._rep))
+                       out_shardings=self._outs(*[self._rep] * 4))
 
     @property
     def sp_size(self) -> int:
@@ -989,6 +1098,14 @@ class ModelRunner:
     def max_prefill_chunk(self) -> int:
         return self.config.prefill_buckets[-1]
 
+    @property
+    def max_prefill_rows(self) -> int:
+        """Rows a prefill launch may hold: each is padded to the smallest
+        bucket at least, so more of them make a tile past the token
+        budget whatever their lengths (and a row of programs more)."""
+        buckets = self.config.prefill_buckets
+        return max(1, buckets[-1] // buckets[0])
+
     # -- host API ----------------------------------------------------------
 
     def prefill_chunk(
@@ -1001,6 +1118,7 @@ class ModelRunner:
         lora_idx: int = 0,
         chunk_embeds: Optional[np.ndarray] = None,  # [t, H] splice rows
         return_device: bool = False,
+        slot: int = 0,  # the scheduler's slot: where recurrent state lives
     ) -> int:
         """Run one prefill chunk; returns the sampled token id (meaningful
         only on the final chunk). `chunk_embeds` rows replace the token
@@ -1023,7 +1141,7 @@ class ModelRunner:
         valid[0, :t] = True
         temp, top_p, top_k, seed = sampling
         args = [
-            self.params, self.kv_cache, jnp.asarray(tok), jnp.asarray(pos),
+            jnp.asarray(tok), jnp.asarray(pos),
             jnp.asarray(block_table[None, :]),
             jnp.asarray([kv_len_after], np.int32),
             jnp.asarray(valid), jnp.asarray([t - 1], np.int32),
@@ -1035,6 +1153,8 @@ class ModelRunner:
         # positional embeds array would silently bind to the `lora`
         # parameter and the splice would never happen.
         kwargs: dict = {}
+        if self._hybrid:
+            kwargs["slots"] = jnp.asarray([slot], jnp.int32)
         if self.lora_pack is not None:
             kwargs["lora"] = self.lora_pack
             kwargs["lora_idx"] = jnp.asarray([lora_idx], jnp.int32)
@@ -1055,7 +1175,8 @@ class ModelRunner:
                     self._zero_embeds[bucket] = zeros
                 kwargs["extra_embeds"] = zeros
         with compile_scope("prefill"):
-            self.kv_cache, token, lp, top_ids, top_lps = fn(*args, **kwargs)
+            token, lp, top_ids, top_lps = self._launch(fn, args, kwargs,
+                                                       phase="prefill")
         if return_device:
             self.last_prefill_sample = None
             return token
@@ -1067,7 +1188,7 @@ class ModelRunner:
     def prefill_chunk_batch(
         self,
         rows: list,  # (tokens, start_pos, block_table, kv_len_after,
-        #              sampling, lora_idx) per sequence
+        #              sampling, lora_idx[, slot]) per sequence
         want_samples: bool = False,
     ):
         """Run SEVERAL sequences' prefill chunks in one compiled dispatch
@@ -1104,8 +1225,11 @@ class ModelRunner:
         top_k = np.zeros(b, np.int32)
         seeds = np.zeros(b, np.uint32)
         lora_rows = np.zeros(b, np.int32)
-        for i, (tokens, start, table, kv_after, sampling, lidx) in \
+        # a padded row's state write is dropped: its slot is past the end
+        slots = np.full(b, self.config.max_batch, np.int32)
+        for i, (tokens, start, table, kv_after, sampling, lidx, *slot) in \
                 enumerate(rows):
+            slots[i] = slot[0] if slot else 0
             t = len(tokens)
             tok[i, :t] = tokens
             pos[i, :t] = np.arange(start, start + t)
@@ -1116,12 +1240,14 @@ class ModelRunner:
             temp[i], top_p[i], top_k[i], seeds[i] = sampling
             lora_rows[i] = lidx
         args = [
-            self.params, self.kv_cache, jnp.asarray(tok), jnp.asarray(pos),
+            jnp.asarray(tok), jnp.asarray(pos),
             jnp.asarray(tables), jnp.asarray(kv_lens), jnp.asarray(valid),
             jnp.asarray(last_idx), jnp.asarray(temp), jnp.asarray(top_p),
             jnp.asarray(top_k), jnp.asarray(seeds),
         ]
         kwargs: dict = {}
+        if self._hybrid:
+            kwargs["slots"] = jnp.asarray(slots)
         if self.lora_pack is not None:
             kwargs["lora"] = self.lora_pack
             kwargs["lora_idx"] = jnp.asarray(lora_rows)
@@ -1136,8 +1262,8 @@ class ModelRunner:
                 self._zero_embeds[(b, bucket)] = zeros
             kwargs["extra_embeds"] = zeros
         with compile_scope("prefill_batch"):
-            self.kv_cache, token, lp, top_ids, top_lps = fn(*args,
-                                                            **kwargs)
+            token, lp, top_ids, top_lps = self._launch(fn, args, kwargs,
+                                                       phase="prefill")
         if want_samples:
             lp_h = np.asarray(lp)  # dynajit: disable=DJ201 -- explicit want_samples contract: callers ask only when a row needs logprobs
             ids_h = np.asarray(top_ids)  # dynajit: disable=DJ201 -- same want_samples drain
@@ -1176,7 +1302,7 @@ class ModelRunner:
         if steps is None:
             steps = np.zeros(len(tokens), np.int32)
         args = [
-            self.params, self.kv_cache, jnp.asarray(tokens, jnp.int32),
+            jnp.asarray(tokens, jnp.int32),
             jnp.asarray(positions, jnp.int32),
             jnp.asarray(block_tables, jnp.int32),
             jnp.asarray(kv_lens, jnp.int32), jnp.asarray(active, bool),
@@ -1194,22 +1320,22 @@ class ModelRunner:
                 self._decode_fn_logits = self._build_decode(
                     with_logits=True)
             with compile_scope("decode"):
-                self.kv_cache, next_tokens, logits = \
-                    self._decode_fn_logits(*args)
+                next_tokens, logits = self._launch(
+                    self._decode_fn_logits, args)
             self.last_decode_logits = np.asarray(logits)  # dynajit: disable=DJ201 -- logits-processor escape hatch: host sampling needs the raw rows now
             self.last_decode_sample = (None, None, None)
         elif want_logprobs:
             if self._decode_fn_lp is None:
                 self._decode_fn_lp = self._build_decode(True)
             with compile_scope("decode"):
-                self.kv_cache, next_tokens, lp, top_ids, top_lps = \
-                    self._decode_fn_lp(*args)
+                next_tokens, lp, top_ids, top_lps = self._launch(
+                    self._decode_fn_lp, args)
             self.last_decode_sample = (np.asarray(lp), np.asarray(top_ids),  # dynajit: disable=DJ201 -- logprobs path: per-step sample data is the request's contract
                                        np.asarray(top_lps))  # dynajit: disable=DJ201 -- same logprobs drain
             self.last_decode_logits = None
         else:
             with compile_scope("decode"):
-                self.kv_cache, next_tokens = self._decode_fn(*args)
+                (next_tokens,) = self._launch(self._decode_fn, args)
             self.last_decode_sample = (None, None, None)
             self.last_decode_logits = None
         return np.asarray(next_tokens)  # dynajit: disable=DJ201 -- the per-token decode drain: [B] int32 is the step's designed readback
@@ -1260,6 +1386,8 @@ class ModelRunner:
         reference's scale_elastic_ep drains the same way,
         ref: components/src/dynamo/vllm/handlers.py:498 scale_elastic_ep).
         Must run on the scheduler thread (kv donation)."""
+        if self._hybrid:
+            self._check_hybrid(self.model_config, self.config, mesh)
         self.mesh = mesh
         if not self._attention_user_supplied:
             # The kernel choice depends on the mesh (Pallas flash-decode is
@@ -1333,6 +1461,13 @@ class ModelRunner:
         bundle is not addressable from one process (the MirroredRunner
         forces it so every host can read the full bundle locally)."""
         from ..ops.block_copy import gather_kv_blocks, gather_kv_blocks_q8
+
+        if self.model_config.has_recurrent_state:
+            # pages without the state that produced them resume nothing
+            raise RuntimeError(
+                f"{self.model_config.name} keeps recurrent state per slot; "
+                "KV pages alone cannot be transferred, offloaded or parked "
+                "(no state snapshot yet)")
 
         # Pad the id list to a power-of-two width (extra ids hit the
         # scratch page 0) so the gather jit compiles O(log n) shapes, not
@@ -1435,7 +1570,7 @@ class ModelRunner:
         stack per layer ([L, 1, ps, 1, rank+rope]), not per-head K/V."""
         cfg = self.model_config
         layout = {
-            "n_layers": cfg.n_layers,
+            "n_layers": len(cfg.kv_layers),
             "kv_heads": cfg.kv_cache_heads,
             "head_dim": cfg.kv_cache_head_dim,
             "kv_dims": cfg.kv_cache_kv_dims,
@@ -1468,7 +1603,8 @@ class ModelRunner:
             (0.0, 1.0, 0, 0),
         )
 
-    def prewarm(self, spec_widths: Optional[Sequence[int]] = None) -> None:
+    def prewarm(self, spec_widths: Optional[Sequence[int]] = None,
+                launches: bool = False, block: int = 1) -> None:
         """Compile the FULL predicted steady-state jit-key space before
         serving — exactly what the dynajit jit-surface registry (and the
         retrace canary) enumerate: decode (attr:_decode_fn, one key),
@@ -1482,15 +1618,38 @@ class ModelRunner:
         steady state never traces (docs/elasticity.md).
 
         `spec_widths` defaults to the DYNT_SPEC_* configuration the
-        scheduler will read: [DYNT_SPEC_MAX_K] when DYNT_SPEC_ENABLE."""
+        scheduler will read: [DYNT_SPEC_MAX_K] when DYNT_SPEC_ENABLE.
+
+        `launches` (the worker's `--prewarm full`) adds every other
+        launch the scheduler can make, derived from this runner's own
+        buckets, budget and table widths and from no list of shapes:
+        batched prefill by (rows, bucket), rows a power of two up to
+        `max_prefill_rows`, and the fused decode block of `block` steps
+        at every block-table width, fed from the host and from the block
+        before it (the pipelined second block takes its tokens on the
+        device: another jit key). A bucket no context of this runner
+        fills is then left out. Served traffic cannot be trusted to warm
+        these (PERF.md, PR 25 fault 4 and PR 30): which shape a crafted
+        group lands on depends on when its rows arrive. Nothing real is
+        touched: every row's pages are the scratch page, its state slot
+        is past the end, no decode row is active."""
         self.warmup()
         b = self.config.max_batch
         p = self.config.max_pages_per_seq
-        for bucket in self.config.prefill_buckets:
+        greedy = (0.0, 1.0, 0, 0)
+        buckets = self.config.prefill_buckets
+        if launches:
+            buckets = tuple(
+                bucket for i, bucket in enumerate(buckets)
+                if i == 0 or buckets[i - 1] < self.config.max_context)
+        for bucket in buckets:
             self.prefill_chunk(
                 np.zeros(bucket, np.int32), 0, np.zeros(p, np.int32),
-                min(bucket, self.config.max_context), (0.0, 1.0, 0, 0),
+                min(bucket, self.config.max_context), greedy,
+                **({"slot": b} if self._hybrid else {}),
             )
+        if launches:
+            self._prewarm_launches(buckets, block)
         if spec_widths is None:
             spec_widths = ([max(1, int(env("DYNT_SPEC_MAX_K")))]
                            if env("DYNT_SPEC_ENABLE") else [])
@@ -1502,3 +1661,39 @@ class ModelRunner:
                 np.ones(b, np.float32), np.ones(b, np.float32),
                 np.zeros(b, np.int32), np.zeros(b, np.uint32),
             )
+
+    def _prewarm_launches(self, buckets: Sequence[int], block: int) -> None:
+        """`prewarm(launches=True)`: batched prefill and the fused decode
+        block, through the host entry points the scheduler calls, so the
+        jit keys are the served ones."""
+        b, p = self.config.max_batch, self.config.max_pages_per_seq
+        greedy = (0.0, 1.0, 0, 0)
+        rows = 2
+        # rows are padded to a power of two: the limit's own ceiling
+        limit = 1 << (min(self.max_prefill_rows, b) - 1).bit_length()
+        while rows <= limit:
+            for bucket in buckets:
+                n = min(bucket, self.config.max_context - 1)
+                row = (np.zeros(n, np.int32), 0, np.zeros(p, np.int32), n,
+                       greedy, 0, *((b,) if self._hybrid else ()))
+                toks = self.prefill_chunk_batch([row] * rows)
+                # the scheduler picks a row's token on the device
+                # (`_prefill_batch`): one tiny program for each batch size
+                toks[0]
+            rows *= 2
+        if block > 1:
+            idle = (np.zeros(b, np.int32), np.zeros(b, np.int32))
+            sampling = (np.zeros(b, np.float32), np.ones(b, np.float32),
+                        np.zeros(b, np.int32), np.zeros(b, np.uint32))
+            width = bucket_table_width(1, p)
+            while True:
+                args = (np.zeros((b, width), np.int32), np.zeros(b, np.int32),
+                        np.zeros(b, bool), *sampling)
+                toks = self.decode_multi(*idle, *args, k=block,
+                                         return_device=True)
+                self.decode_multi(toks[-1], idle[1], *args, k=block,
+                                  return_device=True)
+                if width >= p:
+                    break
+                width = bucket_table_width(width + 1, p)
+        jax.block_until_ready(self.kv_cache)

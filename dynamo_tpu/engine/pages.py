@@ -34,7 +34,12 @@ class PagePool:
         num_pages: int,
         on_stored: Optional[Callable[[list[int], Optional[int]], None]] = None,
         on_removed: Optional[Callable[[list[int]], None]] = None,
+        prefix_cache: bool = True,
     ) -> None:
+        # False for a model with recurrent state: pages without the state
+        # that produced them are useless, so no prefix is matched, none is
+        # registered and no `stored` event is published for them.
+        self.prefix_cache = prefix_cache
         # page 0 reserved for padding scatter writes
         self._free: list[int] = list(range(num_pages - 1, 0, -1))
         self.num_pages = num_pages
@@ -66,6 +71,8 @@ class PagePool:
 
     def match_prefix(self, block_hashes: list[int]) -> int:
         matched = 0
+        if not self.prefix_cache:
+            return 0
         for h in block_hashes:
             if h in self._cached:
                 matched += 1
@@ -130,6 +137,8 @@ class PagePool:
                 self._refcount[h] = max(0, self._refcount[h] - 1)
         if computed_blocks is None:
             computed_blocks = len(block_hashes)
+        if not self.prefix_cache:
+            computed_blocks = alloc.cached_blocks  # register nothing
         new_hashes = block_hashes[alloc.cached_blocks : computed_blocks]
         stored: list[int] = []
         for i, h in enumerate(new_hashes):

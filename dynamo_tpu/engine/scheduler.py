@@ -200,6 +200,14 @@ class SchedulerStats:
     prefill_launches: int = 0
     decode_block_launches: int = 0
     reserved_page_ms: float = 0.0
+    # Beside it for a model with recurrent state
+    # (dynamo_ssm_state_slot_ms): the sum over committed steps of slots
+    # held (each holds one fixed-size state) x the step's wall ms. And
+    # what the dropless expert layer counted on the device
+    # (dynamo_moe_*_total; `ModelRunner.moe_stats`): by phase, tokens per
+    # held expert, dropped slots, experts touched, calls; None without one.
+    state_slot_ms: float = 0.0
+    moe_counts: Optional[np.ndarray] = None
     # Speculative decoding (dynamo_spec_* metrics; docs/metrics.md):
     # proposed/accepted count MINED drafts only (static-shape padding is
     # excluded), spec_ema is the mean acceptance EMA over the slots that
@@ -292,8 +300,19 @@ class InferenceScheduler:
             if kvbm is not None:
                 kvbm.notify_stored(hashes, parent)
 
+        # A model with recurrent state (Mamba-2 layers): its per-slot
+        # state lives in the runner, indexed by the slot a sequence holds
+        # here. A row that prefills from position 0 starts from zero
+        # state, so admission resets nothing; a prefix hit would skip
+        # tokens the state has to see, so none is ever taken; a
+        # preempted request resumes by recomputation (cooperative
+        # migrate), never from parked pages.
+        model_config = getattr(runner, "model_config", None)
+        self._recurrent = bool(getattr(model_config, "has_recurrent_state",
+                                       False))
         self.pool = PagePool(cfg.num_pages, on_stored=_stored,
-                             on_removed=on_removed)
+                             on_removed=on_removed,
+                             prefix_cache=not self._recurrent)
         if kvbm is not None:
             # Offload gathers ride the dispatch/drain gap (run_in_gap):
             # they execute while the decode block is busy on device, and
@@ -657,6 +676,16 @@ class InferenceScheduler:
         except (ValueError, TypeError, KeyError) as exc:
             emit(EngineOutput(finish_reason="error",
                               error=f"logits processors: {exc}"))
+            return None
+        if processors and self._recurrent:
+            # The host-sampling path regenerates the first token by
+            # running the last prompt token AGAIN (an idempotent KV
+            # rewrite); a recurrent state would absorb it twice.
+            emit(EngineOutput(
+                finish_reason="error",
+                error="logits processors (logit_bias, penalties, "
+                      "min_tokens, min_p, guided decoding) are not "
+                      "supported on a model with recurrent state"))
             return None
         seq = _Seq(
             request=request, emit=emit, block_hashes=block_hashes,
@@ -1191,6 +1220,7 @@ class InferenceScheduler:
         # Pages the step's sequences held while it ran: taken before the
         # reap returns the finished ones' (at most max_batch slots).
         reserved = self.reserved_pages()
+        held_slots = sum(s is not None for s in self._slots)
         with _section("sched.reap"):
             self._reap_finished()
         if prefill_tokens or decode_tokens or admitted or finalized:
@@ -1202,6 +1232,12 @@ class InferenceScheduler:
             self.stats.last_step_wall_ms = (time.monotonic() - start) * 1e3
             self.stats.reserved_page_ms += (
                 reserved * self.stats.last_step_wall_ms)
+            if self._recurrent:
+                self.stats.state_slot_ms += (
+                    held_slots * self.stats.last_step_wall_ms)
+            moe = getattr(self.runner, "moe_stats", None)
+            if moe is not None:
+                self.stats.moe_counts = moe()
             sample = self.steptrace.commit(self.stats.last_step_wall_ms)
             self.stats.device_ms_last_step = sample.device_ms
             self.stats.host_ms_last_step = sample.host_ms
@@ -1290,11 +1326,16 @@ class InferenceScheduler:
         first chunk."""
         work: list[tuple[_Seq, int]] = []
         spent = 0
+        # the runner pads each row to its smallest bucket: more rows
+        # than the budget holds of those wait for the next launch
+        max_rows = getattr(self.runner, "max_prefill_rows", len(self._slots))
         for seq in self._slots:
             if seq is None or seq.cancelled or seq.decode_ready:
                 continue
-            if budget - spent < min(self.page_size, budget):
-                break  # leftover budget too small to be worth a dispatch
+            if (budget - spent < min(self.page_size, budget)
+                    or len(work) >= max_rows):
+                break  # leftover budget too small to be worth a dispatch,
+                #        or the launch has its rows
             per = budget - spent
             if (seq.prefill_only and seq.on_prefill_chunk is not None
                     and self.disagg_chunk > 0):
@@ -1361,6 +1402,7 @@ class InferenceScheduler:
                 lora_idx=seq.lora_idx,
                 chunk_embeds=chunk_embeds,
                 return_device=deferred_readback,
+                **({"slot": seq.slot} if self._recurrent else {}),
             )
         if not deferred_readback:
             # Device-stream completion window of the whole prompt pass:
@@ -1404,7 +1446,8 @@ class InferenceScheduler:
                 rows.append((tokens, seq.prefill_pos, seq.block_table,
                              seq.prefill_pos + chunk,
                              (s.temperature, s.top_p, s.top_k, seq.seed),
-                             seq.lora_idx))
+                             seq.lora_idx,
+                             *((seq.slot,) if self._recurrent else ())))
         want_samples = any(
             final and seq.request.sampling.logprobs
             for final, (seq, _) in zip(finals, work))
@@ -2178,7 +2221,7 @@ class InferenceScheduler:
             params = None
             if (register_handoff is not None and seq.decode_ready
                     and seq.generated and not seq.processors
-                    and not seq.first_deferred):
+                    and not seq.first_deferred and not self._recurrent):
                 # KV present on device: positions 0..kv_len-2 (the same
                 # computed-page math as preempt-to-KVBM).
                 computed = seq.kv_len - 1

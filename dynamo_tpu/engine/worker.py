@@ -130,6 +130,43 @@ class KvEventBuffer:
             return out
 
 
+def recurrent_state_refusals(model_config, *, mode: str = "aggregated",
+                             kvbm: bool = False, spec: bool = False,
+                             weight_dtype: str = "model",
+                             kv_dtype: str = "model",
+                             devices: int = 1) -> None:
+    """What a model with recurrent state (Mamba-2 layers; a hybrid stack)
+    cannot be served with yet, refused at start with the flag and the
+    reason, never answered wrongly later: each of these paths moves or
+    reuses KV pages, or shards the step, and none of them carries the
+    per-slot state that the pages are useless without."""
+    cfg = model_config
+    if not cfg.is_hybrid:
+        return
+    from ..models.hybrid import hybrid_refusals
+
+    hybrid_refusals(cfg, weight_dtype, kv_dtype, devices)
+    if not cfg.has_recurrent_state:
+        return
+    what = f"{cfg.name} (layers {cfg.layer_pattern})"
+    if mode != "aggregated":
+        raise ValueError(
+            f"--mode {mode}: disaggregated prefill/decode hands over KV "
+            f"pages (engine/ici_transfer.py, llm/kv_transfer.py); {what} "
+            "keeps recurrent state per slot and no state snapshot travels "
+            "with them")
+    if kvbm:
+        raise ValueError(
+            f"--kvbm-host-blocks/--kvbm-disk-blocks: KVBM offloads and "
+            f"onboards KV pages by prefix hash; {what} cannot resume from "
+            "pages without the recurrent state behind them")
+    if spec:
+        raise ValueError(
+            f"DYNT_SPEC_ENABLE: speculative verification (engine/spec.py) "
+            f"rolls rejected positions back by length; the recurrent state "
+            f"of {what} cannot be rolled back")
+
+
 class TpuWorker:
     def __init__(
         self,
@@ -153,6 +190,8 @@ class TpuWorker:
         ici_bridge=None,  # engine.ici_transfer.IciKvBridge, shared in-proc
         model_path: Optional[str] = None,  # HF checkpoint dir (safetensors)
         step_channel=None,  # parallel.multihost.StepChannel (driver rank)
+        model_config=None,  # a preset cut to this chip's share (cut_config)
+        prewarm: Optional[str] = None,  # off | buckets | full (--prewarm)
     ) -> None:
         self.runtime = runtime
         self.instance_id = new_instance_id()
@@ -166,21 +205,29 @@ class TpuWorker:
 
             self.model_config = config_from_checkpoint(model_path)
         else:
-            self.model_config = get_config(model_name)
+            self.model_config = model_config or get_config(model_name)
         self.runner_config = runner_config or RunnerConfig()
         self.mesh = mesh if mesh is not None else make_mesh(
             mesh_config or MeshConfig())
+        from ..runtime.config import env as _cfg_env
+
+        recurrent_state_refusals(
+            self.model_config, mode=mode,
+            kvbm=bool(kvbm_config is not None and kvbm_config.enabled),
+            spec=bool(_cfg_env("DYNT_SPEC_ENABLE")),
+            weight_dtype=self.runner_config.weight_dtype,
+            kv_dtype=self.runner_config.kv_dtype,
+            devices=self.mesh.devices.size)
         self.ici_bridge = ici_bridge
         if ici_bridge is not None and mode == "prefill":
             ici_bridge.attach_prefill(self)
         self._warmup = warmup
+        self._prewarm = prewarm
         self.mode = mode
         self.transfers = PendingTransferTable()
         # Disagg chunked handoff (docs/disaggregation.md): live streaming
         # transfers keyed by request id, appended per prefill chunk on
         # the scheduler thread. 0 depth disables (serial handoff).
-        from ..runtime.config import env as _cfg_env
-
         self.disagg_pipeline = max(0, int(_cfg_env("DYNT_DISAGG_PIPELINE")
                                           or 0))
         self._stream_transfers: dict[str, StreamingTransfer] = {}
@@ -501,14 +548,20 @@ class TpuWorker:
                 asyncio.to_thread(_publish))
         if self._warmup:
             with self.coldstart.phase("compile"):
-                if _cfg_env("DYNT_PREWARM"):
-                    # Pre-warm the FULL predicted jit-key space (decode +
-                    # every prefill bucket + spec combos) so steady state
-                    # compiles zero keys — with a warm persistent cache
-                    # this is a disk replay, not a compile.
-                    await asyncio.to_thread(self.runner.prewarm)
-                else:
+                prewarm = self._prewarm or (
+                    "buckets" if _cfg_env("DYNT_PREWARM") else "off")
+                if prewarm == "off":
                     await asyncio.to_thread(self.runner.warmup)
+                else:
+                    # Pre-warm the FULL predicted jit-key space (decode +
+                    # every prefill bucket + spec combos; `full`: batched
+                    # prefill and the fused decode block as well) so
+                    # steady state compiles zero keys — with a warm
+                    # persistent cache this is a disk replay, not a
+                    # compile.
+                    await asyncio.to_thread(
+                        self.runner.prewarm, None, prewarm == "full",
+                        max(1, int(_cfg_env("DYNT_DECODE_BLOCK") or 1)))
             if _cfg_env("DYNT_COMPILE_CACHE_STORE"):
                 # Seed the shared cache with whatever this arrival DID
                 # compile — best-effort, off the critical path.
@@ -588,8 +641,9 @@ class TpuWorker:
                  paths["platform"], paths["device_kind"],
                  paths["device_ids"], paths["decode_attention"],
                  paths["spec_attention"], paths["weight_matmul"],
-                 (f" q4_layout={paths['q4_layout']}"
-                  if "q4_layout" in paths else ""), native)
+                 "".join(f" {slot}={paths[slot]}" for slot in (
+                     "q4_layout", "ssm_update", "expert_gmm")
+                     if slot in paths), native)
         ENGINE_INFO.labels(
             worker=f"{self.instance_id:x}", platform=paths["platform"],
             device_kind=paths["device_kind"],
@@ -1441,6 +1495,11 @@ class TpuWorker:
             ENGINE_LAUNCHES,
             ENGINE_TOKENS,
             KV_RESERVED_PAGE_MS,
+            MOE_DROPPED_SLOTS,
+            MOE_EXPERT_CALLS,
+            MOE_EXPERT_TOKENS,
+            MOE_EXPERTS_TOUCHED,
+            SSM_STATE_SLOT_MS,
         )
 
         worker = f"{self.instance_id:x}"
@@ -1456,6 +1515,23 @@ class TpuWorker:
             ENGINE_LAUNCHES.labels(worker=worker, kind=kind).set(count)
         KV_RESERVED_PAGE_MS.labels(worker=worker).set(
             stats.reserved_page_ms)
+        if stats.state_slot_ms:  # only a model with recurrent state
+            SSM_STATE_SLOT_MS.labels(worker=worker).set(stats.state_slot_ms)
+        if stats.moe_counts is not None:
+            from .model_runner import MOE_PHASES
+
+            lo, hi = self.model_config.held_experts
+            tokens = stats.moe_counts[:, :hi - lo].sum(axis=0)
+            for i, count in enumerate(tokens):
+                MOE_EXPERT_TOKENS.labels(
+                    worker=worker, expert=str(lo + i)).set(int(count))
+            MOE_DROPPED_SLOTS.labels(worker=worker).set(
+                int(stats.moe_counts[:, hi - lo].sum()))
+            for phase, row in zip(MOE_PHASES, stats.moe_counts):
+                MOE_EXPERTS_TOUCHED.labels(worker=worker, phase=phase).set(
+                    int(row[hi - lo + 1]))
+                MOE_EXPERT_CALLS.labels(worker=worker, phase=phase).set(
+                    int(row[hi - lo + 2]))
         for device in self.mesh.local_devices:
             mem = device.memory_stats() or {}
             for kind, key in (("in_use", "bytes_in_use"),
@@ -1998,6 +2074,30 @@ def build_arg_parser():
                              "int4 (W4A16, per-group scale/zero — "
                              "quarters it; dense llama/mistral/qwen "
                              "family, tp=1)")
+    parser.add_argument("--serve-layers", type=int, default=None,
+                        help="serve only the leading N layers of the "
+                             "preset: this worker is one stage of a "
+                             "pipeline")
+    parser.add_argument("--experts-held", default=None, metavar="LO:HI",
+                        help="the routed experts this chip holds of the "
+                             "published count (an expert-parallel share): "
+                             "the router keeps its width and its top-k, a "
+                             "token routed to an absent expert gets "
+                             "nothing from it")
+    parser.add_argument("--vocab-rows", type=int, default=None,
+                        help="hold only the leading N rows of the "
+                             "vocabulary: embedding, head, logits and "
+                             "sampling are over the slice")
+    parser.add_argument("--prewarm", default=None,
+                        choices=("off", "buckets", "full"),
+                        help="what to compile before serving (default: "
+                             "DYNT_PREWARM decides between off and "
+                             "buckets). off: decode and the smallest "
+                             "prefill; buckets: every single-row prefill "
+                             "bucket too; full: also every batched prefill "
+                             "(rows x bucket) and fused decode block "
+                             "(table width) the scheduler can launch, "
+                             "from the runner's own buckets and budget")
     parser.add_argument("--tp", type=int, default=1)
     parser.add_argument("--dp", type=int, default=1)
     parser.add_argument("--sp", type=int, default=1)
@@ -2085,6 +2185,32 @@ async def main(argv: Optional[list[str]] = None) -> None:
         raise SystemExit("--kv-dtype int8 supports aggregated serving "
                          "(incl. KVBM tiers); disaggregated prefill/"
                          "decode pools still require kv-dtype=model")
+    model_config = None
+    cut = (args.serve_layers, args.experts_held, args.vocab_rows)
+    has_cut = any(c is not None for c in cut)
+    if has_cut and (args.model_path or args.model_ref or args.multihost
+                    or args.mode == "comesh"):
+        raise SystemExit("--serve-layers/--experts-held/--vocab-rows cut a "
+                         "preset (--model) on one aggregated worker; they "
+                         "do not combine with --model-path, --model-ref, "
+                         "--multihost or --mode comesh")
+    if not (args.model_path or args.model_ref):
+        from ..models.config import cut_config
+
+        try:
+            preset = cut_config(get_config(args.model), *cut)
+            # before any process or connection is made: what this model
+            # cannot be served with, by flag
+            recurrent_state_refusals(
+                preset, mode=args.mode,
+                kvbm=args.kvbm_host_blocks > 0 or args.kvbm_disk_blocks > 0,
+                spec=bool(env("DYNT_SPEC_ENABLE")),
+                weight_dtype=args.weight_dtype, kv_dtype=args.kv_dtype,
+                devices=args.tp * args.dp * args.sp)
+        except (KeyError, ValueError) as exc:
+            raise SystemExit(f"dynamo_tpu.worker: {exc}")
+        if has_cut:
+            model_config = preset
     kvbm_config = None
     if args.kvbm_host_blocks > 0:
         from ..block_manager import KvbmConfig
@@ -2291,7 +2417,6 @@ async def main(argv: Optional[list[str]] = None) -> None:
         return
 
     mesh_config = MeshConfig(dp=args.dp, tp=args.tp, sp=args.sp)
-
     def build_worker(mesh) -> TpuWorker:
         return TpuWorker(
             runtime,
@@ -2319,6 +2444,8 @@ async def main(argv: Optional[list[str]] = None) -> None:
             weight_service=(args.weight_service
                             or _env("DYNT_WEIGHT_SERVICE") or None),
             weights_from_peer=args.weights_from_peer,
+            model_config=model_config,
+            prewarm=args.prewarm,
         )
 
     # Replica i takes local chips [i*k, (i+1)*k); one replica is the

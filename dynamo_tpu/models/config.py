@@ -76,6 +76,66 @@ class ModelConfig:
     rope_yarn_beta_fast: float = 32.0
     rope_yarn_beta_slow: float = 1.0
     rope_yarn_orig_max: int = 4096
+    # Hybrid stacks (nemotron_h): a layer is ONE mixer, its kind read off
+    # `layer_pattern` ("M" Mamba-2, "E" routed experts, "*" attention; ""
+    # = every layer is attention + MLP). Only "*" layers have KV pages
+    # (cache layer index != model layer index); "M" layers keep a
+    # fixed-size state per scheduler slot (models/hybrid.py).
+    layer_pattern: str = ""
+    use_rope: bool = True  # nemotron_h attention has no positional term
+    mlp_act: str = "swiglu"  # swiglu | relu2 (non-gated: down(relu(up x)^2))
+    shared_expert_hidden: int = 0  # 0 = n_shared_experts * expert width
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    conv_kernel: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
+    ssm_state_dtype: str = "float32"
+    # The chip's share of an expert-parallel deployment: (lo, hi) of the
+    # published experts held here. The router keeps its n_experts outputs
+    # and its top-k; a token routed to an absent expert gets nothing from
+    # it. None = all of them.
+    experts_held: Optional[tuple[int, int]] = None
+
+    @property
+    def is_hybrid(self) -> bool:
+        return bool(self.layer_pattern)
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """State that a prefix of KV pages cannot stand for: prefix reuse,
+        KV transfer, offload and speculation need a state snapshot too."""
+        return "M" in self.layer_pattern
+
+    def layer_kind(self, layer_idx: int) -> str:
+        return self.layer_pattern[layer_idx] if self.layer_pattern else "*"
+
+    @property
+    def kv_layers(self) -> tuple[int, ...]:
+        """Model layer index of each layer of the paged KV cache."""
+        return tuple(i for i in range(self.n_layers)
+                     if self.layer_kind(i) == "*")
+
+    @property
+    def state_layers(self) -> tuple[int, ...]:
+        return tuple(i for i in range(self.n_layers)
+                     if self.layer_kind(i) == "M")
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        return self.experts_held or (0, self.n_experts)
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def is_gptoss(self) -> bool:
@@ -91,6 +151,8 @@ class ModelConfig:
     def layer_is_moe(self, layer_idx: int) -> bool:
         """DeepSeek-style mixed stacks: layers below first_k_dense keep a
         dense MLP; the rest route through experts."""
+        if self.layer_pattern:
+            return self.layer_pattern[layer_idx] == "E"
         return self.n_experts > 0 and layer_idx >= self.first_k_dense
 
     @property
@@ -240,6 +302,35 @@ PRESETS: dict[str, ModelConfig] = {
         mla_kv_lora_rank=512, mla_q_lora_rank=1536, mla_rope_head_dim=64,
         mla_nope_head_dim=128, mla_v_head_dim=128,
     ),
+    # NVIDIA-Nemotron-3-Nano-30B-A3B (config.json, model_type nemotron_h)
+    # at its published sizes: 23 Mamba-2, 23 routed-expert and 6 attention
+    # layers, one mixer a layer. A worker serves a cut of it by flags
+    # (--serve-layers, --experts-held, --vocab-rows: `cut_config`).
+    "nemotron3-nano-30b-a3b": ModelConfig(
+        name="nemotron3-nano-30b-a3b", vocab_size=131072, hidden=2688,
+        n_layers=52,
+        layer_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+        n_q_heads=32, n_kv_heads=2, head_dim=128, mlp_hidden=1856,
+        rms_eps=1e-5, use_rope=False, tie_embeddings=False,
+        max_context=262144, mlp_act="relu2",
+        n_experts=128, n_experts_active=6, expert_mlp_hidden=1856,
+        n_shared_experts=1, shared_expert_hidden=3712,
+        moe_norm_topk=True, moe_routed_scale=2.5, moe_scoring="sigmoid",
+        mamba_heads=64, mamba_head_dim=64, ssm_groups=8, ssm_state=128,
+    ),
+    # CPU sibling: every layer kind at least twice, 8 experts top-2, a
+    # shared expert, 4 Mamba heads in 2 groups
+    "tiny-hybrid-test": ModelConfig(
+        name="tiny-hybrid-test", vocab_size=512, hidden=64, n_layers=7,
+        layer_pattern="MEM*EM*", n_q_heads=4, n_kv_heads=2, head_dim=16,
+        mlp_hidden=48, rms_eps=1e-5, use_rope=False, tie_embeddings=False,
+        max_context=256, mlp_act="relu2",
+        n_experts=8, n_experts_active=2, expert_mlp_hidden=48,
+        n_shared_experts=1, shared_expert_hidden=96,
+        moe_norm_topk=True, moe_routed_scale=2.5, moe_scoring="sigmoid",
+        mamba_heads=4, mamba_head_dim=16, ssm_groups=2, ssm_state=32,
+        ssm_chunk=16,
+    ),
     "tiny-mla-test": ModelConfig(
         name="tiny-mla-test", vocab_size=512, hidden=64, n_layers=2,
         n_q_heads=4, n_kv_heads=4, head_dim=24, mlp_hidden=128,
@@ -247,6 +338,43 @@ PRESETS: dict[str, ModelConfig] = {
         mla_v_head_dim=16,
     ),
 }
+
+
+def cut_config(config: ModelConfig, layers: Optional[int] = None,
+               experts: Optional[str] = None,
+               vocab_rows: Optional[int] = None) -> ModelConfig:
+    """The share of `config` one chip of a stated deployment serves: the
+    leading `layers`, the experts `lo:hi` of the published count, the
+    leading `vocab_rows` of the vocabulary (embedding, head, logits and
+    sampling are over the slice). No width changes."""
+    changes: dict = {}
+    if layers is not None:
+        if not 0 < layers <= config.n_layers:
+            raise ValueError(f"--serve-layers {layers}: {config.name} has "
+                             f"{config.n_layers} layers")
+        changes["n_layers"] = layers
+        if config.layer_pattern:
+            changes["layer_pattern"] = config.layer_pattern[:layers]
+    if experts is not None:
+        try:
+            lo, hi = (int(part) for part in experts.split(":"))
+        except ValueError:
+            raise ValueError(f"--experts-held {experts!r} is not lo:hi")
+        if not (config.is_hybrid and 0 <= lo < hi <= config.n_experts):
+            raise ValueError(
+                f"--experts-held {experts}: {config.name} has "
+                f"{config.n_experts} routed experts"
+                + ("" if config.is_hybrid else " and no dropless expert "
+                   "layer that can be told which it holds"))
+        changes["experts_held"] = (lo, hi)
+    if vocab_rows is not None:
+        if not 0 < vocab_rows <= config.vocab_size:
+            raise ValueError(f"--vocab-rows {vocab_rows}: {config.name} "
+                             f"has {config.vocab_size} rows")
+        if config.tie_embeddings and vocab_rows != config.vocab_size:
+            raise ValueError("--vocab-rows needs an untied output head")
+        changes["vocab_size"] = vocab_rows
+    return dataclasses.replace(config, **changes) if changes else config
 
 
 def get_config(name: str) -> ModelConfig:

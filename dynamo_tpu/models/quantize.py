@@ -26,11 +26,13 @@ from ..ops.q8_linear import QUANT_LEAVES, quantize_weight
 
 def check_quantizable(config, tp: int = 1, n_devices: int = 1,
                       dtype: str = "int8") -> None:
-    if config.is_mla or config.is_gptoss or config.n_experts:
+    if (config.is_mla or config.is_gptoss or config.n_experts
+            or config.is_hybrid):
         raise ValueError(
             f"weight_dtype='{dtype}' supports the dense "
             f"llama/mistral/qwen family in v1 ({config.name} is "
-            "MLA/MoE/gpt-oss)")
+            "MLA/MoE/gpt-oss/hybrid: no layout for latent, expert or "
+            "Mamba-2 matrices)")
     if tp != 1 or n_devices != 1:
         raise ValueError(
             f"weight_dtype='{dtype}' is single-device in v1 (the Pallas "

@@ -37,6 +37,13 @@ from .config import ModelConfig
 
 def param_axes(config: ModelConfig) -> dict:
     """Logical sharding axes per parameter (see parallel.shardings)."""
+    if config.is_hybrid:
+        from .hybrid import hybrid_layer_axes
+
+        return {"embed": ("vocab", "embed"), "final_norm": ("embed",),
+                "lm_head": ("embed", "vocab"),
+                "layers": [hybrid_layer_axes(config, i)
+                           for i in range(config.n_layers)]}
     layer = {
         "attn_norm": ("embed",),
         "wq": ("embed", "q_heads", "head_dim"),
@@ -118,6 +125,10 @@ def init_layer_params(k: jax.Array, config: ModelConfig,
     """One layer of `init_params` (same values for the same key). Its
     own entry point so a 7B engine can initialise and quantise a layer
     at a time instead of compiling, and holding, the whole bf16 tree."""
+    if config.is_hybrid:
+        from .hybrid import init_hybrid_layer
+
+        return init_hybrid_layer(k, config, layer_idx)
     dtype = jnp.dtype(config.dtype)
     h, hd = config.hidden, config.head_dim
     qh, kh, m = config.n_q_heads, config.n_kv_heads, config.mlp_hidden
@@ -227,9 +238,11 @@ def make_kv_cache(config: ModelConfig, num_pages: int, page_size: int,
     Standard attention: kv_dims=2 (K and V stacks), heads=n_kv_heads.
     MLA: kv_dims=1, heads=1, head_dim=latent_rank+rope_dim — the compressed
     latent cache. Page 0 is a reserved scratch page (block tables point
-    unused slots at it)."""
+    unused slots at it). A hybrid stack caches its attention layers only
+    (`config.kv_layers`)."""
     return jnp.zeros(
-        (config.n_layers, config.kv_cache_kv_dims, num_pages, page_size,
+        (len(config.kv_layers), config.kv_cache_kv_dims, num_pages,
+         page_size,
          config.kv_cache_heads, config.kv_cache_head_dim),
         dtype=jnp.dtype(dtype or config.dtype),
     )

@@ -344,6 +344,44 @@ KV_RESERVED_PAGE_MS = Gauge(
     "time-weighted mean of pages reserved by live sequences",
     ["worker"], registry=REGISTRY,
 )
+SSM_STATE_SLOT_MS = Gauge(
+    "dynamo_ssm_state_slot_ms",
+    "Model with recurrent state: sum over committed steps of (scheduler "
+    "slots held, each with one fixed-size Mamba-2 state) x the step's "
+    "wall ms. Over the growth of dynamo_step_part_ms_total{part=wall} "
+    "and --max-batch it is the share of the state cache that is live",
+    ["worker"], registry=REGISTRY,
+)
+MOE_EXPERT_TOKENS = Gauge(
+    "dynamo_moe_expert_tokens_total",
+    "Dropless expert layer: token-slots each held expert has computed "
+    "since start, summed over the expert layers (expert = its PUBLISHED "
+    "index; counted on the device, folded in when a launch's results "
+    "land). Busiest over mean is the load imbalance",
+    ["worker", "expert"], registry=REGISTRY,
+)
+MOE_DROPPED_SLOTS = Gauge(
+    "dynamo_moe_dropped_slots_total",
+    "Dropless expert layer: assignments to a held expert that fell "
+    "outside the sorted buffer and were not computed. 0 when sound",
+    ["worker"], registry=REGISTRY,
+)
+MOE_EXPERTS_TOUCHED = Gauge(
+    "dynamo_moe_experts_touched_total",
+    "Dropless expert layer: held experts that at least one token was "
+    "routed to, summed over expert-layer calls (phase = prefill | decode: "
+    "a prefill launch of thousands of tokens touches every expert, a "
+    "decode step of a token a row few). Over "
+    "dynamo_moe_expert_layer_calls_total it is the experts whose weights "
+    "a call had to read",
+    ["worker", "phase"], registry=REGISTRY,
+)
+MOE_EXPERT_CALLS = Gauge(
+    "dynamo_moe_expert_layer_calls_total",
+    "Dropless expert layer: calls of the layer (one for each expert "
+    "layer of each launch or fused decode step) since start",
+    ["worker", "phase"], registry=REGISTRY,
+)
 DEVICE_HBM_BYTES = Gauge(
     "dynamo_device_hbm_bytes",
     "Device memory of each chip in this worker's mesh as the backend "

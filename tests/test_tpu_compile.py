@@ -67,3 +67,46 @@ def test_pool_decode_kernel_compiles_for_v5e(one_chip, case):
         args.append(shape((LAYERS, 2, n_pages, PAGE, LANES), jnp.bfloat16))
     compiled = paged_decode_attention_pool.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# The hybrid stack's kernels (models/hybrid.py) at the published widths of
+# nemotron3-nano-30b-a3b: 64 Mamba heads of 64 over 8 groups, state 128;
+# experts of width 1856 over hidden 2688, 64 held.
+MAMBA = {"heads": 64, "p": 64, "groups": 8, "n": 128}
+
+
+def _shape(one_chip, dims, dtype):
+    return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+
+def test_the_ssm_decode_state_update_compiles_for_v5e(one_chip):
+    from dynamo_tpu.ops.ssm import ssm_state_update
+
+    slots, h, p, n = 128, MAMBA["heads"], MAMBA["p"], MAMBA["n"]
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    compiled = ssm_state_update.lower(
+        _shape(one_chip, (slots, h, p, n), f32),
+        _shape(one_chip, (slots, h), f32), _shape(one_chip, (h,), f32),
+        _shape(one_chip, (slots, h, p), bf16),
+        _shape(one_chip, (slots, h, n), bf16),
+        _shape(one_chip, (slots, h, n), bf16),
+        _shape(one_chip, (slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # in place: the state's buffer is the first output's (no 1.6 GB copy)
+    assert "input_output_alias" in text
+
+
+@pytest.mark.parametrize("rows", [768, 12288])  # a decode step; a prefill
+def test_the_expert_grouped_matmuls_compile_for_v5e(one_chip, rows):
+    from dynamo_tpu.ops.grouped_matmul import expert_gmm
+
+    held, hidden, width = 64, 2688, 1856
+    bf16 = jnp.bfloat16
+    sizes = _shape(one_chip, (held,), jnp.int32)
+    for k, transpose in ((hidden, True), (width, False)):
+        compiled = expert_gmm.lower(
+            _shape(one_chip, (rows, k), bf16),
+            _shape(one_chip, (held, width, hidden), bf16), sizes,
+            path="pallas", transpose_rhs=transpose).compile()
+        assert "tpu_custom_call" in compiled.as_text()
