@@ -453,6 +453,23 @@ class ModelRunner:
         self._embed_fns: dict[int, callable] = {}
         self._zero_embeds: dict[int, jax.Array] = {}  # per-bucket, mm only
         self.decode_steps = 0
+        # What prefill launches were made of (dynamo_engine_positions,
+        # dynamo_prefill_row_blocks_total): positions launched, padding
+        # included, and with int4 weights the row blocks the matmul ran
+        # (live) and was told to skip (padding only).
+        self.prefill_positions = 0
+        self.prefill_row_blocks = {"live": 0, "skipped": 0}
+
+    def _count_prefill(self, lengths: Sequence[int], rows: int,
+                       bucket: int) -> None:
+        """Host arithmetic on a launch's own lengths, no device sync."""
+        self.prefill_positions += rows * bucket
+        if self.config.weight_dtype == "int4":
+            from ..ops.q4_linear import count_row_blocks
+
+            live, skipped = count_row_blocks(lengths, rows, bucket)
+            self.prefill_row_blocks["live"] += live
+            self.prefill_row_blocks["skipped"] += skipped
 
     @staticmethod
     def _check_hybrid(cfg: ModelConfig, rc: RunnerConfig, mesh: Mesh):
@@ -1012,6 +1029,7 @@ class ModelRunner:
         if fn is None:
             fn = self._build_ring_prefill(bucket)
             self._ring_prefill_fns[bucket] = fn
+        self.prefill_positions += b * bucket  # the ring passes no block map
         tok = np.zeros((b, bucket), np.int32)
         pos = np.zeros((b, bucket), np.int32)
         valid = np.zeros((b, bucket), bool)
@@ -1139,6 +1157,7 @@ class ModelRunner:
         pos[0, :t] = np.arange(start_pos, start_pos + t)
         valid = np.zeros((1, bucket), bool)
         valid[0, :t] = True
+        self._count_prefill([t], 1, bucket)
         temp, top_p, top_k, seed = sampling
         args = [
             jnp.asarray(tok), jnp.asarray(pos),
@@ -1239,6 +1258,7 @@ class ModelRunner:
             last_idx[i] = t - 1
             temp[i], top_p[i], top_k[i], seeds[i] = sampling
             lora_rows[i] = lidx
+        self._count_prefill([len(r[0]) for r in rows], b, bucket)
         args = [
             jnp.asarray(tok), jnp.asarray(pos),
             jnp.asarray(tables), jnp.asarray(kv_lens), jnp.asarray(valid),

@@ -1486,19 +1486,22 @@ class TpuWorker:
                 request_shutdown("drain control verb")
 
     def _publish_engine_gauges(self) -> None:
-        """Tokens processed, programs launched, page-time reserved and
-        per-chip device memory (docs/metrics.md: dynamo_engine_tokens,
-        dynamo_engine_launches, dynamo_kv_reserved_page_ms,
-        dynamo_device_hbm_bytes)."""
+        """Tokens processed, programs launched and what they were
+        launched over, page-time reserved and per-chip device memory
+        (docs/metrics.md: dynamo_engine_tokens, dynamo_engine_launches,
+        dynamo_engine_positions, dynamo_prefill_row_blocks_total,
+        dynamo_kv_reserved_page_ms, dynamo_device_hbm_bytes)."""
         from ..runtime.metrics import (
             DEVICE_HBM_BYTES,
             ENGINE_LAUNCHES,
+            ENGINE_POSITIONS,
             ENGINE_TOKENS,
             KV_RESERVED_PAGE_MS,
             MOE_DROPPED_SLOTS,
             MOE_EXPERT_CALLS,
             MOE_EXPERT_TOKENS,
             MOE_EXPERTS_TOUCHED,
+            PREFILL_ROW_BLOCKS,
             SSM_STATE_SLOT_MS,
         )
 
@@ -1513,6 +1516,13 @@ class TpuWorker:
                 ("decode_block", stats.decode_block_launches),
                 ("decode_step", getattr(self.runner, "decode_steps", 0))):
             ENGINE_LAUNCHES.labels(worker=worker, kind=kind).set(count)
+        ENGINE_POSITIONS.labels(worker=worker, kind="prefill").set(
+            getattr(self.runner, "prefill_positions", 0))
+        blocks = getattr(self.runner, "prefill_row_blocks", {})
+        if any(blocks.values()):  # counted with int4 weights only
+            for state, count in blocks.items():
+                PREFILL_ROW_BLOCKS.labels(worker=worker, state=state).set(
+                    count)
         KV_RESERVED_PAGE_MS.labels(worker=worker).set(
             stats.reserved_page_ms)
         if stats.state_slot_ms:  # only a model with recurrent state

@@ -465,17 +465,21 @@ def _gptoss_attention_block(
     return kv_cache, attn
 
 
-def _mm(spec: str, x: jax.Array, w) -> jax.Array:
+def _mm(spec: str, x: jax.Array, w, rows=None) -> jax.Array:
     """Dense projection that transparently supports weight-only
     quantized leaves (models/quantize.py): int8 {"q8","qs"} routes
     through the Pallas W8A16 kernel (ops/q8_linear.py), packed int4
     {"q4","qs4","qz4"} through the W4A16 kernel (ops/q4_linear.py) —
-    either way the bf16 weight never materializes in HBM."""
+    either way the bf16 weight never materializes in HBM. `rows` (a
+    prefill launch's validity mask as ops.q4_linear.live_row_blocks
+    reduced it) lets the int4 kernel skip row blocks of padding; bf16
+    and int8 leaves ignore it."""
     if isinstance(w, dict):
         if "q4" in w:
             from ..ops.q4_linear import q4_einsum
 
-            return q4_einsum(spec, x, w["q4"], w["qs4"], w["qz4"])
+            return q4_einsum(spec, x, w["q4"], w["qs4"], w["qz4"],
+                             rows=rows)
         from ..ops.q8_linear import q8_einsum
 
         return q8_einsum(spec, x, w["q8"], w["qs"])
@@ -483,14 +487,14 @@ def _mm(spec: str, x: jax.Array, w) -> jax.Array:
 
 
 def _swiglu(x: jax.Array, p: dict, lora_layer: Optional[dict] = None,
-            lora_idx: Optional[jax.Array] = None) -> jax.Array:
-    gate = _mm("bth,hm->btm", x, p["w_gate"])
-    up = _mm("bth,hm->btm", x, p["w_up"])
+            lora_idx: Optional[jax.Array] = None, rows=None) -> jax.Array:
+    gate = _mm("bth,hm->btm", x, p["w_gate"], rows)
+    up = _mm("bth,hm->btm", x, p["w_up"], rows)
     if lora_layer is not None:
         gate = gate + _lora_delta(x, lora_layer["w_gate"], lora_idx)
         up = up + _lora_delta(x, lora_layer["w_up"], lora_idx)
     act = jax.nn.silu(gate) * up
-    down = _mm("btm,mh->bth", act, p["w_down"])
+    down = _mm("btm,mh->bth", act, p["w_down"], rows)
     if lora_layer is not None:
         down = down + _lora_delta(act, lora_layer["w_down"], lora_idx)
     return down
@@ -1477,8 +1481,15 @@ def forward(
 
     Returns (new_kv_cache, logits [B, T, vocab]).
     """
+    # Which row blocks of the launch hold a real position, reduced once
+    # here for every int4 projection below (None: the launch takes no map).
+    rows = None
     if valid is None:
         valid = jnp.ones(tokens.shape, dtype=bool)
+    else:
+        from ..ops.q4_linear import live_row_blocks
+
+        rows = live_row_blocks(valid)
     attention = attention_fn or paged_attention_xla
     b, t = tokens.shape
     x = params["embed"][tokens]  # [B, T, H]
@@ -1500,9 +1511,9 @@ def forward(
                          if "wq" in ll else None),
             )
         else:
-            q = _mm("bth,hqd->btqd", h, lp["wq"])
-            k = _mm("bth,hkd->btkd", h, lp["wk"])
-            v = _mm("bth,hkd->btkd", h, lp["wv"])
+            q = _mm("bth,hqd->btqd", h, lp["wq"], rows)
+            k = _mm("bth,hkd->btkd", h, lp["wk"], rows)
+            v = _mm("bth,hkd->btkd", h, lp["wv"], rows)
             if "wq" in ll:
                 q = q + _lora_delta(h, ll["wq"], lora_idx).reshape(q.shape)
                 k = k + _lora_delta(h, ll["wk"], lora_idx).reshape(k.shape)
@@ -1516,7 +1527,7 @@ def forward(
                                       block_tables, positions, valid)
             attn = attention(q, kv_cache, layer_idx, block_tables,
                              positions, kv_lens)
-        attn_out = _mm("btqd,qdh->bth", attn, lp["wo"])
+        attn_out = _mm("btqd,qdh->bth", attn, lp["wo"], rows)
         if "bo" in lp:
             attn_out = attn_out + lp["bo"]
         if "wo" in ll:
@@ -1529,8 +1540,9 @@ def forward(
         elif "router" in lp:  # per-layer: DeepSeek stacks mix dense + MoE
             x = x + _moe(h, lp, config)
         else:
-            x = x + _swiglu(h, lp, ll if "w_gate" in ll else None, lora_idx)
+            x = x + _swiglu(h, lp, ll if "w_gate" in ll else None, lora_idx,
+                            rows)
     x = rms_norm(x, params["final_norm"], config.rms_eps)
     head = params["embed"].T if config.tie_embeddings else params["lm_head"]
-    logits = _mm("bth,hv->btv", x, head).astype(jnp.float32)
+    logits = _mm("bth,hv->btv", x, head, rows).astype(jnp.float32)
     return kv_cache, logits

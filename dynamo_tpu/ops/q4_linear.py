@@ -32,6 +32,29 @@ v1 (half-block, uint8): within each group, byte row r holds code row r
   Unpacking a group yields two half-group tiles, so the kernel pays two
   half-contraction dots per group and a full [bm, bn] VPU pass per
   group for the scale/zero epilogue, all through an int32 widen.
+  What a prefill launch pays (v5e, 7B shapes, bf16 x; PERF.md PR 31):
+  the weight tile is unpacked again for every BLOCK_M = 256 rows of x,
+  and at M = 2048-4096 the kernel still runs the rows it is given at
+  135-183 TFLOP/s for the square, MLP and head projections (84-112 for
+  the narrow wk/wv), as fast as XLA's bf16 dot on the same shapes. What
+  it was given was the waste: a batched launch is pow2(rows) x
+  bucket(longest chunk) positions flattened to M rows, 52-68% of them
+  prompt tokens in the dense cell. So q4_matmul takes `live`, one int32
+  per row block (live_row_blocks, an `any` over the launch's validity
+  mask, handed over by scalar prefetch): a dead block skips the body,
+  stores a zero tile at its last k step and keeps the previous block's
+  weight tiles in VMEM; a dead (row, column) block costs 0.9-1.7 us
+  where a live one at K = 4096 costs 12-15 (nine live row blocks of
+  sixteen: 62-66% of the time of sixteen, 77% for wk/wv). BLOCK_M = 128
+  would skip a little more and measures 3-12% slower at every shape
+  (twice the unpacking). Only launches whose rows are MAP_MIN_ROW =
+  1024 positions or longer take the map: on the serving host a program
+  whose matmuls carry it spends 5.1-5.8 s in jaxpr -> MLIR lowering
+  where one without spends 1.7-2.1 (Mosaic's lowering of the prefetch
+  kernel inside a 32-layer module; alone, either kernel lowers in
+  0.06 s), and that is paid at every start, compile cache or not. Every
+  other caller (shorter buckets, no mask, every decode step) gets the
+  kernel without a map: the same program as before `live` existed.
 
 v2 (VPU-swizzled global half-split, int8): byte row r of the WHOLE
   packed array holds code row r (low nibble) and code row r + K/2
@@ -305,13 +328,32 @@ def repack_q4_leaf(leaf: dict, version: int | None = None) -> dict:
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
+# Rows of x one grid step multiplies, and so the unit in which a prefill
+# launch's padding is skipped (module docstring: what a prefill launch
+# pays).
+BLOCK_M = 256
+# The shortest row (positions) whose launch takes the map: a row of four
+# blocks can end in one to three blocks of padding of its own, a shorter
+# bucket only ever skips whole pad rows, and every program shape with
+# the map is paid for at start-up (module docstring).
+MAP_MIN_ROW = 4 * BLOCK_M
+
+
+def _live_and(live, cond):
+    return cond if live is None else live & cond
+
+
+def _when_live(live):
+    """pl.when(live); with no map, the body as it stands."""
+    return (lambda body: body()) if live is None else pl.when(live)
+
 
 def _q4_matmul_kernel(group, gk, x_ref, wp_ref, s_ref, z_ref, o_ref,
-                      acc_ref):
+                      acc_ref, live=None):
     k = pl.program_id(2)
     half = group // 2
 
-    @pl.when(k == 0)
+    @pl.when(_live_and(live, k == 0))
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
@@ -321,32 +363,34 @@ def _q4_matmul_kernel(group, gk, x_ref, wp_ref, s_ref, z_ref, o_ref,
     # offset folds into a [bm, 1] x [1, bn] outer product). The group
     # scale factors out of the block's contraction and lands on the
     # [bm, bn] partial product.
-    for g in range(gk):
-        # Mosaic has no u8->bf16 cast: widen once to i32, mask/shift,
-        # one convert per nibble tile.
-        w32 = wp_ref[g * half:(g + 1) * half].astype(jnp.int32)
-        u_lo = (w32 & 0xF).astype(x_ref.dtype)
-        u_hi = (w32 >> 4).astype(x_ref.dtype)
-        xg = x_ref[:, g * group:(g + 1) * group]
-        part = jax.lax.dot_general(
-            xg[:, :half], u_lo, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        part += jax.lax.dot_general(
-            xg[:, half:], u_hi, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        xsum = jnp.sum(xg.astype(jnp.float32), axis=1, keepdims=True)
-        z = z_ref[g].astype(jnp.float32)
-        s = s_ref[g].astype(jnp.float32)
-        acc_ref[:] += (part - xsum * z) * s
+    @_when_live(live)
+    def _accumulate():
+        for g in range(gk):
+            # Mosaic has no u8->bf16 cast: widen once to i32, mask/shift,
+            # one convert per nibble tile.
+            w32 = wp_ref[g * half:(g + 1) * half].astype(jnp.int32)
+            u_lo = (w32 & 0xF).astype(x_ref.dtype)
+            u_hi = (w32 >> 4).astype(x_ref.dtype)
+            xg = x_ref[:, g * group:(g + 1) * group]
+            part = jax.lax.dot_general(
+                xg[:, :half], u_lo, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            part += jax.lax.dot_general(
+                xg[:, half:], u_hi, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            xsum = jnp.sum(xg.astype(jnp.float32), axis=1, keepdims=True)
+            z = z_ref[g].astype(jnp.float32)
+            s = s_ref[g].astype(jnp.float32)
+            acc_ref[:] += (part - xsum * z) * s
 
-    @pl.when(k == pl.num_programs(2) - 1)
+    @pl.when(_live_and(live, k == pl.num_programs(2) - 1))
     def _emit():
         o_ref[:] = acc_ref[:].astype(o_ref.dtype)
 
 
 def _q4_matmul_kernel_v2(group, gh, x_lo_ref, x_hi_ref, wp_ref,
                          s_lo_ref, s_hi_ref, z_lo_ref, z_hi_ref, o_ref,
-                         acc_ref):
+                         acc_ref, live=None):
     """v2: the packed tile's nibbles ARE contracted order (low nibbles =
     `gh` whole groups of the low K-half, high nibbles = the matching
     groups of the high K-half), so each k-step is two full-width dots.
@@ -358,64 +402,129 @@ def _q4_matmul_kernel_v2(group, gh, x_lo_ref, x_hi_ref, wp_ref,
     k = pl.program_id(2)
     kb2 = group * gh
 
-    @pl.when(k == 0)
+    @pl.when(_live_and(live, k == 0))
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # [kb2, bn] int8, two signed nibbles per byte; the widen
-    # sign-extends, so arithmetic shifts recover both.
-    w32 = wp_ref[:].astype(jnp.int32)
-    lo = jnp.right_shift(jnp.left_shift(w32, 28), 28)
-    hi = jnp.right_shift(w32, 4)
-    bn = o_ref.shape[1]
-    for x_ref, s_ref, z_ref, codes in (
-            (x_lo_ref, s_lo_ref, z_lo_ref, lo),
-            (x_hi_ref, s_hi_ref, z_hi_ref, hi)):
-        x = x_ref[:]
-        s = s_ref[:].astype(jnp.float32)  # [gh, 1, bn]
-        z = z_ref[:].astype(jnp.float32)
-        # One convert per nibble tile; the scale broadcasts over each
-        # group's sublanes and lands on the weight tile, so the dot
-        # spans all `gh` groups at once.
-        sw = jnp.broadcast_to(s, (gh, group, bn)).reshape(kb2, bn)
-        u = codes.astype(x.dtype) * sw.astype(x.dtype)
-        part = jax.lax.dot_general(
-            x, u, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # Rank-1 zero-point for all gh groups as ONE small MXU dot:
-        # per-group colsums via a 0/1 block-diagonal mask, then
-        # [bm, gh] x [gh, bn] against the (z - 8) * s rows (the signed
-        # codes are u - 8, so the stored v1-convention zero row shifts
-        # by the same bias here instead of at pack time — repacks stay
-        # bit-exact).
-        rows = jax.lax.broadcasted_iota(jnp.int32, (kb2, gh), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (kb2, gh), 1)
-        gmask = (rows // group == cols).astype(x.dtype)
-        xsum = jax.lax.dot_general(
-            x, gmask, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        zs = ((z - 8.0) * s).reshape(gh, bn)
-        acc_ref[:] += part - jax.lax.dot_general(
-            xsum, zs, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    @_when_live(live)
+    def _accumulate():
+        # [kb2, bn] int8, two signed nibbles per byte; the widen
+        # sign-extends, so arithmetic shifts recover both.
+        w32 = wp_ref[:].astype(jnp.int32)
+        lo = jnp.right_shift(jnp.left_shift(w32, 28), 28)
+        hi = jnp.right_shift(w32, 4)
+        bn = o_ref.shape[1]
+        for x_ref, s_ref, z_ref, codes in (
+                (x_lo_ref, s_lo_ref, z_lo_ref, lo),
+                (x_hi_ref, s_hi_ref, z_hi_ref, hi)):
+            x = x_ref[:]
+            s = s_ref[:].astype(jnp.float32)  # [gh, 1, bn]
+            z = z_ref[:].astype(jnp.float32)
+            # One convert per nibble tile; the scale broadcasts over each
+            # group's sublanes and lands on the weight tile, so the dot
+            # spans all `gh` groups at once.
+            sw = jnp.broadcast_to(s, (gh, group, bn)).reshape(kb2, bn)
+            u = codes.astype(x.dtype) * sw.astype(x.dtype)
+            part = jax.lax.dot_general(
+                x, u, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            # Rank-1 zero-point for all gh groups as ONE small MXU dot:
+            # per-group colsums via a 0/1 block-diagonal mask, then
+            # [bm, gh] x [gh, bn] against the (z - 8) * s rows (the
+            # signed codes are u - 8, so the stored v1-convention zero
+            # row shifts by the same bias here instead of at pack time —
+            # repacks stay bit-exact).
+            rows = jax.lax.broadcasted_iota(jnp.int32, (kb2, gh), 0)
+            cols = jax.lax.broadcasted_iota(jnp.int32, (kb2, gh), 1)
+            gmask = (rows // group == cols).astype(x.dtype)
+            xsum = jax.lax.dot_general(
+                x, gmask, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            zs = ((z - 8.0) * s).reshape(gh, bn)
+            acc_ref[:] += part - jax.lax.dot_general(
+                xsum, zs, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(k == pl.num_programs(2) - 1)
+    @pl.when(_live_and(live, k == pl.num_programs(2) - 1))
     def _emit():
         o_ref[:] = acc_ref[:].astype(o_ref.dtype)
+
+
+def _live_rows_only(kernel):
+    """`kernel` behind a scalar-prefetched `live` map: a row block with
+    live[mi] == 0 runs none of the body (no unpack, no MXU pass) and
+    stores zeros at its last k step, so its output is defined and no
+    stale VMEM reaches the residual stream; a live block runs the body
+    as it is."""
+
+    def masked(live_ref, *refs):
+        o_ref = refs[-2]
+        live = live_ref[pl.program_id(0)] != 0
+        kernel(*refs, live=live)
+
+        @pl.when(jnp.logical_not(live)
+                 & (pl.program_id(2) == pl.num_programs(2) - 1))
+        def _zero():
+            o_ref[:] = jnp.zeros_like(o_ref)
+
+    return masked
+
+
+def _q4_call(kernel, grid, in_maps, in_blocks, out_block, out_shape,
+             live, interpret):
+    """The pallas_call both layouts share. `in_maps` take the grid step
+    (mi, ni, ki). With a `live` map every step of a dead row block asks
+    for the tiles of the block's LAST step in place of its own, so the
+    pipeline finds the weight, scale and zero tiles in VMEM already
+    (the row block before it ended on them) and fetches one x tile a
+    dead block, not one a step."""
+    _, nn, nk = grid
+
+    def held(fn):
+        if live is None:
+            return fn
+
+        def index(mi, ni, ki, live_ref):
+            dead = live_ref[mi] == 0
+            return fn(mi, jnp.where(dead, nn - 1, ni),
+                      jnp.where(dead, nk - 1, ki))
+
+        return index
+
+    in_specs = [pl.BlockSpec(blk, held(fn))
+                for blk, fn in zip(in_blocks, in_maps)]
+    out_specs = pl.BlockSpec(out_block, lambda mi, ni, ki, *_: (mi, ni))
+    scratch = [pltpu.VMEM(out_block, jnp.float32)]
+    if live is None:
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch,
+            compiler_params=_COMPILER_PARAMS, interpret=interpret)
+    return functools.partial(pl.pallas_call(
+        _live_rows_only(kernel),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape, compiler_params=_COMPILER_PARAMS,
+        interpret=interpret), live)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "gk", "interpret"))
 def q4_matmul(x: jax.Array, q4: jax.Array, scale: jax.Array,
-              zero: jax.Array, bm: int = 256, bn: int = 1024,
-              gk: int = 0, interpret: bool = False) -> jax.Array:
+              zero: jax.Array, bm: int = BLOCK_M, bn: int = 1024,
+              gk: int = 0, interpret: bool = False,
+              live: jax.Array | None = None) -> jax.Array:
     """x [M, K] (bf16/f32) @ packed-int4 [K//2, N] with per-group
     scale/zero [K//group, N] -> [M, N] in x.dtype. The group (and the
     kernel's k-block) is inferred from the scale shape; the kernel
     variant is dispatched from the packed dtype (uint8 = v1 half-block,
     int8 = v2 swizzled — see module docstring). `gk` overrides the
     groups contracted per k-step (0 = auto; the ablation harness sweeps
-    it)."""
+    it). `live` (int32 [ceil(M / bm)], live_row_blocks) says which row
+    blocks hold a real position: the others do no work and come back
+    exactly zero, the live ones are computed as without it, bit for bit.
+    None is the kernel without a map."""
     m, k2 = x.shape[0], q4.shape[0]
     k = k2 * 2
     n = q4.shape[1]
@@ -441,6 +550,10 @@ def q4_matmul(x: jax.Array, q4: jax.Array, scale: jax.Array,
     version = pack_version(q4)
     bm = min(bm, max(16, 1 << max(0, m - 1).bit_length()))
     mp = -(-m // bm) * bm
+    if live is not None and live.shape != (mp // bm,):
+        raise ValueError(
+            f"q4_matmul: live must name every row block "
+            f"(live {live.shape}, {mp // bm} blocks of {bm} rows)")
     if mp != m:
         x = jnp.pad(x, ((0, mp - m), (0, 0)))
     b = min(bn, n)
@@ -476,47 +589,32 @@ def q4_matmul(x: jax.Array, q4: jax.Array, scale: jax.Array,
     # scale/zero block spans full (singleton) sublane dimensions.
     s3 = scale.reshape(k // group, 1, n)
     z3 = zero.reshape(k // group, 1, n)
+    out_shape = jax.ShapeDtypeStruct((mp, n), x.dtype)
     if version == PACK_V2:
         gh = gk // 2
         kb2 = group * gh  # packed byte rows (= codes per nibble tile)
         nk = (k // 2) // kb2
-        out = pl.pallas_call(
+        lo = lambda mi, ni, ki: (ki, 0, ni)  # noqa: E731
+        hi = lambda mi, ni, ki: (ki + nk, 0, ni)  # noqa: E731
+        out = _q4_call(
             functools.partial(_q4_matmul_kernel_v2, group, gh),
-            grid=(mp // bm, n // bn, nk),
-            in_specs=[
-                pl.BlockSpec((bm, kb2), lambda mi, ni, ki: (mi, ki)),
-                pl.BlockSpec((bm, kb2),
-                             lambda mi, ni, ki, nk=nk: (mi, ki + nk)),
-                pl.BlockSpec((kb2, bn), lambda mi, ni, ki: (ki, ni)),
-                pl.BlockSpec((gh, 1, bn), lambda mi, ni, ki: (ki, 0, ni)),
-                pl.BlockSpec((gh, 1, bn),
-                             lambda mi, ni, ki, nk=nk: (ki + nk, 0, ni)),
-                pl.BlockSpec((gh, 1, bn), lambda mi, ni, ki: (ki, 0, ni)),
-                pl.BlockSpec((gh, 1, bn),
-                             lambda mi, ni, ki, nk=nk: (ki + nk, 0, ni)),
-            ],
-            out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
-            out_shape=jax.ShapeDtypeStruct((mp, n), x.dtype),
-            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            compiler_params=_COMPILER_PARAMS,
-            interpret=interpret,
+            (mp // bm, n // bn, nk),
+            [lambda mi, ni, ki: (mi, ki),
+             lambda mi, ni, ki: (mi, ki + nk),
+             lambda mi, ni, ki: (ki, ni), lo, hi, lo, hi],
+            [(bm, kb2), (bm, kb2), (kb2, bn)] + [(gh, 1, bn)] * 4,
+            (bm, bn), out_shape, live, interpret,
         )(x, x, q4, s3, s3, z3, z3)
         return out[:m]
-    out = pl.pallas_call(
+    per_group = lambda mi, ni, ki: (ki, 0, ni)  # noqa: E731
+    out = _q4_call(
         functools.partial(_q4_matmul_kernel, group, gk),
-        grid=(mp // bm, n // bn, k // (group * gk)),
-        in_specs=[
-            pl.BlockSpec((bm, group * gk), lambda mi, ni, ki: (mi, ki)),
-            pl.BlockSpec((group * gk // 2, bn),
-                         lambda mi, ni, ki: (ki, ni)),
-            pl.BlockSpec((gk, 1, bn), lambda mi, ni, ki: (ki, 0, ni)),
-            pl.BlockSpec((gk, 1, bn), lambda mi, ni, ki: (ki, 0, ni)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
-        out_shape=jax.ShapeDtypeStruct((mp, n), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
+        (mp // bm, n // bn, k // (group * gk)),
+        [lambda mi, ni, ki: (mi, ki), lambda mi, ni, ki: (ki, ni),
+         per_group, per_group],
+        [(bm, group * gk), (group * gk // 2, bn), (gk, 1, bn),
+         (gk, 1, bn)],
+        (bm, bn), out_shape, live, interpret,
     )(x, q4, s3, z3)
     return out[:m]
 
@@ -539,23 +637,61 @@ def dequantize_q4(q4: jax.Array, scale: jax.Array,
 
 
 def q4_matmul_ref(x: jax.Array, q4: jax.Array, scale: jax.Array,
-                  zero: jax.Array) -> jax.Array:
+                  zero: jax.Array,
+                  live: jax.Array | None = None) -> jax.Array:
     """XLA reference: materializes the dequantized weight (correctness
-    path, not the perf path). Layout-agnostic via dequantize_q4."""
+    path, not the perf path). Layout-agnostic via dequantize_q4. With
+    `live`, the rows of a dead block come back zero as the kernel's do
+    (nothing is saved here)."""
     w = dequantize_q4(q4, scale, zero)
     acc = jax.lax.dot_general(
         x, w.astype(x.dtype), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+    if live is not None:
+        rows = jnp.repeat(live != 0, BLOCK_M)[:x.shape[0], None]
+        acc = jnp.where(rows, acc, 0.0)
     return acc.astype(x.dtype)
 
 
+def live_row_blocks(valid: jax.Array) -> jax.Array | None:
+    """Which BLOCK_M-row blocks of a launch's flattened [B*T] positions
+    hold a real position: int32 [ceil(B*T / BLOCK_M)], the `live` of
+    q4_matmul, reduced from the [B, T] validity mask. None when rows
+    are shorter than MAP_MIN_ROW (every decode step, the short prefill
+    buckets): the call keeps the kernel without a map."""
+    if valid.shape[-1] < MAP_MIN_ROW:
+        return None
+    m = valid.size
+    flat = jnp.pad(valid.reshape(m), (0, -m % BLOCK_M))
+    return flat.reshape(-1, BLOCK_M).any(axis=1).astype(jnp.int32)
+
+
+def count_row_blocks(lengths, rows: int, bucket: int) -> tuple[int, int]:
+    """(live, skipped) row blocks of a launch of `rows` x `bucket`
+    positions whose row i holds `lengths[i]` real positions from its
+    start (rows past the list are padding): live_row_blocks's answer
+    from the host's own numbers, for the engine's counters. A launch
+    without a map runs every block it has."""
+    total = -(-rows * bucket // BLOCK_M)
+    if bucket < MAP_MIN_ROW:
+        return total, 0
+    live = set()
+    for i, n in enumerate(lengths):
+        if n > 0:
+            live.update(range(i * bucket // BLOCK_M,
+                              (i * bucket + n - 1) // BLOCK_M + 1))
+    return len(live), total - len(live)
+
+
 def q4_einsum(spec: str, x: jax.Array, q4: jax.Array, qs4: jax.Array,
-              qz4: jax.Array) -> jax.Array:
+              qz4: jax.Array, rows: jax.Array | None = None) -> jax.Array:
     """Quantized drop-in for the transformer's dense einsums (mirror of
     q8_linear.q8_einsum over the packed-int4 leaves). The pack-layout
     version rides the q4 dtype through every reshape, so all five
     projection specs (including the flat wo) dispatch the right kernel
-    variant without extra plumbing."""
+    variant without extra plumbing. `rows` is the launch's validity
+    mask as live_row_blocks reduced it, once a forward: the matmul does
+    no work for row blocks of padding and returns zeros there."""
     if spec in ("bth,hm->btm", "btm,mh->bth", "bth,hv->btv"):
         b, t, k = x.shape
         out_shape = (b, t, q4.shape[1])
@@ -583,8 +719,8 @@ def q4_einsum(spec: str, x: jax.Array, q4: jax.Array, qs4: jax.Array,
         raise ValueError(f"q4_einsum does not support spec {spec!r}")
     path = kernel_path("DYNT_Q4_MATMUL")
     if path == "xla":
-        out = q4_matmul_ref(x2, w2, qs4, qz4)
+        out = q4_matmul_ref(x2, w2, qs4, qz4, live=rows)
     else:
         out = q4_matmul(x2, w2, qs4, qz4,
-                        interpret=path == "interpret")
+                        interpret=path == "interpret", live=rows)
     return out.reshape(out_shape)

@@ -336,6 +336,23 @@ ENGINE_LAUNCHES = Gauge(
     "and useful rows per decode step",
     ["worker", "kind"], registry=REGISTRY,
 )
+ENGINE_POSITIONS = Gauge(
+    "dynamo_engine_positions",
+    "Positions this worker's prefill programs were launched over since "
+    "start, padding included (kind=prefill): rows padded to a power of "
+    "two x the bucket of the longest chunk. Growth of "
+    "dynamo_engine_tokens{kind=prefill} over its growth is the share "
+    "of launched positions that held a prompt token",
+    ["worker", "kind"], registry=REGISTRY,
+)
+PREFILL_ROW_BLOCKS = Gauge(
+    "dynamo_prefill_row_blocks_total",
+    "int4 weights: 256-row blocks of launched prefill positions since "
+    "start, by state: live (the matmul ran it) | skipped (padding only, "
+    "in a launch of 1024-position rows or longer: the matmul is told so "
+    "and does no work for it)",
+    ["worker", "state"], registry=REGISTRY,
+)
 KV_RESERVED_PAGE_MS = Gauge(
     "dynamo_kv_reserved_page_ms",
     "Sum over committed steps of (pages allocated to sequences that "

@@ -493,6 +493,203 @@ class TestQ4VariantParity:
                       interpret=True)
 
 
+def _prefetch_operands(jaxpr) -> list[int]:
+    """Scalar-prefetch operand counts of every pallas_call under a
+    jaxpr, nested calls included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["grid_mapping"].num_index_operands)
+        for sub in eqn.params.values():
+            for inner in (sub if isinstance(sub, (list, tuple)) else [sub]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    found += _prefetch_operands(inner)
+    return found
+
+
+def _mask(rows, bucket, lengths):
+    valid = np.zeros((rows, bucket), bool)
+    for i, n in enumerate(lengths):
+        valid[i, :n] = True
+    return valid
+
+
+def _block_map(valid):
+    """The mask's `any` over each 256-row block of its flattened
+    positions, in numpy."""
+    return valid.reshape(-1, 256).any(axis=1).astype(np.int32).tolist()
+
+
+# The dense cell's launches and scaled-down ones (256-row blocks):
+# (rows, bucket, lengths, live blocks). A bucket under 1024 takes no map
+# through live_row_blocks; the kernel is still handed one here.
+LIVE_ROW_CASES = {
+    "all-live": (2, 512, [512, 512], [1, 1, 1, 1]),
+    "736-of-1024": (1, 1024, [736], [1, 1, 1, 0]),
+    "remainder-beside-long-row": (2, 1024, [736, 160],
+                                  [1, 1, 1, 0, 1, 0, 0, 0]),
+    "three-rows-padded-to-four": (4, 1024, [736, 736, 576],
+                                  [1, 1, 1, 0, 1, 1, 1, 0,
+                                   1, 1, 1, 0, 0, 0, 0, 0]),
+    "short-rows-padded-to-four": (4, 512, [368, 368, 288],
+                                  [1, 1, 1, 1, 1, 1, 0, 0]),
+    "ends-on-a-block": (2, 1024, [256, 1024], [1, 0, 0, 0, 1, 1, 1, 1]),
+}
+
+
+class TestQ4LiveRows:
+    """q4_matmul told which row blocks hold a real position (PR 31): the
+    others do no work and come back zero, the live ones are computed as
+    without the map, bit for bit."""
+
+    @pytest.mark.parametrize("version", [1, 2])
+    @pytest.mark.parametrize("case", sorted(LIVE_ROW_CASES))
+    def test_dead_blocks_are_zero_and_live_rows_unchanged(self, case,
+                                                          version):
+        import jax.numpy as jnp
+
+        from dynamo_tpu.ops.q4_linear import (
+            BLOCK_M,
+            MAP_MIN_ROW,
+            count_row_blocks,
+            live_row_blocks,
+            q4_matmul,
+            quantize_weight_q4,
+        )
+
+        rows, bucket, lengths, want = LIVE_ROW_CASES[case]
+        valid = _mask(rows, bucket, lengths)
+        assert _block_map(valid) == want
+        live = live_row_blocks(jnp.asarray(valid))
+        if bucket < MAP_MIN_ROW:  # the launch itself would take no map
+            assert live is None
+            assert count_row_blocks(lengths, rows, bucket) == (
+                len(want), 0)
+            live = jnp.asarray(want, jnp.int32)
+        else:
+            assert np.asarray(live).tolist() == want
+            assert count_row_blocks(lengths, rows, bucket) == (
+                sum(want), len(want) - sum(want))
+        rng = np.random.default_rng(3)
+        k, n = 512, 256
+        # bf16 inputs, as served (float32 accumulation inside)
+        x = jnp.asarray(rng.standard_normal((rows * bucket, k)),
+                        jnp.bfloat16)
+        w = jnp.asarray(rng.standard_normal((k, n)), jnp.float32)
+        qw = quantize_weight_q4(w, 1, version=version)
+        plain = np.asarray(q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"],
+                                     interpret=True), np.float32)
+        keep = np.repeat(np.asarray(want, bool), BLOCK_M)
+        # what a dead block holds reaches nothing
+        x = jnp.where(keep[:, None], x, jnp.nan)
+        out = np.asarray(q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"],
+                                   interpret=True, live=live), np.float32)
+        assert np.array_equal(out[keep], plain[keep])
+        assert not out[~keep].any()
+        assert not np.isnan(out).any()
+
+    def test_short_rows_or_no_mask_are_the_kernel_without_a_map(self):
+        """The decode programs do not change: a call whose rows are
+        under 1024 positions, or without a mask, has no prefetch
+        operand; a launch of longer rows has one."""
+        import jax
+        import jax.numpy as jnp
+
+        from dynamo_tpu.ops.q4_linear import (
+            live_row_blocks,
+            q4_matmul,
+            quantize_weight_q4,
+        )
+
+        for shape in ((32, 1), (1, 256), (4, 64), (4, 512)):
+            assert live_row_blocks(jnp.ones(shape, bool)) is None
+        rng = np.random.default_rng(4)
+        w = jnp.asarray(rng.standard_normal((512, 256)), jnp.float32)
+        qw = quantize_weight_q4(w, 1)
+
+        def call(x, live=None):
+            return q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"],
+                             interpret=True, live=live)
+
+        x = jnp.zeros((1024, 512), jnp.float32)
+        assert _prefetch_operands(jax.make_jaxpr(call)(x).jaxpr) == [0]
+        assert _prefetch_operands(
+            jax.make_jaxpr(call)(x[:32]).jaxpr) == [0]
+        live = live_row_blocks(jnp.ones((1, 1024), bool))
+        assert _prefetch_operands(
+            jax.make_jaxpr(call)(x, live).jaxpr) == [1]
+        with pytest.raises(ValueError, match="every row block"):
+            call(x, live[:3])
+
+    @pytest.mark.parametrize("version", [1, 2])
+    @pytest.mark.parametrize("path", ["xla", "pallas"])
+    def test_einsum_specs_with_rows_including_flat_wo(self, path, version,
+                                                      monkeypatch):
+        """Every projection spec hands the block map on, on the
+        reference path and through the kernel alike: live rows as
+        without it, the padded row zero."""
+        import jax.numpy as jnp
+
+        from dynamo_tpu.ops.q4_linear import (
+            live_row_blocks,
+            q4_einsum,
+            quantize_weight_q4,
+        )
+
+        monkeypatch.setenv("DYNT_Q4_MATMUL", path)
+        rng = np.random.default_rng(5)
+        b, t, h, qh, hd, mdim = 2, 1024, 512, 4, 128, 1024
+        valid = _mask(b, t, [700])  # the second row is padding
+        rows = live_row_blocks(jnp.asarray(valid))
+        x = jnp.asarray(rng.standard_normal((b, t, h)), jnp.bfloat16)
+        xo = jnp.asarray(rng.standard_normal((b, t, qh, hd)), jnp.bfloat16)
+        for spec, lhs, wshape, nc in [
+            ("bth,hm->btm", x, (h, mdim), 1),
+            ("btm,mh->bth", x, (h, 256), 1),
+            ("bth,hqd->btqd", x, (h, qh, hd), 1),
+            ("bth,hkd->btkd", x, (h, 2, hd), 1),
+            ("bth,hv->btv", x, (h, 1024), 1),
+            ("btqd,qdh->bth", xo, (qh, hd, h), 2),
+        ]:
+            w = jnp.asarray(rng.standard_normal(wshape), jnp.float32)
+            qw = quantize_weight_q4(w, nc, version=version)
+            plain = np.asarray(q4_einsum(spec, lhs, qw["q4"], qw["qs4"],
+                                         qw["qz4"]), np.float32)
+            out = np.asarray(q4_einsum(spec, lhs, qw["q4"], qw["qs4"],
+                                       qw["qz4"], rows=rows), np.float32)
+            assert np.array_equal(out[0, :768], plain[0, :768]), spec
+            assert not out[0, 768:].any() and not out[1].any(), spec
+
+    @pytest.mark.parametrize("lengths,rows,bucket,want", [
+        ([736, 736, 576], 4, 1024, (9, 7)),  # the cell's commonest launch
+        ([736], 1, 1024, (3, 1)),
+        ([160], 1, 1024, (1, 3)),
+        ([736, 160], 2, 1024, (4, 4)),
+        ([1500, 700], 2, 2048, (9, 7)),
+        # rows under 1024 positions: no map, every block is run
+        ([40], 1, 64, (1, 0)),
+        ([32] * 32, 32, 32, (4, 0)),
+        ([100, 100, 100], 4, 128, (2, 0)),
+        ([368, 368, 288], 4, 512, (8, 0)),
+    ])
+    def test_the_hosts_count_is_the_maps(self, lengths, rows, bucket,
+                                         want):
+        import jax.numpy as jnp
+
+        from dynamo_tpu.ops.q4_linear import (
+            count_row_blocks,
+            live_row_blocks,
+        )
+
+        assert count_row_blocks(lengths, rows, bucket) == want
+        live = live_row_blocks(jnp.asarray(_mask(rows, bucket, lengths)))
+        if live is None:
+            assert bucket < 1024 and want[1] == 0
+        else:
+            assert (int(live.sum()), int((live == 0).sum())) == want
+
+
 class TestRunnerInt4Weights:
     def _runner(self, weight_dtype):
         from dynamo_tpu.engine.model_runner import ModelRunner, RunnerConfig
@@ -575,6 +772,87 @@ class TestRunnerInt4Weights:
         # dequant can flip a near-tie; demand near-total agreement.
         same = sum(a == b for a, b in zip(outs["int4"], outs["oracle"]))
         assert same >= len(outs["oracle"]) - 1, outs
+
+    def test_batched_prefill_skips_padding_and_matches_lone_prefills(
+            self, monkeypatch):
+        """Three rows of unlike lengths, padded to four, through the
+        kernel with its block map: the tokens and the KV pages three lone
+        prefills give (prefill_chunk_batch's promise), with eight row
+        blocks run and eight skipped; the decode step after it is the
+        program it was."""
+        import jax
+        import jax.numpy as jnp
+
+        from dynamo_tpu.engine.model_runner import ModelRunner, RunnerConfig
+        from dynamo_tpu.models.transformer import forward
+        from dynamo_tpu.parallel import MeshConfig, make_mesh
+
+        monkeypatch.setenv("DYNT_Q4_MATMUL", "pallas")  # the interpreter
+
+        def runner():
+            return ModelRunner(
+                get_config("tiny-test"),
+                RunnerConfig(page_size=16, num_pages=128, max_batch=4,
+                             max_pages_per_seq=64,
+                             prefill_buckets=(64, 1024),
+                             weight_dtype="int4"),
+                make_mesh(MeshConfig()), seed=0)
+
+        batched, lone = runner(), runner()
+        assert batched.kernel_paths()["weight_matmul"] == "interpret"
+        rng = np.random.default_rng(7)
+        lengths = [600, 40, 1024]
+        prompts = [rng.integers(1, 500, n).astype(np.int32)
+                   for n in lengths]
+        tables, first = [], 1
+        for n in lengths:
+            pages = -(-n // 16)
+            table = np.zeros(64, np.int32)
+            table[:pages] = np.arange(first, first + pages)
+            tables.append(table)
+            first += pages
+        rows = [(p, 0, t, len(p), (0.0, 1.0, 0, i), 0)
+                for i, (p, t) in enumerate(zip(prompts, tables))]
+        tokens = np.asarray(batched.prefill_chunk_batch(rows))[:3]
+        assert batched.prefill_positions == 4 * 1024
+        assert batched.prefill_row_blocks == {"live": 8, "skipped": 8}
+        alone = [lone.prefill_chunk(*row[:5]) for row in rows]
+        assert tokens.tolist() == alone
+        # page 0 is the scratch sink padded positions write to
+        assert np.array_equal(np.asarray(batched.kv_cache)[:, :, 1:],
+                              np.asarray(lone.kv_cache)[:, :, 1:])
+        # alone, 600 of 1024 skips one block; 40 of 64 takes no map
+        assert lone.prefill_row_blocks == {"live": 3 + 1 + 4, "skipped": 1}
+
+        def decode(r, tok, table, pos):
+            return int(r.decode(
+                np.array([tok], np.int32), np.array([pos], np.int32),
+                table[None, :], np.array([pos + 1], np.int32),
+                np.array([True]), np.zeros(1, np.float32),
+                np.ones(1, np.float32), np.zeros(1, np.int32),
+                np.zeros(1, np.uint32), np.array([0], np.int32))[0])
+
+        assert decode(batched, int(tokens[0]), tables[0], 600) == \
+            decode(lone, alone[0], tables[0], 600)
+        assert batched._decode_fn._cache_size() == 1
+        # and no decode-shaped call of forward carries a prefetch operand
+        cfg = batched.model_config
+        step = jnp.zeros((4, 1), jnp.int32)
+        jaxpr = jax.make_jaxpr(
+            lambda p, kv, valid: forward(
+                p, cfg, step, step, kv, jnp.zeros((4, 64), jnp.int32),
+                jnp.ones(4, jnp.int32), valid=valid))(
+            batched.params, batched.kv_cache, jnp.ones((4, 1), bool))
+        counts = _prefetch_operands(jaxpr.jaxpr)
+        assert counts and not any(counts)
+        # while the launch above hands every projection its map
+        wide = jnp.zeros((4, 1024), jnp.int32)
+        jaxpr = jax.make_jaxpr(
+            lambda p, kv, valid: forward(
+                p, cfg, wide, wide, kv, jnp.zeros((4, 64), jnp.int32),
+                jnp.ones(4, jnp.int32), valid=valid))(
+            batched.params, batched.kv_cache, jnp.ones((4, 1024), bool))
+        assert _prefetch_operands(jaxpr.jaxpr) == [1] * 7 * cfg.n_layers
 
     def test_quantized_leaf_structure(self):
         r = self._runner("int4")

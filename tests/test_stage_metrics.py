@@ -317,6 +317,70 @@ def test_the_engine_gauges_carry_the_schedulers_counts(served):
         pytest.approx(sched.stats.reserved_page_ms)
 
 
+def test_a_prefill_launch_counts_its_positions_and_row_blocks():
+    """Three prompts of 736 tokens on int4 weights under a budget of
+    2,048: the first launch is [736, 736, 576] over 4 x 1024 positions
+    (9 blocks of 256 live, 7 padding only), the second the 160 left, one
+    row of 256 (one block, no map)."""
+    from dynamo_tpu.engine import InferenceScheduler, ModelRunner, RunnerConfig
+    from dynamo_tpu.models import get_config
+    from dynamo_tpu.parallel import MeshConfig, make_mesh
+
+    runner = ModelRunner(
+        get_config("tiny-test"),
+        RunnerConfig(page_size=16, num_pages=256, max_batch=4,
+                     max_pages_per_seq=64, prefill_buckets=(256, 1024, 2048),
+                     weight_dtype="int4"),
+        make_mesh(MeshConfig()), seed=0)
+    sched = InferenceScheduler(runner)
+    done = []
+    for i in range(3):  # handed in before the loop runs: one admission
+        sched.submit(PreprocessedRequest(
+            request_id=uuid.uuid4().hex,
+            token_ids=[1 + i] + [100 + j % 300 for j in range(735)],
+            sampling=SamplingOptions(max_tokens=2, temperature=0.0),
+            stop=StopConditions(ignore_eos=True)),
+            lambda out: done.append(out)
+            if out.finish_reason is not None else None)
+    sched.start()
+    try:
+        deadline = time.time() + 180
+        while len(done) < 3 and time.time() < deadline:
+            time.sleep(0.005)
+    finally:
+        sched.stop()
+    assert len(done) == 3
+    assert sched.stats.prefill_launches == 2
+    assert sched.stats.prefill_tokens == 2048 + 160
+    assert runner.prefill_positions == 4096 + 256
+    assert runner.prefill_row_blocks == {"live": 9 + 1, "skipped": 7}
+    fake = types.SimpleNamespace(
+        scheduler=sched, runner=runner, instance_id=0xb10c,
+        mesh=types.SimpleNamespace(local_devices=[]))
+    TpuWorker._publish_engine_gauges(fake)
+    assert sample("dynamo_engine_positions", worker="b10c",
+                  kind="prefill") == 4352
+    assert sample("dynamo_engine_tokens", worker="b10c",
+                  kind="prefill") == 2208
+    blocks = "dynamo_prefill_row_blocks_total"
+    assert sample(blocks, worker="b10c", state="live") == 10
+    assert sample(blocks, worker="b10c", state="skipped") == 7
+
+
+def test_row_blocks_are_counted_with_int4_weights_only(served):
+    runner = served["sched"].runner
+    assert runner.prefill_positions > 0
+    assert runner.prefill_row_blocks == {"live": 0, "skipped": 0}
+    TpuWorker._publish_engine_gauges(types.SimpleNamespace(
+        scheduler=served["sched"], runner=runner, instance_id=0xbf16,
+        mesh=types.SimpleNamespace(local_devices=[])))
+    assert sample("dynamo_engine_positions", worker="bf16",
+                  kind="prefill") == runner.prefill_positions
+    assert REGISTRY.get_sample_value(
+        "dynamo_prefill_row_blocks_total",
+        {"worker": "bf16", "state": "live"}) is None
+
+
 def test_a_capture_holds_the_sched_sections_on_the_launching_thread(served):
     """On the profiler's clock, in the same .xplane.pb as the operations:
     the thread whose line holds the engine's `decode` step annotation
@@ -415,6 +479,8 @@ def test_a_scrape_after_traffic_shows_the_stages_and_the_new_families(
         row = f'dynamo_engine_launches{{kind="{kind}",worker="{worker}"}}'
         assert row in page, kind
         assert float(page.split(row)[1].split()[0]) > 0
+    row = f'dynamo_engine_positions{{kind="prefill",worker="{worker}"}}'
+    assert float(page.split(row)[1].split()[0]) >= 2 * 16  # two prompts of 5, each in a bucket of 16
     assert f'dynamo_kv_reserved_page_ms{{worker="{worker}"}}' in page
     for part in ("wall", "prep", "dispatch", "drain_wait"):
         assert f'dynamo_step_part_ms_total{{part="{part}"}}' in page

@@ -110,3 +110,33 @@ def test_the_expert_grouped_matmuls_compile_for_v5e(one_chip, rows):
             _shape(one_chip, (held, width, hidden), bf16), sizes,
             path="pallas", transpose_rhs=transpose).compile()
         assert "tpu_custom_call" in compiled.as_text()
+
+
+# mistral-7b's five int4 projections (K, N): wq/wo, wk/wv, gate/up,
+# down, the head.
+Q4_PROJECTIONS = {"wq-wo": (4096, 4096), "wk-wv": (4096, 1024),
+                  "gate-up": (4096, 14336), "down": (14336, 4096),
+                  "head": (4096, 32768)}
+
+
+@pytest.mark.parametrize("name", sorted(Q4_PROJECTIONS))
+def test_the_q4_matmul_with_a_row_block_map_compiles_for_v5e(one_chip, name):
+    """A [4, 1024] prefill launch's matmul with its `live` map, and a
+    32-row decode step's without one, under the kernel's own name (the
+    trace and `breakdown.device_ops` find it by that)."""
+    from dynamo_tpu.ops.q4_linear import BLOCK_M, q4_matmul
+
+    k, n = Q4_PROJECTIONS[name]
+    weight = [_shape(one_chip, (k // 2, n), jnp.uint8),
+              _shape(one_chip, (k // 256, n), jnp.float32),
+              _shape(one_chip, (k // 256, n), jnp.float32)]
+    prefill = jax.jit(
+        lambda x, q4, s, z, live: q4_matmul(x, q4, s, z, live=live)).lower(
+        _shape(one_chip, (4096, k), jnp.bfloat16), *weight,
+        _shape(one_chip, (4096 // BLOCK_M,), jnp.int32)).compile().as_text()
+    decode = q4_matmul.lower(
+        _shape(one_chip, (32, k), jnp.bfloat16), *weight).compile().as_text()
+    for text in (prefill, decode):
+        assert "tpu_custom_call" in text and "q4_matmul" in text
+    # the map is an operand of the prefill call alone
+    assert "s32[16]" in prefill and "s32[" not in decode
