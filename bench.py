@@ -253,25 +253,6 @@ def bench_one(model: str, *, model_path: str | None = None,
         "vs_baseline": round(vs_baseline, 4),
         "steptrace": steptrace_cols,
     }
-    if weight_dtype == "int4":
-        # Record WHICH pack layout served the number (the v1/v2 kernels
-        # are A/B-able — docs/quantization.md): the version rides each
-        # leaf's dtype, so read it off the live params.
-        from dynamo_tpu.ops.q4_linear import pack_version
-        from dynamo_tpu.runtime.config import env as _cfg_env
-
-        versions = sorted({
-            pack_version(leaf["q4"])
-            for layer in runner.params["layers"]
-            for leaf in layer.values() if isinstance(leaf, dict)
-        })
-        result["q4_layout"] = {
-            "variant": ("mixed" if len(versions) > 1
-                        else f"v{versions[0]}"),
-            "group": int(_cfg_env("DYNT_Q4_GROUP")),
-            "policy": _cfg_env("DYNT_Q4_VARIANT"),
-        }
-
     # Speculative decode point (ROADMAP item 1 / ISSUE 7): the same
     # decode workload driven through the draftless speculation plane —
     # n-gram proposals mined from each sequence's own token stream,
@@ -611,13 +592,13 @@ def bench_disagg_point(requests: int = 16) -> dict:
 
 
 def bench_session_point() -> dict:
-    """Session-cache A/B for BENCH_MULTI (ROADMAP item 2 / ISSUE 11):
+    """Session-cache A/B (ROADMAP item 2 / ISSUE 11):
     two-turn conversations with ~zero natural cross-session overlap
     against a KV-routed 2-worker mocker pair — cold turn-0 vs cached
     turn-1 TTFT with explicit pinning + session affinity ON, and the
     same traffic with the markers OFF (implicit-overlap baseline).
     Target on silicon: cached-turn TTFT <= the kvbm G1 hit number
-    (BENCH_MULTI.kvbm_ttft: 2.7ms hit vs 6.2ms cold); here the mocker's
+    (`kvbm_ttft` in scripts/bench_multi.py's report); here the mocker's
     measured v5e step physics stand in for the chips
     (docs/prompt-caching.md)."""
     import asyncio
@@ -807,8 +788,8 @@ def bench_cold_start_point() -> dict:
 
 def bench_goodput_point() -> dict:
     """Goodput-vs-load curve with the overload-control loop off vs on
-    (ROADMAP item 4 / ISSUE 9) — the chip-free robustness point
-    BENCH_MULTI records next to the silicon numbers. An open-loop
+    (ROADMAP item 4 / ISSUE 9) — a chip-free robustness point. An
+    open-loop
     Poisson ramp walks offered load past the mocker cluster's capacity
     knee twice; per offered-rate bucket the curve reports SLO-good
     requests/s and the shed fraction. The headline is dominance past the
@@ -850,8 +831,8 @@ def bench_goodput_point() -> dict:
 
 
 def bench_two_class_point() -> dict:
-    """Two-class goodput A/B for BENCH_MULTI (ROADMAP item 5 /
-    ISSUE 14): an interactive tenant at a fixed below-knee rate plus a
+    """Two-class goodput A/B (ROADMAP item 5 / ISSUE 14): an
+    interactive tenant at a fixed below-knee rate plus a
     batch tenant ramping ~2x past the knee, served twice — untagged
     FCFS vs the full QoS plane (priority classes, fair-share quotas,
     class-strict queues, preempt-to-park). The headline: the
@@ -957,8 +938,8 @@ def main() -> None:
     # Secondaries: the int8- and bf16-weight 7B configs and the toy.
     # BENCH_r06 capture prep (ROADMAP item 1): speculation ON for the
     # flagship serving block (the spec block records acceptance_rate and
-    # the DYNT_SPEC_MAX_K it ran) so spec, kvbm_offload, disagg, and
-    # q4_ablation are all captured by ONE `python bench.py` on silicon.
+    # the DYNT_SPEC_MAX_K it ran) so spec, kvbm_offload and disagg are
+    # all captured by ONE `python bench.py` on silicon.
     os.environ.setdefault("DYNT_SPEC_ENABLE", "1")
     result = bench_one("mistral-7b", kv_dtype="int8",
                        weight_dtype="int4", num_pages=448,
@@ -982,21 +963,6 @@ def main() -> None:
             # must survive a secondary-bench failure
             secondary.append({"metric": label, "error": repr(exc)})
     result["secondary"] = secondary
-    if os.environ.get("DYNT_BENCH_Q4_ABLATE", "1") != "0":
-        # Kernel-level decomposition of the flagship number: pack-layout
-        # variant x block-size sweep over the mistral-7b projection
-        # geometries, with per-point effective bandwidth (the same
-        # harness CI runs in interpret mode — scripts/q4_ablate.py).
-        try:
-            gc.collect()
-            jax.clear_caches()
-            from dynamo_tpu.perf.q4_ablation import run_ablation
-
-            result["q4_ablation"] = run_ablation(
-                mode="tpu", gks=(0, 2, 4))
-        except Exception as exc:  # noqa: BLE001 — an ablation failure
-            # must never cost the round its silicon numbers
-            result["q4_ablation"] = {"error": repr(exc)}
     if os.environ.get("DYNT_BENCH_DISAGG", "1") != "0":
         try:
             result["disagg"] = bench_disagg_point()

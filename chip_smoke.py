@@ -631,7 +631,6 @@ def main() -> int:
         versions=device["versions"],
         rehearsal=rehearsal, chips=args.chips, model=model,
         native=all(e.get("native") for e in engines),
-        q4_layout=engines[0].get("q4_layout"),
         phases=phases, total_secs=round(time.monotonic() - T0, 1))
     if rehearsal:
         report["rehearsal_passed"] = passed

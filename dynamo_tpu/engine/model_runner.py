@@ -381,15 +381,9 @@ class ModelRunner:
             )(params)
         else:
             if runner_config.weight_dtype == "int4":
-                # Transparent pack-layout migration: a v1-packed int4
-                # tree (old checkpoint / weight-service stream) repacks
-                # host-side to the DYNT_Q4_VARIANT target before
-                # placement; current-layout leaves pass through
-                # untouched (repack_params_q4 returns the same objects,
-                # so device arrays are never round-tripped for a no-op).
-                from ..models.quantize import repack_params_q4
+                from ..models.quantize import check_packed_int4
 
-                params = repack_params_q4(params)
+                check_packed_int4(params)
             # Host arrays (weight service / peer stream / checkpoint) or
             # device arrays: place each leaf under its sharding. For arrays
             # already placed correctly this is a no-op.
@@ -632,14 +626,7 @@ class ModelRunner:
         if self.config.weight_dtype == "int8":
             paths["weight_matmul"] = kernel_path("DYNT_Q8_MATMUL")
         elif self.config.weight_dtype == "int4":
-            from ..ops.q4_linear import pack_version
-
             paths["weight_matmul"] = kernel_path("DYNT_Q4_MATMUL")
-            versions = {
-                pack_version(leaf["q4"])
-                for layer in self.params["layers"]
-                for leaf in layer.values() if isinstance(leaf, dict)}
-            paths["q4_layout"] = "+".join(f"v{v}" for v in sorted(versions))
         if self._hybrid:
             paths["ssm_update"] = self._ssm_path
             paths["expert_gmm"] = self._gmm_path

@@ -641,9 +641,9 @@ class TpuWorker:
                  paths["platform"], paths["device_kind"],
                  paths["device_ids"], paths["decode_attention"],
                  paths["spec_attention"], paths["weight_matmul"],
-                 "".join(f" {slot}={paths[slot]}" for slot in (
-                     "q4_layout", "ssm_update", "expert_gmm")
-                     if slot in paths), native)
+                 "".join(f" {slot}={paths[slot]}"
+                         for slot in ("ssm_update", "expert_gmm")
+                         if slot in paths), native)
         ENGINE_INFO.labels(
             worker=f"{self.instance_id:x}", platform=paths["platform"],
             device_kind=paths["device_kind"],
@@ -651,7 +651,6 @@ class TpuWorker:
             decode_attention=paths["decode_attention"],
             spec_attention=paths["spec_attention"],
             weight_matmul=paths["weight_matmul"],
-            q4_layout=paths.get("q4_layout", ""),
             native=str(native).lower()).set(1)
 
     def _on_engine_fatal(self, exc: BaseException) -> None:

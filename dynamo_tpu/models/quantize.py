@@ -102,32 +102,18 @@ def quantize_params_int4(params: dict, config) -> dict:
     return out
 
 
-def repack_params_q4(params: dict, version: int | None = None) -> dict:
-    """Host-side pack-layout migration of an already-quantized int4
-    pytree (checkpoint / weight-service load path): every {"q4","qs4",
-    "qz4"} leaf whose layout differs from the target (None = the
-    DYNT_Q4_VARIANT policy; auto = v1) is repacked
-    via ops.q4_linear.repack_q4_leaf. Scale/zero rows are untouched and
-    the code transform is a nibble bijection, so v1 checkpoints load
-    bit-exactly (v1 -> v2 -> v1 roundtrips identically). Leaves already
-    in the target layout are returned as the SAME objects — a
-    current-layout tree passes through without any host/device
-    round-trip. scripts/q4_repack.py runs the same transform offline."""
-    from ..ops.q4_linear import repack_q4_leaf
+def check_packed_int4(params: dict) -> None:
+    """A tree that arrives already quantized (a checkpoint, the weight
+    service, a peer's stream) is placed as it comes, so every packed
+    leaf must be what ops.q4_linear packs: refused here, by leaf name,
+    before anything reaches the device."""
+    from jax.tree_util import keystr, tree_leaves_with_path
 
-    def leaf(v):
-        if isinstance(v, dict) and "q4" in v:
-            return repack_q4_leaf(v, version)
-        return v
+    from ..ops.q4_linear import require_packed
 
-    out = dict(params)
-    out["layers"] = [
-        {name: leaf(value) for name, value in layer.items()}
-        for layer in params["layers"]
-    ]
-    if isinstance(params.get("lm_head"), dict):
-        out["lm_head"] = leaf(params["lm_head"])
-    return out
+    for path, leaf in tree_leaves_with_path(params):
+        if getattr(path[-1], "key", None) == "q4":
+            require_packed(leaf, keystr(path[:-1]))
 
 
 def quantize_param_axes_q4(axes: dict, config) -> dict:

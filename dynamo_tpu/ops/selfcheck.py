@@ -154,14 +154,7 @@ def _projection_geoms(cfg):
 def _matmul_cases(cfg, interpret, rows):
     import jax.numpy as jnp
 
-    from .q4_linear import (
-        PACK_V1,
-        PACK_V2,
-        _group_for,
-        q4_matmul,
-        q4_matmul_ref,
-        quantize_weight_q4,
-    )
+    from .q4_linear import q4_matmul, q4_matmul_ref, quantize_weight_q4
     from .q8_linear import q8_matmul, q8_matmul_ref, quantize_weight
 
     @functools.lru_cache(maxsize=1)  # cases run geometry by geometry
@@ -186,10 +179,10 @@ def _matmul_cases(cfg, interpret, rows):
                     lambda: q8_matmul_ref(*args))
         return run
 
-    def q4(k, n, m, version):
+    def q4(k, n, m):
         def run():
             x, w = inputs(k, n, m)
-            leaf = quantize_weight_q4(w, 1, version=version)
+            leaf = quantize_weight_q4(w, 1)
             args = (x, leaf["q4"], leaf["qs4"], leaf["qz4"])
             return (lambda: q4_matmul(*args, interpret=interpret),
                     lambda: q4_matmul_ref(*args))
@@ -199,11 +192,7 @@ def _matmul_cases(cfg, interpret, rows):
     for name, k, n in _projection_geoms(cfg):
         for m in rows:
             cases.append((f"q8_matmul/{name}/m{m}", q8(k, n, m)))
-            cases.append((f"q4_matmul_v1/{name}/m{m}",
-                          q4(k, n, m, PACK_V1)))
-            if k % (2 * _group_for(k)) == 0:
-                cases.append((f"q4_matmul_v2/{name}/m{m}",
-                              q4(k, n, m, PACK_V2)))
+            cases.append((f"q4_matmul/{name}/m{m}", q4(k, n, m)))
     return cases
 
 

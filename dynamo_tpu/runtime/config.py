@@ -139,16 +139,8 @@ _register("DYNT_MOE_GMM", "auto", _str,
           "ships with JAX on TPU, lax.ragged_dot elsewhere) | pallas | xla")
 _register("DYNT_Q4_GROUP", "256", _str,
           "int4 quantization group (contracted rows per scale/zero "
-          "row): 256 (fastest measured decode on v5e) | 128 (finer "
-          "GPTQ/AWQ-convention groups, slightly better quality)")
-_register("DYNT_Q4_VARIANT", "auto", _str,
-          "Packed-int4 layout the quantizer emits (docs/quantization.md):"
-          " auto (= v1, the layout that has run on the chip) | v1 "
-          "(half-block per group, uint8) | v2 (VPU-swizzled global "
-          "half-split with signed codes, int8; needs K to divide "
-          "2*group). The kernel dispatches "
-          "on the packed dtype; checkpoints repack transparently at "
-          "load (scripts/q4_repack.py migrates offline)")
+          "row): 256 (what the benchmark's cells run) | 128 (the "
+          "GPTQ/AWQ convention; not measured on the chip)")
 _register("DYNT_WEIGHT_SERVICE", "", _str,
           "Unix socket of the weight service (GMS analog): workers "
           "re-attach published weights on restart instead of initializing")

@@ -314,11 +314,10 @@ ENGINE_INFO = Gauge(
     "dynamo_engine_info",
     "Constant 1; the labels name this worker's devices and the path "
     "(pallas | interpret | xla | einsum | custom) decode attention, "
-    "spec attention and the weight matmul resolved to, the int4 pack "
-    "layout served (empty unless int4) and whether dynamo_tpu._native "
-    "loaded",
+    "spec attention and the weight matmul resolved to, and whether "
+    "dynamo_tpu._native loaded",
     ["worker", "platform", "device_kind", "devices", "decode_attention",
-     "spec_attention", "weight_matmul", "q4_layout", "native"],
+     "spec_attention", "weight_matmul", "native"],
     registry=REGISTRY,
 )
 ENGINE_TOKENS = Gauge(

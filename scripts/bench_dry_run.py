@@ -26,9 +26,8 @@ os.environ.setdefault("DYNT_LOG_LEVEL", "WARNING")
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-REQUIRED_BLOCKS = ("spec", "kvbm_offload", "disagg", "q4_ablation",
-                   "session_cache", "two_class_goodput", "drain",
-                   "cold_start")
+REQUIRED_BLOCKS = ("spec", "kvbm_offload", "disagg", "session_cache",
+                   "two_class_goodput", "drain", "cold_start")
 
 
 def main() -> int:
@@ -42,7 +41,6 @@ def main() -> int:
     jax.config.update("jax_platforms", "cpu")
 
     import bench
-    from dynamo_tpu.perf.q4_ablation import run_ablation
 
     # The model bench at toy sizes: one decode block, spec + kvbm
     # blocks on, prefill/ttft off (not capture blocks — pure runtime).
@@ -50,11 +48,6 @@ def main() -> int:
         "qwen3-0.6b", batch=2, prompt_len=64, decode_steps=64,
         num_pages=128, prefill_chunk=256, do_prefill=False,
         do_ttft=False, device_kind="cpu")
-
-    # Kernel parity sweep in interpret mode, one tiny point per layout.
-    result["q4_ablation"] = run_ablation(
-        mode="interpret", m=2, bns=(512,), gks=(0,),
-        geoms=(("k512", 512, 512),), trials=1, steps=2)
 
     # The mocker-backed points, exactly as bench.py main() wires them,
     # with every exposed size knob shrunk.
@@ -81,8 +74,6 @@ def main() -> int:
         failures.append("drain: scenario assertions failed")
     if result["cold_start"]["measured_spot"].get("passed") is not True:
         failures.append("cold_start: spot scenario assertions failed")
-    if result["q4_ablation"].get("parity_failures"):
-        failures.append("q4_ablation: parity failed")
     if failures:
         print("bench dry run FAILED:\n  " + "\n  ".join(failures),
               file=sys.stderr)

@@ -17,9 +17,8 @@ REPO = pathlib.Path(__file__).parent.parent
 
 
 def synth_report():
-    """A report whose every SPEC metric equals the blessed value (lists
-    synthesized to the blessed length for `len` metrics) — what a
-    perfectly-on-baseline bench dry run would emit."""
+    """A report whose every SPEC metric equals the blessed value — what
+    a perfectly-on-baseline bench dry run would emit."""
     report = {}
     for block in dw.REQUIRED_BLOCKS:
         base = dw.load_baseline(block, dw.BASELINE_DIR)
@@ -30,10 +29,7 @@ def synth_report():
             node = blockd
             for hop in hops[:-1]:
                 node = node.setdefault(hop, {})
-            value = entry["value"]
-            if entry["kind"] == "len":
-                value = ["x"] * int(entry["value"])
-            node[hops[-1]] = value
+            node[hops[-1]] = entry["value"]
     return report
 
 
@@ -43,7 +39,7 @@ class TestShippedBaselines:
 
     def test_spec_covers_all_required_blocks(self):
         assert set(dw.REQUIRED_BLOCKS) == {
-            "cold_start", "drain", "q4_ablation", "spec", "kvbm_offload",
+            "cold_start", "drain", "spec", "kvbm_offload",
             "two_class_goodput", "session_cache", "disagg"}
 
     def test_on_baseline_report_passes_the_gate(self):
@@ -73,12 +69,15 @@ class TestGateCatchesDrift:
         assert "drain.handoff_path.handoff" in line
         assert "!= blessed" in line
 
-    def test_len_metric_guards_parity_failures(self):
+    def test_a_failed_scenario_verdict_fails_the_gate(self):
+        """The chaos-backed blocks carry their own pass verdict, nested
+        one level down for the spot join: a false one is drift."""
         report = synth_report()
-        report["q4_ablation"]["parity_failures"].append(
-            {"point": "q4_g128", "delta": 0.2})
+        report["cold_start"]["measured_spot"]["passed"] = False
         failures = dw.gate(report, dw.BASELINE_DIR)
-        assert any("q4_ablation.parity_failures" in f for f in failures)
+        (line,) = failures
+        assert line.startswith("cold_start.measured_spot.passed:")
+        assert "observed False != blessed True" in line
 
     def test_missing_block_and_metric_reported(self):
         report = synth_report()

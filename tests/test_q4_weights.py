@@ -1,16 +1,22 @@
-"""Weight-only packed int4 (W4A16) — the second halving of the decode
-weight stream (ops/q4_linear.py): pack/unpack layouts (v1 half-block +
-v2 VPU-swizzled), the Pallas kernel variants vs the XLA reference
-across the geometry grid, v1<->v2 repack bit-exactness, per-group
-quantization error bounds, einsum-spec plumbing, and runner integration
-including the transparent checkpoint repack (BASELINE.md: decode at 7B
-is weight-streaming-bound; the reference reaches this lever via its
-engines' AWQ/GPTQ w4a16 checkpoint modes)."""
+"""Weight-only packed int4 (W4A16; ops/q4_linear.py): the pack layout's
+round trip, per-group quantization error, the Pallas kernel (interpreted)
+against the XLA reference at both documented group sizes and at the
+dense cell's contraction depths, the k-block rule, zero-point edges, the
+row-block map, what is refused (geometry; a packed leaf that is not
+uint8), einsum-spec plumbing, and the runner: quantize and serve, and a
+tree that arrives already packed."""
 
 import numpy as np
 import pytest
 
 from dynamo_tpu.models import get_config
+
+
+@pytest.fixture
+def group128(monkeypatch):
+    """DYNT_Q4_GROUP's other documented value: twice the scale and zero
+    rows for the same contraction."""
+    monkeypatch.setenv("DYNT_Q4_GROUP", "128")
 
 
 class TestQ4Pack:
@@ -26,7 +32,30 @@ class TestQ4Pack:
         np.testing.assert_array_equal(
             np.asarray(_unpack_codes(packed, 128)), np.asarray(u))
 
-    def test_dequant_error_within_half_lsb(self):
+    def test_quantizer_round_trip_at_group_128(self, group128):
+        """What the quantizer packs under DYNT_Q4_GROUP=128 unpacks to
+        the codes it chose: 128 contracted rows a scale row, and every
+        code the nearest of its group's sixteen levels."""
+        import jax.numpy as jnp
+
+        from dynamo_tpu.ops.q4_linear import (
+            _unpack_codes,
+            quantize_weight_q4,
+        )
+
+        rng = np.random.default_rng(11)
+        w = rng.standard_normal((512, 128)).astype(np.float32)
+        qw = quantize_weight_q4(jnp.asarray(w), 1)
+        assert qw["q4"].dtype == jnp.uint8 and qw["q4"].shape == (256, 128)
+        assert qw["qs4"].shape == qw["qz4"].shape == (4, 128)
+        s = np.repeat(np.asarray(qw["qs4"]), 128, axis=0)
+        z = np.repeat(np.asarray(qw["qz4"]), 128, axis=0)
+        want = np.clip(np.round(w / s) + z, 0, 15).astype(np.uint8)
+        np.testing.assert_array_equal(
+            np.asarray(_unpack_codes(qw["q4"], 128)), want)
+
+    @pytest.mark.parametrize("group", [256, 128])
+    def test_dequant_error_within_half_lsb(self, group, monkeypatch):
         """Asymmetric per-group codes reconstruct within scale/2."""
         import jax.numpy as jnp
 
@@ -35,11 +64,12 @@ class TestQ4Pack:
             quantize_weight_q4,
         )
 
+        monkeypatch.setenv("DYNT_Q4_GROUP", str(group))
         rng = np.random.default_rng(1)
         w = jnp.asarray(rng.standard_normal((512, 256)), jnp.float32)
         qw = quantize_weight_q4(w, 1)
+        assert qw["qs4"].shape[0] == 512 // group
         deq = np.asarray(dequantize_q4(qw["q4"], qw["qs4"], qw["qz4"]))
-        group = 512 // qw["qs4"].shape[0]
         s = np.repeat(np.asarray(qw["qs4"]), group, axis=0)
         assert np.max(np.abs(deq - np.asarray(w)) - s * 0.5) <= 1e-5
 
@@ -66,18 +96,6 @@ class TestQ4Pack:
         # within half an LSB of the true values (range 2 / 15 codes)
         assert np.max(np.abs(deq - np.asarray(pos))) <= 2.0 / 15.0
 
-        # The kernel's rank-1 zero-point fold must survive the huge
-        # zero-points these groups produce (z ~ -lo/eps for constants).
-        from dynamo_tpu.ops.q4_linear import q4_matmul, q4_matmul_ref
-
-        mixed = jnp.concatenate([const[:128], pos[:128]], axis=0)
-        qm = quantize_weight_q4(mixed, 1)
-        x = jnp.asarray(rng.standard_normal((4, 256)), jnp.float32)
-        ref = q4_matmul_ref(x, qm["q4"], qm["qs4"], qm["qz4"])
-        out = q4_matmul(x, qm["q4"], qm["qs4"], qm["qz4"],
-                        interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=2e-3)
 
     def test_non_divisible_k_rejected(self):
         import jax.numpy as jnp
@@ -99,8 +117,20 @@ class TestQ4Matmul:
         w = jnp.asarray(rng.standard_normal((k, n)), jnp.float32)
         return x, w, quantize_weight_q4(w, 1)
 
-    @pytest.mark.parametrize("m,k,n", [(8, 512, 512), (3, 1024, 512),
-                                       (33, 384, 1536), (16, 128, 128)])
+    @pytest.mark.parametrize("m,k,n", [
+        (8, 512, 512),     # one k-step at group 256
+        (1, 512, 512),     # M=1 decode row
+        (3, 1024, 512),    # k-block of four groups
+        (16, 1024, 128),   # lane-minimal N
+        (33, 2048, 256),   # padded M, k-block of eight
+        (33, 384, 1536),   # K not a multiple of 256: group 128; bn 512
+        (16, 128, 128),    # K below the preferred group: one group
+        (16, 512, 640),    # bn halves to 128: five column blocks
+        # the dense cell's contraction depths (wq..up, the head; down)
+        (1, 4096, 256),    # k-block of sixteen, one k step
+        (33, 4096, 256),
+        (8, 14336, 128),   # 56 groups: k-block of eight, seven k steps
+    ])
     def test_kernel_matches_reference(self, m, k, n):
         from dynamo_tpu.ops.q4_linear import q4_matmul, q4_matmul_ref
 
@@ -110,6 +140,78 @@ class TestQ4Matmul:
                         interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-3)
+
+    @pytest.mark.parametrize("m,k,n", [
+        (8, 512, 512),     # four scale rows, k-block of four
+        (1, 1024, 256),    # a decode row, k-block of eight
+        (33, 4096, 256),   # the cell's depth: 32 groups, one k step
+    ])
+    def test_kernel_matches_reference_at_group_128(self, m, k, n,
+                                                   group128):
+        from dynamo_tpu.ops.q4_linear import (
+            _k_block_groups,
+            q4_matmul,
+            q4_matmul_ref,
+        )
+
+        x, _, qw = self._case(m, k, n, seed=12)
+        assert qw["qs4"].shape[0] == k // 128
+        assert _k_block_groups(k, 128) == min(32, k // 128)
+        ref = q4_matmul_ref(x, qw["q4"], qw["qs4"], qw["qz4"])
+        out = q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"],
+                        interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-3)
+
+    @pytest.mark.parametrize("k,want", [(256, 1), (512, 2), (1024, 4)])
+    def test_k_block_rule(self, k, want):
+        """The rule picks the groups a k step contracts (the largest
+        power of two, 32 at most, whose blocks divide K), and the kernel
+        it sizes matches the reference: one, two and four groups a
+        step."""
+        from dynamo_tpu.ops.q4_linear import (
+            _k_block_groups,
+            q4_matmul,
+            q4_matmul_ref,
+        )
+
+        assert _k_block_groups(k, 256) == want
+        x, _, qw = self._case(5, k, 256, seed=k)
+        ref = q4_matmul_ref(x, qw["q4"], qw["qs4"], qw["qz4"])
+        out = q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"],
+                        interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-3)
+
+    @pytest.mark.parametrize("edge", ["constant", "one-sided"])
+    def test_zero_point_edge_through_the_kernel(self, edge):
+        """A constant group (zero point ~ -lo / 1e-12) and an
+        all-positive group (zero point outside the code range) beside an
+        ordinary group: the kernel folds each group's zero point in an
+        epilogue of its own, in float32, and must land where the
+        reference does."""
+        import jax.numpy as jnp
+
+        from dynamo_tpu.ops.q4_linear import (
+            q4_matmul,
+            q4_matmul_ref,
+            quantize_weight_q4,
+        )
+
+        rng = np.random.default_rng(7)
+        first = (np.full((256, 128), 3.0) if edge == "constant"
+                 else rng.uniform(3.0, 4.0, (256, 128)))
+        w = jnp.asarray(np.concatenate(
+            [first, rng.standard_normal((256, 128))]), jnp.float32)
+        qw = quantize_weight_q4(w, 1)
+        zero = np.asarray(qw["qz4"])
+        assert (zero[0] < -15).all() and (np.abs(zero[1]) <= 15).all()
+        x = jnp.asarray(rng.standard_normal((4, 512)), jnp.float32)
+        ref = q4_matmul_ref(x, qw["q4"], qw["qs4"], qw["qz4"])
+        out = q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"],
+                        interpret=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-4, atol=2e-3)
 
     def test_matmul_error_bounded(self):
         """Output error vs exact is within the textbook per-group
@@ -169,305 +271,6 @@ class TestQ4Matmul:
                                    rtol=2e-4, atol=2e-4)
 
 
-class TestQ4PackV2:
-    """The VPU-swizzled v2 layout (global half-split, signed-biased
-    nibbles, int8 storage): pack/unpack bijection, layout-version
-    policy, and bit-exact v1<->v2 repacking (the checkpoint-migration
-    contract — scale/zero rows are never touched)."""
-
-    def test_pack_roundtrip_v2(self):
-        import jax.numpy as jnp
-
-        from dynamo_tpu.ops.q4_linear import (
-            _pack_codes_v2,
-            _unpack_codes_v2,
-        )
-
-        rng = np.random.default_rng(0)
-        u = jnp.asarray(rng.integers(0, 16, (512, 64)), jnp.uint8)
-        packed = _pack_codes_v2(u)
-        assert packed.dtype == jnp.int8 and packed.shape == (256, 64)
-        np.testing.assert_array_equal(np.asarray(_unpack_codes_v2(packed)),
-                                      np.asarray(u))
-
-    def test_version_policy(self, monkeypatch):
-        from dynamo_tpu.ops.q4_linear import (
-            PACK_V1,
-            PACK_V2,
-            resolve_pack_version,
-        )
-
-        # auto: v1, the layout that has run on the chip
-        assert resolve_pack_version(512, 256) == PACK_V1
-        assert resolve_pack_version(256, 256) == PACK_V1  # K == group
-        assert resolve_pack_version(128, 128) == PACK_V1
-        monkeypatch.setenv("DYNT_Q4_VARIANT", "v1")
-        assert resolve_pack_version(512, 256) == PACK_V1
-        monkeypatch.setenv("DYNT_Q4_VARIANT", "v2")
-        assert resolve_pack_version(512, 256) == PACK_V2
-        with pytest.raises(ValueError, match="v2"):
-            resolve_pack_version(256, 256)
-        monkeypatch.setenv("DYNT_Q4_VARIANT", "bogus")
-        with pytest.raises(ValueError, match="DYNT_Q4_VARIANT"):
-            resolve_pack_version(512, 256)
-
-    def test_quantizer_emits_versions(self):
-        import jax.numpy as jnp
-
-        from dynamo_tpu.ops.q4_linear import (
-            pack_version,
-            quantize_weight_q4,
-        )
-
-        rng = np.random.default_rng(1)
-        w = jnp.asarray(rng.standard_normal((512, 128)), jnp.float32)
-        assert pack_version(quantize_weight_q4(w, 1)["q4"]) == 1  # auto
-        assert pack_version(quantize_weight_q4(w, 1, version=2)["q4"]) == 2
-        small = jnp.asarray(rng.standard_normal((128, 128)), jnp.float32)
-        # forcing v2 where the half-split is not well-formed raises
-        # instead of mis-packing
-        assert pack_version(quantize_weight_q4(small, 1)["q4"]) == 1
-        with pytest.raises(ValueError, match="v2"):
-            quantize_weight_q4(small, 1, version=2)
-
-    def test_dequant_bitwise_identical_across_layouts(self):
-        import jax.numpy as jnp
-
-        from dynamo_tpu.ops.q4_linear import (
-            dequantize_q4,
-            quantize_weight_q4,
-        )
-
-        rng = np.random.default_rng(2)
-        w = jnp.asarray(rng.standard_normal((1024, 128)), jnp.float32)
-        q1 = quantize_weight_q4(w, 1, version=1)
-        q2 = quantize_weight_q4(w, 1, version=2)
-        np.testing.assert_array_equal(
-            np.asarray(q1["qs4"]), np.asarray(q2["qs4"]))
-        np.testing.assert_array_equal(
-            np.asarray(q1["qz4"]), np.asarray(q2["qz4"]))
-        np.testing.assert_array_equal(
-            np.asarray(dequantize_q4(q1["q4"], q1["qs4"], q1["qz4"])),
-            np.asarray(dequantize_q4(q2["q4"], q2["qs4"], q2["qz4"])))
-
-    def test_repack_roundtrip_bit_exact(self):
-        """quantize -> repack v1->v2 -> repack back: bit-exact, and the
-        v2 leg matches a direct v2 quantize (the transform is the same
-        nibble bijection either way). Includes constant and one-sided
-        groups — the huge-zero-point edge the f32 rows carry."""
-        import jax.numpy as jnp
-
-        from dynamo_tpu.ops.q4_linear import (
-            quantize_weight_q4,
-            repack_q4_leaf,
-        )
-
-        rng = np.random.default_rng(3)
-        w = jnp.concatenate([
-            jnp.full((256, 64), 3.0, jnp.float32),  # constant groups
-            jnp.asarray(rng.uniform(2.0, 4.0, (256, 64)), jnp.float32),
-            jnp.asarray(rng.standard_normal((512, 64)), jnp.float32),
-        ], axis=0)
-        v1 = {k: np.asarray(v)
-              for k, v in quantize_weight_q4(w, 1, version=1).items()}
-        v2 = repack_q4_leaf(v1, 2)
-        assert v2["q4"].dtype == np.int8
-        direct = quantize_weight_q4(w, 1, version=2)
-        np.testing.assert_array_equal(v2["q4"], np.asarray(direct["q4"]))
-        assert v2["qs4"] is v1["qs4"] and v2["qz4"] is v1["qz4"]
-        back = repack_q4_leaf(v2, 1)
-        np.testing.assert_array_equal(back["q4"], v1["q4"])
-        # no-op repacks return the same dict (device leaves never
-        # round-trip through host for nothing)
-        assert repack_q4_leaf(v1, 1) is v1
-        assert repack_q4_leaf(v2, 2) is v2
-
-    def test_repack_auto_keeps_small_k_on_v1(self, monkeypatch):
-        import jax.numpy as jnp
-
-        from dynamo_tpu.ops.q4_linear import (
-            quantize_weight_q4,
-            repack_q4_leaf,
-        )
-
-        rng = np.random.default_rng(4)
-        w = jnp.asarray(rng.standard_normal((128, 64)), jnp.float32)
-        v1 = {k: np.asarray(v)
-              for k, v in quantize_weight_q4(w, 1, version=1).items()}
-        assert repack_q4_leaf(v1, None) is v1
-        # forcing v2 on an incompatible K keeps the leaf at load time
-        # (non-strict) — only the QUANTIZER refuses to mis-pack...
-        monkeypatch.setenv("DYNT_Q4_VARIANT", "v2")
-        assert repack_q4_leaf(v1, None) is v1
-        # ...but a typo'd knob must raise, not silently skip the repack
-        monkeypatch.setenv("DYNT_Q4_VARIANT", "v3")
-        with pytest.raises(ValueError, match="DYNT_Q4_VARIANT"):
-            repack_q4_leaf(v1, None)
-
-    def test_repack_params_tree(self):
-        """models.quantize.repack_params_q4: q4 dict leaves migrate,
-        everything else (and already-current leaves) pass through as
-        the same objects."""
-        import jax.numpy as jnp
-
-        from dynamo_tpu.models.quantize import repack_params_q4
-        from dynamo_tpu.ops.q4_linear import (
-            dequantize_q4,
-            quantize_weight_q4,
-        )
-
-        rng = np.random.default_rng(5)
-        w = jnp.asarray(rng.standard_normal((512, 128)), jnp.float32)
-        leaf = {k: np.asarray(v)
-                for k, v in quantize_weight_q4(w, 1, version=1).items()}
-        norm = np.ones(128, np.float32)
-        params = {"embed": np.zeros((8, 4), np.float32),
-                  "layers": [{"wq": leaf, "attn_norm": norm}],
-                  "lm_head": dict(leaf)}
-        out = repack_params_q4(params, version=2)
-        assert out["layers"][0]["wq"]["q4"].dtype == np.int8
-        assert out["lm_head"]["q4"].dtype == np.int8
-        assert out["layers"][0]["attn_norm"] is norm
-        assert out["embed"] is params["embed"]
-        np.testing.assert_array_equal(
-            np.asarray(dequantize_q4(out["layers"][0]["wq"]["q4"],
-                                     out["layers"][0]["wq"]["qs4"],
-                                     out["layers"][0]["wq"]["qz4"])),
-            np.asarray(dequantize_q4(leaf["q4"], leaf["qs4"],
-                                     leaf["qz4"])))
-        again = repack_params_q4(out, version=2)
-        assert again["layers"][0]["wq"] is out["layers"][0]["wq"]
-
-
-class TestQ4VariantParity:
-    """Interpret-mode parity for EVERY kernel variant vs q4_matmul_ref
-    across the geometry grid: small-K fallback groups, gk boundaries,
-    the M=1 decode row, the flat-wo multi-axis contraction, and the
-    constant-group zero-point edge (dynajit DJ403 oracle coverage for
-    the new kernel)."""
-
-    def _case(self, m, k, n, version, seed=0):
-        import jax.numpy as jnp
-
-        from dynamo_tpu.ops.q4_linear import quantize_weight_q4
-
-        rng = np.random.default_rng(seed)
-        x = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
-        w = jnp.asarray(rng.standard_normal((k, n)), jnp.float32)
-        return x, quantize_weight_q4(w, 1, version=version)
-
-    @pytest.mark.parametrize("version", [1, 2])
-    @pytest.mark.parametrize("m,k,n", [
-        (8, 512, 512),    # one k-step at group 256 (gk boundary)
-        (1, 512, 512),    # M=1 decode row
-        (3, 1024, 512),   # multiple k-steps
-        (16, 1024, 128),  # lane-minimal N
-        (33, 2048, 256),  # padded M, deep contraction
-    ])
-    def test_variant_matches_reference(self, version, m, k, n):
-        from dynamo_tpu.ops.q4_linear import q4_matmul, q4_matmul_ref
-
-        x, qw = self._case(m, k, n, version)
-        ref = q4_matmul_ref(x, qw["q4"], qw["qs4"], qw["qz4"])
-        out = q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"],
-                        interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-3)
-
-    @pytest.mark.parametrize("version,gk", [(1, 1), (1, 2), (1, 4),
-                                            (2, 2), (2, 4)])
-    def test_forced_gk(self, version, gk):
-        from dynamo_tpu.ops.q4_linear import q4_matmul, q4_matmul_ref
-
-        x, qw = self._case(5, 2048, 256, version)
-        ref = q4_matmul_ref(x, qw["q4"], qw["qs4"], qw["qz4"])
-        out = q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"], gk=gk,
-                        interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-3)
-
-    def test_small_k_fallback_group(self):
-        """K below the preferred group: the group falls back to a
-        divisor and auto stays on v1 — the fallback still matches."""
-        from dynamo_tpu.ops.q4_linear import (
-            pack_version,
-            q4_matmul,
-            q4_matmul_ref,
-        )
-
-        x, qw = self._case(4, 128, 128, None)
-        assert pack_version(qw["q4"]) == 1
-        ref = q4_matmul_ref(x, qw["q4"], qw["qs4"], qw["qz4"])
-        out = q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"],
-                        interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-3)
-
-    def test_constant_group_zero_point_edge_v2(self):
-        """The v2 rank-1 fold (zs = (z - 8) * s) must survive the huge
-        zero-points constant/one-sided groups produce."""
-        import jax.numpy as jnp
-
-        from dynamo_tpu.ops.q4_linear import (
-            q4_matmul,
-            q4_matmul_ref,
-            quantize_weight_q4,
-        )
-
-        rng = np.random.default_rng(7)
-        mixed = jnp.concatenate([
-            jnp.full((256, 128), 3.0, jnp.float32),
-            jnp.asarray(rng.uniform(2.0, 4.0, (256, 128)), jnp.float32),
-        ], axis=0)
-        qm = quantize_weight_q4(mixed, 1, version=2)
-        x = jnp.asarray(rng.standard_normal((4, 512)), jnp.float32)
-        ref = q4_matmul_ref(x, qm["q4"], qm["qs4"], qm["qz4"])
-        out = q4_matmul(x, qm["q4"], qm["qs4"], qm["qz4"],
-                        interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=2e-3)
-
-    def test_einsum_specs_v2_including_flat_wo(self):
-        """q4_einsum carries the layout version (dtype-encoded) through
-        every projection spec — including the flat multi-axis wo."""
-        import jax.numpy as jnp
-
-        from dynamo_tpu.ops.q4_linear import (
-            dequantize_q4,
-            q4_einsum,
-            quantize_weight_q4,
-        )
-
-        rng = np.random.default_rng(8)
-        b, t, h, qh, hd, mdim = 2, 3, 512, 8, 128, 1024
-        x = jnp.asarray(rng.standard_normal((b, t, h)), jnp.float32)
-        for spec, wshape, nc in [
-            ("bth,hm->btm", (h, mdim), 1),
-            ("bth,hqd->btqd", (h, qh, hd), 1),
-            ("bth,hkd->btkd", (h, 4, hd), 1),
-            ("bth,hv->btv", (h, 1024), 1),
-        ]:
-            w = jnp.asarray(rng.standard_normal(wshape), jnp.float32)
-            qw = quantize_weight_q4(w, nc, version=2)
-            assert qw["q4"].dtype == jnp.int8
-            out = q4_einsum(spec, x, qw["q4"], qw["qs4"], qw["qz4"])
-            deq = dequantize_q4(qw["q4"], qw["qs4"], qw["qz4"])
-            ref = jnp.einsum(spec, x,
-                             deq.reshape(wshape).astype(jnp.float32))
-            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                       rtol=2e-4, atol=2e-4)
-        xo = jnp.asarray(rng.standard_normal((b, t, qh, hd)), jnp.float32)
-        wo = jnp.asarray(rng.standard_normal((qh, hd, h)), jnp.float32)
-        qo = quantize_weight_q4(wo, 2, version=2)
-        assert qo["q4"].shape == (qh * hd // 2, h)
-        out = q4_einsum("btqd,qdh->bth", xo, qo["q4"], qo["qs4"],
-                        qo["qz4"])
-        deq = dequantize_q4(qo["q4"], qo["qs4"], qo["qz4"])
-        ref = jnp.einsum("btqd,qdh->bth", xo,
-                         deq.reshape(qh, hd, h).astype(jnp.float32))
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-4, atol=2e-4)
-
     def test_geometry_errors_are_value_errors(self):
         """Geometry validation raises explicit ValueError (survives
         python -O), matching the lane-divisibility error."""
@@ -477,7 +280,7 @@ class TestQ4VariantParity:
 
         rng = np.random.default_rng(9)
         w = jnp.asarray(rng.standard_normal((512, 256)), jnp.float32)
-        qw = quantize_weight_q4(w, 1, version=2)
+        qw = quantize_weight_q4(w, 1)
         x = jnp.asarray(rng.standard_normal((2, 512)), jnp.float32)
         with pytest.raises(ValueError, match="x columns"):
             q4_matmul(x[:, :256], qw["q4"], qw["qs4"], qw["qz4"],
@@ -485,12 +288,31 @@ class TestQ4VariantParity:
         with pytest.raises(ValueError, match="zero"):
             q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"][:1],
                       interpret=True)
-        with pytest.raises(ValueError, match="even gk"):
-            q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"], gk=1,
-                      interpret=True)  # odd gk on the v2 layout
-        with pytest.raises(ValueError, match="does not divide"):
-            q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"], gk=8,
-                      interpret=True)
+
+    @pytest.mark.parametrize("entry", ["q4_matmul", "q4_einsum"])
+    def test_a_packed_leaf_that_is_not_uint8_is_refused(self, entry):
+        """A leaf from outside the program (a checkpoint written by
+        another packer, a peer's stream) whose bytes are not uint8 is
+        refused by name of its dtype, not multiplied."""
+        import jax.numpy as jnp
+
+        from dynamo_tpu.ops.q4_linear import (
+            dequantize_q4,
+            q4_einsum,
+            q4_matmul,
+        )
+
+        x, _, qw = self._case(2, 512, 256)
+        foreign = qw["q4"].astype(jnp.int8)
+        with pytest.raises(ValueError, match="uint8.*int8"):
+            if entry == "q4_matmul":
+                q4_matmul(x, foreign, qw["qs4"], qw["qz4"],
+                          interpret=True)
+            else:
+                q4_einsum("bth,hm->btm", x[None], foreign, qw["qs4"],
+                          qw["qz4"])
+        with pytest.raises(ValueError, match="uint8.*int8"):
+            dequantize_q4(foreign, qw["qs4"], qw["qz4"])
 
 
 def _prefetch_operands(jaxpr) -> list[int]:
@@ -543,10 +365,11 @@ class TestQ4LiveRows:
     others do no work and come back zero, the live ones are computed as
     without the map, bit for bit."""
 
-    @pytest.mark.parametrize("version", [1, 2])
+    @pytest.mark.parametrize("group", [256, 128])
     @pytest.mark.parametrize("case", sorted(LIVE_ROW_CASES))
     def test_dead_blocks_are_zero_and_live_rows_unchanged(self, case,
-                                                          version):
+                                                          group,
+                                                          monkeypatch):
         import jax.numpy as jnp
 
         from dynamo_tpu.ops.q4_linear import (
@@ -558,6 +381,7 @@ class TestQ4LiveRows:
             quantize_weight_q4,
         )
 
+        monkeypatch.setenv("DYNT_Q4_GROUP", str(group))
         rows, bucket, lengths, want = LIVE_ROW_CASES[case]
         valid = _mask(rows, bucket, lengths)
         assert _block_map(valid) == want
@@ -577,7 +401,8 @@ class TestQ4LiveRows:
         x = jnp.asarray(rng.standard_normal((rows * bucket, k)),
                         jnp.bfloat16)
         w = jnp.asarray(rng.standard_normal((k, n)), jnp.float32)
-        qw = quantize_weight_q4(w, 1, version=version)
+        qw = quantize_weight_q4(w, 1)
+        assert qw["qs4"].shape[0] == k // group
         plain = np.asarray(q4_matmul(x, qw["q4"], qw["qs4"], qw["qz4"],
                                      interpret=True), np.float32)
         keep = np.repeat(np.asarray(want, bool), BLOCK_M)
@@ -622,9 +447,9 @@ class TestQ4LiveRows:
         with pytest.raises(ValueError, match="every row block"):
             call(x, live[:3])
 
-    @pytest.mark.parametrize("version", [1, 2])
+    @pytest.mark.parametrize("group", [256, 128])
     @pytest.mark.parametrize("path", ["xla", "pallas"])
-    def test_einsum_specs_with_rows_including_flat_wo(self, path, version,
+    def test_einsum_specs_with_rows_including_flat_wo(self, path, group,
                                                       monkeypatch):
         """Every projection spec hands the block map on, on the
         reference path and through the kernel alike: live rows as
@@ -638,6 +463,7 @@ class TestQ4LiveRows:
         )
 
         monkeypatch.setenv("DYNT_Q4_MATMUL", path)
+        monkeypatch.setenv("DYNT_Q4_GROUP", str(group))
         rng = np.random.default_rng(5)
         b, t, h, qh, hd, mdim = 2, 1024, 512, 4, 128, 1024
         valid = _mask(b, t, [700])  # the second row is padding
@@ -653,7 +479,8 @@ class TestQ4LiveRows:
             ("btqd,qdh->bth", xo, (qh, hd, h), 2),
         ]:
             w = jnp.asarray(rng.standard_normal(wshape), jnp.float32)
-            qw = quantize_weight_q4(w, nc, version=version)
+            qw = quantize_weight_q4(w, nc)
+            assert qw["qs4"].shape[0] == h // group
             plain = np.asarray(q4_einsum(spec, lhs, qw["q4"], qw["qs4"],
                                          qw["qz4"]), np.float32)
             out = np.asarray(q4_einsum(spec, lhs, qw["q4"], qw["qs4"],
@@ -773,13 +600,19 @@ class TestRunnerInt4Weights:
         same = sum(a == b for a, b in zip(outs["int4"], outs["oracle"]))
         assert same >= len(outs["oracle"]) - 1, outs
 
+    @pytest.mark.parametrize("group", [256, 128])
     def test_batched_prefill_skips_padding_and_matches_lone_prefills(
-            self, monkeypatch):
+            self, group, monkeypatch):
         """Three rows of unlike lengths, padded to four, through the
         kernel with its block map: the tokens and the KV pages three lone
         prefills give (prefill_chunk_batch's promise), with eight row
         blocks run and eight skipped; the decode step after it is the
-        program it was."""
+        program it was. At group 128 the MLP is widened to 256, so that
+        w_down's contraction carries two scale rows into the same
+        launches (tiny-test's own contractions are one group either
+        way)."""
+        import dataclasses
+
         import jax
         import jax.numpy as jnp
 
@@ -788,10 +621,15 @@ class TestRunnerInt4Weights:
         from dynamo_tpu.parallel import MeshConfig, make_mesh
 
         monkeypatch.setenv("DYNT_Q4_MATMUL", "pallas")  # the interpreter
+        monkeypatch.setenv("DYNT_Q4_GROUP", str(group))
+
+        config = get_config("tiny-test")
+        if group == 128:
+            config = dataclasses.replace(config, mlp_hidden=256)
 
         def runner():
             return ModelRunner(
-                get_config("tiny-test"),
+                config,
                 RunnerConfig(page_size=16, num_pages=128, max_batch=4,
                              max_pages_per_seq=64,
                              prefill_buckets=(64, 1024),
@@ -800,6 +638,8 @@ class TestRunnerInt4Weights:
 
         batched, lone = runner(), runner()
         assert batched.kernel_paths()["weight_matmul"] == "interpret"
+        assert batched.params["layers"][0]["w_down"]["qs4"].shape[0] == \
+            256 // group  # 128 rows in one group; 256 rows in two
         rng = np.random.default_rng(7)
         lengths = [600, 40, 1024]
         prompts = [rng.integers(1, 500, n).astype(np.int32)
@@ -859,8 +699,6 @@ class TestRunnerInt4Weights:
         layer = r.params["layers"][0]
         for name in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"):
             assert isinstance(layer[name], dict), name
-            # tiny-test contractions (64/128/256 rows) are below the v2
-            # half-split floor, so auto keeps the uint8 v1 layout here.
             assert layer[name]["q4"].dtype == np.uint8
             assert layer[name]["qs4"].ndim == 2
         # wo flattens (pack blocks span heads); head projections keep
@@ -880,27 +718,22 @@ class TestRunnerInt4Weights:
                               dtype="int4")
 
 
-class TestRunnerQ4Repack:
-    """Checkpoint-migration contract at the runner level: a v1-packed
-    quantized tree (old checkpoint / weight-service stream) loads
-    through ModelRunner unchanged in MATH — transparently repacked to
-    the DYNT_Q4_VARIANT target where well-formed, bit-identically kept
-    where not — and serves the same greedy stream either way."""
+class TestRunnerPackedTree:
+    """A tree that arrives already packed (a checkpoint, the weight
+    service, a peer's stream) is placed as it comes, as an int8 tree is,
+    and refused when a packed leaf is not what this program packs."""
 
-    def _config(self):
-        from dynamo_tpu.models.config import ModelConfig
-
-        # Wide enough that every contraction (512 = hidden = qh*hd =
-        # mlp) is v2-capable, tiny everywhere else.
-        return ModelConfig(
-            name="tiny-v2-test", vocab_size=512, hidden=512,
-            n_layers=1, n_q_heads=4, n_kv_heads=2, head_dim=128,
-            mlp_hidden=512, max_context=2048)
-
-    def _runner(self, config, params=None):
+    def _runner(self, params=None):
         from dynamo_tpu.engine.model_runner import ModelRunner, RunnerConfig
+        from dynamo_tpu.models.config import ModelConfig
         from dynamo_tpu.parallel import MeshConfig, make_mesh
 
+        # Every contraction (512 = hidden = qh*hd = mlp) holds two
+        # groups; tiny everywhere else.
+        config = ModelConfig(
+            name="tiny-q4-test", vocab_size=512, hidden=512,
+            n_layers=1, n_q_heads=4, n_kv_heads=2, head_dim=128,
+            mlp_hidden=512, max_context=2048)
         return ModelRunner(
             config,
             RunnerConfig(page_size=4, num_pages=64, max_batch=2,
@@ -910,6 +743,17 @@ class TestRunnerQ4Repack:
             params=params,
             seed=0,
         )
+
+    def _host_tree(self, runner):
+        return {
+            "embed": np.asarray(runner.params["embed"]),
+            "final_norm": np.asarray(runner.params["final_norm"]),
+            "layers": [{
+                name: ({k: np.asarray(v) for k, v in leaf.items()}
+                       if isinstance(leaf, dict) else np.asarray(leaf))
+                for name, leaf in runner.params["layers"][0].items()
+            }],
+        }
 
     def _greedy(self, runner, prompt, steps=4):
         table = np.zeros(16, np.int32)
@@ -929,51 +773,22 @@ class TestRunnerQ4Repack:
             toks.append(tok)
         return toks
 
-    def test_v1_tree_loads_via_transparent_repack(self, monkeypatch):
-        from dynamo_tpu.ops.q4_linear import pack_version
-
-        config = self._config()
-        monkeypatch.setenv("DYNT_Q4_VARIANT", "v1")
-        r1 = self._runner(config)
-        v1_layer = r1.params["layers"][0]
-        assert all(pack_version(v1_layer[n]["q4"]) == 1
-                   for n in ("wq", "wo", "w_down"))
-        host = {
-            "embed": np.asarray(r1.params["embed"]),
-            "final_norm": np.asarray(r1.params["final_norm"]),
-            "layers": [{
-                name: ({k: np.asarray(v) for k, v in leaf.items()}
-                       if isinstance(leaf, dict) else np.asarray(leaf))
-                for name, leaf in r1.params["layers"][0].items()
-            }],
-        }
-        monkeypatch.setenv("DYNT_Q4_VARIANT", "v2")
-        r2 = self._runner(config, params=host)  # repacked to the target
-        v2_layer = r2.params["layers"][0]
-        assert all(pack_version(v2_layer[n]["q4"]) == 2
-                   for n in ("wq", "wo", "w_down"))
+    def test_a_quantized_tree_is_placed_as_it_comes(self):
+        first = self._runner()
+        host = self._host_tree(first)
+        second = self._runner(params=host)
+        for name in ("wq", "wo", "w_down"):
+            for part in ("q4", "qs4", "qz4"):
+                np.testing.assert_array_equal(
+                    np.asarray(second.params["layers"][0][name][part]),
+                    host["layers"][0][name][part])
         rng = np.random.default_rng(6)
         prompt = rng.integers(1, 500, 12).astype(np.int32)
-        assert self._greedy(r1, prompt) == self._greedy(r2, prompt)
+        assert self._greedy(first, prompt) == self._greedy(second, prompt)
 
-    def test_v1_tree_loads_unchanged_when_pinned(self, monkeypatch):
-        from dynamo_tpu.ops.q4_linear import pack_version
-
-        config = self._config()
-        monkeypatch.setenv("DYNT_Q4_VARIANT", "v1")
-        r1 = self._runner(config)
-        host = {
-            "embed": np.asarray(r1.params["embed"]),
-            "final_norm": np.asarray(r1.params["final_norm"]),
-            "layers": [{
-                name: ({k: np.asarray(v) for k, v in leaf.items()}
-                       if isinstance(leaf, dict) else np.asarray(leaf))
-                for name, leaf in r1.params["layers"][0].items()
-            }],
-        }
-        r2 = self._runner(config, params=host)  # policy still v1
-        for name in ("wq", "wo", "w_down"):
-            assert pack_version(r2.params["layers"][0][name]["q4"]) == 1
-            np.testing.assert_array_equal(
-                np.asarray(r2.params["layers"][0][name]["q4"]),
-                np.asarray(r1.params["layers"][0][name]["q4"]))
+    def test_a_tree_with_a_leaf_that_is_not_uint8_is_refused(self):
+        host = self._host_tree(self._runner())
+        wo = host["layers"][0]["wo"]
+        wo["q4"] = wo["q4"].view(np.int8)
+        with pytest.raises(ValueError, match=r"layers.*0.*wo.*uint8.*int8"):
+            self._runner(params=host)

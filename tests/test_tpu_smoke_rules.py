@@ -198,6 +198,25 @@ def test_engine_thread_death_fails_requests_and_the_process(run, tmp_path):
     run(body(), timeout=240)
 
 
+def test_the_kernel_phases_matmul_cases_are_sound_interpreted(monkeypatch):
+    """`chip_smoke.py`'s kernels phase is `ops.selfcheck`: on the tiny
+    preset, interpreted, every weight-matmul case it would send to the
+    chip matches its oracle, a q8 and a q4 case for each projection
+    shape and row count (the attention cases are the slow rehearsal's)."""
+    from dynamo_tpu.ops import selfcheck
+
+    monkeypatch.setattr(selfcheck, "_attention_cases",
+                        lambda *args, **kwargs: [])
+    report = selfcheck.run_selfcheck("tiny-test", interpret=True)
+    failed = [case for case in report["cases"] if not case["ok"]]
+    assert report["ok"] and not failed, failed
+    names = [case["name"] for case in report["cases"]]
+    assert names == [
+        f"{kernel}/{shape}/m{rows}"
+        for shape in ("wq", "wkv", "w_up", "w_down")
+        for rows in (8, 512) for kernel in ("q8_matmul", "q4_matmul")]
+
+
 @pytest.mark.slow
 def test_cpu_rehearsal_runs_every_phase_and_never_reads_as_a_pass():
     out = subprocess.run(

@@ -1,11 +1,11 @@
 """dynawatch — chip-free perf-regression gate over the bench dry run.
 
 `scripts/bench_dry_run.py` exercises every modeled-performance subsystem
-(cold start, drain handoff, q4 parity, spec decode, kvbm offload,
-two-class goodput, session cache, disagg) on CPU and emits one JSON
+(cold start, drain handoff, spec decode, kvbm offload, two-class
+goodput, session cache, disagg) on CPU and emits one JSON
 report. dynawatch pins that report to blessed baselines so a refactor
 that silently changes a modeled closed-form (cold-start totals, fetch
-striping speedups), drops a drain handoff, or breaks q4 parity fails CI
+striping speedups) or drops a drain handoff fails CI
 *before* anyone burns chips reproducing it.
 
 Two classes of metric, declared in SPEC below:
@@ -45,11 +45,8 @@ BASELINE_DIR = pathlib.Path(__file__).resolve().parent / "baselines"
 #           pinned floats like the SLO threshold).
 #   rel   — |report - baseline| <= tol * max(|baseline|, 1e-9); for a
 #           zero baseline the tolerance is absolute.
-#   len   — report value is a list; its LENGTH is compared exactly
-#           (parity_failures must stay empty).
-_KINDS = ("exact", "rel", "len")
 
-# (block, dotpath, kind, tol). Blocks mirror the dry-run report's eight
+# (block, dotpath, kind, tol). Blocks mirror the dry-run report's seven
 # scenario sections; dotpaths index into each block's JSON.
 SPEC: List[Tuple[str, str, str, float]] = [
     # -- cold start: closed-form model + measured spot-join smoke ------
@@ -72,10 +69,6 @@ SPEC: List[Tuple[str, str, str, float]] = [
     # sensitive, so the replayed-token volume gets an envelope.
     ("drain", "replay_fallback.reprefill_tokens", "rel", 0.25),
     ("drain", "bit_identical", "exact", 0.0),
-    # -- q4 ablation: parity is the contract -----------------------------
-    ("q4_ablation", "schema_version", "exact", 0.0),
-    ("q4_ablation", "points", "exact", 0.0),
-    ("q4_ablation", "parity_failures", "len", 0.0),
     # -- speculative decode: proposal accounting -------------------------
     ("spec", "max_k", "exact", 0.0),
     ("spec", "k", "exact", 0.0),
@@ -119,19 +112,12 @@ def _resolve(obj: Any, dotpath: str) -> Any:
     return obj
 
 
-def extract(report: dict, block: str, dotpath: str, kind: str) -> Any:
-    value = _resolve(report.get(block) or {}, dotpath)
-    if kind == "len":
-        return len(value) if isinstance(value, (list, tuple)) else None
-    return value
-
-
 def compare(kind: str, tol: float, baseline: Any, observed: Any
             ) -> Optional[str]:
     """None when within the envelope, else a human-readable reason."""
     if observed is None:
         return "missing from report"
-    if kind in ("exact", "len"):
+    if kind == "exact":
         if observed != baseline:
             return f"observed {observed!r} != blessed {baseline!r}"
         return None
@@ -170,7 +156,7 @@ def bless(report: dict, baseline_dir: pathlib.Path) -> List[str]:
         for blk, dotpath, kind, tol in SPEC:
             if blk != block:
                 continue
-            value = extract(report, block, dotpath, kind)
+            value = _resolve(report.get(block) or {}, dotpath)
             if value is None:
                 raise SystemExit(
                     f"dynawatch: cannot bless — report is missing "
@@ -217,7 +203,7 @@ def gate(report: dict, baseline_dir: pathlib.Path) -> List[str]:
                     f"(blessed {entry.get('kind')}/{entry.get('tol')} vs "
                     f"SPEC {kind}/{tol}) — re-bless")
                 continue
-            observed = extract(report, block, dotpath, kind)
+            observed = _resolve(report[block], dotpath)
             reason = compare(kind, tol, entry.get("value"), observed)
             if reason:
                 failures.append(f"{block}.{dotpath}: {reason}")
