@@ -154,6 +154,11 @@ class _Seq:
     # seed, step count and generated tokens — so decode continues the
     # committed stream bit-identically instead of re-prefilling.
     resume_state: Optional[dict] = None
+    # The open frame: tokens `_append_token` committed in the emitting
+    # section under way, not yet emitted. One EngineOutput a sequence
+    # goes out when the section ends (`_end_emit`), or at once with a
+    # finish; never held across sections.
+    frame: Optional[EngineOutput] = None
 
     @property
     def rank(self) -> int:
@@ -200,6 +205,10 @@ class SchedulerStats:
     prefill_launches: int = 0
     decode_block_launches: int = 0
     reserved_page_ms: float = 0.0
+    # Token frames closed (dynamo_engine_emit_frames_total): one a
+    # sequence an emitting section, so decode tokens over it is the
+    # tokens a frame.
+    emit_frames: int = 0
     # Beside it for a model with recurrent state
     # (dynamo_ssm_state_slot_ms): the sum over committed steps of slots
     # held (each holds one fixed-size state) x the step's wall ms. And
@@ -348,6 +357,11 @@ class InferenceScheduler:
         # registered with nothing behind its endpoints.
         self.failed: Optional[BaseException] = None
         self.on_fatal: Optional[Callable[[BaseException], None]] = None
+        # Called where the loop stops emitting (`_end_emit`): an owner
+        # whose `emit` callbacks only collect hands what they collected
+        # to its consumers here, once a section instead of once a token.
+        self.on_emit_end: Optional[Callable[[], None]] = None
+        self._open_frames: list[_Seq] = []
         self.stats = SchedulerStats()
         # Device-time attribution (perf/steptrace.py): per-step
         # decomposition stamps around every dispatch/drain below, plus
@@ -419,8 +433,11 @@ class InferenceScheduler:
         self._wake.set()
         if self.failed is not None:
             # Raced (or followed) the engine's death: nobody drains the
-            # queue any more, so fail what is in it here.
+            # queue any more, so fail what is in it here. (The caller's
+            # thread: the hook alone, never the loop's open frames.)
             self._fail_incoming()
+            if self.on_emit_end is not None:
+                self.on_emit_end()
         return handle
 
     def run_in_step(self, fn: Callable[[], object]) -> "thread_queue.Queue":
@@ -549,11 +566,13 @@ class InferenceScheduler:
         log.exception("engine thread died", exc_info=exc)
         self.failed = exc
         try:
+            self._close_frames()  # tokens the torn step had committed
             self._fail_incoming()
-            self._finish_all(self._failure_reason())
+            self._finish_all(self._failure_reason())  # hands all over
         except Exception:  # noqa: BLE001 — state may be torn mid-step;
             # the owner must still hear about the death
             log.exception("failing live requests after engine death")
+            self._end_emit()
         if self.on_fatal is not None:
             self.on_fatal(exc)
 
@@ -571,6 +590,7 @@ class InferenceScheduler:
             try:
                 fn = self._control.get_nowait()
             except thread_queue.Empty:
+                self._end_emit()  # e.g. an abandoned transfer's frame
                 return
             try:
                 fn()
@@ -603,6 +623,7 @@ class InferenceScheduler:
                     # interactive arrival overtakes every waiting batch
                     # request.
                     self._waiting.sort(key=lambda s: -s.rank)
+                self._end_emit()  # refusals, drain bounces
                 return
             if self.draining:
                 # Vacating: anything that raced the router's draining
@@ -827,6 +848,9 @@ class InferenceScheduler:
             # Pressure check ran: parked sequences resume when slots and
             # pages are back and nothing higher-class is still waiting.
             admitted += self._resume_parked()
+        # An onboarded first token, a victim's migrate, a parked
+        # sequence's deadline: out now, not behind the step's drain.
+        self._end_emit()
         return admitted
 
     # -- preempt-to-KVBM (docs/multi-tenancy.md) ---------------------------
@@ -1195,6 +1219,9 @@ class InferenceScheduler:
         # is the loop's only blocking device sync.
         pending = self._dispatch_decode()
         prefill_tokens = self._prefill_some()
+        # First tokens of rows that read back in the call (logprobs,
+        # prefill-only, ring) and a streamed transfer's first params.
+        self._end_emit()
         # Overlap window: arrivals that landed during dispatch are
         # admitted while the device is still stepping the decode block.
         with _section("sched.drain_incoming"):
@@ -1216,6 +1243,9 @@ class InferenceScheduler:
         with _section("sched.finalize_prefill"):
             for seq, tok_dev in ripe:
                 finalized += self._finalize_prefill(seq, tok_dev)
+            # First tokens leave here, before the decode block's drain.
+            with self.steptrace.emit(section=False):
+                self._end_emit()
         decode_tokens = self._drain_decode(pending)
         # Pages the step's sequences held while it ran: taken before the
         # reap returns the finished ones' (at most max_batch slots).
@@ -1731,7 +1761,7 @@ class InferenceScheduler:
         for seq in ready:
             seq.device_decode_ms += drain.device_ms
         count = 0
-        with _section("sched.emit"):
+        with self.steptrace.emit():
             for toks_k in blocks_np:
                 for step in range(block):
                     for seq in ready:
@@ -1740,6 +1770,8 @@ class InferenceScheduler:
                         self._append_token(seq,
                                            int(toks_k[step][seq.slot]))
                         count += 1
+            # One frame a sequence, one hand-over for the lot.
+            self._end_emit()
         return count
 
     # -- speculative decoding (engine/spec.py; docs/speculative-decoding.md)
@@ -1854,18 +1886,22 @@ class InferenceScheduler:
         self.stats.spec_last_k = max(
             (s.spec.pending for s in ready if s.spec is not None),
             default=0)
-        for seq in ready:
-            i = seq.slot
-            if seq.finished or seq.cancelled:
-                continue
-            if seq.processors:
-                count += self._commit_spec_host(seq, drafts[i], logits[i])
-            else:
-                n = int(n_acc[i])
-                toks = [int(t) for t in targets[i, : n + 1]]
-                count += self._commit_spec(seq, toks)
-            if seq.spec is not None and seq.spec.pending:
-                emas.append(seq.spec.ema)
+        with self.steptrace.emit():
+            for seq in ready:
+                i = seq.slot
+                if seq.finished or seq.cancelled:
+                    continue
+                if seq.processors:
+                    count += self._commit_spec_host(seq, drafts[i],
+                                                    logits[i])
+                else:
+                    n = int(n_acc[i])
+                    toks = [int(t) for t in targets[i, : n + 1]]
+                    count += self._commit_spec(seq, toks)
+                if seq.spec is not None and seq.spec.pending:
+                    emas.append(seq.spec.ema)
+            # A sequence's verified tokens are one frame.
+            self._end_emit()
         if emas:
             self.stats.spec_ema = float(np.mean(emas))
         return count
@@ -1972,6 +2008,8 @@ class InferenceScheduler:
                 seq, token, sample_info=info,
                 prompt_tokens=seq.prompt_len if first else None)
             count += 1
+        # Frames of one: the step's prefill is still to be dispatched.
+        self._end_emit()
         return count
 
     def _host_process_sample(self, seq: _Seq, raw_row: np.ndarray,
@@ -1998,6 +2036,9 @@ class InferenceScheduler:
         log.warning("logits processor failed for %s: %r",
                     seq.request.request_id, exc)
         seq.finished = True
+        if seq.frame is not None:
+            # tokens a speculative step verified before the failure
+            self._close_frame(seq)
         seq.emit(EngineOutput(
             finish_reason="error",
             error=f"logits processor failed: {exc}"))
@@ -2109,15 +2150,57 @@ class InferenceScheduler:
             get_recorder().device(seq.record_id, "decode",
                                   seq.device_decode_ms)
             seq.device_decode_ms = 0.0
-        seq.emit(EngineOutput(
-            token_ids=[token], finish_reason=finish,
-            prompt_tokens=prompt_tokens,
-            logprobs=logprobs, top_logprobs=top_logprobs,
-        ))
+        frame = seq.frame
+        if frame is not None and ((frame.logprobs is None)
+                                  != (logprobs is None)):
+            # logprob entries stay one a token inside a frame
+            self._close_frame(seq)
+            frame = None
+        if frame is None:
+            seq.frame = EngineOutput(
+                token_ids=[token], prompt_tokens=prompt_tokens,
+                logprobs=logprobs, top_logprobs=top_logprobs)
+            self._open_frames.append(seq)
+        else:
+            frame.token_ids.append(token)
+            if logprobs is not None:
+                frame.logprobs.extend(logprobs)
+                if top_logprobs is not None:  # asked for or not: a
+                    # request's, so the frame's first token had them too
+                    frame.top_logprobs.extend(top_logprobs)
         if finish is not None:
+            self._close_frame(seq, finish)
             seq.finished = True
         elif seq.processors:
             self._maybe_retire_processors(seq)
+
+    def _close_frame(self, seq: _Seq, finish: Optional[str] = None) -> None:
+        """Emit the sequence's open frame: what one emitting section
+        gave it, 1 to block x depth tokens, with the finish if its last
+        token carried one."""
+        frame, seq.frame = seq.frame, None
+        frame.finish_reason = finish
+        self.stats.emit_frames += 1
+        seq.emit(frame)
+
+    def _close_frames(self) -> None:
+        """Emit every frame the section opened; a sequence cancelled
+        meanwhile has no reader."""
+        for seq in self._open_frames:
+            if seq.frame is None:
+                continue
+            if seq.cancelled:
+                seq.frame = None
+            else:
+                self._close_frame(seq)
+        self._open_frames.clear()
+
+    def _end_emit(self) -> None:
+        """Where the loop stops emitting: close the open frames and tell
+        the owner, so nothing emitted waits behind a blocking drain."""
+        self._close_frames()
+        if self.on_emit_end is not None:
+            self.on_emit_end()
 
     def _maybe_retire_processors(self, seq: _Seq) -> None:
         """min_tokens is the only processor that EXPIRES: once the budget
@@ -2159,6 +2242,7 @@ class InferenceScheduler:
                 seq.emit(EngineOutput(finish_reason="migrate", error=reason))
                 seq.finished = True
                 n += 1
+        self._end_emit()  # before the pages go
         self._reap_finished()
         return n
 
@@ -2250,6 +2334,7 @@ class InferenceScheduler:
                     kv_transfer_params=params))
             else:
                 _replay(seq)
+        self._end_emit()  # before the pages go
         self._reap_finished()
         return report
 
@@ -2284,6 +2369,7 @@ class InferenceScheduler:
                 seq.emit(EngineOutput(finish_reason="error", error=reason))
                 seq.finished = True
                 n += 1
+        self._end_emit()  # before the pages go
         self._reap_finished()
         return n
 

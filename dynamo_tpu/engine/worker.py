@@ -75,6 +75,46 @@ def observe_stages(timeline, request, prefill_only: bool = False) -> None:
                               model=request.model).observe(seconds)
 
 
+class Outbox:
+    """The scheduler's out-tray. A request's `emit` only posts here;
+    `flush` (`InferenceScheduler.on_emit_end`) hands everything one
+    emitting section produced, a frame a sequence of a drained block,
+    to the event loop in ONE `call_soon_threadsafe`, where each frame
+    used to be one: a lock, a wake-up write on the loop's socket and,
+    under load, a trip of the GIL to the loop thread and back while the
+    device waits for its next dispatch. The scheduler thread posts and
+    flushes; the lock is for `submit`, which fails a request on its
+    caller's thread once the engine is dead."""
+
+    def __init__(self) -> None:
+        self._frames: dict = {}  # loop -> [(queue, frame)]: one loop,
+        #                          outside tests
+        self._lock = threading.Lock()
+        self.handovers = 0  # dynamo_engine_emit_handovers_total
+
+    def post(self, loop, queue: asyncio.Queue,
+             output: EngineOutput) -> None:
+        with self._lock:
+            self._frames.setdefault(loop, []).append((queue, output))
+
+    def flush(self) -> None:
+        if not self._frames:
+            return
+        with self._lock:  # held to the hand-over: two flushers' batches
+            # reach the loop in the order they were taken
+            batches, self._frames = self._frames, {}
+            for loop, frames in batches.items():
+                self.handovers += 1
+                loop.call_soon_threadsafe(self._deliver, frames)
+
+    @staticmethod
+    def _deliver(frames: list) -> None:
+        """On the event loop: each frame to its request's queue, in the
+        order the scheduler emitted them."""
+        for queue, output in frames:
+            queue.put_nowait(output)
+
+
 class KvEventBuffer:
     """Thread-safe KV event buffer: the scheduler thread records stored /
     removed page hashes; an async drain task batches them onto the event
@@ -334,6 +374,7 @@ class TpuWorker:
         # Set by the scheduler thread when an exception escapes a step;
         # main() exits non-zero once teardown has run.
         self.engine_failure: Optional[BaseException] = None
+        self.outbox = Outbox()
 
     async def start(self) -> None:
         """prepare + serve in one go (normal startup). Snapshot-gated
@@ -616,6 +657,7 @@ class TpuWorker:
             # a tokenizer-less deployment still serves
             self.scheduler.logits_tokenizer = None
         self.scheduler.on_fatal = self._on_engine_fatal
+        self.scheduler.on_emit_end = self.outbox.flush
         self._report_engine()
         self.scheduler.start()
 
@@ -1495,6 +1537,8 @@ class TpuWorker:
             ENGINE_LAUNCHES,
             ENGINE_POSITIONS,
             ENGINE_TOKENS,
+            EMIT_FRAMES,
+            EMIT_HANDOVERS,
             KV_RESERVED_PAGE_MS,
             MOE_DROPPED_SLOTS,
             MOE_EXPERT_CALLS,
@@ -1517,6 +1561,8 @@ class TpuWorker:
             ENGINE_LAUNCHES.labels(worker=worker, kind=kind).set(count)
         ENGINE_POSITIONS.labels(worker=worker, kind="prefill").set(
             getattr(self.runner, "prefill_positions", 0))
+        EMIT_FRAMES.labels(worker=worker).set(stats.emit_frames)
+        EMIT_HANDOVERS.labels(worker=worker).set(self.outbox.handovers)
         blocks = getattr(self.runner, "prefill_row_blocks", {})
         if any(blocks.values()):  # counted with int4 weights only
             for state, count in blocks.items():
@@ -1579,7 +1625,7 @@ class TpuWorker:
     def _publish_steptrace_metrics(self) -> None:
         """Publish the device-time attribution plane (perf/steptrace.py):
         per-step device/host histograms, the steps' wall and its
-        measured parts (prep, dispatch, drain_wait) from the samples
+        measured parts (prep, dispatch, drain_wait, emit) from the samples
         buffered since the last drain, the host-bound verdict, and the live MFU /
         roofline-fraction gauges computed from this interval's work via
         the analytical TimingModel."""
@@ -1595,7 +1641,7 @@ class TpuWorker:
         trace = self.scheduler.steptrace
         worker = f"{self.instance_id:x}"
         parts = {"wall": 0.0, "prep": 0.0, "dispatch": 0.0,
-                 "drain_wait": 0.0}
+                 "drain_wait": 0.0, "emit": 0.0}
         for sample in trace.drain_samples():
             for phase, ms in sample.device_by_phase.items():
                 STEP_DEVICE_MS.labels(phase=phase).observe(ms)
@@ -1604,6 +1650,7 @@ class TpuWorker:
             parts["prep"] += sample.prep_ms
             parts["dispatch"] += sample.dispatch_ms
             parts["drain_wait"] += sample.drain_ms
+            parts["emit"] += sample.emit_ms
         for part, ms in parts.items():
             STEP_PART_MS.labels(part=part).inc(ms)
         HOST_BOUND.labels(worker=worker).set(1.0 if trace.host_bound
@@ -1748,8 +1795,10 @@ class TpuWorker:
             loop = asyncio.get_running_loop()
             out_queue: asyncio.Queue = asyncio.Queue()
 
+            post = self.outbox.post
+
             def emit(output: EngineOutput) -> None:
-                loop.call_soon_threadsafe(out_queue.put_nowait, output)
+                post(loop, out_queue, output)
 
             if request.cache_anchors and self.kvbm is not None \
                     and hasattr(self.kvbm, "pin_blocks"):

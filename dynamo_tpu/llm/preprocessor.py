@@ -447,7 +447,30 @@ class DeltaGenerator:
             else reason
 
     def on_output(self, output: EngineOutput) -> list[dict]:
-        """Convert one engine item into zero or more SSE chunks."""
+        """Convert one engine item into zero or more SSE chunks. A
+        decode frame holds what one drain gave the sequence (up to
+        block x depth tokens); a client gets the chunks that as many
+        one-token frames give: each id goes through the detokeniser and
+        the stop-string filter on its own, with its logprob entry, and
+        the finish rides the last."""
+        ids = output.token_ids
+        if len(ids) <= 1 or output.error:
+            return self._on_token(output)
+        chunks: list[dict] = []
+        for j, token in enumerate(ids):
+            first, last = j == 0, j == len(ids) - 1
+            chunks.extend(self._on_token(EngineOutput(
+                token_ids=[token],
+                finish_reason=output.finish_reason if last else None,
+                prompt_tokens=output.prompt_tokens if first else None,
+                logprobs=(None if output.logprobs is None
+                          else [output.logprobs[j]]),
+                top_logprobs=([output.top_logprobs[j]]
+                              if output.top_logprobs else None),
+            )))
+        return chunks
+
+    def _on_token(self, output: EngineOutput) -> list[dict]:
         if self._stopped:
             return []
         chunks: list[dict] = []

@@ -228,8 +228,16 @@ class PreprocessedRequest:
 
 @dataclasses.dataclass
 class EngineOutput:
-    """One streamed item from a worker: newly generated token ids (usually
-    one for decode, many for a final chunk) plus terminal state."""
+    """One streamed item from a worker: newly generated token ids plus
+    terminal state. A decode frame is what ONE drain of the scheduler
+    gave one sequence: 1 to DYNT_DECODE_BLOCK x DYNT_DECODE_PIPELINE
+    tokens (the verified run of a speculative step; 1 on the per-token
+    host paths and for prefill's first token), never held back to fill
+    up, cut at a finish (`finish_reason` rides the frame of the token
+    that carried it). `logprobs` / `top_logprobs` hold one entry a token
+    of the frame, `prompt_tokens` rides the first frame. The frontend
+    streams one chunk a token whatever a frame holds
+    (`DeltaGenerator.on_output`); consumers count `len(token_ids)`."""
 
     token_ids: list[int] = dataclasses.field(default_factory=list)
     finish_reason: Optional[str] = None  # stop | length | error | cancelled

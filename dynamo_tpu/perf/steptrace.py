@@ -16,6 +16,10 @@ model actually has, with ZERO added device syncs:
               inside it
   drain-wait  the blocking readback slice of the device window (host
               idle, waiting on results)
+  emit        host time committing drained tokens and handing their
+              frames to the owner (the `sched.emit` sections, and the
+              hand-over of first tokens that ends
+              `sched.finalize_prefill`)
 
 The invariant `host_ms + device_ms == wall_ms` holds per step by
 construction (host is the residual of the measured device window), and
@@ -103,6 +107,7 @@ class StepSample:
     dispatch_ms: float  # measured: host time inside submit calls
     device_ms: float  # measured: submit end -> drain complete, summed
     drain_ms: float  # measured: blocked readback slice of device_ms
+    emit_ms: float = 0.0  # measured: committing + handing over tokens
     device_by_phase: dict = dataclasses.field(default_factory=dict)
 
     @property
@@ -209,6 +214,26 @@ class _SyncScope:
         return False
 
 
+class _EmitScope:
+    """Times one emitting section of the loop into the step's `emit`
+    part; with `section`, also names it `sched.emit` in a capture."""
+
+    def __init__(self, trace: "StepTrace", section: bool) -> None:
+        self._trace = trace
+        self._ann = (annotation("sched.emit", section=True) if section
+                     else contextlib.nullcontext())
+
+    def __enter__(self) -> "_EmitScope":
+        self._start = self._trace._clock()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._ann.__exit__(exc_type, exc, tb)
+        self._trace._emit_ms += (self._trace._clock() - self._start) * 1e3
+        return False
+
+
 class StepTrace:
     """Per-scheduler step decomposition accumulator.
 
@@ -239,6 +264,7 @@ class StepTrace:
         self._first_submit: Optional[float] = None
         self._dispatch_ms = 0.0
         self._drain_ms = 0.0
+        self._emit_ms = 0.0
         self._submit_end: dict[str, float] = {}
         self._device_by_phase: dict[str, float] = {}
         self._t0 = 0.0
@@ -259,6 +285,9 @@ class StepTrace:
     def sync(self, phase: str, step: Optional[int] = None) -> _SyncScope:
         return _SyncScope(self, phase, step)
 
+    def emit(self, section: bool = True) -> _EmitScope:
+        return _EmitScope(self, section)
+
     def commit(self, wall_ms: float) -> StepSample:
         """Close the step: device is the measured window sum (clamped to
         the wall — phase windows can overlap when a deferred prefill
@@ -274,6 +303,7 @@ class StepTrace:
             dispatch_ms=self._dispatch_ms,
             device_ms=device,
             drain_ms=self._drain_ms,
+            emit_ms=self._emit_ms,
             device_by_phase=dict(self._device_by_phase),
         )
         with self._lock:

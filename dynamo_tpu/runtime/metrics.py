@@ -344,6 +344,23 @@ ENGINE_POSITIONS = Gauge(
     "of launched positions that held a prompt token",
     ["worker", "kind"], registry=REGISTRY,
 )
+EMIT_FRAMES = Gauge(
+    "dynamo_engine_emit_frames_total",
+    "Token frames this worker's scheduler has emitted since start: one "
+    "a sequence an emitting section (a drained decode block, a spec "
+    "step, a first token). Growth of dynamo_engine_tokens{kind=decode} "
+    "over its growth is the tokens a frame",
+    ["worker"], registry=REGISTRY,
+)
+EMIT_HANDOVERS = Gauge(
+    "dynamo_engine_emit_handovers_total",
+    "Times the scheduler thread has woken the event loop to hand over "
+    "what it emitted (one call_soon_threadsafe for everything an "
+    "emitting section produced). Growth of "
+    "dynamo_engine_emit_frames_total over its growth is the frames a "
+    "hand-over",
+    ["worker"], registry=REGISTRY,
+)
 PREFILL_ROW_BLOCKS = Gauge(
     "dynamo_prefill_row_blocks_total",
     "int4 weights: 256-row blocks of launched prefill positions since "
@@ -532,8 +549,10 @@ STEP_PART_MS = Counter(
     "The committed steps' wall time in ms, summed (part=wall), and its "
     "measured parts: prep (step start -> first dispatch submit), "
     "dispatch (host time inside runner submit calls), drain_wait (the "
-    "blocked readback slice of the device window). A part's share of a "
-    "window is its growth over that of wall",
+    "blocked readback slice of the device window), emit (committing "
+    "drained tokens, closing their frames and handing them to the "
+    "event loop). A part's share of a window is its growth over that "
+    "of wall",
     ["part"], registry=REGISTRY,
 )
 TTFT_DEVICE_MS = Histogram(

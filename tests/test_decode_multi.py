@@ -235,3 +235,169 @@ def test_scheduler_pipeline_depth_reduced_near_budget():
     piped = _run_scheduler(4, max_tokens=6, n_requests=1, pipeline=2)
     assert piped[0].tokens() == base[0].tokens()
     assert piped[0].finish == "length"
+
+
+# -- one frame a sequence a drained block ------------------------------------
+
+_SHARED = {}
+
+
+def _shared_runner():
+    """One runner for the frame tests (its programs compile once); every
+    case makes its own scheduler, so its own page pool."""
+    if "runner" not in _SHARED:
+        _SHARED["runner"] = _runner()
+    return _SHARED["runner"]
+
+
+def _request(prompt, max_tokens, *, eos=None, stop_ids=(), sampled=False):
+    """Greedy, or seeded sampling: the toy's greedy stream repeats one
+    token, so a stop is placed on a sampled one."""
+    return PreprocessedRequest(
+        request_id=uuid.uuid4().hex, token_ids=list(prompt),
+        sampling=SamplingOptions(max_tokens=max_tokens,
+                                 temperature=1.0 if sampled else 0.0,
+                                 seed=11 if sampled else None),
+        stop=StopConditions(ignore_eos=eos is None,
+                            stop_token_ids=list(stop_ids)),
+        eos_token_ids=[eos] if eos is not None else [],
+    )
+
+
+def _stepped(requests, *, block, depth=1, spec=False, after_step=None,
+             then=None):
+    """Drive a scheduler by hand (no thread) until every request has its
+    finish; returns the collectors and the scheduler."""
+    sched = InferenceScheduler(_shared_runner())
+    sched.decode_block = block
+    sched.decode_pipeline = depth
+    assert sched.spec_enabled == spec
+    cols = [_Collect() for _ in requests]
+    handles = [sched.submit(r, c) for r, c in zip(requests, cols)]
+    for i in range(200):
+        sched._drain_control()
+        sched._drain_incoming()
+        sched._step()
+        if i == after_step:
+            then(sched, handles, cols)
+        if all(c.finish is not None or h.seq.cancelled
+               for c, h in zip(cols, handles)):
+            break
+    return cols, sched
+
+
+_PROMPT = [3, 9, 3, 9, 3, 9, 3, 9]  # repeats: the n-gram proposer mines it
+
+
+def _base_tokens(sampled=False, n=20):
+    """The per-token path's stream (block 1: a frame a token)."""
+    if sampled not in _SHARED:
+        cols, _ = _stepped([_request(_PROMPT, n, sampled=sampled)], block=1)
+        assert [len(o.token_ids) for o in cols[0].outputs] == [1] * n
+        _SHARED[sampled] = cols[0].tokens()
+    return _SHARED[sampled]
+
+
+def _first_seen_at(tokens, lo, hi):
+    """An index in [lo, hi) whose token occurs nowhere before it."""
+    for i in range(lo, hi):
+        if tokens[i] not in tokens[:i]:
+            return i
+    raise AssertionError(f"no fresh token in {tokens[lo:hi]} of {tokens}")
+
+
+@pytest.mark.parametrize("where", ["mid_block", "second_chained_block"])
+@pytest.mark.parametrize("reason", ["stop_token", "eos", "length"])
+def test_a_frame_ends_at_the_finishing_token(reason, where):
+    """A finish inside a fused block, or inside the second of two chained
+    blocks, closes the frame AT that token with the finish reason; what
+    the device computed past it is discarded and no frame follows."""
+    base = _base_tokens(sampled=True)
+    # generated[0] is prefill's; a block of 8 then holds 1..8, two
+    # chained blocks of 4 hold 1..4 and 5..8
+    block, depth, lo, hi = ((8, 1, 2, 7) if where == "mid_block"
+                            else (4, 2, 5, 8))
+    if reason == "length":
+        idx = lo + 1
+        req = _request(_PROMPT, idx + 1, sampled=True)
+    else:
+        idx = _first_seen_at(base, lo, hi)
+        req = (_request(_PROMPT, 20, eos=base[idx], sampled=True)
+               if reason == "eos" else
+               _request(_PROMPT, 20, stop_ids=[base[idx]], sampled=True))
+    (col,), sched = _stepped([req], block=block, depth=depth)
+    frames = [o.token_ids for o in col.outputs]
+    assert frames == [base[:1], base[1:idx + 1]]
+    assert [o.finish_reason for o in col.outputs] == [
+        None, "length" if reason == "length" else "stop"]
+    assert col.outputs[0].prompt_tokens == len(_PROMPT)
+    assert sched.stats.emit_frames == 2
+    assert all(s is None for s in sched._slots)  # reaped, nothing open
+
+
+def test_a_cancelled_or_finished_sequence_emits_nothing():
+    """Three rows in one block: one is cancelled before the drain, one
+    finishes at its second token; neither gets a frame for what the block
+    computed past that, the third gets its eight."""
+    base = _base_tokens()
+
+    def cancel_first(sched, handles, cols):
+        # the first token came with this step's finalize_prefill
+        assert [c.tokens() for c in cols] == [base[:1]] * 3
+        handles[0].cancel()
+
+    reqs = [_request(_PROMPT, 20), _request(_PROMPT, 2),
+            _request(_PROMPT, 17)]
+    cols, sched = _stepped(reqs, block=8, after_step=1,
+                           then=cancel_first)
+    assert [o.token_ids for o in cols[0].outputs] == [base[:1]]
+    assert cols[0].finish is None
+    assert [o.token_ids for o in cols[1].outputs] == [base[:1], base[1:2]]
+    assert cols[1].finish == "length"
+    assert [o.token_ids for o in cols[2].outputs] == [
+        base[:1], base[1:9], base[9:17]]
+    assert cols[2].finish == "length"
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("block", [1, 8])
+def test_frames_carry_the_per_token_paths_ids(block, depth, spec, monkeypatch):
+    """Whatever the block, the chaining and the speculation, the ids a
+    client is sent are the per-token path's, token for token (a greedy
+    row the n-gram proposer can mine, beside a sampled one), in frames
+    of at most block x depth (or the verified run of a spec step)."""
+    if spec:
+        monkeypatch.setenv("DYNT_SPEC_ENABLE", "1")
+    reqs = [_request(_PROMPT, 20), _request(_PROMPT, 13, sampled=True)]
+    cols, sched = _stepped(reqs, block=block, depth=depth, spec=spec)
+    assert cols[0].tokens() == _base_tokens()
+    assert cols[1].tokens() == _base_tokens(sampled=True)[:13]
+    assert cols[0].finish == cols[1].finish == "length"
+    sizes = [len(o.token_ids) for c in cols for o in c.outputs]
+    assert sum(sizes) == 33 and min(sizes) >= 1
+    assert sched.stats.emit_frames == len(sizes)
+    if spec:
+        assert sched.stats.spec_steps > 0 and sched.stats.spec_accepted > 0
+    else:
+        # a frame is what ONE drain gave, never more: 19 tokens follow
+        # the longer row's first
+        assert max(sizes) == (min(block * depth, 19) if block > 1 else 1)
+
+
+def test_an_open_frame_goes_out_before_the_error_that_ends_its_stream():
+    """A logits processor that fails inside a speculative step's host
+    verification: the tokens the step had verified reach the client
+    before the error frame, never behind it."""
+    sched = InferenceScheduler(_shared_runner())
+    col = _Collect()
+    seq = sched._prepare(_request(_PROMPT, 20), col)
+    sched._append_token(seq, 7, prompt_tokens=len(_PROMPT))
+    sched._append_token(seq, 8)
+    assert col.outputs == []  # the frame is open: nothing is out yet
+    sched._fail_processor_seq(seq, ValueError("bad token id"))
+    sched._end_emit()
+    assert [(o.token_ids, o.finish_reason) for o in col.outputs] == [
+        ([7, 8], None), ([], "error")]
+    assert col.outputs[0].prompt_tokens == len(_PROMPT)
+    assert seq.finished and seq.frame is None

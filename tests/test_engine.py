@@ -293,6 +293,183 @@ class TestScheduler:
         run(body(), timeout=60)
 
 
+class _CountingLoop:
+    """Stands in for the event loop: counts `call_soon_threadsafe`, runs
+    the callback at once, and notes what the scheduler still held."""
+
+    def __init__(self, sched):
+        self.sched = sched
+        self.queues = {}  # request id -> _Inbox
+        self.calls = []  # per hand-over: [(queue tag, EngineOutput)]
+        self.slots_at_call = []
+
+    def call_soon_threadsafe(self, fn, *args):
+        before = {tag: len(q.items) for tag, q in self.queues.items()}
+        fn(*args)
+        self.calls.append([(tag, out) for tag, q in self.queues.items()
+                           for out in q.items[before[tag]:]])
+        self.slots_at_call.append(
+            {s.request.request_id for s in self.sched._slots
+             if s is not None})
+
+
+class _Inbox:
+    def __init__(self):
+        self.items = []
+
+    def put_nowait(self, item):
+        self.items.append(item)
+
+
+class TestOutbox:
+    """The worker's out-tray behind a hand-driven scheduler: how often
+    the scheduler thread wakes the event loop."""
+
+    def _setup(self, runner, n, max_tokens=20, block=8):
+        from dynamo_tpu.engine.worker import Outbox
+
+        sched = InferenceScheduler(runner)
+        sched.decode_block = block
+        sched.decode_pipeline = 1
+        outbox = Outbox()
+        sched.on_emit_end = outbox.flush
+        loop = _CountingLoop(sched)
+        for i in range(n):
+            rid = f"r{i}"
+            inbox = loop.queues[rid] = _Inbox()
+            sched.submit(
+                _request(range(1 + i, 9 + i), max_tokens=max_tokens,
+                         rid=rid),
+                lambda o, q=inbox: outbox.post(loop, q, o))
+        return sched, outbox, loop
+
+    @staticmethod
+    def _step(sched):
+        sched._drain_control()
+        sched._drain_incoming()
+        sched._step()
+
+    def test_one_wake_up_for_a_drained_block_of_many_sequences(self, runner):
+        sched, outbox, loop = self._setup(runner, 3)
+        self._step(sched)  # prefill dispatched, nothing to hand over
+        assert loop.calls == []
+        self._step(sched)  # the three first tokens: ONE hand-over
+        assert len(loop.calls) == 1
+        assert sorted(tag for tag, _ in loop.calls[0]) == ["r0", "r1", "r2"]
+        for _ in range(2):  # two fused blocks: one hand-over each,
+            before = len(loop.calls)  # three frames of eight tokens
+            self._step(sched)
+            assert len(loop.calls) == before + 1
+            assert [len(out.token_ids) for _, out in loop.calls[-1]] == [8] * 3
+        assert outbox.handovers == len(loop.calls) == 3
+        assert sched.stats.emit_frames == 9
+        sched.stop()
+
+    def test_the_finish_frame_is_handed_over_before_the_pages_go(self, runner):
+        sched, outbox, loop = self._setup(runner, 2, max_tokens=5)
+        for _ in range(3):
+            self._step(sched)
+        finishing = [i for i, call in enumerate(loop.calls)
+                     if any(out.finish_reason for _, out in call)]
+        assert len(finishing) == 1  # both rows end in the same block
+        call = loop.calls[finishing[0]]
+        assert [(out.finish_reason, len(out.token_ids))
+                for _, out in call] == [("length", 4)] * 2
+        # when the loop was woken the scheduler still held both slots:
+        # `_reap_finished` released their pages after the hand-over
+        assert loop.slots_at_call[finishing[0]] == {"r0", "r1"}
+        assert all(s is None for s in sched._slots)
+        sched.stop()
+
+    @pytest.mark.parametrize("path", ["abort", "fail", "drain", "death"])
+    def test_one_wake_up_at_once_when_sequences_are_vacated(self, runner,
+                                                            path):
+        """Abort, failure, drain and engine death each finish every
+        live, waiting and queued request in ONE hand-over, before they
+        return (no frame waits for a step that may never come)."""
+        sched, outbox, loop = self._setup(runner, 3)
+        self._step(sched)
+        self._step(sched)  # three rows decoding
+        extra = _Inbox()   # and one request still in the incoming queue
+        loop.queues["late"] = extra
+        sched._incoming.put((_request(range(8), rid="late"),
+                             lambda o: outbox.post(loop, extra, o),
+                             type("H", (), {"seq": None,
+                                            "_cancelled": False})(), {}))
+        before = len(loop.calls)
+        if path == "abort":
+            assert sched.abort_all("reshard") == 3
+            want, late = "migrate", False
+        elif path == "fail":
+            assert sched._finish_all("deadline") == 3
+            want, late = "error", False
+        elif path == "drain":
+            report = sched.drain_sweep()
+            assert len(report["replay"]) == 3
+            want, late = "migrate", False
+        else:
+            sched._die(RuntimeError("device lost"))
+            want, late = "error", True
+        assert len(loop.calls) == before + 1
+        got = {tag: out.finish_reason for tag, out in loop.calls[-1]}
+        assert got == {**{f"r{i}": want for i in range(3)},
+                       **({"late": "error"} if late else {})}
+        assert all(s is None for s in sched._slots)
+        if path == "death":
+            # a submit that follows the death is failed on its caller's
+            # thread, and handed over there and then
+            after = _Inbox()
+            loop.queues["after"] = after
+            sched.submit(_request(range(8), rid="after"),
+                         lambda o: outbox.post(loop, after, o))
+            assert len(loop.calls) == before + 2
+            assert [out.finish_reason for out in after.items] == ["error"]
+        sched.stop()
+
+    def test_nothing_is_lost_when_threads_post_and_flush_together(self, run):
+        """The engine's death can put a `submit` caller's thread beside
+        the scheduler thread on the out-tray: every frame posted is
+        delivered once, each poster's in its own order."""
+        import sys
+        import threading
+
+        from dynamo_tpu.engine.worker import Outbox
+
+        posters, each = 16, 400
+
+        async def body():
+            loop = asyncio.get_running_loop()
+            outbox, queues = Outbox(), [asyncio.Queue() for _ in range(posters)]
+
+            def work(i):
+                for n in range(each):
+                    outbox.post(loop, queues[i], EngineOutput(token_ids=[n]))
+                    if n % 7 == i % 7:
+                        outbox.flush()
+                outbox.flush()
+
+            threads = [threading.Thread(target=work, args=(i,))
+                       for i in range(posters)]
+            interval = sys.getswitchinterval()
+            sys.setswitchinterval(1e-5)
+            try:
+                for t in threads:
+                    t.start()
+                for t in threads:
+                    await loop.run_in_executor(None, t.join, 30)
+            finally:
+                sys.setswitchinterval(interval)
+            assert not any(t.is_alive() for t in threads)
+            for q in queues:
+                got = [(await asyncio.wait_for(q.get(), 10)).token_ids[0]
+                       for _ in range(each)]
+                assert got == list(range(each))
+                assert q.empty()
+            assert 1 <= outbox.handovers <= posters * each
+
+        run(body(), timeout=60)
+
+
 class TestTpuWorkerE2E:
     def test_worker_serves_and_publishes_events(self, run, mem_runtime_config):
         async def body():

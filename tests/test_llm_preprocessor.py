@@ -168,6 +168,96 @@ class TestDeltaGenerator:
         assert resp["object"] == "chat.completion"
 
 
+class TestFramesOfSeveralTokens:
+    """A decode frame holds what one drain gave a sequence (up to block
+    x depth tokens). A client must get, byte for byte, the SSE chunks
+    and the `usage` that as many one-token frames give."""
+
+    SCENARIOS = {
+        # text, stop strings, finish, eos appended, logprobs
+        "plain": ("hello world, again", None, "length", False, False),
+        "multibyte_split": ("wörld 你好 ok", None, "length", False, False),
+        # frames of 4 behind the first token: a|bcEN|Dxyz -> "END" spans two
+        "stop_string_spans_two_frames": ("abcENDxyzw", ["END"], "length",
+                                         False, False),
+        "stop_prefix_released_at_the_end": ("abcEN", ["END"], "length",
+                                            False, False),
+        "trimmed_eos": ("bye now", None, "stop", True, False),
+        "logprobs": ("hi there", None, "length", False, True),
+        "logprobs_trimmed_eos": ("hi all", None, "stop", True, True),
+    }
+
+    def _gen(self, kind, stop, logprobs):
+        pre = OpenAIPreprocessor(_card())
+        body = {"max_tokens": 64, "stop": stop}
+        if logprobs:
+            body.update({"logprobs": True, "top_logprobs": 2}
+                        if kind == "chat" else {"logprobs": 2})
+        if kind == "chat":
+            req = pre.preprocess_chat(
+                {"messages": [{"role": "user", "content": "hi"}], **body})
+        else:
+            req = pre.preprocess_completions({"prompt": "hi", **body})
+        req.eos_token_ids = [ByteTokenizer.EOS]
+        gen = DeltaGenerator(pre, req, kind=kind)
+        gen.chunk_id, gen.created = "chatcmpl-fixed", 1
+        return gen, pre
+
+    @staticmethod
+    def _frames(ids, sizes, finish, logprobs, prompt_tokens):
+        """The token stream cut into frames of `sizes` (the last repeats),
+        as the scheduler builds them: `prompt_tokens` on the first, the
+        finish on the last, logprob entries one a token."""
+        frames, at = [], 0
+        sizes = list(sizes)
+        while at < len(ids):
+            n = sizes.pop(0) if len(sizes) > 1 else sizes[0]
+            part = ids[at:at + n]
+            frames.append(EngineOutput(
+                token_ids=part,
+                prompt_tokens=prompt_tokens if at == 0 else None,
+                logprobs=([-0.25 * (at + j + 1) for j in range(len(part))]
+                          if logprobs else None),
+                top_logprobs=([[[t, -0.25 * (at + j + 1)], [65 + (at + j) % 20, -3.0]]
+                               for j, t in enumerate(part)]
+                              if logprobs else None)))
+            at += n
+        frames[-1].finish_reason = finish
+        return frames
+
+    @pytest.mark.parametrize("kind", ["chat", "completions"])
+    @pytest.mark.parametrize("scenario", sorted(SCENARIOS))
+    def test_a_frame_of_k_streams_as_k_frames_of_one(self, scenario, kind):
+        import json
+
+        text, stop, finish, eos, logprobs = self.SCENARIOS[scenario]
+        streams = {}
+        for name, sizes in (("one", [1]), ("block", [1, 4]),
+                            ("chained", [1, 8]), ("whole", [64])):
+            gen, pre = self._gen(kind, stop, logprobs)
+            ids = pre.tokenizer.encode(text)
+            if eos:
+                ids = ids + [ByteTokenizer.EOS]
+            chunks = []
+            for frame in self._frames(ids, sizes, finish, logprobs,
+                                      len(gen.request.token_ids)):
+                chunks.extend(gen.on_output(frame))
+            streams[name] = (
+                [f"data: {json.dumps(c)}\n\n".encode() for c in chunks],
+                gen.usage(), json.dumps(gen.final_response()))
+        sse, usage, final = streams["one"]
+        assert len(sse) >= 2 and usage["completion_tokens"] > 0
+        for name in ("block", "chained", "whole"):
+            assert streams[name][0] == sse, name  # every SSE chunk, in order
+            assert streams[name][1] == usage, name
+            assert streams[name][2] == final, name
+        if logprobs:
+            key = "content" if kind == "chat" else "tokens"
+            per_chunk = [len(json.loads(c[6:])["choices"][0]["logprobs"][key])
+                         for c in sse if b'"logprobs"' in c]
+            assert set(per_chunk) == {1}  # an entry rides its token's chunk
+
+
 class TestPriorityWireSurface:
     """Multi-tenant QoS wire surface (docs/multi-tenancy.md): the
     `priority` / `tenant` body fields normalize onto

@@ -148,8 +148,8 @@ def test_step_part_counters_equal_the_step_sample_sums():
     clk = _Clock()
     st = StepTrace(clock=clk)
     samples = []
-    for prep, submit, overlap, blocked in ((1, 2, 4, 3), (5, 1, 0, 7),
-                                           (0.5, 0.25, 2, 0)):
+    for prep, submit, overlap, blocked, emit in (
+            (1, 2, 4, 3, 0.5), (5, 1, 0, 7, 1), (0.5, 0.25, 2, 0, 0.25)):
         st.begin()
         clk.t += prep / 1e3
         with st.dispatch("decode"):
@@ -157,7 +157,9 @@ def test_step_part_counters_equal_the_step_sample_sums():
         clk.t += overlap / 1e3
         with st.drain("decode"):
             clk.t += blocked / 1e3
-        samples.append(st.commit(prep + submit + overlap + blocked))
+        with st.emit():  # the drained tokens committed and handed over
+            clk.t += emit / 1e3
+        samples.append(st.commit(prep + submit + overlap + blocked + emit))
     fake = types.SimpleNamespace(
         scheduler=types.SimpleNamespace(
             steptrace=st, stats=SchedulerStats(),
@@ -166,7 +168,7 @@ def test_step_part_counters_equal_the_step_sample_sums():
         _roof_prev=None, _roofline=None)
     family = "dynamo_step_part_ms_total"
     before = {p: sample(family, part=p)
-              for p in ("wall", "prep", "dispatch", "drain_wait")}
+              for p in ("wall", "prep", "dispatch", "drain_wait", "emit")}
     TpuWorker._publish_steptrace_metrics(fake)
     grew = {p: sample(family, part=p) - before[p] for p in before}
     assert grew["prep"] == pytest.approx(sum(s.prep_ms for s in samples))
@@ -177,9 +179,11 @@ def test_step_part_counters_equal_the_step_sample_sums():
     assert grew["drain_wait"] == pytest.approx(
         sum(s.drain_ms for s in samples))
     assert grew["drain_wait"] == pytest.approx(10.0)
+    assert grew["emit"] == pytest.approx(sum(s.emit_ms for s in samples))
+    assert grew["emit"] == pytest.approx(1.75)
     # the denominator its readers use: the steps' wall itself
     assert grew["wall"] == pytest.approx(sum(s.wall_ms for s in samples))
-    assert grew["wall"] == pytest.approx(10 + 13 + 2.75)
+    assert grew["wall"] == pytest.approx(10.5 + 14 + 3)
     # drained: a second publish adds nothing
     TpuWorker._publish_steptrace_metrics(fake)
     assert sample(family, part="prep") - before["prep"] == \
@@ -304,8 +308,15 @@ def test_the_engine_gauges_carry_the_schedulers_counts(served):
     sched = served["sched"]
     fake = types.SimpleNamespace(
         scheduler=sched, runner=sched.runner, instance_id=0xfeed,
-        mesh=types.SimpleNamespace(local_devices=[]))
+        mesh=types.SimpleNamespace(local_devices=[]),
+        outbox=types.SimpleNamespace(handovers=7))
     TpuWorker._publish_engine_gauges(fake)
+    # four requests: a frame for each first token, then one a drained
+    # block a sequence, never one a token
+    frames = sample("dynamo_engine_emit_frames_total", worker="feed")
+    assert frames == sched.stats.emit_frames
+    assert 4 + 4 <= frames < 4 + sched.stats.decode_tokens
+    assert sample("dynamo_engine_emit_handovers_total", worker="feed") == 7
     launches = "dynamo_engine_launches"
     assert sample(launches, worker="feed", kind="prefill") == \
         sched.stats.prefill_launches
@@ -356,7 +367,8 @@ def test_a_prefill_launch_counts_its_positions_and_row_blocks():
     assert runner.prefill_row_blocks == {"live": 9 + 1, "skipped": 7}
     fake = types.SimpleNamespace(
         scheduler=sched, runner=runner, instance_id=0xb10c,
-        mesh=types.SimpleNamespace(local_devices=[]))
+        mesh=types.SimpleNamespace(local_devices=[]),
+        outbox=types.SimpleNamespace(handovers=0))
     TpuWorker._publish_engine_gauges(fake)
     assert sample("dynamo_engine_positions", worker="b10c",
                   kind="prefill") == 4352
@@ -373,7 +385,8 @@ def test_row_blocks_are_counted_with_int4_weights_only(served):
     assert runner.prefill_row_blocks == {"live": 0, "skipped": 0}
     TpuWorker._publish_engine_gauges(types.SimpleNamespace(
         scheduler=served["sched"], runner=runner, instance_id=0xbf16,
-        mesh=types.SimpleNamespace(local_devices=[])))
+        mesh=types.SimpleNamespace(local_devices=[]),
+        outbox=types.SimpleNamespace(handovers=0)))
     assert sample("dynamo_engine_positions", worker="bf16",
                   kind="prefill") == runner.prefill_positions
     assert REGISTRY.get_sample_value(
