@@ -155,6 +155,10 @@ class RunnerConfig:
     # streaming, the 7B single-chip bandwidth lever; models/quantize.py
     # scope notes).
     weight_dtype: str = "model"
+    # A model with window AND full attention layers: pages of its second
+    # page group (engine/pages.py `WindowPool`; `num_pages` stays the
+    # full group's).
+    window_pages: int = 0
 
     @property
     def max_context(self) -> int:
@@ -271,8 +275,13 @@ class ModelRunner:
         # A hybrid stack (models/hybrid.py): Mamba-2 state per slot
         # beside the KV pages, dropless experts told which they hold.
         self._hybrid = model_config.is_hybrid
+        # Window and full attention side by side: a second page group
+        # (its cache rides `kv_cache` as (full, window)), a second table
+        # and its base in every step program. Other models have neither.
+        self._windowed = model_config.has_window_layers
         if self._hybrid:
             self._check_hybrid(model_config, runner_config, mesh)
+        if model_config.has_recurrent_state:
             # No bucket under one chunk of the scan (published: 128): a
             # shorter launch reads the same weights, and every bucket
             # less is a row of programs less to compile (`prewarm`).
@@ -334,16 +343,8 @@ class ModelRunner:
                     f"int8 KV under tp leaves {shard_heads} kv head(s) "
                     "per shard; the compiled q8 attention kernel needs a "
                     "multiple of 4 — use kv_dtype='model' or a smaller tp")
-        base_kv_sharding = kv_cache_sharding(
-            mesh, head_sharded=not model_config.is_mla
-        )
-        if self._kv_quantized:
-            # (values, scales): the per-token scales are head-shared and
-            # lane-broadcast — replicated across tp shards.
-            self._kv_sharding = (base_kv_sharding,
-                                 NamedSharding(mesh, P()))
-        else:
-            self._kv_sharding = base_kv_sharding
+        self._kv_sharding = self._kv_cache_sharding(mesh)
+
         def _already_quantized(p) -> bool:
             """True when the incoming pytree already carries THIS
             runner's quantized leaves; a tree quantized in the other
@@ -390,22 +391,7 @@ class ModelRunner:
             params = jax.tree.map(jax.device_put, params,
                                   self._param_sharding)
         self.params = params
-        if self._kv_quantized:
-            from ..models.transformer import make_kv_cache_int8
-
-            kv_init = jax.jit(
-                lambda: make_kv_cache_int8(model_config,
-                                           runner_config.num_pages,
-                                           runner_config.page_size),
-                out_shardings=self._kv_sharding,
-            )
-        else:
-            kv_init = jax.jit(
-                lambda: make_kv_cache(model_config, runner_config.num_pages,
-                                      runner_config.page_size),
-                out_shardings=self._kv_sharding,
-            )
-        self.kv_cache = kv_init()
+        self.kv_cache = self._kv_cache_init()()
         self._rep = NamedSharding(mesh, P())  # replicated host inputs
         self.state = None
         # expert statistics (models/hybrid.moe_mixer) of launches whose
@@ -454,6 +440,37 @@ class ModelRunner:
         self.prefill_positions = 0
         self.prefill_row_blocks = {"live": 0, "skipped": 0}
 
+    def _kv_cache_sharding(self, mesh: Mesh):
+        """Sharding of `kv_cache` as the step programs donate it: one
+        array; (values, scales) for an int8 pool, whose per-token scales
+        are head-shared and lane-broadcast, so replicated across tp
+        shards; (full group, window group) for a model with window
+        layers."""
+        base = kv_cache_sharding(
+            mesh, head_sharded=not self.model_config.is_mla)
+        if self._kv_quantized:
+            return (base, NamedSharding(mesh, P()))
+        return (base, base) if self._windowed else base
+
+    def _kv_cache_init(self):
+        """The program that makes a zeroed paged cache under
+        `_kv_sharding`, run once at start and once a reshard."""
+        cfg, rc = self.model_config, self.config
+        if self._kv_quantized:
+            from ..models.transformer import make_kv_cache_int8
+
+            def make():
+                return make_kv_cache_int8(cfg, rc.num_pages, rc.page_size)
+        elif self._windowed:
+            def make():
+                return (make_kv_cache(cfg, rc.num_pages, rc.page_size),
+                        make_kv_cache(cfg, rc.window_pages, rc.page_size,
+                                      group="window"))
+        else:
+            def make():
+                return make_kv_cache(cfg, rc.num_pages, rc.page_size)
+        return jax.jit(make, out_shardings=self._kv_sharding)
+
     def _count_prefill(self, lengths: Sequence[int], rows: int,
                        bucket: int) -> None:
         """Host arithmetic on a launch's own lengths, no device sync."""
@@ -475,6 +492,51 @@ class ModelRunner:
         if rc.max_loras:
             raise ValueError(f"--max-loras: no adapter targets on {cfg.name} "
                              f"(layers {cfg.layer_pattern})")
+        if cfg.has_window_layers:
+            need = -(-cfg.sliding_window // rc.page_size) + 2
+            if rc.window_pages <= need:
+                raise ValueError(
+                    f"--window-pages {rc.window_pages}: {cfg.name} has "
+                    f"window layers (window {cfg.sliding_window}) and "
+                    f"needs a second page group of more than {need} pages "
+                    "(one decoding row's)")
+            if cfg.sliding_window % rc.page_size:
+                raise ValueError(
+                    f"--page-size {rc.page_size} does not divide the "
+                    f"window {cfg.sliding_window} of {cfg.name}")
+
+    # -- the window group's tables (a model with window layers) ------------
+
+    @property
+    def window_table_width(self) -> int:
+        """Columns of the window group's table in a decode program: a
+        decoding row's pages (engine/pages.py `bound`) and room to
+        a multiple of 8."""
+        blocks = self.model_config.sliding_window // self.config.page_size
+        return -(-(blocks + 3) // 8) * 8
+
+    def window_prefill_width(self, bucket: int) -> int:
+        """The same for a prefill program of `bucket` positions a row:
+        window + chunk keys, never the full layers' table."""
+        blocks = -(-(self.model_config.sliding_window + bucket)
+                   // self.config.page_size) + 1
+        return -(-blocks // 8) * 8
+
+    def _table_args(self, block_tables):
+        """Block tables as a step program takes them: one int32 array,
+        or for a model with window layers (full tables, window tables,
+        window base [B])."""
+        if self._windowed:
+            return tuple(jnp.asarray(t, jnp.int32) for t in block_tables)
+        return jnp.asarray(block_tables, jnp.int32)
+
+    def _idle_tables(self, rows: int, width: int):
+        """All-scratch tables of `rows` x `width` (warm-up launches)."""
+        tables = np.zeros((rows, width), np.int32)
+        if not self._windowed:
+            return tables
+        return (tables, np.zeros((rows, self.window_table_width), np.int32),
+                np.zeros(rows, np.int32))
 
     def _launch(self, fn, args, kwargs=None, phase: str = "decode"):
         """Run one compiled step. The caches go in donated behind the
@@ -533,11 +595,17 @@ class ModelRunner:
             from ..models.hybrid import forward_hybrid_decode
 
             kv, state = cache
+            window = None
+            if self._windowed:
+                block_tables, win_tables, win_base = block_tables
+                kv, win = kv
+                window = (win, win_tables, win_base)
             kv, state, logits, stats = forward_hybrid_decode(
                 params, cfg, tokens, positions, kv, state, block_tables,
                 kv_lens, active,
                 decode_attention_fn=self._decode_attention_fn,
-                ssm_path=self._ssm_path, gmm_path=self._gmm_path)
+                ssm_path=self._ssm_path, gmm_path=self._gmm_path,
+                window=window)
             return (kv, state), logits, (stats,)
 
         def one(params, kv, tokens, positions, block_tables, kv_lens,
@@ -764,7 +832,7 @@ class ModelRunner:
         args = [
             jnp.asarray(tokens, jnp.int32),
             jnp.asarray(positions, jnp.int32),
-            jnp.asarray(block_tables, jnp.int32),
+            self._table_args(block_tables),
             jnp.asarray(kv_lens, jnp.int32), jnp.asarray(active, bool),
             jnp.asarray(temperature, jnp.float32),
             jnp.asarray(top_p, jnp.float32), jnp.asarray(top_k, jnp.int32),
@@ -908,17 +976,22 @@ class ModelRunner:
 
         def step(params, kv, tokens, positions, block_table, kv_lens,
                  valid, last_idx, temperature, top_p, top_k, seeds,
-                 lora=None, lora_idx=None, extra_embeds=None, slots=None):
+                 lora=None, lora_idx=None, extra_embeds=None, slots=None,
+                 window=None):
             if self._hybrid:
                 from ..models.hybrid import forward_hybrid
 
                 # logits of each row's last valid position only: a
                 # [rows x T, vocab] float32 never exists on this path
                 kv, state = kv
+                if window is not None:  # (tables [B, pages], base [B])
+                    kv, win = kv
+                    window = (win, *window)
                 kv, state, last, stats = forward_hybrid(
                     params, cfg, tokens, positions, kv, state, slots,
                     block_table, kv_lens, valid, last_idx,
-                    attention_fn=attention_fn, gmm_path=self._gmm_path)
+                    attention_fn=attention_fn, gmm_path=self._gmm_path,
+                    window=window)
                 kv, extra = (kv, state), (stats,)
             else:
                 kv, logits = forward(
@@ -1111,6 +1184,29 @@ class ModelRunner:
         buckets = self.config.prefill_buckets
         return max(1, buckets[-1] // buckets[0])
 
+    def prefill_launch_fits(self, lengths: Sequence[int]) -> bool:
+        """A model with window layers: whether rows of these chunk
+        lengths make a launch (rows to a power of two x the longest's
+        bucket) of no more positions than the token budget. Its tables
+        are wide, so padding past the budget is paid in gathered keys,
+        and each rows x bucket shape is a program `--prewarm full`
+        compiles."""
+        rows = 1 << max(0, len(lengths) - 1).bit_length()
+        return (rows * self._bucket_for(max(lengths))
+                <= self.config.prefill_buckets[-1])
+
+    def _window_rows(self, bucket: int, rows: int, windows):
+        """Each row's (window table, base) as the prefill program's
+        `window` argument: tables padded to the bucket's width with the
+        scratch page."""
+        width = self.window_prefill_width(bucket)
+        tables = np.zeros((rows, width), np.int32)
+        base = np.zeros(rows, np.int32)
+        for i, (table, first_token) in enumerate(windows):
+            tables[i, :len(table)] = table
+            base[i] = first_token
+        return jnp.asarray(tables), jnp.asarray(base)
+
     # -- host API ----------------------------------------------------------
 
     def prefill_chunk(
@@ -1124,6 +1220,8 @@ class ModelRunner:
         chunk_embeds: Optional[np.ndarray] = None,  # [t, H] splice rows
         return_device: bool = False,
         slot: int = 0,  # the scheduler's slot: where recurrent state lives
+        window=None,  # (window group's pages from its first held block,
+        #               that block's first position): window layers only
     ) -> int:
         """Run one prefill chunk; returns the sampled token id (meaningful
         only on the final chunk). `chunk_embeds` rows replace the token
@@ -1161,6 +1259,8 @@ class ModelRunner:
         kwargs: dict = {}
         if self._hybrid:
             kwargs["slots"] = jnp.asarray([slot], jnp.int32)
+        if self._windowed:
+            kwargs["window"] = self._window_rows(bucket, 1, [window])
         if self.lora_pack is not None:
             kwargs["lora"] = self.lora_pack
             kwargs["lora_idx"] = jnp.asarray([lora_idx], jnp.int32)
@@ -1194,7 +1294,7 @@ class ModelRunner:
     def prefill_chunk_batch(
         self,
         rows: list,  # (tokens, start_pos, block_table, kv_len_after,
-        #              sampling, lora_idx[, slot]) per sequence
+        #              sampling, lora_idx[, slot[, window]]) per sequence
         want_samples: bool = False,
     ):
         """Run SEVERAL sequences' prefill chunks in one compiled dispatch
@@ -1233,9 +1333,11 @@ class ModelRunner:
         lora_rows = np.zeros(b, np.int32)
         # a padded row's state write is dropped: its slot is past the end
         slots = np.full(b, self.config.max_batch, np.int32)
+        windows = []
         for i, (tokens, start, table, kv_after, sampling, lidx, *slot) in \
                 enumerate(rows):
             slots[i] = slot[0] if slot else 0
+            windows.append(slot[1] if len(slot) > 1 else None)
             t = len(tokens)
             tok[i, :t] = tokens
             pos[i, :t] = np.arange(start, start + t)
@@ -1255,6 +1357,8 @@ class ModelRunner:
         kwargs: dict = {}
         if self._hybrid:
             kwargs["slots"] = jnp.asarray(slots)
+        if self._windowed:
+            kwargs["window"] = self._window_rows(bucket, b, windows)
         if self.lora_pack is not None:
             kwargs["lora"] = self.lora_pack
             kwargs["lora_idx"] = jnp.asarray(lora_rows)
@@ -1311,7 +1415,7 @@ class ModelRunner:
         args = [
             jnp.asarray(tokens, jnp.int32),
             jnp.asarray(positions, jnp.int32),
-            jnp.asarray(block_tables, jnp.int32),
+            self._table_args(block_tables),
             jnp.asarray(kv_lens, jnp.int32), jnp.asarray(active, bool),
             jnp.asarray(temperature, jnp.float32),
             jnp.asarray(top_p, jnp.float32), jnp.asarray(top_k, jnp.int32),
@@ -1414,32 +1518,11 @@ class ModelRunner:
                               dtype=self.config.weight_dtype)
             axes = self._quantize_axes(axes, self.model_config)
         self._param_sharding = param_shardings(mesh, axes)
-        base_kv_sharding = kv_cache_sharding(
-            mesh, head_sharded=not self.model_config.is_mla
-        )
         self.params = jax.tree.map(
             jax.device_put, self.params, self._param_sharding
         )
-        if self._kv_quantized:
-            from ..models.transformer import make_kv_cache_int8
-
-            self._kv_sharding = (base_kv_sharding,
-                                 NamedSharding(mesh, P()))
-            kv_init = jax.jit(  # dynajit: disable=DJ102 -- elastic reshard is a rare admin path; the pool init deliberately recompiles for the new mesh
-                lambda: make_kv_cache_int8(self.model_config,
-                                           self.config.num_pages,
-                                           self.config.page_size),
-                out_shardings=self._kv_sharding,
-            )
-        else:
-            self._kv_sharding = base_kv_sharding
-            kv_init = jax.jit(  # dynajit: disable=DJ102 -- same rare reshard path
-                lambda: make_kv_cache(self.model_config,
-                                      self.config.num_pages,
-                                      self.config.page_size),
-                out_shardings=self._kv_sharding,
-            )
-        self.kv_cache = kv_init()
+        self._kv_sharding = self._kv_cache_sharding(mesh)
+        self.kv_cache = self._kv_cache_init()()
         self._rep = NamedSharding(mesh, P())
         if self.lora_pack is not None:
             self.lora_pack = jax.device_put(self.lora_pack, self._rep)
@@ -1469,6 +1552,11 @@ class ModelRunner:
         forces it so every host can read the full bundle locally)."""
         from ..ops.block_copy import gather_kv_blocks, gather_kv_blocks_q8
 
+        if self._windowed:
+            raise RuntimeError(
+                f"{self.model_config.name} keeps two page groups; the full "
+                "group's pages alone cannot be transferred, offloaded or "
+                "parked (what lay behind the window is freed)")
         if self.model_config.has_recurrent_state:
             # pages without the state that produced them resume nothing
             raise RuntimeError(
@@ -1600,15 +1688,25 @@ class ModelRunner:
         p = self.config.max_pages_per_seq
         self.decode(
             np.zeros(b, np.int32), np.zeros(b, np.int32),
-            np.zeros((b, p), np.int32), np.zeros(b, np.int32),
+            self._idle_tables(b, p), np.zeros(b, np.int32),
             np.zeros(b, bool), np.ones(b, np.float32),
             np.ones(b, np.float32), np.zeros(b, np.int32),
             np.zeros(b, np.uint32),
         )
         self.prefill_chunk(
             np.zeros(1, np.int32), 0, np.zeros(p, np.int32), 1,
-            (0.0, 1.0, 0, 0),
+            (0.0, 1.0, 0, 0), **self._idle_row(),
         )
+
+    def _idle_row(self) -> dict:
+        """What a warm-up prefill row passes beside its scratch table: a
+        state slot past the end, an empty window table."""
+        row: dict = {}
+        if self._hybrid:
+            row["slot"] = self.config.max_batch
+        if self._windowed:
+            row["window"] = ((), 0)
+        return row
 
     def prewarm(self, spec_widths: Optional[Sequence[int]] = None,
                 launches: bool = False, block: int = 1) -> None:
@@ -1653,7 +1751,7 @@ class ModelRunner:
             self.prefill_chunk(
                 np.zeros(bucket, np.int32), 0, np.zeros(p, np.int32),
                 min(bucket, self.config.max_context), greedy,
-                **({"slot": b} if self._hybrid else {}),
+                **self._idle_row(),
             )
         if launches:
             self._prewarm_launches(buckets, block)
@@ -1678,11 +1776,16 @@ class ModelRunner:
         rows = 2
         # rows are padded to a power of two: the limit's own ceiling
         limit = 1 << (min(self.max_prefill_rows, b) - 1).bit_length()
+        # a warm-up row's [slot[, window]] behind the six every row has
+        tail = tuple(self._idle_row().values())
         while rows <= limit:
             for bucket in buckets:
                 n = min(bucket, self.config.max_context - 1)
+                if self._windowed and not self.prefill_launch_fits(
+                        [n] * rows):
+                    continue  # the scheduler never makes this launch
                 row = (np.zeros(n, np.int32), 0, np.zeros(p, np.int32), n,
-                       greedy, 0, *((b,) if self._hybrid else ()))
+                       greedy, 0, *tail)
                 toks = self.prefill_chunk_batch([row] * rows)
                 # the scheduler picks a row's token on the device
                 # (`_prefill_batch`): one tiny program for each batch size
@@ -1694,7 +1797,7 @@ class ModelRunner:
                         np.zeros(b, np.int32), np.zeros(b, np.uint32))
             width = bucket_table_width(1, p)
             while True:
-                args = (np.zeros((b, width), np.int32), np.zeros(b, np.int32),
+                args = (self._idle_tables(b, width), np.zeros(b, np.int32),
                         np.zeros(b, bool), *sampling)
                 toks = self.decode_multi(*idle, *args, k=block,
                                          return_device=True)

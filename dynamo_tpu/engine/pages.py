@@ -8,6 +8,16 @@ the router indexes (ref: KVBM block lifecycle Reset->Complete->Registered,
 docs/design-docs/kvbm-design.md; vLLM-style prefix caching).
 
 Page 0 is reserved as a scratch page for padding writes; never allocated.
+
+A model with window AND full attention layers has TWO page groups
+(docs/prompt-caching.md): the full group is the pool above, a page for
+every `page_size` positions of a sequence for as long as it lives; the
+window group is a `WindowPool`, a second free list over a second device
+array that holds the window layers only. A sequence's `WindowLease`
+covers the blocks its window layers can still see, allocated ahead of
+the positions a launch will write and freed behind the window's lower
+edge while the sequence lives. Neither group of such a model has a
+prefix cache.
 """
 
 from __future__ import annotations
@@ -173,3 +183,103 @@ class PagePool:
         if hashes:
             self.on_removed(hashes)
         return hashes
+
+
+@dataclasses.dataclass
+class WindowLease:
+    """One sequence's hold on the window group: `pages[j]` is the page
+    of block `first + j` (a block = `page_size` positions), which is how
+    its block table reads too: column 0 is block `first`, and positions
+    in that table's frame count from `first * page_size`. `reserved` is
+    what admission set aside for it: pages it may always take."""
+    reserved: int = 0
+    first: int = 0
+    pages: list[int] = dataclasses.field(default_factory=list)
+    edge: int = 0  # the oldest position its window layers still read
+
+    @property
+    def owed(self) -> int:
+        return max(0, self.reserved - len(self.pages))
+
+
+class WindowPool:
+    """The window group's free list. Admission `reserve`s the most a
+    decoding row can hold (`bound`), so a sequence that has a slot
+    never waits for a page to decode with; a prefill chunk takes what it
+    needs beyond that from the unreserved remainder and goes without
+    (this launch) when there is none. No prefix cache, no hashes."""
+
+    def __init__(self, num_pages: int, page_size: int, window: int) -> None:
+        self.num_pages = num_pages
+        self.page_size = page_size
+        self.window = window
+        self._free: list[int] = list(range(num_pages - 1, 0, -1))
+        self._owed = 0  # reserved and not yet taken, over every lease
+        # pages returned because they fell behind a window (not at
+        # release), and the positions the windows' lower edges moved by
+        # (one page goes back for every `page_size` of them when sound),
+        # each by the phase that moved it; allocations refused
+        self.freed_behind = {"prefill": 0, "decode": 0}
+        self.edge_tokens = {"prefill": 0, "decode": 0}
+        self.alloc_fail = 0
+
+    def bound(self, ahead: int) -> int:
+        """Pages a row holds at most: the window and the `ahead`
+        positions the launch under way may write (a decode block's
+        look-ahead, a prefill chunk's tokens), wherever they start
+        within a page."""
+        return -(-(self.window + ahead - 1) // self.page_size) + 1
+
+    def free_count(self) -> int:
+        return len(self._free)
+
+    def unreserved(self) -> int:
+        return len(self._free) - self._owed
+
+    def reserve(self, pages: int) -> Optional[WindowLease]:
+        if pages > self.unreserved():
+            self.alloc_fail += 1
+            return None
+        self._owed += pages
+        return WindowLease(reserved=pages)
+
+    def advance(self, lease: WindowLease, lo_token: int, hi_token: int,
+                phase: str) -> bool:
+        """Make the lease cover positions lo_token..hi_token: free the
+        blocks wholly before `lo_token` (the oldest position any window
+        layer of a launch still reads), then allocate up to the block of
+        `hi_token` (the last it writes). False, and nothing allocated,
+        where the pool cannot give the pages (those behind are freed
+        either way)."""
+        ps = self.page_size
+        self.edge_tokens[phase] += max(0, lo_token - lease.edge)
+        lease.edge = max(lease.edge, lo_token)
+        lo_block = max(lease.first, lo_token // ps)
+        behind = min(lo_block - lease.first, len(lease.pages))
+        if lo_block > lease.first:
+            self._give_back(lease, behind)
+            lease.first = lo_block
+            self.freed_behind[phase] += behind
+        need = hi_token // ps + 1 - lease.first - len(lease.pages)
+        if need <= 0:
+            return True
+        extra = need - min(need, lease.owed)
+        if extra > self.unreserved():
+            self.alloc_fail += 1
+            return False
+        self._owed -= need - extra
+        lease.pages.extend(self._free.pop() for _ in range(need))
+        return True
+
+    def _give_back(self, lease: WindowLease, n: int) -> None:
+        owed = lease.owed
+        self._free.extend(lease.pages[:n])
+        del lease.pages[:n]
+        self._owed += lease.owed - owed
+
+    def release(self, lease: WindowLease) -> None:
+        """The sequence is done (finished, cancelled, preempted): every
+        page and the reservation go back."""
+        self._give_back(lease, len(lease.pages))
+        self._owed -= lease.reserved
+        lease.reserved = 0

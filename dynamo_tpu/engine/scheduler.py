@@ -43,7 +43,7 @@ from ..runtime.flight_recorder import get_recorder
 from ..runtime.logging import get_logger
 from ..tokens import TokenBlockSequence, compute_block_hashes
 from .model_runner import ModelRunner, bucket_table_width
-from .pages import PageAllocation, PagePool
+from .pages import PageAllocation, PagePool, WindowLease, WindowPool
 from .spec import BlockLookahead, NGramProposer, SlotSpec, propose_for
 
 log = get_logger("engine.scheduler")
@@ -159,6 +159,9 @@ class _Seq:
     # goes out when the section ends (`_end_emit`), or at once with a
     # finish; never held across sections.
     frame: Optional[EngineOutput] = None
+    # A model with window layers: this sequence's hold on the window
+    # group (engine/pages.py), from admission to release.
+    window: Optional[WindowLease] = None
 
     @property
     def rank(self) -> int:
@@ -205,6 +208,10 @@ class SchedulerStats:
     prefill_launches: int = 0
     decode_block_launches: int = 0
     reserved_page_ms: float = 0.0
+    # The window group's twin (dynamo_kv_window_reserved_page_ms): pages
+    # of the second pool held by sequences in a slot x the step's wall
+    # ms; 0 for a model without window layers.
+    window_reserved_page_ms: float = 0.0
     # Token frames closed (dynamo_engine_emit_frames_total): one a
     # sequence an emitting section, so decode tokens over it is the
     # tokens a frame.
@@ -319,9 +326,33 @@ class InferenceScheduler:
         model_config = getattr(runner, "model_config", None)
         self._recurrent = bool(getattr(model_config, "has_recurrent_state",
                                        False))
+        # A model with window AND full attention layers: a second pool
+        # for the window layers' pages (`self.pool` stays the full
+        # group), allocated ahead of each launch and freed behind the
+        # window while a sequence lives. A prefix hit would need the full
+        # group's pages of the prefix AND the window group's last
+        # positions before it, which nothing keeps: neither pool has a
+        # prefix cache, and no `stored` event is published.
+        self._windowed = bool(getattr(model_config, "has_window_layers",
+                                      False))
         self.pool = PagePool(cfg.num_pages, on_stored=_stored,
                              on_removed=on_removed,
-                             prefix_cache=not self._recurrent)
+                             prefix_cache=not (self._recurrent
+                                               or self._windowed))
+        self.win_pool: Optional[WindowPool] = None
+        if self._windowed:
+            self.win_pool = WindowPool(cfg.window_pages, cfg.page_size,
+                                       model_config.sliding_window)
+            # positions a decode launch may write past the one it reads
+            self._win_lookahead = (self.decode_block
+                                   * max(1, self.decode_pipeline))
+            if (self.win_pool.bound(self._win_lookahead)
+                    > runner.window_table_width):
+                raise ValueError(
+                    f"DYNT_DECODE_BLOCK x DYNT_DECODE_PIPELINE = "
+                    f"{self._win_lookahead} positions ahead do not fit "
+                    f"the window group's {runner.window_table_width}-page "
+                    "decode table")
         if kvbm is not None:
             # Offload gathers ride the dispatch/drain gap (run_in_gap):
             # they execute while the decode block is busy on device, and
@@ -373,6 +404,10 @@ class InferenceScheduler:
         self._tokens = np.zeros(b, np.int32)
         self._positions = np.zeros(b, np.int32)
         self._tables = np.zeros((b, p), np.int32)
+        if self._windowed:
+            self._win_tables = np.zeros((b, runner.window_table_width),
+                                        np.int32)
+            self._win_base = np.zeros(b, np.int32)
         self._kv_lens = np.zeros(b, np.int32)
         self._active = np.zeros(b, bool)
         self._temp = np.ones(b, np.float32)
@@ -514,6 +549,12 @@ class InferenceScheduler:
         sequence is reaped."""
         return sum(len(seq.alloc.cached_pages) + len(seq.alloc.new_pages)
                    for seq in list(self._slots) if seq is not None)
+
+    def window_reserved_pages(self) -> int:
+        """The same for the window group: pages its sequences HOLD (a
+        reservation not yet taken is not a page)."""
+        return sum(len(seq.window.pages) for seq in list(self._slots)
+                   if seq is not None and seq.window is not None)
 
     def lora_in_flight(self, lora_slot: int) -> int:
         """Sequences (admitted, waiting, or just submitted) still bound to
@@ -781,6 +822,9 @@ class InferenceScheduler:
             seq = self._waiting[0]
             if seq.cancelled:
                 self._waiting.pop(0)
+                if seq.window is not None:  # reserved, never admitted
+                    self.win_pool.release(seq.window)
+                    seq.window = None
                 continue
             # A parked sequence of the head's class or better resumes
             # BEFORE the head admits (it was admitted first — letting a
@@ -799,7 +843,7 @@ class InferenceScheduler:
                 # pass — the late pass runs with a decode block in
                 # flight whose drain would append tokens to a victim
                 # that no longer owns its pages.
-                if allow_preempt and self._try_preempt_for(seq):
+                if allow_preempt and self._try_preempt_for(seq, "slot"):
                     continue
                 break
             total_pages = self._page_span(seq.prompt_len,
@@ -811,11 +855,23 @@ class InferenceScheduler:
                 total_pages = self._page_span(
                     seq.prompt_len, seq.request.sampling.max_tokens,
                     with_slack=False)
+            if self._windowed and seq.window is None:
+                # The window group first (it has nothing to undo): what
+                # a decoding row holds at most, or the whole sequence
+                # where that is less, set aside for as long as it lives.
+                seq.window = self.win_pool.reserve(min(
+                    total_pages,
+                    self.win_pool.bound(self._win_lookahead)))
+                if seq.window is None:
+                    if allow_preempt and self._try_preempt_for(seq,
+                                                               "window"):
+                        continue
+                    break
             alloc = self.pool.allocate(seq.block_hashes, total_pages)
             if alloc is None:
                 # Page starvation is the other preemption trigger: a
                 # parked batch slot returns its pages to the pool.
-                if allow_preempt and self._try_preempt_for(seq):
+                if allow_preempt and self._try_preempt_for(seq, "full"):
                     continue
                 break  # no pages; retry next iteration
             # Never skip the whole prompt: recompute at least the last token
@@ -881,9 +937,11 @@ class InferenceScheduler:
                 best = (key, seq)
         return best[1] if best is not None else None
 
-    def _try_preempt_for(self, head: _Seq) -> bool:
+    def _try_preempt_for(self, head: _Seq, short_of: str) -> bool:
         """Free a slot (and its pages) for `head` by preempting a
-        lower-class victim. Returns True only when the park path freed
+        lower-class victim; `short_of` is what admission ran out of (a
+        `slot`, the `full` page group, the `window` group: the counter's
+        `group` label). Returns True only when the park path freed
         capacity NOW (caller retries admission); a migrate fallback
         returns False — its slot and pages come back at reap, END of
         this step, so retrying inside this pass would only cascade into
@@ -893,9 +951,9 @@ class InferenceScheduler:
         victim = self._preempt_victim(head.rank)
         if victim is None:
             return False
-        return self._preempt_seq(victim)
+        return self._preempt_seq(victim, short_of)
 
-    def _preempt_seq(self, victim: _Seq) -> bool:
+    def _preempt_seq(self, victim: _Seq, short_of: str = "slot") -> bool:
         """Preempt one decode slot: gather its computed pages into the
         KVBM park store and park the sequence (resume continues the
         committed stream bit-identically — seed, step count, processor
@@ -939,7 +997,7 @@ class InferenceScheduler:
                 victim.parked_pages = n_pages
                 self._parked.append(victim)
                 self.stats.preempt_parked += 1
-                PREEMPT_TOTAL.labels(kind="park").inc()
+                PREEMPT_TOTAL.labels(kind="park", group=short_of).inc()
                 _observe_preempt(f"{id(self)}:{rid}", "park")
                 get_recorder().event(victim.record_id, "preempt",
                                      kind="park", pages=n_pages,
@@ -953,7 +1011,7 @@ class InferenceScheduler:
                 # pages.
                 victim.finished = True
                 self.stats.preempt_migrated += 1
-                PREEMPT_TOTAL.labels(kind="migrate").inc()
+                PREEMPT_TOTAL.labels(kind="migrate", group=short_of).inc()
                 _observe_preempt(f"{id(self)}:{rid}", "migrate")
                 get_recorder().event(victim.record_id, "preempt",
                                      kind="migrate",
@@ -1032,7 +1090,7 @@ class InferenceScheduler:
                 self._parked.remove(seq)
                 seq.finished = True
                 self.stats.preempt_migrated += 1
-                PREEMPT_TOTAL.labels(kind="migrate").inc()
+                PREEMPT_TOTAL.labels(kind="migrate", group="full").inc()
                 _observe_preempt(f"{id(self)}:{rid}", "migrate")
                 seq.emit(EngineOutput(
                     finish_reason="migrate",
@@ -1055,7 +1113,7 @@ class InferenceScheduler:
             self._slots[seq.slot] = seq
             seq.parked_pages = 0
             self.stats.preempt_resumed += 1
-            PREEMPT_TOTAL.labels(kind="resume").inc()
+            PREEMPT_TOTAL.labels(kind="resume", group="full").inc()
             _observe_preempt(f"{id(self)}:{rid}", "resume")
             get_recorder().event(seq.record_id, "preempt", kind="resume",
                                  tokens_preserved=len(seq.generated))
@@ -1250,6 +1308,8 @@ class InferenceScheduler:
         # Pages the step's sequences held while it ran: taken before the
         # reap returns the finished ones' (at most max_batch slots).
         reserved = self.reserved_pages()
+        win_reserved = (self.window_reserved_pages() if self._windowed
+                        else 0)
         held_slots = sum(s is not None for s in self._slots)
         with _section("sched.reap"):
             self._reap_finished()
@@ -1262,6 +1322,8 @@ class InferenceScheduler:
             self.stats.last_step_wall_ms = (time.monotonic() - start) * 1e3
             self.stats.reserved_page_ms += (
                 reserved * self.stats.last_step_wall_ms)
+            self.stats.window_reserved_page_ms += (
+                win_reserved * self.stats.last_step_wall_ms)
             if self._recurrent:
                 self.stats.state_slot_ms += (
                     held_slots * self.stats.last_step_wall_ms)
@@ -1375,6 +1437,8 @@ class InferenceScheduler:
             chunk = min(per, seq.prompt_len - seq.prefill_pos)
             if chunk <= 0:
                 continue
+            if not self._launch_takes(work, seq, chunk):
+                continue
             if seq.record_id is not None and not seq.prefill_stamped:
                 # First chunk of real prefill compute only.
                 seq.prefill_stamped = True
@@ -1382,6 +1446,41 @@ class InferenceScheduler:
             work.append((seq, chunk))
             spent += chunk
         return work
+
+    def _launch_takes(self, work: list, seq: _Seq, chunk: int) -> bool:
+        """Whether this prefill launch can hold one more row. Any, but
+        for a model with window layers: the launch's rows x bucket stay
+        inside the token budget, and the window group's pages for the
+        chunk are taken here: the blocks before the oldest position the
+        chunk's first query sees go back, those up to its last position
+        are allocated. A row that gets none waits for a later launch."""
+        if not self._windowed:
+            return True
+        if not self.runner.prefill_launch_fits(
+                [c for _, c in work] + [chunk]):
+            return False
+        pos = seq.prefill_pos
+        return self.win_pool.advance(
+            seq.window, max(0, pos - self.win_pool.window + 1),
+            pos + chunk - 1, "prefill")
+
+    def _trim_window(self, seq: _Seq) -> None:
+        """Behind a prefill chunk's launch: what its NEXT launch (a chunk
+        or the first decode step, at `prefill_pos`) cannot see goes back
+        at once, so that between launches a row holds the window and no
+        more. Programs run in the order they were launched, so whoever
+        gets these pages writes them after this chunk has read them."""
+        if self._windowed:
+            # one position more than the next chunk reads: a sequence
+            # with logits processors runs its last prompt token again
+            self.win_pool.advance(
+                seq.window, max(0, seq.prefill_pos - self.win_pool.window),
+                seq.prefill_pos - 1, "prefill")
+
+    def _window_arg(self, seq: _Seq) -> tuple:
+        """A prefill row's window group: its pages from its first held
+        block on, and that block's first position."""
+        return (seq.window.pages, seq.window.first * self.page_size)
 
     def _can_batch_prefill(self, work: list) -> bool:
         """Cross-sequence chunk batching requires a runner with the
@@ -1433,6 +1532,8 @@ class InferenceScheduler:
                 chunk_embeds=chunk_embeds,
                 return_device=deferred_readback,
                 **({"slot": seq.slot} if self._recurrent else {}),
+                **({"window": self._window_arg(seq)} if self._windowed
+                   else {}),
             )
         if not deferred_readback:
             # Device-stream completion window of the whole prompt pass:
@@ -1440,6 +1541,7 @@ class InferenceScheduler:
             seq.device_prefill_ms = max(
                 0.0, (time.monotonic() - seq.prefill_submit_ts) * 1e3)
         seq.prefill_pos += chunk
+        self._trim_window(seq)
         if is_final:
             if defer:
                 self._pending_prefill.append((seq, token))
@@ -1477,7 +1579,10 @@ class InferenceScheduler:
                              seq.prefill_pos + chunk,
                              (s.temperature, s.top_p, s.top_k, seq.seed),
                              seq.lora_idx,
-                             *((seq.slot,) if self._recurrent else ())))
+                             *((seq.slot,) if self._recurrent
+                               or self._windowed else ()),
+                             *((self._window_arg(seq),) if self._windowed
+                               else ())))
         want_samples = any(
             final and seq.request.sampling.logprobs
             for final, (seq, _) in zip(finals, work))
@@ -1496,6 +1601,7 @@ class InferenceScheduler:
         total = 0
         for row, ((seq, chunk), is_final) in enumerate(zip(work, finals)):
             seq.prefill_pos += chunk
+            self._trim_window(seq)
             total += chunk
             if not is_final:
                 self._stream_prefill_chunk(seq)
@@ -1674,6 +1780,8 @@ class InferenceScheduler:
                 self._tokens[i] = seq.last_token
                 self._positions[i] = seq.kv_len - 1  # position of last_token
                 self._tables[i] = seq.block_table
+                if self._windowed:
+                    self._advance_decode_window(seq)
                 self._kv_lens[i] = seq.kv_len
                 self._active[i] = True
                 s = seq.request.sampling
@@ -1703,6 +1811,8 @@ class InferenceScheduler:
         width = bucket_table_width(need,
                                    self.runner.config.max_pages_per_seq)
         tables = self._tables[:, :width]
+        if self._windowed:
+            tables = (tables, self._win_tables, self._win_base)
         if block > 1:
             if prefill_pending:
                 self.stats.fused_steps_with_prefill += 1
@@ -1734,6 +1844,26 @@ class InferenceScheduler:
         return ("count",
                 self._decode_single(ready, tables, want_logprobs,
                                     want_logits))
+
+    def _advance_decode_window(self, seq: _Seq) -> None:
+        """Before a decode launch: the window group's blocks the launch
+        can no longer see go back to the pool, those it may write
+        (`_win_lookahead` positions on) are allocated from the row's
+        reservation, and its row of the group's table is written from
+        its first held block."""
+        pos = seq.kv_len - 1  # the position the launch's first step reads
+        lease = seq.window
+        if not self.win_pool.advance(
+                lease, max(0, pos - self.win_pool.window + 1),
+                pos + self._win_lookahead - 1, "decode"):
+            raise RuntimeError(
+                f"window group: a decoding row found no page inside its "
+                f"reservation ({len(lease.pages)} held, {lease.reserved} "
+                "reserved)")
+        row = self._win_tables[seq.slot]
+        row[:] = 0
+        row[:len(lease.pages)] = lease.pages
+        self._win_base[seq.slot] = lease.first * self.page_size
 
     def _drain_decode(self, pending) -> int:
         """Decode phase 2: read the fused block(s) back and append tokens.
@@ -2400,6 +2530,9 @@ class InferenceScheduler:
                     computed = seq.prefill_pos // self.page_size
                     self.pool.release(seq.alloc, seq.block_hashes,
                                       computed_blocks=computed)
+                if seq.window is not None:
+                    self.win_pool.release(seq.window)
+                    seq.window = None
                 if seq.spec is not None:
                     if seq.spec.proposed and seq.record_id is not None:
                         # Where this request's speculated tokens were won

@@ -179,13 +179,16 @@ def recurrent_state_refusals(model_config, *, mode: str = "aggregated",
     cannot be served with yet, refused at start with the flag and the
     reason, never answered wrongly later: each of these paths moves or
     reuses KV pages, or shards the step, and none of them carries the
-    per-slot state that the pages are useless without."""
+    per-slot state that the pages are useless without. A hybrid stack
+    with window layers is refused the same paths for its second page
+    group's sake (`window_layer_refusals`)."""
     cfg = model_config
     if not cfg.is_hybrid:
         return
-    from ..models.hybrid import hybrid_refusals
+    from ..models.hybrid import hybrid_refusals, window_layer_refusals
 
     hybrid_refusals(cfg, weight_dtype, kv_dtype, devices)
+    window_layer_refusals(cfg, mode=mode, kvbm=kvbm, spec=spec)
     if not cfg.has_recurrent_state:
         return
     what = f"{cfg.name} (layers {cfg.layer_pattern})"
@@ -205,6 +208,21 @@ def recurrent_state_refusals(model_config, *, mode: str = "aggregated",
             f"DYNT_SPEC_ENABLE: speculative verification (engine/spec.py) "
             f"rolls rejected positions back by length; the recurrent state "
             f"of {what} cannot be rolled back")
+
+
+def _runner_config(args) -> RunnerConfig:
+    """The worker CLI's flags as a RunnerConfig."""
+    extra = {}
+    if args.prefill_buckets:
+        extra["prefill_buckets"] = tuple(
+            sorted(int(b) for b in args.prefill_buckets.split(",")))
+    return RunnerConfig(
+        page_size=args.page_size, num_pages=args.num_pages,
+        max_batch=args.max_batch,
+        max_pages_per_seq=args.max_pages_per_seq,
+        max_loras=args.max_loras, lora_rank=args.lora_rank,
+        kv_dtype=args.kv_dtype, weight_dtype=args.weight_dtype,
+        window_pages=args.window_pages, **extra)
 
 
 class TpuWorker:
@@ -1531,7 +1549,8 @@ class TpuWorker:
         launched over, page-time reserved and per-chip device memory
         (docs/metrics.md: dynamo_engine_tokens, dynamo_engine_launches,
         dynamo_engine_positions, dynamo_prefill_row_blocks_total,
-        dynamo_kv_reserved_page_ms, dynamo_device_hbm_bytes)."""
+        dynamo_kv_reserved_page_ms, dynamo_kv_window_*,
+        dynamo_device_hbm_bytes)."""
         from ..runtime.metrics import (
             DEVICE_HBM_BYTES,
             ENGINE_LAUNCHES,
@@ -1540,6 +1559,10 @@ class TpuWorker:
             EMIT_FRAMES,
             EMIT_HANDOVERS,
             KV_RESERVED_PAGE_MS,
+            KV_WINDOW_ALLOC_FAIL,
+            KV_WINDOW_EDGE_TOKENS,
+            KV_WINDOW_PAGES_FREED,
+            KV_WINDOW_RESERVED_PAGE_MS,
             MOE_DROPPED_SLOTS,
             MOE_EXPERT_CALLS,
             MOE_EXPERT_TOKENS,
@@ -1570,6 +1593,18 @@ class TpuWorker:
                     count)
         KV_RESERVED_PAGE_MS.labels(worker=worker).set(
             stats.reserved_page_ms)
+        win_pool = self.scheduler.win_pool
+        if win_pool is not None:  # only a model with window layers
+            KV_WINDOW_RESERVED_PAGE_MS.labels(worker=worker).set(
+                stats.window_reserved_page_ms)
+            KV_WINDOW_ALLOC_FAIL.labels(worker=worker).set(
+                win_pool.alloc_fail)
+            for phase, pages in win_pool.freed_behind.items():
+                KV_WINDOW_PAGES_FREED.labels(
+                    worker=worker, phase=phase).set(pages)
+                KV_WINDOW_EDGE_TOKENS.labels(
+                    worker=worker, phase=phase).set(
+                        win_pool.edge_tokens[phase])
         if stats.state_slot_ms:  # only a model with recurrent state
             SSM_STATE_SLOT_MS.labels(worker=worker).set(stats.state_slot_ms)
         if stats.moe_counts is not None:
@@ -2117,6 +2152,19 @@ def build_arg_parser():
     parser.add_argument("--num-pages", type=int, default=2048)
     parser.add_argument("--max-batch", type=int, default=16)
     parser.add_argument("--max-pages-per-seq", type=int, default=128)
+    parser.add_argument("--window-pages", type=int, default=0,
+                        help="a model with window AND full attention "
+                             "layers: pages of its second page group, "
+                             "which holds the window layers only "
+                             "(--num-pages stays the full group's); a "
+                             "decoding row holds window/page-size + 2 of "
+                             "them, a row in a prefill chunk the chunk's "
+                             "more (docs/prompt-caching.md)")
+    parser.add_argument("--prefill-buckets", default=None,
+                        metavar="N,N,..",
+                        help="prefill chunk lengths to compile for "
+                             "(default 32..2048 by powers of two); the "
+                             "largest is the token budget of a launch")
     parser.add_argument("--kv-dtype", default="model",
                         choices=["model", "int8"],
                         help="KV cache storage: model dtype (bf16) or "
@@ -2293,14 +2341,7 @@ async def main(argv: Optional[list[str]] = None) -> None:
                              "multihost workers + host-relay KV transfer)")
         multihost_cfg = mh.MultihostConfig.parse(args.multihost)
         mh.initialize(multihost_cfg)
-        rc = RunnerConfig(
-            page_size=args.page_size, num_pages=args.num_pages,
-            max_batch=args.max_batch,
-            max_pages_per_seq=args.max_pages_per_seq,
-            max_loras=args.max_loras, lora_rank=args.lora_rank,
-            kv_dtype=args.kv_dtype,
-            weight_dtype=args.weight_dtype,
-        )
+        rc = _runner_config(args)
         if not multihost_cfg.is_driver:
             # Follower: engine only — no runtime, no endpoints. Build a
             # runner IDENTICAL to the driver's and replay its steps.
@@ -2401,14 +2442,7 @@ async def main(argv: Optional[list[str]] = None) -> None:
             prefill_tp=args.tp if args.tp > 1 else None,
             decode_tp=args.tp if args.tp > 1 else None)
         bridge = IciKvBridge()
-        rc = RunnerConfig(
-            page_size=args.page_size, num_pages=args.num_pages,
-            max_batch=args.max_batch,
-            max_pages_per_seq=args.max_pages_per_seq,
-            max_loras=args.max_loras, lora_rank=args.lora_rank,
-            kv_dtype=args.kv_dtype,
-            weight_dtype=args.weight_dtype,
-        )
+        rc = _runner_config(args)
         common = dict(
             model_name=args.model, model_path=args.model_path,
             served_name=args.served_model_name,
@@ -2484,14 +2518,7 @@ async def main(argv: Optional[list[str]] = None) -> None:
             namespace=args.namespace,
             component=component,
             mode=args.mode,
-            runner_config=RunnerConfig(
-                page_size=args.page_size, num_pages=args.num_pages,
-                max_batch=args.max_batch,
-                max_pages_per_seq=args.max_pages_per_seq,
-                max_loras=args.max_loras, lora_rank=args.lora_rank,
-                kv_dtype=args.kv_dtype,
-                weight_dtype=args.weight_dtype,
-            ),
+            runner_config=_runner_config(args),
             mesh_config=mesh_config,
             mesh=mesh,
             kvbm_config=kvbm_config,

@@ -656,7 +656,7 @@ class MockerEngine:
             if len(self._running) >= cfg.max_batch:
                 # Slot pressure: preempt a lower-class decode slot (the
                 # chip-free park-to-KVBM analog) and retry.
-                if self._try_preempt_for(seq):
+                if self._try_preempt_for(seq, "slot"):
                     continue
                 break
             cached = self.kv.match_prefix(seq.block_hashes)
@@ -689,7 +689,7 @@ class MockerEngine:
                 self.kv.unpin(prefix)
                 # Block pressure is the other preemption trigger: a
                 # parked batch slot returns its blocks.
-                if self._try_preempt_for(seq):
+                if self._try_preempt_for(seq, "full"):
                     continue
                 break  # wait for blocks to free up
             seq.cached_blocks = cached
@@ -731,9 +731,12 @@ class MockerEngine:
     # -- preemption (docs/multi-tenancy.md; the real engine's
     # preempt-to-KVBM plane, simulated chip-free) -------------------------
 
-    def _try_preempt_for(self, head: "_Sequence") -> bool:
+    def _try_preempt_for(self, head: "_Sequence", short_of: str) -> bool:
         """Park the cheapest lower-class decode slot so `head` can
-        admit. Returns True when a victim was parked."""
+        admit; `short_of` is what admission ran out of ("slot" or the
+        "full" block pool: the `group` label of `dynamo_preempt_total`,
+        as in the real scheduler). Returns True when a victim was
+        parked."""
         if not self.preempt_enabled:
             return False
         victim = None
@@ -759,7 +762,7 @@ class MockerEngine:
         try:
             from ..runtime.metrics import PREEMPT_TOTAL
 
-            PREEMPT_TOTAL.labels(kind="park").inc()
+            PREEMPT_TOTAL.labels(kind="park", group=short_of).inc()
         except Exception:  # noqa: BLE001 — metrics must not break sims
             pass
         from ..runtime.conformance import observe
@@ -840,7 +843,7 @@ class MockerEngine:
             try:
                 from ..runtime.metrics import PREEMPT_TOTAL
 
-                PREEMPT_TOTAL.labels(kind="resume").inc()
+                PREEMPT_TOTAL.labels(kind="resume", group="full").inc()
             except Exception:  # noqa: BLE001 — metrics must not break
                 pass
             from ..runtime.conformance import observe

@@ -68,7 +68,9 @@ class ModelConfig:
     # projections carry biases; experts use the clipped gated-swiglu
     # (clamp + sigmoid(alpha*x)) with fused gate_up weights; rope is YaRN.
     attn_sinks: bool = False
-    sliding_window: int = 0  # even layers sliding when attn_sinks
+    # The window of a layer that has one (0 = none): the "W" layers of a
+    # `layer_pattern`, the even layers of a gpt-oss stack (attn_sinks)
+    sliding_window: int = 0
     attn_bias: bool = False
     swiglu_limit: float = 0.0  # 0 = plain silu*up
     swiglu_alpha: float = 1.702
@@ -76,12 +78,22 @@ class ModelConfig:
     rope_yarn_beta_fast: float = 32.0
     rope_yarn_beta_slow: float = 1.0
     rope_yarn_orig_max: int = 4096
-    # Hybrid stacks (nemotron_h): a layer is ONE mixer, its kind read off
-    # `layer_pattern` ("M" Mamba-2, "E" routed experts, "*" attention; ""
-    # = every layer is attention + MLP). Only "*" layers have KV pages
-    # (cache layer index != model layer index); "M" layers keep a
-    # fixed-size state per scheduler slot (models/hybrid.py).
+    # HF `_compute_yarn_parameters`: round the correction range to whole
+    # dimensions (its default; gpt-oss states truncate=False)
+    rope_yarn_truncate: bool = False
+    # Hybrid stacks: a layer is ONE mixer, its kind read off
+    # `layer_pattern` ("M" Mamba-2, "E" routed experts, "*" full
+    # attention, "W" attention over the last `sliding_window` positions;
+    # "" = every layer is attention + MLP). A pre-norm block of a
+    # published model is `mixers_per_layer` of them (attention, then
+    # experts: 2). Rope is a kind's: the default table on "W", YaRN on
+    # "*" where `rope_yarn_factor` is set. Only attention layers have KV
+    # pages, and each kind has a page group of its own (`kv_layers` the
+    # full group, `window_kv_layers` the window group: a cache layer
+    # index counts within its group); "M" layers keep a fixed-size state
+    # per scheduler slot (models/hybrid.py).
     layer_pattern: str = ""
+    mixers_per_layer: int = 1
     use_rope: bool = True  # nemotron_h attention has no positional term
     mlp_act: str = "swiglu"  # swiglu | relu2 (non-gated: down(relu(up x)^2))
     shared_expert_hidden: int = 0  # 0 = n_shared_experts * expert width
@@ -111,14 +123,30 @@ class ModelConfig:
         KV transfer, offload and speculation need a state snapshot too."""
         return "M" in self.layer_pattern
 
+    @property
+    def has_window_layers(self) -> bool:
+        """Window and full attention side by side: two page groups, and
+        whatever moves or reuses pages by prefix is refused
+        (`hybrid_refusals`, `window_layer_refusals`)."""
+        return "W" in self.layer_pattern
+
     def layer_kind(self, layer_idx: int) -> str:
         return self.layer_pattern[layer_idx] if self.layer_pattern else "*"
 
     @property
     def kv_layers(self) -> tuple[int, ...]:
-        """Model layer index of each layer of the paged KV cache."""
+        """Model layer index of each layer of the paged KV cache's FULL
+        group (a sequence holds a page for every 16 positions)."""
         return tuple(i for i in range(self.n_layers)
                      if self.layer_kind(i) == "*")
+
+    @property
+    def window_kv_layers(self) -> tuple[int, ...]:
+        """The same for the WINDOW group: layers that can see only the
+        last `sliding_window` positions, so that a page behind them goes
+        back to its pool while the sequence lives (engine/pages.py)."""
+        return tuple(i for i in range(self.n_layers)
+                     if self.layer_kind(i) == "W")
 
     @property
     def state_layers(self) -> tuple[int, ...]:
@@ -142,8 +170,12 @@ class ModelConfig:
         return self.attn_sinks
 
     def layer_sliding_window(self, layer_idx: int) -> int:
-        """Per-layer window (0 = full attention). gpt-oss alternates
+        """Per-layer window (0 = full attention): the layer's kind for a
+        `layer_pattern` model; gpt-oss (attn_sinks) alternates
         sliding/full starting with sliding at layer 0 (HF layer_types)."""
+        if self.layer_pattern:
+            return (self.sliding_window
+                    if self.layer_pattern[layer_idx] == "W" else 0)
         if not self.attn_sinks or not self.sliding_window:
             return 0
         return self.sliding_window if layer_idx % 2 == 0 else 0
@@ -318,6 +350,36 @@ PRESETS: dict[str, ModelConfig] = {
         moe_norm_topk=True, moe_routed_scale=2.5, moe_scoring="sigmoid",
         mamba_heads=64, mamba_head_dim=64, ssm_groups=8, ssm_state=128,
     ),
+    # JetBrains Mellum2-12B-A2.5B-Instruct (config.json, model_type
+    # mellum): 28 pre-norm blocks, each an attention mixer then 64 SwiGLU
+    # experts 896 wide (top-8 of a float32 softmax, renormalised, no
+    # shared expert), so 56 mixers; three blocks of four attend over the
+    # last 1024 positions with the default rope, the fourth over
+    # everything with YaRN (factor 16 over 8192). No q/k norm, no
+    # prediction module. `--serve-layers` counts blocks.
+    "mellum2-12b-a2.5b": ModelConfig(
+        name="mellum2-12b-a2.5b", vocab_size=98304, hidden=2304,
+        n_layers=56, layer_pattern="WEWEWE*E" * 7, mixers_per_layer=2,
+        n_q_heads=32, n_kv_heads=4, head_dim=128, mlp_hidden=7168,
+        rope_theta=5e5, rms_eps=1e-6, tie_embeddings=False,
+        max_context=131072, sliding_window=1024,
+        rope_yarn_factor=16.0, rope_yarn_orig_max=8192,
+        rope_yarn_truncate=True,
+        n_experts=64, n_experts_active=8, expert_mlp_hidden=896,
+        moe_norm_topk=True,
+    ),
+    # CPU sibling: two periods, window 32, 8 experts top-2
+    "tiny-mellum-test": ModelConfig(
+        name="tiny-mellum-test", vocab_size=512, hidden=64, n_layers=16,
+        layer_pattern="WEWEWE*E" * 2, mixers_per_layer=2,
+        n_q_heads=4, n_kv_heads=2, head_dim=16, mlp_hidden=128,
+        rope_theta=5e5, rms_eps=1e-6, tie_embeddings=False,
+        max_context=1024, sliding_window=32,
+        rope_yarn_factor=16.0, rope_yarn_orig_max=64,
+        rope_yarn_truncate=True,
+        n_experts=8, n_experts_active=2, expert_mlp_hidden=48,
+        moe_norm_topk=True,
+    ),
     # CPU sibling: every layer kind at least twice, 8 experts top-2, a
     # shared expert, 4 Mamba heads in 2 groups
     "tiny-hybrid-test": ModelConfig(
@@ -349,12 +411,13 @@ def cut_config(config: ModelConfig, layers: Optional[int] = None,
     sampling are over the slice). No width changes."""
     changes: dict = {}
     if layers is not None:
-        if not 0 < layers <= config.n_layers:
+        per = config.mixers_per_layer
+        if not 0 < layers * per <= config.n_layers:
             raise ValueError(f"--serve-layers {layers}: {config.name} has "
-                             f"{config.n_layers} layers")
-        changes["n_layers"] = layers
+                             f"{config.n_layers // per} layers")
+        changes["n_layers"] = layers * per
         if config.layer_pattern:
-            changes["layer_pattern"] = config.layer_pattern[:layers]
+            changes["layer_pattern"] = config.layer_pattern[:layers * per]
     if experts is not None:
         try:
             lo, hi = (int(part) for part in experts.split(":"))

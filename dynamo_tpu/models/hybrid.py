@@ -1,5 +1,7 @@
-"""Hybrid decoder (nemotron_h): ONE mixer a layer, its kind read off
-`ModelConfig.layer_pattern`.
+"""Hybrid decoder: ONE mixer a layer, its kind read off
+`ModelConfig.layer_pattern` (nemotron_h's Mamba-2 / attention / relu2
+experts; mellum's window and full attention over SwiGLU experts, a
+published block being two mixers).
 
     x <- x + Mixer_l(RMSNorm(x; w_l))          after the last: RMSNorm, head
 
@@ -7,13 +9,22 @@
      xBC -> x [H, P], B [G, N], C [G, N]; dt <- softplus(dt + dt_bias);
      S_t = exp(dt A) S_{t-1} + dt (x outer B); y = S C + D x;
      y <- GroupRMSNorm(y * silu(z)) (gate first, then norm); out = y W_out
-  *  attention: grouped-query, causal softmax, NO positional term
+  *  attention: grouped-query, causal softmax; rope where `use_rope`
+     (YaRN where `rope_yarn_factor`), none for nemotron_h
+  W  the same over the last `sliding_window` positions, default rope:
+     scores masked to q_pos - window < kv_pos <= q_pos
   E  routed experts: sigmoid scores, top-k of scores + bias, weights = the
-     unbiased scores renormalised x scale; expert = W_down relu(W_up x)^2;
-     one shared expert of the same form, always added
+     unbiased scores renormalised x scale (or a float32 softmax's top-k,
+     renormalised); expert = W_down relu(W_up x)^2, or with `mlp_act`
+     swiglu W_down (silu(W_gate x) * W_up x) from one fused [gate | up]
+     matrix; a shared expert of the same form where the model has one
 
-Two kinds of cache side by side: KV pages for the `*` layers only (cache
-layer j = the j-th `*` layer), and for each `M` layer a fixed-size state
+Kinds of cache side by side: KV pages for the attention layers only, a
+page group each kind (`*`: cache layer j = the j-th `*` layer of the full
+group, whose table a sequence fills from position 0; `W`: the same count
+within the window group, whose table starts at the first block the
+sequence still holds, positions and lengths counted from that block's
+first token: engine/pages.py), and for each `M` layer a fixed-size state
 per scheduler slot: `conv` [slots, K-1, conv_dim] (the K-1 inputs before
 the next position; the model dtype) and `ssm` [slots, H, P, N] (float32).
 Rules the scheduler and runner rely on:
@@ -33,6 +44,7 @@ an absent expert get nothing from it.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -54,7 +66,19 @@ from .transformer import (
     rms_norm,
     write_kv_pages,
     write_kv_stack,
+    yarn_rope_tables,
 )
+
+# A windowed model's prefill attention (`prefill_attention`) scores at
+# most PREFILL_Q_BLOCK query positions a row and PREFILL_SCORE_POSITIONS
+# over all rows at a time: its full layers' table is as wide as the
+# longest context served, and float32 scores [rows, T, heads, keys] of a
+# whole 2048-token chunk over 8192 keys are 2.1 GB (PERF.md, fault 2). A
+# full layer gathers the narrowest of FULL_TABLE_PAGES (and the whole
+# table) that holds what a block of queries can see.
+PREFILL_Q_BLOCK = 512
+PREFILL_SCORE_POSITIONS = 2048
+FULL_TABLE_PAGES = (64, 128, 256)
 
 
 def hybrid_refusals(config: ModelConfig, weight_dtype: str = "model",
@@ -79,6 +103,37 @@ def hybrid_refusals(config: ModelConfig, weight_dtype: str = "model",
             "exchange, no sharded scan)")
 
 
+def window_layer_refusals(config: ModelConfig, *, mode: str = "aggregated",
+                          kvbm: bool = False, spec: bool = False) -> None:
+    """What a model with window AND full attention layers is refused, by
+    flag and reason: its cache is two page groups, and the window group
+    keeps only the last `sliding_window` positions of a sequence, so
+    whatever finds, moves or rewinds pages by a prefix of the full
+    group's alone would resume from half a cache. (A prefix hit is never
+    taken either: `InferenceScheduler` builds both pools without a
+    prefix cache and publishes no `stored` event.)"""
+    if not config.has_window_layers:
+        return
+    what = (f"{config.name} (layers {config.layer_pattern}, window "
+            f"{config.sliding_window})")
+    if mode != "aggregated":
+        raise ValueError(
+            f"--mode {mode}: disaggregated prefill/decode hands over the "
+            f"pages of ONE pool by block index; {what} keeps a second page "
+            "group whose pages behind the window are already freed")
+    if kvbm:
+        raise ValueError(
+            f"--kvbm-host-blocks/--kvbm-disk-blocks: KVBM offloads and "
+            f"onboards pages by prefix hash; a hit on {what} needs the "
+            "full group's pages of the prefix AND the window group's last "
+            f"{config.sliding_window} positions, which no tier keeps")
+    if spec:
+        raise ValueError(
+            f"DYNT_SPEC_ENABLE: speculative verification scores k+1 "
+            f"positions in one step over one table; the window group of "
+            f"{what} has no multi-position decode path")
+
+
 # ---------------------------------------------------------------------------
 # parameters
 # ---------------------------------------------------------------------------
@@ -94,16 +149,24 @@ def hybrid_layer_axes(config: ModelConfig, layer_idx: int) -> dict:
                 "conv_w": (None, None), "conv_b": (None,),
                 "dt_bias": (None,), "a_log": (None,), "d_skip": (None,),
                 "ssm_norm": (None,), "out_proj": (None, "embed")}
-    if kind == "*":
+    if kind in "*W":
         return {"norm": ("embed",),
                 "wq": ("embed", "q_heads", "head_dim"),
                 "wk": ("embed", "kv_heads", "head_dim"),
                 "wv": ("embed", "kv_heads", "head_dim"),
                 "wo": ("q_heads", "head_dim", "embed")}
-    return {"norm": ("embed",), "router": ("embed", None),
-            "e_bias": (None,), "e_up": (None, None, "embed"),
-            "e_down": (None, None, "embed"),
-            "s_up": ("embed", None), "s_down": (None, "embed")}
+    axes = {"norm": ("embed",), "router": ("embed", None),
+            "e_up": (None, None, "embed"), "e_down": (None, None, "embed")}
+    if config.moe_scoring == "sigmoid":
+        axes["e_bias"] = (None,)
+    if _shared_width(config):
+        axes.update({"s_up": ("embed", None), "s_down": (None, "embed")})
+    return axes
+
+
+def _shared_width(config: ModelConfig) -> int:
+    return (config.shared_expert_hidden
+            or config.n_shared_experts * config.expert_mlp_hidden)
 
 
 def init_hybrid_layer(k: jax.Array, config: ModelConfig,
@@ -121,7 +184,14 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
     draw over [dt_min, dt_max]; A = -exp(a_log) with A uniform in
     [-16, -1]; D = 1; the router's selection bias is 0.02 x normal (small
     beside the scores' spread, as a trained bias that balances the load
-    is), so routing is a little uneven. Those four stay float32."""
+    is), so routing is a little uneven. Those four stay float32.
+
+    A window layer's matrices are a full layer's. A SwiGLU expert
+    (`mlp_act` swiglu) draws its gate from fold_in(ks[9], e) and its up
+    from fold_in(ks[11], e), stored as one [2m, h] matrix, gate rows
+    first; a softmax router has no selection bias and a model without a
+    shared expert no s_up / s_down (no draw is made for either, and the
+    other keys are unmoved)."""
     dtype = jnp.dtype(config.dtype)
     h = config.hidden
     ks = jax.random.split(k, 15)
@@ -154,7 +224,7 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
             "ssm_norm": jnp.ones((inner,), dtype),
             "out_proj": dense(ks[6], (inner, h), inner, 0),
         })
-    elif kind == "*":
+    elif kind in "*W":
         qh, kh, hd = config.n_q_heads, config.n_kv_heads, config.head_dim
         p.update({
             "wq": dense(ks[0], (h, qh, hd), h),
@@ -164,21 +234,29 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
         })
     else:
         m = config.expert_mlp_hidden
-        sm = config.shared_expert_hidden or config.n_shared_experts * m
+        sm = _shared_width(config)
         lo, hi = config.held_experts
         ids = jnp.arange(lo, hi)
+
+        def up_of(key):
+            return jax.vmap(lambda e: dense(
+                jax.random.fold_in(key, e), (h, m), h).T)(ids)
+
         p.update({
             "router": dense(ks[7], (h, config.n_experts), h),
-            "e_bias": 0.02 * jax.random.normal(
-                ks[8], (config.n_experts,), jnp.float32),
             # stored [E, m, h]: see ops/grouped_matmul.expert_gmm
-            "e_up": jax.vmap(lambda e: dense(
-                jax.random.fold_in(ks[9], e), (h, m), h).T)(ids),
+            "e_up": up_of(ks[9]),
             "e_down": jax.vmap(lambda e: dense(
                 jax.random.fold_in(ks[10], e), (m, h), m, 0))(ids),
-            "s_up": dense(ks[12], (h, sm), h),
-            "s_down": dense(ks[13], (sm, h), sm, 0),
         })
+        if config.mlp_act == "swiglu":  # [E, gate | up, h]
+            p["e_up"] = jnp.concatenate([p["e_up"], up_of(ks[11])], axis=1)
+        if config.moe_scoring == "sigmoid":
+            p["e_bias"] = 0.02 * jax.random.normal(
+                ks[8], (config.n_experts,), jnp.float32)
+        if sm:
+            p["s_up"] = dense(ks[12], (h, sm), h)
+            p["s_down"] = dense(ks[13], (sm, h), sm, 0)
     return p
 
 
@@ -295,6 +373,54 @@ def _relu2(u):
     return jnp.square(jax.nn.relu(u))
 
 
+def _swiglu(u):
+    """`u` = x [W_gate | W_up] from one fused matrix: silu(gate) * up."""
+    m = u.shape[-1] // 2
+    return jax.nn.silu(u[..., :m]) * u[..., m:]
+
+
+def rope_tables(config: ModelConfig, kind: str):
+    """(inverse frequencies [hd/2] float32, cos/sin factor) of a layer
+    kind, or None where attention has no positional term: the default
+    table on a window layer, YaRN on a full one where the model states a
+    factor (HF `rope_parameters` keyed by layer type)."""
+    if not config.use_rope:
+        return None
+    if kind == "*" and config.rope_yarn_factor:
+        return yarn_rope_tables(config)
+    half = config.head_dim // 2
+    return (jnp.exp(-math.log(config.rope_theta)
+                    * jnp.arange(0, half, dtype=jnp.float32) / half), 1.0)
+
+
+def apply_rope(x, positions, tables):
+    """Rotate-half rope. x [..., T, H, hd]; positions [..., T], ABSOLUTE
+    (never a page group's own frame)."""
+    if tables is None:
+        return x
+    inv_freq, factor = tables
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    cos = (jnp.cos(angles) * factor)[..., None, :]
+    sin = (jnp.sin(angles) * factor)[..., None, :]
+    half = x.shape[-1] // 2
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+def _qkv(h, lp, config: ModelConfig, kind: str, positions):
+    """h [B, T, hidden] -> q [B, T, qh, hd], k, v [B, T, kh, hd], roped."""
+    tables = rope_tables(config, kind)
+    q = jnp.einsum("bth,hqd->btqd", h, lp["wq"])
+    k = jnp.einsum("bth,hkd->btkd", h, lp["wk"])
+    v = jnp.einsum("bth,hkd->btkd", h, lp["wv"])
+    return apply_rope(q, positions, tables), apply_rope(k, positions,
+                                                        tables), v
+
+
+ATTENTION_SCOPES = {"*": "attn_full", "W": "attn_window"}
+
+
 def moe_stats_size(config: ModelConfig) -> int:
     """Length of a step's expert statistics (`moe_mixer`)."""
     lo, hi = config.held_experts
@@ -307,19 +433,23 @@ def moe_mixer(x, lp, config: ModelConfig, valid, gmm_path: str):
     one token: the weights this call had to read) and 1 for the call."""
     with jax.named_scope("moe_experts"):
         b, t, h = x.shape
+        act = _swiglu if config.mlp_act == "swiglu" else _relu2
         weights, topi = _routing_weights(x, lp, config)
         out, counts, dropped = dropless_experts(
             x.reshape(b * t, h), weights.reshape(b * t, -1),
             topi.reshape(b * t, -1), valid.reshape(b * t),
-            lp["e_up"], lp["e_down"], config.held_experts, _relu2,
+            lp["e_up"], lp["e_down"], config.held_experts, act,
             path=gmm_path)
-        shared = jnp.einsum(
-            "btm,mh->bth",
-            _relu2(jnp.einsum("bth,hm->btm", x, lp["s_up"])), lp["s_down"])
+        shared = None
+        if "s_up" in lp:
+            shared = jnp.einsum(
+                "btm,mh->bth",
+                act(jnp.einsum("bth,hm->btm", x, lp["s_up"])), lp["s_down"])
         stats = jnp.concatenate([
             counts, jnp.stack([dropped, jnp.sum(counts > 0), 1])
         ]).astype(jnp.int32)
-        return out.reshape(b, t, h) + shared, stats
+        out = out.reshape(b, t, h)
+        return (out if shared is None else out + shared), stats
 
 
 def _head(x, params, config: ModelConfig):
@@ -333,21 +463,94 @@ def _head(x, params, config: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
+def _window_frame(window, positions, kv_lens):
+    """A window group's (cache, table, positions, lengths) in its own
+    frame: `window` = (cache, tables [B, pages], base [B]), base the
+    position of the first token of each row's first held block."""
+    cache, tables, base = window
+    return (cache, tables,
+            positions - base.reshape((-1,) + (1,) * (positions.ndim - 1)),
+            kv_lens - base)
+
+
+def prefill_attention(q, kv_cache, layer, block_tables, positions, kv_lens,
+                      window: int = 0):
+    """`paged_attention_xla` for a model with window layers, a block of
+    query positions at a time (`lax.map`), each over the pages it can see
+    and no others: a window layer the window + block positions that end
+    at the block's last query (a slice of its table, by row), a full
+    layer the narrowest table prefix that holds every row's keys up to
+    the block's last query (one of a few static widths, chosen at run
+    time: the program keeps one shape). The same numbers as scoring the
+    whole table: what is left out is masked there."""
+    b, t, qh, hd = q.shape
+    ps = kv_cache.shape[3]
+    width = block_tables.shape[1]
+    block = max(1, min(PREFILL_Q_BLOCK, PREFILL_SCORE_POSITIONS // b))
+    if t % block:
+        block = t
+    if window:
+        pages = min(width, (window + block) // ps + 1)
+    else:
+        widths = [w for w in FULL_TABLE_PAGES if w < width] + [width]
+
+    def one(part):
+        qb, pb = part  # [B, block, qh, hd], [B, block]
+        if window:
+            # padding positions are 0: a row's first query of the block
+            first = jnp.maximum(pb[:, 0] - (window - 1), 0) // ps
+            cols = first[:, None] + jnp.arange(pages)[None, :]
+            tables = jnp.take_along_axis(
+                block_tables, jnp.minimum(cols, width - 1), axis=1)
+            return paged_attention_xla(qb, kv_cache, layer, tables, pb,
+                                       kv_lens, window=window,
+                                       kv_offset=first * ps,
+                                       flat_gather=True)
+        need = jnp.max(jnp.minimum(kv_lens, jnp.max(pb, axis=1) + 1))
+        return jax.lax.switch(
+            sum((need > w * ps).astype(jnp.int32) for w in widths[:-1]),
+            [functools.partial(_prefix_attention, w, layer) for w in widths],
+            qb, kv_cache, block_tables, pb, kv_lens)
+
+    if block == t:
+        return one((q, positions))
+    n = t // block
+    out = jax.lax.map(
+        one, (jnp.moveaxis(q.reshape(b, n, block, qh, hd), 1, 0),
+              jnp.moveaxis(positions.reshape(b, n, block), 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, qh, hd)
+
+
+def _prefix_attention(pages, layer, q, kv_cache, block_tables, positions,
+                      kv_lens):
+    return paged_attention_xla(q, kv_cache, layer, block_tables[:, :pages],
+                               positions, kv_lens, flat_gather=True)
+
+
 def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
                    state, slots, block_tables, kv_lens, valid, last_idx,
                    attention_fn=None, gmm_path: str = "xla",
-                   all_logits: bool = False):
+                   all_logits: bool = False, window=None):
     """A prefill chunk a row. tokens, positions, valid [B, T]; slots [B]:
     each row's state slot (>= the cache's size for an empty row: its
     write is dropped); last_idx [B]: the row's last valid position in
     this chunk. Returns (kv_cache, state, logits [B, vocab] at last_idx,
-    moe stats [E_held + 3]); `all_logits` gives [B, T, vocab] (tests)."""
+    moe stats [E_held + 3]); `all_logits` gives [B, T, vocab] (tests).
+
+    A model with window layers is handed `window` = (the window group's
+    cache, its tables [B, pages], base [B]) and gives back `kv_cache` as
+    (full group, window group); its window layers gather their short
+    table (window + chunk keys), its full layers the sequence's."""
     attention = attention_fn or paged_attention_xla
+    if window is not None:
+        attention = prefill_attention
+        win_cache, win_tables, win_pos, win_lens = _window_frame(
+            window, positions, kv_lens)
     fresh = positions[:, 0] == 0  # a row at position 0 starts from zero
     x = params["embed"][tokens]
     conv_out, ssm_out = list(state["conv"]), list(state["ssm"])
     stats = jnp.zeros((moe_stats_size(config),), jnp.int32)
-    kv_idx = state_idx = 0
+    kv_idx = win_idx = state_idx = 0
     for layer_idx, lp in enumerate(params["layers"]):
         kind = config.layer_kind(layer_idx)
         h = rms_norm(x, lp["norm"], config.rms_eps)
@@ -360,15 +563,23 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
             ssm_out[state_idx] = ssm_all.at[slots].set(ssm, mode="drop")
             state_idx += 1
         elif kind == "*":
-            q = jnp.einsum("bth,hqd->btqd", h, lp["wq"])
-            k = jnp.einsum("bth,hkd->btkd", h, lp["wk"])
-            v = jnp.einsum("bth,hkd->btkd", h, lp["wv"])
-            kv_cache = write_kv_pages(kv_cache, kv_idx, k, v, block_tables,
-                                      positions, valid)
-            attn = attention(q, kv_cache, kv_idx, block_tables, positions,
-                             kv_lens)
-            out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])
+            with jax.named_scope(ATTENTION_SCOPES[kind]):
+                q, k, v = _qkv(h, lp, config, kind, positions)
+                kv_cache = write_kv_pages(kv_cache, kv_idx, k, v,
+                                          block_tables, positions, valid)
+                attn = attention(q, kv_cache, kv_idx, block_tables,
+                                 positions, kv_lens)
+                out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])
             kv_idx += 1
+        elif kind == "W":
+            with jax.named_scope(ATTENTION_SCOPES[kind]):
+                q, k, v = _qkv(h, lp, config, kind, positions)
+                win_cache = write_kv_pages(win_cache, win_idx, k, v,
+                                           win_tables, win_pos, valid)
+                attn = attention(q, win_cache, win_idx, win_tables, win_pos,
+                                 win_lens, window=config.sliding_window)
+                out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])
+            win_idx += 1
         else:
             out, layer_stats = moe_mixer(h, lp, config, valid, gmm_path)
             stats = stats + layer_stats
@@ -376,23 +587,30 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
     state = {"conv": conv_out, "ssm": ssm_out}
     if not all_logits:
         x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
+    if window is not None:
+        kv_cache = (kv_cache, win_cache)
     return kv_cache, state, _head(x, params, config), stats
 
 
 def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
                           kv_cache, state, block_tables, kv_lens, active,
                           decode_attention_fn=None, ssm_path: str = "xla",
-                          gmm_path: str = "xla"):
+                          gmm_path: str = "xla", window=None):
     """One token for every slot (row i = slot i), KV writes deferred to
-    one scatter for all attention layers as in `forward_decode`. Returns
-    (kv_cache, state, logits [S, 1, vocab], moe stats)."""
+    one scatter a page group for all its attention layers as in
+    `forward_decode`. Returns (kv_cache, state, logits [S, 1, vocab], moe
+    stats); `window` as in `forward_hybrid`."""
     attn_fn = decode_attention_fn or paged_attention_decode_xla
     attn_lens = jnp.where(active, kv_lens, 0)
+    if window is not None:
+        win_cache, win_tables, win_pos, win_lens = _window_frame(
+            window, positions, kv_lens)
+        win_attn_lens = jnp.where(active, win_lens, 0)
     x = params["embed"][tokens]  # [S, h]
     conv_out, ssm_out = list(state["conv"]), list(state["ssm"])
     stats = jnp.zeros((moe_stats_size(config),), jnp.int32)
-    ks, vs = [], []
-    kv_idx = state_idx = 0
+    ks, vs, win_ks, win_vs = [], [], [], []
+    kv_idx = win_idx = state_idx = 0
     for layer_idx, lp in enumerate(params["layers"]):
         kind = config.layer_kind(layer_idx)
         h = rms_norm(x, lp["norm"], config.rms_eps)
@@ -402,16 +620,26 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
                 active, ssm_path)
             state_idx += 1
         elif kind == "*":
-            h1 = h[:, None, :]
-            q = jnp.einsum("bth,hqd->btqd", h1, lp["wq"])
-            k = jnp.einsum("bth,hkd->btkd", h1, lp["wk"])
-            v = jnp.einsum("bth,hkd->btkd", h1, lp["wv"])
-            attn = attn_fn(q, kv_cache, kv_idx, block_tables, attn_lens,
-                           k, v)
+            with jax.named_scope(ATTENTION_SCOPES[kind]):
+                q, k, v = _qkv(h[:, None, :], lp, config, kind,
+                               positions[:, None])
+                attn = attn_fn(q, kv_cache, kv_idx, block_tables, attn_lens,
+                               k, v)
+                out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])[:, 0]
             ks.append(k)
             vs.append(v)
-            out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])[:, 0]
             kv_idx += 1
+        elif kind == "W":
+            with jax.named_scope(ATTENTION_SCOPES[kind]):
+                q, k, v = _qkv(h[:, None, :], lp, config, kind,
+                               positions[:, None])
+                attn = attn_fn(q, win_cache, win_idx, win_tables,
+                               win_attn_lens, k, v,
+                               window=config.sliding_window)
+                out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])[:, 0]
+            win_ks.append(k)
+            win_vs.append(v)
+            win_idx += 1
         else:
             out, layer_stats = moe_mixer(h[:, None, :], lp, config,
                                          active[:, None], gmm_path)
@@ -422,5 +650,10 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
         kv_cache = write_kv_stack(kv_cache, jnp.stack(ks), jnp.stack(vs),
                                   block_tables, positions[:, None],
                                   active[:, None])
+    if window is not None:
+        win_cache = write_kv_stack(win_cache, jnp.stack(win_ks),
+                                   jnp.stack(win_vs), win_tables,
+                                   win_pos[:, None], active[:, None])
+        kv_cache = (kv_cache, win_cache)
     state = {"conv": conv_out, "ssm": ssm_out}
     return kv_cache, state, _head(x, params, config)[:, None, :], stats

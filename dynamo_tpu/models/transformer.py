@@ -233,15 +233,19 @@ def init_params(key: jax.Array, config: ModelConfig) -> dict:
 
 
 def make_kv_cache(config: ModelConfig, num_pages: int, page_size: int,
-                  dtype: Optional[str] = None) -> jax.Array:
+                  dtype: Optional[str] = None,
+                  group: str = "full") -> jax.Array:
     """[layers, kv_dims, pages, page_size, cache_heads, cache_head_dim].
     Standard attention: kv_dims=2 (K and V stacks), heads=n_kv_heads.
     MLA: kv_dims=1, heads=1, head_dim=latent_rank+rope_dim — the compressed
     latent cache. Page 0 is a reserved scratch page (block tables point
-    unused slots at it). A hybrid stack caches its attention layers only
-    (`config.kv_layers`)."""
+    unused slots at it). A hybrid stack caches its attention layers only,
+    a page group each kind: `group` "full" holds `config.kv_layers`,
+    "window" `config.window_kv_layers`, each with its own page ids."""
+    layers = (config.window_kv_layers if group == "window"
+              else config.kv_layers)
     return jnp.zeros(
-        (len(config.kv_layers), config.kv_cache_kv_dims, num_pages,
+        (len(layers), config.kv_cache_kv_dims, num_pages,
          page_size,
          config.kv_cache_heads, config.kv_cache_head_dim),
         dtype=jnp.dtype(dtype or config.dtype),
@@ -334,8 +338,11 @@ def yarn_rope_tables(config: ModelConfig) -> tuple[jax.Array, float]:
         return (dim * math.log(orig_max / (num_rot * 2 * math.pi))
                 ) / (2 * math.log(base))
 
-    low = max(correction_dim(config.rope_yarn_beta_fast), 0)
-    high = min(correction_dim(config.rope_yarn_beta_slow), dim - 1)
+    low = correction_dim(config.rope_yarn_beta_fast)
+    high = correction_dim(config.rope_yarn_beta_slow)
+    if config.rope_yarn_truncate:
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, dim - 1)
     if low == high:
         high += 0.001
     pos_freqs = base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
@@ -742,10 +749,25 @@ def paged_attention_xla(
     block_tables: jax.Array,  # [B, max_pages]
     positions: jax.Array,  # [B, T] absolute query positions
     kv_lens: jax.Array,  # [B] total kv tokens visible (incl. this chunk)
+    window: int = 0,
+    kv_offset: Optional[jax.Array] = None,
+    flat_gather: bool = False,
 ) -> jax.Array:
     """Reference paged attention: gather the sequence's pages, run masked
     SDPA. Correct everywhere (CPU tests, fallback); the Pallas kernel
-    (ops/paged_attention.py) replaces this on TPU for decode."""
+    (ops/paged_attention.py) replaces this on TPU for decode.
+
+    `window` > 0 is the mask's lower edge: a query sees the keys with
+    q_pos - window < kv_pos <= q_pos. Table and positions may be a page
+    group's own (column 0 = the group's first held block, positions
+    counted from that block's first token): the mask is the same in
+    either frame. `kv_offset` [B] is the position of the table's first
+    column where the caller hands over a slice of a row's table
+    (`models/hybrid.prefill_attention`: only the pages a block of
+    queries can see). `flat_gather` reads the pages out of the pool as
+    one array of all its layers' pages: inside a loop or a branch
+    `values[layer, 0]` is a copy of the layer's whole pool before the
+    gather (1.07 GB a full layer of 32,768 pages: PERF.md, PR 36)."""
     values, scales = _kv_parts(kv_cache)
     b, t, qh, hd = q.shape
     ps = values.shape[3]
@@ -753,8 +775,12 @@ def paged_attention_xla(
     max_pages = block_tables.shape[1]
     ctx = max_pages * ps
     # Gather pages: [B, max_pages, ps, kh, hd] -> [B, ctx, kh, hd]
-    k_pages = values[layer, 0][block_tables]
-    v_pages = values[layer, 1][block_tables]
+    if flat_gather:
+        k_pages = values[layer, 0, block_tables]
+        v_pages = values[layer, 1, block_tables]
+    else:
+        k_pages = values[layer, 0][block_tables]
+        v_pages = values[layer, 1][block_tables]
     k = k_pages.reshape(b, ctx, kh, hd)
     v = v_pages.reshape(b, ctx, kh, hd)
     if scales is not None:
@@ -771,10 +797,14 @@ def paged_attention_xla(
     scores = jnp.einsum("btkgh,bskh->btkgs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) / math.sqrt(hd)
     kv_pos = jnp.arange(ctx)[None, :]  # [1, ctx]
+    if kv_offset is not None:
+        kv_pos = kv_pos + kv_offset[:, None]  # [B, ctx]
     # causal: kv position must be < kv_len and <= query position
     mask = (kv_pos[:, None, :] <= positions[..., None]) & (
         kv_pos[:, None, :] < kv_lens[:, None, None]
     )  # [B, T, ctx]
+    if window:
+        mask = mask & (kv_pos[:, None, :] > positions[..., None] - window)
     scores = jnp.where(mask[:, :, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("btkgs,bskh->btkgh", probs, v.astype(jnp.float32))
@@ -789,12 +819,15 @@ def paged_attention_decode_xla(
     kv_lens: jax.Array,  # [B] kv length INCLUDING the current token
     k_cur: jax.Array,  # [B, 1, kh, hd] current token's K (not yet cached)
     v_cur: jax.Array,
+    window: int = 0,
 ) -> jax.Array:
     """Decode attention over cached history PLUS the in-register current
     token. The current K/V never round-trips through the paged pool inside
     the step, so the (TPU-slow) cache scatter is deferred and batched once
     per step for ALL layers (write_kv_stack) instead of 2x per layer —
-    scatters dominate small-batch decode latency otherwise."""
+    scatters dominate small-batch decode latency otherwise. `window` > 0:
+    only the last `window` positions, the current one among them, are
+    seen (table and lengths in the page group's own frame)."""
     values, scales = _kv_parts(kv_cache)
     b, _, qh, hd = q.shape
     ps = values.shape[3]
@@ -821,6 +854,8 @@ def paged_attention_decode_xla(
     # History: positions 0 .. kv_len-2 (the current token is separate).
     kv_pos = jnp.arange(ctx)[None, :]
     mask = kv_pos < (kv_lens[:, None] - 1)
+    if window:
+        mask = mask & (kv_pos >= kv_lens[:, None] - window)
     scores = jnp.where(mask[:, None, None, :], scores, -1e30)
     cur = jnp.einsum("bkgh,bkh->bkg",
                      qg.astype(jnp.float32),

@@ -379,16 +379,19 @@ def _pool_decode_kernel(
     layer_ref,  # [1] int32
     buf_idx_ref,  # [1] int32 (mutable scalar-prefetch: double-buffer slot)
     init_ref,  # [1] int32 (1 until the first DMA was issued)
-    # inputs + outputs + scratch, order depending on `quantized` —
-    # unpacked below (Pallas passes refs positionally)
-    q_ref,  # [1, kh*g, hd] (block for this b; rows head-major)
-    pool_ref,  # FULL [L, 2, P, ps, kh, hd] in HBM (memory_space=ANY)
+    # `windowed`: one more scalar prefetch, then inputs + outputs +
+    # scratch, order depending on `quantized` — unpacked below (Pallas
+    # passes refs positionally)
+    #   starts_ref  [B] int32: the first history token a row still sees
+    #   q_ref       [1, kh*g, hd] (block for this b; rows head-major)
+    #   pool_ref    FULL [L, 2, P, ps, kh, hd] in HBM (memory_space=ANY)
     *rest,
     pages_per_chunk: int,
     block_pages: int,
     max_pages: int,
     batch_size: int,
     quantized: bool = False,
+    windowed: bool = False,
 ):
     """Flash decode over the paged HISTORY reading the WHOLE pool ref.
 
@@ -423,7 +426,20 @@ def _pool_decode_kernel(
     convert to the operand dtype exactly; the K scale multiplies the f32
     scores and the V scale the f32 probabilities (`_token_scale_row`),
     never the [tok*kh, hd] tiles.
+
+    `windowed` (static): a window layer's page group. The table's column
+    0 is the first block the row still holds and the lengths count from
+    that block's first token (engine/pages.py frees behind the window),
+    so the pages streamed ARE the live window; `starts_ref` masks what
+    is left of the oldest page below the window's lower edge. The copies
+    and their order are the unwindowed kernel's: a start past the first
+    block would leave that block without a live token, and is the
+    caller's to rule out (it holds one block of slack at most).
     """
+    starts_ref = None
+    if windowed:
+        starts_ref, *rest = rest
+    q_ref, pool_ref, *rest = rest
     if quantized:
         (scale_ref,  # FULL bf16 [L, 2, P, ps, LANES] in HBM (ANY)
          acc_ref, m_out_ref, l_out_ref,
@@ -538,6 +554,9 @@ def _pool_decode_kernel(
             else:
                 s = s * sm_scale
             live = col_tok < length - (i * bk + u * block_tok)
+            if windowed:
+                live = jnp.logical_and(
+                    live, col_tok >= starts_ref[b] - (i * bk + u * block_tok))
             s = jnp.where(jnp.logical_and(same_head, live), s, -jnp.inf)
             m_prev = m_ref[:, 0:1]  # [kh*g, 1]
             l_prev = l_ref[:, 0:1]
@@ -569,33 +588,12 @@ def _pool_decode_kernel(
         l_out_ref[0] = l_ref[...]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("pages_per_chunk", "interpret"),
-                   # Read-only on the WHOLE paged pool by design: the
-                   # decode step that calls this still owns (and
-                   # donates) the cache through its own jit boundary.
-                   donate_argnums=())
-def paged_decode_attention_pool(
-    q: jax.Array,  # [B, qh, hd]
-    kv_pool: jax.Array,  # [L, 2, P, ps, kh, hd] — the WHOLE cache
-    layer: jax.Array,  # scalar int32
-    block_tables: jax.Array,  # [B, max_pages] int32
-    kv_lens_hist: jax.Array,  # [B] int32 history length (current excluded)
-    kv_scales=None,  # bf16 [L, 2, P, ps, LANES] for an int8 pool
-    *,
-    pages_per_chunk: int | None = None,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Chunked-DMA flash partials over the paged history; see
-    _pool_decode_kernel for what it streams and how it scores. Returns
-    (acc, m, l) unnormalized for the deferred current-token combine. With
-    `kv_scales` the pool is int8 (the q8 path). A row with history 0 is
-    skipped: callers pass 0 for every slot that is not active.
-
-    `pages_per_chunk` is a cap on the DMA chunk; left None it follows
-    from the static table width (`_CHUNK_TOKENS`), so a sequence is a
-    few grid steps whatever width the scheduler bucketed it to."""
+def _pool_flash_partials(q, kv_pool, layer, block_tables, kv_lens_hist,
+                         kv_scales, starts, pages_per_chunk, interpret):
+    """The pallas_call both jitted entry points share; `starts` not None
+    is the windowed kernel (one more scalar prefetch)."""
     quantized = kv_scales is not None
+    windowed = starts is not None
     b, qh, hd = q.shape
     ps, kh = kv_pool.shape[3], kv_pool.shape[4]
     max_pages = block_tables.shape[1]
@@ -626,8 +624,19 @@ def paged_decode_attention_pool(
         pltpu.VMEM((qh, 128), jnp.float32),
         pltpu.VMEM((qh, hd), jnp.float32),
     ]
+    prefetch = [kv_lens_hist.astype(jnp.int32),
+                block_tables.reshape(-1).astype(jnp.int32),
+                jnp.asarray(layer, jnp.int32).reshape(1),
+                jnp.zeros((1,), jnp.int32),  # double-buffer slot
+                jnp.ones((1,), jnp.int32)]  # init flag
+    kernel = functools.partial(_pool_decode_kernel, pages_per_chunk=ppc,
+                               block_pages=block_pages, max_pages=max_pages,
+                               batch_size=b, quantized=quantized)
+    if windowed:
+        prefetch.append(starts.astype(jnp.int32))
+        kernel = functools.partial(kernel, windowed=True)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=len(prefetch),
         grid=(b, n_chunks),
         in_specs=in_specs,
         out_specs=[
@@ -638,9 +647,7 @@ def paged_decode_attention_pool(
         scratch_shapes=scratch,
     )
     acc, m, l = pl.pallas_call(
-        functools.partial(_pool_decode_kernel, pages_per_chunk=ppc,
-                          block_pages=block_pages, max_pages=max_pages,
-                          batch_size=b, quantized=quantized),
+        kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, qh, hd), jnp.float32),
@@ -651,15 +658,66 @@ def paged_decode_attention_pool(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
-    )(kv_lens_hist.astype(jnp.int32),
-      block_tables.reshape(-1).astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.zeros((1,), jnp.int32),  # double-buffer slot
-      jnp.ones((1,), jnp.int32),  # init flag
-      *operands)
+        **({"name": "paged_decode_attention_window"} if windowed else {}),
+    )(*prefetch, *operands)
     group = qh // kh
     return (acc.reshape(b, kh, group, hd),
             m[..., 0].reshape(b, kh, group), l[..., 0].reshape(b, kh, group))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("pages_per_chunk", "interpret"),
+                   # Read-only on the WHOLE paged pool by design: the
+                   # decode step that calls this still owns (and
+                   # donates) the cache through its own jit boundary.
+                   donate_argnums=())
+def paged_decode_attention_pool(
+    q: jax.Array,  # [B, qh, hd]
+    kv_pool: jax.Array,  # [L, 2, P, ps, kh, hd] — the WHOLE cache
+    layer: jax.Array,  # scalar int32
+    block_tables: jax.Array,  # [B, max_pages] int32
+    kv_lens_hist: jax.Array,  # [B] int32 history length (current excluded)
+    kv_scales=None,  # bf16 [L, 2, P, ps, LANES] for an int8 pool
+    *,
+    pages_per_chunk: int | None = None,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Chunked-DMA flash partials over the paged history; see
+    _pool_decode_kernel for what it streams and how it scores. Returns
+    (acc, m, l) unnormalized for the deferred current-token combine. With
+    `kv_scales` the pool is int8 (the q8 path). A row with history 0 is
+    skipped: callers pass 0 for every slot that is not active.
+
+    `pages_per_chunk` is a cap on the DMA chunk; left None it follows
+    from the static table width (`_CHUNK_TOKENS`), so a sequence is a
+    few grid steps whatever width the scheduler bucketed it to."""
+    return _pool_flash_partials(q, kv_pool, layer, block_tables,
+                                kv_lens_hist, kv_scales, None,
+                                pages_per_chunk, interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("pages_per_chunk", "interpret"),
+                   donate_argnums=())
+def paged_decode_attention_window(
+    q: jax.Array,  # [B, qh, hd]
+    kv_pool: jax.Array,  # the window group's WHOLE cache
+    layer: jax.Array,
+    block_tables: jax.Array,  # [B, window pages] the group's own table
+    kv_lens_hist: jax.Array,  # [B] history length in the table's frame
+    starts: jax.Array,  # [B] first history token still seen, same frame
+    *,
+    pages_per_chunk: int | None = None,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """`paged_decode_attention_pool` over a window layer's page group,
+    under a name of its own so that a device trace tells the window
+    layers' events from the full layers'. Streams the table's pages up
+    to the history length, which the allocator keeps to the live window,
+    and masks the tokens before `starts`."""
+    return _pool_flash_partials(q, kv_pool, layer, block_tables,
+                                kv_lens_hist, None, starts,
+                                pages_per_chunk, interpret)
 
 
 def paged_attention_decode_fused(
@@ -721,6 +779,7 @@ def paged_attention_decode_pool(
     k_cur: jax.Array,  # [B, 1, kh, hd]
     v_cur: jax.Array,
     *,
+    window: int = 0,
     pages_per_chunk: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -734,7 +793,9 @@ def paged_attention_decode_pool(
     every slot that is not active. `pages_per_chunk` caps the DMA chunk;
     None sizes it from the table width. An int8 (values, scales) cache
     takes the q8 path: half the page bytes, the per-token scales applied
-    to the scores and the probabilities."""
+    to the scores and the probabilities. `window` > 0: a window layer's
+    page group (its own table and lengths; bf16 pool only), through
+    `paged_decode_attention_window`."""
     values, scales = (kv_cache if isinstance(kv_cache, tuple)
                       else (kv_cache, None))
     if _q8_needs_xla(values, scales, interpret):
@@ -742,6 +803,12 @@ def paged_attention_decode_pool(
 
         return paged_attention_decode_xla(q, kv_cache, layer, block_tables,
                                           kv_lens, k_cur, v_cur)
+    if window:
+        acc, m, l = paged_decode_attention_window(
+            q[:, 0], values, layer, block_tables,
+            jnp.maximum(kv_lens - 1, 0), jnp.maximum(kv_lens - window, 0),
+            pages_per_chunk=pages_per_chunk, interpret=interpret)
+        return _combine_current(q, acc, m, l, k_cur, v_cur)
     acc, m, l = paged_decode_attention_pool(
         q[:, 0], values, layer, block_tables,
         jnp.maximum(kv_lens - 1, 0), kv_scales=scales,

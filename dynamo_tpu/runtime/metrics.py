@@ -187,8 +187,10 @@ PREEMPT_TOTAL = Counter(
     "offloaded to the KVBM park store under interactive pressure), "
     "migrate (cooperative preempt-and-migrate fallback — the worker "
     "emitted finish_reason=migrate), resume (parked sequence restored "
-    "and decoding again)",
-    ["kind"], registry=REGISTRY,
+    "and decoding again); and by what admission had run out of: a slot, "
+    "the full page group, the window page group (a resume counts under "
+    "full)",
+    ["kind", "group"], registry=REGISTRY,
 )
 # Runtime protocol conformance (runtime/conformance.py): lifecycle
 # events the ProtocolMonitor observed that the dynastate spec machines
@@ -375,6 +377,37 @@ KV_RESERVED_PAGE_MS = Gauge(
     "hold a slot, prefix-cache residue left out) x the step's wall ms. "
     "Over the growth of dynamo_step_part_ms_total{part=wall} it is the "
     "time-weighted mean of pages reserved by live sequences",
+    ["worker"], registry=REGISTRY,
+)
+KV_WINDOW_RESERVED_PAGE_MS = Gauge(
+    "dynamo_kv_window_reserved_page_ms",
+    "Model with window and full attention layers: the twin of "
+    "dynamo_kv_reserved_page_ms (which keeps counting the full page "
+    "group) for the window group: pages of the second pool held by "
+    "sequences in a slot x the step's wall ms. Over the growth of "
+    "dynamo_step_part_ms_total{part=wall} and --window-pages it is the "
+    "share of the window group that is held",
+    ["worker"], registry=REGISTRY,
+)
+KV_WINDOW_PAGES_FREED = Gauge(
+    "dynamo_kv_window_pages_freed_total",
+    "Window page group: pages returned to the pool because every window "
+    "layer's reach had passed them while their sequence lived (not at "
+    "release), by the phase whose launch moved the window",
+    ["worker", "phase"], registry=REGISTRY,
+)
+KV_WINDOW_EDGE_TOKENS = Gauge(
+    "dynamo_kv_window_edge_tokens_total",
+    "Window page group: positions by which sequences' windows' lower "
+    "edges moved, by phase. A page goes back for every --page-size of "
+    "them when the allocator is sound "
+    "(dynamo_kv_window_pages_freed_total over this)",
+    ["worker", "phase"], registry=REGISTRY,
+)
+KV_WINDOW_ALLOC_FAIL = Gauge(
+    "dynamo_kv_window_alloc_fail_total",
+    "Window page group: reservations (admission) and prefill-chunk "
+    "allocations the pool could not give; the sequence waited",
     ["worker"], registry=REGISTRY,
 )
 SSM_STATE_SLOT_MS = Gauge(

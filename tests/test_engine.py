@@ -145,6 +145,104 @@ class TestPagePool:
         assert pool.free_count() + pool.cached_count() == 15
 
 
+class TestWindowPool:
+    """The window page group's free list (a model with window AND full
+    attention layers): reserved at admission, allocated ahead of a
+    launch, freed behind the window while the sequence lives."""
+
+    PAGE, WINDOW = 16, 1024
+
+    def pool(self, pages=5120):
+        from dynamo_tpu.engine.pages import WindowPool
+
+        return WindowPool(pages, self.PAGE, self.WINDOW)
+
+    def test_the_bounds_a_row_is_held_to(self):
+        pool = self.pool()
+        # a decoding row: the window and the 16 positions a fused block
+        # and its pipelined second may write, wherever a page starts
+        assert pool.bound(16) == 1024 // 16 + 2 == 66
+        assert pool.bound(1) == 65
+        # a row inside a prefill chunk of C tokens: (1024 + C) / 16 + 1
+        assert [pool.bound(c) for c in (256, 1024, 2048)] == [
+            81, 129, 193]
+
+    @pytest.mark.parametrize("chunk,lookahead", [(2048, 16), (512, 8),
+                                                 (1000, 1)])
+    def test_a_row_never_holds_more_than_its_bound(self, chunk, lookahead):
+        """A 7,000-token prompt in chunks, then 500 decode launches of
+        `lookahead` positions: the pages held at every launch against the
+        bound of its phase, every freed page back on the free list."""
+        pool = self.pool(400)
+        lease = pool.reserve(pool.bound(lookahead))
+        pos = 0
+        while pos < 7000:
+            c = min(chunk, 7000 - pos)
+            assert pool.advance(lease, max(0, pos - self.WINDOW + 1),
+                                pos + c - 1, "prefill")
+            assert len(lease.pages) <= pool.bound(c)
+            assert lease.first * 16 <= max(0, pos - self.WINDOW + 1)
+            pos += c
+            # behind the launch, what its next one cannot see goes back
+            pool.advance(lease, max(0, pos - self.WINDOW + 1), pos - 1,
+                         "prefill")
+            assert len(lease.pages) <= 65
+        for _ in range(500):
+            assert pool.advance(lease, pos - self.WINDOW + 1,
+                                pos + lookahead - 1, "decode")
+            held = len(lease.pages)
+            assert held <= pool.bound(lookahead) <= 66
+            # the table's frame: column 0 holds the window's oldest token
+            assert lease.first == (pos - self.WINDOW + 1) // 16
+            assert held + pool.free_count() == 399
+            pos += lookahead
+        assert len(set(lease.pages)) == len(lease.pages)
+        # a page behind for every 16 positions the window's edge moved
+        for phase in ("prefill", "decode"):
+            per = pool.freed_behind[phase] / pool.edge_tokens[phase]
+            assert abs(per - 1 / 16) < 0.003, (phase, per)
+        pool.release(lease)
+        assert pool.free_count() == pool.unreserved() == 399
+        assert sorted(pool._free) == list(range(1, 400))
+
+    def test_admission_reserves_and_a_decoding_row_never_waits(self):
+        """64 rows x 66 reserved of 5,119: the 65th finds no room; a
+        prefill chunk takes from the remainder and goes without when it
+        is spent; a decoding row's pages are always there."""
+        pool = self.pool()
+        leases = [pool.reserve(66) for _ in range(77)]
+        assert pool.unreserved() == 5119 - 77 * 66 == 37
+        assert pool.reserve(66) is None and pool.alloc_fail == 1
+        # a chunk of 2,048 from position 0 needs 128 pages: 66 are its
+        # own, 62 come from the 37 that are left: refused, nothing taken
+        assert not pool.advance(leases[0], 0, 2047, "prefill")
+        assert leases[0].pages == [] and pool.alloc_fail == 2
+        assert pool.advance(leases[0], 0, 16 * (66 + 37) - 1, "prefill")
+        assert pool.unreserved() == 0
+        # every other row still decodes from its reservation
+        for lease in leases[1:]:
+            assert pool.advance(lease, 0, 1039, "decode")
+            assert len(lease.pages) == 65
+        assert not pool.advance(leases[1], 0, 1024 + 32, "prefill")
+        # row 0 decodes on: its surplus returns as its window moves
+        pos = 16 * (66 + 37)
+        assert pool.advance(leases[0], pos - 1023, pos + 15, "decode")
+        assert len(leases[0].pages) <= 66 and pool.unreserved() > 30
+
+    def test_release_and_preemption_leave_the_free_list_whole(self):
+        pool = self.pool(300)
+        a, b = pool.reserve(66), pool.reserve(40)
+        assert pool.advance(a, 0, 2999, "prefill")
+        assert pool.advance(b, 0, 500, "prefill")
+        pool.release(b)  # preempted (cooperative migrate) mid-prefill
+        assert b.pages == [] and b.reserved == 0
+        pool.release(a)
+        assert pool.free_count() == pool.unreserved() == 299
+        assert len(set(pool._free)) == 299 and 0 not in pool._free
+        c = pool.reserve(66)  # and what was freed is allocated again
+        assert pool.advance(c, 0, 1055, "decode") and len(c.pages) == 66
+
+
 @pytest.fixture(scope="module")
 def runner():
     return _runner()

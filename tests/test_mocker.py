@@ -206,9 +206,10 @@ class TestMockerPreemption:
             priority=priority,
         ).to_wire()
 
-    def test_interactive_preempts_batch_slot(self, run):
+    def _one_preemption(self, run):
+        """One slot, a batch stream decoding in it, then an interactive
+        arrival that MUST preempt to run."""
         async def body():
-            # One slot: the interactive arrival MUST preempt to run.
             engine = MockerEngine(_fast_config(max_batch=1,
                                                speedup_ratio=50.0))
 
@@ -224,19 +225,38 @@ class TestMockerPreemption:
                 await asyncio.sleep(0.005)
                 if engine._running and engine._running[0].generated >= 1:
                     break
-            inter_tokens, inter_last = await one(self._request(
+            inter_tokens, _ = await one(self._request(
                 range(64, 96), 4, "inter-1", priority="interactive"))
             batch_tokens, batch_last = await batch_task
             await engine.close()
-            assert engine.preempt_parked >= 1
-            assert engine.preempt_resumed == engine.preempt_parked
-            assert len(inter_tokens) == 4
-            # The preempted batch stream still delivers every token.
-            assert len(batch_tokens) == 24
-            assert batch_last.finish_reason == "length"
-            assert not engine._parked
+            return engine, inter_tokens, batch_tokens, batch_last
 
-        run(body())
+        return run(body())
+
+    def test_interactive_preempts_batch_slot(self, run):
+        engine, inter_tokens, batch_tokens, batch_last = \
+            self._one_preemption(run)
+        assert engine.preempt_parked >= 1
+        assert engine.preempt_resumed == engine.preempt_parked
+        assert len(inter_tokens) == 4
+        # The preempted batch stream still delivers every token.
+        assert len(batch_tokens) == 24
+        assert batch_last.finish_reason == "length"
+        assert not engine._parked
+
+    def test_a_park_and_its_resume_reach_dynamo_preempt_total(self, run):
+        """The counter `mocker/overload.py`'s `preemptions_observed`
+        reads: the mocker swallows a metrics error, so a label set that
+        no longer matches the family's would lose the count silently."""
+        from dynamo_tpu.runtime.metrics import PREEMPT_TOTAL
+
+        def count(kind, group):
+            return PREEMPT_TOTAL.labels(kind=kind, group=group)._value.get()
+
+        before = count("park", "slot"), count("resume", "full")
+        engine, *_ = self._one_preemption(run)
+        assert count("park", "slot") - before[0] == engine.preempt_parked >= 1
+        assert count("resume", "full") - before[1] == engine.preempt_resumed
 
     def test_waiting_order_is_class_strict(self, run, monkeypatch):
         # No preemption: this test pins pure ADMISSION order, so the

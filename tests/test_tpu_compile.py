@@ -112,6 +112,84 @@ def test_the_expert_grouped_matmuls_compile_for_v5e(one_chip, rows):
         assert "tpu_custom_call" in compiled.as_text()
 
 
+# mellum2-12b-a2.5b at its published widths (4 kv heads, 8 query heads a
+# kv head, bf16 pools, 64 rows): the window layers' kernel over their
+# page group's 72-column table, the full layers' over the widest and
+# narrowest tables the cell's contexts reach, and the SwiGLU experts'
+# grouped matmuls (a fused [gate | up] of 2 x 896 rows over hidden 2304,
+# 64 held, 8 of 64 a token) at a decode step's and a prefill launch's rows.
+MELLUM_ROWS = 64
+
+
+@pytest.mark.parametrize("width", [72, 32, 512])
+def test_the_window_and_full_decode_kernels_compile_for_mellum(one_chip,
+                                                               width):
+    from dynamo_tpu.ops.paged_attention import (
+        paged_decode_attention_pool,
+        paged_decode_attention_window,
+    )
+
+    windowed = width == 72
+    layers, n_pages = (6, 5120) if windowed else (2, 32768)
+    args = [
+        _shape(one_chip, (MELLUM_ROWS, 32, HEAD_DIM), jnp.bfloat16),
+        _shape(one_chip, (layers, 2, n_pages, PAGE, 4, HEAD_DIM),
+               jnp.bfloat16),
+        _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (MELLUM_ROWS, width), jnp.int32),
+        _shape(one_chip, (MELLUM_ROWS,), jnp.int32),
+    ]
+    if windowed:
+        text = paged_decode_attention_window.lower(
+            *args, _shape(one_chip, (MELLUM_ROWS,), jnp.int32)
+        ).compile().as_text()
+        assert "paged_decode_attention_window" in text
+    else:
+        text = paged_decode_attention_pool.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("window", [0, 1024])
+def test_mellums_prefill_attention_copies_no_pool(one_chip, window):
+    """`models/hybrid.prefill_attention` at the cell's widest launch
+    gathers its pages straight from the page group's whole cache: inside
+    its `lax.map` body and the full layers' `lax.switch` branches a
+    `cache[layer, 0][tables]` was a copy of the layer's K and V pools
+    first (1.07 GB a full layer a block of queries: PERF.md, PR 36)."""
+    import functools
+
+    from dynamo_tpu.models.hybrid import prefill_attention
+
+    layers, n_pages, width = (6, 5120, 200) if window else (2, 32768, 512)
+    pool = 2 * n_pages * PAGE * 4 * HEAD_DIM * 2  # one layer's K and V
+    compiled = jax.jit(functools.partial(
+        prefill_attention, layer=layers - 1, window=window)).lower(
+        _shape(one_chip, (1, 2048, 32, HEAD_DIM), jnp.bfloat16),
+        _shape(one_chip, (layers, 2, n_pages, PAGE, 4, HEAD_DIM),
+               jnp.bfloat16),
+        block_tables=_shape(one_chip, (1, width), jnp.int32),
+        positions=_shape(one_chip, (1, 2048), jnp.int32),
+        kv_lens=_shape(one_chip, (1,), jnp.int32)).compile()
+    assert "slice_bitcast_fusion" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < pool
+
+
+@pytest.mark.parametrize("rows", [512, 16384])
+def test_the_swiglu_expert_grouped_matmuls_compile_for_v5e(one_chip, rows):
+    from dynamo_tpu.ops.grouped_matmul import expert_gmm
+
+    held, hidden, width = 64, 2304, 896
+    bf16 = jnp.bfloat16
+    sizes = _shape(one_chip, (held,), jnp.int32)
+    for k, n, transpose in ((hidden, 2 * width, True),
+                            (width, width, False)):
+        compiled = expert_gmm.lower(
+            _shape(one_chip, (rows, k), bf16),
+            _shape(one_chip, (held, n, hidden), bf16), sizes,
+            path="pallas", transpose_rhs=transpose).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
 # mistral-7b's five int4 projections (K, N): wq/wo, wk/wv, gate/up,
 # down, the head.
 Q4_PROJECTIONS = {"wq-wo": (4096, 4096), "wk-wv": (4096, 1024),
