@@ -274,7 +274,16 @@ def make_kv_cache_int8(config: ModelConfig, num_pages: int,
     reference gets fp8 KV from its engines' quantized cache modes).
     Head-sharing costs little: qk-norm families normalize per head, so
     per-token absmax dominates. Standard-attention models only (MLA's
-    latent is already ~10x smaller)."""
+    latent is already ~10x smaller).
+
+    Both arrays stay row-major on the device, in every step program: the
+    decode kernels (ops/paged_attention.py) take the whole pool as an
+    operand and Mosaic constrains an operand to row-major, so a program
+    that gives the donated pool any other layout copies all of it out
+    and back every step (1.34 GB of scales at 32 layers x 5120 pages:
+    PERF.md, PR 37). Write scales with `_write_scale_rows` or one layer
+    at a time (`write_kv_pages`), never by a scatter indexed on several
+    of the array's dimensions; tests/test_tpu_compile.py holds it."""
     assert not config.is_mla, "int8 KV targets standard-attention models"
     values = jnp.zeros(
         (config.n_layers, 2, num_pages, page_size, config.n_kv_heads,
@@ -1405,6 +1414,36 @@ def make_pp_prefill(config: ModelConfig, mesh, n_micro: int):
     return run
 
 
+def _write_scale_rows(
+    scales: jax.Array,  # [L, 2, P, ps, LANES]
+    ks: jax.Array,  # [L, B, T, LANES]
+    vs: jax.Array,
+    flat_pages: jax.Array,  # [B*T]
+    flat_off: jax.Array,  # [B*T], in [0, ps)
+) -> jax.Array:
+    """`scales.at[:, kv, flat_pages, flat_off].set(.., mode="drop")` for
+    K and V, written as ONE scatter of whole rows of the array's flat
+    `[L*2*P*ps, LANES]` view (a bitcast of the row-major array). Indexed
+    on four dimensions, XLA's TPU layout assignment gives the scatter,
+    and with it the donated pool the decode loop carries, the layout
+    {4,0,3,2,1}; the decode kernel's operand must be row-major, so every
+    step copied the whole scale array out and back (PERF.md, PR 37). A
+    page the indexed form dropped (past the pool after numpy's wrap of a
+    negative index) goes to the row past the end, which `drop` drops:
+    a plain flat index would alias it into the next layer's rows."""
+    n_layers, _, n_pages, page_size, lanes = scales.shape
+    n_rows = n_layers * 2 * n_pages * page_size
+    pages = jnp.where(flat_pages < 0, flat_pages + n_pages, flat_pages)
+    plane = jnp.arange(n_layers * 2, dtype=jnp.int32)[:, None]
+    rows = jnp.where(
+        (pages >= 0) & (pages < n_pages),
+        (plane * n_pages + pages) * page_size + flat_off, n_rows)
+    new = jnp.stack([ks, vs], axis=1)  # [L, 2, B, T, LANES]: plane-major
+    flat = scales.reshape(n_rows, lanes).at[rows.reshape(-1)].set(
+        new.reshape(-1, lanes), mode="drop")
+    return flat.reshape(scales.shape)
+
+
 def write_kv_stack(
     kv_cache,  # [L, 2, P, ps, kh, hd] or int8 (values, scales) pair
     k_stack: jax.Array,  # [L, B, T, kh, hd]
@@ -1414,7 +1453,11 @@ def write_kv_stack(
     valid: jax.Array,  # [B, T]
 ):
     """Scatter every layer's K/V chunk into the paged pool in one shot
-    (deferred decode writeback + ring-prefill writeback)."""
+    (deferred decode writeback + ring-prefill writeback). A row whose
+    `valid` is false goes to scratch page 0; a position past the table
+    or a page outside the pool is dropped. An int8 pool's scales go in
+    as rows of the array's flat view, which keeps the array row-major
+    for the decode kernel (`_write_scale_rows`)."""
     values, scales = _kv_parts(kv_cache)
     n_layers, b, t = k_stack.shape[:3]
     page_size = values.shape[3]
@@ -1430,11 +1473,8 @@ def write_kv_stack(
             kq.reshape(n_layers, b * t, *kq.shape[3:]), mode="drop")
         values = values.at[:, 1, flat_pages, flat_off].set(
             vq.reshape(n_layers, b * t, *vq.shape[3:]), mode="drop")
-        scales = scales.at[:, 0, flat_pages, flat_off].set(
-            ks.reshape(n_layers, b * t, ks.shape[-1]), mode="drop")
-        scales = scales.at[:, 1, flat_pages, flat_off].set(
-            vs.reshape(n_layers, b * t, vs.shape[-1]), mode="drop")
-        return values, scales
+        return values, _write_scale_rows(scales, ks, vs, flat_pages,
+                                         flat_off)
     values = values.at[:, 0, flat_pages, flat_off].set(
         k_stack.reshape(n_layers, b * t, *k_stack.shape[3:]), mode="drop"
     )

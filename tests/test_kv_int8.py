@@ -21,6 +21,7 @@ from dynamo_tpu.models.transformer import (
     make_kv_cache_int8,
     paged_attention_decode_xla,
     quantize_kv,
+    write_kv_stack,
 )
 
 
@@ -46,6 +47,140 @@ class TestQuantize:
         q, s = quantize_kv(jnp.zeros((2, 5, 4, 16)))
         assert np.asarray(q).sum() == 0
         assert np.asarray(s, np.float32).sum() == 0
+
+
+# write_kv_stack over an int8 pool: 3 layers, 6 pages of 4, 4-page tables.
+W_LAYERS, W_PAGES, W_PS, W_KH = 3, 6, 4, 2
+
+
+def _rows(tables, positions, valid):
+    return (np.asarray(tables, np.int32), np.asarray(positions, np.int32),
+            np.asarray(valid, bool))
+
+
+# name -> (block tables [B, 4], positions [B, T], valid [B, T])
+WRITE_CASES = {
+    "decode-active-and-inactive": _rows(
+        [[1, 2, 0, 0], [3, 0, 0, 0], [4, 5, 0, 0]],
+        [[5], [2], [7]], [[True], [False], [True]]),
+    "chunk-with-padding": _rows(
+        [[1, 2, 3, 0], [4, 5, 0, 0]],
+        [[2, 3, 4, 5, 6], [0, 1, 2, 3, 4]],
+        [[True] * 5, [True, True, True, False, False]]),
+    # every row lands on scratch page 0, several on the same offset
+    "duplicates-on-page-0": _rows(
+        [[0, 0, 0, 0], [0, 0, 0, 0], [1, 0, 0, 0]],
+        [[0, 1, 4], [0, 5, 1], [1, 4, 9]],
+        [[True, True, True], [True, False, True], [False, True, True]]),
+    # a table entry past the pool (6, 40), and one a negative index wraps
+    "page-past-the-pool": _rows(
+        [[1, 6, 2, 0], [40, -1, -7, 3]],
+        [[3, 4, 8], [0, 5, 9]], [[True] * 3, [True] * 3]),
+    # the ring write-back: positions run on past a 4-page table's 16
+    "position-past-the-table": _rows(
+        [[1, 2, 3, 4], [5, 1, 0, 0]],
+        [list(range(12, 20)), list(range(8))],
+        [[True] * 8, [True] * 5 + [False] * 3]),
+    "position-past-the-table-t1": _rows(
+        [[1, 2, 3, 4], [5, 0, 0, 0]], [[16], [3]], [[True], [True]]),
+}
+
+
+def _write_inputs(case):
+    tables, positions, valid = WRITE_CASES[case]
+    rng = np.random.default_rng(sorted(WRITE_CASES).index(case))
+    b, t = positions.shape
+    shape = (W_LAYERS, b, t, W_KH, 128)
+    k = jnp.asarray(rng.normal(size=shape) * 2.0, jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=shape) * 0.5, jnp.bfloat16)
+    # a pool that already holds something everywhere, so a write that
+    # lands where it should not shows
+    values = jnp.asarray(rng.integers(
+        -127, 128, (W_LAYERS, 2, W_PAGES, W_PS, W_KH, 128)), jnp.int8)
+    scales = jnp.asarray(rng.uniform(
+        0.01, 1.0, (W_LAYERS, 2, W_PAGES, W_PS, 128)), jnp.bfloat16)
+    return ((values, scales), k, v, jnp.asarray(tables),
+            jnp.asarray(positions), jnp.asarray(valid))
+
+
+def _write_numpy(kv, k, v, tables, positions, valid):
+    """Element by element, in row order (a later duplicate wins, as on
+    the CPU's scatter): an invalid row goes to page 0, a position past
+    the table and a page outside the pool (after numpy's wrap of a
+    negative index) are dropped."""
+    values, scales = (np.array(a) for a in kv)
+    tables, positions, valid = (np.asarray(a) for a in
+                                (tables, positions, valid))
+    for kv_i, x in enumerate((k, v)):
+        q, s = (np.asarray(a) for a in quantize_kv(x))
+        for layer in range(q.shape[0]):
+            for b in range(q.shape[1]):
+                for t in range(q.shape[2]):
+                    pos = int(positions[b, t])
+                    if not valid[b, t]:
+                        page = 0
+                    elif pos // W_PS >= tables.shape[1]:
+                        continue
+                    else:
+                        page = int(tables[b, pos // W_PS])
+                    if page < 0:
+                        page += W_PAGES
+                    if not 0 <= page < W_PAGES:
+                        continue
+                    values[layer, kv_i, page, pos % W_PS] = q[layer, b, t]
+                    scales[layer, kv_i, page, pos % W_PS] = s[layer, b, t]
+    return values, scales
+
+
+def _write_indexed(kv, k, v, tables, positions, valid):
+    """The write as it stood before PR 37: four scatters, each indexed
+    on (layer, k|v, page, offset)."""
+    values, scales = kv
+    n_layers, b, t = k.shape[:3]
+    page = jnp.take_along_axis(tables, positions // W_PS, axis=1)
+    pages = jnp.where(valid, page, 0).reshape(-1)
+    offs = (positions % W_PS).reshape(-1)
+    for kv_i, x in enumerate((k, v)):
+        q, s = quantize_kv(x)
+        values = values.at[:, kv_i, pages, offs].set(
+            q.reshape(n_layers, b * t, *q.shape[3:]), mode="drop")
+        scales = scales.at[:, kv_i, pages, offs].set(
+            s.reshape(n_layers, b * t, s.shape[-1]), mode="drop")
+    return values, scales
+
+
+class TestWriteKvStackInt8:
+    """The scales go in as rows of the array's flat view (the layout the
+    decode kernel reads: PERF.md, PR 37); what lands where, and what is
+    dropped, is what the indexed write gave, to the bit."""
+
+    @pytest.mark.parametrize("reference", [_write_numpy, _write_indexed],
+                             ids=["numpy", "indexed"])
+    @pytest.mark.parametrize("case", sorted(WRITE_CASES))
+    def test_bit_equal_to(self, case, reference):
+        args = _write_inputs(case)
+        got_v, got_s = jax.jit(write_kv_stack)(*args)
+        want_v, want_s = reference(*args)
+        assert got_v.dtype == jnp.int8 and got_s.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(got_v),
+                                      np.asarray(want_v))
+        np.testing.assert_array_equal(
+            np.asarray(got_s).view(np.uint16),
+            np.asarray(want_s).view(np.uint16))
+
+    def test_the_cases_write_and_drop(self):
+        """The cases are not vacuous: each changes the pool, and the
+        drop cases leave rows out."""
+        for case in sorted(WRITE_CASES):
+            args = _write_inputs(case)
+            _, got_s = write_kv_stack(*args)
+            assert (np.asarray(got_s) != np.asarray(args[0][1])).any(), case
+        args = _write_inputs("position-past-the-table-t1")
+        _, got_s = write_kv_stack(*args)
+        changed = (np.asarray(got_s) != np.asarray(args[0][1])).any(-1)
+        # row 0 (position 16 of a 16-position table) is dropped: only
+        # row 1's page 5, offset 3 changes, in every layer's K and V
+        assert changed.sum() == W_LAYERS * 2 and changed[:, :, 5, 3].all()
 
 
 def _fp32_cfg():

@@ -10,6 +10,9 @@ process may hold the TPU's library, and every xdist worker imports every
 test file.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -218,3 +221,102 @@ def test_the_q4_matmul_with_a_row_block_map_compiles_for_v5e(one_chip, name):
         assert "tpu_custom_call" in text and "q4_matmul" in text
     # the map is an operand of the prefill call alone
     assert "s32[16]" in prefill and "s32[" not in decode
+
+
+def _copies(text, at_least):
+    """Result shapes, of `at_least` bytes or more, of the `copy` and
+    `copy-start` instructions in an optimised HLO text."""
+    found = []
+    for line in text.splitlines():
+        result = re.match(
+            r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) copy(?:-start)?\(", line)
+        if not result:
+            continue
+        for bits, dims in re.findall(r"\b[a-z]+(\d+)\[([\d,]+)\]",
+                                     result.group(1)):
+            size = math.prod(int(d) for d in dims.split(","))
+            if size * max(int(bits), 8) // 8 >= at_least:
+                found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("pool", ["int8", "bf16"])
+@pytest.mark.parametrize("block", [8, 1])
+def test_the_dense_decode_programs_copy_no_pool(one_chip, monkeypatch,
+                                                block, pool):
+    """The flagship cell's decode programs (mistral-7b, int4 weights,
+    5120 pages of 16, 32 rows, 64-page tables; 4 of its 32 layers) as
+    `ModelRunner._build_decode_multi` (a `lax.scan` of 8 steps) and
+    `_build_decode` (one step) build them. The pool is donated and
+    written in place: no instruction copies or relays out an array of
+    the scale array's size, in the loop or at the entry. Indexed on
+    (layer, k|v, page, offset) the scales' scatter took the layout
+    {4,0,3,2,1} and every step copied the whole array out of and back
+    into the row-major one the kernel reads: 3 copies and 0.18 GB of
+    temporaries here, 1.34 GB a copy at 32 layers (PERF.md, PR 37)."""
+    import functools
+
+    import dynamo_tpu.ops.q4_linear as q4_linear
+    from dynamo_tpu.engine.sampler import sample
+    from dynamo_tpu.models.config import cut_config, get_config
+    from dynamo_tpu.models.quantize import quantize_params_int4
+    from dynamo_tpu.models.transformer import (
+        forward_decode,
+        init_params,
+        make_kv_cache,
+        make_kv_cache_int8,
+    )
+    from dynamo_tpu.ops.paged_attention import paged_attention_decode_pool
+
+    # the process's backend is the CPU; the program is the chip's
+    monkeypatch.setattr(q4_linear, "kernel_path", lambda option: "pallas")
+    cfg = cut_config(get_config("mistral-7b"), layers=LAYERS)
+    n_pages, width = 5120, 64
+    scale_bytes = LAYERS * 2 * n_pages * PAGE * LANES * 2
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(lambda: quantize_params_int4(
+        init_params(jax.random.PRNGKey(0), cfg), cfg)))
+    kv = on_chip(jax.eval_shape(
+        lambda: make_kv_cache_int8(cfg, n_pages, PAGE) if pool == "int8"
+        else make_kv_cache(cfg, n_pages, PAGE)))
+    attention = functools.partial(paged_attention_decode_pool,
+                                  interpret=False)
+
+    def step(params, kv, tokens, positions, tables, kv_lens, active,
+             temperature, top_p, top_k, seeds, step_idx):
+        def one(kv, toks, pos, lens, sidx):
+            kv, logits = forward_decode(
+                params, cfg, toks, pos, kv, tables, lens, active,
+                decode_attention_fn=attention)
+            return kv, sample(logits[:, 0, :], temperature, top_p, top_k,
+                              seeds, sidx)
+
+        if block == 1:
+            return one(kv, tokens, positions, kv_lens, step_idx)
+
+        def body(carry, _):
+            kv, toks, pos, lens, sidx = carry
+            kv, nxt = one(kv, toks, pos, lens, sidx)
+            return (kv, nxt, pos + 1, lens + 1, sidx + 1), nxt
+
+        (kv, *_), toks = jax.lax.scan(
+            body, (kv, tokens, positions, kv_lens, step_idx), None,
+            length=block)
+        return kv, toks
+
+    def rows(dtype):
+        return _shape(one_chip, (ROWS,), dtype)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, kv, rows(jnp.int32), rows(jnp.int32),
+        _shape(one_chip, (ROWS, width), jnp.int32), rows(jnp.int32),
+        rows(jnp.bool_), rows(jnp.float32), rows(jnp.float32),
+        rows(jnp.int32), rows(jnp.uint32), rows(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "paged_decode_attention_pool" in text and "q4_matmul" in text
+    assert _copies(text, scale_bytes) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < scale_bytes
