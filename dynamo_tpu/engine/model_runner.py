@@ -212,7 +212,7 @@ def _default_spec_attention_fn(mesh: Mesh):
     return partial(paged_attention_spec_pool, interpret=interpret)
 
 
-def _default_decode_attention_fn(mesh: Mesh):
+def _default_decode_attention_fn(mesh: Mesh, latent: bool = False):
     """History-attention kernel for the DEFERRED-write decode path.
 
     On TPU the XLA page gather reads every table's full extent through
@@ -224,11 +224,22 @@ def _default_decode_attention_fn(mesh: Mesh):
     runs it per-shard via shard_map over the kv-head axis (each shard
     streams its local pool slice — ops/paged_attention.py
     make_paged_attention_decode_pool_tp). Meshes with other multi-size
-    axes (dp/sp/ep/pp) keep the XLA path, whose sharding pjit manages."""
+    axes (dp/sp/ep/pp) keep the XLA path, whose sharding pjit manages.
+
+    `latent`: a `layer_pattern` model with latent attention, whose pool
+    is a single stack of latent rows with a kernel of its own, on one
+    device (the worker refuses more); else its XLA oracle
+    (models/hybrid.py)."""
     interpret = _pallas_mode(mesh)
     if interpret is None:
         return None
     n = mesh.devices.size
+    if latent:
+        if n > 1:
+            return None
+        from ..ops.paged_attention import paged_attention_decode_latent
+
+        return partial(paged_attention_decode_latent, interpret=interpret)
     if n == 1:
         from ..ops.paged_attention import paged_attention_decode_pool
 
@@ -267,7 +278,8 @@ class ModelRunner:
         # branch ignores attention_fn, and fast decode is gated off.
         self._decode_attention_fn = (
             None if self._attention_user_supplied or model_config.is_gptoss
-            else _default_decode_attention_fn(mesh))
+            else _default_decode_attention_fn(
+                mesh, latent=model_config.has_latent_layers))
         self._spec_attention_fn = (
             None if self._attention_user_supplied or model_config.is_gptoss
             or model_config.is_mla
@@ -279,6 +291,10 @@ class ModelRunner:
         # (its cache rides `kv_cache` as (full, window)), a second table
         # and its base in every step program. Other models have neither.
         self._windowed = model_config.has_window_layers
+        # Latent attention in a hybrid stack: a single-stack pool with a
+        # decode kernel of its own; its prefill rebuilds keys and values
+        # from the pool for every launch.
+        self._latent = model_config.has_latent_layers
         if self._hybrid:
             self._check_hybrid(model_config, runner_config, mesh)
         if model_config.has_recurrent_state:
@@ -439,6 +455,12 @@ class ModelRunner:
         # (live) and was told to skip (padding only).
         self.prefill_positions = 0
         self.prefill_row_blocks = {"live": 0, "skipped": 0}
+        # A model with latent attention (dynamo_latent_*): cached
+        # positions its decode kernel was asked to read, and positions
+        # whose keys and values prefill launches rebuilt from latents,
+        # both x latent layers.
+        self.latent_decode_tokens = 0
+        self.latent_prefill_expand_tokens = 0
 
     def _kv_cache_sharding(self, mesh: Mesh):
         """Sharding of `kv_cache` as the step programs donate it: one
@@ -470,6 +492,22 @@ class ModelRunner:
             def make():
                 return make_kv_cache(cfg, rc.num_pages, rc.page_size)
         return jax.jit(make, out_shardings=self._kv_sharding)
+
+    def _count_latent_decode(self, kv_lens, active, steps: int) -> None:
+        """Cached positions `steps` decode steps ask the latent kernel
+        for: each active row's history, one longer a step, x layers."""
+        if self._latent:
+            hist = np.asarray(kv_lens, np.int64)[np.asarray(active, bool)] - 1
+            self.latent_decode_tokens += int(
+                steps * hist.sum() + len(hist) * steps * (steps - 1) // 2
+            ) * len(self.model_config.kv_layers)
+
+    def _count_latent_prefill(self, kv_lens: Sequence[int]) -> None:
+        """Positions whose keys and values one prefill launch rebuilds
+        from latents: every row's context up to its chunk's end."""
+        if self._latent:
+            self.latent_prefill_expand_tokens += int(
+                sum(kv_lens)) * len(self.model_config.kv_layers)
 
     def _count_prefill(self, lengths: Sequence[int], rows: int,
                        bucket: int) -> None:
@@ -823,6 +861,7 @@ class ModelRunner:
         dispatch never waits on the first readback (dispatch/readback
         latency hiding)."""
         self.decode_steps += k
+        self._count_latent_decode(kv_lens, active, k)
         fn = self._decode_multi_fns.get(k)
         if fn is None:
             fn = self._build_decode_multi(k)
@@ -1184,8 +1223,16 @@ class ModelRunner:
         buckets = self.config.prefill_buckets
         return max(1, buckets[-1] // buckets[0])
 
+    @property
+    def bounds_prefill_launches(self) -> bool:
+        """Whether a prefill launch's rows x bucket must stay inside the
+        token budget (`prefill_launch_fits`): a model whose prefill
+        attention scores a launch's positions against wide tables in
+        float32: window layers beside full ones, latent layers."""
+        return self._windowed or self._latent
+
     def prefill_launch_fits(self, lengths: Sequence[int]) -> bool:
-        """A model with window layers: whether rows of these chunk
+        """`bounds_prefill_launches`: whether rows of these chunk
         lengths make a launch (rows to a power of two x the longest's
         bucket) of no more positions than the token budget. Its tables
         are wide, so padding past the budget is paid in gathered keys,
@@ -1243,6 +1290,7 @@ class ModelRunner:
         valid = np.zeros((1, bucket), bool)
         valid[0, :t] = True
         self._count_prefill([t], 1, bucket)
+        self._count_latent_prefill([kv_len_after])
         temp, top_p, top_k, seed = sampling
         args = [
             jnp.asarray(tok), jnp.asarray(pos),
@@ -1348,6 +1396,7 @@ class ModelRunner:
             temp[i], top_p[i], top_k[i], seeds[i] = sampling
             lora_rows[i] = lidx
         self._count_prefill([len(r[0]) for r in rows], b, bucket)
+        self._count_latent_prefill([r[3] for r in rows])
         args = [
             jnp.asarray(tok), jnp.asarray(pos),
             jnp.asarray(tables), jnp.asarray(kv_lens), jnp.asarray(valid),
@@ -1410,6 +1459,7 @@ class ModelRunner:
         it overrides want_logprobs (the scheduler derives logprob data on
         host from the raw rows in that mode)."""
         self.decode_steps += 1
+        self._count_latent_decode(kv_lens, active, 1)
         if steps is None:
             steps = np.zeros(len(tokens), np.int32)
         args = [
@@ -1557,6 +1607,11 @@ class ModelRunner:
                 f"{self.model_config.name} keeps two page groups; the full "
                 "group's pages alone cannot be transferred, offloaded or "
                 "parked (what lay behind the window is freed)")
+        if self._latent:
+            raise RuntimeError(
+                f"{self.model_config.name} keeps a single-stack latent "
+                "pool; its pages cannot be transferred, offloaded or "
+                "parked (the bundles are K and V per kv head)")
         if self.model_config.has_recurrent_state:
             # pages without the state that produced them resume nothing
             raise RuntimeError(
@@ -1781,8 +1836,8 @@ class ModelRunner:
         while rows <= limit:
             for bucket in buckets:
                 n = min(bucket, self.config.max_context - 1)
-                if self._windowed and not self.prefill_launch_fits(
-                        [n] * rows):
+                if (self.bounds_prefill_launches
+                        and not self.prefill_launch_fits([n] * rows)):
                     continue  # the scheduler never makes this launch
                 row = (np.zeros(n, np.int32), 0, np.zeros(p, np.int32), n,
                        greedy, 0, *tail)

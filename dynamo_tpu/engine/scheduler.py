@@ -1449,16 +1449,20 @@ class InferenceScheduler:
 
     def _launch_takes(self, work: list, seq: _Seq, chunk: int) -> bool:
         """Whether this prefill launch can hold one more row. Any, but
-        for a model with window layers: the launch's rows x bucket stay
-        inside the token budget, and the window group's pages for the
+        where the runner bounds its launches (window or latent layers):
+        the launch's rows x bucket stay inside the token budget; and for
+        a model with window layers the window group's pages for the
         chunk are taken here: the blocks before the oldest position the
         chunk's first query sees go back, those up to its last position
         are allocated. A row that gets none waits for a later launch."""
-        if not self._windowed:
+        if not getattr(self.runner, "bounds_prefill_launches",
+                       self._windowed):
             return True
         if not self.runner.prefill_launch_fits(
                 [c for _, c in work] + [chunk]):
             return False
+        if not self._windowed:
+            return True
         pos = seq.prefill_pos
         return self.win_pool.advance(
             seq.window, max(0, pos - self.win_pool.window + 1),

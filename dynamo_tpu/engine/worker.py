@@ -181,14 +181,20 @@ def recurrent_state_refusals(model_config, *, mode: str = "aggregated",
     reuses KV pages, or shards the step, and none of them carries the
     per-slot state that the pages are useless without. A hybrid stack
     with window layers is refused the same paths for its second page
-    group's sake (`window_layer_refusals`)."""
+    group's sake (`window_layer_refusals`), one with latent attention
+    for its single-stack pool's (`latent_layer_refusals`)."""
     cfg = model_config
     if not cfg.is_hybrid:
         return
-    from ..models.hybrid import hybrid_refusals, window_layer_refusals
+    from ..models.hybrid import (
+        hybrid_refusals,
+        latent_layer_refusals,
+        window_layer_refusals,
+    )
 
     hybrid_refusals(cfg, weight_dtype, kv_dtype, devices)
     window_layer_refusals(cfg, mode=mode, kvbm=kvbm, spec=spec)
+    latent_layer_refusals(cfg, mode=mode, kvbm=kvbm, spec=spec)
     if not cfg.has_recurrent_state:
         return
     what = f"{cfg.name} (layers {cfg.layer_pattern})"
@@ -1549,7 +1555,7 @@ class TpuWorker:
         launched over, page-time reserved and per-chip device memory
         (docs/metrics.md: dynamo_engine_tokens, dynamo_engine_launches,
         dynamo_engine_positions, dynamo_prefill_row_blocks_total,
-        dynamo_kv_reserved_page_ms, dynamo_kv_window_*,
+        dynamo_kv_reserved_page_ms, dynamo_kv_window_*, dynamo_latent_*,
         dynamo_device_hbm_bytes)."""
         from ..runtime.metrics import (
             DEVICE_HBM_BYTES,
@@ -1563,6 +1569,8 @@ class TpuWorker:
             KV_WINDOW_EDGE_TOKENS,
             KV_WINDOW_PAGES_FREED,
             KV_WINDOW_RESERVED_PAGE_MS,
+            LATENT_DECODE_TOKENS,
+            LATENT_PREFILL_EXPAND_TOKENS,
             MOE_DROPPED_SLOTS,
             MOE_EXPERT_CALLS,
             MOE_EXPERT_TOKENS,
@@ -1607,6 +1615,11 @@ class TpuWorker:
                         win_pool.edge_tokens[phase])
         if stats.state_slot_ms:  # only a model with recurrent state
             SSM_STATE_SLOT_MS.labels(worker=worker).set(stats.state_slot_ms)
+        expanded = getattr(self.runner, "latent_prefill_expand_tokens", 0)
+        if expanded:  # only a model with latent attention
+            LATENT_DECODE_TOKENS.labels(worker=worker).set(
+                self.runner.latent_decode_tokens)
+            LATENT_PREFILL_EXPAND_TOKENS.labels(worker=worker).set(expanded)
         if stats.moe_counts is not None:
             from .model_runner import MOE_PHASES
 

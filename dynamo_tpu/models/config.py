@@ -82,18 +82,28 @@ class ModelConfig:
     # dimensions (its default; gpt-oss states truncate=False)
     rope_yarn_truncate: bool = False
     # Hybrid stacks: a layer is ONE mixer, its kind read off
-    # `layer_pattern` ("M" Mamba-2, "E" routed experts, "*" full
-    # attention, "W" attention over the last `sliding_window` positions;
-    # "" = every layer is attention + MLP). A pre-norm block of a
-    # published model is `mixers_per_layer` of them (attention, then
-    # experts: 2). Rope is a kind's: the default table on "W", YaRN on
-    # "*" where `rope_yarn_factor` is set. Only attention layers have KV
-    # pages, and each kind has a page group of its own (`kv_layers` the
-    # full group, `window_kv_layers` the window group: a cache layer
-    # index counts within its group); "M" layers keep a fixed-size state
-    # per scheduler slot (models/hybrid.py).
+    # `layer_pattern`, six kinds ("M" Mamba-2, "E" routed experts, "D" a
+    # dense SwiGLU `mlp_hidden` wide, "*" full attention, "W" attention
+    # over the last `sliding_window` positions, "L" latent attention
+    # (the `mla_*` sizes: one row of `mla_kv_lora_rank` latent +
+    # `mla_rope_head_dim` rope-key values a cached token, shared by all
+    # heads); "" = every layer is attention + MLP). A pre-norm block of
+    # a published model is `mixers_per_layer` of them (attention, then
+    # experts: 2). Rope is a kind's: the default table on "W" and "L",
+    # YaRN on "*" where `rope_yarn_factor` is set. Only attention layers
+    # have KV pages, and each kind has a page group of its own
+    # (`kv_layers` the full group, "*" or "L"; `window_kv_layers` the
+    # window group: a cache layer index counts within its group); "M"
+    # layers keep a fixed-size state per scheduler slot
+    # (models/hybrid.py).
     layer_pattern: str = ""
     mixers_per_layer: int = 1
+    # `layer_pattern` stacks: a mixer's OUTPUT is normed too before it
+    # joins the residual stream (x <- x + RMSNorm(Mixer(RMSNorm(x))))
+    sandwich_norm: bool = False
+    # sigmoid routers: the learned per-expert selection bias (False: the
+    # top-k is taken on the raw scores)
+    moe_selection_bias: bool = True
     use_rope: bool = True  # nemotron_h attention has no positional term
     mlp_act: str = "swiglu"  # swiglu | relu2 (non-gated: down(relu(up x)^2))
     shared_expert_hidden: int = 0  # 0 = n_shared_experts * expert width
@@ -138,7 +148,7 @@ class ModelConfig:
         """Model layer index of each layer of the paged KV cache's FULL
         group (a sequence holds a page for every 16 positions)."""
         return tuple(i for i in range(self.n_layers)
-                     if self.layer_kind(i) == "*")
+                     if self.layer_kind(i) in "*L")
 
     @property
     def window_kv_layers(self) -> tuple[int, ...]:
@@ -147,6 +157,13 @@ class ModelConfig:
         back to its pool while the sequence lives (engine/pages.py)."""
         return tuple(i for i in range(self.n_layers)
                      if self.layer_kind(i) == "W")
+
+    @property
+    def has_latent_layers(self) -> bool:
+        """Latent attention in a `layer_pattern` stack: the full group's
+        pool is ONE stack of `kv_cache_head_dim`-wide rows
+        (models/hybrid.py `latent_prefill`, `latent_decode`)."""
+        return "L" in self.layer_pattern
 
     @property
     def state_layers(self) -> tuple[int, ...]:
@@ -221,7 +238,15 @@ class ModelConfig:
         latent + shared rope key instead of per-head K/V — the memory win
         that lets DeepSeek-class models hold long contexts."""
         if self.is_mla:
-            return self.mla_kv_lora_rank + self.mla_rope_head_dim
+            width = self.mla_kv_lora_rank + self.mla_rope_head_dim
+            # A `layer_pattern` stack's decode kernel streams whole rows:
+            # padded to 128 lanes (576 -> 640), the pool keeps the
+            # row-major layout Mosaic reads in place. Unpadded, the TPU
+            # lays [.., pages, 16, 1, 576] out pages-innermost (whichever
+            # minor dimension pads least) and a kernel operand would be
+            # a copy of the pool a layer a step (PERF.md, PR 38).
+            return -(-width // 128) * 128 if self.has_latent_layers \
+                else width
         return self.head_dim
 
 
@@ -393,6 +418,43 @@ PRESETS: dict[str, ModelConfig] = {
         mamba_heads=4, mamba_head_dim=16, ssm_groups=2, ssm_state=32,
         ssm_chunk=16,
     ),
+    # openPangu-Ultra-MoE-718B (config.json, model_type pangu_ultra_moe)
+    # at its published sizes: 61 sandwich-normed blocks as 122 mixers,
+    # latent attention (q rank 1536, kv rank 512, 128 heads of 128 + 64
+    # rope lanes, values 128) then a dense SwiGLU 18,432 wide three
+    # times, then latent attention and 256 routed experts 2,048 wide
+    # (top-8 of sigmoid scores, no selection bias, renormalised, x 2.5)
+    # with one shared expert 58 times. No YaRN; the next-token
+    # prediction module is not held. A worker serves a cut of it by
+    # flags (`cut_config`: one leading dense block, then expert blocks).
+    "openpangu-ultra-moe-718b": ModelConfig(
+        name="openpangu-ultra-moe-718b", vocab_size=153600, hidden=7680,
+        n_layers=122, layer_pattern="LD" * 3 + "LE" * 58,
+        mixers_per_layer=2, sandwich_norm=True,
+        n_q_heads=128, n_kv_heads=128, head_dim=192, mlp_hidden=18432,
+        rope_theta=25.6e6, rms_eps=1e-5, tie_embeddings=False,
+        max_context=131072,
+        n_experts=256, n_experts_active=8, expert_mlp_hidden=2048,
+        n_shared_experts=1, moe_norm_topk=True, moe_routed_scale=2.5,
+        moe_scoring="sigmoid", moe_selection_bias=False,
+        mla_kv_lora_rank=512, mla_q_lora_rank=1536, mla_rope_head_dim=64,
+        mla_nope_head_dim=128, mla_v_head_dim=128,
+    ),
+    # CPU sibling, as the benchmark's cell cuts the model: one dense
+    # block, then four expert blocks; 8 experts top-2, a shared expert
+    "tiny-pangu-test": ModelConfig(
+        name="tiny-pangu-test", vocab_size=512, hidden=64, n_layers=10,
+        layer_pattern="LD" + "LE" * 4, mixers_per_layer=2,
+        sandwich_norm=True,
+        n_q_heads=4, n_kv_heads=4, head_dim=24, mlp_hidden=128,
+        rope_theta=25.6e6, rms_eps=1e-5, tie_embeddings=False,
+        max_context=1024,
+        n_experts=8, n_experts_active=2, expert_mlp_hidden=48,
+        n_shared_experts=1, moe_norm_topk=True, moe_routed_scale=2.5,
+        moe_scoring="sigmoid", moe_selection_bias=False,
+        mla_kv_lora_rank=32, mla_q_lora_rank=24, mla_rope_head_dim=8,
+        mla_nope_head_dim=16, mla_v_head_dim=16,
+    ),
     "tiny-mla-test": ModelConfig(
         name="tiny-mla-test", vocab_size=512, hidden=64, n_layers=2,
         n_q_heads=4, n_kv_heads=4, head_dim=24, mlp_hidden=128,
@@ -408,7 +470,10 @@ def cut_config(config: ModelConfig, layers: Optional[int] = None,
     """The share of `config` one chip of a stated deployment serves: the
     leading `layers`, the experts `lo:hi` of the published count, the
     leading `vocab_rows` of the vocabulary (embedding, head, logits and
-    sampling are over the slice). No width changes."""
+    sampling are over the slice). No width changes. Leading dense blocks
+    (a "D" mixer) count ONCE: the first, then the blocks behind the last
+    of them, so that a cut of a few blocks holds the expert blocks the
+    model is made of."""
     changes: dict = {}
     if layers is not None:
         per = config.mixers_per_layer
@@ -417,7 +482,16 @@ def cut_config(config: ModelConfig, layers: Optional[int] = None,
                              f"{config.n_layers // per} layers")
         changes["n_layers"] = layers * per
         if config.layer_pattern:
-            changes["layer_pattern"] = config.layer_pattern[:layers * per]
+            pattern = config.layer_pattern
+            dense = (pattern.rindex("D") // per + 1) if "D" in pattern else 0
+            if dense > 1:
+                pattern = pattern[:per] + pattern[dense * per:]
+                if layers * per > len(pattern):
+                    raise ValueError(
+                        f"--serve-layers {layers}: {config.name} has one "
+                        f"dense block to serve and {len(pattern) // per - 1}"
+                        " expert blocks behind it")
+            changes["layer_pattern"] = pattern[:layers * per]
     if experts is not None:
         try:
             lo, hi = (int(part) for part in experts.split(":"))

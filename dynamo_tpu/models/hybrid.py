@@ -1,9 +1,12 @@
 """Hybrid decoder: ONE mixer a layer, its kind read off
 `ModelConfig.layer_pattern` (nemotron_h's Mamba-2 / attention / relu2
 experts; mellum's window and full attention over SwiGLU experts, a
-published block being two mixers).
+published block being two mixers; pangu_ultra_moe's latent attention
+over a dense SwiGLU or routed experts with a shared one, every branch
+normed again before it is added).
 
     x <- x + Mixer_l(RMSNorm(x; w_l))          after the last: RMSNorm, head
+    x <- x + RMSNorm(Mixer_l(RMSNorm(x; w_l)); post_l)    with `sandwich_norm`
 
   M  Mamba-2: [z | xBC | dt] = x W_in; xBC <- silu(conv1d_k4(xBC) + b);
      xBC -> x [H, P], B [G, N], C [G, N]; dt <- softplus(dt + dt_bias);
@@ -13,11 +16,22 @@ published block being two mixers).
      (YaRN where `rope_yarn_factor`), none for nemotron_h
   W  the same over the last `sliding_window` positions, default rope:
      scores masked to q_pos - window < kv_pos <= q_pos
-  E  routed experts: sigmoid scores, top-k of scores + bias, weights = the
-     unbiased scores renormalised x scale (or a float32 softmax's top-k,
-     renormalised); expert = W_down relu(W_up x)^2, or with `mlp_act`
-     swiglu W_down (silu(W_gate x) * W_up x) from one fused [gate | up]
-     matrix; a shared expert of the same form where the model has one
+  E  routed experts: sigmoid scores, top-k of scores + bias (of the raw
+     scores without `moe_selection_bias`), weights = the unbiased scores
+     renormalised x scale (or a float32 softmax's top-k, renormalised);
+     expert = W_down relu(W_up x)^2, or with `mlp_act` swiglu
+     W_down (silu(W_gate x) * W_up x) from one fused [gate | up] matrix;
+     a shared expert of the same form where the model has one
+  D  a dense SwiGLU `mlp_hidden` wide, one fused [gate | up] matrix
+  L  latent attention: c_q = RMSNorm(x W_dq), q = c_q W_uq -> heads x
+     (nope | rope), q_rope roped; c_kv = RMSNorm(x W_dkv), k_r =
+     RoPE(x W_kr), ONE rope key a token for all heads. A token's cache
+     row is [c_kv | k_r | zeros to a lane tile]. Prefill does not
+     absorb: k_h = [c_kv W_uk,h | k_r], v_h = c_kv W_uv,h are rebuilt
+     from the cached rows a block of keys at a time (`latent_prefill`).
+     Decode absorbs: q~_h = q_nope,h W_uk,h^T, scores q~_h . c_kv +
+     q_rope,h . k_r over the rows themselves, context sum_s p_s c_kv,s,
+     then W_uv,h (`latent_decode`). Softmax scale 1/sqrt(nope + rope).
 
 Kinds of cache side by side: KV pages for the attention layers only, a
 page group each kind (`*`: cache layer j = the j-th `*` layer of the full
@@ -66,6 +80,7 @@ from .transformer import (
     rms_norm,
     write_kv_pages,
     write_kv_stack,
+    write_latent_pages,
     yarn_rope_tables,
 )
 
@@ -88,10 +103,14 @@ def hybrid_refusals(config: ModelConfig, weight_dtype: str = "model",
     process starts, `ModelRunner` when it is built)."""
     what = f"{config.name} (layers {config.layer_pattern})"
     if weight_dtype != "model":
+        have = " and ".join(
+            name for kind, name in (("M", "Mamba-2"), ("E", "expert"),
+                                    ("L", "latent-attention"))
+            if kind in config.layer_pattern)
         raise ValueError(
-            f"--weight-dtype {weight_dtype}: models/quantize.py packs dense "
-            f"projections only; {what} has Mamba-2 and expert matrices it "
-            "has no layout for")
+            f"--weight-dtype {weight_dtype}: models/quantize.py packs the "
+            f"dense decoder's projections only; {what} has {have} matrices "
+            "it has no layout for")
     if kv_dtype != "model":
         raise ValueError(
             f"--kv-dtype {kv_dtype}: the int8 pool is not wired into the "
@@ -134,6 +153,34 @@ def window_layer_refusals(config: ModelConfig, *, mode: str = "aggregated",
             f"{what} has no multi-position decode path")
 
 
+def latent_layer_refusals(config: ModelConfig, *, mode: str = "aggregated",
+                          kvbm: bool = False, spec: bool = False) -> None:
+    """What a `layer_pattern` model with latent attention is refused, by
+    flag and reason. Its pool is one stack of latent rows: the step
+    programs here read and write it, and nothing that moves pages
+    between workers or tiers, or scores several positions a step, has
+    been built or tested over it."""
+    if not config.has_latent_layers:
+        return
+    what = (f"{config.name} (layers {config.layer_pattern}: a single-stack "
+            f"latent pool, {config.kv_cache_head_dim} values a token)")
+    if mode != "aggregated":
+        raise ValueError(
+            f"--mode {mode}: disaggregated prefill/decode hands over pages "
+            "as K and V bundles (engine/ici_transfer.py, "
+            f"llm/kv_transfer.py); no hand-over of {what} is tested")
+    if kvbm:
+        raise ValueError(
+            f"--kvbm-host-blocks/--kvbm-disk-blocks: KVBM's block layout "
+            f"(ops/block_copy.py) is K and V per kv head; no tier has "
+            f"held a page of {what}")
+    if spec:
+        raise ValueError(
+            f"DYNT_SPEC_ENABLE: speculative verification scores k+1 "
+            f"positions in one step (models/transformer.forward_spec); "
+            f"the absorbed decode path of {what} scores one")
+
+
 # ---------------------------------------------------------------------------
 # parameters
 # ---------------------------------------------------------------------------
@@ -149,19 +196,35 @@ def hybrid_layer_axes(config: ModelConfig, layer_idx: int) -> dict:
                 "conv_w": (None, None), "conv_b": (None,),
                 "dt_bias": (None,), "a_log": (None,), "d_skip": (None,),
                 "ssm_norm": (None,), "out_proj": (None, "embed")}
+    post = {"post_norm": ("embed",)} if config.sandwich_norm else {}
     if kind in "*W":
         return {"norm": ("embed",),
                 "wq": ("embed", "q_heads", "head_dim"),
                 "wk": ("embed", "kv_heads", "head_dim"),
                 "wv": ("embed", "kv_heads", "head_dim"),
-                "wo": ("q_heads", "head_dim", "embed")}
+                "wo": ("q_heads", "head_dim", "embed"), **post}
+    if kind == "L":  # replicated: the worker refuses --tp for this family
+        return {"norm": ("embed",), "w_dq": ("embed", None),
+                "q_norm": (None,), "w_uq": (None, None),
+                "w_dkv": ("embed", None), "w_kr": ("embed", None),
+                "kv_norm": (None,), "w_uk": (None, None, None),
+                "w_uv": (None, None, None), "wo": (None, None, "embed"),
+                **post}
+    if kind == "D":
+        return {"norm": ("embed",), "d_up": ("embed", None),
+                "d_down": (None, "embed"), **post}
     axes = {"norm": ("embed",), "router": ("embed", None),
-            "e_up": (None, None, "embed"), "e_down": (None, None, "embed")}
-    if config.moe_scoring == "sigmoid":
+            "e_up": (None, None, "embed"), "e_down": (None, None, "embed"),
+            **post}
+    if _has_selection_bias(config):
         axes["e_bias"] = (None,)
     if _shared_width(config):
         axes.update({"s_up": ("embed", None), "s_down": (None, "embed")})
     return axes
+
+
+def _has_selection_bias(config: ModelConfig) -> bool:
+    return config.moe_scoring == "sigmoid" and config.moe_selection_bias
 
 
 def _shared_width(config: ModelConfig) -> int:
@@ -191,7 +254,20 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
     from fold_in(ks[11], e), stored as one [2m, h] matrix, gate rows
     first; a softmax router has no selection bias and a model without a
     shared expert no s_up / s_down (no draw is made for either, and the
-    other keys are unmoved)."""
+    other keys are unmoved).
+
+    A latent-attention layer draws W_dq, W_uq, W_dkv, W_o (centred),
+    W_kr, W_uk, W_uv from keys 0..6, each normal / sqrt(its fan_in): the
+    rank for W_uq, W_uk and W_uv, whose inputs are normed to unit RMS.
+    A dense SwiGLU draws gate, up, down (centred) from keys 0..2 and
+    stores [gate | up] as one matrix. A SwiGLU shared expert draws its
+    gate from key 12, its up from key 14 (key 12 alone is relu2's up),
+    stored [gate | up]. With `sandwich_norm` every mixer has a second
+    gain, ones. Why nothing more is added for that block: the branch is
+    normed before it is added, so no matrix's scale reaches the residual
+    stream, each block adds two unit-RMS branches, and the final norm
+    and a head of spread 1 give logits of spread 1 as in the other
+    recipes."""
     dtype = jnp.dtype(config.dtype)
     h = config.hidden
     ks = jax.random.split(k, 15)
@@ -204,7 +280,39 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
 
     kind = config.layer_kind(layer_idx)
     p = {"norm": jnp.ones((h,), dtype)}
-    if kind == "M":
+    if config.sandwich_norm:
+        p["post_norm"] = jnp.ones((h,), dtype)
+    if kind == "L":
+        qh, rank, q_rank = (config.n_q_heads, config.mla_kv_lora_rank,
+                            config.mla_q_lora_rank)
+        nope, rd, vd = (config.mla_nope_head_dim, config.mla_rope_head_dim,
+                        config.mla_v_head_dim)
+        p.update({
+            "w_dq": dense(ks[0], (h, q_rank), h),
+            "q_norm": jnp.ones((q_rank,), dtype),
+            # drawn as [rank, heads, d], stored as the matmuls read them:
+            # W_uq one [q_rank, heads x (nope + rope)] matrix, W_uk and
+            # W_uv head-major, the batch dimension of the absorbed decode
+            # step's products
+            "w_uq": dense(ks[1], (q_rank, qh, nope + rd),
+                          q_rank).reshape(q_rank, -1),
+            "w_dkv": dense(ks[2], (h, rank), h),
+            "kv_norm": jnp.ones((rank,), dtype),
+            "wo": dense(ks[3], (qh, vd, h), qh * vd, (0, 1)),
+            "w_kr": dense(ks[4], (h, rd), h),
+            "w_uk": dense(ks[5], (rank, qh, nope),
+                          rank).transpose(1, 2, 0),  # [heads, nope, rank]
+            "w_uv": dense(ks[6], (rank, qh, vd),
+                          rank).transpose(1, 0, 2),  # [heads, rank, v]
+        })
+    elif kind == "D":
+        m = config.mlp_hidden
+        p.update({
+            "d_up": jnp.concatenate([dense(ks[0], (h, m), h),
+                                     dense(ks[1], (h, m), h)], axis=1),
+            "d_down": dense(ks[2], (m, h), m, 0),
+        })
+    elif kind == "M":
         nh, inner = config.mamba_heads, config.mamba_inner
         conv_dim, kw = config.mamba_conv_dim, config.conv_kernel
         u = jax.random.uniform(ks[3], (nh,), jnp.float32)
@@ -251,12 +359,15 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
         })
         if config.mlp_act == "swiglu":  # [E, gate | up, h]
             p["e_up"] = jnp.concatenate([p["e_up"], up_of(ks[11])], axis=1)
-        if config.moe_scoring == "sigmoid":
+        if _has_selection_bias(config):
             p["e_bias"] = 0.02 * jax.random.normal(
                 ks[8], (config.n_experts,), jnp.float32)
         if sm:
             p["s_up"] = dense(ks[12], (h, sm), h)
             p["s_down"] = dense(ks[13], (sm, h), sm, 0)
+            if config.mlp_act == "swiglu":  # [gate | up]
+                p["s_up"] = jnp.concatenate(
+                    [p["s_up"], dense(ks[14], (h, sm), h)], axis=1)
     return p
 
 
@@ -388,7 +499,9 @@ def rope_tables(config: ModelConfig, kind: str):
         return None
     if kind == "*" and config.rope_yarn_factor:
         return yarn_rope_tables(config)
-    half = config.head_dim // 2
+    # a latent layer ropes its `mla_rope_head_dim` lanes alone
+    half = (config.mla_rope_head_dim if kind == "L"
+            else config.head_dim) // 2
     return (jnp.exp(-math.log(config.rope_theta)
                     * jnp.arange(0, half, dtype=jnp.float32) / half), 1.0)
 
@@ -418,7 +531,192 @@ def _qkv(h, lp, config: ModelConfig, kind: str, positions):
                                                         tables), v
 
 
-ATTENTION_SCOPES = {"*": "attn_full", "W": "attn_window"}
+ATTENTION_SCOPES = {"*": "attn_full", "W": "attn_window", "L": "attn_latent"}
+
+# Keys a step of a latent layer's prefill attention: their K and V are
+# rebuilt from the cached rows and scored against every query of the
+# launch (at most the 2,048 positions `prefill_launch_fits` allows), so
+# the float32 scores [rows, heads, T, keys] are 134 MB at 128 heads.
+LATENT_KEY_BLOCK = 128
+
+
+def _latent_sizes(config: ModelConfig):
+    """(rank, rope lanes, softmax scale) of a latent layer."""
+    return (config.mla_kv_lora_rank, config.mla_rope_head_dim,
+            1.0 / math.sqrt(config.mla_qk_head_dim))
+
+
+def _pad_lanes(x, width: int):
+    """x [..., n] -> [..., width], zeros behind."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def _latent_projections(h, lp, config: ModelConfig, positions):
+    """h [B, T, hidden] (normed) -> q_nope [B, T, heads, nope], q_rope
+    [B, T, heads, rope] (roped) and the tokens' cache rows [B, T, width]:
+    [RMSNorm(c_kv) | RoPE(k_r) | zeros]."""
+    nope = config.mla_nope_head_dim
+    tables = rope_tables(config, "L")
+    c_q = rms_norm(jnp.einsum("bth,hr->btr", h, lp["w_dq"]), lp["q_norm"],
+                   config.rms_eps)
+    q = jnp.einsum("btr,rm->btm", c_q, lp["w_uq"])
+    q = q.reshape(*q.shape[:2], config.n_q_heads, -1)
+    q_rope = apply_rope(q[..., nope:], positions, tables)
+    c_kv = rms_norm(jnp.einsum("bth,hr->btr", h, lp["w_dkv"]),
+                    lp["kv_norm"], config.rms_eps)
+    k_r = apply_rope(jnp.einsum("bth,hr->btr", h, lp["w_kr"])[:, :, None],
+                     positions, tables)[:, :, 0]
+    row = _pad_lanes(jnp.concatenate([c_kv, k_r], axis=-1),
+                     config.kv_cache_head_dim)
+    return q[..., :nope], q_rope, row
+
+
+def latent_prefill_attention(q_nope, q_rope, kv_cache, layer, block_tables,
+                             positions, kv_lens, w_uk, w_uv,
+                             config: ModelConfig):
+    """Causal attention of a launch's queries over the rows' cached
+    latents WITHOUT absorbing: a block of LATENT_KEY_BLOCK keys at a
+    time (a loop as long as the longest row's context needs), the
+    block's rows are gathered straight from the pool, expanded to
+    k_h = [c_kv W_uk,h | k_r] and v_h = c_kv W_uv,h, scored 128 + 64
+    lanes wide and folded into a running softmax. No array over all
+    keys exists. Returns [B, T, heads, v] in q's dtype."""
+    b, t, nh, _ = q_nope.shape
+    rank, rd, scale = _latent_sizes(config)
+    ps = kv_cache.shape[3]
+    width = block_tables.shape[1]
+    pages = min(width, max(1, LATENT_KEY_BLOCK // ps))
+    if width % pages:  # the scratch page, masked by the lengths
+        block_tables = jnp.pad(block_tables,
+                               ((0, 0), (0, pages - width % pages)))
+    keys = pages * ps
+    seen = jnp.minimum(kv_lens, jnp.max(positions, axis=1) + 1)
+    n_blocks = (jnp.max(seen) + keys - 1) // keys
+
+    def body(i, carry):
+        m, l, acc = carry
+        tables = jax.lax.dynamic_slice_in_dim(block_tables, i * pages,
+                                              pages, axis=1)
+        rows = kv_cache[layer, 0, tables].reshape(b, keys, -1)
+        c_kv, k_r = rows[..., :rank], rows[..., rank:rank + rd]
+        k = jnp.einsum("bsr,hnr->bshn", c_kv, w_uk)
+        v = jnp.einsum("bsr,hrv->bshv", c_kv, w_uv)
+        s = (jnp.einsum("bthn,bshn->bhts", q_nope, k,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bthr,bsr->bhts", q_rope, k_r,
+                          preferred_element_type=jnp.float32)) * scale
+        kv_pos = i * keys + jnp.arange(keys)
+        mask = ((kv_pos[None, None, :] <= positions[:, :, None])
+                & (kv_pos[None, None, :] < kv_lens[:, None, None]))
+        # block 0 holds key 0, which every query sees: m is finite from
+        # the first step on, and a block wholly masked adds exp(-1e30 - m)
+        s = jnp.where(mask[:, None], s, -1e30)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhts,bshv->bhtv", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    vd = w_uv.shape[-1]
+    m, l, acc = jax.lax.fori_loop(
+        0, n_blocks, body,
+        (jnp.full((b, nh, t), -1e30, jnp.float32),
+         jnp.zeros((b, nh, t), jnp.float32),
+         jnp.zeros((b, nh, t, vd), jnp.float32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return jnp.moveaxis(out, 1, 2).astype(q_nope.dtype)
+
+
+def latent_prefill(h, lp, config: ModelConfig, kv_cache, layer,
+                   block_tables, positions, kv_lens, valid):
+    """A latent layer over a prefill chunk a row: the chunk's rows go
+    into the pool first, then attention reads the pool. Returns
+    (kv_cache, out [B, T, hidden])."""
+    with jax.named_scope(ATTENTION_SCOPES["L"]):
+        q_nope, q_rope, row = _latent_projections(h, lp, config, positions)
+        kv_cache = write_latent_pages(kv_cache, layer, row, block_tables,
+                                      positions, valid)
+        attn = latent_prefill_attention(
+            q_nope, q_rope, kv_cache, layer, block_tables, positions,
+            kv_lens, lp["w_uk"], lp["w_uv"], config)
+        return kv_cache, jnp.einsum("btqv,qvh->bth", attn, lp["wo"])
+
+
+def paged_attention_decode_latent_xla(q, kv_cache, layer, block_tables,
+                                      kv_lens, row_cur, *, rank: int,
+                                      sm_scale: float):
+    """The oracle of `ops/paged_attention.paged_attention_decode_latent`
+    (same signature): gathers the whole table. q [B, heads, width],
+    row_cur [B, width] -> context [B, heads, rank] float32."""
+    b = q.shape[0]
+    rows = kv_cache[layer, 0][block_tables]
+    rows = rows.reshape(b, -1, rows.shape[-1]).astype(jnp.float32)
+    q32, cur = q.astype(jnp.float32), row_cur.astype(jnp.float32)
+    s = jnp.einsum("bhw,bsw->bhs", q32, rows) * sm_scale
+    hist = jnp.arange(rows.shape[1])[None, :] < (kv_lens[:, None] - 1)
+    s = jnp.where(hist[:, None, :], s, -1e30)
+    s_cur = jnp.einsum("bhw,bw->bh", q32, cur) * sm_scale
+    probs = jax.nn.softmax(
+        jnp.concatenate([s, s_cur[..., None]], axis=-1), axis=-1)
+    return (jnp.einsum("bhs,bsr->bhr", probs[..., :-1], rows[..., :rank])
+            + probs[..., -1:] * cur[:, None, :rank])
+
+
+def latent_decode(h, lp, config: ModelConfig, kv_cache, layer, block_tables,
+                  positions, attn_lens, attn_fn):
+    """One token a slot in the absorbed form: h [S, hidden] (normed) ->
+    (out [S, hidden], the tokens' cache rows [S, width], written by the
+    caller in one scatter for all layers)."""
+    with jax.named_scope(ATTENTION_SCOPES["L"]):
+        rank, _, scale = _latent_sizes(config)
+        q_nope, q_rope, row = _latent_projections(
+            h[:, None], lp, config, positions[:, None])
+        q_abs = jnp.einsum("bhn,hnr->bhr", q_nope[:, 0], lp["w_uk"])
+        q = _pad_lanes(jnp.concatenate([q_abs, q_rope[:, 0]], axis=-1),
+                       row.shape[-1])
+        ctx = attn_fn(q, kv_cache, layer, block_tables, attn_lens, row[:, 0],
+                      rank=rank, sm_scale=scale)
+        out = jnp.einsum("bhr,hrv->bhv", ctx.astype(h.dtype), lp["w_uv"])
+        return jnp.einsum("bhv,hvd->bd", out, lp["wo"]), row[:, 0]
+
+
+def write_latent_stack(kv_cache, rows, block_tables, positions, active):
+    """`write_kv_stack` for a single-stack pool: rows [L, S, width], one
+    token a slot, every latent layer in ONE scatter of whole rows of the
+    pool's flat [L * P * ps, width] view (a bitcast of the row-major
+    array). Indexed on (layer, page, offset) the scatter, and with it
+    the donated pool the decode loop carries, takes a layout with the
+    layer dimension next to the lanes, and every step copies the pool
+    out of and back into the row-major one the kernel reads (3.75 GB at
+    the published sizes: PERF.md, PR 38; `_write_scale_rows` has the
+    same story). An idle slot writes the scratch page; a page outside
+    the pool goes to the row past the end, which `drop` drops."""
+    n_layers, _, n_pages, page_size = kv_cache.shape[:4]
+    width = kv_cache.shape[-1]
+    n_rows = n_layers * n_pages * page_size
+    page = jnp.take_along_axis(
+        block_tables, (positions // page_size)[:, None].astype(jnp.int32),
+        axis=1)[:, 0]
+    page = jnp.where(active, page, 0)
+    row = page * page_size + positions % page_size  # [S], within a layer
+    layer = jnp.arange(n_layers, dtype=jnp.int32)[:, None]
+    at = jnp.where((page >= 0) & (page < n_pages),
+                   layer * (n_pages * page_size) + row, n_rows)
+    flat = kv_cache.reshape(n_rows, width).at[at.reshape(-1)].set(
+        rows.reshape(-1, width), mode="drop")
+    return flat.reshape(kv_cache.shape)
+
+
+def dense_mixer(x, lp):
+    """x [..., h] -> W_down (silu(W_gate x) * W_up x)."""
+    with jax.named_scope("mlp_dense"):
+        return jnp.einsum(
+            "...m,mh->...h",
+            _swiglu(jnp.einsum("...h,hm->...m", x, lp["d_up"])),
+            lp["d_down"])
 
 
 def moe_stats_size(config: ModelConfig) -> int:
@@ -580,9 +878,18 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
                                  win_lens, window=config.sliding_window)
                 out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])
             win_idx += 1
+        elif kind == "L":
+            kv_cache, out = latent_prefill(h, lp, config, kv_cache, kv_idx,
+                                           block_tables, positions, kv_lens,
+                                           valid)
+            kv_idx += 1
+        elif kind == "D":
+            out = dense_mixer(h, lp)
         else:
             out, layer_stats = moe_mixer(h, lp, config, valid, gmm_path)
             stats = stats + layer_stats
+        if config.sandwich_norm:
+            out = rms_norm(out, lp["post_norm"], config.rms_eps)
         x = x + out
     state = {"conv": conv_out, "ssm": ssm_out}
     if not all_logits:
@@ -600,7 +907,9 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
     one scatter a page group for all its attention layers as in
     `forward_decode`. Returns (kv_cache, state, logits [S, 1, vocab], moe
     stats); `window` as in `forward_hybrid`."""
-    attn_fn = decode_attention_fn or paged_attention_decode_xla
+    attn_fn = decode_attention_fn or (
+        paged_attention_decode_latent_xla if config.has_latent_layers
+        else paged_attention_decode_xla)
     attn_lens = jnp.where(active, kv_lens, 0)
     if window is not None:
         win_cache, win_tables, win_pos, win_lens = _window_frame(
@@ -640,13 +949,26 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
             win_ks.append(k)
             win_vs.append(v)
             win_idx += 1
+        elif kind == "L":
+            out, row = latent_decode(h, lp, config, kv_cache, kv_idx,
+                                     block_tables, positions, attn_lens,
+                                     attn_fn)
+            ks.append(row)
+            kv_idx += 1
+        elif kind == "D":
+            out = dense_mixer(h, lp)
         else:
             out, layer_stats = moe_mixer(h[:, None, :], lp, config,
                                          active[:, None], gmm_path)
             out = out[:, 0]
             stats = stats + layer_stats
+        if config.sandwich_norm:
+            out = rms_norm(out, lp["post_norm"], config.rms_eps)
         x = x + out
-    if ks:
+    if config.has_latent_layers:
+        kv_cache = write_latent_stack(kv_cache, jnp.stack(ks), block_tables,
+                                      positions, active)
+    elif ks:
         kv_cache = write_kv_stack(kv_cache, jnp.stack(ks), jnp.stack(vs),
                                   block_tables, positions[:, None],
                                   active[:, None])

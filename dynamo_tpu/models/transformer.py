@@ -531,7 +531,9 @@ def _routing_weights(x: jax.Array, p: dict, config: ModelConfig):
         # unbiased scores at the selected experts.
         b, t, e = logits.shape
         scores = jax.nn.sigmoid(logits)
-        choice = scores + p["e_bias"].astype(jnp.float32)
+        choice = scores  # no selection bias: the raw scores choose
+        if "e_bias" in p:
+            choice = scores + p["e_bias"].astype(jnp.float32)
         g = config.moe_n_group
         if g > 1:
             grouped = choice.reshape(b, t, g, e // g)

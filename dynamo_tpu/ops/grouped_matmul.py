@@ -28,11 +28,19 @@ import jax.numpy as jnp
 # (tm, tk, tn) of the grouped matmul, chosen on the v5e for h=2688 and
 # expert width 1856: a whole contraction and a wide slab of outputs a
 # step, so that a step's DMA (2-3 MB of weights) dwarfs its fixed cost.
+# A wider contraction narrows the slab (h=7680: tn 256, 3.9 MB).
 GMM_ROW_TILE = 128
 
 
+# A step's weight slab [k, tn] is double-buffered in VMEM beside the row
+# tile; past this a contraction of 7680 with tn = 512 asks for 19 MB of
+# the 16 MB a kernel may have (the compiler refuses it).
+GMM_SLAB_BYTES = 4 << 20
+
+
 def _tiling(k: int, n: int) -> tuple[int, int, int]:
-    tn = next((c for c in (512, 384, 256, 128) if n >= c), n)
+    tn = next((c for c in (512, 384, 256, 128)
+               if n >= c and (c == 128 or k * c * 2 <= GMM_SLAB_BYTES)), n)
     return (GMM_ROW_TILE, k, tn)
 
 

@@ -817,6 +817,247 @@ def paged_attention_decode_pool(
     return _combine_current(q, acc, m, l, k_cur, v_cur)
 
 
+# The latent pool's kernel streams chunks of this many tokens (a grid
+# step a chunk a row: 128 rows x a 384-page table are 768 steps at 1024
+# tokens, 1536 at the pool kernel's 512) and scores them in blocks of
+# _LATENT_BLOCK_TOKENS, so that a ragged end copies half a block too
+# many on average and the [heads, block] float32 tile stays 128 KB.
+_LATENT_CHUNK_TOKENS = 1024
+_LATENT_BLOCK_TOKENS = 256
+
+
+def _latent_decode_kernel(
+    # scalar prefetch
+    lengths_ref,  # [B] int32 HISTORY lengths (current token excluded)
+    tables_ref,  # [B * max_pages] int32 flattened block tables
+    layer_ref,  # [1] int32
+    buf_idx_ref,  # [1] int32 (double-buffer slot)
+    init_ref,  # [1] int32 (1 until the first DMA was issued)
+    q_ref,  # [1, heads, width]: absorbed queries [q W_uk^T | q_rope | 0]
+    pool_ref,  # FULL [L, P, ps, width] in HBM (memory_space=ANY)
+    acc_ref,  # [1, heads, rank] f32 unnormalized sum of p x latent
+    m_out_ref, l_out_ref,  # [1, heads, 128] f32
+    kv_buf,  # [2, C, ps, width] page chunks
+    sems,  # DMA semaphores (2,)
+    m_ref, l_ref,  # [heads, 128] f32
+    o_ref,  # [heads, rank] f32
+    *,
+    pages_per_chunk: int,
+    block_pages: int,
+    max_pages: int,
+    batch_size: int,
+    sm_scale: float,
+):
+    """Flash decode over a single-stack LATENT pool (latent attention in
+    its absorbed form): a cached token is ONE row of `width` lanes that
+    every query head shares: `rank` latent values, the rope key behind
+    them, zeros up to a lane tile. All heads score a block of rows in
+    one MXU pass, S = Q K^T [heads, tokens] over all `width` lanes (the
+    padding adds nothing), and the values are the same rows' first
+    `rank` lanes: P K[:, :rank]. So a page is read once for all 128
+    heads, where the pool kernel above reads one K and one V row a kv
+    head. `_pool_decode_kernel` has the why of everything else here: the
+    pool stays in HBM, a row's pages come through its scalar-prefetched
+    table in double-buffered chunks, only blocks that hold history are
+    copied and scored, a row of length 0 costs its grid steps and
+    nothing more, and (acc, m, l) leave unnormalized for the current
+    token's combine."""
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    n_chunks = pl.num_programs(1)
+    ps, width = kv_buf.shape[2:]
+    rank = o_ref.shape[1]
+    bk = pages_per_chunk * ps
+    block_tok = block_pages * ps
+    n_blocks = pages_per_chunk // block_pages
+    layer = layer_ref[0]
+    length = lengths_ref[b]
+
+    def chunk_copies(bi, ci, slot, fn):
+        base = bi * max_pages + ci * pages_per_chunk
+        left = lengths_ref[bi] - ci * bk
+
+        def block(u):
+            for j in range(u * block_pages, (u + 1) * block_pages):
+                fn(pltpu.make_async_copy(
+                    pool_ref.at[layer, tables_ref[base + j]],
+                    kv_buf.at[slot, j], sems.at[slot]))
+
+        block(0)
+        for u in range(1, n_blocks):
+            pl.when(u * block_tok < left)(functools.partial(block, u))
+
+    active = i * bk < length
+
+    @pl.when(jnp.logical_and(active, init_ref[0] == 1))
+    def _first():
+        chunk_copies(b, i, buf_idx_ref[0], lambda c: c.start())
+        init_ref[0] = 0
+
+    @pl.when(i == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(active)
+    def _compute():
+        slot = buf_idx_ref[0]
+        nb, ni = _next_chunk(lengths_ref, b, i, bk=bk, n_chunks=n_chunks,
+                             batch_size=batch_size)
+
+        @pl.when(nb < batch_size)
+        def _prefetch():
+            nslot = jnp.where(slot == 0, 1, 0)
+            chunk_copies(nb, ni, nslot, lambda c: c.start())
+            buf_idx_ref[0] = nslot
+
+        chunk_copies(b, i, slot, lambda c: c.wait())
+        q = q_ref[0]  # [heads, width]
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, block_tok), 1)
+
+        def flash_block(u):
+            rows = kv_buf[slot, pl.ds(u * block_pages, block_pages)]
+            rows = rows.reshape(block_tok, width)
+            s = jax.lax.dot_general(
+                q, rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(col < length - (i * bk + u * block_tok), s,
+                          -jnp.inf)
+            m_prev = m_ref[:, 0:1]
+            l_prev = l_ref[:, 0:1]
+            # finite: the block's first token is live
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(q.dtype), rows[:, :rank], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [heads, rank]
+            o_ref[...] = o_ref[...] * alpha + pv
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+        flash_block(0)
+        for u in range(1, n_blocks):
+            pl.when(i * bk + u * block_tok < length)(
+                functools.partial(flash_block, u))
+
+    @pl.when(i == n_chunks - 1)
+    def _finish():
+        acc_ref[0] = o_ref[...]
+        m_out_ref[0] = m_ref[...]
+        l_out_ref[0] = l_ref[...]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("rank", "sm_scale", "pages_per_chunk",
+                                    "interpret"),
+                   donate_argnums=())  # read-only on the whole pool
+def paged_decode_attention_latent(
+    q: jax.Array,  # [B, heads, width] absorbed queries, padded as the rows
+    kv_pool: jax.Array,  # [L, 1, P, ps, 1, width]: the WHOLE latent cache
+    layer: jax.Array,  # scalar int32
+    block_tables: jax.Array,  # [B, max_pages] int32
+    kv_lens_hist: jax.Array,  # [B] int32 history length (current excluded)
+    *,
+    rank: int,  # leading lanes of a row that are its values
+    sm_scale: float,
+    pages_per_chunk: int | None = None,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Flash partials over the paged history of a latent pool
+    (`_latent_decode_kernel`): (acc [B, heads, rank], m, l [B, heads])
+    float32, unnormalized. A row with history 0 is skipped."""
+    b, heads, width = q.shape
+    n_layers, _, n_pages, ps = kv_pool.shape[:4]
+    max_pages = block_tables.shape[1]
+    ppc = _largest_divisor(
+        max_pages, pages_per_chunk or max(1, _LATENT_CHUNK_TOKENS // ps))
+    block_pages = _largest_divisor(
+        ppc, max(1, _LATENT_BLOCK_TOKENS // ps))
+
+    def q_map(bi, ci, *refs):
+        del ci, refs
+        return (bi, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(b, max_pages // ppc),
+        in_specs=[pl.BlockSpec((1, heads, width), q_map),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec((1, heads, rank), q_map),
+                   pl.BlockSpec((1, heads, 128), q_map),
+                   pl.BlockSpec((1, heads, 128), q_map)],
+        scratch_shapes=[
+            pltpu.VMEM((2, ppc, ps, width), kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((heads, 128), jnp.float32),
+            pltpu.VMEM((heads, 128), jnp.float32),
+            pltpu.VMEM((heads, rank), jnp.float32),
+        ],
+    )
+    acc, m, l = pl.pallas_call(
+        functools.partial(_latent_decode_kernel, pages_per_chunk=ppc,
+                          block_pages=block_pages, max_pages=max_pages,
+                          batch_size=b, sm_scale=sm_scale),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, heads, rank), jnp.float32),
+                   jax.ShapeDtypeStruct((b, heads, 128), jnp.float32),
+                   jax.ShapeDtypeStruct((b, heads, 128), jnp.float32)],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name="paged_decode_attention_latent",
+    )(kv_lens_hist.astype(jnp.int32),
+      block_tables.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32),
+      # the two unit dimensions are the dense pool's (k|v, kv heads): a
+      # row-major bitcast
+      q, kv_pool.reshape(n_layers, n_pages, ps, width))
+    return acc, m[..., 0], l[..., 0]
+
+
+def combine_current_latent(q, acc, m, l, row_cur, rank: int,
+                           sm_scale: float):
+    """Fold the current token's own row (not yet in the pool) into the
+    history's unnormalized partials: q [B, heads, width], row_cur
+    [B, width] -> the normalized context [B, heads, rank] float32."""
+    row = row_cur.astype(jnp.float32)
+    s_cur = jnp.einsum("bhw,bw->bh", q.astype(jnp.float32), row) * sm_scale
+    m_new = jnp.maximum(m, s_cur)
+    alpha = jnp.exp(m - m_new)  # 0 where the history is empty (m = -inf)
+    beta = jnp.exp(s_cur - m_new)
+    out = acc * alpha[..., None] + beta[..., None] * row[:, None, :rank]
+    return out / (l * alpha + beta)[..., None]
+
+
+def paged_attention_decode_latent(
+    q: jax.Array,  # [B, heads, width]
+    kv_cache: jax.Array,  # [L, 1, P, ps, 1, width]
+    layer,
+    block_tables: jax.Array,
+    kv_lens: jax.Array,  # [B] INCLUDING the current token; 0 = inactive
+    row_cur: jax.Array,  # [B, width] the current token's row
+    *,
+    rank: int,
+    sm_scale: float,
+    pages_per_chunk: int | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Deferred-write decode attention over a latent pool: the kernel's
+    partials over the cached history, then the current token. Drop-in
+    for `models/hybrid.paged_attention_decode_latent_xla`; returns the
+    context in latent space [B, heads, rank] float32 (W_uv is the
+    caller's)."""
+    acc, m, l = paged_decode_attention_latent(
+        q, kv_cache, layer, block_tables, jnp.maximum(kv_lens - 1, 0),
+        rank=rank, sm_scale=sm_scale, pages_per_chunk=pages_per_chunk,
+        interpret=interpret)
+    return combine_current_latent(q, acc, m, l, row_cur, rank, sm_scale)
+
+
 def make_paged_attention_decode_pool_tp(mesh, *,
                                         pages_per_chunk: int | None = None,
                                         interpret: bool = False):

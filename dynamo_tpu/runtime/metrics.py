@@ -371,6 +371,25 @@ PREFILL_ROW_BLOCKS = Gauge(
     "and does no work for it)",
     ["worker", "state"], registry=REGISTRY,
 )
+LATENT_DECODE_TOKENS = Gauge(
+    "dynamo_latent_decode_tokens_total",
+    "Model with latent attention: cached positions its decode kernel "
+    "(paged_decode_attention_latent) has been asked to read since start: "
+    "over decode steps, the active rows' history lengths x latent "
+    "layers. Times the bytes of a cached row it is what the kernel's "
+    "roofline reads",
+    ["worker"], registry=REGISTRY,
+)
+LATENT_PREFILL_EXPAND_TOKENS = Gauge(
+    "dynamo_latent_prefill_expand_tokens_total",
+    "Model with latent attention: cached positions whose keys and "
+    "values prefill launches have rebuilt from their latents since "
+    "start (every row's context up to its chunk's end, x latent "
+    "layers). Over the growth of dynamo_engine_tokens{kind=prefill} x "
+    "latent layers it is what chunked prefill that does not absorb "
+    "pays again: 1 for a prompt prefilled in one launch",
+    ["worker"], registry=REGISTRY,
+)
 KV_RESERVED_PAGE_MS = Gauge(
     "dynamo_kv_reserved_page_ms",
     "Sum over committed steps of (pages allocated to sequences that "

@@ -320,3 +320,136 @@ def test_the_dense_decode_programs_copy_no_pool(one_chip, monkeypatch,
     assert "paged_decode_attention_pool" in text and "q4_matmul" in text
     assert _copies(text, scale_bytes) == []
     assert compiled.memory_analysis().temp_size_in_bytes < scale_bytes
+
+
+# openpangu-ultra-moe-718b as the benchmark's cell cuts it (1 dense + 4
+# expert blocks, experts 0:16, 19,200 vocabulary rows; every width as
+# published): 128 rows, 24,576 pages of 16 in ONE latent stack of 640-lane
+# rows, 384-page tables.
+PANGU = {"rows": 128, "pages": 24576, "width": 384}
+
+
+def _pangu_programs(one_chip):
+    """(config, params, caches) as shapes on the described chip."""
+    from dynamo_tpu.models.config import cut_config, get_config
+    from dynamo_tpu.models.hybrid import make_state_cache
+    from dynamo_tpu.models.transformer import init_params, make_kv_cache
+
+    cfg = cut_config(get_config("openpangu-ultra-moe-718b"), 5, "0:16",
+                     19200)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    kv = on_chip(jax.eval_shape(
+        lambda: make_kv_cache(cfg, PANGU["pages"], PAGE)))
+    state = on_chip(jax.eval_shape(
+        lambda: make_state_cache(cfg, PANGU["rows"])))
+    return cfg, params, (kv, state)
+
+
+@pytest.mark.parametrize("program", ["decode-block", "prefill-1x2048"])
+def test_pangus_step_programs_fit_the_chip_and_copy_no_pool(one_chip,
+                                                            program):
+    """The fused 8-step decode block at the widest table and the widest
+    prefill launch at the cell's sizes, as `ModelRunner` builds them,
+    compile for a described v5e: the Mosaic latent kernel is in the
+    decode program, nothing copies or relays out an array of the pool's
+    size (indexed on (layer, page, offset) the stacked latent write gave
+    the donated pool a layout with the layer dimension next to the
+    lanes: a 3.75 GB copy, and the program did not fit), and weights
+    (9.84 GB) + pool (2.52 GB) + the program's temporaries stay under
+    the 15.75 GiB the compiler gives a v5e."""
+    import functools
+
+    from dynamo_tpu.engine.sampler import sample, sample_with_logprobs
+    from dynamo_tpu.models.hybrid import (
+        forward_hybrid,
+        forward_hybrid_decode,
+        moe_stats_size,
+    )
+    from dynamo_tpu.ops.paged_attention import paged_attention_decode_latent
+
+    cfg, params, cache = _pangu_programs(one_chip)
+    n, width = PANGU["rows"], PANGU["width"]
+    pool_bytes = 5 * PANGU["pages"] * PAGE * 640 * 2
+    assert cache[0].shape == (5, 1, PANGU["pages"], PAGE, 1, 640)
+    attention = functools.partial(paged_attention_decode_latent,
+                                  interpret=False)
+
+    def decode(params, cache, tokens, positions, tables, kv_lens, active,
+               temperature, top_p, top_k, seeds, step_idx):
+        def body(carry, _):
+            (kv, state), toks, pos, lens, sidx, acc = carry
+            kv, state, logits, stats = forward_hybrid_decode(
+                params, cfg, toks, pos, kv, state, tables, lens, active,
+                decode_attention_fn=attention, gmm_path="pallas")
+            nxt = sample(logits[:, 0, :], temperature, top_p, top_k, seeds,
+                         sidx)
+            return ((kv, state), nxt, pos + 1, lens + 1, sidx + 1,
+                    acc + stats), nxt
+
+        (cache, *_, acc), toks = jax.lax.scan(
+            body, (cache, tokens, positions, kv_lens, step_idx,
+                   jnp.zeros(moe_stats_size(cfg), jnp.int32)), None, length=8)
+        return cache, toks, acc
+
+    def prefill(params, cache, tokens, positions, tables, kv_lens, valid,
+                last_idx, temperature, top_p, top_k, seeds, slots):
+        kv, state = cache
+        kv, state, last, stats = forward_hybrid(
+            params, cfg, tokens, positions, kv, state, slots, tables,
+            kv_lens, valid, last_idx, gmm_path="pallas")
+        return ((kv, state), *sample_with_logprobs(
+            last, temperature, top_p, top_k, seeds, jnp.int32(0)), stats)
+
+    def vec(rows, dtype):
+        return _shape(one_chip, (rows,), dtype)
+
+    if program == "decode-block":
+        compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+            params, cache, vec(n, jnp.int32), vec(n, jnp.int32),
+            _shape(one_chip, (n, width), jnp.int32), vec(n, jnp.int32),
+            vec(n, jnp.bool_), vec(n, jnp.float32), vec(n, jnp.float32),
+            vec(n, jnp.int32), vec(n, jnp.uint32),
+            vec(n, jnp.int32)).compile()
+        assert "paged_decode_attention_latent" in compiled.as_text()
+    else:
+        def chunk(dtype):
+            return _shape(one_chip, (1, 2048), dtype)
+
+        compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+            params, cache, chunk(jnp.int32), chunk(jnp.int32),
+            _shape(one_chip, (1, width), jnp.int32), vec(1, jnp.int32),
+            chunk(jnp.bool_), vec(1, jnp.int32), vec(1, jnp.float32),
+            vec(1, jnp.float32), vec(1, jnp.int32), vec(1, jnp.uint32),
+            vec(1, jnp.int32)).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "tpu_custom_call" in text  # the experts' grouped matmul at least
+    assert _copies(text, pool_bytes // 5) == []  # not even one layer's
+    assert memory.temp_size_in_bytes < (1.0e9 if program == "decode-block"
+                                        else 2.0e9)
+    assert memory.argument_size_in_bytes < 12.4e9
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+
+
+@pytest.mark.parametrize("width", [8, 384])
+def test_the_latent_decode_kernel_compiles_for_v5e(one_chip, width):
+    import math
+
+    from dynamo_tpu.ops.paged_attention import paged_decode_attention_latent
+
+    compiled = paged_decode_attention_latent.lower(
+        _shape(one_chip, (PANGU["rows"], 128, 640), jnp.bfloat16),
+        _shape(one_chip, (5, 1, PANGU["pages"], PAGE, 1, 640), jnp.bfloat16),
+        _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (PANGU["rows"], width), jnp.int32),
+        _shape(one_chip, (PANGU["rows"],), jnp.int32),
+        rank=512, sm_scale=1 / math.sqrt(192)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the pool is read in place: no row-major copy in front of the call
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
