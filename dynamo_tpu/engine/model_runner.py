@@ -30,7 +30,12 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models import ModelConfig, forward, init_params, make_kv_cache, param_axes
-from ..models.transformer import forward_decode, forward_ring, write_kv_stack
+from ..models.transformer import (
+    KV_SCALE_LANES,
+    forward_decode,
+    forward_ring,
+    write_kv_stack,
+)
 from ..parallel import kv_cache_sharding, param_shardings
 from ..parallel.mesh import AXIS_DP, AXIS_SP, AXIS_TP, Mesh
 from ..runtime.config import env
@@ -185,9 +190,13 @@ def _pallas_mode(mesh: Mesh) -> Optional[bool]:
 
 
 def _default_attention_fn(mesh: Mesh):
-    """Prefill/unified attention: Pallas flash-decode on single-device;
-    XLA otherwise (prefill is compute-bound — XLA's fused SDPA is already
-    MXU-shaped, so a multi-device kernel buys nothing there)."""
+    """Prefill/unified attention on a single device:
+    `ops.paged_attention.paged_attention`, the blocked prefill kernel
+    over the paged pool wherever it admits the geometry. A multi-device
+    mesh keeps the XLA path, whose sharding pjit manages: its float32
+    score tensors cost there what they cost the flagship cell before the
+    kernel (PERF.md, PR 39), and a shard_map over kv heads as the decode
+    kernel has is the mend."""
     interpret = _pallas_mode(mesh)
     if interpret is None or mesh.devices.size > 1:
         return None
@@ -335,7 +344,6 @@ class ModelRunner:
             raise ValueError("int8 KV targets standard-attention models "
                              "(MLA's latent cache is already compact)")
         if self._kv_quantized:
-            from ..models.transformer import KV_SCALE_LANES
             from ..ops import kernel_path
 
             if model_config.head_dim != KV_SCALE_LANES:
@@ -455,6 +463,12 @@ class ModelRunner:
         # (live) and was told to skip (padding only).
         self.prefill_positions = 0
         self.prefill_row_blocks = {"live": 0, "skipped": 0}
+        # Which path the attention layers of prefill launches took
+        # (dynamo_prefill_attn_launches_total) and, on the kernel's, the
+        # (query block, key chunk) pairs a layer scored and skipped
+        # (dynamo_prefill_attn_blocks_total).
+        self.prefill_attn_launches = {"kernel": 0, "xla": 0}
+        self.prefill_attn_blocks = {"live": 0, "skipped": 0}
         # A model with latent attention (dynamo_latent_*): cached
         # positions its decode kernel was asked to read, and positions
         # whose keys and values prefill launches rebuilt from latents,
@@ -509,9 +523,30 @@ class ModelRunner:
             self.latent_prefill_expand_tokens += int(
                 sum(kv_lens)) * len(self.model_config.kv_layers)
 
-    def _count_prefill(self, lengths: Sequence[int], rows: int,
-                       bucket: int) -> None:
-        """Host arithmetic on a launch's own lengths, no device sync."""
+    def prefill_attention_tiles(self, bucket: int):
+        """(query positions a block, key tokens a chunk) where the
+        attention layers of a `bucket`-position prefill launch run the
+        blocked kernel, None where they run in XLA: `paged_attention`'s
+        own rule on the shapes it will be handed, and only where the
+        step program hands them to it (the default `attention_fn`, a
+        model whose prefill has no attention of its own)."""
+        cfg, rc = self.model_config, self.config
+        if (self._attention_user_supplied or self._attention_fn is None
+                or self._windowed or self._latent or cfg.is_mla
+                or cfg.is_gptoss or not cfg.kv_layers):
+            return None
+        from ..ops.paged_attention import prefill_kernel_tiles
+
+        return prefill_kernel_tiles(
+            bucket, cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim,
+            rc.page_size, rc.max_pages_per_seq,
+            jnp.int8 if self._kv_quantized else cfg.dtype,
+            KV_SCALE_LANES if self._kv_quantized else None)
+
+    def _count_prefill(self, starts: Sequence[int], lengths: Sequence[int],
+                       rows: int, bucket: int) -> None:
+        """Host arithmetic on a launch's own positions (row i holds
+        `lengths[i]` of them from `starts[i]`), no device sync."""
         self.prefill_positions += rows * bucket
         if self.config.weight_dtype == "int4":
             from ..ops.q4_linear import count_row_blocks
@@ -519,6 +554,16 @@ class ModelRunner:
             live, skipped = count_row_blocks(lengths, rows, bucket)
             self.prefill_row_blocks["live"] += live
             self.prefill_row_blocks["skipped"] += skipped
+        tiles = self.prefill_attention_tiles(bucket)
+        self.prefill_attn_launches["kernel" if tiles else "xla"] += 1
+        if tiles:
+            from ..ops.paged_attention import count_prefill_blocks
+
+            live, skipped = count_prefill_blocks(
+                starts, [s + n for s, n in zip(starts, lengths)], rows,
+                bucket, *tiles, self.config.max_context)
+            self.prefill_attn_blocks["live"] += live
+            self.prefill_attn_blocks["skipped"] += skipped
 
     @staticmethod
     def _check_hybrid(cfg: ModelConfig, rc: RunnerConfig, mesh: Mesh):
@@ -1289,7 +1334,7 @@ class ModelRunner:
         pos[0, :t] = np.arange(start_pos, start_pos + t)
         valid = np.zeros((1, bucket), bool)
         valid[0, :t] = True
-        self._count_prefill([t], 1, bucket)
+        self._count_prefill([start_pos], [t], 1, bucket)
         self._count_latent_prefill([kv_len_after])
         temp, top_p, top_k, seed = sampling
         args = [
@@ -1395,7 +1440,8 @@ class ModelRunner:
             last_idx[i] = t - 1
             temp[i], top_p[i], top_k[i], seeds[i] = sampling
             lora_rows[i] = lidx
-        self._count_prefill([len(r[0]) for r in rows], b, bucket)
+        self._count_prefill([r[1] for r in rows],
+                            [len(r[0]) for r in rows], b, bucket)
         self._count_latent_prefill([r[3] for r in rows])
         args = [
             jnp.asarray(tok), jnp.asarray(pos),
@@ -1728,8 +1774,6 @@ class ModelRunner:
             "dtype": str(jnp.dtype(cfg.dtype).name),
         }
         if self._kv_quantized:
-            from ..models.transformer import KV_SCALE_LANES
-
             # Tier blocks travel PACKED (uint8 values+scales bytes,
             # ops/block_copy.py gather_kv_blocks_q8); BlockLayoutSpec
             # derives the flat byte geometry from these fields.

@@ -1555,6 +1555,7 @@ class TpuWorker:
         launched over, page-time reserved and per-chip device memory
         (docs/metrics.md: dynamo_engine_tokens, dynamo_engine_launches,
         dynamo_engine_positions, dynamo_prefill_row_blocks_total,
+        dynamo_prefill_attn_launches_total, dynamo_prefill_attn_blocks_total,
         dynamo_kv_reserved_page_ms, dynamo_kv_window_*, dynamo_latent_*,
         dynamo_device_hbm_bytes)."""
         from ..runtime.metrics import (
@@ -1575,6 +1576,8 @@ class TpuWorker:
             MOE_EXPERT_CALLS,
             MOE_EXPERT_TOKENS,
             MOE_EXPERTS_TOUCHED,
+            PREFILL_ATTN_BLOCKS,
+            PREFILL_ATTN_LAUNCHES,
             PREFILL_ROW_BLOCKS,
             SSM_STATE_SLOT_MS,
         )
@@ -1599,6 +1602,12 @@ class TpuWorker:
             for state, count in blocks.items():
                 PREFILL_ROW_BLOCKS.labels(worker=worker, state=state).set(
                     count)
+        for path, count in getattr(
+                self.runner, "prefill_attn_launches", {}).items():
+            PREFILL_ATTN_LAUNCHES.labels(worker=worker, path=path).set(count)
+        for state, count in getattr(
+                self.runner, "prefill_attn_blocks", {}).items():
+            PREFILL_ATTN_BLOCKS.labels(worker=worker, state=state).set(count)
         KV_RESERVED_PAGE_MS.labels(worker=worker).set(
             stats.reserved_page_ms)
         win_pool = self.scheduler.win_pool

@@ -765,8 +765,10 @@ def paged_attention_xla(
     flat_gather: bool = False,
 ) -> jax.Array:
     """Reference paged attention: gather the sequence's pages, run masked
-    SDPA. Correct everywhere (CPU tests, fallback); the Pallas kernel
-    (ops/paged_attention.py) replaces this on TPU for decode.
+    SDPA. Correct everywhere (CPU tests, fallback, the kernels' oracle);
+    on a TPU the Pallas kernels (ops/paged_attention.py) replace it for
+    decode and, behind `attention_fn`, for prefill launches: its scores
+    are a float32 [B, T, heads, table tokens] in HBM.
 
     `window` > 0 is the mask's lower edge: a query sees the keys with
     q_pos - window < kv_pos <= q_pos. Table and positions may be a page

@@ -14,7 +14,11 @@ is a TPU-first Pallas kernel:
     chunks; output written on the last chunk.
   * GQA: q-heads grouped per kv-head; the group dim rides the MXU sublanes.
 
-On CPU (tests, dev boxes) the same kernel runs in interpret mode; the
+Prefill launches (T > 1) have a blocked kernel of their own over the same
+pool, `paged_prefill_attention_pool`: (row, query block, key chunk) grid,
+causal and padding blocks skipped, no [T, S] score tensor anywhere.
+
+On CPU (tests, dev boxes) the same kernels run in interpret mode; the
 pure-XLA fallback (`models.transformer.paged_attention_xla`) remains the
 reference oracle.
 """
@@ -1229,32 +1233,411 @@ def paged_attention_spec_pool(
     return _combine_chunk(q, acc, m, l, k_cur, v_cur)
 
 
+# Prefill tiles: a grid step scores one key chunk of _PREFILL_CHUNK_TOKENS
+# against a query block of _PREFILL_ROWS rows a kv head (positions x
+# group), for every kv head in turn.
+_PREFILL_ROWS = 1024
+_PREFILL_CHUNK_TOKENS = 256
+_PREFILL_VMEM_BYTES = 64 * 1024 * 1024  # of a v5e's 128 MiB
+
+
+def prefill_kernel_tiles(t: int, qh: int, kh: int, hd: int, page_size: int,
+                         max_pages: int, pool_dtype,
+                         scale_lanes: int | None = None):
+    """(query positions a block, key tokens a chunk) of
+    `paged_prefill_attention_pool` for a launch of `t` positions a row
+    over `max_pages`-wide tables, or None where Mosaic has no such
+    geometry and `paged_attention` takes the XLA path: head_dim a lane
+    tile or more, a power-of-two group, a pool whose kv heads fill whole
+    32-bit words (the per-head read unpacks words: bf16 in pairs, int8 in
+    fours), an int8 pool at head_dim == its scale lanes only, and tiles
+    that are whole (sublane, lane) tiles."""
+    pool_dtype = jnp.dtype(pool_dtype)
+    group = qh // max(kh, 1)
+    if (t < 2 or hd % 128 or qh != group * kh or group & (group - 1)
+            or pool_dtype not in (jnp.dtype(jnp.int8),
+                                  jnp.dtype(jnp.bfloat16))
+            or kh % (4 // pool_dtype.itemsize)
+            or (pool_dtype == jnp.int8) != (scale_lanes is not None)
+            or (scale_lanes is not None and scale_lanes != hd)):
+        return None
+    block_q = _largest_divisor(t, max(1, _PREFILL_ROWS // group))
+    chunk = page_size * _largest_divisor(
+        max_pages, max(1, _PREFILL_CHUNK_TOKENS // page_size))
+    if (block_q * group) % 16 or chunk % 128:
+        return None
+    return block_q, chunk
+
+
+def count_prefill_blocks(starts, kv_lens, rows: int, t: int, block_q: int,
+                         chunk_tokens: int,
+                         table_tokens: int) -> tuple[int, int]:
+    """(live, skipped) (query block, key chunk) pairs of one attention
+    layer of a launch of `rows` x `t` positions over tables of
+    `table_tokens`: the kernel's own liveness rule on the host's numbers,
+    for the engine's counters. Row i's queries start at `starts[i]` and
+    see `kv_lens[i]` keys; rows past the lists are padding."""
+    total = rows * (t // block_q) * (table_tokens // chunk_tokens)
+    live = 0
+    for start, kv_len in zip(starts, kv_lens):
+        for qi in range(-(-(kv_len - start) // block_q)):
+            limit = min(kv_len, start + (qi + 1) * block_q)
+            live += -(-min(limit, table_tokens) // chunk_tokens)
+    return live, total - live
+
+
+def _head_rows(words_ref, group: int, sub, n_tok: int, kh: int, dtype):
+    """One kv head of a chunk, float32 [n_tok, hd], read out of the
+    chunk's [n_tok * kh, hd] rows (a token's kh rows consecutive) viewed
+    as 32-bit words: a word holds the same lane of `pack` consecutive
+    rows, the lowest row in its lowest bits, so head `group * pack + sub`
+    is every (kh / pack)-th word row from `group` on (static: a strided
+    load), shifted down by `sub` elements (traced: the caller loops over
+    a word's heads without unrolling them). int8 codes and bf16 values
+    both convert exactly."""
+    bits = 8 * jnp.dtype(dtype).itemsize
+    words = words_ref[pl.ds(group, n_tok, stride=kh * bits // 32), :]
+    words = words >> (sub * bits).astype(jnp.uint32)
+    if jnp.dtype(dtype) == jnp.int8:
+        return pltpu.bitcast(words.astype(jnp.uint8),
+                             jnp.int8).astype(jnp.float32)
+    return pltpu.bitcast(words << 16, jnp.float32)
+
+
+def _pool_prefill_kernel(
+    # scalar prefetch
+    starts_ref,  # [B] int32 position of a row's first query
+    lengths_ref,  # [B] int32 keys a row sees, this chunk's included
+    tables_ref,  # [B * max_pages] int32 flattened block tables
+    layer_ref,  # [1] int32
+    buf_idx_ref,  # [1] int32 (double-buffer slot)
+    init_ref,  # [1] int32 (1 until the first DMA was issued)
+    q_ref,  # [1, block_q, qh, hd]
+    pool_ref,  # FULL [L, 2, P, ps, kh, hd] in HBM (memory_space=ANY)
+    *rest,
+    block_q: int,
+    group: int,
+    pages_per_chunk: int,
+    max_pages: int,
+    batch_size: int,
+    quantized: bool,
+):
+    """Blocked causal attention of a prefill launch over the paged pool,
+    the launch's own keys included (`write_kv_pages` has put them there).
+
+    Grid (row, query block, key chunk), run in order. A query block is
+    `block_q` consecutive positions of one row for all its heads, read
+    and written in the launch's own [B, T, qh, hd] layout (no relayout
+    around the kernel) and folded once a block into a kv head's
+    contiguous `block_q * g` query rows (`q_buf`); per kv head those
+    rows score one chunk of `pages_per_chunk` pages in one MXU pass,
+    S = Q K^T [block_q * g, chunk tokens], and the flash state (running
+    max, sum, accumulator: float32 VMEM, a set a kv head) carries it
+    across the row's chunks. [T, S] exists nowhere.
+
+    What is never fetched or scored: chunks wholly above a query block's
+    last position (the causal half), chunks past the row's keys, query
+    blocks wholly past the row's valid positions (bucket padding), rows
+    of length 0 (a pow2 launch's padding). Their grid steps do nothing;
+    the output of a dead query block is zeros. Queries past a row's
+    valid count inside a live block score every key of the row: their
+    output is finite and nobody reads it.
+
+    `_pool_decode_kernel` has the why of the streaming: the pool stays in
+    HBM, a page's K and V for all kv heads come in one DMA through the
+    scalar-prefetched table into a double-buffered chunk, and a step
+    starts the next live step's chunk before it waits for its own. Here
+    a kv head's rows are then read out of the chunk on their own
+    (`_head_rows`), so the MXU does no work across heads. The heads of
+    one 32-bit word and a chunk's pages are loops, not unrolled: a
+    program's trace, its Mosaic module and its compile hold kh / pack
+    flash bodies and three DMA sites (11 s -> 7 s of Mosaic a shape,
+    0.35 -> 0.13 s of lowering a program: PERF.md, PR 39).
+
+    Precision: bf16 MXU operands (the query's dtype), float32 scores,
+    softmax and accumulation. `quantized`: int8 pages and per-token bf16
+    scale rows ([ps, LANES], LANES == hd); codes x scale (x 1/sqrt(hd)
+    for K) are rounded once to the operand dtype, as a float32 matmul at
+    the TPU's default precision rounds the dequantised keys.
+    """
+    if quantized:
+        (scale_ref,  # FULL bf16 [L, 2, P, ps, LANES] in HBM (ANY)
+         o_ref,  # [1, block_q, qh, hd]
+         kv_buf,  # [2, 2, C, ps, kh, hd] (slot, K|V) page chunks
+         sc_buf,  # [2, 2, C, ps, LANES]
+         sems, q_buf, m_ref, l_ref, acc_ref) = rest
+    else:
+        scale_ref = sc_buf = None
+        (o_ref, kv_buf,
+         sems,  # DMA semaphores (2,): one per slot
+         q_buf,  # [kh, block_q * g, hd]: row t * g + j = position t, head j
+         m_ref, l_ref,  # [kh, block_q * g, 128] f32
+         acc_ref) = rest  # [kh, block_q * g, hd] f32
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    c = pl.program_id(2)
+    n_q = pl.num_programs(1)
+    n_chunks = pl.num_programs(2)
+    ps, kh, hd = kv_buf.shape[3:]
+    rows = block_q * group
+    bk = pages_per_chunk * ps
+    layer = layer_ref[0]
+    sm_scale = 1.0 / math.sqrt(hd)
+
+    def block_live(bi, qi):
+        return qi * block_q < lengths_ref[bi] - starts_ref[bi]
+
+    def key_limit(bi, qi):  # keys the block's last query sees
+        return jnp.minimum(lengths_ref[bi],
+                           starts_ref[bi] + (qi + 1) * block_q)
+
+    def chunk_copies(bi, ci, slot, fn):
+        base = bi * max_pages + ci * pages_per_chunk
+
+        @pl.loop(0, pages_per_chunk)
+        def _page(j):
+            page = tables_ref[base + j]
+            fn(pltpu.make_async_copy(
+                pool_ref.at[layer, :, page], kv_buf.at[slot, :, j],
+                sems.at[slot]))
+            if quantized:
+                fn(pltpu.make_async_copy(
+                    scale_ref.at[layer, :, page], sc_buf.at[slot, :, j],
+                    sems.at[slot]))
+
+    def next_step():
+        """The next live (row, query block, chunk) in grid order; row ==
+        batch_size when nothing is left."""
+        def next_row():
+            nb = jax.lax.fori_loop(
+                0, batch_size,
+                lambda _, cur: jnp.where(
+                    jnp.logical_and(
+                        cur < batch_size,
+                        jnp.logical_not(block_live(
+                            jnp.clip(cur, 0, batch_size - 1), 0))),
+                    cur + 1, cur),
+                b + 1)
+            return nb, jnp.int32(0), jnp.int32(0)
+
+        def next_block():
+            more = jnp.logical_and(i + 1 < n_q, block_live(b, i + 1))
+            return jax.lax.cond(
+                more, lambda: (b, i + 1, jnp.int32(0)), next_row)
+
+        more = jnp.logical_and(c + 1 < n_chunks,
+                               (c + 1) * bk < key_limit(b, i))
+        return jax.lax.cond(more, lambda: (b, i, c + 1), next_block)
+
+    live = block_live(b, i)
+    active = jnp.logical_and(live, c * bk < key_limit(b, i))
+
+    @pl.when(jnp.logical_and(active, init_ref[0] == 1))
+    def _first():
+        chunk_copies(b, c, buf_idx_ref[0], lambda cp: cp.start())
+        init_ref[0] = 0
+
+    @pl.when(jnp.logical_and(c == 0, live))
+    def _fold_queries():
+        # a kv head's query rows, contiguous: once a query block
+        for h in range(kh):
+            q_buf[h] = q_ref[0, :, h * group:(h + 1) * group, :].reshape(
+                rows, hd)
+
+    @pl.when(active)
+    def _compute():
+        slot = buf_idx_ref[0]
+        nb, _, nc = next_step()
+
+        @pl.when(nb < batch_size)
+        def _prefetch():
+            nslot = jnp.where(slot == 0, 1, 0)
+            chunk_copies(nb, nc, nslot, lambda cp: cp.start())
+            buf_idx_ref[0] = nslot
+
+        chunk_copies(b, c, slot, lambda cp: cp.wait())
+        # Row r of a head's tile is position r // g of the block; column
+        # j is key c * bk + j.
+        q_pos = (starts_ref[b] + i * block_q + _div(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), group))
+        k_pos = c * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        seen = jnp.logical_and(k_pos <= q_pos, k_pos < lengths_ref[b])
+        first = c == 0
+        pool_dtype = kv_buf.dtype
+        k_words = kv_buf.at[slot, 0].reshape(bk * kh, hd).bitcast(jnp.uint32)
+        v_words = kv_buf.at[slot, 1].reshape(bk * kh, hd).bitcast(jnp.uint32)
+        if quantized:
+            k_scale = (sc_buf[slot, 0].reshape(bk, hd).astype(jnp.float32)
+                       * sm_scale)
+            v_scale = sc_buf[slot, 1].reshape(bk, hd).astype(jnp.float32)
+        pack = 4 // jnp.dtype(pool_dtype).itemsize  # kv heads a word
+
+        def flash_head(word_group, sub):
+            h = word_group * pack + sub
+            q = q_buf[h]  # [rows, hd], the matmul operand dtype
+            k = _head_rows(k_words, word_group, sub, bk, kh, pool_dtype)
+            v = _head_rows(v_words, word_group, sub, bk, kh, pool_dtype)
+            if quantized:
+                k, v = k * k_scale, v * v_scale
+            s = jax.lax.dot_general(
+                q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [rows, bk]
+            if not quantized:
+                s = s * sm_scale
+            s = jnp.where(seen, s, -jnp.inf)
+            # No reset between query blocks: a block's first chunk takes
+            # an empty state instead of the scratch's leftovers. Finite
+            # from there on: key 0 is seen by every query of a live row.
+            m_prev = jnp.where(first, -jnp.inf, m_ref[h, :, 0:1])
+            l_prev = jnp.where(first, 0.0, l_ref[h, :, 0:1])
+            o_prev = jnp.where(first, 0.0, acc_ref[h])
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(q.dtype), v.astype(q.dtype),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [rows, hd]
+            acc_ref[h] = o_prev * alpha + pv
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+        # A loop over a word's heads, each turn the same head of every
+        # word row: a program's trace and Mosaic's module hold kh / pack
+        # flash bodies, and the bodies of one turn are independent, so
+        # one head's softmax overlaps another's matmuls.
+        @pl.loop(0, pack)
+        def _heads(sub):
+            for word_group in range(kh // pack):
+                flash_head(word_group, sub)
+
+    @pl.when(jnp.logical_and(c == n_chunks - 1, live))
+    def _finish():
+        for h in range(kh):
+            o_ref[0, :, h * group:(h + 1) * group, :] = (
+                acc_ref[h] / l_ref[h, :, 0:1]).astype(o_ref.dtype).reshape(
+                    block_q, group, hd)
+
+    @pl.when(jnp.logical_and(c == n_chunks - 1, jnp.logical_not(live)))
+    def _padding():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",),
+                   donate_argnums=())  # read-only on the whole pool
+def paged_prefill_attention_pool(
+    q: jax.Array,  # [B, T, qh, hd]
+    kv_pool: jax.Array,  # [L, 2, P, ps, kh, hd]: the WHOLE cache
+    layer: jax.Array,  # scalar int32
+    block_tables: jax.Array,  # [B, max_pages] int32
+    starts: jax.Array,  # [B] int32 position of each row's first query
+    kv_lens: jax.Array,  # [B] int32 keys a row sees, this chunk's included
+    kv_scales=None,  # bf16 [L, 2, P, ps, LANES] for an int8 pool
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal attention of a prefill launch, `_pool_prefill_kernel`: row b
+    holds the consecutive positions starts[b].. of which the first
+    kv_lens[b] - starts[b] are real; returns [B, T, qh, hd]. The caller
+    (`paged_attention`) has checked the geometry with
+    `prefill_kernel_tiles`."""
+    quantized = kv_scales is not None
+    b, t, qh, hd = q.shape
+    ps, kh = kv_pool.shape[3], kv_pool.shape[4]
+    group = qh // kh
+    max_pages = block_tables.shape[1]
+    block_q, chunk = prefill_kernel_tiles(
+        t, qh, kh, hd, ps, max_pages, kv_pool.dtype,
+        kv_scales.shape[-1] if quantized else None)
+    ppc = chunk // ps
+    assert t % block_q == 0 and max_pages % ppc == 0
+    rows = block_q * group
+
+    def q_map(bi, qi, ci, *refs):
+        del ci, refs
+        return (bi, qi, 0, 0)
+
+    in_specs = [pl.BlockSpec((1, block_q, qh, hd), q_map),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    scratch = [pltpu.VMEM((2, 2, ppc, ps, kh, hd), kv_pool.dtype)]
+    operands = [q, kv_pool]
+    if quantized:
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        scratch.append(pltpu.VMEM((2, 2, ppc, ps, kv_scales.shape[-1]),
+                                  kv_scales.dtype))
+        operands.append(kv_scales)
+    scratch += [
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.VMEM((kh, rows, hd), q.dtype),
+        pltpu.VMEM((kh, rows, 128), jnp.float32),
+        pltpu.VMEM((kh, rows, 128), jnp.float32),
+        pltpu.VMEM((kh, rows, hd), jnp.float32),
+    ]
+    return pl.pallas_call(
+        functools.partial(_pool_prefill_kernel, block_q=block_q,
+                          group=group, pages_per_chunk=ppc,
+                          max_pages=max_pages, batch_size=b,
+                          quantized=quantized),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(b, t // block_q, max_pages // ppc),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, block_q, qh, hd), q_map),
+            scratch_shapes=scratch,
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_PREFILL_VMEM_BYTES),
+        name="paged_prefill_attention_pool",
+    )(starts.astype(jnp.int32), kv_lens.astype(jnp.int32),
+      block_tables.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32), *operands)
+
+
 def paged_attention(
     q: jax.Array,  # [B, T, qh, hd]
-    kv_cache: jax.Array,  # [L, 2, P, ps, kh, hd]
+    kv_cache,  # [L, 2, P, ps, kh, hd] or int8 (values, scales) pair
     layer: int,
     block_tables: jax.Array,
-    positions: jax.Array,
+    positions: jax.Array,  # [B, T]: a row's positions are consecutive
     kv_lens: jax.Array,
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """Drop-in `attention_fn` for `models.transformer.forward`.
+    """Drop-in `attention_fn` for `models.transformer.forward` and the
+    full-attention layers of `models.hybrid.forward_hybrid`.
 
-    Decode (T == 1) runs the Pallas flash-decode kernel; prefill chunks
-    (T > 1) use the XLA path (compute-bound; XLA's fused SDPA is already
-    MXU-shaped there — ref SURVEY §7 "hard parts").
-    """
+    A prefill chunk (T > 1) runs the blocked kernel over the paged pool
+    (`paged_prefill_attention_pool`) wherever `prefill_kernel_tiles`
+    admits the geometry: XLA's attention there writes and reads a float32
+    score tensor [B, T, heads, table tokens] several times over and was
+    30% of the flagship cell's device time (PERF.md, PR 39). A row's
+    first query position is `positions[:, 0]` and its valid count
+    `kv_lens - positions[:, 0]`, as every prefill launch lays its rows
+    out. One token (T == 1) over a bf16 pool runs the per-layer flash
+    decode kernel. Everything else takes `paged_attention_xla`, the CPU
+    path and the oracle of both."""
     from ..models.transformer import paged_attention_xla
 
-    if q.shape[1] != 1 or isinstance(kv_cache, tuple):
-        # Prefill chunks are compute-bound (XLA's fused SDPA is already
-        # MXU-shaped); int8 caches dequantize on the XLA path here — the
-        # q8 Pallas kernel covers the decode hot loop.
-        return paged_attention_xla(q, kv_cache, layer, block_tables,
-                                   positions, kv_lens)
-    out = paged_decode_attention(
-        q[:, 0], kv_cache[layer, 0], kv_cache[layer, 1],
-        block_tables, kv_lens, interpret=interpret,
-    )
-    return out[:, None]
+    values, scales = (kv_cache if isinstance(kv_cache, tuple)
+                      else (kv_cache, None))
+    _, t, qh, hd = q.shape
+    if t == 1 and scales is None:
+        out = paged_decode_attention(
+            q[:, 0], values[layer, 0], values[layer, 1],
+            block_tables, kv_lens, interpret=interpret,
+        )
+        return out[:, None]
+    if prefill_kernel_tiles(
+            t, qh, values.shape[4], hd, values.shape[3],
+            block_tables.shape[1], values.dtype,
+            None if scales is None else scales.shape[-1]) is not None:
+        return paged_prefill_attention_pool(
+            q, values, layer, block_tables, positions[:, 0], kv_lens,
+            kv_scales=scales, interpret=interpret)
+    return paged_attention_xla(q, kv_cache, layer, block_tables,
+                               positions, kv_lens)

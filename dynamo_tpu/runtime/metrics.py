@@ -371,6 +371,21 @@ PREFILL_ROW_BLOCKS = Gauge(
     "and does no work for it)",
     ["worker", "state"], registry=REGISTRY,
 )
+PREFILL_ATTN_LAUNCHES = Gauge(
+    "dynamo_prefill_attn_launches_total",
+    "Prefill launches since start by the path their attention layers "
+    "took: kernel (the blocked Pallas prefill kernel over the paged "
+    "pool) | xla (gathered pages, a full float32 score tensor)",
+    ["worker", "path"], registry=REGISTRY,
+)
+PREFILL_ATTN_BLOCKS = Gauge(
+    "dynamo_prefill_attn_blocks_total",
+    "Kernel-path prefill launches: (query block, key chunk) pairs of one "
+    "attention layer since start, by state: live (fetched and scored) | "
+    "skipped (above the causal diagonal, past the row's keys, padding: "
+    "a dense rows x bucket x table grid holds both)",
+    ["worker", "state"], registry=REGISTRY,
+)
 LATENT_DECODE_TOKENS = Gauge(
     "dynamo_latent_decode_tokens_total",
     "Model with latent attention: cached positions its decode kernel "

@@ -1,0 +1,142 @@
+"""The blocked prefill attention kernel (`paged_prefill_attention_pool`)
+against `paged_attention_xla`, in the Pallas interpreter on the CPU:
+what a prefill launch hands `attention_fn`, row by row. Mosaic's view of
+the same kernel is in tests/test_tpu_compile.py.
+"""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.models.transformer import (
+    KV_SCALE_LANES,
+    paged_attention_xla,
+)
+from dynamo_tpu.ops.paged_attention import (
+    count_prefill_blocks,
+    paged_attention,
+    prefill_kernel_tiles,
+)
+
+PAGE, HEAD_DIM, LAYERS = 16, 128, 2
+
+# name: (pool, kv heads, group, positions a row (the bucket), table width
+# in pages, rows as (first position, valid positions); (0, 0) is a row a
+# pow2 launch pads in)
+CASES = {
+    "bf16-g4-fresh": ("bf16", 2, 4, 512, 32, [(0, 512)]),
+    "int8-g4-fresh": ("int8", 4, 4, 512, 32, [(0, 512)]),
+    "bf16-g8-fresh": ("bf16", 2, 8, 256, 16, [(0, 256)]),
+    "int8-g8-fresh": ("int8", 4, 8, 256, 16, [(0, 256)]),
+    "bf16-g16-fresh": ("bf16", 2, 16, 128, 16, [(0, 128)]),
+    "int8-g16-fresh": ("int8", 4, 16, 128, 16, [(0, 128)]),
+    "bf16-g4-continuation": ("bf16", 2, 4, 512, 64, [(512, 512)]),
+    "int8-g4-continuation": ("int8", 4, 4, 512, 64, [(512, 512)]),
+    "bf16-g4-short-row": ("bf16", 2, 4, 512, 32, [(0, 300)]),
+    "int8-g4-short-row-mid-page": ("int8", 4, 4, 512, 32, [(0, 333)]),
+    "bf16-g4-padded-launch": ("bf16", 2, 4, 256, 16,
+                              [(0, 256), (0, 100), (0, 201), (0, 0)]),
+    "int8-g4-padded-launch": ("int8", 4, 4, 256, 16,
+                              [(0, 77), (0, 0)]),
+    "bf16-g16-wide-table": ("bf16", 2, 16, 128, 64, [(0, 90), (64, 128)]),
+    "int8-g4-wide-table": ("int8", 4, 4, 256, 64, [(0, 200)]),
+    "int8-g4-mixed-rows": ("int8", 8, 4, 512, 64,
+                           [(0, 512), (512, 300), (250, 77), (0, 0)]),
+    "bf16-g8-continuation-mid-page": ("bf16", 2, 8, 256, 32,
+                                      [(131, 256), (7, 120)]),
+}
+
+
+def _launch(case, seed=0):
+    pool, kh, g, t, width, rows = CASES[case]
+    rng = np.random.default_rng(seed)
+    b = len(rows)
+    n_pages = b * width + 1
+    shape = (LAYERS, 2, n_pages, PAGE, kh, HEAD_DIM)
+    if pool == "int8":
+        values = jnp.asarray(rng.integers(-127, 128, shape, dtype=np.int8))
+        scale = rng.uniform(0.002, 0.02, shape[:4] + (1,))
+        scales = jnp.asarray(np.broadcast_to(
+            scale, shape[:4] + (KV_SCALE_LANES,)), jnp.bfloat16)
+        cache = (values, scales)
+    else:
+        cache = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    q = jnp.asarray(rng.normal(size=(b, t, kh * g, HEAD_DIM)), jnp.bfloat16)
+    tables = np.zeros((b, width), np.int32)  # a padded row: the scratch page
+    positions = np.zeros((b, t), np.int32)
+    kv_lens = np.zeros(b, np.int32)
+    pages = rng.permutation(np.arange(1, n_pages))
+    for i, (start, n) in enumerate(rows):
+        if n:
+            tables[i] = pages[i * width:(i + 1) * width]
+            positions[i, :n] = np.arange(start, start + n)
+            kv_lens[i] = start + n
+    return (q, cache, jnp.asarray(tables), jnp.asarray(positions),
+            jnp.asarray(kv_lens))
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_prefill_kernel_matches_the_xla_oracle(case):
+    _, kh, g, t, width, rows = CASES[case]
+    q, cache, tables, positions, kv_lens = _launch(case)
+    values = cache[0] if isinstance(cache, tuple) else cache
+    block_q, _ = prefill_kernel_tiles(
+        t, kh * g, kh, HEAD_DIM, PAGE, width, values.dtype,
+        KV_SCALE_LANES if isinstance(cache, tuple) else None)
+    layer = LAYERS - 1
+    got = np.asarray(paged_attention(
+        q, cache, layer, tables, positions, kv_lens, interpret=True),
+        np.float32)
+    want = np.asarray(paged_attention_xla(
+        q, cache, layer, tables, positions, kv_lens), np.float32)
+    assert got.shape == want.shape and np.isfinite(got).all()
+    for i, (_, n) in enumerate(rows):
+        # bf16 operands and probabilities against a float32 oracle
+        np.testing.assert_allclose(got[i, :n], want[i, :n], atol=0.03,
+                                   rtol=0.03)
+    # what nobody reads: zeros for a query block wholly past the row's end
+    for i, (_, n) in enumerate(rows):
+        dead = -(-n // block_q) * block_q
+        assert not got[i, dead:].any()
+
+
+@pytest.mark.parametrize("why,args", [
+    ("head_dim under a lane tile", (256, 8, 2, 64, 16, 64, jnp.bfloat16)),
+    ("one token", (1, 32, 8, 128, 16, 64, jnp.bfloat16)),
+    ("a group that is no power of two", (256, 6, 2, 128, 16, 64,
+                                         jnp.bfloat16)),
+    ("one bf16 kv head: half a word", (256, 8, 1, 128, 16, 64,
+                                       jnp.bfloat16)),
+    ("two int8 kv heads: half a word", (256, 8, 2, 128, 16, 64, jnp.int8,
+                                        128)),
+    ("an int8 pool without scales", (256, 32, 8, 128, 16, 64, jnp.int8)),
+    ("a float32 pool", (256, 32, 8, 128, 16, 64, jnp.float32)),
+    ("a key chunk under a lane tile", (256, 32, 8, 128, 16, 4,
+                                       jnp.bfloat16)),
+])
+def test_geometries_the_kernel_leaves_to_xla(why, args):
+    assert prefill_kernel_tiles(*args) is None, why
+
+
+def test_the_cells_geometries_take_the_kernel():
+    # mistral-7b w4kv8: 32 heads over 8, [rows, 1024] over 64-page tables
+    assert prefill_kernel_tiles(1024, 32, 8, 128, 16, 64, jnp.int8,
+                                128) == (256, 256)
+    # nemotron-3-nano's attention layers: 32 heads over 2, bf16
+    assert prefill_kernel_tiles(128, 32, 2, 128, 16, 64,
+                                jnp.bfloat16) == (64, 256)
+
+
+def test_block_counts_follow_the_kernels_liveness():
+    # [4, 1024] over 64-page tables in blocks of 256 x 256: a full bucket
+    # skips the causal half only (1 + 2 + 3 + 4 of 16 pairs)
+    assert count_prefill_blocks([0], [1024], 1, 1024, 256, 256,
+                                1024) == (10, 6)
+    # a 736-token prompt: three live query blocks, 1 + 2 + 3 chunks; two
+    # rows of padding
+    live, skipped = count_prefill_blocks([0, 0], [736, 736], 4, 1024, 256,
+                                         256, 1024)
+    assert (live, skipped) == (12, 52)
+    # a continuation sees its prefix whole: 3 + 4 chunks
+    assert count_prefill_blocks([512], [1024], 1, 512, 256, 256,
+                                1024) == (7, 1)

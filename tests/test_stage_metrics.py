@@ -497,3 +497,66 @@ def test_a_scrape_after_traffic_shows_the_stages_and_the_new_families(
     assert f'dynamo_kv_reserved_page_ms{{worker="{worker}"}}' in page
     for part in ("wall", "prep", "dispatch", "drain_wait"):
         assert f'dynamo_step_part_ms_total{{part="{part}"}}' in page
+
+
+def test_prefill_attention_launches_and_blocks_are_host_arithmetic(
+        monkeypatch):
+    """`ModelRunner._count_prefill` on hand-made lengths, no launch: which
+    path a launch's attention layers take follows from the geometry the
+    step program hands `attention_fn`, and on the kernel's path the
+    (query block, key chunk) pairs it scores and skips add up to the
+    dense rows x bucket x table grid."""
+    import dataclasses
+
+    from dynamo_tpu.engine import ModelRunner, RunnerConfig
+    from dynamo_tpu.models import get_config
+    from dynamo_tpu.parallel import MeshConfig, make_mesh
+
+    def runner(head_dim, **kw):
+        return ModelRunner(
+            dataclasses.replace(get_config("tiny-test"), head_dim=head_dim),
+            RunnerConfig(page_size=16, num_pages=80, max_batch=4,
+                         max_pages_per_seq=64, prefill_buckets=(256, 1024),
+                         **kw),
+            make_mesh(MeshConfig()), seed=0)
+
+    # the CPU's default is the XLA reference: no attention_fn at all
+    xla = runner(128)
+    assert xla.prefill_attention_tiles(1024) is None
+    xla._count_prefill([0], [736], 1, 1024)
+    assert xla.prefill_attn_launches == {"kernel": 0, "xla": 1}
+    assert xla.prefill_attn_blocks == {"live": 0, "skipped": 0}
+
+    monkeypatch.setenv("DYNT_ATTENTION", "pallas")  # here: the interpreter
+    # head_dim under a lane tile: `paged_attention` takes XLA whatever the
+    # option says
+    assert runner(16).prefill_attention_tiles(1024) is None
+    # 4 heads over 2: two int8 kv heads are half a 32-bit word -> XLA
+    assert runner(128, kv_dtype="int8").prefill_attention_tiles(1024) is None
+    r = runner(128)
+    assert r.prefill_attention_tiles(1024) == (512, 256)
+    # a full bucket skips the causal half only: 2 + 4 of 2 x 4 pairs
+    r._count_prefill([0], [1024], 1, 1024)
+    assert r.prefill_attn_blocks == {"live": 6, "skipped": 2}
+    # [4, 1024] holding 736, 736, 576: 2 + 3 chunks a row of 736 (its
+    # second block sees 736 keys), 2 + 3 for 576; one row of padding
+    r._count_prefill([0, 0, 0], [736, 736, 576], 4, 1024)
+    assert r.prefill_attn_blocks == {"live": 6 + 15, "skipped": 2 + 17}
+    # a continuation sees its prefix: positions 512.. of 1,024 keys
+    r._count_prefill([512], [512], 1, 1024)
+    assert r.prefill_attn_blocks == {"live": 21 + 4, "skipped": 19 + 4}
+    assert r.prefill_attn_launches == {"kernel": 3, "xla": 0}
+    assert sum(r.prefill_attn_blocks.values()) == (1 + 4 + 1) * 2 * 4
+    TpuWorker._publish_engine_gauges(types.SimpleNamespace(
+        scheduler=types.SimpleNamespace(stats=SchedulerStats(),
+                                        win_pool=None),
+        runner=r, instance_id=0xa77,
+        mesh=types.SimpleNamespace(local_devices=[]),
+        outbox=types.SimpleNamespace(handovers=0)))
+    launches = "dynamo_prefill_attn_launches_total"
+    assert sample(launches, worker="a77", path="kernel") == 3
+    assert REGISTRY.get_sample_value(
+        launches, {"worker": "a77", "path": "xla"}) == 0
+    blocks = "dynamo_prefill_attn_blocks_total"
+    assert sample(blocks, worker="a77", state="live") == 25
+    assert sample(blocks, worker="a77", state="skipped") == 23

@@ -371,7 +371,10 @@ def test_pangus_step_programs_fit_the_chip_and_copy_no_pool(one_chip,
         forward_hybrid_decode,
         moe_stats_size,
     )
-    from dynamo_tpu.ops.paged_attention import paged_attention_decode_latent
+    from dynamo_tpu.ops.paged_attention import (
+        paged_attention,
+        paged_attention_decode_latent,
+    )
 
     cfg, params, cache = _pangu_programs(one_chip)
     n, width = PANGU["rows"], PANGU["width"]
@@ -402,7 +405,9 @@ def test_pangus_step_programs_fit_the_chip_and_copy_no_pool(one_chip,
         kv, state = cache
         kv, state, last, stats = forward_hybrid(
             params, cfg, tokens, positions, kv, state, slots, tables,
-            kv_lens, valid, last_idx, gmm_path="pallas")
+            kv_lens, valid, last_idx, gmm_path="pallas",
+            attention_fn=functools.partial(paged_attention,
+                                           interpret=False))
         return ((kv, state), *sample_with_logprobs(
             last, temperature, top_p, top_k, seeds, jnp.int32(0)), stats)
 
@@ -429,6 +434,8 @@ def test_pangus_step_programs_fit_the_chip_and_copy_no_pool(one_chip,
             vec(1, jnp.int32)).compile()
     text, memory = compiled.as_text(), compiled.memory_analysis()
     assert "tpu_custom_call" in text  # the experts' grouped matmul at least
+    # a latent layer's prefill is its own: `attention_fn` never sees it
+    assert "paged_prefill_attention_pool" not in text
     assert _copies(text, pool_bytes // 5) == []  # not even one layer's
     assert memory.temp_size_in_bytes < (1.0e9 if program == "decode-block"
                                         else 2.0e9)
@@ -453,3 +460,189 @@ def test_the_latent_decode_kernel_compiles_for_v5e(one_chip, width):
     assert "tpu_custom_call" in compiled.as_text()
     # the pool is read in place: no row-major copy in front of the call
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# `paged_prefill_attention_pool` (query rows a launch, positions a row, kv
+# heads, group, int8 pool, table width): the flagship cell's widest and
+# narrowest launches, the hybrid's attention layers (32 heads over 2,
+# bf16) at its shortest bucket, a bf16 pool of 8 kv heads.
+PREFILL_CASES = {
+    "q8-4x1024-w64": (4, 1024, 8, 4, True, 64),
+    "q8-8x128-w64": (8, 128, 8, 4, True, 64),
+    "bf16-g16-8x128-w64": (8, 128, 2, 16, False, 64),
+    "bf16-2x1024-w64": (2, 1024, 8, 4, False, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PREFILL_CASES))
+def test_the_prefill_attention_kernel_compiles_for_v5e(one_chip, case):
+    from dynamo_tpu.ops.paged_attention import (
+        paged_prefill_attention_pool,
+        prefill_kernel_tiles,
+    )
+
+    rows, t, kh, g, quantized, width = PREFILL_CASES[case]
+    n_pages = 5120
+    pool_dtype = jnp.int8 if quantized else jnp.bfloat16
+    assert prefill_kernel_tiles(t, kh * g, kh, HEAD_DIM, PAGE, width,
+                                pool_dtype, LANES if quantized else None)
+    args = [
+        _shape(one_chip, (rows, t, kh * g, HEAD_DIM), jnp.bfloat16),
+        _shape(one_chip, (LAYERS, 2, n_pages, PAGE, kh, HEAD_DIM),
+               pool_dtype),
+        _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (rows, width), jnp.int32),
+        _shape(one_chip, (rows,), jnp.int32),
+        _shape(one_chip, (rows,), jnp.int32),
+    ]
+    if quantized:
+        args.append(_shape(one_chip, (LAYERS, 2, n_pages, PAGE, LANES),
+                           jnp.bfloat16))
+    compiled = paged_prefill_attention_pool.lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "paged_prefill_attention_pool" in text
+    # a device trace tells it from the decode kernels by its name's head
+    assert not re.search(r"paged_decode_attention\w* = ", text)
+    # the pool is read in place: nothing the size of a layer's K or V
+    assert _copies(text, n_pages * PAGE * kh * HEAD_DIM) == []
+
+
+def test_the_dense_prefill_program_holds_the_kernel_and_no_score_tensor(
+        one_chip, monkeypatch):
+    """The flagship cell's `[4, 1024]` prefill program (mistral-7b, int4
+    weights, int8 pool of 5120 pages, 64-page tables; 2 of its 32
+    layers) as `ModelRunner._build_prefill` builds it around the default
+    `attention_fn`: attention is the Mosaic kernel, no float32 array of
+    rows x positions x heads x table tokens exists (XLA's path held
+    `f32[4,1024,8,4,1024]`, 537 MB, written and read four or five times
+    a layer: PERF.md, PR 39), no layer's pool is sliced out of the cache
+    (`s8[5120,16,8,128]`: ROADMAP A6), and the temporaries are the
+    all-position float32 logits (fault 3: 537 MB) and little else."""
+    import functools
+
+    import dynamo_tpu.ops.q4_linear as q4_linear
+    from dynamo_tpu.engine.sampler import sample_with_logprobs
+    from dynamo_tpu.models.config import cut_config, get_config
+    from dynamo_tpu.models.quantize import quantize_params_int4
+    from dynamo_tpu.models.transformer import (
+        forward,
+        init_params,
+        make_kv_cache_int8,
+    )
+    from dynamo_tpu.ops.paged_attention import paged_attention
+
+    monkeypatch.setattr(q4_linear, "kernel_path", lambda option: "pallas")
+    cfg = cut_config(get_config("mistral-7b"), layers=2)
+    rows, t, n_pages, width = 4, 1024, 5120, 64
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(lambda: quantize_params_int4(
+        init_params(jax.random.PRNGKey(0), cfg), cfg)))
+    kv = on_chip(jax.eval_shape(
+        lambda: make_kv_cache_int8(cfg, n_pages, PAGE)))
+    attention = functools.partial(paged_attention, interpret=False)
+
+    def step(params, kv, tokens, positions, tables, kv_lens, valid,
+             last_idx, temperature, top_p, top_k, seeds):
+        kv, logits = forward(params, cfg, tokens, positions, kv, tables,
+                             kv_lens, valid=valid, attention_fn=attention)
+        last = jnp.take_along_axis(
+            logits, last_idx[:, None, None], axis=1)[:, 0, :]
+        return (kv, *sample_with_logprobs(
+            last, temperature, top_p, top_k, seeds, jnp.int32(0)))
+
+    def vec(dtype):
+        return _shape(one_chip, (rows,), dtype)
+
+    def chunk(dtype):
+        return _shape(one_chip, (rows, t), dtype)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, kv, chunk(jnp.int32), chunk(jnp.int32),
+        _shape(one_chip, (rows, width), jnp.int32), vec(jnp.int32),
+        chunk(jnp.bool_), vec(jnp.int32), vec(jnp.float32),
+        vec(jnp.float32), vec(jnp.int32), vec(jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert "paged_prefill_attention_pool" in text and "q4_matmul" in text
+    scores = rows * t * cfg.n_q_heads * width * PAGE
+    for dims in re.findall(r"\bf32\[([\d,]+)\]", text):
+        shape = tuple(int(d) for d in dims.split(","))
+        # the logits have as many elements: 32 heads x 1,024 keys
+        assert (math.prod(shape) < scores
+                or shape[-1] == cfg.vocab_size), shape
+    assert "slice_bitcast_fusion" not in text
+    assert f"s8[{n_pages},{PAGE},{cfg.n_kv_heads},{HEAD_DIM}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.9e9
+
+
+def _lowered_hybrid_prefill(one_chip, name, layers, experts, rows, t,
+                            window_pages=0):
+    """StableHLO text of `forward_hybrid` for a cut of a preset, lowered
+    for the described chip around the default `attention_fn` (nothing is
+    compiled: a kernel call is in the text or it is not)."""
+    import functools
+
+    from dynamo_tpu.models.config import cut_config, get_config
+    from dynamo_tpu.models.hybrid import forward_hybrid, make_state_cache
+    from dynamo_tpu.models.transformer import init_params, make_kv_cache
+    from dynamo_tpu.ops.paged_attention import paged_attention
+
+    cfg = cut_config(get_config(name), layers, experts)
+    width = 64
+
+    def on_chip(make):
+        return jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype),
+                            jax.eval_shape(make))
+
+    params = on_chip(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    kv = on_chip(lambda: make_kv_cache(cfg, 1024, PAGE))
+    state = on_chip(lambda: make_state_cache(cfg, rows))
+    window = None
+    if window_pages:
+        window = (on_chip(lambda: make_kv_cache(cfg, window_pages, PAGE,
+                                                group="window")),
+                  _shape(one_chip, (rows, 128), jnp.int32),
+                  _shape(one_chip, (rows,), jnp.int32))
+
+    def prefill(params, kv, state, tokens, positions, tables, kv_lens,
+                valid, last_idx, slots, window):
+        return forward_hybrid(
+            params, cfg, tokens, positions, kv, state, slots, tables,
+            kv_lens, valid, last_idx, window=window,
+            attention_fn=functools.partial(paged_attention,
+                                           interpret=False))
+
+    def vec(dtype):
+        return _shape(one_chip, (rows,), dtype)
+
+    def chunk(dtype):
+        return _shape(one_chip, (rows, t), dtype)
+
+    return cfg, jax.jit(prefill).lower(
+        params, kv, state, chunk(jnp.int32), chunk(jnp.int32),
+        _shape(one_chip, (rows, width), jnp.int32), vec(jnp.int32),
+        chunk(jnp.bool_), vec(jnp.int32), vec(jnp.int32), window).as_text()
+
+
+def test_a_hybrid_stacks_full_attention_layers_call_the_prefill_kernel(
+        one_chip):
+    cfg, text = _lowered_hybrid_prefill(
+        one_chip, "nemotron3-nano-30b-a3b", 13, "0:8", rows=8, t=128)
+    assert cfg.layer_pattern.count("*") == 2
+    assert "paged_prefill_attention_pool" in text
+
+
+def test_a_stack_with_window_layers_keeps_its_own_prefill_attention(
+        one_chip):
+    """`models/hybrid.prefill_attention` replaces `attention_fn` in a
+    model with window layers, full layers included: its `lax.map` over
+    query blocks goes with a kernel measured in that model's own cell."""
+    cfg, text = _lowered_hybrid_prefill(
+        one_chip, "mellum2-12b-a2.5b", 4, None, rows=1, t=1024,
+        window_pages=512)
+    assert cfg.layer_pattern == "WEWEWE*E"
+    assert "paged_prefill_attention_pool" not in text
