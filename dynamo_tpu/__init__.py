@@ -22,4 +22,9 @@ Layer map (mirrors reference layers, see SURVEY.md section 1):
   planner/    SLA autoscaler           (ref: components/planner)
 """
 
+import time as _time
+
 __version__ = "0.1.0"
+# Where the kernel's own record of this process's start cannot be read,
+# the cold-start ladder counts from here (engine/coldstart.py).
+IMPORTED_AT = _time.monotonic()

@@ -1,7 +1,9 @@
 """Cold-start ladder — arrival-side observability (docs/elasticity.md).
 
-A joining worker walks `fetch -> load -> compile -> register ->
-first_token`; each rung is stamped into the flight recorder and the
+A joining worker walks `boot -> fetch -> load -> compile -> register ->
+first_token`, from the process's own start (`boot`: interpreter,
+imports, JAX's start, the native build, up to the engine build's first
+line); each rung is stamped into the flight recorder and the
 `dynamo_coldstart_*` metric families, and the completed total feeds the
 planner as SCALE-UP LEAD TIME: a planner that knows arrivals take T
 seconds projects demand T seconds ahead, so capacity lands when the
@@ -14,10 +16,12 @@ exact code chip-free.
 from __future__ import annotations
 
 import contextlib
+import os
 import threading
 import time
 from typing import Optional
 
+from .. import IMPORTED_AT
 from ..runtime import conformance
 from ..runtime.flight_recorder import get_recorder
 from ..runtime.logging import get_logger
@@ -29,7 +33,25 @@ from ..runtime.metrics import (
 
 log = get_logger("engine.coldstart")
 
-PHASES = ("fetch", "load", "compile", "register", "first_token")
+PHASES = ("boot", "fetch", "load", "compile", "register", "first_token")
+
+
+def process_started() -> float:
+    """This process's start on `time.monotonic()`'s clock, as the kernel
+    has it (`/proc/self/stat` field 22 against `/proc/uptime`); the
+    package's import time where that cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command may hold spaces and parentheses: split behind it
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        age = uptime - ticks / os.sysconf("SC_CLK_TCK")
+        if age >= 0:
+            return time.monotonic() - age
+    except (OSError, ValueError, IndexError):
+        pass
+    return IMPORTED_AT
 
 # Latest completed ladder totals, process-wide: the planner's lead-time
 # source and the chaos/bench assertions' read side. Guarded by a lock —
@@ -71,16 +93,25 @@ def reset_observations() -> None:
 
 
 class ColdStartLadder:
-    """One worker's walk up the arrival ladder. Phases may be stamped
-    with the `phase` context manager or recorded directly with `mark`
-    (the mocker's modeled walk); `first_token()` closes the ladder."""
+    """One worker's walk up the arrival ladder, from the process's
+    start: the seconds before the ladder was made are its first phase,
+    `boot`. Phases may be stamped with the `phase` context manager or
+    recorded directly with `mark`; `first_token()` closes the ladder.
+    A modeled walk (the mocker's, whose process is no arrival's) passes
+    `started`, the monotonic time its walk begins, and marks its own
+    `boot`."""
 
-    def __init__(self, worker: str, source: str = "unknown") -> None:
+    def __init__(self, worker: str, source: str = "unknown",
+                 started: Optional[float] = None) -> None:
         self.worker = worker
         self.source = source        # weights source the fetch resolved
-        self.started = time.monotonic()
         self.phases: dict[str, float] = {}
         self.total: Optional[float] = None
+        if started is None:
+            self.started = process_started()
+            self.mark("boot", max(0.0, time.monotonic() - self.started))
+        else:
+            self.started = started
 
     @contextlib.contextmanager
     def phase(self, name: str):

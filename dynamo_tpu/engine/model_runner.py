@@ -16,6 +16,7 @@ under XLA static shapes", SURVEY section 7).
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import os
@@ -57,32 +58,154 @@ MOE_PHASES = ("prefill", "decode")
 # thread. Steady-state decode must hold the counter flat; the
 # retrace-canary tier-1 test asserts the observed set is bounded and
 # matches what the checked-in jit-signature registry predicts.
+#
+# A build is a span, recorded where jax reports it: the three duration
+# events of a compile (trace, jaxpr -> MLIR, backend) and the persistent
+# cache's hit / miss fire on the compiling thread, inside the
+# `compile_scope` the runner entry opened there, and close into one
+# record of the ring `/debug/programs` serves. The scope's key is the
+# entry and the static shape that selects the compiled program
+# (`program_key`): the name a program is built under is the name it is
+# launched under (`ModelRunner.program_launches`).
 
 _COMPILE_SCOPE = threading.local()
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
 _LISTENER_LOCK = threading.Lock()
 _LISTENER_INSTALLED = False
 
+# The last builds of this process, oldest first, and how many ever
+# closed. Builds land from the start-up thread and the scheduler
+# threads (one a replica), `/debug/programs` reads on the loop.
+BUILD_RING = 512
+_BUILDS: collections.deque = collections.deque(maxlen=BUILD_RING)
+_BUILDS_LOCK = threading.Lock()
+_builds_total = 0
 
-def _on_compile_event(event: str, duration: float, **_kw) -> None:
-    if event != _COMPILE_EVENT:
+
+def program_key(fn: str, *parts) -> str:
+    """`fn[part,part]`: a runner entry and the static shape that selects
+    its compiled program (`prefill_batch[4x1024]`, `decode[w128]`)."""
+    return f"{fn}[{','.join(str(p) for p in parts if p != '')}]"
+
+
+class _Build:
+    """What jax reported on one thread between a scope's opening and its
+    close (or, outside any scope, up to one backend event)."""
+
+    def __init__(self, fn: str, key: str, cause) -> None:
+        self.fn, self.key, self.cause = fn, key, cause
+        self.t_start = time.time()
+        self.trace_s = self.lower_s = self.backend_s = 0.0
+        self.backends = 0
+        self.cache: Optional[str] = None
+        self.hit = False  # a cache hit since the last backend event
+        self._traces: list[tuple[float, float]] = []
+
+    def trace(self, duration: float) -> float:
+        """Seconds of this trace not yet counted: a jitted function
+        traced inside another's trace reports first, and the outer
+        one's duration holds it."""
+        start = time.monotonic() - duration
+        inner = 0.0
+        while self._traces and self._traces[-1][0] >= start:
+            inner += self._traces.pop()[1]
+        self._traces.append((start, duration))
+        return max(0.0, duration - inner)
+
+    def record(self) -> dict:
+        return {"fn": self.fn, "key": self.key, "cause": self.cause,
+                "t_start": round(self.t_start, 3),
+                "t_end": round(time.time(), 3),
+                "trace_s": round(self.trace_s, 4),
+                "lower_s": round(self.lower_s, 4),
+                "backend_s": round(self.backend_s, 4),
+                "backends": self.backends, "cache": self.cache or "off"}
+
+
+def _append_build(build: _Build) -> dict:
+    global _builds_total
+    rec = build.record()
+    with _BUILDS_LOCK:
+        _BUILDS.append(rec)
+        _builds_total += 1
+    return rec
+
+
+def _open_build() -> _Build:
+    """The build events on this thread belong to: the open scope's, or
+    one kept for what compiles outside any scope."""
+    build = getattr(_COMPILE_SCOPE, "build", None)
+    if build is None:
+        build = getattr(_COMPILE_SCOPE, "loose", None)
+        if build is None:
+            build = _COMPILE_SCOPE.loose = _Build("unscoped", "", "unscoped")
+    return build
+
+
+def _on_compile_event(event: str, duration: float, **kw) -> None:
+    if event not in (_TRACE_EVENT, _LOWER_EVENT, _COMPILE_EVENT):
         return
-    from ..runtime.metrics import JIT_COMPILE_SECONDS, JIT_COMPILES
+    from ..runtime.metrics import (
+        JIT_COMPILE_SECONDS,
+        JIT_COMPILES,
+        JIT_STAGE_SECONDS,
+    )
 
-    label = getattr(_COMPILE_SCOPE, "label", None) or "unscoped"
-    JIT_COMPILES.labels(fn=label).inc()
-    JIT_COMPILE_SECONDS.labels(fn=label).inc(duration)
+    build = _open_build()
+    if event == _TRACE_EVENT:
+        fresh = build.trace(duration)
+        build.trace_s += fresh
+        JIT_STAGE_SECONDS.labels(fn=build.fn, stage="trace").inc(fresh)
+        return
+    if event == _LOWER_EVENT:
+        build.lower_s += duration
+        JIT_STAGE_SECONDS.labels(fn=build.fn, stage="lower").inc(duration)
+        return
+    # The backend event wraps compile_or_get_cached: its whole duration
+    # is a cache load if the cache reported a hit on this thread since
+    # the last one, a compile otherwise, so the stages never overlap.
+    stage = "cache_load" if build.hit else "compile"
+    build.hit = False
+    build._traces.clear()
+    build.backend_s += duration
+    build.backends += 1
+    JIT_COMPILES.labels(fn=build.fn).inc()
+    JIT_COMPILE_SECONDS.labels(fn=build.fn).inc(duration)
+    JIT_STAGE_SECONDS.labels(fn=build.fn, stage=stage).inc(duration)
+    if build is getattr(_COMPILE_SCOPE, "loose", None):
+        # no scope will close it: one record a program, named by jax
+        build.key = str(kw.get("fun_name", ""))
+        _COMPILE_SCOPE.loose = None
+        _append_build(build)
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    outcome = _CACHE_EVENTS.get(event)
+    if outcome is None:
+        return
+    from ..runtime.metrics import COMPILE_CACHE
+
+    COMPILE_CACHE.labels(outcome=outcome).inc()
+    build = _open_build()
+    build.hit = outcome == "hit"
+    if build.cache != "miss":
+        build.cache = outcome
 
 
 def _install_compile_listener() -> None:
     """Idempotent process-wide registration (jax.monitoring listeners
-    cannot be unregistered individually; one is enough)."""
+    cannot be unregistered individually; one of each kind is enough)."""
     global _LISTENER_INSTALLED
     with _LISTENER_LOCK:
         if _LISTENER_INSTALLED:
             return
         jax.monitoring.register_event_duration_secs_listener(
             _on_compile_event)
+        jax.monitoring.register_event_listener(_on_cache_event)
         _LISTENER_INSTALLED = True
 
 
@@ -97,18 +220,47 @@ COMPILE_STALL_SECS = 2.0
 
 
 @contextlib.contextmanager
-def compile_scope(label: str):
+def compile_scope(label: str, key: str, cause=None):
     """Attribute any XLA compile fired inside the block to `label`, and
-    mark this thread as inside that entry's dispatch."""
-    prev = getattr(_COMPILE_SCOPE, "label", None)
-    _COMPILE_SCOPE.label = label
+    mark this thread as inside that entry's dispatch. If anything was
+    built, the scope closes into one record of the build ring under
+    `key`; `cause` names the warm-up pass that asked for it, and a
+    launch's build (no cause) is kept for `take_builds`."""
+    prev = getattr(_COMPILE_SCOPE, "build", None)
+    build = _COMPILE_SCOPE.build = _Build(label, key, cause or "launch")
     ident = threading.get_ident()
     _IN_DISPATCH[ident] = (label, time.monotonic())
     try:
         yield
     finally:
-        _COMPILE_SCOPE.label = prev
+        _COMPILE_SCOPE.build = prev
         _IN_DISPATCH.pop(ident, None)
+        if build.backends or build.trace_s or build.lower_s:
+            rec = _append_build(build)
+            if cause is None:
+                fresh = getattr(_COMPILE_SCOPE, "fresh", None)
+                if fresh is None:
+                    fresh = _COMPILE_SCOPE.fresh = collections.deque(
+                        maxlen=16)
+                fresh.append(rec)
+
+
+def take_builds() -> Sequence[dict]:
+    """Records of the launches' builds that closed on this thread since
+    the last call (the scheduler names their requests as the cause)."""
+    fresh = getattr(_COMPILE_SCOPE, "fresh", None)
+    if not fresh:
+        return ()
+    _COMPILE_SCOPE.fresh = None
+    return fresh
+
+
+def programs_snapshot() -> dict:
+    """The build ring, oldest first, and how many builds ever closed
+    (more than the ring holds once the oldest have left it)."""
+    with _BUILDS_LOCK:
+        return {"builds": list(_BUILDS), "builds_total": _builds_total,
+                "ring": BUILD_RING}
 
 
 def compiling(thread_ident: Optional[int]) -> Optional[tuple[str, float]]:
@@ -475,6 +627,11 @@ class ModelRunner:
         # both x latent layers.
         self.latent_decode_tokens = 0
         self.latent_prefill_expand_tokens = 0
+        # Launches by program (dynamo_program_launches, _tokens):
+        # (entry, key) -> [launches, useful prompt tokens] of served
+        # traffic; a warm-up pass lists the keys it walks at 0.
+        self.program_launches: dict[tuple[str, str], list[int]] = {}
+        self._warming: Optional[str] = None  # "warmup" | "prewarm"
 
     def _kv_cache_sharding(self, mesh: Mesh):
         """Sharding of `kv_cache` as the step programs donate it: one
@@ -522,6 +679,36 @@ class ModelRunner:
         if self._latent:
             self.latent_prefill_expand_tokens += int(
                 sum(kv_lens)) * len(self.model_config.kv_layers)
+
+    def _program(self, fn: str, *shape, tokens: int = 0, cause=None):
+        """One launch of entry `fn`'s compiled program for the static
+        `shape` (`program_key`): counted under that key unless a warm-up
+        pass makes it, and whatever it builds recorded under the same
+        key (`compile_scope`). `cause` names a launch no scheduler
+        dispatch stands behind."""
+        key = program_key(fn, *shape)
+        row = self.program_launches.setdefault((fn, key), [0, 0])
+        if self._warming is None:
+            row[0] += 1
+            row[1] += tokens
+        return compile_scope(fn, key, cause=self._warming or cause)
+
+    def _table_widths(self, tables) -> tuple:
+        """A decode program's key parts for its tables (`_table_args`):
+        the width it was traced at and, for a model with two page
+        groups, its window table's."""
+        if self._windowed:
+            return (f"w{tables[0].shape[-1]}", f"ww{tables[1].shape[-1]}")
+        return (f"w{tables.shape[-1]}",)
+
+    @contextlib.contextmanager
+    def _warm(self, cause: str):
+        """Launches inside are a warm-up pass's: built, not counted."""
+        prev, self._warming = self._warming, self._warming or cause
+        try:
+            yield
+        finally:
+            self._warming = prev
 
     def prefill_attention_tiles(self, bucket: int):
         """(query positions a block, key tokens a chunk) where the
@@ -927,7 +1114,11 @@ class ModelRunner:
             if lora_idx is None:
                 lora_idx = np.zeros(len(tokens), np.int32)
             args += [self.lora_pack, jnp.asarray(lora_idx, jnp.int32)]
-        with compile_scope("decode_multi"):
+        # tokens from the host or from the block before, still on the
+        # device (the pipelined second block): two jit keys a width
+        fed = "chained" if isinstance(tokens, jax.Array) else "fed"
+        with self._program("decode_multi", *self._table_widths(args[2]),
+                           f"b{k}", fed):
             (toks_k,) = self._launch(fn, args)
         self.last_decode_sample = (None, None, None)
         if return_device:
@@ -1037,15 +1228,17 @@ class ModelRunner:
             if lora_idx is None:
                 lora_idx = np.zeros(b, np.int32)
             args += [self.lora_pack, jnp.asarray(lora_idx, jnp.int32)]
+        shape = (*self._table_widths(args[4]), f"k{k}",
+                 "logits" if want_logits else "")
         if want_logits:
-            with compile_scope("decode_spec"):
+            with self._program("decode_spec", *shape):
                 self.kv_cache, targets, n_accept, logits = fn(*args)
             if return_device:
                 self.last_spec_logits = logits
                 return targets, n_accept
             self.last_spec_logits = np.asarray(logits)  # dynajit: disable=DJ201 -- processor-slot raw rows; paid only by want_logits steps
         else:
-            with compile_scope("decode_spec"):
+            with self._program("decode_spec", *shape):
                 self.kv_cache, targets, n_accept = fn(*args)
             self.last_spec_logits = None
             if return_device:
@@ -1191,7 +1384,8 @@ class ModelRunner:
         top_p = np.asarray([s[1] for s in samplings], np.float32)
         top_k = np.asarray([s[2] for s in samplings], np.int32)
         seeds = np.asarray([s[3] for s in samplings], np.uint32)
-        with compile_scope("prefill_ring"):
+        with self._program("prefill_ring", f"{b}x{bucket}",
+                           tokens=sum(len(p) for p in prompts)):
             self.kv_cache, token, lp, top_ids, top_lps = fn(
                 self.params, self.kv_cache, jnp.asarray(tok),
                 jnp.asarray(pos),
@@ -1245,7 +1439,7 @@ class ModelRunner:
         tok[0, :t] = tokens
         valid = np.zeros((1, bucket), bool)
         valid[0, :t] = True
-        with compile_scope("embed"):
+        with self._program("embed", bucket, cause="embed"):
             out = fn(self.params, tokens=jnp.asarray(tok),
                      valid=jnp.asarray(valid))
         return np.asarray(out)[0]
@@ -1373,7 +1567,7 @@ class ModelRunner:
                         (1, bucket, self.model_config.hidden), jnp.float32)
                     self._zero_embeds[bucket] = zeros
                 kwargs["extra_embeds"] = zeros
-        with compile_scope("prefill"):
+        with self._program("prefill", bucket, tokens=t):
             token, lp, top_ids, top_lps = self._launch(fn, args, kwargs,
                                                        phase="prefill")
         if return_device:
@@ -1467,7 +1661,8 @@ class ModelRunner:
                     (b, bucket, self.model_config.hidden), jnp.float32)
                 self._zero_embeds[(b, bucket)] = zeros
             kwargs["extra_embeds"] = zeros
-        with compile_scope("prefill_batch"):
+        with self._program("prefill_batch", f"{b}x{bucket}",
+                           tokens=sum(len(r[0]) for r in rows)):
             token, lp, top_ids, top_lps = self._launch(fn, args, kwargs,
                                                        phase="prefill")
         if want_samples:
@@ -1522,11 +1717,13 @@ class ModelRunner:
             if lora_idx is None:
                 lora_idx = np.zeros(len(tokens), np.int32)
             args += [self.lora_pack, jnp.asarray(lora_idx, jnp.int32)]
+        shape = (*self._table_widths(args[2]),
+                 "logits" if want_logits else "lp" if want_logprobs else "")
         if want_logits:
             if self._decode_fn_logits is None:
                 self._decode_fn_logits = self._build_decode(
                     with_logits=True)
-            with compile_scope("decode"):
+            with self._program("decode", *shape):
                 next_tokens, logits = self._launch(
                     self._decode_fn_logits, args)
             self.last_decode_logits = np.asarray(logits)  # dynajit: disable=DJ201 -- logits-processor escape hatch: host sampling needs the raw rows now
@@ -1534,14 +1731,14 @@ class ModelRunner:
         elif want_logprobs:
             if self._decode_fn_lp is None:
                 self._decode_fn_lp = self._build_decode(True)
-            with compile_scope("decode"):
+            with self._program("decode", *shape):
                 next_tokens, lp, top_ids, top_lps = self._launch(
                     self._decode_fn_lp, args)
             self.last_decode_sample = (np.asarray(lp), np.asarray(top_ids),  # dynajit: disable=DJ201 -- logprobs path: per-step sample data is the request's contract
                                        np.asarray(top_lps))  # dynajit: disable=DJ201 -- same logprobs drain
             self.last_decode_logits = None
         else:
-            with compile_scope("decode"):
+            with self._program("decode", *shape):
                 (next_tokens,) = self._launch(self._decode_fn, args)
             self.last_decode_sample = (None, None, None)
             self.last_decode_logits = None
@@ -1785,17 +1982,18 @@ class ModelRunner:
         """Compile decode + smallest prefill bucket ahead of traffic."""
         b = self.config.max_batch
         p = self.config.max_pages_per_seq
-        self.decode(
-            np.zeros(b, np.int32), np.zeros(b, np.int32),
-            self._idle_tables(b, p), np.zeros(b, np.int32),
-            np.zeros(b, bool), np.ones(b, np.float32),
-            np.ones(b, np.float32), np.zeros(b, np.int32),
-            np.zeros(b, np.uint32),
-        )
-        self.prefill_chunk(
-            np.zeros(1, np.int32), 0, np.zeros(p, np.int32), 1,
-            (0.0, 1.0, 0, 0), **self._idle_row(),
-        )
+        with self._warm("warmup"):
+            self.decode(
+                np.zeros(b, np.int32), np.zeros(b, np.int32),
+                self._idle_tables(b, p), np.zeros(b, np.int32),
+                np.zeros(b, bool), np.ones(b, np.float32),
+                np.ones(b, np.float32), np.zeros(b, np.int32),
+                np.zeros(b, np.uint32),
+            )
+            self.prefill_chunk(
+                np.zeros(1, np.int32), 0, np.zeros(p, np.int32), 1,
+                (0.0, 1.0, 0, 0), **self._idle_row(),
+            )
 
     def _idle_row(self) -> dict:
         """What a warm-up prefill row passes beside its scratch table: a
@@ -1836,7 +2034,13 @@ class ModelRunner:
         these (PERF.md, PR 25 fault 4 and PR 30): which shape a crafted
         group lands on depends on when its rows arrive. Nothing real is
         touched: every row's pages are the scratch page, its state slot
-        is past the end, no decode row is active."""
+        is past the end, no decode row is active. The launches go
+        through the served entries and so under the served program keys
+        (`/debug/programs`, cause `prewarm`), and count as no launch."""
+        with self._warm("prewarm"):
+            self._prewarm(spec_widths, launches, block)
+
+    def _prewarm(self, spec_widths, launches: bool, block: int) -> None:
         self.warmup()
         b = self.config.max_batch
         p = self.config.max_pages_per_seq

@@ -42,7 +42,7 @@ from ..perf.steptrace import StepTrace, annotation
 from ..runtime.flight_recorder import get_recorder
 from ..runtime.logging import get_logger
 from ..tokens import TokenBlockSequence, compute_block_hashes
-from .model_runner import ModelRunner, bucket_table_width
+from .model_runner import ModelRunner, bucket_table_width, take_builds
 from .pages import PageAllocation, PagePool, WindowLease, WindowPool
 from .spec import BlockLookahead, NGramProposer, SlotSpec, propose_for
 
@@ -1377,6 +1377,7 @@ class InferenceScheduler:
                       s.request.sampling.top_p,
                       s.request.sampling.top_k, s.seed) for s in ring],
                 )
+            self._stamp_builds(ring)
             for seq in ring:
                 seq.device_prefill_ms += rsc.device_ms
             samples = getattr(self.runner, "last_prefill_samples",
@@ -1539,6 +1540,7 @@ class InferenceScheduler:
                 **({"window": self._window_arg(seq)} if self._windowed
                    else {}),
             )
+        self._stamp_builds((seq,))
         if not deferred_readback:
             # Device-stream completion window of the whole prompt pass:
             # first chunk dispatched -> final token materialized.
@@ -1561,6 +1563,24 @@ class InferenceScheduler:
         else:
             self._stream_prefill_chunk(seq)
         return chunk
+
+    def _stamp_builds(self, seqs) -> None:
+        """After a dispatch: if it built a program (a first launch of its
+        shape, or a retrace), the launch's requests are the cause, and
+        each timeline says so (`program_built` in `/debug/requests`: why
+        this request's stage took the seconds it did)."""
+        built = take_builds()
+        if not built:
+            return
+        ids = [s.record_id for s in seqs
+               if s is not None and s.record_id is not None]
+        for rec in built:
+            rec["cause"] = ids or rec["cause"]
+            seconds = round(
+                rec["trace_s"] + rec["lower_s"] + rec["backend_s"], 3)
+            for rid in ids:
+                get_recorder().event(rid, "program_built", fn=rec["fn"],
+                                     key=rec["key"], seconds=seconds)
 
     def _prefill_batch(self, work: list) -> int:
         """Dispatch several sequences' prefill chunks in ONE compiled
@@ -1598,6 +1618,7 @@ class InferenceScheduler:
         with self.steptrace.dispatch("prefill", self.stats.steps):
             toks_dev = self.runner.prefill_chunk_batch(
                 rows, want_samples=want_samples)
+        self._stamp_builds([seq for seq, _chunk in work])
         samples = (self.runner.last_prefill_samples
                    if want_samples else [None] * len(work))
         self.stats.prefill_batched_steps += 1
@@ -1843,6 +1864,7 @@ class InferenceScheduler:
                         lora_idx=self._lora_idx, return_device=True,
                     )
                     device_blocks.append(toks_dev)
+            self._stamp_builds(ready)
             self.stats.decode_block_launches += depth
             return ("blocks", device_blocks, ready, block)
         return ("count",
@@ -1991,6 +2013,7 @@ class InferenceScheduler:
                 lora_idx=self._lora_idx, want_logits=want_logits,
                 return_device=True,
             )
+        self._stamp_builds(ready)
         return ("spec", targets, n_acc, ready, drafts, want_logits)
 
     def _drain_spec(self, pending) -> int:
@@ -2116,6 +2139,7 @@ class InferenceScheduler:
                 want_logprobs=want_logprobs and not want_logits,
                 want_logits=want_logits,
             )
+        self._stamp_builds(ready)
         for seq in ready:
             seq.device_decode_ms += sc.device_ms
         lp_b, tid_b, tlp_b = getattr(self.runner, "last_decode_sample",

@@ -1557,6 +1557,7 @@ class TpuWorker:
         dynamo_engine_positions, dynamo_prefill_row_blocks_total,
         dynamo_prefill_attn_launches_total, dynamo_prefill_attn_blocks_total,
         dynamo_kv_reserved_page_ms, dynamo_kv_window_*, dynamo_latent_*,
+        dynamo_program_launches, dynamo_program_tokens,
         dynamo_device_hbm_bytes)."""
         from ..runtime.metrics import (
             DEVICE_HBM_BYTES,
@@ -1579,6 +1580,8 @@ class TpuWorker:
             PREFILL_ATTN_BLOCKS,
             PREFILL_ATTN_LAUNCHES,
             PREFILL_ROW_BLOCKS,
+            PROGRAM_LAUNCHES,
+            PROGRAM_TOKENS,
             SSM_STATE_SLOT_MS,
         )
 
@@ -1608,6 +1611,14 @@ class TpuWorker:
         for state, count in getattr(
                 self.runner, "prefill_attn_blocks", {}).items():
             PREFILL_ATTN_BLOCKS.labels(worker=worker, state=state).set(count)
+        # a snapshot: the scheduler thread adds keys as it launches
+        for (fn, key), (launches, tokens) in list(getattr(
+                self.runner, "program_launches", {}).items()):
+            PROGRAM_LAUNCHES.labels(worker=worker, fn=fn, key=key).set(
+                launches)
+            if fn.startswith("prefill"):
+                PROGRAM_TOKENS.labels(worker=worker, fn=fn, key=key).set(
+                    tokens)
         KV_RESERVED_PAGE_MS.labels(worker=worker).set(
             stats.reserved_page_ms)
         win_pool = self.scheduler.win_pool

@@ -74,13 +74,14 @@ class MockerConfig:
     kv_transfer_us_per_block: float = 0.0
     # -- cold-start model (fast-start plane, docs/elasticity.md) ----------
     # With coldstart=True, MockerWorker.start() walks the real arrival
-    # ladder (fetch -> load -> compile -> register) with the modeled
+    # ladder (boot -> fetch -> load -> compile -> register) with the modeled
     # latencies below before registering endpoints, stamping the same
     # dynamo_coldstart_* metric families TpuWorker does — so cold-start
     # A/Bs (striped vs single-source fetch, warm vs cold compile cache)
     # and the chaos-spot evict+replace scenario run chip-free. Sleeps
     # divide by speedup_ratio like every other mocker latency.
     coldstart: bool = False
+    boot_ms: float = 0.0                 # process start -> engine build
     weight_bytes: float = 1.4e9          # weight tree size to fetch
     fetch_striped: bool = True           # peer-striped vs single-source
     fetch_donors: int = 4
@@ -115,6 +116,7 @@ def coldstart_phases(cfg: MockerConfig) -> dict[str, float]:
     compile_ms = (cfg.compile_warm_ms if cfg.compile_cache_warm
                   else cfg.compile_cold_ms)
     return {
+        "boot": cfg.boot_ms / 1e3,
         "fetch": cfg.weight_bytes * 8 / (rate_gbps * 1e9),
         "load": cfg.load_ms / 1e3,
         "compile": compile_ms / 1e3,
@@ -178,6 +180,7 @@ TIMING_PRESETS: dict[str, dict] = {
         prefill_us_per_token=113.0,
         block_size=16,
         coldstart=True,
+        boot_ms=8000.0,
         weight_bytes=1.4e9,
         fetch_donors=4,
         fetch_gbps_per_donor=12.0,

@@ -91,10 +91,11 @@ class MockerWorker:
         self.coldstart = ColdStartLadder(
             f"{self.instance_id:x}",
             source=("peer_striped" if self.config.fetch_striped
-                    else "object_store"))
+                    else "object_store"),
+            started=time.monotonic())  # modeled: this process is no arrival
         phases = coldstart_phases(self.config)
         scale = max(self.config.speedup_ratio, 1e-9)
-        for name in ("fetch", "load", "compile", "register"):
+        for name in ("boot", "fetch", "load", "compile", "register"):
             secs = phases[name] / scale
             await asyncio.sleep(secs)
             self.coldstart.mark(name, secs)

@@ -34,10 +34,9 @@ from ITS OWN submit end this step to its drain end — a readback of work
 submitted last step (deferred prefill tokens) contributes only its
 blocked-wait slice, keeping every window inside the step wall.
 
-The same definitions serve the kernel ablation harness
-(`measure_device`) and the live MFU / roofline gauges (`LiveRoofline`
-vs `profiler/timing_model.py`), so ablation numbers, serving metrics,
-and analytical-model comparisons share ONE measurement meaning.
+The same definitions serve the live MFU / roofline gauges
+(`LiveRoofline` vs `profiler/timing_model.py`), so serving metrics and
+analytical-model comparisons share ONE measurement meaning.
 """
 
 from __future__ import annotations
@@ -74,27 +73,6 @@ def annotation(phase: str, step: Optional[int] = None,
     if step is None:
         return profiler.StepTraceAnnotation(phase)
     return profiler.StepTraceAnnotation(phase, step_num=step)
-
-
-def measure_device(fn: Callable[[], object], steps: int = 16,
-                   trials: int = 3) -> dict:
-    """THE timing definition shared by the kernel ablation harness and
-    bench decomposition columns: dispatch `fn` `steps` times, block on
-    the LAST result only (the device queue serializes the rest), median
-    over `trials`. Returns per-call seconds so ablation numbers and live
-    serving numbers mean the same thing."""
-    import jax
-
-    timed = []
-    for _ in range(trials):
-        t0 = time.perf_counter()
-        out = None
-        for _ in range(steps):
-            out = fn()
-        jax.block_until_ready(out)
-        timed.append((time.perf_counter() - t0) / steps)
-    return {"median_s": sorted(timed)[len(timed) // 2],
-            "trials_s": timed}
 
 
 @dataclasses.dataclass
@@ -253,7 +231,6 @@ class StepTrace:
         self.steps = 0
         self.device_ms_total = 0.0
         self.host_ms_total = 0.0
-        self.dispatch_ms_total = 0.0
         self.device_ms_by_phase: dict[str, float] = {}
         # persistence streak behind the host-bound verdict
         self._host_over_device = 0
@@ -311,7 +288,6 @@ class StepTrace:
             self.steps += 1
             self.device_ms_total += sample.device_ms
             self.host_ms_total += sample.host_ms
-            self.dispatch_ms_total += sample.dispatch_ms
             for phase, ms in sample.device_by_phase.items():
                 self.device_ms_by_phase[phase] = (
                     self.device_ms_by_phase.get(phase, 0.0) + ms)

@@ -307,6 +307,45 @@ JIT_COMPILE_SECONDS = Counter(
     "a cold start (or a retrace under traffic) costs in time",
     ["fn"], registry=REGISTRY,
 )
+# Where a build's seconds went, from the same listener: jax reports a
+# compile's tracing, its jaxpr -> MLIR lowering and its backend step on
+# the compiling thread; the backend step is a persistent-cache load
+# when the cache reported a hit there, a compile otherwise, so
+# compile + cache_load sums to dynamo_jit_compile_seconds_total per fn.
+JIT_STAGE_SECONDS = Counter(
+    "dynamo_jit_stage_seconds_total",
+    "Seconds of building compiled programs, by ModelRunner entry point "
+    "and stage: trace (a jitted function traced inside another counts "
+    "once), lower (jaxpr to MLIR), compile (backend compile), "
+    "cache_load (backend step served by the persistent compile cache). "
+    "compile + cache_load = dynamo_jit_compile_seconds_total",
+    ["fn", "stage"], registry=REGISTRY,
+)
+COMPILE_CACHE = Counter(
+    "dynamo_compile_cache_total",
+    "Persistent compile cache lookups as jax reports them, by outcome "
+    "(hit | miss); none where no cache directory is set",
+    ["outcome"], registry=REGISTRY,
+)
+# The same key a program is built under counts its launches
+# (ModelRunner.program_launches; warm-up launches list a key at 0): a
+# key that never grows was built for nothing, and tokens over launches x
+# the key's rows x bucket is the padding by shape.
+PROGRAM_LAUNCHES = Gauge(
+    "dynamo_program_launches",
+    "Launches of each compiled program by served traffic since start, "
+    "by ModelRunner entry point and program key (entry[static shape], "
+    "as /debug/programs lists builds); a key a warm-up pass built and "
+    "no request launched reads 0",
+    ["worker", "fn", "key"], registry=REGISTRY,
+)
+PROGRAM_TOKENS = Gauge(
+    "dynamo_program_tokens",
+    "Useful prompt tokens the launches of dynamo_program_launches "
+    "carried (prefill entries only): over launches x the key's rows x "
+    "bucket, the share of a program's positions that was not padding",
+    ["worker", "fn", "key"], registry=REGISTRY,
+)
 # What the engine behind a worker actually runs on (engine/worker.py
 # start-up report): the device, the kernel path each hot-path slot took
 # and whether the native extension loaded — so a reference kernel, the
@@ -661,7 +700,8 @@ HOST_BOUND = Gauge(
 COLDSTART_PHASE_SECONDS = Gauge(
     "dynamo_coldstart_phase_seconds",
     "Seconds this worker's most recent cold start spent in each arrival-"
-    "ladder phase (fetch / load / compile / register / first_token)",
+    "ladder phase (boot / fetch / load / compile / register / "
+    "first_token; boot is process start to the engine build's start)",
     ["worker", "phase"], registry=REGISTRY,
 )
 COLDSTART_TOTAL_SECONDS = Gauge(

@@ -183,6 +183,33 @@ def _timeline_matches(timeline: dict, status: str, tenant: str,
     return True
 
 
+def debug_programs_response(_request: web.Request) -> web.Response:
+    """/debug/programs: the compiled programs this process built, oldest
+    first (`builds`: entry, key, cause, start and end, the seconds jax
+    reported for tracing, lowering and the backend step, backend events
+    inside, the persistent cache's outcome), and each key's launches as
+    the worker last published them (`launches`: the samples of
+    dynamo_program_launches / dynamo_program_tokens). Kept in memory,
+    written out when asked. A process that runs no engine answers with
+    empty lists and imports nothing."""
+    import sys
+
+    runner = sys.modules.get("dynamo_tpu.engine.model_runner")
+    out = (runner.programs_snapshot() if runner is not None
+           else {"builds": [], "builds_total": 0, "ring": 0})
+    launches: dict[tuple, dict] = {}
+    for field, gauge in (("launches", metrics.PROGRAM_LAUNCHES),
+                         ("tokens", metrics.PROGRAM_TOKENS)):
+        for family in gauge.collect():
+            for sample in family.samples:
+                row = launches.setdefault(
+                    tuple(sorted(sample.labels.items())),
+                    dict(sample.labels))
+                row[field] = int(sample.value)
+    out["launches"] = list(launches.values())
+    return web.json_response(out)
+
+
 def debug_requests_response(request: web.Request) -> web.Response:
     """Shared /debug/requests responder: the flight recorder's inflight
     + recently-completed request timelines.
@@ -281,6 +308,9 @@ class SystemStatusServer:
     async def _debug_requests(self, request: web.Request) -> web.Response:
         return debug_requests_response(request)
 
+    async def _debug_programs(self, request: web.Request) -> web.Response:
+        return debug_programs_response(request)
+
     async def _debug_profile(self, request: web.Request) -> web.Response:
         return await profile_response(request)
 
@@ -318,6 +348,7 @@ class SystemStatusServer:
         if env("DYNT_DRAIN_HTTP"):
             app.router.add_post("/drain", self._drain)
         app.router.add_get("/debug/requests", self._debug_requests)
+        app.router.add_get("/debug/programs", self._debug_programs)
         app.router.add_get("/debug/profile", self._debug_profile)
         app.router.add_get("/fleet", self._fleet)
         app.router.add_get("/debug/alerts", self._debug_alerts)

@@ -14,6 +14,7 @@ from dynamo_tpu.engine.coldstart import (
     ColdStartLadder as _Ladder,
     last_cold_start_secs,
     observed_cold_start_secs,
+    process_started,
     reset_observations,
 )
 
@@ -39,6 +40,74 @@ class TestLadder:
         # first_token is the residual: total minus the accounted phases
         assert rep["phases"]["first_token"] is not None
         assert set(rep["phases"]) == set(PHASES)
+
+    def test_boot_comes_first_and_the_phases_sum_to_the_total(
+            self, monkeypatch):
+        """The ladder's clock is the process's: what ran before the
+        ladder was made is `boot`, marked first and once, and the total
+        is process start to first token, the sum of the six phases."""
+        from dynamo_tpu.runtime import conformance
+
+        monkeypatch.setenv("DYNT_CONFORMANCE", "1")
+        conformance.reset_monitor()
+        age = time.monotonic() - process_started()
+        lad = ColdStartLadder("boot-w")
+        assert list(lad.phases) == ["boot"]
+        assert age <= lad.phases["boot"] <= age + 1.0
+        for name in ("fetch", "load", "compile", "register"):
+            with lad.phase(name):
+                time.sleep(0.01)
+        time.sleep(0.01)  # registered, waiting for a client
+        total = lad.first_token()
+        assert list(lad.phases) == list(PHASES) and PHASES[0] == "boot"
+        assert lad.phases["first_token"] >= 0.01
+        assert sum(lad.phases.values()) == pytest.approx(total, abs=1e-3)
+        assert total == pytest.approx(age + 0.05, abs=1.0)
+        # a second boot, behind another rung, is an order the spec refuses
+        late = ColdStartLadder("boot-late", started=time.monotonic())
+        late.mark("fetch", 0.1)
+        assert conformance.get_monitor().snapshot()["total_violations"] == 0
+        late.mark("boot", 0.1)
+        assert conformance.get_monitor().snapshot()["total_violations"] == 1
+        conformance.reset_monitor()
+
+    def test_process_start_is_the_kernels_and_falls_back_to_the_import(
+            self, monkeypatch):
+        import builtins
+        import os
+
+        import dynamo_tpu
+
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        age = uptime - ticks / os.sysconf("SC_CLK_TCK")
+        assert time.monotonic() - process_started() == pytest.approx(
+            age, abs=0.5)
+        # the interpreter started before the package was imported
+        assert process_started() <= dynamo_tpu.IMPORTED_AT
+        real_open = builtins.open
+
+        def no_proc(path, *args, **kwargs):
+            if str(path).startswith("/proc/"):
+                raise OSError("no procfs here")
+            return real_open(path, *args, **kwargs)
+
+        monkeypatch.setattr(builtins, "open", no_proc)
+        assert process_started() == dynamo_tpu.IMPORTED_AT
+
+    def test_a_modeled_walk_starts_where_it_says_and_marks_its_own_boot(
+            self):
+        t0 = time.monotonic()
+        lad = ColdStartLadder("model-w", started=t0)
+        assert lad.phases == {} and lad.started == t0
+        lad.mark("boot", 0.2)
+        lad.mark("fetch", 0.1)
+        total = lad.first_token()
+        # a few milliseconds old, whatever the test process's age
+        assert total < 5.0
+        assert lad.phases["boot"] == 0.2
 
     def test_phase_contextmanager_accumulates(self):
         lad = ColdStartLadder("w2")
@@ -116,7 +185,8 @@ class TestMockerColdStartModel:
         from dynamo_tpu.mocker.engine import coldstart_phases
 
         phases = coldstart_phases(self._cfg())
-        assert set(phases) == {"fetch", "load", "compile", "register"}
+        assert list(phases) == ["boot", "fetch", "load", "compile",
+                                "register"]
         assert all(v > 0 for v in phases.values())
 
     def test_striped_strictly_faster_than_single_source(self):
@@ -146,7 +216,7 @@ class TestMockerColdStartModel:
         from dynamo_tpu.mocker.worker import MockerWorker
         from dynamo_tpu.runtime import DistributedRuntime
 
-        cfg = MockerConfig(coldstart=True, weight_bytes=1e6,
+        cfg = MockerConfig(coldstart=True, boot_ms=15.0, weight_bytes=1e6,
                            fetch_gbps_per_donor=1.0, load_ms=20.0,
                            compile_cache_warm=True, compile_warm_ms=30.0,
                            register_ms=10.0)
@@ -161,8 +231,13 @@ class TestMockerColdStartModel:
             try:
                 rep = worker.coldstart.report()
                 assert rep["total_secs"] is None  # no token served yet
-                for rung in ("fetch", "load", "compile", "register"):
+                for rung in ("boot", "fetch", "load", "compile",
+                             "register"):
                     assert (rep["phases"][rung] or 0.0) > 0.0
+                # modeled: the boot is the configuration's, not the age
+                # of the process the test runs in
+                assert rep["phases"]["boot"] == pytest.approx(0.015)
+                assert list(worker.coldstart.phases)[0] == "boot"
                 # the walk really slept the modeled (scaled) time
                 assert walked >= 0.05
             finally:

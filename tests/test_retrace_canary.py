@@ -197,3 +197,65 @@ class TestRetraceCanary:
             "post-prewarm steady state recompiled — the pre-warm pass "
             "missed part of the predicted key space: "
             f"{_delta(steady, _snapshot())}")
+
+    def test_program_keys_are_stable_and_bounded_by_the_prewarm_grid(self):
+        """The key a program is built and launched under is the entry
+        and its static shape, nothing of a request: two runners walk the
+        same keys, traffic of every occupancy, length and seed reaches
+        only keys `prewarm(launches=True)` derived from the runner's own
+        buckets, row counts and widths, and builds nothing new."""
+        from dynamo_tpu.engine.model_runner import bucket_table_width
+
+        def warmed():
+            runner = _runner()
+            runner.prewarm(spec_widths=[2], launches=True, block=4)
+            return runner
+
+        runner, twin = warmed(), warmed()
+        grid = set(runner.program_launches)
+        assert grid == set(twin.program_launches)  # stable across runs
+        buckets, b, p = (8, 16, 32), 4, 16
+        widths = sorted({bucket_table_width(n, p) for n in range(1, p + 1)})
+        assert grid == (
+            {("decode", f"decode[w{p}]"),
+             ("decode_spec", f"decode_spec[w{p},k2]")}
+            | {("prefill", f"prefill[{t}]") for t in buckets}
+            | {("prefill_batch", f"prefill_batch[{r}x{t}]")
+               for r in (2, 4) for t in buckets}
+            | {("decode_multi", f"decode_multi[w{w},b4,{src}]")
+               for w in widths for src in ("fed", "chained")})
+        assert all(row == [0, 0] for row in runner.program_launches.values())
+
+        steady = _snapshot()
+        table = np.arange(1, p + 1, dtype=np.int32) % runner.config.num_pages
+        greedy = (0.0, 1.0, 0, 0)
+        for n in (3, 8, 13, 30):
+            runner.prefill_chunk(np.full(n, 2, np.int32), 0, table, n, greedy)
+        for rows, n in ((2, 5), (3, 12), (4, 20)):
+            runner.prefill_chunk_batch(
+                [(np.full(n + i, 3, np.int32), 0, table, n + i, greedy, 0)
+                 for i in range(rows)])
+        ones, zeros = np.ones(b, np.float32), np.zeros(b, np.int32)
+        for step, width in enumerate(widths * 2):
+            kv = np.asarray([3 + step, 4, 5, 3 + step], np.int32)
+            args = (kv - 1, np.tile(table[:width], (b, 1)), kv,
+                    np.asarray([1, step % 2, 1, 1], bool), ones, ones,
+                    zeros, np.full(b, step, np.uint32))
+            toks = runner.decode_multi(zeros, *args, k=4, return_device=True)
+            runner.decode_multi(toks[-1], *args, k=4, return_device=True)
+        assert set(runner.program_launches) == grid, (
+            set(runner.program_launches) - grid)
+        assert _delta(steady, _snapshot()) == {}
+        # launches and useful tokens land under the key of the shape
+        launched = runner.program_launches
+        assert launched[("prefill", "prefill[8]")] == [2, 11]
+        assert launched[("prefill", "prefill[32]")] == [1, 30]
+        assert launched[("prefill_batch", "prefill_batch[4x16]")] == [
+            1, 12 + 13 + 14]
+        assert launched[("prefill_batch", "prefill_batch[4x32]")] == [
+            1, 20 + 21 + 22 + 23]
+        for width in widths:
+            for src in ("fed", "chained"):
+                assert launched[("decode_multi",
+                                 f"decode_multi[w{width},b4,{src}]")][0] == 2
+        assert twin.program_launches[("prefill", "prefill[8]")] == [0, 0]

@@ -38,7 +38,6 @@ from dynamo_tpu.perf.steptrace import (
     LiveRoofline,
     StepTrace,
     detect_chip,
-    measure_device,
 )
 from dynamo_tpu.planner.metrics_source import PhaseBreakdownSource
 from dynamo_tpu.runtime.flight_recorder import get_recorder, reset_recorder
@@ -170,17 +169,6 @@ class TestStepTraceUnit:
         assert [s.wall_ms for s in samples] == [1.0, 2.0]
         assert st.drain_samples() == []
         assert st.steps == 2
-
-
-class TestMeasureDevice:
-    def test_median_positive_and_shared_definition(self):
-        import jax.numpy as jnp
-
-        x = jnp.ones((64, 64))
-        out = measure_device(lambda: x @ x, steps=4, trials=3)
-        assert out["median_s"] > 0
-        assert len(out["trials_s"]) == 3
-        assert out["median_s"] in out["trials_s"]
 
 
 class TestLiveRoofline:
