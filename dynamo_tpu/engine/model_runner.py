@@ -618,9 +618,12 @@ class ModelRunner:
         # Which path the attention layers of prefill launches took
         # (dynamo_prefill_attn_launches_total) and, on the kernel's, the
         # (query block, key chunk) pairs a layer scored and skipped
-        # (dynamo_prefill_attn_blocks_total).
+        # (dynamo_prefill_attn_blocks_total), a page group apart: the
+        # full group's (a model's only one, but for window layers) and
+        # the window group's.
         self.prefill_attn_launches = {"kernel": 0, "xla": 0}
         self.prefill_attn_blocks = {"live": 0, "skipped": 0}
+        self.prefill_attn_window_blocks = {"live": 0, "skipped": 0}
         # A model with latent attention (dynamo_latent_*): cached
         # positions its decode kernel was asked to read, and positions
         # whose keys and values prefill launches rebuilt from latents,
@@ -710,30 +713,37 @@ class ModelRunner:
         finally:
             self._warming = prev
 
-    def prefill_attention_tiles(self, bucket: int):
+    def prefill_attention_tiles(self, bucket: int, window: bool = False):
         """(query positions a block, key tokens a chunk) where the
         attention layers of a `bucket`-position prefill launch run the
         blocked kernel, None where they run in XLA: `paged_attention`'s
         own rule on the shapes it will be handed, and only where the
         step program hands them to it (the default `attention_fn`, a
-        model whose prefill has no attention of its own)."""
+        model whose prefill has no attention of its own: latent layers
+        have). `window`: the window layers of a model that has them,
+        over their own page group's table (`window_prefill_width`);
+        else the full group's layers."""
         cfg, rc = self.model_config, self.config
         if (self._attention_user_supplied or self._attention_fn is None
-                or self._windowed or self._latent or cfg.is_mla
-                or cfg.is_gptoss or not cfg.kv_layers):
+                or self._latent or cfg.is_mla or cfg.is_gptoss
+                or not cfg.kv_layers or (window and not self._windowed)):
             return None
         from ..ops.paged_attention import prefill_kernel_tiles
 
         return prefill_kernel_tiles(
             bucket, cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim,
-            rc.page_size, rc.max_pages_per_seq,
+            rc.page_size,
+            (self.window_prefill_width(bucket) if window
+             else rc.max_pages_per_seq),
             jnp.int8 if self._kv_quantized else cfg.dtype,
             KV_SCALE_LANES if self._kv_quantized else None)
 
     def _count_prefill(self, starts: Sequence[int], lengths: Sequence[int],
-                       rows: int, bucket: int) -> None:
+                       rows: int, bucket: int, windows=()) -> None:
         """Host arithmetic on a launch's own positions (row i holds
-        `lengths[i]` of them from `starts[i]`), no device sync."""
+        `lengths[i]` of them from `starts[i]`), no device sync. `windows`:
+        each row's (window table, base) where the model has window
+        layers."""
         self.prefill_positions += rows * bucket
         if self.config.weight_dtype == "int4":
             from ..ops.q4_linear import count_row_blocks
@@ -741,16 +751,30 @@ class ModelRunner:
             live, skipped = count_row_blocks(lengths, rows, bucket)
             self.prefill_row_blocks["live"] += live
             self.prefill_row_blocks["skipped"] += skipped
-        tiles = self.prefill_attention_tiles(bucket)
-        self.prefill_attn_launches["kernel" if tiles else "xla"] += 1
-        if tiles:
-            from ..ops.paged_attention import count_prefill_blocks
+        from ..ops.paged_attention import count_prefill_blocks
 
+        tiles = self.prefill_attention_tiles(bucket)
+        win_tiles = (self.prefill_attention_tiles(bucket, window=True)
+                     if self._windowed else tiles)
+        # a launch is the kernel's where every page group's layers are
+        self.prefill_attn_launches[
+            "kernel" if tiles and win_tiles else "xla"] += 1
+        if tiles:
             live, skipped = count_prefill_blocks(
                 starts, [s + n for s, n in zip(starts, lengths)], rows,
                 bucket, *tiles, self.config.max_context)
             self.prefill_attn_blocks["live"] += live
             self.prefill_attn_blocks["skipped"] += skipped
+        if self._windowed and win_tiles:
+            # the window group's frame: positions from each row's base
+            frame = [s - w[1] for s, w in zip(starts, windows)]
+            live, skipped = count_prefill_blocks(
+                frame, [s + n for s, n in zip(frame, lengths)], rows,
+                bucket, *win_tiles,
+                self.window_prefill_width(bucket) * self.config.page_size,
+                self.model_config.sliding_window)
+            self.prefill_attn_window_blocks["live"] += live
+            self.prefill_attn_window_blocks["skipped"] += skipped
 
     @staticmethod
     def _check_hybrid(cfg: ModelConfig, rc: RunnerConfig, mesh: Mesh):
@@ -787,10 +811,14 @@ class ModelRunner:
 
     def window_prefill_width(self, bucket: int) -> int:
         """The same for a prefill program of `bucket` positions a row:
-        window + chunk keys, never the full layers' table."""
+        window + chunk keys, never the full layers' table, and room to
+        whole key chunks of the prefill kernel, which admits no other
+        width (`ops.paged_attention.prefill_table_pages`)."""
+        from ..ops.paged_attention import prefill_table_pages
+
         blocks = -(-(self.model_config.sliding_window + bucket)
                    // self.config.page_size) + 1
-        return -(-blocks // 8) * 8
+        return prefill_table_pages(blocks, self.config.page_size)
 
     def _table_args(self, block_tables):
         """Block tables as a step program takes them: one int32 array,
@@ -1465,9 +1493,12 @@ class ModelRunner:
     @property
     def bounds_prefill_launches(self) -> bool:
         """Whether a prefill launch's rows x bucket must stay inside the
-        token budget (`prefill_launch_fits`): a model whose prefill
-        attention scores a launch's positions against wide tables in
-        float32: window layers beside full ones, latent layers."""
+        token budget (`prefill_launch_fits`): latent layers, whose
+        prefill attention scores a launch's positions against wide
+        tables in float32, and window layers beside full ones, whose XLA
+        form does (on the chip both their page groups run the blocked
+        kernel since PR 41 and hold no such scores; the bound stays
+        because lifting it changes the program grid: ROADMAP A7)."""
         return self._windowed or self._latent
 
     def prefill_launch_fits(self, lengths: Sequence[int]) -> bool:
@@ -1528,7 +1559,7 @@ class ModelRunner:
         pos[0, :t] = np.arange(start_pos, start_pos + t)
         valid = np.zeros((1, bucket), bool)
         valid[0, :t] = True
-        self._count_prefill([start_pos], [t], 1, bucket)
+        self._count_prefill([start_pos], [t], 1, bucket, [window])
         self._count_latent_prefill([kv_len_after])
         temp, top_p, top_k, seed = sampling
         args = [
@@ -1635,7 +1666,7 @@ class ModelRunner:
             temp[i], top_p[i], top_k[i], seeds[i] = sampling
             lora_rows[i] = lidx
         self._count_prefill([r[1] for r in rows],
-                            [len(r[0]) for r in rows], b, bucket)
+                            [len(r[0]) for r in rows], b, bucket, windows)
         self._count_latent_prefill([r[3] for r in rows])
         args = [
             jnp.asarray(tok), jnp.asarray(pos),

@@ -1608,9 +1608,13 @@ class TpuWorker:
         for path, count in getattr(
                 self.runner, "prefill_attn_launches", {}).items():
             PREFILL_ATTN_LAUNCHES.labels(worker=worker, path=path).set(count)
-        for state, count in getattr(
-                self.runner, "prefill_attn_blocks", {}).items():
-            PREFILL_ATTN_BLOCKS.labels(worker=worker, state=state).set(count)
+        for group, attr in (("full", "prefill_attn_blocks"),
+                            ("window", "prefill_attn_window_blocks")):
+            blocks = getattr(self.runner, attr, {})
+            if group == "full" or any(blocks.values()):
+                for state, count in blocks.items():
+                    PREFILL_ATTN_BLOCKS.labels(
+                        worker=worker, state=state, group=group).set(count)
         # a snapshot: the scheduler thread adds keys as it launches
         for (fn, key), (launches, tokens) in list(getattr(
                 self.runner, "program_launches", {}).items()):

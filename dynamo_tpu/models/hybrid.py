@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.grouped_matmul import dropless_experts
+from ..ops.paged_attention import prefill_kernel_tiles
 from ..ops.ssm import (
     causal_conv,
     expand_groups,
@@ -84,13 +85,17 @@ from .transformer import (
     yarn_rope_tables,
 )
 
-# A windowed model's prefill attention (`prefill_attention`) scores at
-# most PREFILL_Q_BLOCK query positions a row and PREFILL_SCORE_POSITIONS
-# over all rows at a time: its full layers' table is as wide as the
-# longest context served, and float32 scores [rows, T, heads, keys] of a
-# whole 2048-token chunk over 8192 keys are 2.1 GB (PERF.md, fault 2). A
-# full layer gathers the narrowest of FULL_TABLE_PAGES (and the whole
-# table) that holds what a block of queries can see.
+# A windowed model's prefill attention in XLA (`prefill_attention`: the
+# CPU's path, the kernel's oracle at the model's shapes and the fallback
+# for a page group whose geometry `prefill_kernel_tiles` refuses; on the
+# chip both page groups run the blocked kernel behind `attention_fn`)
+# scores at most PREFILL_Q_BLOCK query positions a row and
+# PREFILL_SCORE_POSITIONS over all rows at a time: its full layers'
+# table is as wide as the longest context served, and float32 scores
+# [rows, T, heads, keys] of a whole 2048-token chunk over 8192 keys are
+# 2.1 GB (PERF.md, fault 2). A full layer gathers the narrowest of
+# FULL_TABLE_PAGES (and the whole table) that holds what a block of
+# queries can see.
 PREFILL_Q_BLOCK = 512
 PREFILL_SCORE_POSITIONS = 2048
 FULL_TABLE_PAGES = (64, 128, 256)
@@ -773,14 +778,16 @@ def _window_frame(window, positions, kv_lens):
 
 def prefill_attention(q, kv_cache, layer, block_tables, positions, kv_lens,
                       window: int = 0):
-    """`paged_attention_xla` for a model with window layers, a block of
-    query positions at a time (`lax.map`), each over the pages it can see
-    and no others: a window layer the window + block positions that end
-    at the block's last query (a slice of its table, by row), a full
-    layer the narrowest table prefix that holds every row's keys up to
-    the block's last query (one of a few static widths, chosen at run
-    time: the program keeps one shape). The same numbers as scoring the
-    whole table: what is left out is masked there."""
+    """`paged_attention_xla` for a model with window layers where no
+    kernel runs (`_group_attention`: the CPU, a geometry the kernel
+    refuses), a block of query positions at a time (`lax.map`), each
+    over the pages it can see and no others: a window layer the window
+    + block positions that end at the block's last query (a slice of
+    its table, by row), a full layer the narrowest table prefix that
+    holds every row's keys up to the block's last query (one of a few
+    static widths, chosen at run time: the program keeps one shape).
+    The same numbers as scoring the whole table: what is left out is
+    masked there."""
     b, t, qh, hd = q.shape
     ps = kv_cache.shape[3]
     width = block_tables.shape[1]
@@ -825,6 +832,21 @@ def _prefix_attention(pages, layer, q, kv_cache, block_tables, positions,
                                positions, kv_lens, flat_gather=True)
 
 
+def _group_attention(attention_fn, q_shape, cache, tables):
+    """Prefill attention of one page group of a model with window
+    layers: `attention_fn` (`ops.paged_attention.paged_attention`: the
+    blocked kernel, with a window's lower edge where the layer states
+    one) where one is given and `prefill_kernel_tiles` admits the
+    group's shapes, the blocked XLA form otherwise. Static: the shapes
+    of a program decide."""
+    _, t, qh, hd = q_shape
+    if attention_fn is not None and prefill_kernel_tiles(
+            t, qh, cache.shape[4], hd, cache.shape[3], tables.shape[1],
+            cache.dtype) is not None:
+        return attention_fn
+    return prefill_attention
+
+
 def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
                    state, slots, block_tables, kv_lens, valid, last_idx,
                    attention_fn=None, gmm_path: str = "xla",
@@ -837,13 +859,18 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
 
     A model with window layers is handed `window` = (the window group's
     cache, its tables [B, pages], base [B]) and gives back `kv_cache` as
-    (full group, window group); its window layers gather their short
-    table (window + chunk keys), its full layers the sequence's."""
-    attention = attention_fn or paged_attention_xla
+    (full group, window group); its window layers read their short
+    table (window + chunk keys) in that group's frame, its full layers
+    the sequence's, each group through `_group_attention`."""
+    attention = win_attention = attention_fn or paged_attention_xla
     if window is not None:
-        attention = prefill_attention
         win_cache, win_tables, win_pos, win_lens = _window_frame(
             window, positions, kv_lens)
+        q_shape = (*tokens.shape, config.n_q_heads, config.head_dim)
+        attention = _group_attention(attention_fn, q_shape, kv_cache,
+                                     block_tables)
+        win_attention = _group_attention(attention_fn, q_shape, win_cache,
+                                         win_tables)
     fresh = positions[:, 0] == 0  # a row at position 0 starts from zero
     x = params["embed"][tokens]
     conv_out, ssm_out = list(state["conv"]), list(state["ssm"])
@@ -874,8 +901,9 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
                 q, k, v = _qkv(h, lp, config, kind, positions)
                 win_cache = write_kv_pages(win_cache, win_idx, k, v,
                                            win_tables, win_pos, valid)
-                attn = attention(q, win_cache, win_idx, win_tables, win_pos,
-                                 win_lens, window=config.sliding_window)
+                attn = win_attention(q, win_cache, win_idx, win_tables,
+                                     win_pos, win_lens,
+                                     window=config.sliding_window)
                 out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])
             win_idx += 1
         elif kind == "L":

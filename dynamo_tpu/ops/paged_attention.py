@@ -1269,20 +1269,33 @@ def prefill_kernel_tiles(t: int, qh: int, kh: int, hd: int, page_size: int,
     return block_q, chunk
 
 
+def prefill_table_pages(pages: int, page_size: int) -> int:
+    """`pages` table columns rounded up to whole key chunks of the
+    prefill kernel (and to 8): a width `prefill_kernel_tiles` admits at
+    its full chunk, for a table the caller pads (a window group's)."""
+    step = max(8, _PREFILL_CHUNK_TOKENS // page_size)
+    return -(-pages // step) * step
+
+
 def count_prefill_blocks(starts, kv_lens, rows: int, t: int, block_q: int,
-                         chunk_tokens: int,
-                         table_tokens: int) -> tuple[int, int]:
+                         chunk_tokens: int, table_tokens: int,
+                         window: int = 0) -> tuple[int, int]:
     """(live, skipped) (query block, key chunk) pairs of one attention
     layer of a launch of `rows` x `t` positions over tables of
     `table_tokens`: the kernel's own liveness rule on the host's numbers,
     for the engine's counters. Row i's queries start at `starts[i]` and
-    see `kv_lens[i]` keys; rows past the lists are padding."""
+    see `kv_lens[i]` keys, a window layer's (`window` > 0) the last
+    `window` of them, all in the table's frame; rows past the lists are
+    padding."""
     total = rows * (t // block_q) * (table_tokens // chunk_tokens)
     live = 0
     for start, kv_len in zip(starts, kv_lens):
         for qi in range(-(-(kv_len - start) // block_q)):
             limit = min(kv_len, start + (qi + 1) * block_q)
             live += -(-min(limit, table_tokens) // chunk_tokens)
+            if window:
+                edge = max(0, start + qi * block_q - (window - 1))
+                live -= edge // chunk_tokens
     return live, total - live
 
 
@@ -1292,12 +1305,15 @@ def _head_rows(words_ref, group: int, sub, n_tok: int, kh: int, dtype):
     as 32-bit words: a word holds the same lane of `pack` consecutive
     rows, the lowest row in its lowest bits, so head `group * pack + sub`
     is every (kh / pack)-th word row from `group` on (static: a strided
-    load), shifted down by `sub` elements (traced: the caller loops over
-    a word's heads without unrolling them). int8 codes and bf16 values
-    both convert exactly."""
+    load), shifted down by `sub` elements (traced where the caller loops
+    over a word's heads without unrolling them, a Python int where it
+    unrolls them). int8 codes and bf16 values both convert exactly."""
     bits = 8 * jnp.dtype(dtype).itemsize
     words = words_ref[pl.ds(group, n_tok, stride=kh * bits // 32), :]
-    words = words >> (sub * bits).astype(jnp.uint32)
+    if not isinstance(sub, int):
+        words = words >> (sub * bits).astype(jnp.uint32)
+    elif sub:
+        words = words >> jnp.uint32(sub * bits)
     if jnp.dtype(dtype) == jnp.int8:
         return pltpu.bitcast(words.astype(jnp.uint8),
                              jnp.int8).astype(jnp.float32)
@@ -1321,6 +1337,7 @@ def _pool_prefill_kernel(
     max_pages: int,
     batch_size: int,
     quantized: bool,
+    window: int = 0,
 ):
     """Blocked causal attention of a prefill launch over the paged pool,
     the launch's own keys included (`write_kv_pages` has put them there).
@@ -1342,6 +1359,17 @@ def _pool_prefill_kernel(
     the output of a dead query block is zeros. Queries past a row's
     valid count inside a live block score every key of the row: their
     output is finite and nobody reads it.
+
+    `window` > 0 (static) is a window layer's mask: a query at position
+    q sees the keys q - window < k <= q, positions, lengths and table in
+    the page group's own frame. The chunks wholly below the lower edge
+    of a query block's FIRST query are never fetched either, so a
+    block's flash state starts at its first live chunk, not at chunk 0.
+    There its later queries may see no key at all (their edge lies in
+    the next chunk): masked scores are a large finite negative, as the
+    XLA oracle's, so such a row carries a finite average that the first
+    seen key's weight wipes out (exp(-1e30 - m) == 0), where -inf less
+    -inf would be a NaN. With `window` == 0 nothing of this is traced.
 
     `_pool_decode_kernel` has the why of the streaming: the pool stays in
     HBM, a page's K and V for all kv heads come in one DMA through the
@@ -1391,6 +1419,13 @@ def _pool_prefill_kernel(
         return jnp.minimum(lengths_ref[bi],
                            starts_ref[bi] + (qi + 1) * block_q)
 
+    def first_chunk(bi, qi):  # the block's lowest live chunk
+        if not window:
+            return jnp.int32(0)
+        edge = starts_ref[jnp.minimum(bi, batch_size - 1)] + (
+            qi * block_q - (window - 1))
+        return _div(jnp.maximum(edge, 0), bk)
+
     def chunk_copies(bi, ci, slot, fn):
         base = bi * max_pages + ci * pages_per_chunk
 
@@ -1418,12 +1453,12 @@ def _pool_prefill_kernel(
                             jnp.clip(cur, 0, batch_size - 1), 0))),
                     cur + 1, cur),
                 b + 1)
-            return nb, jnp.int32(0), jnp.int32(0)
+            return nb, jnp.int32(0), first_chunk(nb, 0)
 
         def next_block():
             more = jnp.logical_and(i + 1 < n_q, block_live(b, i + 1))
             return jax.lax.cond(
-                more, lambda: (b, i + 1, jnp.int32(0)), next_row)
+                more, lambda: (b, i + 1, first_chunk(b, i + 1)), next_row)
 
         more = jnp.logical_and(c + 1 < n_chunks,
                                (c + 1) * bk < key_limit(b, i))
@@ -1431,6 +1466,8 @@ def _pool_prefill_kernel(
 
     live = block_live(b, i)
     active = jnp.logical_and(live, c * bk < key_limit(b, i))
+    if window:
+        active = jnp.logical_and(active, c >= first_chunk(b, i))
 
     @pl.when(jnp.logical_and(active, init_ref[0] == 1))
     def _first():
@@ -1462,7 +1499,10 @@ def _pool_prefill_kernel(
             jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), group))
         k_pos = c * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         seen = jnp.logical_and(k_pos <= q_pos, k_pos < lengths_ref[b])
-        first = c == 0
+        if window:
+            seen = jnp.logical_and(seen, k_pos > q_pos - window)
+        first = c == first_chunk(b, i)
+        masked = -1e30 if window else -jnp.inf
         pool_dtype = kv_buf.dtype
         k_words = kv_buf.at[slot, 0].reshape(bk * kh, hd).bitcast(jnp.uint32)
         v_words = kv_buf.at[slot, 1].reshape(bk * kh, hd).bitcast(jnp.uint32)
@@ -1484,11 +1524,12 @@ def _pool_prefill_kernel(
                 preferred_element_type=jnp.float32)  # [rows, bk]
             if not quantized:
                 s = s * sm_scale
-            s = jnp.where(seen, s, -jnp.inf)
-            # No reset between query blocks: a block's first chunk takes
-            # an empty state instead of the scratch's leftovers. Finite
-            # from there on: key 0 is seen by every query of a live row.
-            m_prev = jnp.where(first, -jnp.inf, m_ref[h, :, 0:1])
+            s = jnp.where(seen, s, masked)
+            # No reset between query blocks: a block's first live chunk
+            # takes an empty state instead of the scratch's leftovers.
+            # Finite from there on: without a window key 0 is seen by
+            # every query of a live row; with one `masked` is finite.
+            m_prev = jnp.where(first, masked, m_ref[h, :, 0:1])
             l_prev = jnp.where(first, 0.0, l_ref[h, :, 0:1])
             o_prev = jnp.where(first, 0.0, acc_ref[h])
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -1506,11 +1547,21 @@ def _pool_prefill_kernel(
         # A loop over a word's heads, each turn the same head of every
         # word row: a program's trace and Mosaic's module hold kh / pack
         # flash bodies, and the bodies of one turn are independent, so
-        # one head's softmax overlaps another's matmuls.
-        @pl.loop(0, pack)
-        def _heads(sub):
-            for word_group in range(kh // pack):
-                flash_head(word_group, sub)
+        # one head's softmax overlaps another's matmuls. The window form
+        # unrolls the loop: kh independent bodies a step run in 73% of
+        # the looped form's time (PERF.md, PR 41), and a model with
+        # window layers lowers this kernel in six prefill programs,
+        # where the dense cell's thirteen could not bear 8 bodies'
+        # lowering (PR 39).
+        if window:
+            for sub in range(pack):
+                for word_group in range(kh // pack):
+                    flash_head(word_group, sub)
+        else:
+            @pl.loop(0, pack)
+            def _heads(sub):
+                for word_group in range(kh // pack):
+                    flash_head(word_group, sub)
 
     @pl.when(jnp.logical_and(c == n_chunks - 1, live))
     def _finish():
@@ -1524,24 +1575,10 @@ def _pool_prefill_kernel(
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",),
-                   donate_argnums=())  # read-only on the whole pool
-def paged_prefill_attention_pool(
-    q: jax.Array,  # [B, T, qh, hd]
-    kv_pool: jax.Array,  # [L, 2, P, ps, kh, hd]: the WHOLE cache
-    layer: jax.Array,  # scalar int32
-    block_tables: jax.Array,  # [B, max_pages] int32
-    starts: jax.Array,  # [B] int32 position of each row's first query
-    kv_lens: jax.Array,  # [B] int32 keys a row sees, this chunk's included
-    kv_scales=None,  # bf16 [L, 2, P, ps, LANES] for an int8 pool
-    *,
-    interpret: bool = False,
-) -> jax.Array:
-    """Causal attention of a prefill launch, `_pool_prefill_kernel`: row b
-    holds the consecutive positions starts[b].. of which the first
-    kv_lens[b] - starts[b] are real; returns [B, T, qh, hd]. The caller
-    (`paged_attention`) has checked the geometry with
-    `prefill_kernel_tiles`."""
+def _pool_prefill_call(q, kv_pool, layer, block_tables, starts, kv_lens,
+                       kv_scales, window, interpret):
+    """The pallas_call both jitted entry points share; `window` > 0 is
+    the window layers' form, under its own name."""
     quantized = kv_scales is not None
     b, t, qh, hd = q.shape
     ps, kh = kv_pool.shape[3], kv_pool.shape[4]
@@ -1578,7 +1615,7 @@ def paged_prefill_attention_pool(
         functools.partial(_pool_prefill_kernel, block_q=block_q,
                           group=group, pages_per_chunk=ppc,
                           max_pages=max_pages, batch_size=b,
-                          quantized=quantized),
+                          quantized=quantized, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=(b, t // block_q, max_pages // ppc),
@@ -1591,11 +1628,57 @@ def paged_prefill_attention_pool(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_PREFILL_VMEM_BYTES),
-        name="paged_prefill_attention_pool",
+        name=("paged_prefill_attention_window" if window
+              else "paged_prefill_attention_pool"),
     )(starts.astype(jnp.int32), kv_lens.astype(jnp.int32),
       block_tables.reshape(-1).astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1),
       jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32), *operands)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",),
+                   donate_argnums=())  # read-only on the whole pool
+def paged_prefill_attention_pool(
+    q: jax.Array,  # [B, T, qh, hd]
+    kv_pool: jax.Array,  # [L, 2, P, ps, kh, hd]: the WHOLE cache
+    layer: jax.Array,  # scalar int32
+    block_tables: jax.Array,  # [B, max_pages] int32
+    starts: jax.Array,  # [B] int32 position of each row's first query
+    kv_lens: jax.Array,  # [B] int32 keys a row sees, this chunk's included
+    kv_scales=None,  # bf16 [L, 2, P, ps, LANES] for an int8 pool
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal attention of a prefill launch, `_pool_prefill_kernel`: row b
+    holds the consecutive positions starts[b].. of which the first
+    kv_lens[b] - starts[b] are real; returns [B, T, qh, hd]. The caller
+    (`paged_attention`) has checked the geometry with
+    `prefill_kernel_tiles`."""
+    return _pool_prefill_call(q, kv_pool, layer, block_tables, starts,
+                              kv_lens, kv_scales, 0, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret"),
+                   donate_argnums=())
+def paged_prefill_attention_window(
+    q: jax.Array,  # [B, T, qh, hd]
+    kv_pool: jax.Array,  # the window group's WHOLE cache
+    layer: jax.Array,
+    block_tables: jax.Array,  # [B, pages] the group's own table
+    starts: jax.Array,  # [B] first query's position in the table's frame
+    kv_lens: jax.Array,  # [B] keys a row sees, same frame
+    kv_scales=None,
+    *,
+    window: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """`paged_prefill_attention_pool` for a window layer: a query sees
+    the last `window` keys up to its own, and the chunks below a query
+    block's window are never fetched. Under a name of its own, as the
+    decode kernels are, so that a device trace tells the window layers'
+    events from the full layers'."""
+    return _pool_prefill_call(q, kv_pool, layer, block_tables, starts,
+                              kv_lens, kv_scales, window, interpret)
 
 
 def paged_attention(
@@ -1606,27 +1689,30 @@ def paged_attention(
     positions: jax.Array,  # [B, T]: a row's positions are consecutive
     kv_lens: jax.Array,
     *,
+    window: int = 0,
     interpret: bool = False,
 ) -> jax.Array:
     """Drop-in `attention_fn` for `models.transformer.forward` and the
-    full-attention layers of `models.hybrid.forward_hybrid`.
+    attention layers of `models.hybrid.forward_hybrid`, full and window
+    (`window` > 0: the mask's lower edge, with table, positions and
+    lengths in the window group's own frame).
 
     A prefill chunk (T > 1) runs the blocked kernel over the paged pool
-    (`paged_prefill_attention_pool`) wherever `prefill_kernel_tiles`
-    admits the geometry: XLA's attention there writes and reads a float32
-    score tensor [B, T, heads, table tokens] several times over and was
-    30% of the flagship cell's device time (PERF.md, PR 39). A row's
-    first query position is `positions[:, 0]` and its valid count
-    `kv_lens - positions[:, 0]`, as every prefill launch lays its rows
-    out. One token (T == 1) over a bf16 pool runs the per-layer flash
-    decode kernel. Everything else takes `paged_attention_xla`, the CPU
-    path and the oracle of both."""
+    (`paged_prefill_attention_pool`, `paged_prefill_attention_window`)
+    wherever `prefill_kernel_tiles` admits the geometry: XLA's attention
+    there writes and reads a float32 score tensor [B, T, heads, table
+    tokens] several times over and was 30% of the flagship cell's device
+    time (PERF.md, PR 39). A row's first query position is
+    `positions[:, 0]` and its valid count `kv_lens - positions[:, 0]`, as
+    every prefill launch lays its rows out. One token (T == 1) over a
+    bf16 pool runs the per-layer flash decode kernel. Everything else
+    takes `paged_attention_xla`, the CPU path and the oracle of both."""
     from ..models.transformer import paged_attention_xla
 
     values, scales = (kv_cache if isinstance(kv_cache, tuple)
                       else (kv_cache, None))
     _, t, qh, hd = q.shape
-    if t == 1 and scales is None:
+    if t == 1 and scales is None and not window:
         out = paged_decode_attention(
             q[:, 0], values[layer, 0], values[layer, 1],
             block_tables, kv_lens, interpret=interpret,
@@ -1636,8 +1722,12 @@ def paged_attention(
             t, qh, values.shape[4], hd, values.shape[3],
             block_tables.shape[1], values.dtype,
             None if scales is None else scales.shape[-1]) is not None:
+        if window:
+            return paged_prefill_attention_window(
+                q, values, layer, block_tables, positions[:, 0], kv_lens,
+                kv_scales=scales, window=window, interpret=interpret)
         return paged_prefill_attention_pool(
             q, values, layer, block_tables, positions[:, 0], kv_lens,
             kv_scales=scales, interpret=interpret)
     return paged_attention_xla(q, kv_cache, layer, block_tables,
-                               positions, kv_lens)
+                               positions, kv_lens, window=window)

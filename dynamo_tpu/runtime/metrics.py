@@ -414,16 +414,21 @@ PREFILL_ATTN_LAUNCHES = Gauge(
     "dynamo_prefill_attn_launches_total",
     "Prefill launches since start by the path their attention layers "
     "took: kernel (the blocked Pallas prefill kernel over the paged "
-    "pool) | xla (gathered pages, a full float32 score tensor)",
+    "pool, in every page group) | xla (gathered pages, a full float32 "
+    "score tensor)",
     ["worker", "path"], registry=REGISTRY,
 )
 PREFILL_ATTN_BLOCKS = Gauge(
     "dynamo_prefill_attn_blocks_total",
     "Kernel-path prefill launches: (query block, key chunk) pairs of one "
     "attention layer since start, by state: live (fetched and scored) | "
-    "skipped (above the causal diagonal, past the row's keys, padding: "
-    "a dense rows x bucket x table grid holds both)",
-    ["worker", "state"], registry=REGISTRY,
+    "skipped (above the causal diagonal, past the row's keys, below a "
+    "window's lower edge, padding: a dense rows x bucket x table grid "
+    "holds both), and by page group: full (a full-attention layer over "
+    "the sequence's table: every model's but for its window layers) | "
+    "window (a window layer over its own group's table, a model with "
+    "window layers only)",
+    ["worker", "state", "group"], registry=REGISTRY,
 )
 LATENT_DECODE_TOKENS = Gauge(
     "dynamo_latent_decode_tokens_total",

@@ -471,6 +471,49 @@ def test_prefill_attention_by_blocks_equals_the_whole_table(monkeypatch,
         assert np.abs(want - np.asarray(paged_attention_xla(*args))).max() > .1
 
 
+def test_both_page_groups_run_the_prefill_kernel_where_it_admits_them(
+        monkeypatch):
+    """The preset at head_dim 128 in bf16 (the kernel's geometry) through
+    the runner twice: `attention_fn` as on the chip (here the Pallas
+    interpreter) and the CPU's XLA form. A 119-token prompt in chunks of
+    32 (the window group's frame starts past 0 from the third on), then
+    decode steps whose logits rest on every layer's prefill attention.
+    The launches count under `kernel`, a page group's blocks apart.
+    Dense mixers in the experts' place: in bf16 a top-2 of 8 flips on
+    the last bit of a score (the decode kernels move these logits by 1.7
+    against XLA with the experts in, by 0.04 without)."""
+    config = dataclasses.replace(get_config("tiny-mellum-test"),
+                                 head_dim=128, dtype="bfloat16",
+                                 layer_pattern="WDWDWD*D" * 2)
+    prompt = prompt_of(119, seed=5)
+
+    def logits(runner):
+        pool = WindowPool(16, PAGE, WINDOW)
+        row = Row(runner, pool, 1, prompt)
+        row.prefill([32, 32, 32, 23])
+        return np.stack([row.decode() for _ in range(4)])
+
+    xla = make_runner(config)
+    assert xla.prefill_attention_tiles(32) is None
+    monkeypatch.setenv("DYNT_ATTENTION", "pallas")  # here: the interpreter
+    kernel = make_runner(config)
+    assert kernel.window_prefill_width(32) == 16
+    assert kernel.prefill_attention_tiles(32) == (32, 256)
+    assert kernel.prefill_attention_tiles(32, window=True) == (32, 256)
+    want, got = logits(xla), logits(kernel)
+    assert want.std() > 0.5
+    # bf16 weights and activations on both sides; the kernel's bf16
+    # probabilities against float32 ones
+    np.testing.assert_allclose(got, want, atol=0.08)
+    assert (got.argmax(-1) == want.argmax(-1)).all()
+    assert xla.prefill_attn_launches == {"kernel": 0, "xla": 4}
+    assert kernel.prefill_attn_launches == {"kernel": 4, "xla": 0}
+    # one query block x one key chunk a launch in either group
+    assert kernel.prefill_attn_blocks == {"live": 4, "skipped": 0}
+    assert kernel.prefill_attn_window_blocks == {"live": 4, "skipped": 0}
+    assert xla.prefill_attn_window_blocks == {"live": 0, "skipped": 0}
+
+
 # -- what it is refused ----------------------------------------------------------
 
 

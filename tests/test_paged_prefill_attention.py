@@ -1,4 +1,5 @@
-"""The blocked prefill attention kernel (`paged_prefill_attention_pool`)
+"""The blocked prefill attention kernel (`paged_prefill_attention_pool`
+and, with a window's lower edge, `paged_prefill_attention_window`)
 against `paged_attention_xla`, in the Pallas interpreter on the CPU:
 what a prefill launch hands `attention_fn`, row by row. Mosaic's view of
 the same kernel is in tests/test_tpu_compile.py.
@@ -16,13 +17,15 @@ from dynamo_tpu.ops.paged_attention import (
     count_prefill_blocks,
     paged_attention,
     prefill_kernel_tiles,
+    prefill_table_pages,
 )
 
 PAGE, HEAD_DIM, LAYERS = 16, 128, 2
 
 # name: (pool, kv heads, group, positions a row (the bucket), table width
 # in pages, rows as (first position, valid positions); (0, 0) is a row a
-# pow2 launch pads in)
+# pow2 launch pads in[, window: the layer's, with positions and table in
+# the window group's frame, column 0 = the row's first held block])
 CASES = {
     "bf16-g4-fresh": ("bf16", 2, 4, 512, 32, [(0, 512)]),
     "int8-g4-fresh": ("int8", 4, 4, 512, 32, [(0, 512)]),
@@ -44,11 +47,40 @@ CASES = {
                            [(0, 512), (512, 300), (250, 77), (0, 0)]),
     "bf16-g8-continuation-mid-page": ("bf16", 2, 8, 256, 32,
                                       [(131, 256), (7, 120)]),
+    # a fresh row shorter than the window: the mask is the causal one
+    "window-fresh-short-row": ("bf16", 2, 8, 512, 112, [(0, 300)], 1024),
+    # pages freed behind: the frame starts at the block of the first
+    # query's lower edge, so the row's first position is window - 1 + 7
+    "window-frame-past-0-continues": ("bf16", 2, 8, 512, 112,
+                                      [(1030, 512)], 1024),
+    # the edge of every block but the first falls inside a chunk, and
+    # blocks 2.. start at chunk 1, 2: chunks below are never fetched
+    "window-edge-inside-a-chunk": ("bf16", 2, 4, 1024, 80,
+                                   [(0, 1024)], 300),
+    "int8-window-edge-inside-a-chunk": ("int8", 4, 4, 512, 48,
+                                        [(150, 512)], 200),
+    # block 0 holds positions 250..377 and starts at chunk 0 (its first
+    # query's edge is 187); its queries from 319 on see no key there
+    "window-later-queries-see-no-key-in-first-chunk": (
+        "bf16", 2, 8, 256, 32, [(250, 256)], 64),
+    # the same past a row's end: padding queries far above the last key
+    # see none in any chunk
+    "window-padded-queries-see-no-key": ("bf16", 2, 8, 256, 32,
+                                         [(250, 40)], 16),
+    "window-padded-rows-and-last-block": (
+        "bf16", 2, 8, 512, 112,
+        [(1030, 300), (0, 0), (1024, 130), (0, 77)], 1024),
+    # the mellum cell's three buckets at its window tables' widths
+    "window-cell-512-w112": ("bf16", 2, 8, 512, 112, [(1038, 470)], 1024),
+    "window-cell-1024-w144": ("bf16", 2, 8, 1024, 144, [(1023, 1000)],
+                              1024),
+    "window-cell-2048-w208": ("bf16", 2, 8, 2048, 208, [(1027, 1567)],
+                              1024),
 }
 
 
 def _launch(case, seed=0):
-    pool, kh, g, t, width, rows = CASES[case]
+    pool, kh, g, t, width, rows = CASES[case][:6]
     rng = np.random.default_rng(seed)
     b = len(rows)
     n_pages = b * width + 1
@@ -77,7 +109,8 @@ def _launch(case, seed=0):
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_the_prefill_kernel_matches_the_xla_oracle(case):
-    _, kh, g, t, width, rows = CASES[case]
+    _, kh, g, t, width, rows, *window = CASES[case]
+    window = window[0] if window else 0
     q, cache, tables, positions, kv_lens = _launch(case)
     values = cache[0] if isinstance(cache, tuple) else cache
     block_q, _ = prefill_kernel_tiles(
@@ -85,10 +118,11 @@ def test_the_prefill_kernel_matches_the_xla_oracle(case):
         KV_SCALE_LANES if isinstance(cache, tuple) else None)
     layer = LAYERS - 1
     got = np.asarray(paged_attention(
-        q, cache, layer, tables, positions, kv_lens, interpret=True),
-        np.float32)
+        q, cache, layer, tables, positions, kv_lens, window=window,
+        interpret=True), np.float32)
     want = np.asarray(paged_attention_xla(
-        q, cache, layer, tables, positions, kv_lens), np.float32)
+        q, cache, layer, tables, positions, kv_lens, window=window),
+        np.float32)
     assert got.shape == want.shape and np.isfinite(got).all()
     for i, (_, n) in enumerate(rows):
         # bf16 operands and probabilities against a float32 oracle
@@ -140,3 +174,46 @@ def test_block_counts_follow_the_kernels_liveness():
     # a continuation sees its prefix whole: 3 + 4 chunks
     assert count_prefill_blocks([512], [1024], 1, 512, 256, 256,
                                 1024) == (7, 1)
+
+
+def _brute_force_blocks(starts, kv_lens, rows, t, block_q, chunk, table,
+                        window):
+    """Pairs that hold at least one (query, key) the mask admits, the
+    block's first query alone deciding the lower edge as in the kernel;
+    query blocks are live by their first position."""
+    live = 0
+    for start, kv_len in zip(starts, kv_lens):
+        for qi in range(t // block_q):
+            first = start + qi * block_q
+            if first >= kv_len:
+                continue
+            last = min(first + block_q, kv_len) - 1
+            lo = max(0, first - (window - 1)) if window else 0
+            for c in range(table // chunk):
+                keys = range(c * chunk, (c + 1) * chunk)
+                live += keys[-1] >= lo and keys[0] <= last
+    return live, rows * (t // block_q) * (table // chunk) - live
+
+
+@pytest.mark.parametrize("window", [0, 16, 64, 300, 1024])
+def test_block_counts_with_a_window_match_a_brute_force_count(window):
+    rng = np.random.default_rng(window)
+    for t, block_q, chunk, table in ((512, 128, 256, 1792),
+                                     (2048, 128, 256, 3328),
+                                     (256, 64, 128, 1024)):
+        starts = [int(s) for s in rng.integers(0, table - t, 3)]
+        lens = [s + int(n) for s, n in zip(starts, rng.integers(1, t + 1, 3))]
+        assert count_prefill_blocks(
+            starts, lens, 4, t, block_q, chunk, table, window
+        ) == _brute_force_blocks(starts, lens, 4, t, block_q, chunk, table,
+                                 window)
+
+
+@pytest.mark.parametrize("bucket,width", [(512, 112), (1024, 144),
+                                          (2048, 208)])
+def test_the_kernel_admits_the_mellum_cells_window_tables(bucket, width):
+    # window 1024 + the bucket's keys, wherever they start within a page,
+    # in pages of 16, padded to whole key chunks
+    assert prefill_table_pages(-(-(1024 + bucket) // PAGE) + 1, PAGE) == width
+    assert prefill_kernel_tiles(bucket, 32, 4, HEAD_DIM, PAGE, width,
+                                jnp.bfloat16) == (128, 256)

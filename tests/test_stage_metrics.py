@@ -558,5 +558,8 @@ def test_prefill_attention_launches_and_blocks_are_host_arithmetic(
     assert REGISTRY.get_sample_value(
         launches, {"worker": "a77", "path": "xla"}) == 0
     blocks = "dynamo_prefill_attn_blocks_total"
-    assert sample(blocks, worker="a77", state="live") == 25
-    assert sample(blocks, worker="a77", state="skipped") == 23
+    assert sample(blocks, worker="a77", state="live", group="full") == 25
+    assert sample(blocks, worker="a77", state="skipped", group="full") == 23
+    # one page group: no series for a window group
+    assert REGISTRY.get_sample_value(blocks, {
+        "worker": "a77", "state": "live", "group": "window"}) is None

@@ -508,6 +508,89 @@ def test_the_prefill_attention_kernel_compiles_for_v5e(one_chip, case):
     assert _copies(text, n_pages * PAGE * kh * HEAD_DIM) == []
 
 
+# `paged_prefill_attention_window` at the mellum cell's launches (rows x
+# bucket within 2,048 positions, the bucket's window table:
+# `ModelRunner.window_prefill_width`): 32 heads over 4, window 1,024, the
+# window group's 6 layers of 5,120 pages.
+WINDOW_PREFILL_CASES = {
+    "1x2048-w208": (1, 2048, 208),
+    "2x1024-w144": (2, 1024, 144),
+    "4x512-w112": (4, 512, 112),
+    "1x512-w112": (1, 512, 112),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WINDOW_PREFILL_CASES))
+def test_the_window_prefill_kernel_compiles_for_mellums_launches(one_chip,
+                                                                 case):
+    from dynamo_tpu.ops.paged_attention import (
+        paged_prefill_attention_window,
+        prefill_kernel_tiles,
+    )
+
+    rows, t, width = WINDOW_PREFILL_CASES[case]
+    n_pages, kh, g = 5120, 4, 8
+    assert prefill_kernel_tiles(t, kh * g, kh, HEAD_DIM, PAGE, width,
+                                jnp.bfloat16) == (128, 256)
+    compiled = paged_prefill_attention_window.lower(
+        _shape(one_chip, (rows, t, kh * g, HEAD_DIM), jnp.bfloat16),
+        _shape(one_chip, (6, 2, n_pages, PAGE, kh, HEAD_DIM), jnp.bfloat16),
+        _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (rows, width), jnp.int32),
+        _shape(one_chip, (rows,), jnp.int32),
+        _shape(one_chip, (rows,), jnp.int32), window=1024).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # a name of its own: a breakdown lists the window layers' kernel apart
+    # from the full layers', and the decode kernels' roofline reads neither
+    assert "paged_prefill_attention_window" in text
+    assert "paged_prefill_attention_pool" not in text
+    assert not re.search(r"paged_decode_attention\w* = ", text)
+    assert _copies(text, n_pages * PAGE * kh * HEAD_DIM) == []
+
+
+def test_without_a_window_the_prefill_kernel_traces_as_before():
+    """`window` is a static branch of `_pool_prefill_kernel`: with 0 the
+    dense and the hybrid cells' kernel keeps its six scalar-prefetch
+    operands, its -inf mask and its comparisons (counted at PR 39's
+    kernel; its Mosaic module was compared with the parent's op for op
+    when the window came: PERF.md, PR 41), so their programs keep their
+    text. With a window: the same operands, a finite mask, the lower
+    edge's comparisons."""
+    import collections
+
+    from dynamo_tpu.ops.paged_attention import (
+        paged_prefill_attention_pool,
+        paged_prefill_attention_window,
+    )
+
+    shape = jax.ShapeDtypeStruct
+    args = [shape((1, 512, 32, HEAD_DIM), jnp.bfloat16),
+            shape((2, 2, 64, PAGE, 4, HEAD_DIM), jnp.bfloat16),
+            shape((), jnp.int32), shape((1, 112), jnp.int32),
+            shape((1,), jnp.int32), shape((1,), jnp.int32)]
+
+    def traced(fn, **kw):
+        jaxpr = jax.make_jaxpr(lambda *a: fn(*a, **kw))(*args)
+        (call,) = [e for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
+                   if e.primitive.name == "pallas_call"]
+        text = str(jaxpr)
+        ops = collections.Counter(
+            re.findall(r"= (lt|le|gt|ge|eq|ne|and|min|max)\b", text))
+        return call.params["grid_mapping"].num_index_operands, ops, text
+
+    operands, ops, text = traced(paged_prefill_attention_pool)
+    assert operands == 6
+    assert ops == {"lt": 9, "le": 1, "gt": 1, "eq": 6, "and": 9, "min": 3,
+                   "max": 3}
+    assert "-inf" in text and "-1e+30" not in text
+    operands, win_ops, text = traced(paged_prefill_attention_window,
+                                     window=1024)
+    assert operands == 6
+    assert win_ops["ge"] == 1 and win_ops["gt"] == ops["gt"] + 1
+    assert "-1e+30" in text and "-inf" not in text
+
+
 def test_the_dense_prefill_program_holds_the_kernel_and_no_score_tensor(
         one_chip, monkeypatch):
     """The flagship cell's `[4, 1024]` prefill program (mistral-7b, int4
@@ -580,10 +663,12 @@ def test_the_dense_prefill_program_holds_the_kernel_and_no_score_tensor(
 
 
 def _lowered_hybrid_prefill(one_chip, name, layers, experts, rows, t,
-                            window_pages=0):
-    """StableHLO text of `forward_hybrid` for a cut of a preset, lowered
-    for the described chip around the default `attention_fn` (nothing is
-    compiled: a kernel call is in the text or it is not)."""
+                            window_pages=0, pages=1024, width=64,
+                            window_width=128):
+    """`forward_hybrid` for a cut of a preset, lowered for the described
+    chip around the default `attention_fn` (`.as_text()`: a kernel call
+    is in the StableHLO or it is not; `.compile()` for what XLA makes of
+    the rest)."""
     import functools
 
     from dynamo_tpu.models.config import cut_config, get_config
@@ -592,20 +677,19 @@ def _lowered_hybrid_prefill(one_chip, name, layers, experts, rows, t,
     from dynamo_tpu.ops.paged_attention import paged_attention
 
     cfg = cut_config(get_config(name), layers, experts)
-    width = 64
 
     def on_chip(make):
         return jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype),
                             jax.eval_shape(make))
 
     params = on_chip(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    kv = on_chip(lambda: make_kv_cache(cfg, 1024, PAGE))
+    kv = on_chip(lambda: make_kv_cache(cfg, pages, PAGE))
     state = on_chip(lambda: make_state_cache(cfg, rows))
     window = None
     if window_pages:
         window = (on_chip(lambda: make_kv_cache(cfg, window_pages, PAGE,
                                                 group="window")),
-                  _shape(one_chip, (rows, 128), jnp.int32),
+                  _shape(one_chip, (rows, window_width), jnp.int32),
                   _shape(one_chip, (rows,), jnp.int32))
 
     def prefill(params, kv, state, tokens, positions, tables, kv_lens,
@@ -625,24 +709,50 @@ def _lowered_hybrid_prefill(one_chip, name, layers, experts, rows, t,
     return cfg, jax.jit(prefill).lower(
         params, kv, state, chunk(jnp.int32), chunk(jnp.int32),
         _shape(one_chip, (rows, width), jnp.int32), vec(jnp.int32),
-        chunk(jnp.bool_), vec(jnp.int32), vec(jnp.int32), window).as_text()
+        chunk(jnp.bool_), vec(jnp.int32), vec(jnp.int32), window)
 
 
 def test_a_hybrid_stacks_full_attention_layers_call_the_prefill_kernel(
         one_chip):
-    cfg, text = _lowered_hybrid_prefill(
+    cfg, lowered = _lowered_hybrid_prefill(
         one_chip, "nemotron3-nano-30b-a3b", 13, "0:8", rows=8, t=128)
     assert cfg.layer_pattern.count("*") == 2
+    text = lowered.as_text()
     assert "paged_prefill_attention_pool" in text
+    assert "paged_prefill_attention_window" not in text
 
 
-def test_a_stack_with_window_layers_keeps_its_own_prefill_attention(
+def test_a_stack_with_window_layers_runs_both_page_groups_through_the_kernel(
         one_chip):
-    """`models/hybrid.prefill_attention` replaces `attention_fn` in a
-    model with window layers, full layers included: its `lax.map` over
-    query blocks goes with a kernel measured in that model's own cell."""
-    cfg, text = _lowered_hybrid_prefill(
-        one_chip, "mellum2-12b-a2.5b", 4, None, rows=1, t=1024,
-        window_pages=512)
+    """The mellum cell's widest prefill launch (`[1, 2048]`, 512-page
+    tables over the full group's 32,768 pages, the window group's
+    208-column table over 5,120; one period of the pattern, all 64
+    experts): the window layers call `paged_prefill_attention_window`,
+    the full layer `paged_prefill_attention_pool`, and no float32 array
+    of a block of positions x heads x keys is left of
+    `models/hybrid.prefill_attention` (`f32[1,512,4,8,1552]` a window
+    layer, up to `f32[1,512,4,8,8192]` a full one, each written and read
+    five or six times: PERF.md, PR 41), nor its `lax.map` (a `while`)
+    and four-way `lax.switch`. What is left of the temporaries is the
+    experts' (`[2048 x 8, 1792]` float32 gate|up and its neighbours):
+    as large as the scores were, so the launch's memory does not fall."""
+    cfg, lowered = _lowered_hybrid_prefill(
+        one_chip, "mellum2-12b-a2.5b", 4, None, rows=1, t=2048,
+        window_pages=5120, pages=32768, width=512, window_width=208)
     assert cfg.layer_pattern == "WEWEWE*E"
-    assert "paged_prefill_attention_pool" not in text
+    text = lowered.as_text()
+    assert text.count("paged_prefill_attention_window") >= 3
+    assert "paged_prefill_attention_pool" in text
+    assert "stablehlo.case" not in text
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert not re.search(r"paged_decode_attention\w* = ", hlo)
+    for dims in re.findall(r"\bf32\[([\d,]+)\]", hlo):
+        shape = tuple(int(d) for d in dims.split(","))
+        # by heads: nothing the size of a query block's scores over the
+        # fewest keys the XLA form gathered (the experts' float32 rows
+        # are [positions x 8, width])
+        assert len(shape) < 4 or math.prod(shape) < 512 * 32 * 1024, shape
+    # 0.63 GB, and 0.62 GB with the XLA form: the experts' rows are as
+    # large as its scores were, and XLA had given both one buffer
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
