@@ -630,6 +630,13 @@ class ModelRunner:
         # both x latent layers.
         self.latent_decode_tokens = 0
         self.latent_prefill_expand_tokens = 0
+        # A model with recurrent state (dynamo_ssm_prefill_*): valid
+        # positions x Mamba layers its prefill launches scanned and the
+        # rows they held, by whether a row began at position 0 (fresh:
+        # from zero state) or took its slot's state up (continued).
+        self._state_layers = len(model_config.state_layers)
+        self.ssm_prefill_positions = {"fresh": 0, "continued": 0}
+        self.ssm_prefill_rows = {"fresh": 0, "continued": 0}
         # Launches by program (dynamo_program_launches, _tokens):
         # (entry, key) -> [launches, useful prompt tokens] of served
         # traffic; a warm-up pass lists the keys it walks at 0.
@@ -745,6 +752,12 @@ class ModelRunner:
         each row's (window table, base) where the model has window
         layers."""
         self.prefill_positions += rows * bucket
+        if self._state_layers:
+            for start, length in zip(starts, lengths):
+                carry = "continued" if start else "fresh"
+                self.ssm_prefill_positions[carry] += (length
+                                                      * self._state_layers)
+                self.ssm_prefill_rows[carry] += 1
         if self.config.weight_dtype == "int4":
             from ..ops.q4_linear import count_row_blocks
 
@@ -951,11 +964,16 @@ class ModelRunner:
 
         def layer_init_fn(i: int):
             return jax.jit(
-                lambda k: quantize(
-                    {"layers": [init_layer_params(k, cfg, i)]},
+                lambda k, *gain: quantize(
+                    {"layers": [init_layer_params(k, cfg, i, *gain)]},
                     cfg)["layers"][0],
                 out_shardings=shard["layers"][i])
 
+        gains = [1.0] * cfg.n_layers
+        if cfg.is_hybrid:
+            from ..models.hybrid import branch_gain
+
+            gains = [branch_gain(cfg, i) for i in range(cfg.n_layers)]
         keys = jax.random.split(jax.random.PRNGKey(seed), cfg.n_layers + 2)
         params = top_init_fn()(keys[0], keys[-1])
         layer_fns: dict = {}  # one program per layer kind
@@ -964,7 +982,11 @@ class ModelRunner:
             kind = cfg.layer_kind(i) if cfg.is_hybrid else cfg.layer_is_moe(i)
             if kind not in layer_fns:
                 layer_fns[kind] = layer_init_fn(i)
-            params["layers"].append(layer_fns[kind](keys[i + 1]))
+            # a recipe whose residual writers grow with depth (a tied
+            # hybrid head: `branch_gain`) hands the layer's gain in as
+            # an operand; every other model's program takes the key alone
+            gain = () if gains[i] == 1.0 else (jnp.float32(gains[i]),)
+            params["layers"].append(layer_fns[kind](keys[i + 1], *gain))
         return params
 
     def kernel_paths(self) -> dict:
@@ -1498,8 +1520,16 @@ class ModelRunner:
         tables in float32, and window layers beside full ones, whose XLA
         form does (on the chip both their page groups run the blocked
         kernel since PR 41 and hold no such scores; the bound stays
-        because lifting it changes the program grid: ROADMAP A7)."""
-        return self._windowed or self._latent
+        because lifting it changes the program grid: ROADMAP A7); and
+        Mamba layers where a context runs past one launch (the chunked
+        scan holds float32 [positions, heads, chunk] products, 134 MB
+        each at 2,048 positions x 128 heads, and a grid of rows x
+        bucket past the budget is programs no launch needs: a model
+        whose contexts fit one launch keeps its rows)."""
+        return (self._windowed or self._latent
+                or (self.model_config.has_recurrent_state
+                    and self.config.max_context
+                    > self.config.prefill_buckets[-1]))
 
     def prefill_launch_fits(self, lengths: Sequence[int]) -> bool:
         """`bounds_prefill_launches`: whether rows of these chunk

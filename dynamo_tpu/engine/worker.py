@@ -710,6 +710,14 @@ class TpuWorker:
                  "".join(f" {slot}={paths[slot]}"
                          for slot in ("ssm_update", "expert_gmm")
                          if slot in paths), native)
+        if self.model_config.has_recurrent_state:
+            from ..models.hybrid import state_slot_bytes
+
+            # what --max-batch costs a model whose cache is mostly state
+            per_slot = state_slot_bytes(self.model_config)
+            slots = self.runner_config.max_batch
+            log.info("recurrent state: %d slots x %.1f MB = %.2f GB", slots,
+                     per_slot / 1e6, slots * per_slot / 1e9)
         ENGINE_INFO.labels(
             worker=f"{self.instance_id:x}", platform=paths["platform"],
             device_kind=paths["device_kind"],
@@ -1557,7 +1565,8 @@ class TpuWorker:
         dynamo_engine_positions, dynamo_prefill_row_blocks_total,
         dynamo_prefill_attn_launches_total, dynamo_prefill_attn_blocks_total,
         dynamo_kv_reserved_page_ms, dynamo_kv_window_*, dynamo_latent_*,
-        dynamo_program_launches, dynamo_program_tokens,
+        dynamo_ssm_prefill_*, dynamo_program_launches,
+        dynamo_program_tokens,
         dynamo_device_hbm_bytes)."""
         from ..runtime.metrics import (
             DEVICE_HBM_BYTES,
@@ -1582,6 +1591,8 @@ class TpuWorker:
             PREFILL_ROW_BLOCKS,
             PROGRAM_LAUNCHES,
             PROGRAM_TOKENS,
+            SSM_PREFILL_LAUNCH_ROWS,
+            SSM_PREFILL_POSITIONS,
             SSM_STATE_SLOT_MS,
         )
 
@@ -1639,6 +1650,13 @@ class TpuWorker:
                         win_pool.edge_tokens[phase])
         if stats.state_slot_ms:  # only a model with recurrent state
             SSM_STATE_SLOT_MS.labels(worker=worker).set(stats.state_slot_ms)
+            for carry, count in getattr(
+                    self.runner, "ssm_prefill_positions", {}).items():
+                SSM_PREFILL_POSITIONS.labels(
+                    worker=worker, carry=carry).set(count)
+                SSM_PREFILL_LAUNCH_ROWS.labels(
+                    worker=worker, carry=carry).set(
+                        self.runner.ssm_prefill_rows[carry])
         expanded = getattr(self.runner, "latent_prefill_expand_tokens", 0)
         if expanded:  # only a model with latent attention
             LATENT_DECODE_TOKENS.labels(worker=worker).set(

@@ -117,6 +117,16 @@ class ModelConfig:
     ssm_dt_max: float = 0.1
     ssm_dt_floor: float = 1e-4
     ssm_state_dtype: str = "float32"
+    # granitemoehybrid's four multipliers (`layer_pattern` stacks only;
+    # the dense decoder refuses a preset that sets one): the embedding
+    # x `embedding_multiplier`, every mixer's branch x
+    # `residual_multiplier` before it is added, attention scores
+    # `attention_multiplier` x q.k (0 = 1/sqrt(head_dim)), logits /
+    # `logits_scaling`
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
     # The chip's share of an expert-parallel deployment: (lo, hi) of the
     # published experts held here. The router keeps its n_experts outputs
     # and its top-k; a token routed to an absent expert gets nothing from
@@ -126,6 +136,17 @@ class ModelConfig:
     @property
     def is_hybrid(self) -> bool:
         return bool(self.layer_pattern)
+
+    @property
+    def multipliers(self) -> dict:
+        """The multipliers this preset moves off their defaults, by
+        field name."""
+        return {name: getattr(self, name)
+                for name, default in (("embedding_multiplier", 1.0),
+                                      ("residual_multiplier", 1.0),
+                                      ("attention_multiplier", 0.0),
+                                      ("logits_scaling", 1.0))
+                if getattr(self, name) != default}
 
     @property
     def has_recurrent_state(self) -> bool:
@@ -405,6 +426,44 @@ PRESETS: dict[str, ModelConfig] = {
         n_experts=8, n_experts_active=2, expert_mlp_hidden=48,
         moe_norm_topk=True,
     ),
+    # IBM granite-4.0-h-small (config.json, model_type granitemoehybrid)
+    # at its published sizes: 40 pre-norm blocks, each a token mixer then
+    # 72 SwiGLU experts 768 wide (top-10, a softmax over the chosen
+    # logits) with a shared expert 1536 wide, so 80 mixers; nine token
+    # mixers in ten are Mamba-2 (128 heads of 64, ONE group of B and C,
+    # state 128), the sixth of every ten grouped-query attention with no
+    # positional term and scores 1/128 x q.k. The embedding is read x 12
+    # going in and, tied, / 16 coming out; every branch joins the
+    # residual stream x 0.22. `--serve-layers` counts blocks.
+    "granite-4.0-h-small": ModelConfig(
+        name="granite-4.0-h-small", vocab_size=100352, hidden=4096,
+        n_layers=80, layer_pattern=("ME" * 5 + "*E" + "ME" * 4) * 4,
+        mixers_per_layer=2,
+        n_q_heads=32, n_kv_heads=8, head_dim=128, mlp_hidden=768,
+        rms_eps=1e-5, use_rope=False, tie_embeddings=True,
+        max_context=131072,
+        n_experts=72, n_experts_active=10, expert_mlp_hidden=768,
+        n_shared_experts=1, shared_expert_hidden=1536, moe_norm_topk=True,
+        mamba_heads=128, mamba_head_dim=64, ssm_groups=1, ssm_state=128,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.0078125, logits_scaling=16.0,
+    ),
+    # CPU sibling: one period of the same pattern, 4 Mamba heads in one
+    # group, 8 experts top-3, a shared expert, a tied head, all four
+    # multipliers off 1
+    "tiny-granite-test": ModelConfig(
+        name="tiny-granite-test", vocab_size=512, hidden=64, n_layers=20,
+        layer_pattern="ME" * 5 + "*E" + "ME" * 4, mixers_per_layer=2,
+        n_q_heads=4, n_kv_heads=2, head_dim=16, mlp_hidden=48,
+        rms_eps=1e-5, use_rope=False, tie_embeddings=True,
+        max_context=1024,
+        n_experts=8, n_experts_active=3, expert_mlp_hidden=48,
+        n_shared_experts=1, shared_expert_hidden=96, moe_norm_topk=True,
+        mamba_heads=4, mamba_head_dim=16, ssm_groups=1, ssm_state=32,
+        ssm_chunk=16,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.03125, logits_scaling=16.0,
+    ),
     # CPU sibling: every layer kind at least twice, 8 experts top-2, a
     # shared expert, 4 Mamba heads in 2 groups
     "tiny-hybrid-test": ModelConfig(
@@ -508,7 +567,10 @@ def cut_config(config: ModelConfig, layers: Optional[int] = None,
         if not 0 < vocab_rows <= config.vocab_size:
             raise ValueError(f"--vocab-rows {vocab_rows}: {config.name} "
                              f"has {config.vocab_size} rows")
-        if config.tie_embeddings and vocab_rows != config.vocab_size:
+        if (config.tie_embeddings and not config.is_hybrid
+                and vocab_rows != config.vocab_size):
+            # a `layer_pattern` stack's tied head contracts the held
+            # rows of the embedding itself (models/hybrid._head)
             raise ValueError("--vocab-rows needs an untied output head")
         changes["vocab_size"] = vocab_rows
     return dataclasses.replace(config, **changes) if changes else config

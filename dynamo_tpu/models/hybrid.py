@@ -3,17 +3,33 @@
 experts; mellum's window and full attention over SwiGLU experts, a
 published block being two mixers; pangu_ultra_moe's latent attention
 over a dense SwiGLU or routed experts with a shared one, every branch
-normed again before it is added).
+normed again before it is added; granitemoehybrid's Mamba-2 or attention
+mixer over SwiGLU experts with a shared one, a published block being
+two mixers, with the family's four multipliers and a tied head).
 
     x <- x + Mixer_l(RMSNorm(x; w_l))          after the last: RMSNorm, head
     x <- x + RMSNorm(Mixer_l(RMSNorm(x; w_l)); post_l)    with `sandwich_norm`
 
+With granitemoehybrid's multipliers (each a `ModelConfig` field at its
+default for every other family, where nothing of it is traced):
+
+    x_0 = embedding_multiplier * Emb[token]
+    x <- x + residual_multiplier * Mixer_l(RMSNorm(x; w_l))
+    attention scores = attention_multiplier * q.k    (not 1/sqrt(head_dim))
+    logits = RMSNorm(x) Head / logits_scaling
+
+The head is `lm_head` [h, rows], or with `tie_embeddings` the embedding's
+own [rows, h] array contracted over h (`_head`: no transposed copy).
+
   M  Mamba-2: [z | xBC | dt] = x W_in; xBC <- silu(conv1d_k4(xBC) + b);
      xBC -> x [H, P], B [G, N], C [G, N]; dt <- softplus(dt + dt_bias);
      S_t = exp(dt A) S_{t-1} + dt (x outer B); y = S C + D x;
-     y <- GroupRMSNorm(y * silu(z)) (gate first, then norm); out = y W_out
+     y <- GroupRMSNorm(y * silu(z)) (gate first, then norm); out = y W_out.
+     One mixer a block (nemotron_h) or the first of two (granitemoehybrid:
+     G = 1, the norm over all H x P lanes as one group)
   *  attention: grouped-query, causal softmax; rope where `use_rope`
-     (YaRN where `rope_yarn_factor`), none for nemotron_h
+     (YaRN where `rope_yarn_factor`), none for nemotron_h and
+     granitemoehybrid
   W  the same over the last `sliding_window` positions, default rope:
      scores masked to q_pos - window < kv_pos <= q_pos
   E  routed experts: sigmoid scores, top-k of scores + bias (of the raw
@@ -237,10 +253,61 @@ def _shared_width(config: ModelConfig) -> int:
             or config.n_shared_experts * config.expert_mlp_hidden)
 
 
+# Seeded recipe, tied head: a branch writes `BRANCH_GROWTH` times wider
+# than the mixer before it (`branch_gain`).
+BRANCH_GROWTH = 1.23
+
+
+def branch_gain(config: ModelConfig, layer_idx: int) -> float:
+    """Seeded recipe: what a matrix that writes into the residual stream
+    (out_proj, wo, every down-projection) of mixer `layer_idx` is
+    multiplied by. 1 for an untied head. A TIED head reads the logits
+    off the embedding, so whatever of Emb[token] is left in the last
+    hidden state scores token itself: at cosine c between the two the
+    self-logit is c sqrt(h) times the logits' spread, and a random
+    model in which the embedding is a fifth of the stream (equal
+    branches, twenty mixers) answers every token with itself. Trained
+    models have streams that grow with depth; so has this one. The
+    embedding enters with entries of spread s0 = embedding_multiplier x
+    logits_scaling / sqrt(h) (`init_top_params` draws it logits_scaling /
+    sqrt(h) wide); a Mamba branch of unit gain writes entries of spread
+    about 1, so a gain of s0 / residual_multiplier adds what the stream
+    holds, and BRANCH_GROWTH a mixer (1.51 a block) keeps it so: after
+    20 mixers the embedding is under 1/60 of the state, the self-logit
+    under the spread, while the first blocks see embedding and branches
+    side by side, which is where the multipliers' ratio matters."""
+    if not config.tie_embeddings:
+        return 1.0
+    s0 = (config.embedding_multiplier * config.logits_scaling
+          / math.sqrt(config.hidden))
+    return s0 / config.residual_multiplier * BRANCH_GROWTH ** layer_idx
+
+
+def score_gain(config: ModelConfig) -> float:
+    """Seeded recipe: what wq and wk are multiplied by, so that scores
+    at `attention_multiplier` have the spread they have at
+    1/sqrt(head_dim) with unit gains."""
+    if not config.attention_multiplier:
+        return 1.0
+    return (config.attention_multiplier
+            * math.sqrt(config.head_dim)) ** -0.5
+
+
+def attention_scale(config: ModelConfig) -> dict:
+    """The keyword a model that states its own score scale hands every
+    attention function (kernels and XLA forms alike); nothing for the
+    others, whose programs trace 1/sqrt(head_dim) as they always did."""
+    return ({"sm_scale": config.attention_multiplier}
+            if config.attention_multiplier else {})
+
+
 def init_hybrid_layer(k: jax.Array, config: ModelConfig,
-                      layer_idx: int) -> dict:
+                      layer_idx: int, out_gain=None) -> dict:
     """Seeded weights of one layer. The recipe is restated, not imported,
     by benchmarks/references/nemotron_h.py; the tests hold the two equal.
+    `out_gain`: `branch_gain(config, layer_idx)` handed in as a traced
+    float32 scalar, so that one compiled program draws every layer of a
+    kind (`ModelRunner._init_random_params`); None: computed here.
 
     The layer's key splits 15 ways. Matrices are normal / sqrt(fan_in) in
     the model dtype; one that writes into the residual stream (out_proj,
@@ -261,6 +328,15 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
     shared expert no s_up / s_down (no draw is made for either, and the
     other keys are unmoved).
 
+    A model that states multipliers (granitemoehybrid; gains of 1 and
+    the recipe above for every other): wq and wk are drawn
+    (attention_multiplier sqrt(head_dim))^-1/2 times wider, so that
+    scores keep the spread 1 they have at 1/sqrt(head_dim); and with a
+    TIED head a matrix that writes into the residual stream is drawn
+    `branch_gain` times wider, growing with the mixer's published index
+    (`branch_gain` has the why: the embedding's share of the last
+    hidden state has to be small, or every token predicts itself).
+
     A latent-attention layer draws W_dq, W_uq, W_dkv, W_o (centred),
     W_kr, W_uk, W_uv from keys 0..6, each normal / sqrt(its fan_in): the
     rank for W_uq, W_uk and W_uv, whose inputs are normed to unit RMS.
@@ -277,12 +353,16 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
     h = config.hidden
     ks = jax.random.split(k, 15)
 
-    def dense(key, shape, fan_in, centre=None):
+    def dense(key, shape, fan_in, centre=None, gain=1.0):
         w = jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
         if centre is not None:
             w = w - jnp.mean(w, axis=centre, keepdims=True)
+        if not (isinstance(gain, float) and gain == 1.0):
+            w = w * gain
         return w.astype(dtype)
 
+    if out_gain is None:
+        out_gain = branch_gain(config, layer_idx)
     kind = config.layer_kind(layer_idx)
     p = {"norm": jnp.ones((h,), dtype)}
     if config.sandwich_norm:
@@ -303,7 +383,7 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
                           q_rank).reshape(q_rank, -1),
             "w_dkv": dense(ks[2], (h, rank), h),
             "kv_norm": jnp.ones((rank,), dtype),
-            "wo": dense(ks[3], (qh, vd, h), qh * vd, (0, 1)),
+            "wo": dense(ks[3], (qh, vd, h), qh * vd, (0, 1), out_gain),
             "w_kr": dense(ks[4], (h, rd), h),
             "w_uk": dense(ks[5], (rank, qh, nope),
                           rank).transpose(1, 2, 0),  # [heads, nope, rank]
@@ -315,7 +395,7 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
         p.update({
             "d_up": jnp.concatenate([dense(ks[0], (h, m), h),
                                      dense(ks[1], (h, m), h)], axis=1),
-            "d_down": dense(ks[2], (m, h), m, 0),
+            "d_down": dense(ks[2], (m, h), m, 0, out_gain),
         })
     elif kind == "M":
         nh, inner = config.mamba_heads, config.mamba_inner
@@ -335,15 +415,16 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
                 ks[4], (nh,), jnp.float32, 1.0, 16.0)),
             "d_skip": jnp.ones((nh,), jnp.float32),
             "ssm_norm": jnp.ones((inner,), dtype),
-            "out_proj": dense(ks[6], (inner, h), inner, 0),
+            "out_proj": dense(ks[6], (inner, h), inner, 0, out_gain),
         })
     elif kind in "*W":
         qh, kh, hd = config.n_q_heads, config.n_kv_heads, config.head_dim
+        qk_gain = score_gain(config)
         p.update({
-            "wq": dense(ks[0], (h, qh, hd), h),
-            "wk": dense(ks[1], (h, kh, hd), h),
+            "wq": dense(ks[0], (h, qh, hd), h, gain=qk_gain),
+            "wk": dense(ks[1], (h, kh, hd), h, gain=qk_gain),
             "wv": dense(ks[2], (h, kh, hd), h),
-            "wo": dense(ks[3], (qh, hd, h), qh * hd, (0, 1)),
+            "wo": dense(ks[3], (qh, hd, h), qh * hd, (0, 1), out_gain),
         })
     else:
         m = config.expert_mlp_hidden
@@ -360,7 +441,8 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
             # stored [E, m, h]: see ops/grouped_matmul.expert_gmm
             "e_up": up_of(ks[9]),
             "e_down": jax.vmap(lambda e: dense(
-                jax.random.fold_in(ks[10], e), (m, h), m, 0))(ids),
+                jax.random.fold_in(ks[10], e), (m, h), m, 0,
+                out_gain))(ids),
         })
         if config.mlp_act == "swiglu":  # [E, gate | up, h]
             p["e_up"] = jnp.concatenate([p["e_up"], up_of(ks[11])], axis=1)
@@ -369,7 +451,7 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
                 ks[8], (config.n_experts,), jnp.float32)
         if sm:
             p["s_up"] = dense(ks[12], (h, sm), h)
-            p["s_down"] = dense(ks[13], (sm, h), sm, 0)
+            p["s_down"] = dense(ks[13], (sm, h), sm, 0, out_gain)
             if config.mlp_act == "swiglu":  # [gate | up]
                 p["s_up"] = jnp.concatenate(
                     [p["s_up"], dense(ks[14], (h, sm), h)], axis=1)
@@ -757,8 +839,31 @@ def moe_mixer(x, lp, config: ModelConfig, valid, gmm_path: str):
 
 def _head(x, params, config: ModelConfig):
     x = rms_norm(x, params["final_norm"], config.rms_eps)
-    return jnp.einsum("...h,hv->...v", x, params["lm_head"]
-                      ).astype(jnp.float32)
+    if config.tie_embeddings:
+        # the embedding's own [rows, h] array, contracted over h: a
+        # transposed copy of it (0.41 GB at 50,176 x 4096) is in no step
+        with jax.named_scope("tied_head"):
+            logits = jnp.einsum("...h,vh->...v", x, params["embed"]
+                                ).astype(jnp.float32)
+    else:
+        logits = jnp.einsum("...h,hv->...v", x, params["lm_head"]
+                            ).astype(jnp.float32)
+    if config.logits_scaling != 1.0:
+        logits = logits / config.logits_scaling
+    return logits
+
+
+def _embed(params, config: ModelConfig, tokens):
+    x = params["embed"][tokens]
+    if config.embedding_multiplier != 1.0:
+        x = x * config.embedding_multiplier
+    return x
+
+
+def _branch(out, config: ModelConfig):
+    """A mixer's output as it joins the residual stream."""
+    return (out if config.residual_multiplier == 1.0
+            else out * config.residual_multiplier)
 
 
 # ---------------------------------------------------------------------------
@@ -872,7 +977,8 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
         win_attention = _group_attention(attention_fn, q_shape, win_cache,
                                          win_tables)
     fresh = positions[:, 0] == 0  # a row at position 0 starts from zero
-    x = params["embed"][tokens]
+    scaled = attention_scale(config)
+    x = _embed(params, config, tokens)
     conv_out, ssm_out = list(state["conv"]), list(state["ssm"])
     stats = jnp.zeros((moe_stats_size(config),), jnp.int32)
     kv_idx = win_idx = state_idx = 0
@@ -893,7 +999,7 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
                 kv_cache = write_kv_pages(kv_cache, kv_idx, k, v,
                                           block_tables, positions, valid)
                 attn = attention(q, kv_cache, kv_idx, block_tables,
-                                 positions, kv_lens)
+                                 positions, kv_lens, **scaled)
                 out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])
             kv_idx += 1
         elif kind == "W":
@@ -918,7 +1024,7 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
             stats = stats + layer_stats
         if config.sandwich_norm:
             out = rms_norm(out, lp["post_norm"], config.rms_eps)
-        x = x + out
+        x = x + _branch(out, config)
     state = {"conv": conv_out, "ssm": ssm_out}
     if not all_logits:
         x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
@@ -943,7 +1049,8 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
         win_cache, win_tables, win_pos, win_lens = _window_frame(
             window, positions, kv_lens)
         win_attn_lens = jnp.where(active, win_lens, 0)
-    x = params["embed"][tokens]  # [S, h]
+    scaled = attention_scale(config)
+    x = _embed(params, config, tokens)  # [S, h]
     conv_out, ssm_out = list(state["conv"]), list(state["ssm"])
     stats = jnp.zeros((moe_stats_size(config),), jnp.int32)
     ks, vs, win_ks, win_vs = [], [], [], []
@@ -961,7 +1068,7 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
                 q, k, v = _qkv(h[:, None, :], lp, config, kind,
                                positions[:, None])
                 attn = attn_fn(q, kv_cache, kv_idx, block_tables, attn_lens,
-                               k, v)
+                               k, v, **scaled)
                 out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])[:, 0]
             ks.append(k)
             vs.append(v)
@@ -992,7 +1099,7 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
             stats = stats + layer_stats
         if config.sandwich_norm:
             out = rms_norm(out, lp["post_norm"], config.rms_eps)
-        x = x + out
+        x = x + _branch(out, config)
     if config.has_latent_layers:
         kv_cache = write_latent_stack(kv_cache, jnp.stack(ks), block_tables,
                                       positions, active)

@@ -40,10 +40,13 @@ def param_axes(config: ModelConfig) -> dict:
     if config.is_hybrid:
         from .hybrid import hybrid_layer_axes
 
+        head = ({} if config.tie_embeddings
+                else {"lm_head": ("embed", "vocab")})
         return {"embed": ("vocab", "embed"), "final_norm": ("embed",),
-                "lm_head": ("embed", "vocab"),
+                **head,
                 "layers": [hybrid_layer_axes(config, i)
                            for i in range(config.n_layers)]}
+    _refuse_multipliers(config)
     layer = {
         "attn_norm": ("embed",),
         "wq": ("embed", "q_heads", "head_dim"),
@@ -115,20 +118,36 @@ def param_axes(config: ModelConfig) -> dict:
     return axes
 
 
-def _init_dense(k: jax.Array, shape, fan_in: int, dtype) -> jax.Array:
+def _refuse_multipliers(config: ModelConfig) -> None:
+    """The dense decoder applies none of granitemoehybrid's multipliers
+    (models/hybrid.py does): a preset that sets one and has no
+    `layer_pattern` would be served silently unscaled."""
+    if config.multipliers:
+        raise ValueError(
+            f"{config.name} sets "
+            + ", ".join(f"{k}={v}" for k, v in config.multipliers.items())
+            + ", which the dense decoder (models/transformer.py) would "
+            "ignore: only a `layer_pattern` stack (models/hybrid.py) "
+            "applies them")
+
+
+def _init_dense(k: jax.Array, shape, fan_in: int, dtype,
+                gain: float = 1.0) -> jax.Array:
     return (jax.random.normal(k, shape, dtype=jnp.float32)
-            * (1.0 / math.sqrt(fan_in))).astype(dtype)
+            * (gain / math.sqrt(fan_in))).astype(dtype)
 
 
 def init_layer_params(k: jax.Array, config: ModelConfig,
-                      layer_idx: int) -> dict:
+                      layer_idx: int, out_gain=None) -> dict:
     """One layer of `init_params` (same values for the same key). Its
     own entry point so a 7B engine can initialise and quantise a layer
-    at a time instead of compiling, and holding, the whole bf16 tree."""
+    at a time instead of compiling, and holding, the whole bf16 tree.
+    `out_gain`: a hybrid layer's `branch_gain` as a traced scalar
+    (models/hybrid.init_hybrid_layer)."""
     if config.is_hybrid:
         from .hybrid import init_hybrid_layer
 
-        return init_hybrid_layer(k, config, layer_idx)
+        return init_hybrid_layer(k, config, layer_idx, out_gain)
     dtype = jnp.dtype(config.dtype)
     h, hd = config.hidden, config.head_dim
     qh, kh, m = config.n_q_heads, config.n_kv_heads, config.mlp_hidden
@@ -214,13 +233,18 @@ def init_top_params(k_embed: jax.Array, k_head: jax.Array,
     """Everything in `init_params` outside the layer list."""
     dtype = jnp.dtype(config.dtype)
     h = config.hidden
+    # The matrix the logits are read from is drawn `logits_scaling`
+    # times wider, so that logits / logits_scaling have the spread 1
+    # every recipe gives: the embedding itself where the head is tied.
+    tied = config.tie_embeddings
     params = {
-        "embed": _init_dense(k_embed, (config.vocab_size, h), h, dtype),
+        "embed": _init_dense(k_embed, (config.vocab_size, h), h, dtype,
+                             config.logits_scaling if tied else 1.0),
         "final_norm": jnp.ones((h,), dtype),
     }
-    if not config.tie_embeddings:
+    if not tied:
         params["lm_head"] = _init_dense(k_head, (h, config.vocab_size), h,
-                                        dtype)
+                                        dtype, config.logits_scaling)
     return params
 
 
@@ -753,6 +777,11 @@ def write_kv_pages(
     return values
 
 
+def _scaled(scores, hd: int, sm_scale: Optional[float]):
+    """Attention scores at 1/sqrt(hd), or at the scale a model states."""
+    return scores / math.sqrt(hd) if sm_scale is None else scores * sm_scale
+
+
 def paged_attention_xla(
     q: jax.Array,  # [B, T, qh, hd]
     kv_cache: jax.Array,  # [L, 2, P, ps, kh, hd]
@@ -763,6 +792,7 @@ def paged_attention_xla(
     window: int = 0,
     kv_offset: Optional[jax.Array] = None,
     flat_gather: bool = False,
+    sm_scale: Optional[float] = None,
 ) -> jax.Array:
     """Reference paged attention: gather the sequence's pages, run masked
     SDPA. Correct everywhere (CPU tests, fallback, the kernels' oracle);
@@ -780,7 +810,9 @@ def paged_attention_xla(
     queries can see). `flat_gather` reads the pages out of the pool as
     one array of all its layers' pages: inside a loop or a branch
     `values[layer, 0]` is a copy of the layer's whole pool before the
-    gather (1.07 GB a full layer of 32,768 pages: PERF.md, PR 36)."""
+    gather (1.07 GB a full layer of 32,768 pages: PERF.md, PR 36).
+    `sm_scale`: the score scale of a model that states one (None:
+    1/sqrt(hd))."""
     values, scales = _kv_parts(kv_cache)
     b, t, qh, hd = q.shape
     ps = values.shape[3]
@@ -807,8 +839,8 @@ def paged_attention_xla(
         v = v.astype(jnp.float32) * v_s[..., None, None]
     group = qh // kh
     qg = q.reshape(b, t, kh, group, hd)
-    scores = jnp.einsum("btkgh,bskh->btkgs", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) / math.sqrt(hd)
+    scores = _scaled(jnp.einsum("btkgh,bskh->btkgs", qg.astype(jnp.float32),
+                                k.astype(jnp.float32)), hd, sm_scale)
     kv_pos = jnp.arange(ctx)[None, :]  # [1, ctx]
     if kv_offset is not None:
         kv_pos = kv_pos + kv_offset[:, None]  # [B, ctx]
@@ -833,6 +865,7 @@ def paged_attention_decode_xla(
     k_cur: jax.Array,  # [B, 1, kh, hd] current token's K (not yet cached)
     v_cur: jax.Array,
     window: int = 0,
+    sm_scale: Optional[float] = None,
 ) -> jax.Array:
     """Decode attention over cached history PLUS the in-register current
     token. The current K/V never round-trips through the paged pool inside
@@ -862,17 +895,17 @@ def paged_attention_decode_xla(
         v = v.astype(jnp.float32) * v_s[..., None, None]
     group = qh // kh
     qg = q.reshape(b, kh, group, hd)
-    scores = jnp.einsum("bkgh,bskh->bkgs", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) / math.sqrt(hd)
+    scores = _scaled(jnp.einsum("bkgh,bskh->bkgs", qg.astype(jnp.float32),
+                                k.astype(jnp.float32)), hd, sm_scale)
     # History: positions 0 .. kv_len-2 (the current token is separate).
     kv_pos = jnp.arange(ctx)[None, :]
     mask = kv_pos < (kv_lens[:, None] - 1)
     if window:
         mask = mask & (kv_pos >= kv_lens[:, None] - window)
     scores = jnp.where(mask[:, None, None, :], scores, -1e30)
-    cur = jnp.einsum("bkgh,bkh->bkg",
-                     qg.astype(jnp.float32),
-                     k_cur[:, 0].astype(jnp.float32)) / math.sqrt(hd)
+    cur = _scaled(jnp.einsum("bkgh,bkh->bkg",
+                             qg.astype(jnp.float32),
+                             k_cur[:, 0].astype(jnp.float32)), hd, sm_scale)
     full = jnp.concatenate([scores, cur[..., None]], axis=-1)
     probs = jax.nn.softmax(full, axis=-1)
     out = (
@@ -1562,6 +1595,7 @@ def forward(
     """
     # Which row blocks of the launch hold a real position, reduced once
     # here for every int4 projection below (None: the launch takes no map).
+    _refuse_multipliers(config)
     rows = None
     if valid is None:
         valid = jnp.ones(tokens.shape, dtype=bool)

@@ -396,6 +396,7 @@ def _pool_decode_kernel(
     batch_size: int,
     quantized: bool = False,
     windowed: bool = False,
+    sm_scale: float | None = None,
 ):
     """Flash decode over the paged HISTORY reading the WHOLE pool ref.
 
@@ -467,6 +468,8 @@ def _pool_decode_kernel(
     g = rows // kh
     bk = pages_per_chunk * ps
     block_tok = block_pages * ps
+    if sm_scale is None:  # a model that states no scale of its own
+        sm_scale = 1.0 / math.sqrt(hd)
     n_blocks = pages_per_chunk // block_pages
     layer = layer_ref[0]
     length = lengths_ref[b]
@@ -529,7 +532,6 @@ def _pool_decode_kernel(
         wait_copy(b, i, slot)
         q = q_ref[0]  # [kh*g, hd], the matmul operand dtype
         cols = block_tok * kh
-        sm_scale = 1.0 / math.sqrt(hd)
         # Column c of a score tile is (token c // kh, kv head c % kh);
         # row r belongs to kv head r // g. Static but for the length.
         col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
@@ -593,9 +595,11 @@ def _pool_decode_kernel(
 
 
 def _pool_flash_partials(q, kv_pool, layer, block_tables, kv_lens_hist,
-                         kv_scales, starts, pages_per_chunk, interpret):
+                         kv_scales, starts, pages_per_chunk, interpret,
+                         sm_scale=None):
     """The pallas_call both jitted entry points share; `starts` not None
-    is the windowed kernel (one more scalar prefetch)."""
+    is the windowed kernel (one more scalar prefetch); `sm_scale` the
+    score scale of a model that states one (None: 1/sqrt(hd))."""
     quantized = kv_scales is not None
     windowed = starts is not None
     b, qh, hd = q.shape
@@ -635,7 +639,8 @@ def _pool_flash_partials(q, kv_pool, layer, block_tables, kv_lens_hist,
                 jnp.ones((1,), jnp.int32)]  # init flag
     kernel = functools.partial(_pool_decode_kernel, pages_per_chunk=ppc,
                                block_pages=block_pages, max_pages=max_pages,
-                               batch_size=b, quantized=quantized)
+                               batch_size=b, quantized=quantized,
+                               sm_scale=sm_scale)
     if windowed:
         prefetch.append(starts.astype(jnp.int32))
         kernel = functools.partial(kernel, windowed=True)
@@ -670,7 +675,8 @@ def _pool_flash_partials(q, kv_pool, layer, block_tables, kv_lens_hist,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("pages_per_chunk", "interpret"),
+                   static_argnames=("pages_per_chunk", "interpret",
+                                    "sm_scale"),
                    # Read-only on the WHOLE paged pool by design: the
                    # decode step that calls this still owns (and
                    # donates) the cache through its own jit boundary.
@@ -685,6 +691,7 @@ def paged_decode_attention_pool(
     *,
     pages_per_chunk: int | None = None,
     interpret: bool = False,
+    sm_scale: float | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Chunked-DMA flash partials over the paged history; see
     _pool_decode_kernel for what it streams and how it scores. Returns
@@ -697,7 +704,7 @@ def paged_decode_attention_pool(
     few grid steps whatever width the scheduler bucketed it to."""
     return _pool_flash_partials(q, kv_pool, layer, block_tables,
                                 kv_lens_hist, kv_scales, None,
-                                pages_per_chunk, interpret)
+                                pages_per_chunk, interpret, sm_scale)
 
 
 @functools.partial(jax.jit,
@@ -755,7 +762,7 @@ def _q8_needs_xla(values, scales, interpret: bool) -> bool:
             and values.shape[5] != scales.shape[-1])
 
 
-def _combine_current(q, acc, m, l, k_cur, v_cur):
+def _combine_current(q, acc, m, l, k_cur, v_cur, sm_scale=None):
     """Fold the in-register current token into unnormalized flash partials
     (the deferred-write combine shared by both kernel variants)."""
     b, _, qh, hd = q.shape
@@ -764,7 +771,9 @@ def _combine_current(q, acc, m, l, k_cur, v_cur):
     qg = q[:, 0].reshape(b, kh, group, hd)
     s_cur = jnp.einsum(
         "bkgh,bkh->bkg", qg.astype(jnp.float32),
-        k_cur[:, 0].astype(jnp.float32)) / math.sqrt(hd)
+        k_cur[:, 0].astype(jnp.float32))
+    s_cur = (s_cur / math.sqrt(hd) if sm_scale is None
+             else s_cur * sm_scale)
     m_new = jnp.maximum(m, s_cur)
     alpha = jnp.exp(m - m_new)  # 0 when history empty (m = -inf)
     beta = jnp.exp(s_cur - m_new)
@@ -786,6 +795,7 @@ def paged_attention_decode_pool(
     window: int = 0,
     pages_per_chunk: int | None = None,
     interpret: bool = False,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """Deferred-write decode attention via the whole-pool chunked-DMA
     kernel — the production TPU path: no per-layer pool slices (no copies),
@@ -799,9 +809,13 @@ def paged_attention_decode_pool(
     takes the q8 path: half the page bytes, the per-token scales applied
     to the scores and the probabilities. `window` > 0: a window layer's
     page group (its own table and lengths; bf16 pool only), through
-    `paged_decode_attention_window`."""
+    `paged_decode_attention_window`. `sm_scale`: the score scale of a
+    model that states one (a full layer's bf16 pool only; None:
+    1/sqrt(hd), and nothing of it is traced)."""
     values, scales = (kv_cache if isinstance(kv_cache, tuple)
                       else (kv_cache, None))
+    assert sm_scale is None or (scales is None and not window), (
+        "sm_scale: a bf16 full group")
     if _q8_needs_xla(values, scales, interpret):
         from ..models.transformer import paged_attention_decode_xla
 
@@ -817,8 +831,9 @@ def paged_attention_decode_pool(
         q[:, 0], values, layer, block_tables,
         jnp.maximum(kv_lens - 1, 0), kv_scales=scales,
         pages_per_chunk=pages_per_chunk, interpret=interpret,
+        sm_scale=sm_scale,
     )
-    return _combine_current(q, acc, m, l, k_cur, v_cur)
+    return _combine_current(q, acc, m, l, k_cur, v_cur, sm_scale)
 
 
 # The latent pool's kernel streams chunks of this many tokens (a grid
@@ -1338,6 +1353,7 @@ def _pool_prefill_kernel(
     batch_size: int,
     quantized: bool,
     window: int = 0,
+    sm_scale: float | None = None,
 ):
     """Blocked causal attention of a prefill launch over the paged pool,
     the launch's own keys included (`write_kv_pages` has put them there).
@@ -1410,7 +1426,8 @@ def _pool_prefill_kernel(
     rows = block_q * group
     bk = pages_per_chunk * ps
     layer = layer_ref[0]
-    sm_scale = 1.0 / math.sqrt(hd)
+    if sm_scale is None:  # a model that states no scale of its own
+        sm_scale = 1.0 / math.sqrt(hd)
 
     def block_live(bi, qi):
         return qi * block_q < lengths_ref[bi] - starts_ref[bi]
@@ -1576,9 +1593,10 @@ def _pool_prefill_kernel(
 
 
 def _pool_prefill_call(q, kv_pool, layer, block_tables, starts, kv_lens,
-                       kv_scales, window, interpret):
+                       kv_scales, window, interpret, sm_scale=None):
     """The pallas_call both jitted entry points share; `window` > 0 is
-    the window layers' form, under its own name."""
+    the window layers' form, under its own name; `sm_scale` the score
+    scale of a model that states one (None: 1/sqrt(hd))."""
     quantized = kv_scales is not None
     b, t, qh, hd = q.shape
     ps, kh = kv_pool.shape[3], kv_pool.shape[4]
@@ -1615,7 +1633,8 @@ def _pool_prefill_call(q, kv_pool, layer, block_tables, starts, kv_lens,
         functools.partial(_pool_prefill_kernel, block_q=block_q,
                           group=group, pages_per_chunk=ppc,
                           max_pages=max_pages, batch_size=b,
-                          quantized=quantized, window=window),
+                          quantized=quantized, window=window,
+                          sm_scale=sm_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=(b, t // block_q, max_pages // ppc),
@@ -1636,7 +1655,7 @@ def _pool_prefill_call(q, kv_pool, layer, block_tables, starts, kv_lens,
       jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32), *operands)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",),
+@functools.partial(jax.jit, static_argnames=("interpret", "sm_scale"),
                    donate_argnums=())  # read-only on the whole pool
 def paged_prefill_attention_pool(
     q: jax.Array,  # [B, T, qh, hd]
@@ -1648,6 +1667,7 @@ def paged_prefill_attention_pool(
     kv_scales=None,  # bf16 [L, 2, P, ps, LANES] for an int8 pool
     *,
     interpret: bool = False,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """Causal attention of a prefill launch, `_pool_prefill_kernel`: row b
     holds the consecutive positions starts[b].. of which the first
@@ -1655,7 +1675,7 @@ def paged_prefill_attention_pool(
     (`paged_attention`) has checked the geometry with
     `prefill_kernel_tiles`."""
     return _pool_prefill_call(q, kv_pool, layer, block_tables, starts,
-                              kv_lens, kv_scales, 0, interpret)
+                              kv_lens, kv_scales, 0, interpret, sm_scale)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"),
@@ -1691,6 +1711,7 @@ def paged_attention(
     *,
     window: int = 0,
     interpret: bool = False,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """Drop-in `attention_fn` for `models.transformer.forward` and the
     attention layers of `models.hybrid.forward_hybrid`, full and window
@@ -1706,13 +1727,15 @@ def paged_attention(
     `positions[:, 0]` and its valid count `kv_lens - positions[:, 0]`, as
     every prefill launch lays its rows out. One token (T == 1) over a
     bf16 pool runs the per-layer flash decode kernel. Everything else
-    takes `paged_attention_xla`, the CPU path and the oracle of both."""
+    takes `paged_attention_xla`, the CPU path and the oracle of both.
+    `sm_scale`: the score scale of a model that states one (the full
+    layers' kernel and the XLA form; None: 1/sqrt(hd), nothing traced)."""
     from ..models.transformer import paged_attention_xla
 
     values, scales = (kv_cache if isinstance(kv_cache, tuple)
                       else (kv_cache, None))
     _, t, qh, hd = q.shape
-    if t == 1 and scales is None and not window:
+    if t == 1 and scales is None and not window and sm_scale is None:
         out = paged_decode_attention(
             q[:, 0], values[layer, 0], values[layer, 1],
             block_tables, kv_lens, interpret=interpret,
@@ -1723,11 +1746,13 @@ def paged_attention(
             block_tables.shape[1], values.dtype,
             None if scales is None else scales.shape[-1]) is not None:
         if window:
+            assert sm_scale is None, "sm_scale: a full group's layers"
             return paged_prefill_attention_window(
                 q, values, layer, block_tables, positions[:, 0], kv_lens,
                 kv_scales=scales, window=window, interpret=interpret)
         return paged_prefill_attention_pool(
             q, values, layer, block_tables, positions[:, 0], kv_lens,
-            kv_scales=scales, interpret=interpret)
+            kv_scales=scales, interpret=interpret, sm_scale=sm_scale)
     return paged_attention_xla(q, kv_cache, layer, block_tables,
-                               positions, kv_lens, window=window)
+                               positions, kv_lens, window=window,
+                               sm_scale=sm_scale)

@@ -496,6 +496,23 @@ SSM_STATE_SLOT_MS = Gauge(
     "and --max-batch it is the share of the state cache that is live",
     ["worker"], registry=REGISTRY,
 )
+SSM_PREFILL_POSITIONS = Gauge(
+    "dynamo_ssm_prefill_positions_total",
+    "Model with recurrent state: valid positions x Mamba layers that "
+    "prefill launches have scanned since start, by carry: fresh (the "
+    "row began at position 0, from zero state) | continued (the row "
+    "took up the state its slot kept from the launch before). "
+    "continued over both is the share of the scan that ran on a "
+    "carried state",
+    ["worker", "carry"], registry=REGISTRY,
+)
+SSM_PREFILL_LAUNCH_ROWS = Gauge(
+    "dynamo_ssm_prefill_launch_rows_total",
+    "Model with recurrent state: rows of prefill launches since start, "
+    "by carry (as dynamo_ssm_prefill_positions_total). Both over the "
+    "fresh rows is the launches a prompt took",
+    ["worker", "carry"], registry=REGISTRY,
+)
 MOE_EXPERT_TOKENS = Gauge(
     "dynamo_moe_expert_tokens_total",
     "Dropless expert layer: token-slots each held expert has computed "

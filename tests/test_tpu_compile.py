@@ -756,3 +756,207 @@ def test_a_stack_with_window_layers_runs_both_page_groups_through_the_kernel(
     # 0.63 GB, and 0.62 GB with the XLA form: the experts' rows are as
     # large as its scores were, and XLA had given both one buffer
     assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
+
+
+# granite-4.0-h-small as the `granite4-h-small-ep2` cell serves it (one
+# period of ten blocks = 20 mixers, experts 0:36, 50,176 rows of the tied
+# matrix; every width as published): 48 rows, 24,576 pages of 16, 512-page
+# tables; 128 Mamba heads of 64 in ONE group, state 128; 36 SwiGLU experts
+# 768 wide over hidden 4096; scores at 1/128.
+GRANITE = {"rows": 48, "pages": 24576, "width": 512}
+
+
+def test_granites_state_update_and_grouped_matmuls_compile_for_v5e(one_chip):
+    """The decode state update at 128 heads (a row's block is 4.19 MB of
+    float32, in and out double-buffered: twice nemotron's) in place, and
+    the experts' grouped matmuls at a decode step's and a 2,048-token
+    launch's rows: contraction 4096 over the fused [gate | up] of 1536
+    rows and 768 over 4096 (`_tiling`: a [k, 512] slab for both; the
+    768-deep down-projection is one k tile)."""
+    from dynamo_tpu.ops.grouped_matmul import _tiling, expert_gmm
+    from dynamo_tpu.ops.ssm import ssm_state_update
+
+    slots, h, p, n = GRANITE["rows"], 128, 64, 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    compiled = ssm_state_update.lower(
+        _shape(one_chip, (slots, h, p, n), f32),
+        _shape(one_chip, (slots, h), f32), _shape(one_chip, (h,), f32),
+        _shape(one_chip, (slots, h, p), bf16),
+        _shape(one_chip, (slots, h, n), bf16),
+        _shape(one_chip, (slots, h, n), bf16),
+        _shape(one_chip, (slots,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "input_output_alias" in text
+    held, hidden, width = 36, 4096, 768
+    assert _tiling(hidden, 2 * width) == (128, hidden, 512)
+    assert _tiling(width, hidden) == (128, width, 512)
+    sizes = _shape(one_chip, (held,), jnp.int32)
+    for rows in (512, 20480):  # 48 x 10 and 2,048 x 10 slots, padded
+        for k, n_out, weights, transpose in (
+                (hidden, 2 * width, (held, 2 * width, hidden), True),
+                (width, hidden, (held, width, hidden), False)):
+            compiled = expert_gmm.lower(
+                _shape(one_chip, (rows, k), bf16),
+                _shape(one_chip, weights, bf16), sizes,
+                path="pallas", transpose_rhs=transpose).compile()
+            assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_a_stated_score_scale_is_a_constant_of_the_same_kernels():
+    """`sm_scale` is static: handed the default (1/sqrt(head_dim)) the
+    pool decode kernel and the blocked prefill kernel trace to the very
+    text they trace to without it (every accepted cell's programs keep
+    theirs), and handed granite's 1/128 to the same text but for that
+    constant."""
+    from dynamo_tpu.ops.paged_attention import (
+        paged_decode_attention_pool,
+        paged_prefill_attention_pool,
+    )
+
+    shape = jax.ShapeDtypeStruct
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    cases = {
+        paged_decode_attention_pool: [
+            shape((4, 32, HEAD_DIM), bf16),
+            shape((2, 2, 64, PAGE, 8, HEAD_DIM), bf16), shape((), i32),
+            shape((4, 16), i32), shape((4,), i32)],
+        paged_prefill_attention_pool: [
+            shape((1, 512, 32, HEAD_DIM), bf16),
+            shape((2, 2, 64, PAGE, 8, HEAD_DIM), bf16), shape((), i32),
+            shape((1, 112), i32), shape((1,), i32), shape((1,), i32)],
+    }
+    for fn, args in cases.items():
+        def text(**kw):
+            return str(jax.make_jaxpr(lambda *a: fn(*a, **kw))(*args))
+
+        plain = text()
+        assert text(sm_scale=1.0 / math.sqrt(HEAD_DIM)) == plain
+        stated = text(sm_scale=1.0 / 128)
+        assert stated != plain
+        assert stated.replace("0.0078125", "C") == re.sub(
+            r"0\.08838834\d*", "C", plain)
+
+
+def _granite_programs(one_chip):
+    """(config, params, caches) as shapes on the described chip."""
+    from dynamo_tpu.models.config import cut_config, get_config
+    from dynamo_tpu.models.hybrid import make_state_cache
+    from dynamo_tpu.models.transformer import init_params, make_kv_cache
+
+    cfg = cut_config(get_config("granite-4.0-h-small"), 10, "0:36", 50176)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    kv = on_chip(jax.eval_shape(
+        lambda: make_kv_cache(cfg, GRANITE["pages"], PAGE)))
+    state = on_chip(jax.eval_shape(
+        lambda: make_state_cache(cfg, GRANITE["rows"])))
+    return cfg, params, (kv, state)
+
+
+@pytest.mark.parametrize("program", ["decode-block", "prefill-1x2048"])
+def test_granites_step_programs_fit_the_chip_and_copy_nothing_large(
+        one_chip, program):
+    """The fused 8-step decode block at the widest table and the widest
+    prefill launch at the cell's sizes, as `ModelRunner` builds them,
+    compile for a described v5e with 48 slots: the tied head contracts
+    the embedding's own [50176, 4096] array (no 0.41 GB transpose or
+    copy of it in the step, nor of the pool or of a layer's state), the
+    state kernel, the pool decode kernel at the model's score scale and
+    the grouped matmul are in the decode program, the blocked prefill
+    kernel in the launch, and weights (9.51 GB) + state (1.83 GB) + pool
+    (1.61 GB) + the program's temporaries stay under the 15.75 GiB the
+    compiler gives a v5e. A launch's temporaries are the chunked scan's
+    float32 [16 chunks, 128 heads, 128, 128] products, 134 MB each."""
+    import functools
+
+    from dynamo_tpu.engine.sampler import sample, sample_with_logprobs
+    from dynamo_tpu.models.hybrid import (
+        forward_hybrid,
+        forward_hybrid_decode,
+        moe_stats_size,
+    )
+    from dynamo_tpu.ops.paged_attention import (
+        paged_attention,
+        paged_attention_decode_pool,
+    )
+
+    cfg, params, cache = _granite_programs(one_chip)
+    n, width = GRANITE["rows"], GRANITE["width"]
+    assert cache[0].shape == (1, 2, GRANITE["pages"], PAGE, 8, HEAD_DIM)
+    assert len(cache[1]["ssm"]) == 9
+    assert cache[1]["ssm"][0].shape == (n, 128, 64, 128)
+    assert "lm_head" not in params
+    tied_bytes = 50176 * 4096 * 2
+
+    def decode(params, cache, tokens, positions, tables, kv_lens, active,
+               temperature, top_p, top_k, seeds, step_idx):
+        def body(carry, _):
+            (kv, state), toks, pos, lens, sidx, acc = carry
+            kv, state, logits, stats = forward_hybrid_decode(
+                params, cfg, toks, pos, kv, state, tables, lens, active,
+                decode_attention_fn=functools.partial(
+                    paged_attention_decode_pool, interpret=False),
+                ssm_path="pallas", gmm_path="pallas")
+            nxt = sample(logits[:, 0, :], temperature, top_p, top_k, seeds,
+                         sidx)
+            return ((kv, state), nxt, pos + 1, lens + 1, sidx + 1,
+                    acc + stats), nxt
+
+        (cache, *_, acc), toks = jax.lax.scan(
+            body, (cache, tokens, positions, kv_lens, step_idx,
+                   jnp.zeros(moe_stats_size(cfg), jnp.int32)), None, length=8)
+        return cache, toks, acc
+
+    def prefill(params, cache, tokens, positions, tables, kv_lens, valid,
+                last_idx, temperature, top_p, top_k, seeds, slots):
+        kv, state = cache
+        kv, state, last, stats = forward_hybrid(
+            params, cfg, tokens, positions, kv, state, slots, tables,
+            kv_lens, valid, last_idx, gmm_path="pallas",
+            attention_fn=functools.partial(paged_attention,
+                                           interpret=False))
+        return ((kv, state), *sample_with_logprobs(
+            last, temperature, top_p, top_k, seeds, jnp.int32(0)), stats)
+
+    def vec(rows, dtype):
+        return _shape(one_chip, (rows,), dtype)
+
+    if program == "decode-block":
+        compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+            params, cache, vec(n, jnp.int32), vec(n, jnp.int32),
+            _shape(one_chip, (n, width), jnp.int32), vec(n, jnp.int32),
+            vec(n, jnp.bool_), vec(n, jnp.float32), vec(n, jnp.float32),
+            vec(n, jnp.int32), vec(n, jnp.uint32),
+            vec(n, jnp.int32)).compile()
+        text = compiled.as_text()
+        for kernel in ("ssm_state_update", "paged_decode_attention_pool"):
+            assert kernel in text, kernel
+    else:
+        def chunk(dtype):
+            return _shape(one_chip, (1, 2048), dtype)
+
+        compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+            params, cache, chunk(jnp.int32), chunk(jnp.int32),
+            _shape(one_chip, (1, width), jnp.int32), vec(1, jnp.int32),
+            chunk(jnp.bool_), vec(1, jnp.int32), vec(1, jnp.float32),
+            vec(1, jnp.float32), vec(1, jnp.int32), vec(1, jnp.uint32),
+            vec(1, jnp.int32)).compile()
+        text = compiled.as_text()
+        assert "paged_prefill_attention_pool" in text
+    memory = compiled.memory_analysis()
+    assert "tpu_custom_call" in text and "tied_head" in text
+    # nothing the size of the tied matrix, a layer's state or the pool
+    # is copied or transposed (`_copies` reads `copy`; a transpose of
+    # the embedding would be a bf16[4096,50176] result)
+    assert _copies(text, min(tied_bytes, n * 128 * 64 * 128 * 4) // 2) == []
+    assert not re.search(r"bf16\[4096,50176\]", text)
+    assert memory.temp_size_in_bytes < (0.3e9 if program == "decode-block"
+                                        else 1.3e9)
+    assert 12.9e9 < memory.argument_size_in_bytes < 13.0e9
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
