@@ -637,6 +637,9 @@ class ModelRunner:
         self._state_layers = len(model_config.state_layers)
         self.ssm_prefill_positions = {"fresh": 0, "continued": 0}
         self.ssm_prefill_rows = {"fresh": 0, "continued": 0}
+        # and which path the scan of those launches took
+        # (dynamo_ssm_scan_launches_total)
+        self.ssm_scan_launches = {"kernel": 0, "xla": 0}
         # Launches by program (dynamo_program_launches, _tokens):
         # (entry, key) -> [launches, useful prompt tokens] of served
         # traffic; a warm-up pass lists the keys it walks at 0.
@@ -745,6 +748,16 @@ class ModelRunner:
             jnp.int8 if self._kv_quantized else cfg.dtype,
             KV_SCALE_LANES if self._kv_quantized else None)
 
+    def ssm_scan_tiles(self, bucket: int):
+        """Heads a grid step where the Mamba layers of a `bucket`-position
+        prefill launch run the chunked-scan kernel, None where they run
+        the XLA form (or the model has none): `mamba_prefill`'s rule."""
+        if not self._state_layers:
+            return None
+        from ..models.hybrid import scan_head_block
+
+        return scan_head_block(self.model_config, bucket, self._ssm_path)
+
     def _count_prefill(self, starts: Sequence[int], lengths: Sequence[int],
                        rows: int, bucket: int, windows=()) -> None:
         """Host arithmetic on a launch's own positions (row i holds
@@ -758,6 +771,8 @@ class ModelRunner:
                 self.ssm_prefill_positions[carry] += (length
                                                       * self._state_layers)
                 self.ssm_prefill_rows[carry] += 1
+            self.ssm_scan_launches[
+                "kernel" if self.ssm_scan_tiles(bucket) else "xla"] += 1
         if self.config.weight_dtype == "int4":
             from ..ops.q4_linear import count_row_blocks
 
@@ -996,9 +1011,11 @@ class ModelRunner:
         `chip_smoke.py` checks, so a reference kernel or the interpreter
         can never serve unnoticed. Unquantized weights have one
         implementation (`einsum`); `custom` is a caller-supplied
-        attention_fn. A hybrid stack adds its two kernels: the decode
-        state update (DYNT_SSM) and the experts' grouped matmul
-        (DYNT_MOE_GMM)."""
+        attention_fn. A hybrid stack adds its kernels: the decode state
+        update and, where it has Mamba layers, the prefill scan (both
+        DYNT_SSM; a launch whose shapes the scan kernel refuses still
+        takes the XLA form: dynamo_ssm_scan_launches_total) and the
+        experts' grouped matmul (DYNT_MOE_GMM)."""
         from ..ops import kernel_path
 
         def attention(fn) -> str:
@@ -1017,6 +1034,8 @@ class ModelRunner:
             paths["weight_matmul"] = kernel_path("DYNT_Q4_MATMUL")
         if self._hybrid:
             paths["ssm_update"] = self._ssm_path
+            if self._state_layers:
+                paths["ssm_scan"] = self._ssm_path
             paths["expert_gmm"] = self._gmm_path
         devices = list(self.mesh.devices.flat)
         paths["platform"] = devices[0].platform
@@ -1318,7 +1337,7 @@ class ModelRunner:
                     params, cfg, tokens, positions, kv, state, slots,
                     block_table, kv_lens, valid, last_idx,
                     attention_fn=attention_fn, gmm_path=self._gmm_path,
-                    window=window)
+                    window=window, ssm_path=self._ssm_path)
                 kv, extra = (kv, state), (stats,)
             else:
                 kv, logits = forward(
@@ -1521,11 +1540,13 @@ class ModelRunner:
         form does (on the chip both their page groups run the blocked
         kernel since PR 41 and hold no such scores; the bound stays
         because lifting it changes the program grid: ROADMAP A7); and
-        Mamba layers where a context runs past one launch (the chunked
-        scan holds float32 [positions, heads, chunk] products, 134 MB
-        each at 2,048 positions x 128 heads, and a grid of rows x
-        bucket past the budget is programs no launch needs: a model
-        whose contexts fit one launch keeps its rows)."""
+        Mamba layers where a context runs past one launch (a grid of
+        rows x bucket past the budget is programs no launch needs: a
+        model whose contexts fit one launch keeps its rows. The scan's
+        XLA form also held float32 [positions, heads, chunk] products,
+        134 MB each at 2,048 positions x 128 heads; on the chip the
+        scan is a kernel since PR 43 and holds none, and this bound
+        stays for the grid's sake: ROADMAP A7)."""
         return (self._windowed or self._latent
                 or (self.model_config.has_recurrent_state
                     and self.config.max_context

@@ -708,7 +708,7 @@ class TpuWorker:
                  paths["device_ids"], paths["decode_attention"],
                  paths["spec_attention"], paths["weight_matmul"],
                  "".join(f" {slot}={paths[slot]}"
-                         for slot in ("ssm_update", "expert_gmm")
+                         for slot in ("ssm_update", "ssm_scan", "expert_gmm")
                          if slot in paths), native)
         if self.model_config.has_recurrent_state:
             from ..models.hybrid import state_slot_bytes
@@ -1565,7 +1565,8 @@ class TpuWorker:
         dynamo_engine_positions, dynamo_prefill_row_blocks_total,
         dynamo_prefill_attn_launches_total, dynamo_prefill_attn_blocks_total,
         dynamo_kv_reserved_page_ms, dynamo_kv_window_*, dynamo_latent_*,
-        dynamo_ssm_prefill_*, dynamo_program_launches,
+        dynamo_ssm_prefill_*, dynamo_ssm_scan_launches_total,
+        dynamo_program_launches,
         dynamo_program_tokens,
         dynamo_device_hbm_bytes)."""
         from ..runtime.metrics import (
@@ -1593,6 +1594,7 @@ class TpuWorker:
             PROGRAM_TOKENS,
             SSM_PREFILL_LAUNCH_ROWS,
             SSM_PREFILL_POSITIONS,
+            SSM_SCAN_LAUNCHES,
             SSM_STATE_SLOT_MS,
         )
 
@@ -1657,6 +1659,9 @@ class TpuWorker:
                 SSM_PREFILL_LAUNCH_ROWS.labels(
                     worker=worker, carry=carry).set(
                         self.runner.ssm_prefill_rows[carry])
+            for path, count in getattr(
+                    self.runner, "ssm_scan_launches", {}).items():
+                SSM_SCAN_LAUNCHES.labels(worker=worker, path=path).set(count)
         expanded = getattr(self.runner, "latent_prefill_expand_tokens", 0)
         if expanded:  # only a model with latent attention
             LATENT_DECODE_TOKENS.labels(worker=worker).set(

@@ -83,9 +83,12 @@ import jax.numpy as jnp
 from ..ops.grouped_matmul import dropless_experts
 from ..ops.paged_attention import prefill_kernel_tiles
 from ..ops.ssm import (
+    LANES,
     causal_conv,
     expand_groups,
+    scan_kernel_tiles,
     ssm_chunk_scan,
+    ssm_chunk_scan_kernel,
     ssm_state_update,
     ssm_state_update_xla,
 )
@@ -487,14 +490,29 @@ def state_slot_bytes(config: ModelConfig) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _gated_group_norm(y, z, weight, groups: int, eps: float):
-    """GroupRMSNorm(y * silu(z)): gate first, then norm each group."""
+def _gated_group_norm(y, z, weight, groups: int, eps: float,
+                      by_slices: bool = False):
+    """GroupRMSNorm(y * silu(z)): gate first, then norm each group.
+    `by_slices` (a prefill launch): each group as a slice of lanes. Over
+    [rows, T, inner] XLA turns the [.., groups, width] reshape into a
+    float32 broadcast of the scales and a relaid copy of the gated input,
+    67 MB each at 8 x 512 x 4096; over a decode step's [slots, inner] the
+    reshape is one fusion and the slices would be two a group."""
     dtype = z.dtype
     g = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32)))
     shape = g.shape
-    g = g.reshape(*shape[:-1], groups, shape[-1] // groups)
-    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
-    return g.reshape(shape).astype(dtype) * weight
+    width = shape[-1] // groups
+
+    def normed(part):
+        return part * jax.lax.rsqrt(
+            jnp.mean(part * part, axis=-1, keepdims=True) + eps)
+
+    if by_slices:
+        g = jnp.concatenate([normed(g[..., k * width:(k + 1) * width])
+                             for k in range(groups)], axis=-1)
+    else:
+        g = normed(g.reshape(*shape[:-1], groups, width)).reshape(shape)
+    return g.astype(dtype) * weight
 
 
 def _split_in_proj(zxbcdt, config: ModelConfig):
@@ -516,23 +534,58 @@ def _split_xbc(xbc, config: ModelConfig):
     return xs, b, c
 
 
-def mamba_prefill(x, lp, config: ModelConfig, conv, ssm, valid):
+def scan_head_block(config: ModelConfig, t: int, ssm_path: str):
+    """Heads a grid step where the Mamba mixers of a launch of `t`
+    positions a row run the chunked-scan kernel, None where they run the
+    XLA form: the slot says `xla`, or `scan_kernel_tiles` refuses the
+    shapes. `mamba_prefill`'s rule, and what `ModelRunner` counts by."""
+    if ssm_path == "xla":
+        return None
+    return scan_kernel_tiles(t, config.mamba_heads, config.mamba_head_dim,
+                             config.ssm_groups, config.ssm_state,
+                             config.ssm_chunk)
+
+
+def mamba_prefill(x, lp, config: ModelConfig, conv, ssm, valid,
+                  ssm_path: str = "xla"):
     """x [B, T, h] (normed); conv [B, K-1, C], ssm [B, H, P, N]: the rows'
-    state going in. Returns (out [B, T, h], conv, ssm coming out)."""
+    state going in. Returns (out [B, T, h], conv, ssm coming out).
+    `ssm_path` (ops.kernel_path("DYNT_SSM")): the scan is the Pallas
+    kernel where `scan_head_block` gives it a head block; `x` and `y`
+    then stay [B, T, H*P], as the projection and the conv wrote them."""
     with jax.named_scope("mamba_mixer"):
-        z, xbc, dt = _split_in_proj(
-            jnp.einsum("bth,hm->btm", x, lp["in_proj"]), config)
+        w, split = lp["in_proj"], config.mamba_inner + config.mamba_conv_dim
+        if w.shape[1] % LANES == 0:
+            z, xbc, dt = _split_in_proj(
+                jnp.einsum("bth,hm->btm", x, w), config)
+        else:
+            # [z | xbc] are whole lane tiles and the heads' dt behind
+            # them are not: projected as one, XLA lays the product out
+            # with POSITIONS minor and relays z and xbc for every reader
+            z, xbc, _ = _split_in_proj(
+                jnp.einsum("bth,hm->btm", x, w[:, :split]), config)
+            dt = jnp.einsum("bth,hm->btm", x, w[:, split:])
         n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
         xbc, conv = causal_conv(conv, xbc, lp["conv_w"], lp["conv_b"],
                                 n_valid)
-        xs, b, c = _split_xbc(xbc, config)
         dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
         dt = jnp.where(valid[:, :, None], dt, 0.0)
         a = -jnp.exp(lp["a_log"])
-        ssm, y = ssm_chunk_scan(ssm, dt, a, xs, b, c, chunk=config.ssm_chunk)
-        y = y + lp["d_skip"][None, None, :, None] * xs.astype(jnp.float32)
-        y = _gated_group_norm(y.reshape(*z.shape), z, lp["ssm_norm"],
-                              config.ssm_groups, config.rms_eps)
+        xs = xbc[..., :config.mamba_inner]
+        head_block = scan_head_block(config, x.shape[1], ssm_path)
+        if head_block is None:
+            ssm, y = ssm_chunk_scan(ssm, dt, a, *_split_xbc(xbc, config),
+                                    chunk=config.ssm_chunk)
+            y = y.reshape(*z.shape)
+        else:
+            ssm, y = ssm_chunk_scan_kernel(
+                ssm, dt, a, xbc, chunk=config.ssm_chunk,
+                heads_per_block=head_block,
+                interpret=ssm_path == "interpret")
+        y = y + (jnp.repeat(lp["d_skip"], config.mamba_head_dim)
+                 * xs.astype(jnp.float32))
+        y = _gated_group_norm(y, z, lp["ssm_norm"], config.ssm_groups,
+                              config.rms_eps, by_slices=True)
         return jnp.einsum("btm,mh->bth", y, lp["out_proj"]), conv, ssm
 
 
@@ -955,7 +1008,8 @@ def _group_attention(attention_fn, q_shape, cache, tables):
 def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
                    state, slots, block_tables, kv_lens, valid, last_idx,
                    attention_fn=None, gmm_path: str = "xla",
-                   all_logits: bool = False, window=None):
+                   all_logits: bool = False, window=None,
+                   ssm_path: str = "xla"):
     """A prefill chunk a row. tokens, positions, valid [B, T]; slots [B]:
     each row's state slot (>= the cache's size for an empty row: its
     write is dropped); last_idx [B]: the row's last valid position in
@@ -966,7 +1020,8 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
     cache, its tables [B, pages], base [B]) and gives back `kv_cache` as
     (full group, window group); its window layers read their short
     table (window + chunk keys) in that group's frame, its full layers
-    the sequence's, each group through `_group_attention`."""
+    the sequence's, each group through `_group_attention`. `ssm_path`:
+    the Mamba mixers' scan (`mamba_prefill`); no other mixer reads it."""
     attention = win_attention = attention_fn or paged_attention_xla
     if window is not None:
         win_cache, win_tables, win_pos, win_lens = _window_frame(
@@ -989,7 +1044,8 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
             conv_all, ssm_all = conv_out[state_idx], ssm_out[state_idx]
             conv = jnp.where(fresh[:, None, None], 0, conv_all[slots])
             ssm = jnp.where(fresh[:, None, None, None], 0, ssm_all[slots])
-            out, conv, ssm = mamba_prefill(h, lp, config, conv, ssm, valid)
+            out, conv, ssm = mamba_prefill(h, lp, config, conv, ssm, valid,
+                                           ssm_path)
             conv_out[state_idx] = conv_all.at[slots].set(conv, mode="drop")
             ssm_out[state_idx] = ssm_all.at[slots].set(ssm, mode="drop")
             state_idx += 1

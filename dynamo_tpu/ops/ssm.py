@@ -12,18 +12,33 @@ Two callers, two shapes of the same recurrence:
     (their slot ids are prefetched; the rest of the grid re-points at the
     last live block, so nothing moves for them) and never round-trips the
     state through HBM between the update and the `S C` read-out.
-  * prefill (`ssm_chunk_scan`): T tokens a row in chunks of `chunk`
-    (published: 128), matmul form: inside a chunk the recurrence is a
-    masked [L, L] attention-like product, between chunks a short scan
-    carries the state. Takes an initial state and returns the final one,
-    so the scheduler's chunked and batched prefill carry state between
-    launches; a position with dt = 0 (padding) leaves the state as it was.
-    XLA operations, no kernel: a Pallas kernel of the [L, L] products
-    and the carry was faster alone (0.55 against 0.79 ms at 2 x 1024) and
-    10-25% slower inside the layer at every shape the cell reaches (5.4
-    against 4.3 ms a layer at 8 x 512), because its operands want heads
-    before positions and XLA fuses this form into its neighbours (my
-    chip runs, PR 30; PERF.md), so it was taken out.
+  * prefill (`ssm_chunk_scan`, `ssm_chunk_scan_kernel`): T tokens a row
+    in chunks of `chunk` (published: 128), matmul form: inside a chunk
+    the recurrence is a masked [L, L] attention-like product, between
+    chunks the state is carried. Takes an initial state and returns the
+    final one, so the scheduler's chunked and batched prefill carry
+    state between launches; a position with dt = 0 (padding) leaves the
+    state as it was. Two forms of one contract:
+      - `ssm_chunk_scan`, XLA operations: the CPU path, the oracle and
+        the fallback for shapes `scan_kernel_tiles` refuses. On the
+        chip it writes every [L, L] product, every chunk's state and
+        relaid copies of x and y (heads before positions and back) to
+        HBM: a granite layer moved 2.3-2.8 GB where 0.6 would do.
+      - `ssm_chunk_scan_kernel`, one Pallas kernel a layer (PR 43),
+        which reads x | b | c where the conv wrote them, [positions,
+        heads x head_dim] with heads along lanes, and writes y the same
+        way: a head block is a slice of lanes picked by a BlockSpec, a
+        chunk's products and the carried state stay in VMEM, and
+        nothing is transposed on either side of the call.
+    PR 30 had a Pallas scan too and took it out: its operands wanted
+    heads before positions, so it paid the relayouts XLA pays and more
+    (0.55 against 0.79 ms alone at 2 x 1024 x 64 heads, 5.4 against
+    4.3 ms a layer at 8 x 512). The layout is what differs now.
+    Measured on a v5e (my chip runs, PR 43; kernel | XLA form, ms):
+    alone at [1, 2048] x 128 heads in one group 0.51 | 1.04, at
+    [8, 512] x 64 heads in 8 groups 0.65 | 2.38, y and state bit-equal;
+    a whole `mamba_prefill` layer at granite's widths [1, 2048]
+    3.41 | 4.53, at nemotron's [8, 512] 2.73 | 5.03.
 
 State is float32 whatever the activations are (NVIDIA's serving notes for
 the family ask for a float32 SSM cache; `ModelConfig.ssm_state_dtype`, and
@@ -242,6 +257,205 @@ def ssm_chunk_scan(state, dt, a, x, b, c, *, chunk: int):
             y.reshape(bsz, t, h, p))
 
 
+# Lanes of a vreg: a head block of `x` and `y` is a slice of whole lane
+# tiles, and the kernel walks it a tile (LANES // head_dim heads) at a time.
+LANES = 128
+# Heads a grid step: 16 x 64 lanes of `x` and `y` and a 0.5 MB float32
+# state block. 32 read 4% under it alone at twice the VMEM, 8 8% over it
+# (0.49 | 0.51 | 0.55 ms at [1, 2048] x 128 heads: my chip run, PR 43).
+SCAN_HEAD_BLOCK = 16
+
+
+def scan_kernel_tiles(t: int, heads: int, head_dim: int, groups: int,
+                      state: int, chunk: int) -> int | None:
+    """Heads a grid step of `ssm_chunk_scan_kernel` for a launch of `t`
+    positions a row, or None where the kernel has no tiling and the
+    caller takes `ssm_chunk_scan`. A function of shapes alone:
+
+      * two heads fill a 128-lane tile (head_dim 64: the kernel keeps a
+        pair's states side by side as one [N, 128] tile);
+      * a block lies inside one group and its rows of the per-head
+        vectors are whole sublane tiles: a multiple of 8 heads dividing
+        heads / groups;
+      * a group's `b` and `c` are whole lane tiles (state % 128 == 0) and
+        so are a chunk's positions (chunk % 128 == 0, t a multiple of it:
+        a launch shorter than a chunk has no [L, L] tile to fill); `b`
+        and `c` lie behind `x` in the conv's output, which the kernel
+        reads in blocks of `state` lanes, so x's lanes are whole blocks."""
+    if (head_dim * 2 != LANES or groups < 1 or heads % groups
+            or state % LANES or heads * head_dim % state
+            or chunk % LANES or t % chunk):
+        return None
+    per_group = heads // groups
+    for hb in range(min(SCAN_HEAD_BLOCK, per_group), 7, -1):
+        if hb % 8 == 0 and per_group % hb == 0:
+            return hb
+    return None
+
+
+def _chunk_scan_kernel(rows_ref, x_ref, b_ref, c_ref, s_ref, y_ref, o_ref,
+                       st_ref):
+    """One grid step = one row x one head block x one chunk of L
+    positions; the chunk axis is innermost and sequential.
+
+    rows_ref [1, 4, hb, L] f32, positions on LANES: cs (the inclusive
+    cumulative dt A inside the chunk) | dt | exp(cs_L - cs) dt | exp(cs);
+    x_ref [1, L, hb*P] and y_ref (f32) likewise: lanes as `in_proj` and
+    the conv wrote them; b_ref, c_ref [1, L, N]: the block's group;
+    s_ref / o_ref [1, hb, P, N] f32: read at chunk 0, written at the last;
+    st_ref [hb/2, N, 2P] f32 scratch: the states of a PAIR of heads,
+    transposed and side by side, so a pair's read-out `C S^T` and update
+    `B^T (to_end x)` are one [., 128]-lane matmul each and `x`, `y` move
+    as whole lane tiles."""
+    k, last = pl.program_id(2), pl.num_programs(2) - 1
+    hb, p, n = s_ref.shape[1:]
+    length = x_ref.shape[1]
+    pairs = hb // 2
+    f32 = jnp.float32
+
+    @pl.when(k == 0)
+    def _enter():
+        def load(i, carry):
+            states = s_ref[0, pl.ds(2 * i, 2)].astype(f32)  # [2, P, N]
+            st_ref[i] = states.reshape(2 * p, n).T
+            return carry
+
+        jax.lax.fori_loop(0, pairs, load, 0)
+
+    bmat, cmat = b_ref[0], c_ref[0]  # [L, N]
+    dtype = x_ref.dtype
+    # C B^T once a (group, chunk); B^T for the pairs' state updates
+    cb = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
+                             preferred_element_type=f32)  # [L, L]
+    b_t = bmat.astype(f32).T.astype(dtype)  # [N, L]
+    c32 = cmat.astype(f32)
+    causal = (jax.lax.broadcasted_iota(jnp.int32, (length, length), 0)
+              >= jax.lax.broadcasted_iota(jnp.int32, (length, length), 1))
+    # lanes (and, before a transpose, sublanes) of a pair's second head
+    second = jax.lax.broadcasted_iota(jnp.int32, (length, 2 * p), 1) >= p
+    second_t = jax.lax.broadcasted_iota(jnp.int32, (2 * p, length), 0) >= p
+
+    def column(row):
+        """[1, L] (positions on lanes) -> [L, L]: entry [l, s] = row[l]."""
+        return jnp.broadcast_to(row, (length, length)).T
+
+    def pair_columns(row0, row1):
+        """Two heads' [1, L] -> [L, 2P]: a position's value of head 0 on
+        the first P lanes, of head 1 on the rest."""
+        return jnp.where(second_t, jnp.broadcast_to(row1, (2 * p, length)),
+                         jnp.broadcast_to(row0, (2 * p, length))).T
+
+    def pair(i, carry):
+        def rows(q, j):
+            return rows_ref[0, q, pl.ds(2 * i + j, 1), :]  # [1, L]
+
+        lanes = pl.ds(pl.multiple_of(i * 2 * p, 2 * p), 2 * p)
+        x = x_ref[0, :, lanes]  # [L, 2P]
+        ys = []
+        for j in range(2):
+            cs = rows(0, j)
+            # y_l += sum_{s<=l} exp(cs_l - cs_s) dt_s (C_l . B_s) x_s
+            seg = jnp.where(causal, column(cs) - cs, -1e30)
+            weights = jnp.exp(seg) * rows(1, j) * cb
+            ys.append(jnp.dot(weights.astype(dtype), x,
+                              preferred_element_type=f32))
+        y = jnp.where(second, ys[1], ys[0])
+        entering = st_ref[i]  # [N, 2P]
+        decay = pair_columns(rows(3, 0), rows(3, 1))  # exp(cs) [L, 2P]
+        # across chunks: y_l += exp(cs_l) C_l . S_entering
+        y_ref[0, :, lanes] = y + decay * jnp.dot(
+            c32, entering, preferred_element_type=f32)
+        # S <- exp(cs_L) S + (to_end x)^T B, transposed
+        to_end = pair_columns(rows(2, 0), rows(2, 1))
+        st_ref[i] = decay[length - 1:] * entering + jnp.dot(
+            b_t, (to_end * x.astype(f32)).astype(dtype),
+            preferred_element_type=f32)
+        return carry
+
+    # a loop, not an unroll: four pairs unrolled an iteration read 0.40
+    # against 0.51 ms a granite layer alone and cost every prefill
+    # program 0.35 s more of lowering at each start (26 programs in the
+    # two Mamba cells; my chip runs, PR 43; ROADMAP rule 7)
+    jax.lax.fori_loop(0, pairs, pair, 0)
+
+    @pl.when(k == last)
+    def _leave():
+        def store(i, carry):
+            o_ref[0, pl.ds(2 * i, 2)] = st_ref[i].T.reshape(2, p, n).astype(
+                o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, pairs, store, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "heads_per_block",
+                                             "interpret"))
+def ssm_chunk_scan_kernel(state, dt, a, xbc, *, chunk: int,
+                          heads_per_block: int, interpret: bool = False):
+    """`ssm_chunk_scan` as one Pallas kernel that reads the conv's output
+    as the conv wrote it: xbc [B, T, H*P + 2*G*N] = x | b | c along
+    lanes (block specs pick a head block's lanes of x and its group's of
+    b and c: no slice of it is copied), dt [B, T, H] f32 (0 at padding),
+    a [H], state [B, H, P, N] f32. Returns (final state,
+    y [B, T, H*P] f32), lanes as x's. No [chunk, chunk] product,
+    per-chunk state or relaid copy of x or y reaches HBM: the per-head
+    vectors (cs, dt and the two decays, T x H floats each) are all that
+    XLA prepares, transposed so that a head's are a row.
+    `heads_per_block`: `scan_kernel_tiles`' answer."""
+    bsz, t, _ = xbc.shape
+    h, (p, n) = dt.shape[2], state.shape[2:]
+    inner, hb = h * p, heads_per_block
+    g = (xbc.shape[2] - inner) // (2 * n)
+    assert (xbc.shape[2] == inner + 2 * g * n and t % chunk == 0
+            and (h // g) % hb == 0 and inner % n == 0), (
+        xbc.shape, dt.shape, state.shape, chunk, hb)
+    nc = t // chunk
+    f32 = jnp.float32
+    with jax.named_scope("ssm_scan"):
+        dtc = dt.reshape(bsz, nc, chunk, h)
+        cs = jnp.cumsum(dtc * a[None, None, None, :], axis=2)
+        rows = jnp.stack([cs, dtc, jnp.exp(cs[:, :, -1:] - cs) * dtc,
+                          jnp.exp(cs)], axis=1)  # [B, 4, nc, L, H]
+        rows = jnp.swapaxes(rows.reshape(bsz, 4, t, h), 2, 3)  # [B, 4, H, T]
+
+        def lanes_map(i, j, k):
+            return (i, k, j)
+
+        def group_map(offset):
+            # in blocks of N lanes: behind x, a group's b, then its c
+            return lambda i, j, k: (i, k, offset + j * hb // (h // g))
+
+        def state_map(i, j, k):
+            return (i, j, 0, 0)
+
+        y, final = pl.pallas_call(
+            _chunk_scan_kernel,
+            grid=(bsz, h // hb, nc),
+            in_specs=[
+                pl.BlockSpec((1, 4, hb, chunk), lambda i, j, k: (i, 0, j, k)),
+                pl.BlockSpec((1, chunk, hb * p), lanes_map),
+                pl.BlockSpec((1, chunk, n), group_map(inner // n)),
+                pl.BlockSpec((1, chunk, n), group_map(inner // n + g)),
+                pl.BlockSpec((1, hb, p, n), state_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, chunk, hb * p), lanes_map),
+                pl.BlockSpec((1, hb, p, n), state_map),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bsz, t, inner), f32),
+                jax.ShapeDtypeStruct(state.shape, state.dtype),
+            ],
+            scratch_shapes=[pltpu.VMEM((hb // 2, n, 2 * p), f32)],
+            interpret=interpret,
+            name="ssm_chunk_scan",
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=64 * 1024 * 1024),
+        )(rows, xbc, xbc, xbc, state)
+    return final, y
+
+
 # ---------------------------------------------------------------------------
 # the causal depthwise convolution in front of the scan
 # ---------------------------------------------------------------------------
@@ -255,11 +469,19 @@ def causal_conv(carry, x, weight, bias, n_valid):
     (silu(conv + bias) [B, T, C], new carry): the last K-1 REAL inputs of
     each row, so padding never enters it (n_valid = 0 keeps the carry)."""
     k = weight.shape[0]
-    seq = jnp.concatenate([carry.astype(x.dtype), x], axis=1)
+    prev = carry.astype(x.dtype)
+    seq = jnp.concatenate([prev, x], axis=1)
     t = x.shape[1]
     out = sum(seq[:, i:i + t].astype(jnp.float32)
               * weight[i].astype(jnp.float32) for i in range(k))
     out = jax.nn.silu(out + bias.astype(jnp.float32)).astype(x.dtype)
+    # rows n_valid .. n_valid + K-2 of `seq`, taken from its two parts:
+    # gathered from `seq` itself, XLA writes all [K-1 + T, C] of it first
     idx = n_valid[:, None] + jnp.arange(k - 1)[None, :]  # [B, K-1]
-    new_carry = jnp.take_along_axis(seq, idx[:, :, None], axis=1)
+    new_carry = jnp.where(
+        (idx >= k - 1)[:, :, None],
+        jnp.take_along_axis(
+            x, jnp.clip(idx - (k - 1), 0, t - 1)[:, :, None], axis=1),
+        jnp.take_along_axis(prev, jnp.minimum(idx, k - 2)[:, :, None],
+                            axis=1))
     return out, new_carry.astype(carry.dtype)

@@ -131,9 +131,10 @@ _register("DYNT_Q4_MATMUL", "auto", _str,
           "W4A16 matmul backend for packed-int4 weights: auto (Pallas "
           "on TPU, XLA reference elsewhere) | pallas | xla")
 _register("DYNT_SSM", "auto", _str,
-          "Mamba-2 decode state update (in place over the per-slot "
-          "state): auto (the Pallas kernel on TPU, the XLA reference "
-          "elsewhere) | pallas | xla")
+          "Mamba-2 state kernels, the decode update (in place over the "
+          "per-slot state) and the prefill chunked scan: auto (the "
+          "Pallas kernels on TPU, the XLA reference elsewhere) | pallas "
+          "| xla")
 _register("DYNT_MOE_GMM", "auto", _str,
           "Dropless experts' grouped matmul: auto (the Pallas gmm that "
           "ships with JAX on TPU, lax.ragged_dot elsewhere) | pallas | xla")

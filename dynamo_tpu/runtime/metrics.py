@@ -513,6 +513,14 @@ SSM_PREFILL_LAUNCH_ROWS = Gauge(
     "fresh rows is the launches a prompt took",
     ["worker", "carry"], registry=REGISTRY,
 )
+SSM_SCAN_LAUNCHES = Gauge(
+    "dynamo_ssm_scan_launches_total",
+    "Model with recurrent state: prefill launches since start by the "
+    "path their Mamba layers' chunked scan took: kernel (the Pallas "
+    "kernel ssm_chunk_scan, x and y in the projection's own layout) | "
+    "xla (the XLA form: [chunk, chunk] products and relaid copies in HBM)",
+    ["worker", "path"], registry=REGISTRY,
+)
 MOE_EXPERT_TOKENS = Gauge(
     "dynamo_moe_expert_tokens_total",
     "Dropless expert layer: token-slots each held expert has computed "

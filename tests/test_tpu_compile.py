@@ -223,14 +223,16 @@ def test_the_q4_matmul_with_a_row_block_map_compiles_for_v5e(one_chip, name):
     assert "s32[16]" in prefill and "s32[" not in decode
 
 
-def _copies(text, at_least):
+def _copies(text, at_least, ops=("copy", "copy-start"), scope=None):
     """Result shapes, of `at_least` bytes or more, of the `copy` and
-    `copy-start` instructions in an optimised HLO text."""
+    `copy-start` instructions (or `ops`) in an optimised HLO text; with
+    `scope`, of those whose metadata names it."""
+    op = "|".join(re.escape(o) for o in ops)
     found = []
     for line in text.splitlines():
         result = re.match(
-            r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) copy(?:-start)?\(", line)
-        if not result:
+            rf"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) (?:{op})\(", line)
+        if not result or (scope and scope not in line):
             continue
         for bits, dims in re.findall(r"\b[a-z]+(\d+)\[([\d,]+)\]",
                                      result.group(1)):
@@ -664,7 +666,7 @@ def test_the_dense_prefill_program_holds_the_kernel_and_no_score_tensor(
 
 def _lowered_hybrid_prefill(one_chip, name, layers, experts, rows, t,
                             window_pages=0, pages=1024, width=64,
-                            window_width=128):
+                            window_width=128, **paths):
     """`forward_hybrid` for a cut of a preset, lowered for the described
     chip around the default `attention_fn` (`.as_text()`: a kernel call
     is in the StableHLO or it is not; `.compile()` for what XLA makes of
@@ -698,7 +700,7 @@ def _lowered_hybrid_prefill(one_chip, name, layers, experts, rows, t,
             params, cfg, tokens, positions, kv, state, slots, tables,
             kv_lens, valid, last_idx, window=window,
             attention_fn=functools.partial(paged_attention,
-                                           interpret=False))
+                                           interpret=False), **paths)
 
     def vec(dtype):
         return _shape(one_chip, (rows,), dtype)
@@ -720,6 +722,53 @@ def test_a_hybrid_stacks_full_attention_layers_call_the_prefill_kernel(
     text = lowered.as_text()
     assert "paged_prefill_attention_pool" in text
     assert "paged_prefill_attention_window" not in text
+
+
+def test_nemotrons_widest_launch_scans_in_the_kernel_and_relays_nothing(
+        one_chip):
+    """The hybrid cell's `[8, 512]` launch (all 14 layers, experts 0:64):
+    its six Mamba mixers call the chunked-scan kernel once each, with 8
+    heads a group a grid step, and nothing of 32 MB or more is copied,
+    reshaped or transposed in a mixer's scope. Two things beside the
+    kernel make that so (PERF.md, PR 43): `in_proj`'s 10,304 columns are
+    not whole lane tiles, and projected as one XLA lays the product out
+    with positions minor and relays z (33.6 MB) and xbc (50.3 MB) for
+    the conv and the kernel: [z | xbc] and dt are projected apart; and
+    the gated norm's 8 groups as a [.., 8, 512] reshape left a 67 MB
+    float32 broadcast of the scales and a relaid copy of its input: the
+    groups are normed as lane slices."""
+    cfg, lowered = _lowered_hybrid_prefill(
+        one_chip, "nemotron3-nano-30b-a3b", 14, "0:64", rows=8, t=512,
+        ssm_path="pallas", gmm_path="pallas")
+    assert cfg.layer_pattern.count("M") == 6
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" custom-call\(.*ssm_chunk_scan", text)) == 6
+    assert _relaid_in_mixers(text, 32 << 20) == []
+    # 0.82 GB, as with the XLA form of the scan (0.79): the experts' rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.85e9
+
+
+@pytest.mark.parametrize("stack", ["mellum", "pangu"])
+def test_a_stack_without_mamba_mixers_lowers_to_the_text_it_had(one_chip,
+                                                                stack):
+    """`forward_hybrid`'s `ssm_path` reaches Mamba mixers only: the
+    mellum and pangu cells' prefill programs lower to the same text
+    whatever it says (their cells' programs are PR 42's)."""
+    name, layers, kw = {
+        "mellum": ("mellum2-12b-a2.5b", 4,
+                   dict(window_pages=512, pages=1024, width=64,
+                        window_width=80)),
+        "pangu": ("openpangu-ultra-moe-718b", 3, {}),
+    }[stack]
+    texts = []
+    for paths in ({}, {"ssm_path": "pallas"}, {"ssm_path": "xla"}):
+        cfg, lowered = _lowered_hybrid_prefill(
+            one_chip, name, layers, None, rows=1, t=512, **kw, **paths)
+        assert "M" not in cfg.layer_pattern
+        texts.append(lowered.as_text())
+    assert "ssm_chunk_scan" not in texts[0]
+    assert texts[0] == texts[1] == texts[2]
 
 
 def test_a_stack_with_window_layers_runs_both_page_groups_through_the_kernel(
@@ -858,7 +907,18 @@ def _granite_programs(one_chip):
     return cfg, params, (kv, state)
 
 
-@pytest.mark.parametrize("program", ["decode-block", "prefill-1x2048"])
+def _relaid_in_mixers(text, at_least):
+    """Results of `at_least` bytes or more that the entry computation
+    copies, reshapes or transposes inside a Mamba mixer's scope: what a
+    relaid `x`, `y` or state would be (inside a fusion a reshape is free)."""
+    entry = text[text.index("\nENTRY"):]
+    return _copies(entry[:entry.index("\n}")], at_least,
+                   ops=("copy", "copy-start", "reshape", "transpose"),
+                   scope="mamba_mixer")
+
+
+@pytest.mark.parametrize("program", ["decode-block", "prefill-1x2048",
+                                     "prefill-4x512"])
 def test_granites_step_programs_fit_the_chip_and_copy_nothing_large(
         one_chip, program):
     """The fused 8-step decode block at the widest table and the widest
@@ -870,8 +930,14 @@ def test_granites_step_programs_fit_the_chip_and_copy_nothing_large(
     the grouped matmul are in the decode program, the blocked prefill
     kernel in the launch, and weights (9.51 GB) + state (1.83 GB) + pool
     (1.61 GB) + the program's temporaries stay under the 15.75 GiB the
-    compiler gives a v5e. A launch's temporaries are the chunked scan's
-    float32 [16 chunks, 128 heads, 128, 128] products, 134 MB each."""
+    compiler gives a v5e. The nine Mamba mixers of a launch call the
+    chunked-scan kernel once each and nothing of 32 MB or more is copied,
+    reshaped or transposed in a mixer's scope: `x`, `y` and the state
+    reach and leave the kernel as the projection, the conv and the cache
+    hold them (the XLA form relaid `x` to heads-before-positions and `y`
+    back, 33.6 and 67.1 MB each, several times a mixer: PERF.md, PR 43).
+    A launch's temporaries are now the experts' float32 rows
+    ([2048 x 10, 4096] a layer), not the scan's."""
     import functools
 
     from dynamo_tpu.engine.sampler import sample, sample_with_logprobs
@@ -917,7 +983,7 @@ def test_granites_step_programs_fit_the_chip_and_copy_nothing_large(
         kv, state = cache
         kv, state, last, stats = forward_hybrid(
             params, cfg, tokens, positions, kv, state, slots, tables,
-            kv_lens, valid, last_idx, gmm_path="pallas",
+            kv_lens, valid, last_idx, gmm_path="pallas", ssm_path="pallas",
             attention_fn=functools.partial(paged_attention,
                                            interpret=False))
         return ((kv, state), *sample_with_logprobs(
@@ -937,17 +1003,23 @@ def test_granites_step_programs_fit_the_chip_and_copy_nothing_large(
         for kernel in ("ssm_state_update", "paged_decode_attention_pool"):
             assert kernel in text, kernel
     else:
+        rows, t = (1, 2048) if program == "prefill-1x2048" else (4, 512)
+
         def chunk(dtype):
-            return _shape(one_chip, (1, 2048), dtype)
+            return _shape(one_chip, (rows, t), dtype)
 
         compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
             params, cache, chunk(jnp.int32), chunk(jnp.int32),
-            _shape(one_chip, (1, width), jnp.int32), vec(1, jnp.int32),
-            chunk(jnp.bool_), vec(1, jnp.int32), vec(1, jnp.float32),
-            vec(1, jnp.float32), vec(1, jnp.int32), vec(1, jnp.uint32),
-            vec(1, jnp.int32)).compile()
+            _shape(one_chip, (rows, width), jnp.int32),
+            vec(rows, jnp.int32), chunk(jnp.bool_), vec(rows, jnp.int32),
+            vec(rows, jnp.float32), vec(rows, jnp.float32),
+            vec(rows, jnp.int32), vec(rows, jnp.uint32),
+            vec(rows, jnp.int32)).compile()
         text = compiled.as_text()
         assert "paged_prefill_attention_pool" in text
+        assert len(re.findall(r" custom-call\(.*ssm_chunk_scan", text)) == 9
+        assert "ssm_state_update" not in text
+        assert _relaid_in_mixers(text, 32 << 20) == []
     memory = compiled.memory_analysis()
     assert "tpu_custom_call" in text and "tied_head" in text
     # nothing the size of the tied matrix, a layer's state or the pool
@@ -955,8 +1027,10 @@ def test_granites_step_programs_fit_the_chip_and_copy_nothing_large(
     # the embedding would be a bf16[4096,50176] result)
     assert _copies(text, min(tied_bytes, n * 128 * 64 * 128 * 4) // 2) == []
     assert not re.search(r"bf16\[4096,50176\]", text)
+    # 1.06 GB at [1, 2048] and 1.11 at [4, 512] (1.33 and 1.10 with the
+    # XLA form of the scan: what is left is the experts')
     assert memory.temp_size_in_bytes < (0.3e9 if program == "decode-block"
-                                        else 1.3e9)
+                                        else 1.15e9)
     assert 12.9e9 < memory.argument_size_in_bytes < 13.0e9
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.75 * 2 ** 30)
