@@ -458,7 +458,7 @@ class ModelRunner:
         self._latent = model_config.has_latent_layers
         if self._hybrid:
             self._check_hybrid(model_config, runner_config, mesh)
-        if model_config.has_recurrent_state:
+        if model_config.ssm_layers:
             # No bucket under one chunk of the scan (published: 128): a
             # shorter launch reads the same weights, and every bucket
             # less is a row of programs less to compile (`prewarm`).
@@ -631,14 +631,16 @@ class ModelRunner:
         self.latent_decode_tokens = 0
         self.latent_prefill_expand_tokens = 0
         # A model with recurrent state (dynamo_ssm_prefill_*): valid
-        # positions x Mamba layers its prefill launches scanned and the
-        # rows they held, by whether a row began at position 0 (fresh:
-        # from zero state) or took its slot's state up (continued).
+        # positions x state layers (Mamba-2 and short-conv alike) its
+        # prefill launches carried a state over and the rows they held,
+        # by whether a row began at position 0 (fresh: from zero state)
+        # or took its slot's state up (continued).
         self._state_layers = len(model_config.state_layers)
+        self._ssm_layers = len(model_config.ssm_layers)
         self.ssm_prefill_positions = {"fresh": 0, "continued": 0}
         self.ssm_prefill_rows = {"fresh": 0, "continued": 0}
-        # and which path the scan of those launches took
-        # (dynamo_ssm_scan_launches_total)
+        # and which path the Mamba layers' scan of those launches took
+        # (dynamo_ssm_scan_launches_total; a model without them has none)
         self.ssm_scan_launches = {"kernel": 0, "xla": 0}
         # Launches by program (dynamo_program_launches, _tokens):
         # (entry, key) -> [launches, useful prompt tokens] of served
@@ -706,6 +708,28 @@ class ModelRunner:
             row[1] += tokens
         return compile_scope(fn, key, cause=self._warming or cause)
 
+    def prefill_attention_path(self) -> str:
+        """kernel | xla | mixed: where the attention layers of this
+        runner's prefill programs run, over its buckets
+        (`prefill_attention_tiles`; the shapes decide, program by
+        program), the full page group's and then `+` the window
+        group's where the model has one; custom for a caller's own
+        function, none for a stack without attention layers."""
+        cfg = self.model_config
+        if cfg.is_hybrid and not (cfg.kv_layers or cfg.window_kv_layers):
+            return "none"
+        if self._attention_user_supplied:
+            return "custom"
+
+        def group(window: bool) -> str:
+            took = {bool(self.prefill_attention_tiles(bucket, window))
+                    for bucket in self.config.prefill_buckets}
+            return ("mixed" if len(took) > 1
+                    else "kernel" if took == {True} else "xla")
+
+        return "+".join(group(w) for w in (
+            (False, True) if self._windowed else (False,)))
+
     def _table_widths(self, tables) -> tuple:
         """A decode program's key parts for its tables (`_table_args`):
         the width it was traced at and, for a model with two page
@@ -752,7 +776,7 @@ class ModelRunner:
         """Heads a grid step where the Mamba layers of a `bucket`-position
         prefill launch run the chunked-scan kernel, None where they run
         the XLA form (or the model has none): `mamba_prefill`'s rule."""
-        if not self._state_layers:
+        if not self._ssm_layers:
             return None
         from ..models.hybrid import scan_head_block
 
@@ -771,6 +795,7 @@ class ModelRunner:
                 self.ssm_prefill_positions[carry] += (length
                                                       * self._state_layers)
                 self.ssm_prefill_rows[carry] += 1
+        if self._ssm_layers:
             self.ssm_scan_launches[
                 "kernel" if self.ssm_scan_tiles(bucket) else "xla"] += 1
         if self.config.weight_dtype == "int4":
@@ -1026,6 +1051,7 @@ class ModelRunner:
         paths = {
             "decode_attention": attention(self._decode_attention_fn),
             "spec_attention": attention(self._spec_attention_fn),
+            "prefill_attention": self.prefill_attention_path(),
             "weight_matmul": "einsum",
         }
         if self.config.weight_dtype == "int8":
@@ -1034,7 +1060,7 @@ class ModelRunner:
             paths["weight_matmul"] = kernel_path("DYNT_Q4_MATMUL")
         if self._hybrid:
             paths["ssm_update"] = self._ssm_path
-            if self._state_layers:
+            if self._ssm_layers:
                 paths["ssm_scan"] = self._ssm_path
             paths["expert_gmm"] = self._gmm_path
         devices = list(self.mesh.devices.flat)

@@ -175,9 +175,10 @@ def recurrent_state_refusals(model_config, *, mode: str = "aggregated",
                              weight_dtype: str = "model",
                              kv_dtype: str = "model",
                              devices: int = 1) -> None:
-    """What a model with recurrent state (Mamba-2 layers; a hybrid stack)
-    cannot be served with yet, refused at start with the flag and the
-    reason, never answered wrongly later: each of these paths moves or
+    """What a model with recurrent state (Mamba-2 or gated short
+    convolution layers; a hybrid stack) cannot be served with yet,
+    refused at start with the flag and the reason, never answered
+    wrongly later: each of these paths moves or
     reuses KV pages, or shards the step, and none of them carries the
     per-slot state that the pages are useless without. A hybrid stack
     with window layers is refused the same paths for its second page
@@ -703,10 +704,12 @@ class TpuWorker:
             kv_dtype_bytes=1 if self.runner_config.kv_dtype == "int8" else 2,
         )
         log.info("engine on %s %r devices=%s: decode_attention=%s "
-                 "spec_attention=%s weight_matmul=%s%s native=%s",
+                 "spec_attention=%s prefill_attention=%s weight_matmul=%s%s "
+                 "native=%s",
                  paths["platform"], paths["device_kind"],
                  paths["device_ids"], paths["decode_attention"],
-                 paths["spec_attention"], paths["weight_matmul"],
+                 paths["spec_attention"], paths["prefill_attention"],
+                 paths["weight_matmul"],
                  "".join(f" {slot}={paths[slot]}"
                          for slot in ("ssm_update", "ssm_scan", "expert_gmm")
                          if slot in paths), native)
@@ -724,6 +727,7 @@ class TpuWorker:
             devices=",".join(str(d) for d in paths["device_ids"]),
             decode_attention=paths["decode_attention"],
             spec_attention=paths["spec_attention"],
+            prefill_attention=paths["prefill_attention"],
             weight_matmul=paths["weight_matmul"],
             native=str(native).lower()).set(1)
 
@@ -1659,9 +1663,11 @@ class TpuWorker:
                 SSM_PREFILL_LAUNCH_ROWS.labels(
                     worker=worker, carry=carry).set(
                         self.runner.ssm_prefill_rows[carry])
-            for path, count in getattr(
-                    self.runner, "ssm_scan_launches", {}).items():
-                SSM_SCAN_LAUNCHES.labels(worker=worker, path=path).set(count)
+            if self.model_config.ssm_layers:  # only Mamba-2 layers scan
+                for path, count in getattr(
+                        self.runner, "ssm_scan_launches", {}).items():
+                    SSM_SCAN_LAUNCHES.labels(
+                        worker=worker, path=path).set(count)
         expanded = getattr(self.runner, "latent_prefill_expand_tokens", 0)
         if expanded:  # only a model with latent attention
             LATENT_DECODE_TOKENS.labels(worker=worker).set(

@@ -82,8 +82,10 @@ class ModelConfig:
     # dimensions (its default; gpt-oss states truncate=False)
     rope_yarn_truncate: bool = False
     # Hybrid stacks: a layer is ONE mixer, its kind read off
-    # `layer_pattern`, six kinds ("M" Mamba-2, "E" routed experts, "D" a
-    # dense SwiGLU `mlp_hidden` wide, "*" full attention, "W" attention
+    # `layer_pattern`, seven kinds ("M" Mamba-2, "C" a gated short
+    # convolution `conv_kernel` taps wide over `hidden` channels, "E"
+    # routed experts, "D" a dense SwiGLU `mlp_hidden` wide, "*" full
+    # attention (q and k normed per head where `qk_norm`), "W" attention
     # over the last `sliding_window` positions, "L" latent attention
     # (the `mla_*` sizes: one row of `mla_kv_lora_rank` latent +
     # `mla_rope_head_dim` rope-key values a cached token, shared by all
@@ -94,8 +96,8 @@ class ModelConfig:
     # have KV pages, and each kind has a page group of its own
     # (`kv_layers` the full group, "*" or "L"; `window_kv_layers` the
     # window group: a cache layer index counts within its group); "M"
-    # layers keep a fixed-size state per scheduler slot
-    # (models/hybrid.py).
+    # and "C" layers keep a fixed-size state per scheduler slot, "C" the
+    # conv's carry alone (models/hybrid.py).
     layer_pattern: str = ""
     mixers_per_layer: int = 1
     # `layer_pattern` stacks: a mixer's OUTPUT is normed too before it
@@ -104,6 +106,9 @@ class ModelConfig:
     # sigmoid routers: the learned per-expert selection bias (False: the
     # top-k is taken on the raw scores)
     moe_selection_bias: bool = True
+    # what the chosen scores' sum is given before they are divided by it
+    # (`moe_norm_topk`): the family's own (lfm2_moe states 1e-6)
+    moe_renorm_eps: float = 1e-20
     use_rope: bool = True  # nemotron_h attention has no positional term
     mlp_act: str = "swiglu"  # swiglu | relu2 (non-gated: down(relu(up x)^2))
     shared_expert_hidden: int = 0  # 0 = n_shared_experts * expert width
@@ -152,7 +157,7 @@ class ModelConfig:
     def has_recurrent_state(self) -> bool:
         """State that a prefix of KV pages cannot stand for: prefix reuse,
         KV transfer, offload and speculation need a state snapshot too."""
-        return "M" in self.layer_pattern
+        return bool(self.state_layers)
 
     @property
     def has_window_layers(self) -> bool:
@@ -188,6 +193,13 @@ class ModelConfig:
 
     @property
     def state_layers(self) -> tuple[int, ...]:
+        """Layers that keep a state per scheduler slot: a conv carry
+        each, and the "M" ones (`ssm_layers`) an SSM state beside it."""
+        return tuple(i for i in range(self.n_layers)
+                     if self.layer_kind(i) in "MC")
+
+    @property
+    def ssm_layers(self) -> tuple[int, ...]:
         return tuple(i for i in range(self.n_layers)
                      if self.layer_kind(i) == "M")
 
@@ -250,8 +262,27 @@ class ModelConfig:
         return 1 if self.is_mla else 2
 
     @property
+    def kv_heads_per_lane_tile(self) -> int:
+        """kv heads that share one 128-lane row of the pool: 1, or for a
+        `layer_pattern` stack whose head_dim divides a lane tile (64: 2)
+        and whose kv heads fill whole tiles, 128 / head_dim. The TPU
+        tiles a pool's last two axes as (8 or 16, 128): [.., 8, 64] is
+        padded to 128 lanes, twice the memory, and Mosaic refuses a
+        page's 64-lane slice ("Slice shape along dimension 5 must be
+        aligned to tiling (128), but is 64": the compiler, PR 44). So a
+        token's row is stored [kv heads / 2, 128], head 2j in lanes
+        0..63 and head 2j + 1 in 64..127: the same bytes in the same
+        order as [kv heads, 64] row-major, read back by a reshape."""
+        if (not self.layer_pattern or self.is_mla or self.head_dim >= 128
+                or 128 % self.head_dim):
+            return 1
+        per = 128 // self.head_dim
+        return per if self.n_kv_heads % per == 0 else 1
+
+    @property
     def kv_cache_heads(self) -> int:
-        return 1 if self.is_mla else self.n_kv_heads
+        return (1 if self.is_mla
+                else self.n_kv_heads // self.kv_heads_per_lane_tile)
 
     @property
     def kv_cache_head_dim(self) -> int:
@@ -268,7 +299,15 @@ class ModelConfig:
             # a copy of the pool a layer a step (PERF.md, PR 38).
             return -(-width // 128) * 128 if self.has_latent_layers \
                 else width
-        return self.head_dim
+        return self.head_dim * self.kv_heads_per_lane_tile
+
+
+def _blocks(token_mixers: str, dense: int) -> str:
+    """A `layer_pattern` of two mixers a block: each token mixer, then a
+    dense feed-forward behind the first `dense` and experts behind the
+    rest."""
+    return "".join(mixer + ("D" if block < dense else "E")
+                   for block, mixer in enumerate(token_mixers))
 
 
 PRESETS: dict[str, ModelConfig] = {
@@ -464,6 +503,39 @@ PRESETS: dict[str, ModelConfig] = {
         embedding_multiplier=12.0, residual_multiplier=0.22,
         attention_multiplier=0.03125, logits_scaling=16.0,
     ),
+    # LiquidAI LFM2-8B-A1B (config.json, model_type lfm2_moe) at its
+    # published sizes: 24 pre-norm blocks, each a token mixer then a
+    # feed-forward, so 48 mixers; three token mixers in four are a gated
+    # short convolution (three taps over 2048 channels, no bias, no
+    # activation), the others (blocks 2, 6, 10, 14, 18, 21) grouped-query
+    # attention at head_dim 64 with q and k normed per head before rope;
+    # the first two feed-forwards are a dense SwiGLU 7168 wide, the other
+    # 22 are 32 SwiGLU experts 1792 wide (top-4 of sigmoid scores + a
+    # selection bias, renormalised over their sum + 1e-6, no shared
+    # expert). Tied head. `--serve-layers` counts blocks and keeps both
+    # dense ones (`cut_config`).
+    "lfm2-8b-a1b": ModelConfig(
+        name="lfm2-8b-a1b", vocab_size=65536, hidden=2048, n_layers=48,
+        layer_pattern=_blocks("CC*C" * 5 + "C*CC", dense=2),
+        mixers_per_layer=2,
+        n_q_heads=32, n_kv_heads=8, head_dim=64, mlp_hidden=7168,
+        rope_theta=1e6, rms_eps=1e-5, qk_norm=True, tie_embeddings=True,
+        max_context=128000, conv_kernel=3,
+        n_experts=32, n_experts_active=4, expert_mlp_hidden=1792,
+        moe_norm_topk=True, moe_scoring="sigmoid", moe_renorm_eps=1e-6,
+    ),
+    # CPU sibling: two periods (both dense blocks, then six expert
+    # blocks), head_dim 64 as published (two kv heads a lane tile of the
+    # pool), 8 experts top-2
+    "tiny-lfm2-test": ModelConfig(
+        name="tiny-lfm2-test", vocab_size=512, hidden=256, n_layers=16,
+        layer_pattern=_blocks("CC*C" * 2, dense=2), mixers_per_layer=2,
+        n_q_heads=4, n_kv_heads=2, head_dim=64, mlp_hidden=256,
+        rope_theta=1e6, rms_eps=1e-5, qk_norm=True, tie_embeddings=True,
+        max_context=1024, conv_kernel=3,
+        n_experts=8, n_experts_active=2, expert_mlp_hidden=64,
+        moe_norm_topk=True, moe_scoring="sigmoid", moe_renorm_eps=1e-6,
+    ),
     # CPU sibling: every layer kind at least twice, 8 experts top-2, a
     # shared expert, 4 Mamba heads in 2 groups
     "tiny-hybrid-test": ModelConfig(
@@ -529,10 +601,13 @@ def cut_config(config: ModelConfig, layers: Optional[int] = None,
     """The share of `config` one chip of a stated deployment serves: the
     leading `layers`, the experts `lo:hi` of the published count, the
     leading `vocab_rows` of the vocabulary (embedding, head, logits and
-    sampling are over the slice). No width changes. Leading dense blocks
-    (a "D" mixer) count ONCE: the first, then the blocks behind the last
-    of them, so that a cut of a few blocks holds the expert blocks the
-    model is made of."""
+    sampling are over the slice). No width changes. Where every block
+    has the same token mixer, leading dense blocks (a "D" mixer) count
+    ONCE: the first, then the blocks behind the last of them, so that a
+    cut of a few blocks holds the expert blocks the model is made of.
+    Where token mixers differ by block the cut is the contiguous leading
+    blocks, a pipeline stage: skipping a dense block would drop the
+    mixer in front of it and shift the period."""
     changes: dict = {}
     if layers is not None:
         per = config.mixers_per_layer
@@ -543,7 +618,11 @@ def cut_config(config: ModelConfig, layers: Optional[int] = None,
         if config.layer_pattern:
             pattern = config.layer_pattern
             dense = (pattern.rindex("D") // per + 1) if "D" in pattern else 0
-            if dense > 1:
+            # alike but for the feed-forward: pangu_ultra_moe's "LD" x 3
+            # + "LE" x 58; a stack of several token mixers (lfm2_moe's
+            # conv, conv, attention, conv) is cut as it lies
+            alike = per == 2 and len(set(pattern[::per])) == 1
+            if dense > 1 and alike:
                 pattern = pattern[:per] + pattern[dense * per:]
                 if layers * per > len(pattern):
                     raise ValueError(

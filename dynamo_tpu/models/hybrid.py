@@ -5,7 +5,9 @@ published block being two mixers; pangu_ultra_moe's latent attention
 over a dense SwiGLU or routed experts with a shared one, every branch
 normed again before it is added; granitemoehybrid's Mamba-2 or attention
 mixer over SwiGLU experts with a shared one, a published block being
-two mixers, with the family's four multipliers and a tied head).
+two mixers, with the family's four multipliers and a tied head;
+lfm2_moe's gated short convolution or attention with normed q and k over
+a dense SwiGLU or sigmoid-routed SwiGLU experts, a tied head).
 
     x <- x + Mixer_l(RMSNorm(x; w_l))          after the last: RMSNorm, head
     x <- x + RMSNorm(Mixer_l(RMSNorm(x; w_l)); post_l)    with `sandwich_norm`
@@ -27,14 +29,20 @@ own [rows, h] array contracted over h (`_head`: no transposed copy).
      y <- GroupRMSNorm(y * silu(z)) (gate first, then norm); out = y W_out.
      One mixer a block (nemotron_h) or the first of two (granitemoehybrid:
      G = 1, the norm over all H x P lanes as one group)
-  *  attention: grouped-query, causal softmax; rope where `use_rope`
-     (YaRN where `rope_yarn_factor`), none for nemotron_h and
-     granitemoehybrid
+  C  gated short convolution (lfm2_moe): [B | C | u] = x W_in, three
+     thirds of `hidden`; v = B * u; c_t = sum_k w_k v_{t-(K-1)+k}, a
+     depthwise causal conv of `conv_kernel` taps with no bias and NO
+     activation (v before position 0 is zero); out = (C * c) W_out
+  *  attention: grouped-query, causal softmax; with `qk_norm` q and k
+     are RMS-normed per head (a learned gain of head_dim) BEFORE rope;
+     rope where `use_rope` (YaRN where `rope_yarn_factor`), none for
+     nemotron_h and granitemoehybrid
   W  the same over the last `sliding_window` positions, default rope:
      scores masked to q_pos - window < kv_pos <= q_pos
   E  routed experts: sigmoid scores, top-k of scores + bias (of the raw
      scores without `moe_selection_bias`), weights = the unbiased scores
-     renormalised x scale (or a float32 softmax's top-k, renormalised);
+     renormalised (over their sum + `moe_renorm_eps`) x scale (or a
+     float32 softmax's top-k, renormalised);
      expert = W_down relu(W_up x)^2, or with `mlp_act` swiglu
      W_down (silu(W_gate x) * W_up x) from one fused [gate | up] matrix;
      a shared expert of the same form where the model has one
@@ -54,15 +62,19 @@ page group each kind (`*`: cache layer j = the j-th `*` layer of the full
 group, whose table a sequence fills from position 0; `W`: the same count
 within the window group, whose table starts at the first block the
 sequence still holds, positions and lengths counted from that block's
-first token: engine/pages.py), and for each `M` layer a fixed-size state
-per scheduler slot: `conv` [slots, K-1, conv_dim] (the K-1 inputs before
-the next position; the model dtype) and `ssm` [slots, H, P, N] (float32).
+first token: engine/pages.py), and for each `M` and each `C` layer a
+fixed-size state per scheduler slot: `conv` [slots, K-1, channels], the
+K-1 conv inputs before the next position in the model dtype (an `M`
+layer's xBC, conv_dim wide; a `C` layer's B * u, hidden wide) and, an
+`M` layer only, `ssm` [slots, H, P, N] (float32): a `C` layer has no
+other state.
 Rules the scheduler and runner rely on:
 
   * a row that starts at position 0 starts from ZERO state, whatever the
     slot held: admission needs no separate reset;
   * padding of a prefill bucket and empty rows of a batched prefill give
-    dt = 0 and stay out of the conv carry: they advance nothing;
+    dt = 0 and stay out of the conv carry (of either kind): they advance
+    nothing;
   * a decode step touches only active rows (a slot between two prefill
     chunks keeps its state);
   * prefill computes logits for each row's LAST valid position only.
@@ -128,7 +140,8 @@ def hybrid_refusals(config: ModelConfig, weight_dtype: str = "model",
     what = f"{config.name} (layers {config.layer_pattern})"
     if weight_dtype != "model":
         have = " and ".join(
-            name for kind, name in (("M", "Mamba-2"), ("E", "expert"),
+            name for kind, name in (("M", "Mamba-2"), ("C", "short-conv"),
+                                    ("E", "expert"),
                                     ("L", "latent-attention"))
             if kind in config.layer_pattern)
         raise ValueError(
@@ -220,13 +233,18 @@ def hybrid_layer_axes(config: ModelConfig, layer_idx: int) -> dict:
                 "conv_w": (None, None), "conv_b": (None,),
                 "dt_bias": (None,), "a_log": (None,), "d_skip": (None,),
                 "ssm_norm": (None,), "out_proj": (None, "embed")}
+    if kind == "C":  # replicated, as the state it carries
+        return {"norm": ("embed",), "in_proj": ("embed", None),
+                "conv_w": (None, None), "out_proj": (None, "embed")}
     post = {"post_norm": ("embed",)} if config.sandwich_norm else {}
     if kind in "*W":
+        qk = ({"q_norm": ("head_dim",), "k_norm": ("head_dim",)}
+              if config.qk_norm else {})
         return {"norm": ("embed",),
                 "wq": ("embed", "q_heads", "head_dim"),
                 "wk": ("embed", "kv_heads", "head_dim"),
                 "wv": ("embed", "kv_heads", "head_dim"),
-                "wo": ("q_heads", "head_dim", "embed"), **post}
+                "wo": ("q_heads", "head_dim", "embed"), **qk, **post}
     if kind == "L":  # replicated: the worker refuses --tp for this family
         return {"norm": ("embed",), "w_dq": ("embed", None),
                 "q_norm": (None,), "w_uq": (None, None),
@@ -259,6 +277,15 @@ def _shared_width(config: ModelConfig) -> int:
 # Seeded recipe, tied head: a branch writes `BRANCH_GROWTH` times wider
 # than the mixer before it (`branch_gain`).
 BRANCH_GROWTH = 1.23
+# The same for a stack with gated short convolutions, whose mixers are
+# cubic in their input: the first branch is FIRST_JUMP times the
+# embedding, every later one BRANCH_SHARE of the stream it joins, each
+# kind's unit-gain spread taken out (`_conv_stack_gain`). And q and k
+# that are normed per head are drawn NORMED_QK_GAIN times wider, which
+# the norms take out again.
+FIRST_JUMP, BRANCH_SHARE = 60.0, 0.25
+KIND_SPREAD = {"C": 1.0, "D": 0.6, "E": 0.3, "*": 0.125}
+NORMED_QK_GAIN = 2.0
 
 
 def branch_gain(config: ModelConfig, layer_idx: int) -> float:
@@ -283,13 +310,52 @@ def branch_gain(config: ModelConfig, layer_idx: int) -> float:
         return 1.0
     s0 = (config.embedding_multiplier * config.logits_scaling
           / math.sqrt(config.hidden))
+    if "C" in config.layer_pattern:
+        return _conv_stack_gain(config, layer_idx, s0)
     return s0 / config.residual_multiplier * BRANCH_GROWTH ** layer_idx
 
 
+def _conv_stack_gain(config: ModelConfig, layer_idx: int, s0: float):
+    """`branch_gain` for a stack with gated short convolutions. A conv
+    mixer is CUBIC in its input (B * u, gated by C), so a branch as wide
+    as the stream it joins multiplies a relative error by 2.2, and nine
+    such mixers by a thousand: under the exponential recipe float32 and
+    bf16 share no token at the published sizes (gap_mean 3.3 on the
+    chip, where a token drawn at random reads 4.2; K and V rounded to
+    int8 alone read 0.73: PERF.md, PR 44). A branch much wider than the
+    stream costs a factor 3 once, so the growth a tied head needs is ONE
+    step: mixer 0 writes FIRST_JUMP times the embedding's spread (the
+    embedding is 1/60 of the stream from there on), and every later
+    mixer BRANCH_SHARE of the stream it joins, whatever its kind: each
+    kind's gain is over the spread a unit-gain mixer of that kind writes
+    (KIND_SPREAD, measured at the published widths on the chip: an
+    attention mixer averages its values, an expert layer four experts)."""
+    stream = s0
+    for m in range(layer_idx + 1):
+        branch = stream * (FIRST_JUMP if m == 0 else BRANCH_SHARE)
+        if m < layer_idx:
+            stream = math.hypot(stream, branch)
+    kind = config.layer_kind(layer_idx)
+    if kind not in KIND_SPREAD:
+        raise ValueError(
+            f"{config.name} (layers {config.layer_pattern}): no seeded "
+            f"recipe for a stack that mixes gated short convolutions "
+            f"with layer kind {kind!r}; it has one for "
+            f"{' '.join(KIND_SPREAD)}")
+    return branch / KIND_SPREAD[kind] / config.residual_multiplier
+
+
 def score_gain(config: ModelConfig) -> float:
-    """Seeded recipe: what wq and wk are multiplied by, so that scores
-    at `attention_multiplier` have the spread they have at
-    1/sqrt(head_dim) with unit gains."""
+    """Seeded recipe: what wq and wk are multiplied by. A model that
+    states its own score scale: so that scores at `attention_multiplier`
+    have the spread they have at 1/sqrt(head_dim) with unit gains. A
+    `layer_pattern` stack that norms q and k per head: NORMED_QK_GAIN,
+    which the norms take out again (a trained model's projections have
+    no unit scale, which is what its norms are for; without them the
+    seeded scores are four times sharper, and a program that forgets
+    the norms is told from one that has them)."""
+    if config.qk_norm and config.is_hybrid:
+        return NORMED_QK_GAIN
     if not config.attention_multiplier:
         return 1.0
     return (config.attention_multiplier
@@ -351,7 +417,13 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
     normed before it is added, so no matrix's scale reaches the residual
     stream, each block adds two unit-RMS branches, and the final norm
     and a head of spread 1 give logits of spread 1 as in the other
-    recipes."""
+    recipes.
+
+    A gated short convolution draws W_in [h, 3h] (the thirds B, C, u in
+    that order) from key 0, its taps [K, h] from key 1 (normal /
+    sqrt(K), tap K-1 on the current position) and W_out (centred) from
+    key 6, the keys a Mamba mixer's three like matrices have. With
+    `qk_norm` an attention mixer has two more gains of head_dim, ones."""
     dtype = jnp.dtype(config.dtype)
     h = config.hidden
     ks = jax.random.split(k, 15)
@@ -420,6 +492,13 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
             "ssm_norm": jnp.ones((inner,), dtype),
             "out_proj": dense(ks[6], (inner, h), inner, 0, out_gain),
         })
+    elif kind == "C":
+        kw = config.conv_kernel
+        p.update({
+            "in_proj": dense(ks[0], (h, 3 * h), h),
+            "conv_w": dense(ks[1], (kw, h), kw),
+            "out_proj": dense(ks[6], (h, h), h, 0, out_gain),
+        })
     elif kind in "*W":
         qh, kh, hd = config.n_q_heads, config.n_kv_heads, config.head_dim
         qk_gain = score_gain(config)
@@ -429,6 +508,9 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
             "wv": dense(ks[2], (h, kh, hd), h),
             "wo": dense(ks[3], (qh, hd, h), qh * hd, (0, 1), out_gain),
         })
+        if config.qk_norm:
+            p["q_norm"] = jnp.ones((hd,), dtype)
+            p["k_norm"] = jnp.ones((hd,), dtype)
     else:
         m = config.expert_mlp_hidden
         sm = _shared_width(config)
@@ -461,28 +543,39 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
     return p
 
 
+def conv_channels(config: ModelConfig, layer_idx: int) -> int:
+    """Width of a state layer's conv carry: Mamba-2's xBC, or the gated
+    short convolution's B * u."""
+    return (config.mamba_conv_dim if config.layer_kind(layer_idx) == "M"
+            else config.hidden)
+
+
 def make_state_cache(config: ModelConfig, slots: int) -> dict:
-    """The per-slot recurrent state: one `conv` and one `ssm` array per
-    Mamba layer (a list, so each layer's update aliases its own buffer)."""
-    n = len(config.state_layers)
+    """The per-slot recurrent state: one `conv` array per state layer
+    ("M" or "C", in order) and one `ssm` array per Mamba layer (lists, so
+    each layer's update aliases its own buffer). A stack of "C" layers
+    alone has an empty `ssm` list."""
     return {
         "conv": [jnp.zeros((slots, config.conv_kernel - 1,
-                            config.mamba_conv_dim), jnp.dtype(config.dtype))
-                 for _ in range(n)],
+                            conv_channels(config, i)),
+                           jnp.dtype(config.dtype))
+                 for i in config.state_layers],
         "ssm": [jnp.zeros((slots, config.mamba_heads, config.mamba_head_dim,
                            config.ssm_state),
                           jnp.dtype(config.ssm_state_dtype))
-                for _ in range(n)],
+                for _ in config.ssm_layers],
     }
 
 
 def state_slot_bytes(config: ModelConfig) -> int:
-    """Bytes of recurrent state one slot holds, all Mamba layers."""
-    conv = ((config.conv_kernel - 1) * config.mamba_conv_dim
+    """Bytes of recurrent state one slot holds, all state layers: each
+    its conv carry, a Mamba layer its SSM state beside it."""
+    conv = ((config.conv_kernel - 1)
+            * sum(conv_channels(config, i) for i in config.state_layers)
             * jnp.dtype(config.dtype).itemsize)
     ssm = (config.mamba_heads * config.mamba_head_dim * config.ssm_state
            * jnp.dtype(config.ssm_state_dtype).itemsize)
-    return len(config.state_layers) * (conv + ssm)
+    return conv + len(config.ssm_layers) * ssm
 
 
 # ---------------------------------------------------------------------------
@@ -566,8 +659,10 @@ def mamba_prefill(x, lp, config: ModelConfig, conv, ssm, valid,
                 jnp.einsum("bth,hm->btm", x, w[:, :split]), config)
             dt = jnp.einsum("bth,hm->btm", x, w[:, split:])
         n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
-        xbc, conv = causal_conv(conv, xbc, lp["conv_w"], lp["conv_b"],
-                                n_valid)
+        xbc_dtype = xbc.dtype
+        xbc, conv = causal_conv(conv, xbc, lp["conv_w"], n_valid)
+        xbc = jax.nn.silu(xbc + lp["conv_b"].astype(jnp.float32)
+                          ).astype(xbc_dtype)
         dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
         dt = jnp.where(valid[:, :, None], dt, 0.0)
         a = -jnp.exp(lp["a_log"])
@@ -620,6 +715,41 @@ def mamba_decode(x, lp, config: ModelConfig, conv, ssm, active,
         return jnp.einsum("sm,mh->sh", y, lp["out_proj"]), conv, ssm
 
 
+def _split_bcu(bcu, config: ModelConfig):
+    h = config.hidden
+    return bcu[..., :h], bcu[..., h:2 * h], bcu[..., 2 * h:]
+
+
+def short_conv_prefill(x, lp, config: ModelConfig, conv, valid):
+    """The gated short convolution over a prefill chunk a row. x [B, T, h]
+    (normed); conv [B, K-1, h]: the rows' carry going in (the K-1 values
+    of B * u before the chunk). Returns (out [B, T, h], carry coming
+    out); padding stays out of the carry (`causal_conv`)."""
+    with jax.named_scope("conv_mixer"):
+        b, c, u = _split_bcu(
+            jnp.einsum("bth,hm->btm", x, lp["in_proj"]), config)
+        n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+        y, conv = causal_conv(conv, b * u, lp["conv_w"], n_valid)
+        y = (c.astype(jnp.float32) * y).astype(x.dtype)
+        return jnp.einsum("btm,mh->bth", y, lp["out_proj"]), conv
+
+
+def short_conv_decode(x, lp, config: ModelConfig, conv, active):
+    """One token a slot. x [S, h]; conv [S, K-1, h]: the WHOLE carry of
+    this layer (row i = slot i). Inactive rows keep theirs."""
+    with jax.named_scope("conv_mixer"):
+        b, c, u = _split_bcu(
+            jnp.einsum("sh,hm->sm", x, lp["in_proj"]), config)
+        window = jnp.concatenate([conv.astype(x.dtype), (b * u)[:, None]],
+                                 axis=1)  # [S, K, h]
+        y = jnp.einsum("skc,kc->sc", window.astype(jnp.float32),
+                       lp["conv_w"].astype(jnp.float32))
+        conv = jnp.where(active[:, None, None],
+                         window[:, 1:].astype(conv.dtype), conv)
+        y = (c.astype(jnp.float32) * y).astype(x.dtype)
+        return jnp.einsum("sm,mh->sh", y, lp["out_proj"]), conv
+
+
 def _relu2(u):
     return jnp.square(jax.nn.relu(u))
 
@@ -667,6 +797,9 @@ def _qkv(h, lp, config: ModelConfig, kind: str, positions):
     q = jnp.einsum("bth,hqd->btqd", h, lp["wq"])
     k = jnp.einsum("bth,hkd->btkd", h, lp["wk"])
     v = jnp.einsum("bth,hkd->btkd", h, lp["wv"])
+    if config.qk_norm:  # per head, before rope
+        q = rms_norm(q, lp["q_norm"], config.rms_eps)
+        k = rms_norm(k, lp["k_norm"], config.rms_eps)
     return apply_rope(q, positions, tables), apply_rope(k, positions,
                                                         tables), v
 
@@ -990,17 +1123,25 @@ def _prefix_attention(pages, layer, q, kv_cache, block_tables, positions,
                                positions, kv_lens, flat_gather=True)
 
 
+def _as_stored(x, cache):
+    """k or v [..., kv heads, head_dim] as the pool holds a token: [...,
+    cache heads, cache lanes], the same values row-major (a pool that
+    packs kv heads into lane tiles: `ModelConfig.kv_heads_per_lane_tile`;
+    every other pool's shape is x's own and nothing is traced)."""
+    return x.reshape(*x.shape[:-2], *cache.shape[4:])
+
+
 def _group_attention(attention_fn, q_shape, cache, tables):
     """Prefill attention of one page group of a model with window
-    layers: `attention_fn` (`ops.paged_attention.paged_attention`: the
-    blocked kernel, with a window's lower edge where the layer states
-    one) where one is given and `prefill_kernel_tiles` admits the
-    group's shapes, the blocked XLA form otherwise. Static: the shapes
-    of a program decide."""
+    layers, or whose pool packs kv heads into lane tiles: `attention_fn`
+    (`ops.paged_attention.paged_attention`: the blocked kernel, with a
+    window's lower edge where the layer states one) where one is given
+    and `prefill_kernel_tiles` admits the group's shapes, the blocked
+    XLA form otherwise. Static: the shapes of a program decide."""
     _, t, qh, hd = q_shape
     if attention_fn is not None and prefill_kernel_tiles(
-            t, qh, cache.shape[4], hd, cache.shape[3], tables.shape[1],
-            cache.dtype) is not None:
+            t, qh, cache.shape[4] * cache.shape[5] // hd, hd,
+            cache.shape[3], tables.shape[1], cache.dtype) is not None:
         return attention_fn
     return prefill_attention
 
@@ -1023,37 +1164,51 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
     the sequence's, each group through `_group_attention`. `ssm_path`:
     the Mamba mixers' scan (`mamba_prefill`); no other mixer reads it."""
     attention = win_attention = attention_fn or paged_attention_xla
+    q_shape = (*tokens.shape, config.n_q_heads, config.head_dim)
     if window is not None:
         win_cache, win_tables, win_pos, win_lens = _window_frame(
             window, positions, kv_lens)
-        q_shape = (*tokens.shape, config.n_q_heads, config.head_dim)
         attention = _group_attention(attention_fn, q_shape, kv_cache,
                                      block_tables)
         win_attention = _group_attention(attention_fn, q_shape, win_cache,
                                          win_tables)
+    elif config.kv_heads_per_lane_tile > 1:
+        # head_dim 64: no prefill kernel takes the geometry, and the
+        # blocked XLA form scores the table prefix the rows fill, not the
+        # longest context served (float32 [rows, T, heads, keys])
+        attention = _group_attention(attention_fn, q_shape, kv_cache,
+                                     block_tables)
     fresh = positions[:, 0] == 0  # a row at position 0 starts from zero
     scaled = attention_scale(config)
     x = _embed(params, config, tokens)
     conv_out, ssm_out = list(state["conv"]), list(state["ssm"])
     stats = jnp.zeros((moe_stats_size(config),), jnp.int32)
-    kv_idx = win_idx = state_idx = 0
+    kv_idx = win_idx = state_idx = ssm_idx = 0
     for layer_idx, lp in enumerate(params["layers"]):
         kind = config.layer_kind(layer_idx)
         h = rms_norm(x, lp["norm"], config.rms_eps)
         if kind == "M":
-            conv_all, ssm_all = conv_out[state_idx], ssm_out[state_idx]
+            conv_all, ssm_all = conv_out[state_idx], ssm_out[ssm_idx]
             conv = jnp.where(fresh[:, None, None], 0, conv_all[slots])
             ssm = jnp.where(fresh[:, None, None, None], 0, ssm_all[slots])
             out, conv, ssm = mamba_prefill(h, lp, config, conv, ssm, valid,
                                            ssm_path)
             conv_out[state_idx] = conv_all.at[slots].set(conv, mode="drop")
-            ssm_out[state_idx] = ssm_all.at[slots].set(ssm, mode="drop")
+            ssm_out[ssm_idx] = ssm_all.at[slots].set(ssm, mode="drop")
+            state_idx += 1
+            ssm_idx += 1
+        elif kind == "C":
+            conv_all = conv_out[state_idx]
+            conv = jnp.where(fresh[:, None, None], 0, conv_all[slots])
+            out, conv = short_conv_prefill(h, lp, config, conv, valid)
+            conv_out[state_idx] = conv_all.at[slots].set(conv, mode="drop")
             state_idx += 1
         elif kind == "*":
             with jax.named_scope(ATTENTION_SCOPES[kind]):
                 q, k, v = _qkv(h, lp, config, kind, positions)
-                kv_cache = write_kv_pages(kv_cache, kv_idx, k, v,
-                                          block_tables, positions, valid)
+                kv_cache = write_kv_pages(
+                    kv_cache, kv_idx, _as_stored(k, kv_cache),
+                    _as_stored(v, kv_cache), block_tables, positions, valid)
                 attn = attention(q, kv_cache, kv_idx, block_tables,
                                  positions, kv_lens, **scaled)
                 out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])
@@ -1110,14 +1265,19 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
     conv_out, ssm_out = list(state["conv"]), list(state["ssm"])
     stats = jnp.zeros((moe_stats_size(config),), jnp.int32)
     ks, vs, win_ks, win_vs = [], [], [], []
-    kv_idx = win_idx = state_idx = 0
+    kv_idx = win_idx = state_idx = ssm_idx = 0
     for layer_idx, lp in enumerate(params["layers"]):
         kind = config.layer_kind(layer_idx)
         h = rms_norm(x, lp["norm"], config.rms_eps)
         if kind == "M":
-            out, conv_out[state_idx], ssm_out[state_idx] = mamba_decode(
-                h, lp, config, conv_out[state_idx], ssm_out[state_idx],
+            out, conv_out[state_idx], ssm_out[ssm_idx] = mamba_decode(
+                h, lp, config, conv_out[state_idx], ssm_out[ssm_idx],
                 active, ssm_path)
+            state_idx += 1
+            ssm_idx += 1
+        elif kind == "C":
+            out, conv_out[state_idx] = short_conv_decode(
+                h, lp, config, conv_out[state_idx], active)
             state_idx += 1
         elif kind == "*":
             with jax.named_scope(ATTENTION_SCOPES[kind]):
@@ -1160,9 +1320,10 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
         kv_cache = write_latent_stack(kv_cache, jnp.stack(ks), block_tables,
                                       positions, active)
     elif ks:
-        kv_cache = write_kv_stack(kv_cache, jnp.stack(ks), jnp.stack(vs),
-                                  block_tables, positions[:, None],
-                                  active[:, None])
+        kv_cache = write_kv_stack(
+            kv_cache, _as_stored(jnp.stack(ks), kv_cache),
+            _as_stored(jnp.stack(vs), kv_cache), block_tables,
+            positions[:, None], active[:, None])
     if window is not None:
         win_cache = write_kv_stack(win_cache, jnp.stack(win_ks),
                                    jnp.stack(win_vs), win_tables,

@@ -575,7 +575,8 @@ def _routing_weights(x: jax.Array, p: dict, config: ModelConfig):
         scores = jax.nn.softmax(logits, axis=-1)
         topv, topi = jax.lax.top_k(scores, config.n_experts_active)
     if config.moe_norm_topk:
-        topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+        topv = topv / (jnp.sum(topv, axis=-1, keepdims=True)
+                       + config.moe_renorm_eps)
     return topv * config.moe_routed_scale, topi
 
 
@@ -816,7 +817,10 @@ def paged_attention_xla(
     values, scales = _kv_parts(kv_cache)
     b, t, qh, hd = q.shape
     ps = values.shape[3]
-    kh = values.shape[4]
+    # a pool that packs kv heads into whole lane tiles ([.., kh / 2,
+    # 128] at head_dim 64: `ModelConfig.kv_heads_per_lane_tile`) holds a
+    # token's [kh, hd] row-major as it is
+    kh = values.shape[4] * values.shape[5] // hd
     max_pages = block_tables.shape[1]
     ctx = max_pages * ps
     # Gather pages: [B, max_pages, ps, kh, hd] -> [B, ctx, kh, hd]
@@ -877,7 +881,7 @@ def paged_attention_decode_xla(
     values, scales = _kv_parts(kv_cache)
     b, _, qh, hd = q.shape
     ps = values.shape[3]
-    kh = values.shape[4]
+    kh = values.shape[4] * values.shape[5] // hd  # a packed pool's too
     max_pages = block_tables.shape[1]
     ctx = max_pages * ps
     k_pages = values[layer, 0][block_tables]

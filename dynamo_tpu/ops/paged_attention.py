@@ -783,6 +783,38 @@ def _combine_current(q, acc, m, l, k_cur, v_cur, sm_scale=None):
     return out.reshape(b, 1, qh, hd).astype(q.dtype)
 
 
+def _packed_pool_partials(q, kv_pool, layer, block_tables, kv_lens_hist,
+                          kh: int, pages_per_chunk, interpret, sm_scale):
+    """`paged_decode_attention_pool` over a pool that packs `per` = 128 /
+    head_dim kv heads into every 128-lane row ([L, 2, P, ps, kh / per,
+    128]: `ModelConfig.kv_heads_per_lane_tile`; at head_dim 64 Mosaic
+    refuses a page's 64-lane slice and the TPU pads such a pool to twice
+    its bytes). The kernel is the one every other pool runs, handed the
+    pool as it lies: to it a lane tile is ONE kv head 128 wide with per x
+    group query rows. Each query row carries its values in the lanes its
+    own kv head has in the tile and zeros in the others, so q . row =
+    q_h . k_h exactly (the other head's lanes meet zeros) and P V comes
+    back 128 wide, of which the row's own lanes are its head's context.
+    Twice the MXU columns of a bandwidth-bound kernel; the bytes
+    streamed are the pool's own. q [B, qh, hd] -> (acc [B, kh, group,
+    hd], m, l [B, kh, group]) as the unpacked entry gives them."""
+    b, qh, hd = q.shape
+    per = kv_pool.shape[5] // hd
+    group, tiles = qh // kh, kh // per
+    own = jnp.eye(per, dtype=q.dtype)  # [head's slot, lane block]
+    wide = (q.reshape(b, tiles, per, group, 1, hd)
+            * own[None, None, :, None, :, None]).reshape(b, qh, per * hd)
+    acc, m, l = paged_decode_attention_pool(
+        wide, kv_pool, layer, block_tables, kv_lens_hist,
+        pages_per_chunk=pages_per_chunk, interpret=interpret,
+        sm_scale=sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd))
+    acc = jnp.einsum("btsgrh,sr->btsgh",
+                     acc.reshape(b, tiles, per, group, per, hd),
+                     own.astype(acc.dtype))
+    return (acc.reshape(b, kh, group, hd), m.reshape(b, kh, group),
+            l.reshape(b, kh, group))
+
+
 def paged_attention_decode_pool(
     q: jax.Array,  # [B, 1, qh, hd]
     kv_cache,  # [L, 2, P, ps, kh, hd] or int8 (values, scales) pair
@@ -811,16 +843,27 @@ def paged_attention_decode_pool(
     page group (its own table and lengths; bf16 pool only), through
     `paged_decode_attention_window`. `sm_scale`: the score scale of a
     model that states one (a full layer's bf16 pool only; None:
-    1/sqrt(hd), and nothing of it is traced)."""
+    1/sqrt(hd), and nothing of it is traced). A pool whose rows pack
+    several kv heads into a lane tile (head_dim 64) runs the same kernel
+    through `_packed_pool_partials`; the geometry decides."""
     values, scales = (kv_cache if isinstance(kv_cache, tuple)
                       else (kv_cache, None))
     assert sm_scale is None or (scales is None and not window), (
         "sm_scale: a bf16 full group")
-    if _q8_needs_xla(values, scales, interpret):
+    packed = values.shape[5] != q.shape[-1]  # kv heads share lane tiles
+    if _q8_needs_xla(values, scales, interpret) or (
+            packed and (window or scales is not None)):
         from ..models.transformer import paged_attention_decode_xla
 
         return paged_attention_decode_xla(q, kv_cache, layer, block_tables,
-                                          kv_lens, k_cur, v_cur)
+                                          kv_lens, k_cur, v_cur,
+                                          window=window)
+    if packed:
+        acc, m, l = _packed_pool_partials(
+            q[:, 0], values, layer, block_tables,
+            jnp.maximum(kv_lens - 1, 0), k_cur.shape[2], pages_per_chunk,
+            interpret, sm_scale)
+        return _combine_current(q, acc, m, l, k_cur, v_cur, sm_scale)
     if window:
         acc, m, l = paged_decode_attention_window(
             q[:, 0], values, layer, block_tables,

@@ -461,20 +461,21 @@ def ssm_chunk_scan_kernel(state, dt, a, xbc, *, chunk: int,
 # ---------------------------------------------------------------------------
 
 
-def causal_conv(carry, x, weight, bias, n_valid):
+def causal_conv(carry, x, weight, n_valid):
     """Depthwise causal convolution of width K over T positions with a
     carry of the K-1 inputs before them. carry [B, K-1, C], x [B, T, C],
-    weight [K, C] (tap K-1 multiplies the current position), bias [C],
-    n_valid [B]: how many leading positions of each row are real. Returns
-    (silu(conv + bias) [B, T, C], new carry): the last K-1 REAL inputs of
-    each row, so padding never enters it (n_valid = 0 keeps the carry)."""
+    weight [K, C] (tap K-1 multiplies the current position), n_valid [B]:
+    how many leading positions of each row are real. Returns (the taps'
+    sum [B, T, C] in float32: bias, activation and rounding are the
+    caller's, Mamba-2's silu(conv + b) and the gated short convolution's
+    bare sum alike; new carry): the last K-1 REAL inputs of each row, so
+    padding never enters it (n_valid = 0 keeps the carry)."""
     k = weight.shape[0]
     prev = carry.astype(x.dtype)
     seq = jnp.concatenate([prev, x], axis=1)
     t = x.shape[1]
     out = sum(seq[:, i:i + t].astype(jnp.float32)
               * weight[i].astype(jnp.float32) for i in range(k))
-    out = jax.nn.silu(out + bias.astype(jnp.float32)).astype(x.dtype)
     # rows n_valid .. n_valid + K-2 of `seq`, taken from its two parts:
     # gathered from `seq` itself, XLA writes all [K-1 + T, C] of it first
     idx = n_valid[:, None] + jnp.arange(k - 1)[None, :]  # [B, K-1]

@@ -355,10 +355,13 @@ ENGINE_INFO = Gauge(
     "dynamo_engine_info",
     "Constant 1; the labels name this worker's devices and the path "
     "(pallas | interpret | xla | einsum | custom) decode attention, "
-    "spec attention and the weight matmul resolved to, and whether "
-    "dynamo_tpu._native loaded",
+    "spec attention and the weight matmul resolved to, where the "
+    "attention layers of its prefill programs run (prefill_attention: "
+    "kernel | xla | mixed over the buckets, the full page group's then "
+    "+ the window group's; the geometry decides, program by program; "
+    "custom; none), and whether dynamo_tpu._native loaded",
     ["worker", "platform", "device_kind", "devices", "decode_attention",
-     "spec_attention", "weight_matmul", "native"],
+     "spec_attention", "prefill_attention", "weight_matmul", "native"],
     registry=REGISTRY,
 )
 ENGINE_TOKENS = Gauge(
@@ -491,19 +494,21 @@ KV_WINDOW_ALLOC_FAIL = Gauge(
 SSM_STATE_SLOT_MS = Gauge(
     "dynamo_ssm_state_slot_ms",
     "Model with recurrent state: sum over committed steps of (scheduler "
-    "slots held, each with one fixed-size Mamba-2 state) x the step's "
-    "wall ms. Over the growth of dynamo_step_part_ms_total{part=wall} "
-    "and --max-batch it is the share of the state cache that is live",
+    "slots held, each with one fixed-size state: a Mamba-2 layer's conv "
+    "carry and SSM state, a gated short-convolution layer's conv carry "
+    "alone) x the step's wall ms. Over the growth of "
+    "dynamo_step_part_ms_total{part=wall} and --max-batch it is the "
+    "share of the state cache that is live",
     ["worker"], registry=REGISTRY,
 )
 SSM_PREFILL_POSITIONS = Gauge(
     "dynamo_ssm_prefill_positions_total",
-    "Model with recurrent state: valid positions x Mamba layers that "
-    "prefill launches have scanned since start, by carry: fresh (the "
-    "row began at position 0, from zero state) | continued (the row "
-    "took up the state its slot kept from the launch before). "
-    "continued over both is the share of the scan that ran on a "
-    "carried state",
+    "Model with recurrent state: valid positions x state layers (Mamba-2 "
+    "and short-convolution alike) that prefill launches have carried a "
+    "state over since start, by carry: fresh (the row began at position "
+    "0, from zero state) | continued (the row took up the state its "
+    "slot kept from the launch before). continued over both is the "
+    "share that ran on a carried state",
     ["worker", "carry"], registry=REGISTRY,
 )
 SSM_PREFILL_LAUNCH_ROWS = Gauge(

@@ -1034,3 +1034,175 @@ def test_granites_step_programs_fit_the_chip_and_copy_nothing_large(
     assert 12.9e9 < memory.argument_size_in_bytes < 13.0e9
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.75 * 2 ** 30)
+
+
+# lfm2-8b-a1b's first pipeline stage as the benchmark's cell serves it
+# (12 of 24 blocks: 9 conv and 3 attention mixers at head_dim 64, both
+# dense blocks, 10 expert layers of 32; 256 slots, 192-page tables).
+LFM2 = {"rows": 256, "width": 192, "pages": 49152}
+
+
+@pytest.mark.parametrize("width", [8, 192])
+def test_the_pool_decode_kernel_compiles_at_head_dim_64(one_chip, width):
+    """A pool of [.., 16, 8, 64] pages is refused by Mosaic ("Slice shape
+    along dimension 5 must be aligned to tiling (128), but is 64": the
+    TPU pads such a pool to 128 lanes, twice its bytes); stored [.., 16,
+    4, 128], two kv heads a lane tile, the same kernel compiles through
+    `paged_attention_decode_pool` at 256 rows, 4 queries a kv head, and
+    the narrowest and widest tables the cell serves."""
+    import functools
+
+    from dynamo_tpu.ops.paged_attention import (
+        paged_attention_decode_pool,
+        paged_decode_attention_pool,
+    )
+
+    n = LFM2["rows"]
+    q = _shape(one_chip, (n, 1, 32, 64), jnp.bfloat16)
+    cur = _shape(one_chip, (n, 1, 8, 64), jnp.bfloat16)
+    tables = _shape(one_chip, (n, width), jnp.int32)
+    lens = _shape(one_chip, (n,), jnp.int32)
+    packed = _shape(one_chip, (3, 2, LFM2["pages"], PAGE, 4, 128),
+                    jnp.bfloat16)
+    compiled = jax.jit(functools.partial(
+        paged_attention_decode_pool, interpret=False)).lower(
+            q, packed, _shape(one_chip, (), jnp.int32), tables, lens, cur,
+            cur).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    if width == 8:  # what the unpacked pool meets, in the compiler's words
+        unpacked = _shape(one_chip, (3, 2, 1025, PAGE, 8, 64), jnp.bfloat16)
+        with pytest.raises(Exception, match="aligned to tiling"):
+            paged_decode_attention_pool.lower(
+                _shape(one_chip, (n, 32, 64), jnp.bfloat16), unpacked,
+                _shape(one_chip, (), jnp.int32), tables, lens).compile()
+
+
+def _lfm2_programs(one_chip):
+    """(config, params, caches) as shapes on the described chip."""
+    from dynamo_tpu.models.config import cut_config, get_config
+    from dynamo_tpu.models.hybrid import make_state_cache
+    from dynamo_tpu.models.transformer import init_params, make_kv_cache
+
+    cfg = cut_config(get_config("lfm2-8b-a1b"), 12)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    kv = on_chip(jax.eval_shape(
+        lambda: make_kv_cache(cfg, LFM2["pages"], PAGE)))
+    state = on_chip(jax.eval_shape(
+        lambda: make_state_cache(cfg, LFM2["rows"])))
+    return cfg, params, (kv, state)
+
+
+@pytest.mark.parametrize("program", ["decode-block", "prefill-1x2048",
+                                     "prefill-4x512"])
+def test_lfm2s_step_programs_fit_the_chip_and_copy_nothing_large(
+        one_chip, program):
+    """The fused 8-step decode block at 256 rows and the widest table,
+    and the widest prefill launches, as `ModelRunner` builds them,
+    compile for a described v5e: the pool ([3, 2, 49152, 16, 4, 128]:
+    4.83 GB, not the 9.66 an unpacked head_dim 64 would be padded to),
+    the conv carries (18.9 MB) and the weights (7.86 GB) are
+    12.71 GB of arguments, and with the program's temporaries stay under
+    the 15.75 GiB the compiler gives a v5e; the tied head contracts the
+    embedding's own [65536, 2048] array; the pool decode kernel and the
+    grouped matmul are in the decode program (the conv mixers are XLA's
+    in both); a prefill launch holds no Pallas attention kernel (head_dim
+    64: the blocked XLA form) and copies neither the pool nor the tied
+    matrix."""
+    import functools
+
+    from dynamo_tpu.engine.sampler import sample, sample_with_logprobs
+    from dynamo_tpu.models.hybrid import (
+        forward_hybrid,
+        forward_hybrid_decode,
+        moe_stats_size,
+    )
+    from dynamo_tpu.ops.paged_attention import (
+        paged_attention,
+        paged_attention_decode_pool,
+    )
+
+    cfg, params, cache = _lfm2_programs(one_chip)
+    n, width = LFM2["rows"], LFM2["width"]
+    assert cache[0].shape == (3, 2, LFM2["pages"], PAGE, 4, 128)
+    assert [c.shape for c in cache[1]["conv"]] == [(n, 2, 2048)] * 9
+    assert cache[1]["ssm"] == [] and "lm_head" not in params
+    tied_bytes = 65536 * 2048 * 2
+
+    def decode(params, cache, tokens, positions, tables, kv_lens, active,
+               temperature, top_p, top_k, seeds, step_idx):
+        def body(carry, _):
+            (kv, state), toks, pos, lens, sidx, acc = carry
+            kv, state, logits, stats = forward_hybrid_decode(
+                params, cfg, toks, pos, kv, state, tables, lens, active,
+                decode_attention_fn=functools.partial(
+                    paged_attention_decode_pool, interpret=False),
+                ssm_path="pallas", gmm_path="pallas")
+            nxt = sample(logits[:, 0, :], temperature, top_p, top_k, seeds,
+                         sidx)
+            return ((kv, state), nxt, pos + 1, lens + 1, sidx + 1,
+                    acc + stats), nxt
+
+        (cache, *_, acc), toks = jax.lax.scan(
+            body, (cache, tokens, positions, kv_lens, step_idx,
+                   jnp.zeros(moe_stats_size(cfg), jnp.int32)), None, length=8)
+        return cache, toks, acc
+
+    def prefill(params, cache, tokens, positions, tables, kv_lens, valid,
+                last_idx, temperature, top_p, top_k, seeds, slots):
+        kv, state = cache
+        kv, state, last, stats = forward_hybrid(
+            params, cfg, tokens, positions, kv, state, slots, tables,
+            kv_lens, valid, last_idx, gmm_path="pallas", ssm_path="pallas",
+            attention_fn=functools.partial(paged_attention,
+                                           interpret=False))
+        return ((kv, state), *sample_with_logprobs(
+            last, temperature, top_p, top_k, seeds, jnp.int32(0)), stats)
+
+    def vec(rows, dtype):
+        return _shape(one_chip, (rows,), dtype)
+
+    if program == "decode-block":
+        compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+            params, cache, vec(n, jnp.int32), vec(n, jnp.int32),
+            _shape(one_chip, (n, width), jnp.int32), vec(n, jnp.int32),
+            vec(n, jnp.bool_), vec(n, jnp.float32), vec(n, jnp.float32),
+            vec(n, jnp.int32), vec(n, jnp.uint32),
+            vec(n, jnp.int32)).compile()
+        text = compiled.as_text()
+        assert "paged_decode_attention_pool" in text
+    else:
+        rows, t = (1, 2048) if program == "prefill-1x2048" else (4, 512)
+
+        def chunk(dtype):
+            return _shape(one_chip, (rows, t), dtype)
+
+        compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+            params, cache, chunk(jnp.int32), chunk(jnp.int32),
+            _shape(one_chip, (rows, width), jnp.int32),
+            vec(rows, jnp.int32), chunk(jnp.bool_), vec(rows, jnp.int32),
+            vec(rows, jnp.float32), vec(rows, jnp.float32),
+            vec(rows, jnp.int32), vec(rows, jnp.uint32),
+            vec(rows, jnp.int32)).compile()
+        text = compiled.as_text()
+        # the kernels' names, not their stem: the text's table of source
+        # files may hold tests/test_paged_prefill_attention.py
+        assert "paged_prefill_attention_pool" not in text
+        assert "paged_prefill_attention_window" not in text
+    memory = compiled.memory_analysis()
+    assert "tpu_custom_call" in text and "tied_head" in text
+    # nothing the size of the tied matrix or a layer's pool is copied
+    assert _copies(text, tied_bytes // 2) == []
+    assert not re.search(r"bf16\[2048,65536\]", text)
+    assert 12.70e9 < memory.argument_size_in_bytes < 12.72e9
+    # 0.23 GB the fused block, 0.32 GB at [1, 2048], 0.88 GB at [4, 512]
+    # (the experts' float32 rows and the blocked scores)
+    assert memory.temp_size_in_bytes < (0.3e9 if program == "decode-block"
+                                        else 1.0e9)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
