@@ -66,6 +66,26 @@ def _error_body(status: int, message: str, err_type: str = "invalid_request_erro
     return {"error": {"message": message, "type": err_type, "code": status}}
 
 
+_SSE_DONE = "data: [DONE]\n\n"
+
+
+def _sse(payload: dict, event: str = "") -> str:
+    """One server-sent event: the payload's JSON behind its optional name."""
+    head = f"event: {event}\n" if event else ""
+    return f"{head}data: {json.dumps(payload)}\n\n"
+
+
+async def _write_events(response: web.StreamResponse,
+                        events: list[str]) -> None:
+    """What one frame gave, in one write: the k events of a k-token frame
+    leave as one send, and a client reads the bytes k writes gave it."""
+    if not events:
+        return
+    rt_metrics.SSE_CHUNKS.inc(len(events))
+    rt_metrics.SSE_WRITES.inc()
+    await response.write("".join(events).encode())
+
+
 def _trace_id_of(preprocessed: PreprocessedRequest) -> str:
     """Trace id carried on the request (empty when tracing is off) — the
     exemplar that links a latency observation back to its trace."""
@@ -592,6 +612,8 @@ class HttpService:
         emit is a queue put)."""
         labels = dict(namespace="http", component="frontend", endpoint=model)
         rt_metrics.REQUESTS_TOTAL.labels(status=status, **labels).inc()
+        if delta_gen is not None:
+            delta_gen.count_detok()
         if start is not None:
             rt_metrics.REQUEST_DURATION.labels(**labels).observe(
                 max(0.0, time.monotonic() - start))
@@ -737,43 +759,42 @@ class HttpService:
         try:
             async for output in self._generate(entry, preprocessed):
                 obs.on_output(output)
-                for chunk in delta_gen.on_output(output):
-                    await response.write(
-                        f"data: {json.dumps(chunk)}\n\n".encode())
+                await _write_events(response, [
+                    _sse(chunk) for chunk in delta_gen.on_output(output)])
                 if delta_gen.finish_reason is not None:
                     break
+            tail = []
             if include_usage:
-                usage_chunk = {"id": delta_gen.chunk_id,
-                               "object": "chat.completion.chunk" if delta_gen.kind == "chat" else "text_completion",
-                               "created": delta_gen.created, "model": model,
-                               "choices": [], "usage": delta_gen.usage()}
-                await response.write(f"data: {json.dumps(usage_chunk)}\n\n".encode())
-            await response.write(b"data: [DONE]\n\n")
+                tail.append(_sse({
+                    "id": delta_gen.chunk_id,
+                    "object": ("chat.completion.chunk"
+                               if delta_gen.kind == "chat"
+                               else "text_completion"),
+                    "created": delta_gen.created, "model": model,
+                    "choices": [], "usage": delta_gen.usage()}))
+            await _write_events(response, tail + [_SSE_DONE])
         except NoInstancesAvailable:
-            await response.write(
-                f"data: {json.dumps(_error_body(503, 'no workers available'))}\n\n".encode())
-            await response.write(b"data: [DONE]\n\n")
+            await _write_events(response, [
+                _sse(_error_body(503, 'no workers available')), _SSE_DONE])
         except AdmissionRefused as exc:
             # Mid-pipeline refusal after the stream headers went out:
             # surface in-band like every other post-prepare failure.
             get_recorder().finish(preprocessed.request_id, "shed")
-            await response.write(
-                f"data: {json.dumps(_error_body(503, str(exc), 'overloaded'))}\n\n".encode())
-            await response.write(b"data: [DONE]\n\n")
+            await _write_events(response, [
+                _sse(_error_body(503, str(exc), 'overloaded')), _SSE_DONE])
         except DeadlineExceeded as exc:
             rt_metrics.DEADLINE_EXCEEDED.labels(component="frontend").inc()
             get_recorder().finish(preprocessed.request_id,
                                   "deadline_exceeded")
-            await response.write(
-                f"data: {json.dumps(_error_body(504, str(exc), 'deadline_exceeded'))}\n\n".encode())
-            await response.write(b"data: [DONE]\n\n")
+            await _write_events(response, [
+                _sse(_error_body(504, str(exc), 'deadline_exceeded')),
+                _SSE_DONE])
         except RemoteError as exc:
             # Emit an OpenAI-shaped error event then terminate the stream
             # cleanly so SDK clients see a parseable failure, not a dropped
             # chunked read.
-            await response.write(
-                f"data: {json.dumps(_error_body(502, str(exc), 'engine_error'))}\n\n".encode())
-            await response.write(b"data: [DONE]\n\n")
+            await _write_events(response, [
+                _sse(_error_body(502, str(exc), 'engine_error')), _SSE_DONE])
         except (ConnectionResetError, asyncio.CancelledError):
             # Client went away: stop generating (cancellation propagates to
             # the worker through the request plane). Normal teardown — the
@@ -1163,22 +1184,21 @@ class HttpService:
         )
         await response.prepare(request)
 
-        async def emit(event: str, payload: dict) -> None:
-            await response.write(
-                f"event: {event}\ndata: {json.dumps(payload)}\n\n".encode())
+        async def emit(*events: tuple[str, dict]) -> None:
+            await _write_events(
+                response, [_sse(payload, name) for name, payload in events])
 
-        await emit("message_start", {
+        await emit(("message_start", {
             "type": "message_start",
             "message": {"id": msg_id, "type": "message", "role": "assistant",
                         "model": preprocessed.model, "content": [],
                         "stop_reason": None, "stop_sequence": None,
                         "usage": {"input_tokens": len(preprocessed.token_ids),
                                   "output_tokens": 0}},
-        })
-        await emit("content_block_start", {
+        }), ("content_block_start", {
             "type": "content_block_start", "index": 0,
             "content_block": {"type": "text", "text": ""},
-        })
+        }))
         start = time.monotonic()
         obs = _SloObserver(preprocessed, self.slo_ttft_ms, self.slo_itl_ms,
                            wait_estimator=entry.wait_estimator)
@@ -1189,39 +1209,40 @@ class HttpService:
                 obs.on_output(output)
                 if output.error:
                     errored = True
-                    await emit("error", {"type": "error",
-                                         "error": {"type": "api_error",
-                                                   "message": output.error}})
+                    await emit(("error", {"type": "error",
+                                          "error": {"type": "api_error",
+                                                    "message": output.error}}))
                     break
-                for chunk in delta_gen.on_output(output):
-                    text = chunk["choices"][0]["delta"].get("content")
-                    if text:
-                        await emit("content_block_delta", {
-                            "type": "content_block_delta", "index": 0,
-                            "delta": {"type": "text_delta", "text": text},
-                        })
+                texts = [chunk["choices"][0]["delta"].get("content")
+                         for chunk in delta_gen.on_output(output)]
+                await emit(*(("content_block_delta", {
+                    "type": "content_block_delta", "index": 0,
+                    "delta": {"type": "text_delta", "text": text},
+                }) for text in texts if text))
                 if delta_gen.finish_reason is not None:
                     break
             if not errored:
                 stop_reason, stop_sequence = self._anthropic_stop(delta_gen)
-                await emit("content_block_stop",
-                           {"type": "content_block_stop", "index": 0})
-                await emit("message_delta", {
-                    "type": "message_delta",
-                    "delta": {"stop_reason": stop_reason,
-                              "stop_sequence": stop_sequence},
-                    "usage": {"output_tokens": delta_gen.completion_tokens},
-                })
-                await emit("message_stop", {"type": "message_stop"})
+                await emit(
+                    ("content_block_stop",
+                     {"type": "content_block_stop", "index": 0}),
+                    ("message_delta", {
+                        "type": "message_delta",
+                        "delta": {"stop_reason": stop_reason,
+                                  "stop_sequence": stop_sequence},
+                        "usage": {"output_tokens":
+                                  delta_gen.completion_tokens},
+                    }),
+                    ("message_stop", {"type": "message_stop"}))
         except (NoInstancesAvailable, AdmissionRefused, RemoteError) as exc:
             errored = True
             if isinstance(exc, AdmissionRefused):
                 # Deliberate early shed, not a failure: keep its
                 # timeline out of the error auto-dump storm.
                 get_recorder().finish(preprocessed.request_id, "shed")
-            await emit("error", {"type": "error",
-                                 "error": {"type": "api_error",
-                                           "message": str(exc)}})
+            await emit(("error", {"type": "error",
+                                  "error": {"type": "api_error",
+                                            "message": str(exc)}}))
         except DeadlineExceeded as exc:
             # Same classification as the chat stream: counted, recorded
             # as deadline_exceeded (not a bare error), surfaced as a
@@ -1230,9 +1251,9 @@ class HttpService:
             rt_metrics.DEADLINE_EXCEEDED.labels(component="frontend").inc()
             get_recorder().finish(preprocessed.request_id,
                                   "deadline_exceeded")
-            await emit("error", {"type": "error",
-                                 "error": {"type": "timeout_error",
-                                           "message": str(exc)}})
+            await emit(("error", {"type": "error",
+                                  "error": {"type": "timeout_error",
+                                            "message": str(exc)}}))
         except (ConnectionResetError, asyncio.CancelledError):
             # Client went away: normal teardown, excluded from goodput.
             get_recorder().finish(preprocessed.request_id, "cancelled")
@@ -1395,15 +1416,15 @@ class HttpService:
         )
         await response.prepare(request)
 
-        async def emit(event: str, payload: dict) -> None:
-            await response.write(
-                f"event: {event}\ndata: {json.dumps(payload)}\n\n".encode())
+        async def emit(*events: tuple[str, dict]) -> None:
+            await _write_events(
+                response, [_sse(payload, name) for name, payload in events])
 
-        await emit("response.created", {
+        await emit(("response.created", {
             "type": "response.created",
             "response": self._responses_body(resp_id, preprocessed.model,
                                              delta_gen, "in_progress"),
-        })
+        }))
         start = time.monotonic()
         obs = _SloObserver(preprocessed, self.slo_ttft_ms, self.slo_itl_ms,
                            wait_estimator=entry.wait_estimator)
@@ -1414,44 +1435,42 @@ class HttpService:
                 obs.on_output(output)
                 if output.error:
                     errored = True
-                    await emit("error", {"type": "error",
-                                         "message": output.error})
+                    await emit(("error", {"type": "error",
+                                          "message": output.error}))
                     break
-                for chunk in delta_gen.on_output(output):
-                    text = chunk["choices"][0]["delta"].get("content")
-                    if text:
-                        await emit("response.output_text.delta", {
-                            "type": "response.output_text.delta",
-                            "delta": text,
-                        })
+                texts = [chunk["choices"][0]["delta"].get("content")
+                         for chunk in delta_gen.on_output(output)]
+                await emit(*(("response.output_text.delta", {
+                    "type": "response.output_text.delta",
+                    "delta": text,
+                }) for text in texts if text))
                 if delta_gen.finish_reason is not None:
                     break
             if not errored:
-                await emit("response.output_text.done", {
+                await emit(("response.output_text.done", {
                     "type": "response.output_text.done",
                     "text": delta_gen.full_text,
-                })
-                await emit("response.completed", {
+                }), ("response.completed", {
                     "type": "response.completed",
                     "response": self._responses_body(
                         resp_id, preprocessed.model, delta_gen, "completed"),
-                })
+                }))
         except (NoInstancesAvailable, AdmissionRefused, RemoteError) as exc:
             errored = True
             if isinstance(exc, AdmissionRefused):
                 # Deliberate early shed, not a failure: keep its
                 # timeline out of the error auto-dump storm.
                 get_recorder().finish(preprocessed.request_id, "shed")
-            await emit("error", {"type": "error", "message": str(exc)})
+            await emit(("error", {"type": "error", "message": str(exc)}))
         except DeadlineExceeded as exc:
             # Same classification as the chat stream (see _stream_response).
             errored = True
             rt_metrics.DEADLINE_EXCEEDED.labels(component="frontend").inc()
             get_recorder().finish(preprocessed.request_id,
                                   "deadline_exceeded")
-            await emit("error", {"type": "error",
-                                 "message": str(exc),
-                                 "code": "deadline_exceeded"})
+            await emit(("error", {"type": "error",
+                                  "message": str(exc),
+                                  "code": "deadline_exceeded"}))
         except (ConnectionResetError, asyncio.CancelledError):
             # Client went away: normal teardown, excluded from goodput.
             get_recorder().finish(preprocessed.request_id, "cancelled")

@@ -202,6 +202,7 @@ class KServeGrpcService:
             # Aborts, client cancellation, and engine exceptions all pass
             # here: the span must never leak open (first end() wins).
             span.end(ok=False)
+            delta_gen.count_detok()
             get_recorder().finish(preprocessed.request_id, status)
 
     async def _model_stream_infer(
@@ -247,6 +248,7 @@ class KServeGrpcService:
             finally:
                 # Stream torn down mid-request (client cancel) included.
                 span.end(ok=False)
+                delta_gen.count_detok()
                 get_recorder().finish(preprocessed.request_id, status)
 
     # -- lifecycle ---------------------------------------------------------

@@ -15,6 +15,7 @@ from typing import Any, AsyncIterator, Optional
 
 import jinja2
 
+from ..runtime import metrics as rt_metrics
 from .model_card import ModelDeploymentCard
 from .protocols import (
     EngineOutput,
@@ -454,51 +455,49 @@ class DeltaGenerator:
         the stop-string filter on its own, with its logprob entry, and
         the finish rides the last."""
         ids = output.token_ids
+        lps, tops = output.logprobs, output.top_logprobs or None
         if len(ids) <= 1 or output.error:
-            return self._on_token(output)
+            return self._on_token(ids, output.finish_reason, lps, tops,
+                                  output.error)
         chunks: list[dict] = []
-        for j, token in enumerate(ids):
-            first, last = j == 0, j == len(ids) - 1
-            chunks.extend(self._on_token(EngineOutput(
-                token_ids=[token],
-                finish_reason=output.finish_reason if last else None,
-                prompt_tokens=output.prompt_tokens if first else None,
-                logprobs=(None if output.logprobs is None
-                          else [output.logprobs[j]]),
-                top_logprobs=([output.top_logprobs[j]]
-                              if output.top_logprobs else None),
-            )))
+        last = len(ids) - 1
+        for j in range(last + 1):
+            chunks.extend(self._on_token(
+                ids[j:j + 1], output.finish_reason if j == last else None,
+                None if lps is None else lps[j:j + 1],
+                tops and tops[j:j + 1]))
         return chunks
 
-    def _on_token(self, output: EngineOutput) -> list[dict]:
+    def _on_token(self, ids: list[int], finish_reason: Optional[str],
+                  logprobs=None, top_logprobs=None,
+                  error: Optional[str] = None) -> list[dict]:
         if self._stopped:
             return []
         chunks: list[dict] = []
-        if output.error:
+        if error:
             self.finish_reason = "error"
             self._stopped = True
             return [self._chunk({}, "error")]
-        self.completion_tokens += len(output.token_ids)
-        final = output.finish_reason is not None
-        ids = output.token_ids
-        trimmed_eos = (output.finish_reason == "stop" and ids
+        self.completion_tokens += len(ids)
+        final = finish_reason is not None
+        trimmed_eos = (finish_reason == "stop" and ids
                        and (ids[-1] in self.request.eos_token_ids
                             or ids[-1] in self.request.stop.stop_token_ids))
-        if trimmed_eos:
-            # the terminating eos/stop TOKEN is not content (HF
-            # tokenizers render it as "" via skip_special_tokens, but
-            # e.g. the byte tokenizer names its specials)
-            ids = ids[:-1]
         new_lp_entries: list[dict] = []
-        if output.logprobs is not None:
+        if logprobs is not None:
             before = len(self.logprob_entries)
-            self._collect_logprobs(output)
+            self._collect_logprobs(ids, logprobs, top_logprobs)
             new_lp_entries = self.logprob_entries[before:]
             if trimmed_eos and new_lp_entries:
                 # keep logprob entries 1:1 with CONTENT tokens (OpenAI
                 # emits no entry for the stop token)
                 new_lp_entries.pop()
                 self.logprob_entries.pop()
+        if trimmed_eos:
+            # the terminating eos/stop TOKEN is not content (HF
+            # tokenizers render it as "" via skip_special_tokens, but
+            # e.g. the byte tokenizer names its specials)
+            ids = ids[:-1]
         text = self.detok.push(ids)
         if final:
             text += self.detok.flush()
@@ -513,7 +512,7 @@ class DeltaGenerator:
             self._stopped = True
             chunks.append(self._chunk({}, self.finish_reason))
         elif final:
-            self.finish_reason = self._final_reason(output.finish_reason)
+            self.finish_reason = self._final_reason(finish_reason)
             self._stopped = True
             chunks.append(self._chunk({}, self.finish_reason))
         if new_lp_entries and chunks:
@@ -527,18 +526,18 @@ class DeltaGenerator:
                     self._completions_lp_block(new_lp_entries)
         return chunks
 
-    def _collect_logprobs(self, output) -> None:
+    def _collect_logprobs(self, ids, logprobs, top_logprobs) -> None:
         decode = self.pre.tokenizer.decode
-        for j, tid in enumerate(output.token_ids):
+        for j, tid in enumerate(ids):
             entry = {
                 "token": decode([tid]),
-                "logprob": float(output.logprobs[j]),
+                "logprob": float(logprobs[j]),
             }
-            if output.top_logprobs:
+            if top_logprobs:
                 entry["top_logprobs"] = [
                     {"token": decode([int(alt_id)]),
                      "logprob": float(alt_lp)}
-                    for alt_id, alt_lp in output.top_logprobs[j]
+                    for alt_id, alt_lp in top_logprobs[j]
                 ]
             self.logprob_entries.append(entry)
 
@@ -561,6 +560,12 @@ class DeltaGenerator:
         if self.kind == "chat":
             return {"content": self.logprob_entries}
         return self._completions_lp_block(self.logprob_entries)
+
+    def count_detok(self) -> None:
+        """Where the request ends: this stream's detokeniser work onto the
+        frontend's counters (ids decoded beside ids pushed)."""
+        rt_metrics.DETOK_TOKENS.inc(self.detok.pushed_tokens)
+        rt_metrics.DETOK_DECODED_TOKENS.inc(self.detok.decoded_tokens)
 
     def usage(self) -> dict:
         return {

@@ -161,25 +161,40 @@ class IncrementalDetokenizer:
     """Streaming decode: emits only text that can no longer change as more
     tokens arrive (ref: Backend detokenizer hot loop, lib/llm/src/backend.rs).
 
-    Per-token cost is O(window): we decode a sliding tail window anchored at
-    `_ctx_start` and diff against the previously decoded length, instead of
-    re-decoding the whole sequence (the reference's Rust hot loop does the
-    same prefix-offset trick). The anchor slides forward periodically so the
-    decoded span stays bounded."""
+    A push decodes a bounded tail, never a span that grows with the
+    answer: the tokens since the anchor `_ctx_start`, diffed against the
+    length already emitted from there (the reference's prefix-offset /
+    read-offset pair). The anchor follows the emissions. Behind a
+    prefix-stable tokenizer (`stable_window == 0`: byte-level) it moves
+    onto every clean end, so a push decodes the pushed ids and whatever
+    partial UTF-8 bytes are held back; otherwise it is re-set
+    `_CTX_KEEP` tokens behind the stable edge once the span passes
+    `_CTX_KEEP + stable_window`, and a decode is handed at most that
+    many ids plus the pushed ones."""
 
     # Keep this many already-stable tokens as decode context when sliding the
     # anchor (BPE/sentencepiece boundary effects cancel within the context).
     _CTX_KEEP = 16
-    # Slide the anchor once the decoded span exceeds this many tokens.
-    _CTX_MAX = 256
 
     def __init__(self, tokenizer: Tokenizer, window: Optional[int] = None) -> None:
         self._tok = tokenizer
         self._ids: list[int] = []
         self._window = tokenizer.stable_window if window is None else window
+        # decode(a + b) == decode(a) + decode(b) wherever decode(a) ends
+        # clean: the tokenizer's own word for it is stable_window == 0
+        self._prefix_stable = tokenizer.stable_window == 0
         self._ctx_start = 0  # decode-anchor token index
         self._stable_tokens = 0  # tokens whose text has been emitted
         self._prev_len = 0  # len(decode(ids[_ctx_start:_stable_tokens])) - held-back "�"
+        self.decoded_tokens = 0  # ids handed to Tokenizer.decode so far
+
+    @property
+    def pushed_tokens(self) -> int:
+        return len(self._ids)
+
+    def _decode(self, start: int, stop: int) -> str:
+        self.decoded_tokens += stop - start
+        return self._tok.decode(self._ids[start:stop])
 
     def push(self, token_ids: Sequence[int]) -> str:
         """Add tokens, return newly-stable text (may be '')."""
@@ -188,25 +203,30 @@ class IncrementalDetokenizer:
         stable = n if self._window == 0 else max(0, n - self._window)
         if stable <= self._stable_tokens:
             return ""
-        text = self._tok.decode(self._ids[self._ctx_start : stable])
-        candidate = text[self._prev_len :]
+        text = self._decode(self._ctx_start, stable)
         # Never emit a trailing replacement char (partial UTF-8 sequence);
         # it re-decodes complete once the rest of the char arrives.
-        while candidate.endswith("�"):
-            candidate = candidate[:-1]
+        candidate = text[self._prev_len :].rstrip("�")
         self._stable_tokens = stable
         self._prev_len += len(candidate)
-        if stable - self._ctx_start > self._CTX_MAX:
-            self._ctx_start = max(0, stable - self._CTX_KEEP)
-            anchored = self._tok.decode(self._ids[self._ctx_start : stable])
-            while anchored.endswith("�"):  # keep held-back partial chars held
-                anchored = anchored[:-1]
-            self._prev_len = len(anchored)
+        if self._prefix_stable and self._prev_len == len(text):
+            # nothing held back: what follows decodes on its own
+            self._ctx_start = stable
+            self._prev_len = 0
+        elif stable - self._ctx_start > self._CTX_KEEP + self._window:
+            # as many chars stay held back behind the new anchor as were
+            # (counted, not stripped: a byte-fallback decoder turns a whole
+            # run of bytes to "�" while its last char is partial, chars
+            # already emitted among them)
+            held = max(0, len(text) - self._prev_len)
+            self._ctx_start = stable - self._CTX_KEEP
+            self._prev_len = max(
+                0, len(self._decode(self._ctx_start, stable)) - held)
         return candidate
 
     def flush(self) -> str:
         """Emit everything outstanding (end of stream)."""
-        full = self._tok.decode(self._ids[self._ctx_start :])
+        full = self._decode(self._ctx_start, len(self._ids))
         out = full[self._prev_len :]
         self._prev_len = len(full)
         self._stable_tokens = len(self._ids)

@@ -70,6 +70,28 @@ OUTPUT_TOKENS = Histogram(
     "dynamo_output_sequence_tokens", "Output sequence length", ["model"],
     registry=REGISTRY, buckets=(1, 16, 64, 128, 256, 512, 1024, 2048, 4096),
 )
+# A frame's egress (llm/tokenizer.py IncrementalDetokenizer,
+# llm/http_service.py _write_events). decoded / tokens = ids re-decoded for a
+# token emitted (about 1 for a byte tokenizer, under 24 behind a merging
+# one); chunks / writes = SSE events a socket write (the tokens a frame).
+# Both detokeniser counters grow where a request ends, by that request.
+DETOK_TOKENS = Counter(
+    "dynamo_frontend_detok_tokens_total",
+    "Token ids pushed through the incremental detokeniser", registry=REGISTRY,
+)
+DETOK_DECODED_TOKENS = Counter(
+    "dynamo_frontend_detok_decoded_tokens_total",
+    "Token ids handed to Tokenizer.decode by the incremental detokeniser",
+    registry=REGISTRY,
+)
+SSE_CHUNKS = Counter(
+    "dynamo_frontend_sse_chunks_total",
+    "SSE events written to streaming responses", registry=REGISTRY,
+)
+SSE_WRITES = Counter(
+    "dynamo_frontend_sse_writes_total",
+    "Writes that carried those events to the transport", registry=REGISTRY,
+)
 KV_USAGE = Gauge(
     "dynamo_kv_usage_ratio", "Paged-KV pool usage fraction", ["worker"],
     registry=REGISTRY,

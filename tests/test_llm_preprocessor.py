@@ -53,6 +53,187 @@ class TestIncrementalDetokenizer:
             assert "�" not in chunk
 
 
+class _ParentDetokenizer:
+    """The incremental detokeniser as it stood before PR 45, kept as the
+    oracle: it re-decodes `ids[_ctx_start:stable]` on every push and
+    slides the anchor only once the span passes 256 tokens. One line is
+    not its own: `sends_twice`, set where a slide strips more "\ufffd"
+    than were held back. A byte-fallback decoder turns a whole run of
+    byte tokens to "\ufffd" while the run's last character is partial,
+    characters already emitted among them; stripping those forgets that
+    they went out, and the next piece repeats them."""
+
+    _CTX_KEEP = 16
+    _CTX_MAX = 256
+
+    def __init__(self, tokenizer, window=None):
+        self._tok = tokenizer
+        self._ids = []
+        self._window = tokenizer.stable_window if window is None else window
+        self._ctx_start = 0
+        self._stable_tokens = 0
+        self._prev_len = 0
+        self.sends_twice = False
+
+    def push(self, token_ids):
+        self._ids.extend(token_ids)
+        n = len(self._ids)
+        stable = n if self._window == 0 else max(0, n - self._window)
+        if stable <= self._stable_tokens:
+            return ""
+        text = self._tok.decode(self._ids[self._ctx_start:stable])
+        candidate = text[self._prev_len:]
+        while candidate.endswith("\ufffd"):
+            candidate = candidate[:-1]
+        self._stable_tokens = stable
+        self._prev_len += len(candidate)
+        if stable - self._ctx_start > self._CTX_MAX:
+            held = len(text) - self._prev_len
+            self._ctx_start = max(0, stable - self._CTX_KEEP)
+            anchored = self._tok.decode(self._ids[self._ctx_start:stable])
+            full = len(anchored)
+            while anchored.endswith("\ufffd"):
+                anchored = anchored[:-1]
+            self._prev_len = len(anchored)
+            self.sends_twice = full - len(anchored) > held
+        return candidate
+
+    def flush(self):
+        full = self._tok.decode(self._ids[self._ctx_start:])
+        out = full[self._prev_len:]
+        self._prev_len = len(full)
+        self._stable_tokens = len(self._ids)
+        return out
+
+
+_WORDS = ("the quick brown fox jumps over the lazy dog hello world again "
+          "and streaming tokens one by one is thing na\u00efve caf\u00e9 "
+          "w\u00f6rld \u4f60\u597d \u65e5\u672c\u8a9e \U0001f642 "
+          "\u00e9t\u00e9 a b c , . !").split()
+
+
+@pytest.fixture(scope="module")
+def merge_and_space_tokenizer(tmp_path_factory):
+    """A small sentencepiece-style BPE built here (no download): learned
+    merges, `\u2581` leading-space pieces of which decode strips the
+    first, byte fallback for what the vocabulary lacks (so a character's
+    UTF-8 bytes arrive as several tokens), specials that decode to
+    nothing. Loaded through HfTokenizer, as a deployment's is."""
+    import json
+
+    from tokenizers import (Tokenizer, decoders, models, normalizers,
+                            pre_tokenizers, trainers)
+
+    from dynamo_tpu.llm.tokenizer import HfTokenizer
+
+    norm = normalizers.Sequence([normalizers.Prepend("\u2581"),
+                                 normalizers.Replace(" ", "\u2581")])
+    split = pre_tokenizers.Split("\u2581", "merged_with_next")
+    seed = Tokenizer(models.BPE(unk_token="<unk>"))
+    seed.normalizer, seed.pre_tokenizer = norm, split
+    corpus = [" ".join(w for w in _WORDS if w.isascii())] * 20
+    seed.train_from_iterator(corpus, trainers.BpeTrainer(
+        vocab_size=120, special_tokens=["<unk>", "<s>", "</s>"],
+        show_progress=False))
+    model = json.loads(seed.to_str())["model"]
+    vocab = dict(model["vocab"])
+    for byte in range(256):
+        vocab[f"<0x{byte:02X}>"] = len(vocab)
+    merges = [tuple(m) if isinstance(m, list) else tuple(m.split(" "))
+              for m in model["merges"]]
+    tok = Tokenizer(models.BPE(vocab, merges, unk_token="<unk>",
+                               byte_fallback=True))
+    tok.normalizer, tok.pre_tokenizer = norm, split
+    tok.decoder = decoders.Sequence([
+        decoders.Replace("\u2581", " "), decoders.ByteFallback(),
+        decoders.Fuse(), decoders.Strip(" ", 1, 0)])
+    tok.add_special_tokens(["<unk>", "<s>", "</s>"])
+    path = tmp_path_factory.mktemp("bpe") / "tokenizer.json"
+    tok.save(str(path))
+    return HfTokenizer(str(path))
+
+
+def _id_sequence(kind, tok, length, seed):
+    """`length` ids, seeded: running text whose multi-byte characters
+    come as one token a byte, specials in between, and stray bytes that
+    are no UTF-8 at all (runs of one to three)."""
+    import random
+
+    rng = random.Random(f"{kind}/{length}/{seed}")
+    if kind == "byte":
+        specials = [ByteTokenizer.BOS, ByteTokenizer.EOS,
+                    ByteTokenizer.IM_START, ByteTokenizer.IM_END, 300, 511]
+        stray = list(range(0x80, 0x100))
+    else:
+        vocab = tok._tok.get_vocab()
+        specials = [vocab["<s>"], vocab["</s>"], vocab["<unk>"]]
+        # the library's byte-fallback decoder gives up on a whole run of
+        # byte tokens where any of them is no UTF-8, back to characters it
+        # had decoded: a stray run stands between two words here and holds
+        # no byte that could start a character
+        stray = [vocab[f"<0x{b:02X}>"]
+                 for b in (*range(0x80, 0xC0), *range(0xF8, 0x100))]
+        fence = tok.encode("the")
+    ids = []
+    while len(ids) < length:
+        roll = rng.random()
+        if roll < 0.08:
+            ids.append(rng.choice(specials))
+        elif roll < 0.12:
+            run = [rng.choice(stray) for _ in range(rng.randint(1, 3))]
+            ids.extend(run if kind == "byte" else fence + run + fence)
+        else:
+            ids.extend(tok.encode(" ".join(
+                rng.choice(_WORDS) for _ in range(rng.randint(1, 6)))))
+    return ids[:length]
+
+
+class TestDetokenizerDecodesWhatIsNew:
+    """PR 45: a push decodes a bounded tail, and what it emits is what
+    the detokeniser before it emitted, piece for piece; but for the
+    characters that one sent twice (`_ParentDetokenizer.sends_twice`:
+    the anchor now moves every few tokens, so the count of what is held
+    back is carried over it, where stripping went wrong once in some
+    thousand tokens of such text)."""
+
+    @pytest.mark.parametrize("piece", [1, 8, 11])
+    @pytest.mark.parametrize("length", [1, 5, 40, 700, 3000])
+    @pytest.mark.parametrize("kind", ["byte", "bpe"])
+    def test_pieces_equal_the_parents_and_a_push_decodes_a_bounded_tail(
+            self, kind, length, piece, merge_and_space_tokenizer):
+        tok = ByteTokenizer() if kind == "byte" else merge_and_space_tokenizer
+        assert tok.stable_window == (0 if kind == "byte" else 4)
+        for seed in range(3):
+            ids = _id_sequence(kind, tok, length, seed)
+            detok, oracle = IncrementalDetokenizer(tok), _ParentDetokenizer(tok)
+            # a push: at most the context, the window and the pushed ids
+            # in its decode, and the context once more where the anchor
+            # is re-set; never a span that grows with the answer
+            bound = 2 * detok._CTX_KEEP + tok.stable_window + piece
+            pieces, repeated = [], 0
+            for at in range(0, len(ids), piece):
+                before = detok.decoded_tokens
+                pieces.append(detok.push(ids[at:at + piece]))
+                parents = oracle.push(ids[at:at + piece])
+                if oracle.sends_twice and parents != pieces[-1]:
+                    assert parents.endswith(pieces[-1]), at
+                    repeated += len(parents) - len(pieces[-1])
+                    oracle.sends_twice = False
+                else:
+                    assert pieces[-1] == parents, at
+                assert detok.decoded_tokens - before <= bound, at
+            pieces.append(detok.flush())
+            assert pieces[-1] == oracle.flush()
+            assert repeated <= 3  # characters, in 3,000 tokens
+            assert "".join(pieces) == tok.decode(ids)
+            assert detok.pushed_tokens == len(ids)
+            if kind == "byte" and length >= 700:
+                # prefix-stable: the pushed ids and the bytes held back
+                assert detok.decoded_tokens < 2 * len(ids)
+            elif length >= 700:
+                assert detok.decoded_tokens < 24 * len(ids)
+
+
 class TestPreprocessor:
     def test_chat_template_applied(self):
         pre = OpenAIPreprocessor(_card())
