@@ -1572,7 +1572,13 @@ class ModelRunner:
         XLA form also held float32 [positions, heads, chunk] products,
         134 MB each at 2,048 positions x 128 heads; on the chip the
         scan is a kernel since PR 43 and holds none, and this bound
-        stays for the grid's sake: ROADMAP A7)."""
+        stays for the grid's sake: ROADMAP A7). What a launch holds in
+        temporaries now is the routed experts' float32 rows, twice: as
+        the down-projection wrote them and gathered slots-major for the
+        sum, [positions x k, hidden] each (`ops/grouped_matmul.
+        dropless_experts`: 0.86-0.91 GB at 2,048 positions x top-10 of
+        hidden 4096, 1.06-1.11 before PR 46). They grow with a launch's
+        positions, so a lifted bound is paid in them too."""
         return (self._windowed or self._latent
                 or (self.model_config.has_recurrent_state
                     and self.config.max_context

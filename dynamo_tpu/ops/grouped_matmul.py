@@ -82,8 +82,21 @@ def dropless_experts(x, weights, topi, valid, w_up, w_down, held, act,
                      *, path: str):
     """x [T, h]; weights, topi [T, k] (global expert ids); valid [T] bool
     (padding and empty rows route nowhere); w_up [E_held, m, h] (stored
-    output-major: `expert_gmm`), w_down [E_held, m, h]; held = (lo, hi). Returns (out [T, h] in x's dtype,
-    tokens per held expert [E_held] int32, dropped int32)."""
+    output-major: `expert_gmm`), w_down [E_held, m, h]; held = (lo, hi).
+    Returns (out [T, h] in x's dtype, tokens per held expert [E_held]
+    int32, dropped int32).
+
+    The combine un-sorts the down-projection's float32 rows with ONE
+    gather whose slots lie on the major axis, [k, T, h], and masks,
+    weighs and sums them in one fusion over that axis: two passes over
+    the rows, 2 x T*k*h*4 bytes read and one written (1.02 GB a layer at
+    granite's [2048, 4096] top-10). Gathered token-major, [T, k, h], the
+    TPU tiles (k, h) as (8, 128): k = 10 pads to 16 sublanes (4 and 6
+    likewise) and XLA relays the whole array out to get there, and a
+    mask over the sorted rows is a pass of its own: 2.77 GB a layer
+    (PERF.md, PR 46). Rows past the groups are undefined (`expert_gmm`)
+    and may hold NaN, so they are dropped by a select on the gathered
+    row's index, never by a zero weight."""
     t, h = x.shape
     k = topi.shape[1]
     lo, hi = held
@@ -102,12 +115,12 @@ def dropless_experts(x, weights, topi, valid, w_up, w_down, held, act,
     with jax.named_scope("expert_gmm"):
         up = expert_gmm(rows, w_up, counts, path=path, transpose_rhs=True)
         mid = act(up).astype(x.dtype)
-        down = expert_gmm(mid, w_down, counts, path=path)[: t * k]
+        down = expert_gmm(mid, w_down, counts, path=path)
     n_rows = jnp.sum(counts)
-    computed = jnp.arange(t * k) < n_rows
-    down = jnp.where(computed[:, None], down, 0.0)
     inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(
         jnp.arange(t * k, dtype=jnp.int32))
-    per_slot = down[inverse].reshape(t, k, h)
-    out = jnp.sum(per_slot * weights[:, :, None].astype(jnp.float32), axis=1)
+    row_of = inverse.reshape(t, k).T  # [k, T], every one under T*k
+    weighed = down[row_of] * weights.T[:, :, None].astype(jnp.float32)
+    out = jnp.sum(jnp.where((row_of < n_rows)[:, :, None], weighed, 0.0),
+                  axis=0)
     return out.astype(x.dtype), counts, dropped_slots(here, inverse, n_rows)

@@ -242,6 +242,85 @@ def _copies(text, at_least, ops=("copy", "copy-start"), scope=None):
     return found
 
 
+def _unfused(text):
+    """An optimised HLO text less its fused computations: the entry and
+    the loop bodies a scan leaves, whose every result is an array in
+    memory (inside a fusion a reshape or a transpose is free)."""
+    kept, fused = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            fused = "fused_computation" in line.split("(", 1)[0]
+        if not fused:
+            kept.append(line)
+    return "\n".join(kept)
+
+
+def _assert_the_experts_combine_is_two_passes(text, t, k, h, layers):
+    """Under the `moe_experts` scope of a compiled program, a layer
+    makes ONE more array the size of the down-projection's float32 rows
+    [t*k, h]: their gather, slots major (a fusion). No `[t, k, h]`
+    relaid, no pass that masks the sorted rows (`broadcast_select_
+    fusion`), nothing that size or larger copied, reshaped or
+    transposed: `ops/grouped_matmul.dropless_experts`."""
+    program = _unfused(text)
+    assert _copies(program, t * k * h * 4, scope="moe_experts",
+                   ops=("copy", "copy-start", "reshape", "transpose")) == []
+    assert not re.search(rf"f32\[{t},{k},{h}\]", program)
+    made = []
+    for line in program.splitlines():
+        found = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+?)[.\d]* = (.*?) ([\w\-]+)\(", line)
+        if not found or "moe_experts" not in line:
+            continue
+        name, result, op = found.groups()
+        if op != "bitcast" and any(
+                math.prod(int(d) for d in dims.split(",")) == t * k * h
+                for dims in re.findall(r"\bf32\[([\d,]+)\]", result)):
+            made.append((name, op))
+    # the grouped matmul's rows are padded to a tile of 128: another size
+    gmm = [("gmm", "custom-call")] * (layers if t * k % 128 == 0 else 0)
+    assert sorted(made) == sorted(gmm + [("fusion", "fusion")] * layers)
+
+
+# (tokens, hidden, k, held experts, expert width, SwiGLU): a launch of
+# the lfm2, nemotron and granite cells. 4, 6 and 10 slots are no whole
+# tile of 8 sublanes; 8 (mellum, pangu) is, and compiles to the same two
+# passes inside `test_pangus_step_programs_fit_the_chip_and_copy_no_pool`.
+COMBINE_CASES = {
+    "k4-lfm2": (2048, 2048, 4, 32, 1792, True),
+    "k6-nemotron": (4096, 2688, 6, 64, 1856, False),
+    "k10-granite": (2048, 4096, 10, 36, 768, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COMBINE_CASES))
+def test_the_experts_combine_is_one_gather_and_one_sum(one_chip, case):
+    """`dropless_experts` alone at a cell's launch: behind the second
+    grouped matmul the entry holds the gather of its float32 rows, slots
+    major, and one fusion that masks, weighs and sums them; nowhere a
+    `[T, k, h]` array, whose k slots the TPU would pad to 8 or 16
+    sublanes by copying it whole (k = 10: 1.6 times its size)."""
+    from dynamo_tpu.models.hybrid import _relu2, _swiglu
+    from dynamo_tpu.ops.grouped_matmul import dropless_experts
+
+    t, h, k, held, width, swiglu = COMBINE_CASES[case]
+
+    def experts(x, weights, topi, valid, w_up, w_down):
+        with jax.named_scope("moe_experts"):
+            return dropless_experts(
+                x, weights, topi, valid, w_up, w_down, (0, held),
+                _swiglu if swiglu else _relu2, path="pallas")
+
+    bf16 = jnp.bfloat16
+    text = jax.jit(experts).lower(
+        _shape(one_chip, (t, h), bf16), _shape(one_chip, (t, k), jnp.float32),
+        _shape(one_chip, (t, k), jnp.int32), _shape(one_chip, (t,), jnp.bool_),
+        _shape(one_chip, (held, width * (2 if swiglu else 1), h), bf16),
+        _shape(one_chip, (held, width, h), bf16)).compile().as_text()
+    _assert_the_experts_combine_is_two_passes(text, t, k, h, 1)
+    assert re.search(rf"f32\[{k},{t},{h}\]\S* bitcast\(", text)
+
+
 @pytest.mark.parametrize("pool", ["int8", "bf16"])
 @pytest.mark.parametrize("block", [8, 1])
 def test_the_dense_decode_programs_copy_no_pool(one_chip, monkeypatch,
@@ -439,6 +518,8 @@ def test_pangus_step_programs_fit_the_chip_and_copy_no_pool(one_chip,
     # a latent layer's prefill is its own: `attention_fn` never sees it
     assert "paged_prefill_attention_pool" not in text
     assert _copies(text, pool_bytes // 5) == []  # not even one layer's
+    _assert_the_experts_combine_is_two_passes(
+        text, n if program == "decode-block" else 2048, 8, 7680, 4)
     assert memory.temp_size_in_bytes < (1.0e9 if program == "decode-block"
                                         else 2.0e9)
     assert memory.argument_size_in_bytes < 12.4e9
@@ -908,11 +989,11 @@ def _granite_programs(one_chip):
 
 
 def _relaid_in_mixers(text, at_least):
-    """Results of `at_least` bytes or more that the entry computation
-    copies, reshapes or transposes inside a Mamba mixer's scope: what a
-    relaid `x`, `y` or state would be (inside a fusion a reshape is free)."""
-    entry = text[text.index("\nENTRY"):]
-    return _copies(entry[:entry.index("\n}")], at_least,
+    """Results of `at_least` bytes or more that a program copies,
+    reshapes or transposes, outside fusions, inside a Mamba mixer's
+    scope: what a relaid `x`, `y` or state would be (inside a fusion a
+    reshape is free)."""
+    return _copies(_unfused(text), at_least,
                    ops=("copy", "copy-start", "reshape", "transpose"),
                    scope="mamba_mixer")
 
@@ -936,8 +1017,11 @@ def test_granites_step_programs_fit_the_chip_and_copy_nothing_large(
     reach and leave the kernel as the projection, the conv and the cache
     hold them (the XLA form relaid `x` to heads-before-positions and `y`
     back, 33.6 and 67.1 MB each, several times a mixer: PERF.md, PR 43).
-    A launch's temporaries are now the experts' float32 rows
-    ([2048 x 10, 4096] a layer), not the scan's."""
+    A launch's temporaries are the experts' float32 rows twice, as the
+    down-projection wrote them and gathered slots-major ([10 x 2048,
+    4096], 335.5 MB each): 0.86 GB, where a `[2048, 10, 4096]` relaid to
+    16 sublanes and a masked copy of the sorted rows held 1.06
+    (PERF.md, PR 46)."""
     import functools
 
     from dynamo_tpu.engine.sampler import sample, sample_with_logprobs
@@ -1020,6 +1104,8 @@ def test_granites_step_programs_fit_the_chip_and_copy_nothing_large(
         assert len(re.findall(r" custom-call\(.*ssm_chunk_scan", text)) == 9
         assert "ssm_state_update" not in text
         assert _relaid_in_mixers(text, 32 << 20) == []
+    _assert_the_experts_combine_is_two_passes(
+        text, n if program == "decode-block" else rows * t, 10, 4096, 10)
     memory = compiled.memory_analysis()
     assert "tpu_custom_call" in text and "tied_head" in text
     # nothing the size of the tied matrix, a layer's state or the pool
@@ -1027,10 +1113,11 @@ def test_granites_step_programs_fit_the_chip_and_copy_nothing_large(
     # the embedding would be a bf16[4096,50176] result)
     assert _copies(text, min(tied_bytes, n * 128 * 64 * 128 * 4) // 2) == []
     assert not re.search(r"bf16\[4096,50176\]", text)
-    # 1.06 GB at [1, 2048] and 1.11 at [4, 512] (1.33 and 1.10 with the
-    # XLA form of the scan: what is left is the experts')
+    # 0.864 GB at [1, 2048] and 0.908 at [4, 512] (1.06 and 1.11 with
+    # the combine token-major, 1.33 and 1.10 with the XLA form of the
+    # scan before that: what is left is the experts')
     assert memory.temp_size_in_bytes < (0.3e9 if program == "decode-block"
-                                        else 1.15e9)
+                                        else 0.92e9)
     assert 12.9e9 < memory.argument_size_in_bytes < 13.0e9
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.75 * 2 ** 30)
@@ -1194,6 +1281,8 @@ def test_lfm2s_step_programs_fit_the_chip_and_copy_nothing_large(
         # files may hold tests/test_paged_prefill_attention.py
         assert "paged_prefill_attention_pool" not in text
         assert "paged_prefill_attention_window" not in text
+    _assert_the_experts_combine_is_two_passes(
+        text, n if program == "decode-block" else rows * t, 4, 2048, 10)
     memory = compiled.memory_analysis()
     assert "tpu_custom_call" in text and "tied_head" in text
     # nothing the size of the tied matrix or a layer's pool is copied
