@@ -133,7 +133,7 @@ class IciKvBridge:
             launch the ICI reshard; False -> recompute fallback."""
             page_ids = jnp.asarray(ids, jnp.int32)
             resultq = gap_exec(
-                lambda: gather_kv_blocks(worker.runner.kv_cache, page_ids))
+                lambda: gather_kv_blocks(*worker.runner.cache[0], page_ids))
             try:
                 bundle, exc = await asyncio.to_thread(resultq.get, True, 60.0)
             except Exception as exc_:  # noqa: BLE001 — queue.Empty on timeout

@@ -23,20 +23,16 @@ import os
 import threading
 import time
 from functools import partial
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models import ModelConfig, forward, init_params, make_kv_cache, param_axes
-from ..models.transformer import (
-    KV_SCALE_LANES,
-    forward_decode,
-    forward_ring,
-    write_kv_stack,
-)
+from ..models import ModelConfig, make_kv_cache, make_steps, param_axes
+from ..models.config import cache_plan
+from ..models.transformer import KV_SCALE_LANES, forward_ring, write_kv_stack
 from ..parallel import kv_cache_sharding, param_shardings
 from ..parallel.mesh import AXIS_DP, AXIS_SP, AXIS_TP, Mesh
 from ..runtime.config import env
@@ -322,6 +318,30 @@ class RunnerConfig:
         return self.page_size * self.max_pages_per_seq
 
 
+class PrefillRow(NamedTuple):
+    """One sequence's chunk in a batched prefill launch
+    (`ModelRunner.prefill_chunk_batch`): the same fields for every model,
+    as `prefill_chunk` takes them one by one."""
+
+    tokens: np.ndarray  # [t] chunk token ids
+    start: int  # absolute position of tokens[0]
+    table: np.ndarray  # [max_pages_per_seq] int32: the full group's pages
+    kv_len_after: int
+    sampling: tuple  # (temp, top_p, top_k, seed)
+    lora_idx: int = 0
+    # the scheduler's slot, where per-slot state lives; None: past the
+    # end, its write dropped
+    slot: Optional[int] = None
+    # a second page group's (pages from the row's first held block, that
+    # block's first position); None where the cache has one group
+    window: Optional[tuple] = None
+
+
+# What a warm-up prefill row passes beside its scratch table: its state
+# slot is past the end (the default), its window table empty.
+IDLE_WINDOW = ((), 0)
+
+
 def _enable_compile_cache() -> None:
     cache_dir = env("DYNT_COMPILE_CACHE_DIR")
     try:
@@ -341,36 +361,34 @@ def _pallas_mode(mesh: Mesh) -> Optional[bool]:
     return None if path == "xla" else path == "interpret"
 
 
-def _default_attention_fn(mesh: Mesh):
-    """Prefill/unified attention on a single device:
-    `ops.paged_attention.paged_attention`, the blocked prefill kernel
-    over the paged pool wherever it admits the geometry. A multi-device
-    mesh keeps the XLA path, whose sharding pjit manages: its float32
-    score tensors cost there what they cost the flagship cell before the
-    kernel (PERF.md, PR 39), and a shard_map over kv heads as the decode
-    kernel has is the mend."""
+def _mesh_kernels(mesh: Mesh) -> dict:
+    """What this mesh and backend run in each attention slot of a step
+    program (None: the XLA form). The model's adapter takes the ones its
+    stack has a use for (`models.make_steps`).
+
+    prefill: `ops.paged_attention.paged_attention`, the blocked prefill
+    kernel over the paged pool wherever it admits the geometry. spec:
+    the whole-pool chunked-DMA kernel with the chunk dim folded into the
+    GQA group dim, so one dispatch streams each owned page once for all
+    k+1 candidate positions of a speculative verification. Both on a
+    single device; a multi-device mesh keeps the XLA path, whose
+    sharding pjit manages (its float32 score tensors cost there what
+    they cost the flagship cell before the kernel: PERF.md, PR 39; a
+    shard_map over kv heads as the decode kernel has is the mend)."""
+    from ..ops.paged_attention import (
+        paged_attention,
+        paged_attention_spec_pool,
+    )
+
     interpret = _pallas_mode(mesh)
-    if interpret is None or mesh.devices.size > 1:
-        return None
-    from ..ops.paged_attention import paged_attention
-
-    return partial(paged_attention, interpret=interpret)
-
-
-def _default_spec_attention_fn(mesh: Mesh):
-    """History-attention kernel for speculative batched verification
-    (forward_spec): the whole-pool chunked-DMA kernel with the chunk dim
-    folded into the GQA group dim, so one dispatch streams each owned
-    page once for all k+1 candidate positions. Single-device meshes run
-    the Pallas kernel; multi-device meshes keep the XLA reference path
-    (pjit manages its sharding — speculation still works, the history
-    gather is just not kernel-accelerated there yet)."""
-    interpret = _pallas_mode(mesh)
-    if interpret is None or mesh.devices.size > 1:
-        return None
-    from ..ops.paged_attention import paged_attention_spec_pool
-
-    return partial(paged_attention_spec_pool, interpret=interpret)
+    one_device = interpret is not None and mesh.devices.size == 1
+    return {
+        "prefill": (partial(paged_attention, interpret=interpret)
+                    if one_device else None),
+        "decode": _default_decode_attention_fn(mesh),
+        "decode_latent": _default_decode_attention_fn(mesh, latent=True),
+        "spec": (partial(paged_attention_spec_pool, interpret=interpret)
+                 if one_device else None)}
 
 
 def _default_decode_attention_fn(mesh: Mesh, latent: bool = False):
@@ -430,34 +448,16 @@ class ModelRunner:
         self.model_config = model_config
         self.config = runner_config
         self.mesh = mesh
-        self._attention_user_supplied = attention_fn is not None
-        if attention_fn is None and not model_config.is_gptoss:
-            attention_fn = _default_attention_fn(mesh)
-        self._attention_fn = attention_fn
-        # gpt-oss: sink + sliding-window attention lives in the unified
-        # forward (the Pallas kernels don't model sinks); its forward
-        # branch ignores attention_fn, and fast decode is gated off.
-        self._decode_attention_fn = (
-            None if self._attention_user_supplied or model_config.is_gptoss
-            else _default_decode_attention_fn(
-                mesh, latent=model_config.has_latent_layers))
-        self._spec_attention_fn = (
-            None if self._attention_user_supplied or model_config.is_gptoss
-            or model_config.is_mla
-            else _default_spec_attention_fn(mesh))
-        # A hybrid stack (models/hybrid.py): Mamba-2 state per slot
-        # beside the KV pages, dropless experts told which they hold.
-        self._hybrid = model_config.is_hybrid
-        # Window and full attention side by side: a second page group
-        # (its cache rides `kv_cache` as (full, window)), a second table
-        # and its base in every step program. Other models have neither.
-        self._windowed = model_config.has_window_layers
-        # Latent attention in a hybrid stack: a single-stack pool with a
-        # decode kernel of its own; its prefill rebuilds keys and values
-        # from the pool for every launch.
-        self._latent = model_config.has_latent_layers
-        if self._hybrid:
-            self._check_hybrid(model_config, runner_config, mesh)
+        # What the cache is and cannot do, by the configuration
+        # (`cache_plan`), and the model's half of every step program
+        # (`models.make_steps`: the forwards, the kernels they take of
+        # this mesh's, a caller's own `attention_fn` before any). Nothing
+        # below asks which family the model is.
+        self.cache_plan = cache_plan(model_config)
+        self._user_attention_fn = attention_fn
+        self._steps = make_steps(model_config, _mesh_kernels(mesh),
+                                 attention_fn)
+        self._check_plan(mesh)
         if model_config.ssm_layers:
             # No bucket under one chunk of the scan (published: 128): a
             # shorter launch reads the same weights, and every bucket
@@ -492,9 +492,6 @@ class ModelRunner:
                 f"unknown kv_dtype {runner_config.kv_dtype!r} "
                 "(expected 'model' or 'int8')")
         self._kv_quantized = runner_config.kv_dtype == "int8"
-        if self._kv_quantized and model_config.is_mla:
-            raise ValueError("int8 KV targets standard-attention models "
-                             "(MLA's latent cache is already compact)")
         if self._kv_quantized:
             from ..ops import kernel_path
 
@@ -509,7 +506,7 @@ class ModelRunner:
             shard_heads = model_config.n_kv_heads // mesh.shape.get(
                 AXIS_TP, 1)
             if (shard_heads % 4 and mesh.devices.size > 1
-                    and self._decode_attention_fn is not None
+                    and self._steps.decode_attention_fn is not None
                     and kernel_path("DYNT_ATTENTION") == "pallas"):
                 # Found on the v5e (PR 21): Mosaic tiles an int8 pool's
                 # (kv heads, head_dim) as (4, 128), and the kernel's
@@ -519,7 +516,8 @@ class ModelRunner:
                     f"int8 KV under tp leaves {shard_heads} kv head(s) "
                     "per shard; the compiled q8 attention kernel needs a "
                     "multiple of 4 — use kv_dtype='model' or a smaller tp")
-        self._kv_sharding = self._kv_cache_sharding(mesh)
+        self._rep = NamedSharding(mesh, P())  # replicated host inputs
+        self._cache_sharding = self._kv_cache_sharding(mesh)
 
         def _already_quantized(p) -> bool:
             """True when the incoming pytree already carries THIS
@@ -567,28 +565,20 @@ class ModelRunner:
             params = jax.tree.map(jax.device_put, params,
                                   self._param_sharding)
         self.params = params
-        self.kv_cache = self._kv_cache_init()()
-        self._rep = NamedSharding(mesh, P())  # replicated host inputs
-        self.state = None
+        # The one cache, donated whole to every step program and taken
+        # back whole: (pools, state). `pools` has an entry a page group of
+        # the plan (an int8 pool's (values, scales) inside its entry);
+        # `state` is the per-slot pytree, None for a stack without.
+        self.cache = self._kv_cache_init()()
         # expert statistics (models/hybrid.moe_mixer) of launches whose
         # readback nobody waits for, with the phase each belongs to;
         # `moe_stats` folds the ones that are ready into `moe_counts`
         # [phase (MOE_PHASES), tokens per held expert..., dropped,
         # touched, calls]
         self._moe_pending: list = []
-        self.moe_counts = None
-        if self._hybrid:
-            from ..models.hybrid import make_state_cache, moe_stats_size
-            from ..ops import kernel_path
-
-            self._ssm_path = kernel_path("DYNT_SSM")
-            self._gmm_path = kernel_path("DYNT_MOE_GMM")
-            self.state = jax.jit(
-                lambda: make_state_cache(model_config,
-                                         runner_config.max_batch),
-                out_shardings=self._rep)()
-            self.moe_counts = np.zeros(
-                (len(MOE_PHASES), moe_stats_size(model_config)), np.int64)
+        self.moe_counts = (np.zeros((len(MOE_PHASES), self._steps.stats_size),
+                                    np.int64)
+                           if self._steps.stats_size else None)
         self.lora_pack = None
         if runner_config.max_loras > 0:
             from ..models.transformer import init_lora_pack
@@ -628,6 +618,8 @@ class ModelRunner:
         # positions its decode kernel was asked to read, and positions
         # whose keys and values prefill launches rebuilt from latents,
         # both x latent layers.
+        self._latent_layers = (len(model_config.kv_layers)
+                               if model_config.has_latent_layers else 0)
         self.latent_decode_tokens = 0
         self.latent_prefill_expand_tokens = 0
         # A model with recurrent state (dynamo_ssm_prefill_*): valid
@@ -649,51 +641,52 @@ class ModelRunner:
         self._warming: Optional[str] = None  # "warmup" | "prewarm"
 
     def _kv_cache_sharding(self, mesh: Mesh):
-        """Sharding of `kv_cache` as the step programs donate it: one
-        array; (values, scales) for an int8 pool, whose per-token scales
-        are head-shared and lane-broadcast, so replicated across tp
-        shards; (full group, window group) for a model with window
-        layers."""
-        base = kv_cache_sharding(
-            mesh, head_sharded=not self.model_config.is_mla)
+        """Sharding of `cache` as the step programs donate it: a pool a
+        page group (K and V stacks sharded over their kv heads; a single
+        latent stack has none to shard) and the replicated state. An
+        int8 pool is (values, scales), whose per-token scales are
+        head-shared and lane-broadcast, so replicated across tp shards."""
+        pool = kv_cache_sharding(
+            mesh, head_sharded=self.model_config.kv_cache_kv_dims == 2)
         if self._kv_quantized:
-            return (base, NamedSharding(mesh, P()))
-        return (base, base) if self._windowed else base
+            pool = (pool, self._rep)
+        return (tuple(pool for _ in self.cache_plan.groups), self._rep)
 
     def _kv_cache_init(self):
-        """The program that makes a zeroed paged cache under
-        `_kv_sharding`, run once at start and once a reshard."""
-        cfg, rc = self.model_config, self.config
+        """The program that makes a zeroed cache under `_cache_sharding`,
+        run once at start and once a reshard."""
+        cfg, rc, steps = self.model_config, self.config, self._steps
+        pages = {"full": rc.num_pages, "window": rc.window_pages}
         if self._kv_quantized:
             from ..models.transformer import make_kv_cache_int8
 
-            def make():
-                return make_kv_cache_int8(cfg, rc.num_pages, rc.page_size)
-        elif self._windowed:
-            def make():
-                return (make_kv_cache(cfg, rc.num_pages, rc.page_size),
-                        make_kv_cache(cfg, rc.window_pages, rc.page_size,
-                                      group="window"))
+            def pool(group):
+                return make_kv_cache_int8(cfg, pages[group], rc.page_size)
         else:
-            def make():
-                return make_kv_cache(cfg, rc.num_pages, rc.page_size)
-        return jax.jit(make, out_shardings=self._kv_sharding)
+            def pool(group):
+                return make_kv_cache(cfg, pages[group], rc.page_size,
+                                     group=group)
+
+        def make():
+            return (tuple(pool(group) for group in self.cache_plan.groups),
+                    steps.make_state(rc.max_batch))
+
+        return jax.jit(make, out_shardings=self._cache_sharding)
 
     def _count_latent_decode(self, kv_lens, active, steps: int) -> None:
         """Cached positions `steps` decode steps ask the latent kernel
         for: each active row's history, one longer a step, x layers."""
-        if self._latent:
+        if self._latent_layers:
             hist = np.asarray(kv_lens, np.int64)[np.asarray(active, bool)] - 1
             self.latent_decode_tokens += int(
                 steps * hist.sum() + len(hist) * steps * (steps - 1) // 2
-            ) * len(self.model_config.kv_layers)
+            ) * self._latent_layers
 
     def _count_latent_prefill(self, kv_lens: Sequence[int]) -> None:
         """Positions whose keys and values one prefill launch rebuilds
         from latents: every row's context up to its chunk's end."""
-        if self._latent:
-            self.latent_prefill_expand_tokens += int(
-                sum(kv_lens)) * len(self.model_config.kv_layers)
+        self.latent_prefill_expand_tokens += int(
+            sum(kv_lens)) * self._latent_layers
 
     def _program(self, fn: str, *shape, tokens: int = 0, cause=None):
         """One launch of entry `fn`'s compiled program for the static
@@ -716,27 +709,27 @@ class ModelRunner:
         group's where the model has one; custom for a caller's own
         function, none for a stack without attention layers."""
         cfg = self.model_config
-        if cfg.is_hybrid and not (cfg.kv_layers or cfg.window_kv_layers):
+        if not (cfg.kv_layers or cfg.window_kv_layers):
             return "none"
-        if self._attention_user_supplied:
+        if self._user_attention_fn is not None:
             return "custom"
 
-        def group(window: bool) -> str:
-            took = {bool(self.prefill_attention_tiles(bucket, window))
+        def path(group: str) -> str:
+            took = {bool(self.prefill_attention_tiles(bucket,
+                                                      group == "window"))
                     for bucket in self.config.prefill_buckets}
             return ("mixed" if len(took) > 1
                     else "kernel" if took == {True} else "xla")
 
-        return "+".join(group(w) for w in (
-            (False, True) if self._windowed else (False,)))
+        return "+".join(path(group) for group in self.cache_plan.groups)
 
     def _table_widths(self, tables) -> tuple:
         """A decode program's key parts for its tables (`_table_args`):
-        the width it was traced at and, for a model with two page
-        groups, its window table's."""
-        if self._windowed:
-            return (f"w{tables[0].shape[-1]}", f"ww{tables[1].shape[-1]}")
-        return (f"w{tables.shape[-1]}",)
+        the width each page group's was traced at (`w64`; a second
+        group's `ww72`)."""
+        groups = len(self.cache_plan.groups)
+        return tuple(f"{'w' * (i + 1)}{table.shape[-1]}"
+                     for i, table in enumerate(tables[:groups]))
 
     @contextlib.contextmanager
     def _warm(self, cause: str):
@@ -758,9 +751,10 @@ class ModelRunner:
         over their own page group's table (`window_prefill_width`);
         else the full group's layers."""
         cfg, rc = self.model_config, self.config
-        if (self._attention_user_supplied or self._attention_fn is None
-                or self._latent or cfg.is_mla or cfg.is_gptoss
-                or not cfg.kv_layers or (window and not self._windowed)):
+        if (self._user_attention_fn is not None
+                or self._steps.attention_fn is None
+                or ("window" if window else "full")
+                not in self._steps.attention_groups):
             return None
         from ..ops.paged_attention import prefill_kernel_tiles
 
@@ -780,7 +774,8 @@ class ModelRunner:
             return None
         from ..models.hybrid import scan_head_block
 
-        return scan_head_block(self.model_config, bucket, self._ssm_path)
+        return scan_head_block(self.model_config, bucket,
+                               self._steps.ssm_path)
 
     def _count_prefill(self, starts: Sequence[int], lengths: Sequence[int],
                        rows: int, bucket: int, windows=()) -> None:
@@ -806,9 +801,10 @@ class ModelRunner:
             self.prefill_row_blocks["skipped"] += skipped
         from ..ops.paged_attention import count_prefill_blocks
 
+        windowed = "window" in self.cache_plan.groups
         tiles = self.prefill_attention_tiles(bucket)
         win_tiles = (self.prefill_attention_tiles(bucket, window=True)
-                     if self._windowed else tiles)
+                     if windowed else tiles)
         # a launch is the kernel's where every page group's layers are
         self.prefill_attn_launches[
             "kernel" if tiles and win_tiles else "xla"] += 1
@@ -818,7 +814,7 @@ class ModelRunner:
                 bucket, *tiles, self.config.max_context)
             self.prefill_attn_blocks["live"] += live
             self.prefill_attn_blocks["skipped"] += skipped
-        if self._windowed and win_tiles:
+        if windowed and win_tiles:
             # the window group's frame: positions from each row's base
             frame = [s - w[1] for s, w in zip(starts, windows)]
             live, skipped = count_prefill_blocks(
@@ -829,17 +825,25 @@ class ModelRunner:
             self.prefill_attn_window_blocks["live"] += live
             self.prefill_attn_window_blocks["skipped"] += skipped
 
-    @staticmethod
-    def _check_hybrid(cfg: ModelConfig, rc: RunnerConfig, mesh: Mesh):
-        """What a hybrid stack cannot run with yet, refused at start by
-        name: never a wrong answer later."""
-        from ..models.hybrid import hybrid_refusals
-
-        hybrid_refusals(cfg, rc.weight_dtype, rc.kv_dtype, mesh.devices.size)
-        if rc.max_loras:
+    def _check_plan(self, mesh: Mesh) -> None:
+        """What this model's cache and stack cannot run with, refused
+        when the runner is built, by name: never a wrong answer later.
+        (The worker refuses the same by flag before a process starts:
+        `engine.worker.recurrent_state_refusals`.)"""
+        cfg, rc, plan = self.model_config, self.config, self.cache_plan
+        for asked, why in (
+                (f"weight_dtype={rc.weight_dtype!r}",
+                 rc.weight_dtype != "model" and plan.quantized_weights),
+                (f"kv_dtype={rc.kv_dtype!r}",
+                 rc.kv_dtype != "model" and plan.int8_pool),
+                (f"a mesh of {mesh.devices.size} devices",
+                 mesh.devices.size > 1 and plan.shard)):
+            if why:
+                raise ValueError(f"{asked}: {why}")
+        if rc.max_loras and not self._steps.lora:
             raise ValueError(f"--max-loras: no adapter targets on {cfg.name} "
                              f"(layers {cfg.layer_pattern})")
-        if cfg.has_window_layers:
+        if "window" in plan.groups:
             need = -(-cfg.sliding_window // rc.page_size) + 2
             if rc.window_pages <= need:
                 raise ValueError(
@@ -873,44 +877,41 @@ class ModelRunner:
                    // self.config.page_size) + 1
         return prefill_table_pages(blocks, self.config.page_size)
 
-    def _table_args(self, block_tables):
-        """Block tables as a step program takes them: one int32 array,
-        or for a model with window layers (full tables, window tables,
-        window base [B])."""
-        if self._windowed:
-            return tuple(jnp.asarray(t, jnp.int32) for t in block_tables)
-        return jnp.asarray(block_tables, jnp.int32)
+    def _table_args(self, block_tables) -> tuple:
+        """Block tables as a step program takes them, always a tuple of
+        int32 arrays: `(full,)`, or for a model with a window group
+        (full tables, window tables, window base [B]). A caller with one
+        group may hand its one array in bare."""
+        if not isinstance(block_tables, tuple):
+            block_tables = (block_tables,)
+        return tuple(jnp.asarray(t, jnp.int32) for t in block_tables)
 
-    def _idle_tables(self, rows: int, width: int):
-        """All-scratch tables of `rows` x `width` (warm-up launches)."""
-        tables = np.zeros((rows, width), np.int32)
-        if not self._windowed:
-            return tables
-        return (tables, np.zeros((rows, self.window_table_width), np.int32),
-                np.zeros(rows, np.int32))
+    def _idle_tables(self, rows: int, width: int) -> tuple:
+        """All-scratch tables of `rows` x `width` (warm-up launches): a
+        second group's at its decode width, with its base."""
+        tables = [np.zeros((rows, width), np.int32)]
+        if "window" in self.cache_plan.groups:
+            tables += [np.zeros((rows, self.window_table_width), np.int32),
+                       np.zeros(rows, np.int32)]
+        return tuple(tables)
 
     def _launch(self, fn, args, kwargs=None, phase: str = "decode"):
-        """Run one compiled step. The caches go in donated behind the
-        params and come back first; a hybrid step's last output is its
-        experts' statistics, kept on the device until ready."""
-        cache = (self.kv_cache, self.state) if self._hybrid \
-            else self.kv_cache
-        cache, *rest = fn(self.params, cache, *args, **(kwargs or {}))
-        if self._hybrid:
-            self.kv_cache, self.state = cache
-            self._moe_pending.append((MOE_PHASES.index(phase), rest.pop()))
-        else:
-            self.kv_cache = cache
+        """Run one compiled step, every one (params, cache, tokens,
+        positions, tables, ...) -> (cache, <sampled>, stats). The cache
+        goes in donated behind the params and comes back first; the last
+        output is the experts' statistics (None where the stack has no
+        dropless experts), kept on the device until ready."""
+        self.cache, *rest, stats = fn(self.params, self.cache, *args,
+                                      **(kwargs or {}))
+        if stats is not None:
+            self._moe_pending.append((MOE_PHASES.index(phase), stats))
         return rest
 
     def _outs(self, *rest):
-        """out_shardings of a compiled step: the caches it took donated
-        (the KV pool, or (pool, per-slot state)), `rest`, and a hybrid
-        step's moe stats. Read off the runner at build time, since
-        `reshard` replaces the shardings."""
-        if not self._hybrid:
-            return (self._kv_sharding, *rest)
-        return ((self._kv_sharding, self._rep), *rest, self._rep)
+        """out_shardings of a compiled step: the cache it took donated,
+        `rest`, and the experts' statistics. Read off the runner at
+        build time, since `reshard` replaces the shardings."""
+        return (self._cache_sharding, *rest, self._rep)
 
     def moe_stats(self):
         """By phase (a row of MOE_PHASES each): tokens each held expert
@@ -924,60 +925,6 @@ class ModelRunner:
             phase, stats = self._moe_pending.pop(0)
             self.moe_counts[phase] += np.asarray(stats)  # dynalint: disable=DL201 -- is_ready() above: nothing to wait for; a few dozen ints per launch # dynajit: disable=DJ201 -- same: the launch has completed
         return self.moe_counts
-
-    def _decode_model(self):
-        """The model's one-token step as (params, cache, tokens,
-        positions, block_tables, kv_lens, active, lora, lora_idx) ->
-        (cache, logits [B, 1, V], extra outputs)."""
-        cfg = self.model_config
-        attention_fn = self._attention_fn
-        with_lora = self.lora_pack is not None
-        # Deferred-write decode (2 batched scatters per step for all layers
-        # instead of 2 per layer) measured ~12x faster than the unified
-        # path with the Pallas flash-decode kernel on v5e — it is the
-        # default. A USER-SUPPLIED attention_fn still wins (tests inject
-        # reference kernels); MLA keeps the unified path (its latent cache
-        # is a single stack, so the scatter count is already minimal).
-        fast_decode = (not cfg.is_mla and not cfg.is_gptoss
-                       and not self._attention_user_supplied)
-
-        def hybrid(params, cache, tokens, positions, block_tables, kv_lens,
-                   active, lora, lora_idx):
-            from ..models.hybrid import forward_hybrid_decode
-
-            kv, state = cache
-            window = None
-            if self._windowed:
-                block_tables, win_tables, win_base = block_tables
-                kv, win = kv
-                window = (win, win_tables, win_base)
-            kv, state, logits, stats = forward_hybrid_decode(
-                params, cfg, tokens, positions, kv, state, block_tables,
-                kv_lens, active,
-                decode_attention_fn=self._decode_attention_fn,
-                ssm_path=self._ssm_path, gmm_path=self._gmm_path,
-                window=window)
-            return (kv, state), logits, (stats,)
-
-        def one(params, kv, tokens, positions, block_tables, kv_lens,
-                active, lora, lora_idx):
-            if not fast_decode:
-                kv, logits = forward(
-                    params, cfg, tokens[:, None], positions[:, None], kv,
-                    block_tables, kv_lens, valid=active[:, None],
-                    attention_fn=attention_fn,
-                    lora=lora if with_lora else None, lora_idx=lora_idx,
-                )
-            else:
-                kv, logits = forward_decode(
-                    params, cfg, tokens, positions, kv, block_tables,
-                    kv_lens, active, lora=lora if with_lora else None,
-                    lora_idx=lora_idx,
-                    decode_attention_fn=self._decode_attention_fn,
-                )
-            return kv, logits, ()
-
-        return hybrid if self._hybrid else one
 
     def _init_random_params(self, seed: int) -> dict:
         """`init_params` from the seed (same values), built and — for
@@ -1036,21 +983,18 @@ class ModelRunner:
         `chip_smoke.py` checks, so a reference kernel or the interpreter
         can never serve unnoticed. Unquantized weights have one
         implementation (`einsum`); `custom` is a caller-supplied
-        attention_fn. A hybrid stack adds its kernels: the decode state
-        update and, where it has Mamba layers, the prefill scan (both
-        DYNT_SSM; a launch whose shapes the scan kernel refuses still
-        takes the XLA form: dynamo_ssm_scan_launches_total) and the
-        experts' grouped matmul (DYNT_MOE_GMM)."""
+        attention_fn. The model's adapter adds its stack's own slots
+        (`models.hybrid.HybridSteps.kernel_paths`)."""
         from ..ops import kernel_path
 
         def attention(fn) -> str:
-            if self._attention_user_supplied:
+            if self._user_attention_fn is not None:
                 return "custom"
             return "xla" if fn is None else kernel_path("DYNT_ATTENTION")
 
         paths = {
-            "decode_attention": attention(self._decode_attention_fn),
-            "spec_attention": attention(self._spec_attention_fn),
+            "decode_attention": attention(self._steps.decode_attention_fn),
+            "spec_attention": attention(self._steps.spec_attention_fn),
             "prefill_attention": self.prefill_attention_path(),
             "weight_matmul": "einsum",
         }
@@ -1058,11 +1002,7 @@ class ModelRunner:
             paths["weight_matmul"] = kernel_path("DYNT_Q8_MATMUL")
         elif self.config.weight_dtype == "int4":
             paths["weight_matmul"] = kernel_path("DYNT_Q4_MATMUL")
-        if self._hybrid:
-            paths["ssm_update"] = self._ssm_path
-            if self._ssm_layers:
-                paths["ssm_scan"] = self._ssm_path
-            paths["expert_gmm"] = self._gmm_path
+        paths.update(self._steps.kernel_paths())
         devices = list(self.mesh.devices.flat)
         paths["platform"] = devices[0].platform
         paths["device_kind"] = devices[0].device_kind
@@ -1093,17 +1033,17 @@ class ModelRunner:
 
     def _build_decode(self, with_logprobs: bool = False,
                       with_logits: bool = False):
-        one = self._decode_model()
+        one = self._steps.decode
 
-        def step(params, kv, tokens, positions, block_tables, kv_lens,
+        def step(params, cache, tokens, positions, tables, kv_lens,
                  active, temperature, top_p, top_k, seeds, step_idx,
                  lora=None, lora_idx=None):
             # step_idx: [B] per-slot generated-token index, so a fixed
             # request seed reproduces its stream independent of what other
             # requests the engine is running.
-            kv, logits, extra = one(params, kv, tokens, positions,
-                                    block_tables, kv_lens, active, lora,
-                                    lora_idx)
+            cache, logits, stats = one(params, cache, tokens, positions,
+                                       tables, kv_lens, active, lora,
+                                       lora_idx)
             if with_logits:
                 # Logits-processor escape hatch: ship the raw rows to
                 # host alongside the device-sampled tokens; the scheduler
@@ -1113,19 +1053,19 @@ class ModelRunner:
                 next_tokens = sample(
                     logits[:, 0, :], temperature, top_p, top_k, seeds,
                     step_idx)
-                return (kv, next_tokens,
-                        logits[:, 0, :].astype(jnp.float32), *extra)
+                return (cache, next_tokens,
+                        logits[:, 0, :].astype(jnp.float32), stats)
             if with_logprobs:
                 next_tokens, lp, top_ids, top_lps = sample_with_logprobs(
                     logits[:, 0, :], temperature, top_p, top_k, seeds,
                     step_idx)
-                return (kv, next_tokens, lp, top_ids, top_lps, *extra)
+                return (cache, next_tokens, lp, top_ids, top_lps, stats)
             # Hot path: no full-vocab log_softmax/top_k and only [B] int32
             # crosses device->host (the per-token latency discipline,
             # SURVEY section 7).
             next_tokens = sample(
                 logits[:, 0, :], temperature, top_p, top_k, seeds, step_idx)
-            return (kv, next_tokens, *extra)
+            return (cache, next_tokens, stats)
 
         n_rest = 2 if with_logits else 4 if with_logprobs else 1
         return jax.jit(step, donate_argnums=(1,),
@@ -1135,32 +1075,54 @@ class ModelRunner:
         """K decode steps inside ONE jit call via lax.scan: a single
         host<->device round trip produces K tokens per slot. This is the
         TPU answer to per-token dispatch latency (multi-step scheduling in
-        vLLM terms): it removes K-1 host syncs per block. A hybrid
-        model's per-slot state rides the carry with the pool."""
-        one = self._decode_model()
+        vLLM terms): it removes K-1 host syncs per block. The whole cache
+        (a model's per-slot state with its pools) rides the carry."""
+        one = self._steps.decode
+        size = self._steps.stats_size
 
-        def multi(params, kv, tokens, positions, block_tables, kv_lens,
+        def multi(params, cache, tokens, positions, tables, kv_lens,
                   active, temperature, top_p, top_k, seeds, step_idx,
                   lora=None, lora_idx=None):
             def body(carry, _):
-                kv, toks, pos, lens, sidx, acc = carry
-                kv, logits, extra = one(params, kv, toks, pos,
-                                        block_tables, lens, active, lora,
-                                        lora_idx)
+                cache, toks, pos, lens, sidx, acc = carry
+                cache, logits, stats = one(params, cache, toks, pos,
+                                           tables, lens, active, lora,
+                                           lora_idx)
                 nxt = sample(logits[:, 0, :], temperature, top_p, top_k,
                              seeds, sidx)
-                acc = tuple(a + e for a, e in zip(acc, extra))
-                return (kv, nxt, pos + 1, lens + 1, sidx + 1, acc), nxt
+                acc = None if stats is None else acc + stats
+                return (cache, nxt, pos + 1, lens + 1, sidx + 1, acc), nxt
 
-            acc0 = ((jnp.zeros(self.moe_counts.shape[1], jnp.int32),)
-                    if self._hybrid else ())
-            (kv, *_, acc), toks_k = jax.lax.scan(
-                body, (kv, tokens, positions, kv_lens, step_idx, acc0),
+            acc0 = jnp.zeros(size, jnp.int32) if size else None
+            (cache, *_, acc), toks_k = jax.lax.scan(
+                body, (cache, tokens, positions, kv_lens, step_idx, acc0),
                 None, length=k)
-            return (kv, toks_k, *acc)  # toks_k: [K, B]
+            return cache, toks_k, acc  # toks_k: [K, B]
 
         return jax.jit(multi, donate_argnums=(1,),
                        out_shardings=self._outs(self._rep))
+
+    def _decode_args(self, tokens, positions, block_tables, kv_lens, active,
+                     temperature, top_p, top_k, seeds, steps, lora_idx):
+        """What every decode program takes behind the params and the
+        cache: `tokens` and `positions` already on the device, the rest
+        placed here; the adapter pack and each slot's adapter where the
+        runner holds one."""
+        rows = len(kv_lens)
+        if steps is None:
+            steps = np.zeros(rows, np.int32)
+        args = [
+            tokens, positions, self._table_args(block_tables),
+            jnp.asarray(kv_lens, jnp.int32), jnp.asarray(active, bool),
+            jnp.asarray(temperature, jnp.float32),
+            jnp.asarray(top_p, jnp.float32), jnp.asarray(top_k, jnp.int32),
+            jnp.asarray(seeds, jnp.uint32), jnp.asarray(steps, jnp.int32),
+        ]
+        if self.lora_pack is not None:
+            if lora_idx is None:
+                lora_idx = np.zeros(rows, np.int32)
+            args += [self.lora_pack, jnp.asarray(lora_idx, jnp.int32)]
+        return args
 
     def decode_multi(
         self,
@@ -1193,22 +1155,10 @@ class ModelRunner:
         if fn is None:
             fn = self._build_decode_multi(k)
             self._decode_multi_fns[k] = fn  # dynajit: disable=DJ103 -- k is DYNT_DECODE_BLOCK, a deployment constant (one value per process; reshard resets the dict)
-        if steps is None:
-            steps = np.zeros(len(tokens), np.int32)
-        args = [
+        args = self._decode_args(
             jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(positions, jnp.int32),
-            self._table_args(block_tables),
-            jnp.asarray(kv_lens, jnp.int32), jnp.asarray(active, bool),
-            jnp.asarray(temperature, jnp.float32),
-            jnp.asarray(top_p, jnp.float32), jnp.asarray(top_k, jnp.int32),
-            jnp.asarray(seeds, jnp.uint32),
-            jnp.asarray(steps, jnp.int32),
-        ]
-        if self.lora_pack is not None:
-            if lora_idx is None:
-                lora_idx = np.zeros(len(tokens), np.int32)
-            args += [self.lora_pack, jnp.asarray(lora_idx, jnp.int32)]
+            jnp.asarray(positions, jnp.int32), block_tables, kv_lens,
+            active, temperature, top_p, top_k, seeds, steps, lora_idx)
         # tokens from the host or from the block before, still on the
         # device (the pipelined second block): two jit keys a width
         fed = "chained" if isinstance(tokens, jax.Array) else "fed"
@@ -1223,15 +1173,10 @@ class ModelRunner:
     @property
     def supports_spec(self) -> bool:
         """Whether this runner can run speculative batched verification:
-        `forward_spec` covers standard-attention models only (MLA's
-        latent cache and gpt-oss's sink attention keep per-token paths).
-        A user-supplied attention_fn also disables it — sequential decode
-        then runs the injected kernel, and verification targets drawn
-        from different attention semantics would silently diverge from
-        the non-speculative stream."""
-        cfg = self.model_config
-        return (not cfg.is_mla and not cfg.is_gptoss and not cfg.is_hybrid
-                and not self._attention_user_supplied)
+        whether the model's adapter has a step that scores several
+        positions a slot (`DenseSteps.spec`: standard attention and no
+        injected kernel)."""
+        return self._steps.spec is not None
 
     def _build_decode_spec(self, t: int, with_logits: bool = False):
         """Speculative batched verification: ONE forward scores t chunk
@@ -1244,31 +1189,26 @@ class ModelRunner:
         t committed tokens. `with_logits` additionally ships the raw
         [B, t, V] rows to host for the logits-processor verification leg
         (scheduler._drain_spec applies processors per position there)."""
-        cfg = self.model_config
-        with_lora = self.lora_pack is not None
-        from ..models.transformer import forward_spec
-
         from .sampler import spec_verify
 
-        def step(params, kv, tokens, positions, block_tables, kv_lens,
+        score = self._steps.spec
+        assert score is not None, "this stack scores one position a step"
+
+        def step(params, cache, tokens, positions, tables, kv_lens,
                  active, temperature, top_p, top_k, seeds, step_idx,
                  lora=None, lora_idx=None):
-            kv, logits = forward_spec(
-                params, cfg, tokens, positions, kv, block_tables, kv_lens,
-                active, lora=lora if with_lora else None, lora_idx=lora_idx,
-                spec_attention_fn=self._spec_attention_fn,
-            )
+            cache, logits = score(params, cache, tokens, positions, tables,
+                                  kv_lens, active, lora, lora_idx)
             targets, n_accept = spec_verify(
                 logits, tokens[:, 1:], temperature, top_p, top_k, seeds,
                 step_idx)
             if with_logits:
-                return kv, targets, n_accept, logits.astype(jnp.float32)
-            return kv, targets, n_accept
+                return (cache, targets, n_accept,
+                        logits.astype(jnp.float32), None)
+            return cache, targets, n_accept, None
 
-        shard = (self._kv_sharding, self._rep, self._rep)
-        if with_logits:
-            shard = shard + (self._rep,)
-        return jax.jit(step, donate_argnums=(1,), out_shardings=shard)
+        return jax.jit(step, donate_argnums=(1,), out_shardings=self._outs(
+            *[self._rep] * (3 if with_logits else 2)))
 
     def decode_spec(
         self,
@@ -1301,90 +1241,46 @@ class ModelRunner:
         if fn is None:
             fn = self._build_decode_spec(t, want_logits)
             self._decode_spec_fns[(t, want_logits)] = fn
-        if steps is None:
-            steps = np.zeros(b, np.int32)
         chunk = np.concatenate(
             [np.asarray(tokens, np.int32)[:, None],
              np.asarray(drafts, np.int32)], axis=1)
         pos2 = (np.asarray(positions, np.int32)[:, None]
                 + np.arange(t, dtype=np.int32)[None, :])
-        assert not self._hybrid, "speculation is refused for hybrid stacks"
-        args = [
-            self.params, self.kv_cache, jnp.asarray(chunk),
-            jnp.asarray(pos2),
-            jnp.asarray(block_tables, jnp.int32),
-            jnp.asarray(kv_lens, jnp.int32), jnp.asarray(active, bool),
-            jnp.asarray(temperature, jnp.float32),
-            jnp.asarray(top_p, jnp.float32), jnp.asarray(top_k, jnp.int32),
-            jnp.asarray(seeds, jnp.uint32),
-            jnp.asarray(steps, jnp.int32),
-        ]
-        if self.lora_pack is not None:
-            if lora_idx is None:
-                lora_idx = np.zeros(b, np.int32)
-            args += [self.lora_pack, jnp.asarray(lora_idx, jnp.int32)]
-        shape = (*self._table_widths(args[4]), f"k{k}",
+        args = self._decode_args(
+            jnp.asarray(chunk), jnp.asarray(pos2), block_tables, kv_lens,
+            active, temperature, top_p, top_k, seeds, steps, lora_idx)
+        shape = (*self._table_widths(args[2]), f"k{k}",
                  "logits" if want_logits else "")
-        if want_logits:
-            with self._program("decode_spec", *shape):
-                self.kv_cache, targets, n_accept, logits = fn(*args)
-            if return_device:
-                self.last_spec_logits = logits
-                return targets, n_accept
-            self.last_spec_logits = np.asarray(logits)  # dynajit: disable=DJ201 -- processor-slot raw rows; paid only by want_logits steps
-        else:
-            with self._program("decode_spec", *shape):
-                self.kv_cache, targets, n_accept = fn(*args)
-            self.last_spec_logits = None
-            if return_device:
-                return targets, n_accept
+        with self._program("decode_spec", *shape):
+            targets, n_accept, *logits = self._launch(fn, args)
+        self.last_spec_logits = logits[0] if logits else None
+        if return_device:
+            return targets, n_accept
+        if logits:
+            self.last_spec_logits = np.asarray(logits[0])  # dynajit: disable=DJ201 -- processor-slot raw rows; paid only by want_logits steps
         return np.asarray(targets), np.asarray(n_accept)  # dynajit: disable=DJ201 -- the spec step's designed drain (scheduler defers via return_device)
 
     def _build_prefill(self, bucket: int):
-        cfg = self.model_config
-        attention_fn = self._attention_fn
-        with_lora = self.lora_pack is not None
-        with_mm = cfg.image_token_id >= 0
+        chunk = self._steps.prefill
 
-        def step(params, kv, tokens, positions, block_table, kv_lens,
-                 valid, last_idx, temperature, top_p, top_k, seeds,
-                 lora=None, lora_idx=None, extra_embeds=None, slots=None,
-                 window=None):
-            if self._hybrid:
-                from ..models.hybrid import forward_hybrid
-
-                # logits of each row's last valid position only: a
-                # [rows x T, vocab] float32 never exists on this path
-                kv, state = kv
-                if window is not None:  # (tables [B, pages], base [B])
-                    kv, win = kv
-                    window = (win, *window)
-                kv, state, last, stats = forward_hybrid(
-                    params, cfg, tokens, positions, kv, state, slots,
-                    block_table, kv_lens, valid, last_idx,
-                    attention_fn=attention_fn, gmm_path=self._gmm_path,
-                    window=window, ssm_path=self._ssm_path)
-                kv, extra = (kv, state), (stats,)
-            else:
-                kv, logits = forward(
-                    params, cfg, tokens, positions, kv, block_table,
-                    kv_lens, valid=valid, attention_fn=attention_fn,
-                    lora=lora if with_lora else None, lora_idx=lora_idx,
-                    extra_embeds=extra_embeds if with_mm else None,
-                    extra_mask=((tokens == cfg.image_token_id)
-                                if with_mm else None),
-                )
-                last = jnp.take_along_axis(
-                    logits, last_idx[:, None, None], axis=1
-                )[:, 0, :]  # [1, V]
-                extra = ()
+        def step(params, cache, tokens, positions, tables, kv_lens, valid,
+                 last_idx, temperature, top_p, top_k, seeds, slots=None,
+                 lora=None, lora_idx=None, extra_embeds=None, window=()):
+            # `window`: a second page group's (tables, base), handed in
+            # behind every other argument and not beside `tables`, where
+            # these programs have always taken them: the order of a
+            # program's parameters is part of its compiled text.
+            cache, last, stats = chunk(
+                params, cache, tokens, positions, (*tables, *window),
+                kv_lens, valid, last_idx, slots, lora, lora_idx,
+                extra_embeds)
             # Unconditional here, unlike decode: one [1, V] log_softmax per
             # CHUNK is noise next to the chunk forward, and the extra host
             # transfer is a handful of floats. Decode pays this per token,
             # hence its gated _decode_fn/_decode_fn_lp split.
             token, lp, top_ids, top_lps = sample_with_logprobs(
                 last, temperature, top_p, top_k, seeds, jnp.int32(0))
-            return (kv, token, lp, top_ids, top_lps, *extra)
+            return (cache, token, lp, top_ids, top_lps, stats)
 
         return jax.jit(step, donate_argnums=(1,),
                        out_shardings=self._outs(*[self._rep] * 4))
@@ -1413,8 +1309,9 @@ class ModelRunner:
             out_specs=s_q,
         )
 
-        def step(params, kv, tokens, positions, valid, block_table,
+        def step(params, cache, tokens, positions, valid, block_table,
                  last_idx, temperature, top_p, top_k, seeds):
+            (kv,), state = cache
             logits, ks, vs = forward_ring(params, cfg, tokens, positions,
                                           valid, ring_fn)
             kv = write_kv_stack(kv, ks, vs, block_table, positions, valid)
@@ -1423,11 +1320,10 @@ class ModelRunner:
             )[:, 0, :]
             token, lp, top_ids, top_lps = sample_with_logprobs(
                 last, temperature, top_p, top_k, seeds, jnp.int32(0))
-            return kv, token, lp, top_ids, top_lps
+            return ((kv,), state), token, lp, top_ids, top_lps, None
 
         return jax.jit(step, donate_argnums=(1,),
-                       out_shardings=(self._kv_sharding, self._rep,
-                                      self._rep, self._rep, self._rep))
+                       out_shardings=self._outs(*[self._rep] * 4))
 
     def prefill_ring_batch(
         self,
@@ -1481,14 +1377,13 @@ class ModelRunner:
         seeds = np.asarray([s[3] for s in samplings], np.uint32)
         with self._program("prefill_ring", f"{b}x{bucket}",
                            tokens=sum(len(p) for p in prompts)):
-            self.kv_cache, token, lp, top_ids, top_lps = fn(
-                self.params, self.kv_cache, jnp.asarray(tok),
-                jnp.asarray(pos),
+            token, lp, top_ids, top_lps = self._launch(fn, [
+                jnp.asarray(tok), jnp.asarray(pos),
                 jnp.asarray(valid), jnp.asarray(block_tables, jnp.int32),
                 jnp.asarray(last_idx),
                 jnp.asarray(temp), jnp.asarray(top_p),
                 jnp.asarray(top_k), jnp.asarray(seeds),
-            )
+            ], phase="prefill")
         lp_h = np.asarray(lp)  # dynajit: disable=DJ201 -- ring prefill ends the prompt pass; its sample drain is the step boundary
         ids_h = np.asarray(top_ids)  # dynajit: disable=DJ201 -- same ring-prefill drain
         lps_h = np.asarray(top_lps)  # dynajit: disable=DJ201 -- same ring-prefill drain
@@ -1560,29 +1455,21 @@ class ModelRunner:
     @property
     def bounds_prefill_launches(self) -> bool:
         """Whether a prefill launch's rows x bucket must stay inside the
-        token budget (`prefill_launch_fits`): latent layers, whose
-        prefill attention scores a launch's positions against wide
-        tables in float32, and window layers beside full ones, whose XLA
-        form does (on the chip both their page groups run the blocked
-        kernel since PR 41 and hold no such scores; the bound stays
-        because lifting it changes the program grid: ROADMAP A7); and
-        Mamba layers where a context runs past one launch (a grid of
-        rows x bucket past the budget is programs no launch needs: a
-        model whose contexts fit one launch keeps its rows. The scan's
-        XLA form also held float32 [positions, heads, chunk] products,
-        134 MB each at 2,048 positions x 128 heads; on the chip the
-        scan is a kernel since PR 43 and holds none, and this bound
-        stays for the grid's sake: ROADMAP A7). What a launch holds in
-        temporaries now is the routed experts' float32 rows, twice: as
-        the down-projection wrote them and gathered slots-major for the
-        sum, [positions x k, hidden] each (`ops/grouped_matmul.
-        dropless_experts`: 0.86-0.91 GB at 2,048 positions x top-10 of
-        hidden 4096, 1.06-1.11 before PR 46). They grow with a launch's
-        positions, so a lifted bound is paid in them too."""
-        return (self._windowed or self._latent
-                or (self.model_config.has_recurrent_state
-                    and self.config.max_context
-                    > self.config.prefill_buckets[-1]))
+        token budget (`prefill_launch_fits`), by the cache plan's
+        `launch_bound`: always for latent layers, whose prefill
+        attention scores a launch's positions against wide tables in
+        float32, and window layers beside full ones, whose XLA form
+        does; and for state carried from launch to launch, where a
+        context runs past one launch (a model whose contexts fit one
+        launch keeps its rows). On the chip the window groups and the
+        scan run kernels that hold no such scores; the bound stays
+        because a grid of rows x bucket past the budget is programs no
+        launch needs, and what a launch holds in temporaries, the routed
+        experts' float32 rows, grows with its positions (ROADMAP A7)."""
+        bound = self.cache_plan.launch_bound
+        return bound == "always" or (
+            bound == "carried"
+            and self.config.max_context > self.config.prefill_buckets[-1])
 
     def prefill_launch_fits(self, lengths: Sequence[int]) -> bool:
         """`bounds_prefill_launches`: whether rows of these chunk
@@ -1595,10 +1482,12 @@ class ModelRunner:
         return (rows * self._bucket_for(max(lengths))
                 <= self.config.prefill_buckets[-1])
 
-    def _window_rows(self, bucket: int, rows: int, windows):
+    def _window_rows(self, bucket: int, rows: int, windows) -> tuple:
         """Each row's (window table, base) as the prefill program's
         `window` argument: tables padded to the bucket's width with the
-        scratch page."""
+        scratch page. Empty where the cache has one page group."""
+        if "window" not in self.cache_plan.groups:
+            return ()
         width = self.window_prefill_width(bucket)
         tables = np.zeros((rows, width), np.int32)
         base = np.zeros(rows, np.int32)
@@ -1608,6 +1497,83 @@ class ModelRunner:
         return jnp.asarray(tables), jnp.asarray(base)
 
     # -- host API ----------------------------------------------------------
+
+    def _prefill(self, entry: str, rows: Sequence[PrefillRow], b: int,
+                 embeds: Optional[np.ndarray] = None):
+        """Pack `rows` into one [b, bucket] launch of the bucket's
+        prefill program and make it under `entry`'s program key. Rows
+        past `len(rows)` write into the page-0 scratch sink with an
+        all-False valid mask and a state slot past the end, the same
+        padding contract a row's token tail has. `embeds` [t, H]: splice
+        rows of a lone row's chunk (a multimodal engine)."""
+        bucket = self._bucket_for(max(len(r.tokens) for r in rows))
+        fn = self._prefill_fns.get(bucket)
+        if fn is None:
+            fn = self._build_prefill(bucket)
+            self._prefill_fns[bucket] = fn
+        tok = np.zeros((b, bucket), np.int32)
+        pos = np.zeros((b, bucket), np.int32)
+        valid = np.zeros((b, bucket), bool)
+        tables = np.zeros((b, len(rows[0].table)), np.int32)
+        kv_lens = np.zeros(b, np.int32)
+        last_idx = np.zeros(b, np.int32)
+        temp = np.zeros(b, np.float32)
+        top_p = np.ones(b, np.float32)
+        top_k = np.zeros(b, np.int32)
+        seeds = np.zeros(b, np.uint32)
+        lora_rows = np.zeros(b, np.int32)
+        slots = np.full(b, self.config.max_batch, np.int32)
+        for i, row in enumerate(rows):
+            t = len(row.tokens)
+            tok[i, :t] = row.tokens
+            pos[i, :t] = np.arange(row.start, row.start + t)
+            valid[i, :t] = True
+            tables[i] = row.table
+            kv_lens[i] = row.kv_len_after
+            last_idx[i] = t - 1
+            temp[i], top_p[i], top_k[i], seeds[i] = row.sampling
+            lora_rows[i] = row.lora_idx
+            if row.slot is not None:
+                slots[i] = row.slot
+        windows = [r.window for r in rows]
+        self._count_prefill([r.start for r in rows],
+                            [len(r.tokens) for r in rows], b, bucket, windows)
+        self._count_latent_prefill([r.kv_len_after for r in rows])
+        args = [
+            jnp.asarray(tok), jnp.asarray(pos),
+            (jnp.asarray(tables),), jnp.asarray(kv_lens), jnp.asarray(valid),
+            jnp.asarray(last_idx), jnp.asarray(temp), jnp.asarray(top_p),
+            jnp.asarray(top_k), jnp.asarray(seeds),
+        ]
+        # Optional features pass by KEYWORD: with lora disabled, a
+        # positional embeds array would silently bind to the `lora`
+        # parameter and the splice would never happen.
+        kwargs: dict = {
+            "slots": jnp.asarray(slots),
+            "window": self._window_rows(bucket, b, windows)}
+        if self.lora_pack is not None:
+            kwargs["lora"] = self.lora_pack
+            kwargs["lora_idx"] = jnp.asarray(lora_rows)
+        if self.model_config.image_token_id >= 0:
+            if embeds is not None:
+                spliced = np.zeros((b, bucket, self.model_config.hidden),
+                                   np.float32)
+                spliced[0, :len(embeds)] = embeds
+                kwargs["extra_embeds"] = jnp.asarray(spliced)
+            else:
+                # Text only on a multimodal engine: reuse a cached device
+                # zero buffer (a fresh 10s-of-MB host alloc + transfer
+                # per launch would tax every text request).
+                zeros = self._zero_embeds.get((b, bucket))
+                if zeros is None:
+                    zeros = jnp.zeros(
+                        (b, bucket, self.model_config.hidden), jnp.float32)
+                    self._zero_embeds[(b, bucket)] = zeros
+                kwargs["extra_embeds"] = zeros
+        shape = bucket if entry == "prefill" else f"{b}x{bucket}"
+        with self._program(entry, shape,
+                           tokens=sum(len(r.tokens) for r in rows)):
+            return self._launch(fn, args, kwargs, phase="prefill")
 
     def prefill_chunk(
         self,
@@ -1619,9 +1585,10 @@ class ModelRunner:
         lora_idx: int = 0,
         chunk_embeds: Optional[np.ndarray] = None,  # [t, H] splice rows
         return_device: bool = False,
-        slot: int = 0,  # the scheduler's slot: where recurrent state lives
+        slot: Optional[int] = None,  # the scheduler's slot, where per-slot
+        #               state lives; None: past the end, its write dropped
         window=None,  # (window group's pages from its first held block,
-        #               that block's first position): window layers only
+        #               that block's first position): a second page group
     ) -> int:
         """Run one prefill chunk; returns the sampled token id (meaningful
         only on the final chunk). `chunk_embeds` rows replace the token
@@ -1630,60 +1597,10 @@ class ModelRunner:
         token array — lets callers (bench pipelining, speculative
         schedulers) overlap successive chunks across the dispatch
         round trip the same way decode_multi does."""
-        t = len(tokens)
-        bucket = self._bucket_for(t)
-        fn = self._prefill_fns.get(bucket)
-        if fn is None:
-            fn = self._build_prefill(bucket)
-            self._prefill_fns[bucket] = fn
-        tok = np.zeros((1, bucket), np.int32)
-        tok[0, :t] = tokens
-        pos = np.zeros((1, bucket), np.int32)
-        pos[0, :t] = np.arange(start_pos, start_pos + t)
-        valid = np.zeros((1, bucket), bool)
-        valid[0, :t] = True
-        self._count_prefill([start_pos], [t], 1, bucket, [window])
-        self._count_latent_prefill([kv_len_after])
-        temp, top_p, top_k, seed = sampling
-        args = [
-            jnp.asarray(tok), jnp.asarray(pos),
-            jnp.asarray(block_table[None, :]),
-            jnp.asarray([kv_len_after], np.int32),
-            jnp.asarray(valid), jnp.asarray([t - 1], np.int32),
-            jnp.asarray([temp], np.float32), jnp.asarray([top_p], np.float32),
-            jnp.asarray([top_k], np.int32),
-            jnp.asarray([seed], np.uint32),
-        ]
-        # Optional features pass by KEYWORD: with lora disabled, a
-        # positional embeds array would silently bind to the `lora`
-        # parameter and the splice would never happen.
-        kwargs: dict = {}
-        if self._hybrid:
-            kwargs["slots"] = jnp.asarray([slot], jnp.int32)
-        if self._windowed:
-            kwargs["window"] = self._window_rows(bucket, 1, [window])
-        if self.lora_pack is not None:
-            kwargs["lora"] = self.lora_pack
-            kwargs["lora_idx"] = jnp.asarray([lora_idx], jnp.int32)
-        if self.model_config.image_token_id >= 0:
-            if chunk_embeds is not None:
-                embeds = np.zeros((1, bucket, self.model_config.hidden),
-                                  np.float32)
-                embeds[0, :t] = chunk_embeds
-                kwargs["extra_embeds"] = jnp.asarray(embeds)
-            else:
-                # Text-only request on a multimodal engine: reuse a cached
-                # device zero buffer (a fresh 10s-of-MB host alloc +
-                # transfer per chunk would tax every text request).
-                zeros = self._zero_embeds.get(bucket)
-                if zeros is None:
-                    zeros = jnp.zeros(
-                        (1, bucket, self.model_config.hidden), jnp.float32)
-                    self._zero_embeds[bucket] = zeros
-                kwargs["extra_embeds"] = zeros
-        with self._program("prefill", bucket, tokens=t):
-            token, lp, top_ids, top_lps = self._launch(fn, args, kwargs,
-                                                       phase="prefill")
+        row = PrefillRow(tokens, start_pos, block_table, kv_len_after,
+                         sampling, lora_idx, slot, window)
+        token, lp, top_ids, top_lps = self._prefill("prefill", [row], 1,
+                                                    chunk_embeds)
         if return_device:
             self.last_prefill_sample = None
             return token
@@ -1694,8 +1611,7 @@ class ModelRunner:
 
     def prefill_chunk_batch(
         self,
-        rows: list,  # (tokens, start_pos, block_table, kv_len_after,
-        #              sampling, lora_idx[, slot[, window]]) per sequence
+        rows: Sequence[PrefillRow],  # one a sequence
         want_samples: bool = False,
     ):
         """Run SEVERAL sequences' prefill chunks in one compiled dispatch
@@ -1704,81 +1620,18 @@ class ModelRunner:
         prefill step function is batch-general, jit specializes per
         (B, bucket)). Per-row results are bit-identical to equivalent
         prefill_chunk calls: the sampler keys on each row's (seed, step),
-        never the row index.
+        never the row index. The batched path carries no embed splicing
+        (the scheduler routes media sequences through single-row
+        prefill).
 
         Returns the device token array [B_padded] (row i = rows[i]); with
         want_samples=True, `last_prefill_samples` holds per-row
         (logprob, top_ids, top_logprobs) — a host sync, so ask only when
-        a row actually needs logprobs. Rows padded to the power-of-two
-        batch write into the page-0 scratch sink with an all-False valid
-        mask, the same padding contract single-row prefill uses for its
-        token tail."""
+        a row actually needs logprobs. B is padded to a power of two:
+        bounded jit variants."""
         n = len(rows)
-        b = 1 << max(0, n - 1).bit_length()  # pow2 B: bounded jit variants
-        bucket = self._bucket_for(max(len(r[0]) for r in rows))
-        fn = self._prefill_fns.get(bucket)
-        if fn is None:
-            fn = self._build_prefill(bucket)
-            self._prefill_fns[bucket] = fn
-        max_pages = self.config.max_pages_per_seq
-        tok = np.zeros((b, bucket), np.int32)
-        pos = np.zeros((b, bucket), np.int32)
-        valid = np.zeros((b, bucket), bool)
-        tables = np.zeros((b, max_pages), np.int32)  # pad rows -> scratch
-        kv_lens = np.zeros(b, np.int32)
-        last_idx = np.zeros(b, np.int32)
-        temp = np.zeros(b, np.float32)
-        top_p = np.ones(b, np.float32)
-        top_k = np.zeros(b, np.int32)
-        seeds = np.zeros(b, np.uint32)
-        lora_rows = np.zeros(b, np.int32)
-        # a padded row's state write is dropped: its slot is past the end
-        slots = np.full(b, self.config.max_batch, np.int32)
-        windows = []
-        for i, (tokens, start, table, kv_after, sampling, lidx, *slot) in \
-                enumerate(rows):
-            slots[i] = slot[0] if slot else 0
-            windows.append(slot[1] if len(slot) > 1 else None)
-            t = len(tokens)
-            tok[i, :t] = tokens
-            pos[i, :t] = np.arange(start, start + t)
-            valid[i, :t] = True
-            tables[i] = table
-            kv_lens[i] = kv_after
-            last_idx[i] = t - 1
-            temp[i], top_p[i], top_k[i], seeds[i] = sampling
-            lora_rows[i] = lidx
-        self._count_prefill([r[1] for r in rows],
-                            [len(r[0]) for r in rows], b, bucket, windows)
-        self._count_latent_prefill([r[3] for r in rows])
-        args = [
-            jnp.asarray(tok), jnp.asarray(pos),
-            jnp.asarray(tables), jnp.asarray(kv_lens), jnp.asarray(valid),
-            jnp.asarray(last_idx), jnp.asarray(temp), jnp.asarray(top_p),
-            jnp.asarray(top_k), jnp.asarray(seeds),
-        ]
-        kwargs: dict = {}
-        if self._hybrid:
-            kwargs["slots"] = jnp.asarray(slots)
-        if self._windowed:
-            kwargs["window"] = self._window_rows(bucket, b, windows)
-        if self.lora_pack is not None:
-            kwargs["lora"] = self.lora_pack
-            kwargs["lora_idx"] = jnp.asarray(lora_rows)
-        if self.model_config.image_token_id >= 0:
-            # Batched path carries no embed splicing (the scheduler routes
-            # media sequences through single-row prefill); reuse a cached
-            # device zero buffer per (B, bucket).
-            zeros = self._zero_embeds.get((b, bucket))
-            if zeros is None:
-                zeros = jnp.zeros(
-                    (b, bucket, self.model_config.hidden), jnp.float32)
-                self._zero_embeds[(b, bucket)] = zeros
-            kwargs["extra_embeds"] = zeros
-        with self._program("prefill_batch", f"{b}x{bucket}",
-                           tokens=sum(len(r[0]) for r in rows)):
-            token, lp, top_ids, top_lps = self._launch(fn, args, kwargs,
-                                                       phase="prefill")
+        token, lp, top_ids, top_lps = self._prefill(
+            "prefill_batch", rows, 1 << max(0, n - 1).bit_length())
         if want_samples:
             lp_h = np.asarray(lp)  # dynajit: disable=DJ201 -- explicit want_samples contract: callers ask only when a row needs logprobs
             ids_h = np.asarray(top_ids)  # dynajit: disable=DJ201 -- same want_samples drain
@@ -1815,47 +1668,27 @@ class ModelRunner:
         host from the raw rows in that mode)."""
         self.decode_steps += 1
         self._count_latent_decode(kv_lens, active, 1)
-        if steps is None:
-            steps = np.zeros(len(tokens), np.int32)
-        args = [
+        args = self._decode_args(
             jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(positions, jnp.int32),
-            self._table_args(block_tables),
-            jnp.asarray(kv_lens, jnp.int32), jnp.asarray(active, bool),
-            jnp.asarray(temperature, jnp.float32),
-            jnp.asarray(top_p, jnp.float32), jnp.asarray(top_k, jnp.int32),
-            jnp.asarray(seeds, jnp.uint32),
-            jnp.asarray(steps, jnp.int32),
-        ]
-        if self.lora_pack is not None:
-            if lora_idx is None:
-                lora_idx = np.zeros(len(tokens), np.int32)
-            args += [self.lora_pack, jnp.asarray(lora_idx, jnp.int32)]
-        shape = (*self._table_widths(args[2]),
-                 "logits" if want_logits else "lp" if want_logprobs else "")
-        if want_logits:
-            if self._decode_fn_logits is None:
-                self._decode_fn_logits = self._build_decode(
-                    with_logits=True)
-            with self._program("decode", *shape):
-                next_tokens, logits = self._launch(
-                    self._decode_fn_logits, args)
-            self.last_decode_logits = np.asarray(logits)  # dynajit: disable=DJ201 -- logits-processor escape hatch: host sampling needs the raw rows now
-            self.last_decode_sample = (None, None, None)
-        elif want_logprobs:
-            if self._decode_fn_lp is None:
-                self._decode_fn_lp = self._build_decode(True)
-            with self._program("decode", *shape):
-                next_tokens, lp, top_ids, top_lps = self._launch(
-                    self._decode_fn_lp, args)
+            jnp.asarray(positions, jnp.int32), block_tables, kv_lens,
+            active, temperature, top_p, top_k, seeds, steps, lora_idx)
+        variant = "logits" if want_logits else "lp" if want_logprobs else ""
+        if variant == "logits" and self._decode_fn_logits is None:
+            self._decode_fn_logits = self._build_decode(with_logits=True)
+        if variant == "lp" and self._decode_fn_lp is None:
+            self._decode_fn_lp = self._build_decode(True)
+        fn = (self._decode_fn_logits if variant == "logits"
+              else self._decode_fn_lp if variant else self._decode_fn)
+        with self._program("decode", *self._table_widths(args[2]), variant):
+            next_tokens, *more = self._launch(fn, args)
+        self.last_decode_logits = None
+        if variant == "logits":
+            self.last_decode_logits = np.asarray(more[0])  # dynajit: disable=DJ201 -- logits-processor escape hatch: host sampling needs the raw rows now
+        self.last_decode_sample = (None, None, None)
+        if variant == "lp":
+            lp, top_ids, top_lps = more
             self.last_decode_sample = (np.asarray(lp), np.asarray(top_ids),  # dynajit: disable=DJ201 -- logprobs path: per-step sample data is the request's contract
                                        np.asarray(top_lps))  # dynajit: disable=DJ201 -- same logprobs drain
-            self.last_decode_logits = None
-        else:
-            with self._program("decode", *shape):
-                (next_tokens,) = self._launch(self._decode_fn, args)
-            self.last_decode_sample = (None, None, None)
-            self.last_decode_logits = None
         return np.asarray(next_tokens)  # dynajit: disable=DJ201 -- the per-token decode drain: [B] int32 is the step's designed readback
 
     # -- LoRA slot pack ----------------------------------------------------
@@ -1904,17 +1737,12 @@ class ModelRunner:
         reference's scale_elastic_ep drains the same way,
         ref: components/src/dynamo/vllm/handlers.py:498 scale_elastic_ep).
         Must run on the scheduler thread (kv donation)."""
-        if self._hybrid:
-            self._check_hybrid(self.model_config, self.config, mesh)
+        self._check_plan(mesh)
         self.mesh = mesh
-        if not self._attention_user_supplied:
-            # The kernel choice depends on the mesh (Pallas flash-decode is
-            # single-device only): re-derive it for the new device count.
-            self._attention_fn = _default_attention_fn(mesh)
-            self._decode_attention_fn = _default_decode_attention_fn(mesh)
-            if not (self.model_config.is_gptoss
-                    or self.model_config.is_mla):
-                self._spec_attention_fn = _default_spec_attention_fn(mesh)
+        # The kernel choice depends on the mesh (Pallas flash-decode is
+        # single-device only): the adapter takes it anew.
+        self._steps = make_steps(self.model_config, _mesh_kernels(mesh),
+                                 self._user_attention_fn)
         axes = param_axes(self.model_config)
         if self._weight_quantized:
             from ..models.quantize import check_quantizable
@@ -1928,9 +1756,9 @@ class ModelRunner:
         self.params = jax.tree.map(
             jax.device_put, self.params, self._param_sharding
         )
-        self._kv_sharding = self._kv_cache_sharding(mesh)
-        self.kv_cache = self._kv_cache_init()()
         self._rep = NamedSharding(mesh, P())
+        self._cache_sharding = self._kv_cache_sharding(mesh)
+        self.cache = self._kv_cache_init()()
         if self.lora_pack is not None:
             self.lora_pack = jax.device_put(self.lora_pack, self._rep)
         self._decode_fn = self._build_decode(False)
@@ -1959,22 +1787,11 @@ class ModelRunner:
         forces it so every host can read the full bundle locally)."""
         from ..ops.block_copy import gather_kv_blocks, gather_kv_blocks_q8
 
-        if self._windowed:
+        if self.cache_plan.move_pages:
             raise RuntimeError(
-                f"{self.model_config.name} keeps two page groups; the full "
-                "group's pages alone cannot be transferred, offloaded or "
-                "parked (what lay behind the window is freed)")
-        if self._latent:
-            raise RuntimeError(
-                f"{self.model_config.name} keeps a single-stack latent "
-                "pool; its pages cannot be transferred, offloaded or "
-                "parked (the bundles are K and V per kv head)")
-        if self.model_config.has_recurrent_state:
-            # pages without the state that produced them resume nothing
-            raise RuntimeError(
-                f"{self.model_config.name} keeps recurrent state per slot; "
-                "KV pages alone cannot be transferred, offloaded or parked "
-                "(no state snapshot yet)")
+                "pages cannot be transferred, offloaded or parked: "
+                + self.cache_plan.move_pages)
+        (pool,), _ = self.cache
 
         # Pad the id list to a power-of-two width (extra ids hit the
         # scratch page 0) so the gather jit compiles O(log n) shapes, not
@@ -1988,11 +1805,9 @@ class ModelRunner:
             # Quantized pool: PACKED uint8 universal blocks (int8 values
             # + bf16 scale rows, ops/block_copy.py) — bit-exact through
             # every tier, no dequant/requant roundtrip.
-            bundle = gather_kv_blocks_q8(self.kv_cache[0],
-                                         self.kv_cache[1],
-                                         jnp.asarray(ids))
+            bundle = gather_kv_blocks_q8(*pool, jnp.asarray(ids))
         else:
-            bundle = gather_kv_blocks(self.kv_cache, jnp.asarray(ids))
+            bundle = gather_kv_blocks(pool, jnp.asarray(ids))
         if m != n:
             bundle = bundle[:n]
         if replicated and not bundle.is_fully_addressable:
@@ -2021,25 +1836,24 @@ class ModelRunner:
             scatter_kv_blocks_q8,
         )
 
+        (pool,), state = self.cache
         if self._kv_quantized:
-            values, scales = self.kv_cache
+            values, scales = pool
             if isinstance(blocks, jax.Array):
-                self.kv_cache = scatter_kv_blocks_q8(
+                pool = scatter_kv_blocks_q8(
                     values, scales, jnp.asarray(page_ids, jnp.int32),
                     blocks)
             else:
-                self.kv_cache = scatter_from_host_q8(
+                pool = scatter_from_host_q8(
                     values, scales, np.asarray(page_ids, np.int32),
                     blocks)
-            return
-        if isinstance(blocks, jax.Array):
-            self.kv_cache = scatter_kv_blocks(
-                self.kv_cache, jnp.asarray(page_ids, jnp.int32), blocks
-            )
+        elif isinstance(blocks, jax.Array):
+            pool = scatter_kv_blocks(
+                pool, jnp.asarray(page_ids, jnp.int32), blocks)
         else:
-            self.kv_cache = scatter_from_host(
-                self.kv_cache, np.asarray(page_ids, np.int32), blocks
-            )
+            pool = scatter_from_host(
+                pool, np.asarray(page_ids, np.int32), blocks)
+        self.cache = ((pool,), state)
 
     # -- distributed KVBM worker half (block_manager/distributed.py) -------
     # Mirrored across multihost ranks via the step channel: each host
@@ -2106,18 +1920,8 @@ class ModelRunner:
             )
             self.prefill_chunk(
                 np.zeros(1, np.int32), 0, np.zeros(p, np.int32), 1,
-                (0.0, 1.0, 0, 0), **self._idle_row(),
+                (0.0, 1.0, 0, 0), window=IDLE_WINDOW,
             )
-
-    def _idle_row(self) -> dict:
-        """What a warm-up prefill row passes beside its scratch table: a
-        state slot past the end, an empty window table."""
-        row: dict = {}
-        if self._hybrid:
-            row["slot"] = self.config.max_batch
-        if self._windowed:
-            row["window"] = ((), 0)
-        return row
 
     def prewarm(self, spec_widths: Optional[Sequence[int]] = None,
                 launches: bool = False, block: int = 1) -> None:
@@ -2168,7 +1972,7 @@ class ModelRunner:
             self.prefill_chunk(
                 np.zeros(bucket, np.int32), 0, np.zeros(p, np.int32),
                 min(bucket, self.config.max_context), greedy,
-                **self._idle_row(),
+                window=IDLE_WINDOW,
             )
         if launches:
             self._prewarm_launches(buckets, block)
@@ -2193,16 +1997,15 @@ class ModelRunner:
         rows = 2
         # rows are padded to a power of two: the limit's own ceiling
         limit = 1 << (min(self.max_prefill_rows, b) - 1).bit_length()
-        # a warm-up row's [slot[, window]] behind the six every row has
-        tail = tuple(self._idle_row().values())
         while rows <= limit:
             for bucket in buckets:
                 n = min(bucket, self.config.max_context - 1)
                 if (self.bounds_prefill_launches
                         and not self.prefill_launch_fits([n] * rows)):
                     continue  # the scheduler never makes this launch
-                row = (np.zeros(n, np.int32), 0, np.zeros(p, np.int32), n,
-                       greedy, 0, *tail)
+                row = PrefillRow(np.zeros(n, np.int32), 0,
+                                 np.zeros(p, np.int32), n, greedy,
+                                 window=IDLE_WINDOW)
                 toks = self.prefill_chunk_batch([row] * rows)
                 # the scheduler picks a row's token on the device
                 # (`_prefill_batch`): one tiny program for each batch size
@@ -2223,4 +2026,4 @@ class ModelRunner:
                 if width >= p:
                     break
                 width = bucket_table_width(width + 1, p)
-        jax.block_until_ready(self.kv_cache)
+        jax.block_until_ready(self.cache)

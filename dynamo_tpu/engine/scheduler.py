@@ -42,7 +42,13 @@ from ..perf.steptrace import StepTrace, annotation
 from ..runtime.flight_recorder import get_recorder
 from ..runtime.logging import get_logger
 from ..tokens import TokenBlockSequence, compute_block_hashes
-from .model_runner import ModelRunner, bucket_table_width, take_builds
+from ..models.config import CachePlan, cache_plan
+from .model_runner import (
+    ModelRunner,
+    PrefillRow,
+    bucket_table_width,
+    take_builds,
+)
 from .pages import PageAllocation, PagePool, WindowLease, WindowPool
 from .spec import BlockLookahead, NGramProposer, SlotSpec, propose_for
 
@@ -316,31 +322,27 @@ class InferenceScheduler:
             if kvbm is not None:
                 kvbm.notify_stored(hashes, parent)
 
-        # A model with recurrent state (Mamba-2 layers): its per-slot
-        # state lives in the runner, indexed by the slot a sequence holds
-        # here. A row that prefills from position 0 starts from zero
-        # state, so admission resets nothing; a prefix hit would skip
-        # tokens the state has to see, so none is ever taken; a
+        # What the model's cache is and cannot do (`models.config.
+        # cache_plan`; a stub runner without a configuration has the
+        # plain one). Per-slot state lives in the runner, indexed by the
+        # slot a sequence holds here: a row that prefills from position 0
+        # starts from zero state, so admission resets nothing, and a
         # preempted request resumes by recomputation (cooperative
-        # migrate), never from parked pages.
+        # migrate), never from parked pages. Where a prefix hit cannot
+        # be taken (it would skip tokens a state has to see, or need a
+        # window group's last positions, which nothing keeps) no pool
+        # has a prefix cache and no `stored` event is published. A
+        # second page group is a second pool (`self.pool` stays the full
+        # group's), allocated ahead of each launch and freed behind the
+        # window while a sequence lives.
         model_config = getattr(runner, "model_config", None)
-        self._recurrent = bool(getattr(model_config, "has_recurrent_state",
-                                       False))
-        # A model with window AND full attention layers: a second pool
-        # for the window layers' pages (`self.pool` stays the full
-        # group), allocated ahead of each launch and freed behind the
-        # window while a sequence lives. A prefix hit would need the full
-        # group's pages of the prefix AND the window group's last
-        # positions before it, which nothing keeps: neither pool has a
-        # prefix cache, and no `stored` event is published.
-        self._windowed = bool(getattr(model_config, "has_window_layers",
-                                      False))
+        self.cache_plan = (cache_plan(model_config)
+                           if model_config is not None else CachePlan())
         self.pool = PagePool(cfg.num_pages, on_stored=_stored,
                              on_removed=on_removed,
-                             prefix_cache=not (self._recurrent
-                                               or self._windowed))
+                             prefix_cache=not self.cache_plan.reuse_prefix)
         self.win_pool: Optional[WindowPool] = None
-        if self._windowed:
+        if "window" in self.cache_plan.groups:
             self.win_pool = WindowPool(cfg.window_pages, cfg.page_size,
                                        model_config.sliding_window)
             # positions a decode launch may write past the one it reads
@@ -404,10 +406,13 @@ class InferenceScheduler:
         self._tokens = np.zeros(b, np.int32)
         self._positions = np.zeros(b, np.int32)
         self._tables = np.zeros((b, p), np.int32)
-        if self._windowed:
-            self._win_tables = np.zeros((b, runner.window_table_width),
-                                        np.int32)
-            self._win_base = np.zeros(b, np.int32)
+        # behind the full group's table in every decode launch: a second
+        # page group's table and its base
+        self._win_tables: tuple = ()
+        if self.win_pool is not None:
+            self._win_tables = (
+                np.zeros((b, runner.window_table_width), np.int32),
+                np.zeros(b, np.int32))
         self._kv_lens = np.zeros(b, np.int32)
         self._active = np.zeros(b, bool)
         self._temp = np.ones(b, np.float32)
@@ -739,7 +744,7 @@ class InferenceScheduler:
             emit(EngineOutput(finish_reason="error",
                               error=f"logits processors: {exc}"))
             return None
-        if processors and self._recurrent:
+        if processors and self.cache_plan.state:
             # The host-sampling path regenerates the first token by
             # running the last prompt token AGAIN (an idempotent KV
             # rewrite); a recurrent state would absorb it twice.
@@ -855,7 +860,7 @@ class InferenceScheduler:
                 total_pages = self._page_span(
                     seq.prompt_len, seq.request.sampling.max_tokens,
                     with_slack=False)
-            if self._windowed and seq.window is None:
+            if self.win_pool is not None and seq.window is None:
                 # The window group first (it has nothing to undo): what
                 # a decoding row holds at most, or the whole sequence
                 # where that is less, set aside for as long as it lives.
@@ -1308,8 +1313,8 @@ class InferenceScheduler:
         # Pages the step's sequences held while it ran: taken before the
         # reap returns the finished ones' (at most max_batch slots).
         reserved = self.reserved_pages()
-        win_reserved = (self.window_reserved_pages() if self._windowed
-                        else 0)
+        win_reserved = (self.window_reserved_pages()
+                        if self.win_pool is not None else 0)
         held_slots = sum(s is not None for s in self._slots)
         with _section("sched.reap"):
             self._reap_finished()
@@ -1324,7 +1329,7 @@ class InferenceScheduler:
                 reserved * self.stats.last_step_wall_ms)
             self.stats.window_reserved_page_ms += (
                 win_reserved * self.stats.last_step_wall_ms)
-            if self._recurrent:
+            if self.cache_plan.state:
                 self.stats.state_slot_ms += (
                     held_slots * self.stats.last_step_wall_ms)
             moe = getattr(self.runner, "moe_stats", None)
@@ -1450,19 +1455,18 @@ class InferenceScheduler:
 
     def _launch_takes(self, work: list, seq: _Seq, chunk: int) -> bool:
         """Whether this prefill launch can hold one more row. Any, but
-        where the runner bounds its launches (window or latent layers):
-        the launch's rows x bucket stay inside the token budget; and for
-        a model with window layers the window group's pages for the
-        chunk are taken here: the blocks before the oldest position the
-        chunk's first query sees go back, those up to its last position
-        are allocated. A row that gets none waits for a later launch."""
-        if not getattr(self.runner, "bounds_prefill_launches",
-                       self._windowed):
-            return True
-        if not self.runner.prefill_launch_fits(
-                [c for _, c in work] + [chunk]):
+        where the runner bounds its launches (`ModelRunner.
+        bounds_prefill_launches`): the launch's rows x bucket stay
+        inside the token budget; and where there is a window group its
+        pages for the chunk are taken here: the blocks before the oldest
+        position the chunk's first query sees go back, those up to its
+        last position are allocated. A row that gets none waits for a
+        later launch."""
+        if (getattr(self.runner, "bounds_prefill_launches", False)
+                and not self.runner.prefill_launch_fits(
+                    [c for _, c in work] + [chunk])):
             return False
-        if not self._windowed:
+        if self.win_pool is None:
             return True
         pos = seq.prefill_pos
         return self.win_pool.advance(
@@ -1475,16 +1479,19 @@ class InferenceScheduler:
         at once, so that between launches a row holds the window and no
         more. Programs run in the order they were launched, so whoever
         gets these pages writes them after this chunk has read them."""
-        if self._windowed:
+        if self.win_pool is not None:
             # one position more than the next chunk reads: a sequence
             # with logits processors runs its last prompt token again
             self.win_pool.advance(
                 seq.window, max(0, seq.prefill_pos - self.win_pool.window),
                 seq.prefill_pos - 1, "prefill")
 
-    def _window_arg(self, seq: _Seq) -> tuple:
+    def _window_arg(self, seq: _Seq) -> Optional[tuple]:
         """A prefill row's window group: its pages from its first held
-        block on, and that block's first position."""
+        block on, and that block's first position. None where the cache
+        has one page group."""
+        if seq.window is None:
+            return None
         return (seq.window.pages, seq.window.first * self.page_size)
 
     def _can_batch_prefill(self, work: list) -> bool:
@@ -1536,9 +1543,7 @@ class InferenceScheduler:
                 lora_idx=seq.lora_idx,
                 chunk_embeds=chunk_embeds,
                 return_device=deferred_readback,
-                **({"slot": seq.slot} if self._recurrent else {}),
-                **({"window": self._window_arg(seq)} if self._windowed
-                   else {}),
+                slot=seq.slot, window=self._window_arg(seq),
             )
         self._stamp_builds((seq,))
         if not deferred_readback:
@@ -1599,14 +1604,11 @@ class InferenceScheduler:
                     np.int32,
                 )
                 s = seq.request.sampling
-                rows.append((tokens, seq.prefill_pos, seq.block_table,
-                             seq.prefill_pos + chunk,
-                             (s.temperature, s.top_p, s.top_k, seq.seed),
-                             seq.lora_idx,
-                             *((seq.slot,) if self._recurrent
-                               or self._windowed else ()),
-                             *((self._window_arg(seq),) if self._windowed
-                               else ())))
+                rows.append(PrefillRow(
+                    tokens, seq.prefill_pos, seq.block_table,
+                    seq.prefill_pos + chunk,
+                    (s.temperature, s.top_p, s.top_k, seq.seed),
+                    seq.lora_idx, seq.slot, self._window_arg(seq)))
         want_samples = any(
             final and seq.request.sampling.logprobs
             for final, (seq, _) in zip(finals, work))
@@ -1805,7 +1807,7 @@ class InferenceScheduler:
                 self._tokens[i] = seq.last_token
                 self._positions[i] = seq.kv_len - 1  # position of last_token
                 self._tables[i] = seq.block_table
-                if self._windowed:
+                if seq.window is not None:
                     self._advance_decode_window(seq)
                 self._kv_lens[i] = seq.kv_len
                 self._active[i] = True
@@ -1835,9 +1837,7 @@ class InferenceScheduler:
         need = -(-max_kv // self.page_size)
         width = bucket_table_width(need,
                                    self.runner.config.max_pages_per_seq)
-        tables = self._tables[:, :width]
-        if self._windowed:
-            tables = (tables, self._win_tables, self._win_base)
+        tables = (self._tables[:, :width], *self._win_tables)
         if block > 1:
             if prefill_pending:
                 self.stats.fused_steps_with_prefill += 1
@@ -1886,10 +1886,11 @@ class InferenceScheduler:
                 f"window group: a decoding row found no page inside its "
                 f"reservation ({len(lease.pages)} held, {lease.reserved} "
                 "reserved)")
-        row = self._win_tables[seq.slot]
+        win_tables, win_base = self._win_tables
+        row = win_tables[seq.slot]
         row[:] = 0
         row[:len(lease.pages)] = lease.pages
-        self._win_base[seq.slot] = lease.first * self.page_size
+        win_base[seq.slot] = lease.first * self.page_size
 
     def _drain_decode(self, pending) -> int:
         """Decode phase 2: read the fused block(s) back and append tokens.
@@ -2463,7 +2464,8 @@ class InferenceScheduler:
             params = None
             if (register_handoff is not None and seq.decode_ready
                     and seq.generated and not seq.processors
-                    and not seq.first_deferred and not self._recurrent):
+                    and not seq.first_deferred
+                    and not self.cache_plan.move_pages):
                 # KV present on device: positions 0..kv_len-2 (the same
                 # computed-page math as preempt-to-KVBM).
                 computed = seq.kv_len - 1

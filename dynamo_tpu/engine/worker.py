@@ -45,6 +45,7 @@ from ..llm.protocols import (
     StopConditions,
 )
 from ..models import get_config
+from ..models.config import cache_plan
 from ..parallel import MeshConfig, make_mesh
 from ..perf.steptrace import LiveRoofline
 from ..runtime import DistributedRuntime, new_instance_id
@@ -175,46 +176,34 @@ def recurrent_state_refusals(model_config, *, mode: str = "aggregated",
                              weight_dtype: str = "model",
                              kv_dtype: str = "model",
                              devices: int = 1) -> None:
-    """What a model with recurrent state (Mamba-2 or gated short
-    convolution layers; a hybrid stack) cannot be served with yet,
-    refused at start with the flag and the reason, never answered
-    wrongly later: each of these paths moves or
-    reuses KV pages, or shards the step, and none of them carries the
-    per-slot state that the pages are useless without. A hybrid stack
-    with window layers is refused the same paths for its second page
-    group's sake (`window_layer_refusals`), one with latent attention
-    for its single-stack pool's (`latent_layer_refusals`)."""
-    cfg = model_config
-    if not cfg.is_hybrid:
-        return
-    from ..models.hybrid import (
-        hybrid_refusals,
-        latent_layer_refusals,
-        window_layer_refusals,
-    )
-
-    hybrid_refusals(cfg, weight_dtype, kv_dtype, devices)
-    window_layer_refusals(cfg, mode=mode, kvbm=kvbm, spec=spec)
-    latent_layer_refusals(cfg, mode=mode, kvbm=kvbm, spec=spec)
-    if not cfg.has_recurrent_state:
-        return
-    what = f"{cfg.name} (layers {cfg.layer_pattern})"
-    if mode != "aggregated":
-        raise ValueError(
-            f"--mode {mode}: disaggregated prefill/decode hands over KV "
-            f"pages (engine/ici_transfer.py, llm/kv_transfer.py); {what} "
-            "keeps recurrent state per slot and no state snapshot travels "
-            "with them")
-    if kvbm:
-        raise ValueError(
-            f"--kvbm-host-blocks/--kvbm-disk-blocks: KVBM offloads and "
-            f"onboards KV pages by prefix hash; {what} cannot resume from "
-            "pages without the recurrent state behind them")
-    if spec:
-        raise ValueError(
-            f"DYNT_SPEC_ENABLE: speculative verification (engine/spec.py) "
-            f"rolls rejected positions back by length; the recurrent state "
-            f"of {what} cannot be rolled back")
+    """What a model's cache cannot be served with yet, refused at start
+    with the flag and the reason, never answered wrongly later. The
+    configuration says what its cache cannot do and why
+    (`models.config.cache_plan`: recurrent state per slot, a second page
+    group, a single-stack latent pool); here each flag that is set asks
+    the one trait it needs. A dense stack's plan refuses nothing but an
+    int8 pool of latents."""
+    plan = cache_plan(model_config)
+    for asked, trait, flag in (
+            (weight_dtype != "model", "quantized_weights",
+             f"--weight-dtype {weight_dtype}"),
+            (kv_dtype != "model", "int8_pool", f"--kv-dtype {kv_dtype}"),
+            (devices > 1, "shard",
+             f"--tp/--sp/--dp over {devices} devices"),
+            (mode != "aggregated", "move_pages",
+             f"--mode {mode}: disaggregated prefill/decode hands over KV "
+             "pages by block index (engine/ici_transfer.py, "
+             "llm/kv_transfer.py)"),
+            (kvbm, "move_pages",
+             "--kvbm-host-blocks/--kvbm-disk-blocks: KVBM offloads and "
+             "onboards KV pages by prefix hash"),
+            (spec, "score_positions",
+             "DYNT_SPEC_ENABLE: speculative verification (engine/spec.py) "
+             "scores k+1 positions in one step and rolls rejected ones "
+             "back by length")):
+        why = getattr(plan, trait)
+        if asked and why:
+            raise ValueError(f"{flag}: {why}")
 
 
 def _runner_config(args) -> RunnerConfig:
@@ -713,7 +702,7 @@ class TpuWorker:
                  "".join(f" {slot}={paths[slot]}"
                          for slot in ("ssm_update", "ssm_scan", "expert_gmm")
                          if slot in paths), native)
-        if self.model_config.has_recurrent_state:
+        if self.runner.cache_plan.state:
             from ..models.hybrid import state_slot_bytes
 
             # what --max-batch costs a model whose cache is mostly state

@@ -2,6 +2,7 @@
 
 from .config import ModelConfig, PRESETS, get_config
 from .transformer import (
+    DenseSteps,
     forward,
     forward_embed,
     init_params,
@@ -13,6 +14,18 @@ from .transformer import (
     write_kv_pages,
 )
 
+
+
+def make_steps(config: ModelConfig, kernels: dict, attention_fn=None):
+    """The adapter between `ModelRunner`'s step programs and `config`'s
+    stack: the one place that asks which kind of stack it is."""
+    if config.layer_pattern:
+        from .hybrid import HybridSteps
+
+        return HybridSteps(config, kernels, attention_fn)
+    return DenseSteps(config, kernels, attention_fn)
+
+
 __all__ = [
     "ModelConfig",
     "PRESETS",
@@ -21,6 +34,7 @@ __all__ = [
     "get_config",
     "init_params",
     "make_kv_cache",
+    "make_steps",
     "paged_attention_xla",
     "param_axes",
     "rms_norm",

@@ -153,19 +153,6 @@ class ModelConfig:
                                       ("logits_scaling", 1.0))
                 if getattr(self, name) != default}
 
-    @property
-    def has_recurrent_state(self) -> bool:
-        """State that a prefix of KV pages cannot stand for: prefix reuse,
-        KV transfer, offload and speculation need a state snapshot too."""
-        return bool(self.state_layers)
-
-    @property
-    def has_window_layers(self) -> bool:
-        """Window and full attention side by side: two page groups, and
-        whatever moves or reuses pages by prefix is refused
-        (`hybrid_refusals`, `window_layer_refusals`)."""
-        return "W" in self.layer_pattern
-
     def layer_kind(self, layer_idx: int) -> str:
         return self.layer_pattern[layer_idx] if self.layer_pattern else "*"
 
@@ -300,6 +287,105 @@ class ModelConfig:
             return -(-width // 128) * 128 if self.has_latent_layers \
                 else width
         return self.head_dim * self.kv_heads_per_lane_tile
+
+
+@dataclasses.dataclass(frozen=True)
+class CachePlan:
+    """What a configuration's cache is, and what it cannot do: the one
+    place that says so. `ModelRunner` builds the cache from `groups` and
+    `state`; the scheduler, the worker's flags
+    (`engine.worker.recurrent_state_refusals`) and every feature that
+    moves, reuses or re-scores pages ask a trait and never which family
+    the model is. A trait is "" where the cache can, else the reason in
+    words, naming the model."""
+
+    # page groups by name, one pool and one block table each: "full" (a
+    # page for every 16 positions of a sequence) and, for a stack with
+    # window layers, "window" (the last `sliding_window` positions)
+    groups: tuple[str, ...] = ("full",)
+    # whether a slot keeps state beside its pages: a conv carry or an
+    # SSM state a state layer (`models.hybrid.make_state_cache`)
+    state: bool = False
+    # have a prefix of its pages found again and reused
+    reuse_prefix: str = ""
+    # have pages moved between workers or tiers: disaggregated transfer,
+    # KVBM offload and onboard, parking a preempted sequence
+    move_pages: str = ""
+    # be scored at several positions a step and rolled back by length
+    # (speculative verification)
+    score_positions: str = ""
+    # be sharded over devices
+    shard: str = ""
+    # hold an int8 pool; run over int8 or int4 weights
+    int8_pool: str = ""
+    quantized_weights: str = ""
+    # whether a prefill launch's rows x bucket stay inside the token
+    # budget (`ModelRunner.bounds_prefill_launches`): "always", "carried"
+    # (only where a context runs past one launch, its state carried from
+    # launch to launch), or "" for never
+    launch_bound: str = ""
+
+
+def cache_plan(config: ModelConfig) -> CachePlan:
+    """The plan of `config`'s cache, a pure function of the
+    configuration. A dense stack's is one page group and nothing it
+    cannot do but hold an int8 pool where it caches latents (MLA). A
+    `layer_pattern` stack's says what its kinds of layer bring: window
+    layers a second page group, Mamba-2 or short-conv layers per-slot
+    state, latent layers a single-stack pool; where several would refuse
+    the same thing, the first of those in that order gives the reason."""
+    if not config.layer_pattern:
+        return CachePlan(int8_pool=(
+            f"int8 KV targets standard-attention models ({config.name}: "
+            "MLA's latent cache is already compact)"
+            if config.is_mla else ""))
+    what = f"{config.name} (layers {config.layer_pattern})"
+    windowed, latent = "W" in config.layer_pattern, config.has_latent_layers
+    stateful = bool(config.state_layers)
+    have = " and ".join(
+        name for kind, name in (("M", "Mamba-2"), ("C", "short-conv"),
+                                ("E", "expert"), ("L", "latent-attention"))
+        if kind in config.layer_pattern)
+    window_group = (f"{what} keeps two page groups, and the window group "
+                    f"only a sequence's last {config.sliding_window} "
+                    "positions (what lay behind is freed)")
+    latent_pool = (f"{what} keeps a single-stack latent pool "
+                   f"({config.kv_cache_head_dim} values a token)")
+    return CachePlan(
+        groups=("full", "window") if windowed else ("full",),
+        state=stateful,
+        reuse_prefix=(
+            f"{window_group}: a prefix hit needs the full group's pages of "
+            "the prefix AND the window group's last positions before it, "
+            "which nothing keeps" if windowed else
+            f"{what} keeps recurrent state per slot: a prefix hit would "
+            "skip tokens the state has to see" if stateful else ""),
+        move_pages=(
+            f"{window_group}: the full group's pages alone resume from "
+            "half a cache, and no tier keeps the other half" if windowed
+            else
+            f"{latent_pool}; page bundles are K and V per kv head "
+            "(ops/block_copy.py), and no hand-over or tier of latent "
+            "pages is built or tested" if latent else
+            f"{what} keeps recurrent state per slot and no state snapshot "
+            "travels with its pages: pages alone resume nothing"
+            if stateful else ""),
+        score_positions=(
+            f"{window_group}, which has no multi-position decode path"
+            if windowed else
+            f"{latent_pool}, whose absorbed decode path scores one "
+            "position a step" if latent else
+            f"the recurrent state of {what} cannot be rolled back"
+            if stateful else ""),
+        shard=(f"the per-slot state and the experts of {what} are not "
+               "sharded yet (no expert exchange, no sharded scan)"),
+        int8_pool=("the int8 pool is not wired into the hybrid decode "
+                   f"path of {what}"),
+        quantized_weights=(
+            "models/quantize.py packs the dense decoder's projections "
+            f"only; {what} has {have} matrices it has no layout for"),
+        launch_bound=("always" if windowed or latent
+                      else "carried" if stateful else ""))
 
 
 def _blocks(token_mixers: str, dense: int) -> str:

@@ -132,92 +132,6 @@ PREFILL_SCORE_POSITIONS = 2048
 FULL_TABLE_PAGES = (64, 128, 256)
 
 
-def hybrid_refusals(config: ModelConfig, weight_dtype: str = "model",
-                    kv_dtype: str = "model", devices: int = 1) -> None:
-    """What the step programs of a hybrid stack cannot run with yet,
-    refused by flag and reason (engine/worker.py refuses them before a
-    process starts, `ModelRunner` when it is built)."""
-    what = f"{config.name} (layers {config.layer_pattern})"
-    if weight_dtype != "model":
-        have = " and ".join(
-            name for kind, name in (("M", "Mamba-2"), ("C", "short-conv"),
-                                    ("E", "expert"),
-                                    ("L", "latent-attention"))
-            if kind in config.layer_pattern)
-        raise ValueError(
-            f"--weight-dtype {weight_dtype}: models/quantize.py packs the "
-            f"dense decoder's projections only; {what} has {have} matrices "
-            "it has no layout for")
-    if kv_dtype != "model":
-        raise ValueError(
-            f"--kv-dtype {kv_dtype}: the int8 pool is not wired into the "
-            f"hybrid decode path of {what}")
-    if devices > 1:
-        raise ValueError(
-            f"--tp/--sp/--dp over {devices} devices: the per-slot state "
-            f"and the experts of {what} are not sharded yet (no expert "
-            "exchange, no sharded scan)")
-
-
-def window_layer_refusals(config: ModelConfig, *, mode: str = "aggregated",
-                          kvbm: bool = False, spec: bool = False) -> None:
-    """What a model with window AND full attention layers is refused, by
-    flag and reason: its cache is two page groups, and the window group
-    keeps only the last `sliding_window` positions of a sequence, so
-    whatever finds, moves or rewinds pages by a prefix of the full
-    group's alone would resume from half a cache. (A prefix hit is never
-    taken either: `InferenceScheduler` builds both pools without a
-    prefix cache and publishes no `stored` event.)"""
-    if not config.has_window_layers:
-        return
-    what = (f"{config.name} (layers {config.layer_pattern}, window "
-            f"{config.sliding_window})")
-    if mode != "aggregated":
-        raise ValueError(
-            f"--mode {mode}: disaggregated prefill/decode hands over the "
-            f"pages of ONE pool by block index; {what} keeps a second page "
-            "group whose pages behind the window are already freed")
-    if kvbm:
-        raise ValueError(
-            f"--kvbm-host-blocks/--kvbm-disk-blocks: KVBM offloads and "
-            f"onboards pages by prefix hash; a hit on {what} needs the "
-            "full group's pages of the prefix AND the window group's last "
-            f"{config.sliding_window} positions, which no tier keeps")
-    if spec:
-        raise ValueError(
-            f"DYNT_SPEC_ENABLE: speculative verification scores k+1 "
-            f"positions in one step over one table; the window group of "
-            f"{what} has no multi-position decode path")
-
-
-def latent_layer_refusals(config: ModelConfig, *, mode: str = "aggregated",
-                          kvbm: bool = False, spec: bool = False) -> None:
-    """What a `layer_pattern` model with latent attention is refused, by
-    flag and reason. Its pool is one stack of latent rows: the step
-    programs here read and write it, and nothing that moves pages
-    between workers or tiers, or scores several positions a step, has
-    been built or tested over it."""
-    if not config.has_latent_layers:
-        return
-    what = (f"{config.name} (layers {config.layer_pattern}: a single-stack "
-            f"latent pool, {config.kv_cache_head_dim} values a token)")
-    if mode != "aggregated":
-        raise ValueError(
-            f"--mode {mode}: disaggregated prefill/decode hands over pages "
-            "as K and V bundles (engine/ici_transfer.py, "
-            f"llm/kv_transfer.py); no hand-over of {what} is tested")
-    if kvbm:
-        raise ValueError(
-            f"--kvbm-host-blocks/--kvbm-disk-blocks: KVBM's block layout "
-            f"(ops/block_copy.py) is K and V per kv head; no tier has "
-            f"held a page of {what}")
-    if spec:
-        raise ValueError(
-            f"DYNT_SPEC_ENABLE: speculative verification scores k+1 "
-            f"positions in one step (models/transformer.forward_spec); "
-            f"the absorbed decode path of {what} scores one")
-
-
 # ---------------------------------------------------------------------------
 # parameters
 # ---------------------------------------------------------------------------
@@ -1331,3 +1245,94 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
         kv_cache = (kv_cache, win_cache)
     state = {"conv": conv_out, "ssm": ssm_out}
     return kv_cache, state, _head(x, params, config)[:, None, :], stats
+
+
+# ---------------------------------------------------------------------------
+# The stack behind ModelRunner's step programs
+# ---------------------------------------------------------------------------
+
+
+class HybridSteps:
+    """A `layer_pattern` stack as `ModelRunner`'s step programs call
+    every stack (the one signature: models/transformer.DenseSteps).
+    `cache` = (pools, state): `pools` is `(full,)`, or `(full, window)`
+    for a stack with window layers, whose `tables` are then (full
+    tables, window tables, window base [B]); `state` is
+    `make_state_cache`'s. Every step gives its experts' statistics back
+    (`moe_stats_size` counters). Nothing here scores several positions a
+    step (`cache_plan`: `score_positions`), and no adapter has targets
+    on these layers."""
+
+    spec = spec_attention_fn = None
+    lora = False
+
+    def __init__(self, config: ModelConfig, kernels: dict,
+                 attention_fn=None) -> None:
+        from ..ops import kernel_path
+
+        self.config = config
+        user = attention_fn is not None
+        self.attention_fn = attention_fn if user else kernels["prefill"]
+        # a single-stack latent pool has a decode kernel of its own
+        self.decode_attention_fn = None if user else kernels[
+            "decode_latent" if config.has_latent_layers else "decode"]
+        # page groups whose prefill layers run through `attention_fn`: a
+        # latent layer's prefill rebuilds keys and values from the pool
+        # and scores them itself
+        self.attention_groups = () if config.has_latent_layers else tuple(
+            group for group, layers in (("full", config.kv_layers),
+                                        ("window", config.window_kv_layers))
+            if layers)
+        self.ssm_path = kernel_path("DYNT_SSM")
+        self.gmm_path = kernel_path("DYNT_MOE_GMM")
+        self.stats_size = moe_stats_size(config)
+
+    def make_state(self, slots: int) -> dict:
+        return make_state_cache(self.config, slots)
+
+    def kernel_paths(self) -> dict:
+        """This stack's own slots of `ModelRunner.kernel_paths`: the
+        decode state update and, where it has Mamba layers, the prefill
+        scan (both DYNT_SSM; a launch whose shapes the scan kernel
+        refuses still takes the XLA form:
+        dynamo_ssm_scan_launches_total) and the experts' grouped matmul
+        (DYNT_MOE_GMM)."""
+        paths = {"ssm_update": self.ssm_path}
+        if self.config.ssm_layers:
+            paths["ssm_scan"] = self.ssm_path
+        return {**paths, "expert_gmm": self.gmm_path}
+
+    @staticmethod
+    def _unpack(pools, tables):
+        """(full pool, full tables, `forward_hybrid*`'s `window=`) of the
+        runner's tuples."""
+        if len(pools) == 1:
+            (kv,), (table,) = pools, tables
+            return kv, table, None
+        (kv, win), (table, win_tables, win_base) = pools, tables
+        return kv, table, (win, win_tables, win_base)
+
+    def prefill(self, params, cache, tokens, positions, tables, kv_lens,
+                valid, last_idx, slots, lora=None, lora_idx=None,
+                extra_embeds=None):
+        """A chunk a row: (cache, logits [B, V] of each row's `last_idx`
+        only, so that no [rows x T, vocab] float32 exists on this path,
+        moe stats)."""
+        pools, state = cache
+        kv, table, window = self._unpack(pools, tables)
+        kv, state, last, stats = forward_hybrid(
+            params, self.config, tokens, positions, kv, state, slots, table,
+            kv_lens, valid, last_idx, attention_fn=self.attention_fn,
+            gmm_path=self.gmm_path, window=window, ssm_path=self.ssm_path)
+        return (kv if window is not None else (kv,), state), last, stats
+
+    def decode(self, params, cache, tokens, positions, tables, kv_lens,
+               active, lora=None, lora_idx=None):
+        """One token a slot: (cache, logits [B, 1, V], moe stats)."""
+        pools, state = cache
+        kv, table, window = self._unpack(pools, tables)
+        kv, state, logits, stats = forward_hybrid_decode(
+            params, self.config, tokens, positions, kv, state, table,
+            kv_lens, active, decode_attention_fn=self.decode_attention_fn,
+            ssm_path=self.ssm_path, gmm_path=self.gmm_path, window=window)
+        return (kv if window is not None else (kv,), state), logits, stats

@@ -1663,3 +1663,102 @@ def forward(
     head = params["embed"].T if config.tie_embeddings else params["lm_head"]
     logits = _mm("bth,hv->btv", x, head, rows).astype(jnp.float32)
     return kv_cache, logits
+
+
+# ---------------------------------------------------------------------------
+# The stack behind ModelRunner's step programs
+# ---------------------------------------------------------------------------
+
+
+class DenseSteps:
+    """The dense decoder as `ModelRunner`'s step programs call every
+    stack (a `layer_pattern` one: models/hybrid.HybridSteps): one
+    signature, `cache` = (pools, state) in and out, `tables` a tuple with
+    an entry a page group, and what the sampler needs back beside the
+    experts' statistics. Here `pools` is `(kv,)` (an int8 pool's (values,
+    scales) inside its entry), `state` is None, `slots` goes unread and
+    the statistics are None: no leaf more than the forwards take.
+
+    `kernels`: what the runner's mesh and backend run in each attention
+    slot (prefill, decode, decode_latent, spec; None for the XLA form).
+    A caller's own `attention_fn` takes every path instead."""
+
+    stats_size = 0  # no dropless experts
+    lora = True  # `lora_target_dims` names this stack's adapter targets
+
+    def __init__(self, config: ModelConfig, kernels: dict,
+                 attention_fn=None) -> None:
+        self.config = config
+        user = attention_fn is not None
+        # gpt-oss: sink + sliding-window attention lives in the unified
+        # forward (the Pallas kernels don't model sinks), which ignores
+        # attention_fn.
+        own = user or config.is_gptoss
+        self.attention_fn = attention_fn if own else kernels["prefill"]
+        self.decode_attention_fn = None if own else kernels["decode"]
+        self.spec_attention_fn = (None if own or config.is_mla
+                                  else kernels["spec"])
+        # page groups whose prefill layers run through `attention_fn`
+        self.attention_groups = (() if config.is_mla or config.is_gptoss
+                                 else ("full",))
+        # Deferred-write decode (2 batched scatters per step for all layers
+        # instead of 2 per layer) measured ~12x faster than the unified
+        # path with the Pallas flash-decode kernel on v5e — it is the
+        # default. A USER-SUPPLIED attention_fn still wins (tests inject
+        # reference kernels); MLA keeps the unified path (its latent cache
+        # is a single stack, so the scatter count is already minimal).
+        self.fast_decode = not (config.is_mla or config.is_gptoss or user)
+        # `forward_spec` covers what `forward_decode` does: MLA's latent
+        # cache and gpt-oss's sink attention keep per-token paths, and
+        # verification targets drawn from other attention semantics than
+        # the injected kernel's would silently diverge from it.
+        self.spec = self._spec if self.fast_decode else None
+
+    def make_state(self, slots: int) -> None:
+        return None
+
+    def kernel_paths(self) -> dict:
+        return {}
+
+    def prefill(self, params, cache, tokens, positions, tables, kv_lens,
+                valid, last_idx, slots=None, lora=None, lora_idx=None,
+                extra_embeds=None):
+        """A chunk a row: (cache, logits [B, V] of each row's `last_idx`,
+        None). `extra_embeds` is handed in by a multimodal engine only."""
+        ((kv,), state), (table,) = cache, tables
+        kv, logits = forward(
+            params, self.config, tokens, positions, kv, table, kv_lens,
+            valid=valid, attention_fn=self.attention_fn, lora=lora,
+            lora_idx=lora_idx, extra_embeds=extra_embeds,
+            extra_mask=(None if extra_embeds is None
+                        else tokens == self.config.image_token_id))
+        last = jnp.take_along_axis(
+            logits, last_idx[:, None, None], axis=1)[:, 0, :]
+        return ((kv,), state), last, None
+
+    def decode(self, params, cache, tokens, positions, tables, kv_lens,
+               active, lora=None, lora_idx=None):
+        """One token a slot: (cache, logits [B, 1, V], None)."""
+        ((kv,), state), (table,) = cache, tables
+        if self.fast_decode:
+            kv, logits = forward_decode(
+                params, self.config, tokens, positions, kv, table,
+                kv_lens, active, lora=lora, lora_idx=lora_idx,
+                decode_attention_fn=self.decode_attention_fn)
+        else:
+            kv, logits = forward(
+                params, self.config, tokens[:, None], positions[:, None],
+                kv, table, kv_lens, valid=active[:, None],
+                attention_fn=self.attention_fn, lora=lora,
+                lora_idx=lora_idx)
+        return ((kv,), state), logits, None
+
+    def _spec(self, params, cache, tokens, positions, tables, kv_lens,
+              active, lora=None, lora_idx=None):
+        """t candidate positions a slot: (cache, logits [B, t, V])."""
+        ((kv,), state), (table,) = cache, tables
+        kv, logits = forward_spec(
+            params, self.config, tokens, positions, kv, table, kv_lens,
+            active, lora=lora, lora_idx=lora_idx,
+            spec_attention_fn=self.spec_attention_fn)
+        return ((kv,), state), logits

@@ -349,7 +349,7 @@ class MirroredRunner:
 
         return mirrored
 
-    # kv_cache / params are read by transfer paths via attribute access —
+    # cache / params are read by transfer paths via attribute access —
     # __getattr__ already forwards them. Assignment must hit the inner
     # runner, not this wrapper:
     def __setattr__(self, name, value):

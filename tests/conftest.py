@@ -18,6 +18,15 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 os.environ.setdefault("DYNT_LOG_LEVEL", "WARNING")
+# Each xdist worker keeps its own persistent compile cache: six workers
+# reading and writing one directory lost a worker to a segmentation fault
+# inside jax's cache read (ROADMAP C12 (1), PR 45's run).
+if "PYTEST_XDIST_WORKER" in os.environ:
+    import tempfile
+
+    os.environ["DYNT_COMPILE_CACHE_DIR"] = os.path.join(
+        tempfile.gettempdir(),
+        f"dynamo_tpu_jax_cache-{os.environ['PYTEST_XDIST_WORKER']}")
 
 import pytest
 

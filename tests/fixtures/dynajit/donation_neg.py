@@ -17,12 +17,12 @@ class Engine:
         return jax.jit(lambda kv, t: (kv + t, t), donate_argnums=(0,))
 
     def __init__(self):
-        self.kv_cache = None
+        self.cache = None
 
     def step(self, tokens):
         fn = self._build_step()
-        args = [self.kv_cache, tokens]
-        self.kv_cache, out = fn(*args)  # rebound through the star call
+        args = [self.cache, tokens]
+        self.cache, out = fn(*args)  # rebound through the star call
         return out
 
 

@@ -15,12 +15,12 @@ class Engine:
         return jax.jit(lambda kv, t: (kv + t, t), donate_argnums=(0,))
 
     def __init__(self):
-        self.kv_cache = None
+        self.cache = None
         self._step = self._build_step()
 
     def step(self, tokens):
         fn = self._build_step()
-        out = fn(self.kv_cache, tokens)  # DJ302: donated attr not rebound
+        out = fn(self.cache, tokens)  # DJ302: donated attr not rebound
         return out
 
 

@@ -72,7 +72,7 @@ def test_forward_decode_matches_unified_forward():
     cfg = runner.model_config
     prompt = list(range(1, 7))
     tables = _prefill_two(runner, prompt, list(range(2, 8)))
-    kv0 = runner.kv_cache  # populated by the two prefills
+    (kv0,), _ = runner.cache  # populated by the two prefills
 
     tokens = np.asarray([5, 7, 0, 0], np.int32)
     positions = np.full(4, len(prompt), np.int32)

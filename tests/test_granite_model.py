@@ -24,7 +24,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine import InferenceScheduler, ModelRunner, RunnerConfig
+from dynamo_tpu.engine import (
+    InferenceScheduler,
+    ModelRunner,
+    PrefillRow,
+    RunnerConfig,
+)
 from dynamo_tpu.llm.protocols import (
     EngineOutput,
     PreprocessedRequest,
@@ -267,13 +272,13 @@ def test_a_prompt_in_two_or_three_launches_equals_one(runner, chunks):
     assert whole == parts
     logits = decode_logits(runner, {0: (whole, 53), 1: (parts, 53)})
     np.testing.assert_allclose(logits[0], logits[1], atol=SAME_PROGRAM)
-    assert len(runner.state["ssm"]) == 9  # a state a Mamba MIXER
+    assert len(runner.cache[1]["ssm"]) == 9  # a state a Mamba MIXER
     for layer in range(9):
-        np.testing.assert_allclose(runner.state["ssm"][layer][0],
-                                   runner.state["ssm"][layer][1],
+        np.testing.assert_allclose(runner.cache[1]["ssm"][layer][0],
+                                   runner.cache[1]["ssm"][layer][1],
                                    atol=SAME_PROGRAM)
-        np.testing.assert_allclose(runner.state["conv"][layer][0],
-                                   runner.state["conv"][layer][1],
+        np.testing.assert_allclose(runner.cache[1]["conv"][layer][0],
+                                   runner.cache[1]["conv"][layer][1],
                                    atol=SAME_PROGRAM)
 
 
@@ -289,8 +294,8 @@ def test_a_batch_of_fresh_and_continued_rows_equals_each_alone(runner):
     prefill(runner, prompts[1][:32], slot=1)
     before = (dict(runner.ssm_prefill_positions),
               dict(runner.ssm_prefill_rows))
-    rows = [(np.asarray(p[start:], np.int32), start, table_for(slot),
-             len(p), GREEDY, 0, slot)
+    rows = [PrefillRow(np.asarray(p[start:], np.int32), start,
+                       table_for(slot), len(p), GREEDY, 0, slot)
             for slot, (p, start) in enumerate(zip(prompts, (0, 32, 0)))]
     tokens = np.asarray(runner.prefill_chunk_batch(rows))
     assert tokens[:3].tolist() == alone
@@ -324,12 +329,12 @@ def test_the_fused_block_equals_single_steps(runner):
     for i in range(8):
         token = int(runner.decode(*batch(token, 12 + i), *args)[1])
         singles.append(token)
-    state_after = [np.asarray(s[1]) for s in runner.state["ssm"]]
+    state_after = [np.asarray(s[1]) for s in runner.cache[1]["ssm"]]
     assert prefill(runner, prompt, slot=1) == first  # from zero again
     fused = runner.decode_multi(*batch(first, 12), *args, k=8)
     assert fused[:, 1].tolist() == singles
     for layer, want in enumerate(state_after):
-        np.testing.assert_allclose(runner.state["ssm"][layer][1], want,
+        np.testing.assert_allclose(runner.cache[1]["ssm"][layer][1], want,
                                    atol=SAME_PROGRAM)
 
 
@@ -466,31 +471,6 @@ def test_the_dense_decoder_refuses_a_multiplier_by_name():
             ModelRunner(config, RunnerConfig(page_size=PAGE, num_pages=16,
                                              max_batch=2),
                         make_mesh(MeshConfig()))
-
-
-REFUSALS = {
-    "disagg-prefill": (dict(mode="prefill"), ["--mode prefill",
-                                              "state snapshot"]),
-    "kvbm": (dict(kvbm=True), ["--kvbm-host-blocks", "recurrent state"]),
-    "speculation": (dict(spec=True), ["DYNT_SPEC_ENABLE", "rolled back"]),
-    "weights-int4": (dict(weight_dtype="int4"), ["--weight-dtype int4",
-                                                 "Mamba-2"]),
-    "kv-int8": (dict(kv_dtype="int8"), ["--kv-dtype int8", "hybrid"]),
-    "tp": (dict(devices=4), ["--tp/--sp", "not sharded"]),
-}
-
-
-@pytest.mark.parametrize("case", sorted(REFUSALS))
-def test_it_is_refused_by_flag_and_reason_as_the_other_hybrids(case):
-    from dynamo_tpu.engine.worker import recurrent_state_refusals
-
-    flags, words = REFUSALS[case]
-    config = get_config("granite-4.0-h-small")
-    with pytest.raises(ValueError) as err:
-        recurrent_state_refusals(config, **flags)
-    assert all(word in str(err.value) for word in words), str(err.value)
-    assert config.name in str(err.value)
-    recurrent_state_refusals(config)  # aggregated, no extras: fine
 
 
 def test_launches_are_bounded_where_a_context_runs_past_one():

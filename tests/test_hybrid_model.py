@@ -21,7 +21,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine import InferenceScheduler, ModelRunner, RunnerConfig
+from dynamo_tpu.engine import (
+    InferenceScheduler,
+    ModelRunner,
+    PrefillRow,
+    RunnerConfig,
+)
 from dynamo_tpu.llm.protocols import (
     EngineOutput,
     PreprocessedRequest,
@@ -188,12 +193,12 @@ def test_a_prompt_prefilled_in_chunks_equals_one_launch(runner, chunks):
     assert whole == parts
     logits = decode_logits(runner, {0: (whole, 23), 1: (parts, 23)})
     np.testing.assert_allclose(logits[0], logits[1], atol=SAME_PROGRAM)
-    for layer in range(len(runner.state["ssm"])):
-        np.testing.assert_allclose(runner.state["ssm"][layer][0],
-                                   runner.state["ssm"][layer][1],
+    for layer in range(len(runner.cache[1]["ssm"])):
+        np.testing.assert_allclose(runner.cache[1]["ssm"][layer][0],
+                                   runner.cache[1]["ssm"][layer][1],
                                    atol=SAME_PROGRAM)
-        np.testing.assert_allclose(runner.state["conv"][layer][0],
-                                   runner.state["conv"][layer][1],
+        np.testing.assert_allclose(runner.cache[1]["conv"][layer][0],
+                                   runner.cache[1]["conv"][layer][1],
                                    atol=SAME_PROGRAM)
 
 
@@ -206,11 +211,11 @@ def test_a_batched_prefill_with_ragged_rows_equals_each_row_alone(runner):
     alone = [prefill(runner, p, slot=i) for i, p in enumerate(prompts)]
     want = decode_logits(runner, {i: (alone[i], len(p))
                                   for i, p in enumerate(prompts)})
-    want_state = [np.asarray(s) for s in runner.state["ssm"]]
+    want_state = [np.asarray(s) for s in runner.cache[1]["ssm"]]
     # again, batched: row 1's first 16 tokens alone, then the batch
     prefill(runner, prompts[1][:16], slot=1)
-    rows = [(np.asarray(p[start:], np.int32), start, table_for(slot),
-             len(p), GREEDY, 0, slot)
+    rows = [PrefillRow(np.asarray(p[start:], np.int32), start,
+                       table_for(slot), len(p), GREEDY, 0, slot)
             for slot, (p, start) in enumerate(zip(prompts, (0, 16, 0)))]
     tokens = np.asarray(runner.prefill_chunk_batch(rows))
     assert tokens[:3].tolist() == alone
@@ -218,8 +223,8 @@ def test_a_batched_prefill_with_ragged_rows_equals_each_row_alone(runner):
                                  for i, p in enumerate(prompts)})
     np.testing.assert_allclose(got[:3], want[:3], atol=SAME_PROGRAM)
     # slot 3 was never written by the padded fourth row
-    assert not np.asarray(runner.state["ssm"][0][3]).any() or np.allclose(
-        runner.state["ssm"][0][3], want_state[0][3])
+    assert not np.asarray(runner.cache[1]["ssm"][0][3]).any() or np.allclose(
+        runner.cache[1]["ssm"][0][3], want_state[0][3])
 
 
 def test_the_fused_block_equals_single_steps(runner):
@@ -240,12 +245,12 @@ def test_the_fused_block_equals_single_steps(runner):
     for i in range(8):
         token = int(runner.decode(*batch(token, 12 + i), *args)[1])
         singles.append(token)
-    state_after = [np.asarray(s[1]) for s in runner.state["ssm"]]
+    state_after = [np.asarray(s[1]) for s in runner.cache[1]["ssm"]]
     assert prefill(runner, prompt, slot=1) == first  # from zero again
     fused = runner.decode_multi(*batch(first, 12), *args, k=8)
     assert fused[:, 1].tolist() == singles
     for layer, want in enumerate(state_after):
-        np.testing.assert_allclose(runner.state["ssm"][layer][1], want,
+        np.testing.assert_allclose(runner.cache[1]["ssm"][layer][1], want,
                                    atol=SAME_PROGRAM)
 
 
@@ -277,11 +282,11 @@ def test_a_reused_slot_starts_from_zero_and_a_preempted_request_resumes(
 def test_a_decode_step_leaves_a_slot_between_two_chunks_alone(runner):
     prompt = prompt_of(24, seed=13)
     prefill(runner, prompt[:16], slot=2)
-    before = [np.asarray(s[2]) for s in runner.state["ssm"]]
+    before = [np.asarray(s[2]) for s in runner.cache[1]["ssm"]]
     other = prefill(runner, prompt_of(6, seed=14), slot=0)
     decode_logits(runner, {0: (other, 6)})  # slot 2 inactive
     for layer, want in enumerate(before):
-        np.testing.assert_array_equal(runner.state["ssm"][layer][2], want)
+        np.testing.assert_array_equal(runner.cache[1]["ssm"][layer][2], want)
 
 
 # -- the expert layer ---------------------------------------------------------
@@ -594,35 +599,6 @@ def test_logits_processors_are_refused_in_band():
 
 
 # -- the refusals ---------------------------------------------------------------
-
-
-REFUSALS = {
-    "disagg-prefill": (dict(mode="prefill"), ["--mode prefill",
-                                              "state snapshot"]),
-    "disagg-decode": (dict(mode="decode"), ["--mode decode", "kv_transfer"]),
-    "kvbm": (dict(kvbm=True), ["--kvbm-host-blocks", "recurrent state"]),
-    "speculation": (dict(spec=True), ["DYNT_SPEC_ENABLE", "rolled back"]),
-    "weights-int8": (dict(weight_dtype="int8"), ["--weight-dtype int8",
-                                                 "quantize.py"]),
-    "weights-int4": (dict(weight_dtype="int4"), ["--weight-dtype int4",
-                                                 "Mamba-2"]),
-    "kv-int8": (dict(kv_dtype="int8"), ["--kv-dtype int8", "hybrid"]),
-    "tp": (dict(devices=4), ["--tp/--sp", "not sharded"]),
-}
-
-
-@pytest.mark.parametrize("case", sorted(REFUSALS))
-def test_a_recurrent_model_is_refused_by_flag_and_reason(case):
-    from dynamo_tpu.engine.worker import recurrent_state_refusals
-
-    flags, words = REFUSALS[case]
-    config = get_config("nemotron3-nano-30b-a3b")
-    with pytest.raises(ValueError) as err:
-        recurrent_state_refusals(config, **flags)
-    assert all(word in str(err.value) for word in words), str(err.value)
-    assert config.name in str(err.value)
-    recurrent_state_refusals(get_config("tiny-test"), **flags)  # dense: fine
-    recurrent_state_refusals(config)  # aggregated, no extras: fine
 
 
 def test_the_modules_refuse_it_too():

@@ -86,13 +86,13 @@ class TestDeviceBundleMovement:
         pre.prefill_chunk(prompt, 0, table, len(prompt), (0.0, 1.0, 0, 0))
 
         src_pages = jnp.asarray([1, 2, 3, 4], jnp.int32)
-        bundle = gather_kv_blocks(pre.kv_cache, src_pages)
+        bundle = gather_kv_blocks(*pre.cache[0], src_pages)
         moved = jax.device_put(bundle, bundle_sharding(dec_mesh))
         dec.scatter_pages(np.array([5, 6, 7, 8], np.int32), moved)
 
         got = np.asarray(jax.device_get(
-            gather_kv_blocks(dec.kv_cache, jnp.asarray([5, 6, 7, 8],
-                                                       jnp.int32))),
+            gather_kv_blocks(*dec.cache[0], jnp.asarray([5, 6, 7, 8],
+                                                        jnp.int32))),
             np.float32)
         want = np.asarray(jax.device_get(bundle), np.float32)
         np.testing.assert_array_equal(got, want)

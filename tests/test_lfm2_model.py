@@ -28,7 +28,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine import InferenceScheduler, ModelRunner, RunnerConfig
+from dynamo_tpu.engine import (
+    InferenceScheduler,
+    ModelRunner,
+    PrefillRow,
+    RunnerConfig,
+)
 from dynamo_tpu.llm.protocols import (
     EngineOutput,
     PreprocessedRequest,
@@ -300,22 +305,22 @@ def test_a_prompt_in_two_or_three_launches_equals_one(runner, chunks):
     kept = []
 
     def idle_step():
-        before = [np.asarray(c[1]) for c in runner.state["conv"]]
+        before = [np.asarray(c[1]) for c in runner.cache[1]["conv"]]
         decode_logits(runner, {3: (other, 6)})  # slot 1 is not active
         kept.extend(np.array_equal(b, np.asarray(c[1])) for b, c in zip(
-            before, runner.state["conv"]))
+            before, runner.cache[1]["conv"]))
 
     parts = prefill(runner, prompt, slot=1, chunks=chunks, between=idle_step)
     assert whole == parts and kept and all(kept)
     logits = decode_logits(runner, {0: (whole, 53), 1: (parts, 53)})
     np.testing.assert_allclose(logits[0], logits[1], atol=SAME_PROGRAM)
     # a carry a conv MIXER and no SSM state at all
-    assert len(runner.state["conv"]) == N_CONV == 6
-    assert runner.state["ssm"] == []
+    assert len(runner.cache[1]["conv"]) == N_CONV == 6
+    assert runner.cache[1]["ssm"] == []
     for layer in range(N_CONV):
-        assert runner.state["conv"][layer].shape == (SLOTS, 2, CONFIG.hidden)
-        np.testing.assert_allclose(runner.state["conv"][layer][0],
-                                   runner.state["conv"][layer][1],
+        assert runner.cache[1]["conv"][layer].shape == (SLOTS, 2, CONFIG.hidden)
+        np.testing.assert_allclose(runner.cache[1]["conv"][layer][0],
+                                   runner.cache[1]["conv"][layer][1],
                                    atol=SAME_PROGRAM)
 
 
@@ -329,7 +334,7 @@ def test_a_reused_slot_starts_from_zero(runner):
     dirty = prefill(runner, prompt_of(40, seed=31), slot=1)
     decode_logits(runner, {1: (dirty, 40)})
     assert prefill(runner, prompt, slot=1) == want
-    for got, ref in zip(runner.state["conv"], clean.state["conv"]):
+    for got, ref in zip(runner.cache[1]["conv"], clean.cache[1]["conv"]):
         np.testing.assert_allclose(got[1], ref[1], atol=SAME_PROGRAM)
 
 
@@ -343,15 +348,15 @@ def test_a_batch_of_fresh_and_continued_rows_equals_each_alone(runner):
     want = decode_logits(runner, {i: (alone[i], len(p))
                                   for i, p in enumerate(prompts)})
     prefill(runner, prompts[1][:32], slot=1)
-    idle = [np.asarray(c[3]) for c in runner.state["conv"]]
+    idle = [np.asarray(c[3]) for c in runner.cache[1]["conv"]]
     before = (dict(runner.ssm_prefill_positions),
               dict(runner.ssm_prefill_rows))
-    rows = [(np.asarray(p[start:], np.int32), start, table_for(slot),
-             len(p), GREEDY, 0, slot)
+    rows = [PrefillRow(np.asarray(p[start:], np.int32), start,
+                       table_for(slot), len(p), GREEDY, 0, slot)
             for slot, (p, start) in enumerate(zip(prompts, (0, 32, 0)))]
     tokens = np.asarray(runner.prefill_chunk_batch(rows))
     assert tokens[:3].tolist() == alone
-    for was, conv in zip(idle, runner.state["conv"]):
+    for was, conv in zip(idle, runner.cache[1]["conv"]):
         np.testing.assert_array_equal(was, np.asarray(conv[3]))
     got = decode_logits(runner, {i: (alone[i], len(p))
                                  for i, p in enumerate(prompts)})
@@ -385,12 +390,12 @@ def test_the_fused_block_equals_single_steps(runner):
     for i in range(8):
         token = int(runner.decode(*batch(token, 12 + i), *args)[1])
         singles.append(token)
-    carry_after = [np.asarray(c[1]) for c in runner.state["conv"]]
+    carry_after = [np.asarray(c[1]) for c in runner.cache[1]["conv"]]
     assert prefill(runner, prompt, slot=1) == first  # from zero again
     fused = runner.decode_multi(*batch(first, 12), *args, k=8)
     assert fused[:, 1].tolist() == singles
     for layer, want in enumerate(carry_after):
-        np.testing.assert_allclose(runner.state["conv"][layer][1], want,
+        np.testing.assert_allclose(runner.cache[1]["conv"][layer][1], want,
                                    atol=SAME_PROGRAM)
 
 
@@ -404,7 +409,7 @@ def test_the_kernels_through_the_runner_give_the_xla_paths_answers(
     each took."""
     prompt = prompt_of(37, seed=40)
     plain = make_runner()
-    assert plain.kv_cache.shape == (2, 2, 96, PAGE, 1, 128)
+    assert plain.cache[0][0].shape == (2, 2, 96, PAGE, 1, 128)
     assert CONFIG.kv_heads_per_lane_tile == 2
     want = prefill(plain, prompt, slot=1, chunks=[32, 5])
     want_logits = decode_logits(plain, {1: (want, 37)})[1]
@@ -418,7 +423,7 @@ def test_the_kernels_through_the_runner_give_the_xla_paths_answers(
     assert runner.prefill_attn_launches == {"kernel": 0, "xla": 2}
     got = decode_logits(runner, {1: (want, 37)})[1]
     np.testing.assert_allclose(got, want_logits, atol=SAME_PROGRAM)
-    for kernel, xla in zip(runner.state["conv"], plain.state["conv"]):
+    for kernel, xla in zip(runner.cache[1]["conv"], plain.cache[1]["conv"]):
         np.testing.assert_allclose(kernel[1], xla[1], atol=SAME_PROGRAM)
 
 
@@ -612,7 +617,7 @@ def test_the_published_preset_and_its_stage():
     assert stage.layer_pattern == "CDCD*ECECECE*ECECECE*ECE"
     assert stage.n_layers == 24 and stage.n_experts == 32
     assert stage.state_layers == (0, 2, 6, 8, 10, 14, 16, 18, 22)
-    assert stage.ssm_layers == () and stage.has_recurrent_state
+    assert stage.ssm_layers == () and stage.state_layers
     assert stage.kv_layers == (4, 12, 20) and stage.window_kv_layers == ()
     from dynamo_tpu.models.hybrid import state_slot_bytes
 
@@ -639,34 +644,6 @@ def test_a_cut_keeps_pangus_one_dense_block_and_this_familys_stage():
     tiny = get_config("tiny-lfm2-test")
     assert tiny.layer_pattern == "CDCD*ECECECE*ECE"
     assert cut_config(tiny, layers=3).layer_pattern == "CDCD*E"
-
-
-REFUSALS = {
-    "disagg-prefill": (dict(mode="prefill"), ["--mode prefill",
-                                              "state snapshot"]),
-    "kvbm": (dict(kvbm=True), ["--kvbm-host-blocks", "recurrent state"]),
-    "speculation": (dict(spec=True), ["DYNT_SPEC_ENABLE", "rolled back"]),
-    "weights-int4": (dict(weight_dtype="int4"), ["--weight-dtype int4",
-                                                 "short-conv"]),
-    "kv-int8": (dict(kv_dtype="int8"), ["--kv-dtype int8", "hybrid"]),
-    "tp": (dict(devices=4), ["--tp/--sp", "not sharded"]),
-}
-
-
-@pytest.mark.parametrize("case", sorted(REFUSALS))
-def test_it_is_refused_by_flag_and_reason_as_the_other_hybrids(case):
-    """Every refusal that recurrent state earns holds for a stack whose
-    only state is a conv carry."""
-    from dynamo_tpu.engine.worker import recurrent_state_refusals
-
-    flags, words = REFUSALS[case]
-    config = get_config("lfm2-8b-a1b")
-    assert config.has_recurrent_state
-    with pytest.raises(ValueError) as err:
-        recurrent_state_refusals(config, **flags)
-    assert all(word in str(err.value) for word in words), str(err.value)
-    assert config.name in str(err.value)
-    recurrent_state_refusals(config)  # aggregated, no extras: fine
 
 
 def test_pages_alone_are_not_handed_over_and_launches_are_bounded(runner):

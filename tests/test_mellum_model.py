@@ -517,29 +517,11 @@ def test_both_page_groups_run_the_prefill_kernel_where_it_admits_them(
 # -- what it is refused ----------------------------------------------------------
 
 
-@pytest.mark.parametrize("case", [
-    ({"mode": "prefill"}, "--mode prefill"),
-    ({"kvbm": True}, "--kvbm-host-blocks"),
-    ({"spec": True}, "DYNT_SPEC_ENABLE"),
-    ({"kv_dtype": "int8"}, "--kv-dtype int8"),
-    ({"weight_dtype": "int4"}, "--weight-dtype int4"),
-    ({"weight_dtype": "int8"}, "--weight-dtype int8"),
-    ({"devices": 4}, "--tp/--sp/--dp"),
-])
-def test_a_model_with_window_layers_is_refused_by_flag_and_reason(case):
-    from dynamo_tpu.engine.worker import recurrent_state_refusals
-
-    flags, said = case
-    with pytest.raises(ValueError, match=said):
-        recurrent_state_refusals(CONFIG, **flags)
-    recurrent_state_refusals(CONFIG)  # and served without them
-
-
 def test_the_runner_wants_a_second_page_group_and_moves_no_pages():
     with pytest.raises(ValueError, match="--window-pages"):
         make_runner(window_pages=0)
     runner = make_runner()
-    full, window = runner.kv_cache
+    (full, window), _ = runner.cache
     assert full.shape[0] == 2 and window.shape[0] == 6
     assert full.shape[2] == 64 and window.shape[2] == 16
     with pytest.raises(RuntimeError, match="two page groups"):

@@ -694,7 +694,7 @@ class TestPagedAttentionDecodePoolTp:
         rc = RunnerConfig(page_size=4, num_pages=32, max_batch=2,
                           max_pages_per_seq=8, prefill_buckets=(16,))
         r_pallas = ModelRunner(get_config("tiny-test"), rc, mesh, seed=0)
-        assert r_pallas._decode_attention_fn is not None
+        assert r_pallas._steps.decode_attention_fn is not None
         monkeypatch.setenv("DYNT_ATTENTION", "xla")
         r_xla = ModelRunner(get_config("tiny-test"), rc, self._mesh(2),
                             seed=0)

@@ -199,7 +199,7 @@ def test_the_absorbed_decode_equals_the_prefill_that_does_not_absorb(runner):
             runner.params, CONFIG, jnp.asarray(prompt)[None], pos, kv, state,
             jnp.asarray([3]), jnp.asarray(table_for(3))[None],
             jnp.asarray([48]), jnp.ones((1, 48), bool), jnp.asarray([47])))(
-        runner.kv_cache, runner.state)
+        *runner.cache[0], runner.cache[1])
     by_decode = row.decode()
     np.testing.assert_allclose(by_decode, np.asarray(by_prefill)[0],
                                atol=VS_REFERENCE)
@@ -442,39 +442,10 @@ def test_the_engine_serves_rows_at_different_contexts(reference):
 # -- what it is refused ----------------------------------------------------------
 
 
-@pytest.mark.parametrize("case", [
-    ({"mode": "prefill"}, "--mode prefill"),
-    ({"mode": "decode"}, "--mode decode"),
-    ({"kvbm": True}, "--kvbm-host-blocks"),
-    ({"spec": True}, "DYNT_SPEC_ENABLE"),
-    ({"kv_dtype": "int8"}, "--kv-dtype int8"),
-    ({"weight_dtype": "int4"}, "latent-attention matrices"),
-    ({"weight_dtype": "int8"}, "--weight-dtype int8"),
-    ({"devices": 4}, "--tp/--sp/--dp"),
-])
-def test_a_model_with_latent_layers_is_refused_by_flag_and_reason(case):
-    from dynamo_tpu.engine.worker import recurrent_state_refusals
-
-    flags, said = case
-    with pytest.raises(ValueError, match=said):
-        recurrent_state_refusals(CONFIG, **flags)
-    recurrent_state_refusals(CONFIG)  # and served without them
-
-
-def test_the_weight_dtype_refusal_names_the_models_own_matrices():
-    from dynamo_tpu.models.hybrid import hybrid_refusals
-
-    for name, have in (("tiny-hybrid-test", "Mamba-2 and expert matrices"),
-                       ("tiny-mellum-test", "has expert matrices"),
-                       ("tiny-pangu-test",
-                        "expert and latent-attention matrices")):
-        with pytest.raises(ValueError, match=have):
-            hybrid_refusals(get_config(name), weight_dtype="int4")
-
-
 def test_the_runner_holds_one_latent_stack_and_moves_no_pages(runner):
-    assert runner.kv_cache.shape == (5, 1, 64, PAGE, 1, 128)
-    assert runner.state["conv"] == [] and not runner.supports_spec
+    (pool,), _ = runner.cache
+    assert pool.shape == (5, 1, 64, PAGE, 1, 128)
+    assert runner.cache[1]["conv"] == [] and not runner.supports_spec
     with pytest.raises(RuntimeError, match="single-stack latent pool"):
         runner.gather_pages_device(np.asarray([1, 2]))
     # a launch's rows x bucket stay inside the token budget

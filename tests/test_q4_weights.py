@@ -616,7 +616,11 @@ class TestRunnerInt4Weights:
         import jax
         import jax.numpy as jnp
 
-        from dynamo_tpu.engine.model_runner import ModelRunner, RunnerConfig
+        from dynamo_tpu.engine.model_runner import (
+            ModelRunner,
+            PrefillRow,
+            RunnerConfig,
+        )
         from dynamo_tpu.models.transformer import forward
         from dynamo_tpu.parallel import MeshConfig, make_mesh
 
@@ -651,7 +655,7 @@ class TestRunnerInt4Weights:
             table[:pages] = np.arange(first, first + pages)
             tables.append(table)
             first += pages
-        rows = [(p, 0, t, len(p), (0.0, 1.0, 0, i), 0)
+        rows = [PrefillRow(p, 0, t, len(p), (0.0, 1.0, 0, i))
                 for i, (p, t) in enumerate(zip(prompts, tables))]
         tokens = np.asarray(batched.prefill_chunk_batch(rows))[:3]
         assert batched.prefill_positions == 4 * 1024
@@ -659,8 +663,8 @@ class TestRunnerInt4Weights:
         alone = [lone.prefill_chunk(*row[:5]) for row in rows]
         assert tokens.tolist() == alone
         # page 0 is the scratch sink padded positions write to
-        assert np.array_equal(np.asarray(batched.kv_cache)[:, :, 1:],
-                              np.asarray(lone.kv_cache)[:, :, 1:])
+        assert np.array_equal(np.asarray(batched.cache[0][0])[:, :, 1:],
+                              np.asarray(lone.cache[0][0])[:, :, 1:])
         # alone, 600 of 1024 skips one block; 40 of 64 takes no map
         assert lone.prefill_row_blocks == {"live": 3 + 1 + 4, "skipped": 1}
 
@@ -682,7 +686,7 @@ class TestRunnerInt4Weights:
             lambda p, kv, valid: forward(
                 p, cfg, step, step, kv, jnp.zeros((4, 64), jnp.int32),
                 jnp.ones(4, jnp.int32), valid=valid))(
-            batched.params, batched.kv_cache, jnp.ones((4, 1), bool))
+            batched.params, *batched.cache[0], jnp.ones((4, 1), bool))
         counts = _prefetch_operands(jaxpr.jaxpr)
         assert counts and not any(counts)
         # while the launch above hands every projection its map
@@ -691,7 +695,7 @@ class TestRunnerInt4Weights:
             lambda p, kv, valid: forward(
                 p, cfg, wide, wide, kv, jnp.zeros((4, 64), jnp.int32),
                 jnp.ones(4, jnp.int32), valid=valid))(
-            batched.params, batched.kv_cache, jnp.ones((4, 1024), bool))
+            batched.params, *batched.cache[0], jnp.ones((4, 1024), bool))
         assert _prefetch_operands(jaxpr.jaxpr) == [1] * 7 * cfg.n_layers
 
     def test_quantized_leaf_structure(self):

@@ -22,7 +22,7 @@ import pathlib
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine import ModelRunner, RunnerConfig
+from dynamo_tpu.engine import ModelRunner, PrefillRow, RunnerConfig
 from dynamo_tpu.models import get_config
 from dynamo_tpu.parallel import MeshConfig, make_mesh
 from dynamo_tpu.runtime.metrics import REGISTRY
@@ -233,7 +233,8 @@ class TestRetraceCanary:
             runner.prefill_chunk(np.full(n, 2, np.int32), 0, table, n, greedy)
         for rows, n in ((2, 5), (3, 12), (4, 20)):
             runner.prefill_chunk_batch(
-                [(np.full(n + i, 3, np.int32), 0, table, n + i, greedy, 0)
+                [PrefillRow(np.full(n + i, 3, np.int32), 0, table, n + i,
+                            greedy)
                  for i in range(rows)])
         ones, zeros = np.ones(b, np.float32), np.zeros(b, np.int32)
         for step, width in enumerate(widths * 2):

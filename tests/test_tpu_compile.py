@@ -15,6 +15,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -31,6 +32,22 @@ def one_chip():
     except Exception as e:  # noqa: BLE001 — no TPU compiler on this box
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to jax's persistent
+    cache and can never be read back without the chip (the next one
+    warns and compiles again): the cache is off around every compile of
+    this file, as the on-chip-measurement guide asks."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
 
 
 # (kv heads, query rows a kv head, int8 pool, table width): the benchmark
@@ -1295,3 +1312,138 @@ def test_lfm2s_step_programs_fit_the_chip_and_copy_nothing_large(
                                         else 1.0e9)
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.75 * 2 ** 30)
+
+
+# -- the adapter between ModelRunner's programs and a stack (PR 47) -----------
+
+SEAM = {
+    "dense": ("tiny-test", {}),
+    "state": ("tiny-hybrid-test", {}),
+    "window": ("tiny-mellum-test", {"window_pages": 16}),
+}
+
+
+@pytest.mark.parametrize("stack", sorted(SEAM))
+def test_the_runners_programs_are_the_forwards_called_directly(one_chip,
+                                                               stack):
+    """A batched prefill program and a fused decode block as
+    `ModelRunner`'s builders make them (the cache one `(pools, state)`
+    pytree, the tables a tuple, `slots` always passed, the model behind
+    its adapter) lower to the text of the stack's forward called
+    directly with the arrays unpacked as the programs took them before
+    PR 47: the adapter adds no parameter, no copy and no operation, and
+    a dense program's unread `slots` is not in it."""
+    from dynamo_tpu.engine import ModelRunner, PrefillRow, RunnerConfig
+    from dynamo_tpu.engine.model_runner import IDLE_WINDOW
+    from dynamo_tpu.engine.sampler import sample, sample_with_logprobs
+    from dynamo_tpu.models import get_config
+    from dynamo_tpu.models.hybrid import (
+        forward_hybrid,
+        forward_hybrid_decode,
+        moe_stats_size,
+    )
+    from dynamo_tpu.models.transformer import forward, forward_decode
+    from dynamo_tpu.parallel import MeshConfig, make_mesh
+    from tools.lowered_text import lowering, normalise
+
+    preset, serve = SEAM[stack]
+    cfg = get_config(preset)
+    hybrid = bool(cfg.layer_pattern)
+    (device,) = one_chip.device_set
+    b, width, block = 4, 8, 2
+    with lowering(device) as built:
+        runner = ModelRunner(
+            cfg, RunnerConfig(page_size=16, num_pages=32, max_batch=b,
+                              max_pages_per_seq=width,
+                              prefill_buckets=(16, 32), **serve),
+            make_mesh(MeshConfig(), [device]))
+        row = PrefillRow(np.zeros(9, np.int32), 0, np.zeros(width, np.int32),
+                         9, (0.0, 1.0, 0, 0), window=IDLE_WINDOW)
+        runner.prefill_chunk_batch([row, row])
+        zeros = np.zeros(b, np.int32)
+        runner.decode_multi(
+            zeros, zeros, runner._idle_tables(b, width), zeros,
+            np.zeros(b, bool), np.zeros(b, np.float32),
+            np.ones(b, np.float32), zeros, np.zeros(b, np.uint32), k=block,
+            return_device=True)
+    rep, (pool_shardings, _) = runner._rep, runner._cache_sharding
+    windowed = len(pool_shardings) == 2
+
+    def unpacked(cache):
+        """The cache as the programs took it before: the pool alone, or
+        (pool, state) for a hybrid stack, a window group riding the pool
+        as a pair."""
+        pools, state = cache
+        kv = pools if windowed else pools[0]
+        return (kv, state) if hybrid else kv
+
+    kv_sharding = unpacked(runner._cache_sharding)
+    if hybrid:
+        kv_sharding = (kv_sharding[0], rep)
+
+    def model(params, cache, window, call):
+        """One forward over the unpacked cache; `call(kv, state,
+        window)` -> (kv, state, logits, stats)."""
+        kv, state = cache if hybrid else (cache, None)
+        if windowed:
+            kv, win = kv
+            window = (win, *window)
+        kv, state, logits, stats = call(kv, state, window or None)
+        return ((kv, state) if hybrid else kv), logits, stats
+
+    def step(params, cache, tokens, positions, table, kv_lens, valid,
+             last_idx, temperature, top_p, top_k, seeds, slots=None,
+             *window):
+        def call(kv, state, window):
+            if hybrid:
+                return forward_hybrid(
+                    params, cfg, tokens, positions, kv, state, slots, table,
+                    kv_lens, valid, last_idx, window=window)
+            kv, logits = forward(params, cfg, tokens, positions, kv, table,
+                                 kv_lens, valid=valid)
+            return kv, None, jnp.take_along_axis(
+                logits, last_idx[:, None, None], axis=1)[:, 0, :], None
+
+        cache, last, stats = model(params, cache, window, call)
+        sampled = sample_with_logprobs(last, temperature, top_p, top_k,
+                                       seeds, jnp.int32(0))
+        return (cache, *sampled, stats) if hybrid else (cache, *sampled)
+
+    def multi(params, cache, tokens, positions, table, *rest):
+        *window, kv_lens, active, temperature, top_p, top_k, seeds, \
+            step_idx = rest
+
+        def body(carry, _):
+            cache, toks, pos, lens, sidx, acc = carry
+
+            def call(kv, state, window):
+                if hybrid:
+                    return forward_hybrid_decode(
+                        params, cfg, toks, pos, kv, state, table, lens,
+                        active, window=window)
+                kv, logits = forward_decode(params, cfg, toks, pos, kv,
+                                            table, lens, active)
+                return kv, None, logits, None
+
+            cache, logits, stats = model(params, cache, tuple(window), call)
+            nxt = sample(logits[:, 0, :], temperature, top_p, top_k, seeds,
+                         sidx)
+            acc = acc + stats if hybrid else acc
+            return (cache, nxt, pos + 1, lens + 1, sidx + 1, acc), nxt
+
+        acc0 = jnp.zeros(moe_stats_size(cfg), jnp.int32) if hybrid else None
+        (cache, *_, acc), toks_k = jax.lax.scan(
+            body, (cache, tokens, positions, kv_lens, step_idx, acc0), None,
+            length=block)
+        return (cache, toks_k, acc) if hybrid else (cache, toks_k)
+
+    for name, direct, n_out in (("step", step, 4), ("multi", multi, 1)):
+        (params, cache, *args), kwargs = built.calls[name]
+        leaves = [*jax.tree.leaves(args), *jax.tree.leaves(kwargs)]
+        if not hybrid and name == "step":
+            leaves.pop()  # `slots`: a dense program does not read it
+        outs = (kv_sharding, *[rep] * n_out, *([rep] if hybrid else []))
+        text = normalise(jax.jit(
+            direct, donate_argnums=(1,), out_shardings=outs).lower(
+                params, unpacked(cache), *leaves).as_text())
+        assert text == built[name], (stack, name)

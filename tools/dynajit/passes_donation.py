@@ -266,7 +266,7 @@ class DonatedAttrNotRebound(ProjectRule):
     name = "donated-attr-not-rebound"
     description = (
         "a donated `self.<attr>` must be rebound by the donating call's "
-        "own statement (`self.kv_cache, ... = fn(...)`): the attribute "
+        "own statement (`self.cache, ... = fn(...)`): the attribute "
         "outlives this function, and any other method reading it after "
         "the call holds a retired buffer")
 
