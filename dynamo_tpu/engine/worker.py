@@ -718,6 +718,9 @@ class TpuWorker:
             spec_attention=paths["spec_attention"],
             prefill_attention=paths["prefill_attention"],
             weight_matmul=paths["weight_matmul"],
+            block=("parallel" if self.model_config.parallel_block
+                   else "sequential"),
+            norm=self.model_config.norm_kind,
             native=str(native).lower()).set(1)
 
     def _on_engine_fatal(self, exc: BaseException) -> None:

@@ -92,7 +92,8 @@ class ModelConfig:
     # heads); "" = every layer is attention + MLP). A pre-norm block of
     # a published model is `mixers_per_layer` of them (attention, then
     # experts: 2). Rope is a kind's: the default table on "W" and "L",
-    # YaRN on "*" where `rope_yarn_factor` is set. Only attention layers
+    # YaRN on "*" where `rope_yarn_factor` is set, none on a kind that
+    # `rope_kinds` leaves out. Only attention layers
     # have KV pages, and each kind has a page group of its own
     # (`kv_layers` the full group, "*" or "L"; `window_kv_layers` the
     # window group: a cache layer index counts within its group); "M"
@@ -132,6 +133,23 @@ class ModelConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: float = 0.0
     logits_scaling: float = 1.0
+    # cohere2_moe's block (`layer_pattern` stacks only; each at its
+    # default for every other family, where nothing of it is traced).
+    # `parallel_block`: the `mixers_per_layer` mixers of a block all read
+    # ONE normed input, the first mixer's `norm` (the others have none),
+    # and join the residual stream together: x <- x + a + m.
+    # `norm_kind` "layer": every norm of the stack takes the mean off
+    # first (float32, a weight, no bias, eps `rms_eps`). `rope_kinds`:
+    # the attention kinds that rope ("" = every kind, where `use_rope`);
+    # a kind left out has no positional term. `rope_interleaved`: rope
+    # pairs lanes (2i, 2i+1) (GPT-J's convention), not (i, i + hd/2).
+    # `shared_expert_mean`: the shared experts' output is the MEAN of
+    # the `n_shared_experts` experts' outputs, not their sum.
+    parallel_block: bool = False
+    norm_kind: str = "rms"  # rms | layer
+    rope_kinds: str = ""
+    rope_interleaved: bool = False
+    shared_expert_mean: bool = False
     # The chip's share of an expert-parallel deployment: (lo, hi) of the
     # published experts held here. The router keeps its n_experts outputs
     # and its top-k; a token routed to an absent expert gets nothing from
@@ -671,6 +689,47 @@ PRESETS: dict[str, ModelConfig] = {
         moe_scoring="sigmoid", moe_selection_bias=False,
         mla_kv_lora_rank=32, mla_q_lora_rank=24, mla_rope_head_dim=8,
         mla_nope_head_dim=16, mla_v_head_dim=16,
+    ),
+    # CohereLabs command-a-plus-05-2026 (config.json, model_type
+    # cohere2_moe) at its published sizes, text only: 32 PARALLEL blocks
+    # (`use_parallel_block`: attention and experts both read ONE
+    # LayerNorm of the stream and are added to it together), so 64
+    # mixers, the second of a block without a norm of its own; three
+    # blocks of four attend over the last 4096 positions with rope on
+    # lane pairs (2i, 2i+1) (`rope_gptj`, theta 50,000), the fourth over
+    # everything with NO positional term; 128 query heads over 8 KV
+    # heads of 128; 128 SwiGLU experts 4096 wide (top-8 of float32
+    # sigmoid scores, no selection bias, renormalised) beside four
+    # shared experts whose outputs are averaged (one SwiGLU 16,384 wide
+    # x 1/4). `first_k_dense_replace` is 0: no dense block, and
+    # `prefix_dense_intermediate_size` (`mlp_hidden` here) is used by no
+    # layer. Tied head, `logit_scale` 1. The vision tower is not held.
+    # `--serve-layers` counts blocks.
+    "command-a-plus-05-2026": ModelConfig(
+        name="command-a-plus-05-2026", vocab_size=262144, hidden=4096,
+        n_layers=64, layer_pattern="WEWEWE*E" * 8, mixers_per_layer=2,
+        parallel_block=True, norm_kind="layer", rope_kinds="W",
+        rope_interleaved=True,
+        n_q_heads=128, n_kv_heads=8, head_dim=128, mlp_hidden=16384,
+        rope_theta=5e4, rms_eps=1e-5, tie_embeddings=True,
+        max_context=200000, sliding_window=4096,
+        n_experts=128, n_experts_active=8, expert_mlp_hidden=4096,
+        n_shared_experts=4, shared_expert_mean=True, moe_norm_topk=True,
+        moe_scoring="sigmoid", moe_selection_bias=False,
+    ),
+    # CPU sibling: two periods, window 32, 8 experts top-2, two shared
+    # experts averaged
+    "tiny-cohere2-test": ModelConfig(
+        name="tiny-cohere2-test", vocab_size=512, hidden=64, n_layers=16,
+        layer_pattern="WEWEWE*E" * 2, mixers_per_layer=2,
+        parallel_block=True, norm_kind="layer", rope_kinds="W",
+        rope_interleaved=True,
+        n_q_heads=4, n_kv_heads=2, head_dim=16, mlp_hidden=128,
+        rope_theta=5e4, rms_eps=1e-5, tie_embeddings=True,
+        max_context=1024, sliding_window=32,
+        n_experts=8, n_experts_active=2, expert_mlp_hidden=48,
+        n_shared_experts=2, shared_expert_mean=True, moe_norm_topk=True,
+        moe_scoring="sigmoid", moe_selection_bias=False,
     ),
     "tiny-mla-test": ModelConfig(
         name="tiny-mla-test", vocab_size=512, hidden=64, n_layers=2,

@@ -7,10 +7,20 @@ normed again before it is added; granitemoehybrid's Mamba-2 or attention
 mixer over SwiGLU experts with a shared one, a published block being
 two mixers, with the family's four multipliers and a tied head;
 lfm2_moe's gated short convolution or attention with normed q and k over
-a dense SwiGLU or sigmoid-routed SwiGLU experts, a tied head).
+a dense SwiGLU or sigmoid-routed SwiGLU experts, a tied head;
+cohere2_moe's PARALLEL block: window or full attention and sigmoid-routed
+SwiGLU experts beside averaged shared ones, both off ONE LayerNorm and
+added together, rope on lane pairs on the window layers and no
+positional term on the full ones, a tied head).
 
     x <- x + Mixer_l(RMSNorm(x; w_l))          after the last: RMSNorm, head
     x <- x + RMSNorm(Mixer_l(RMSNorm(x; w_l)); post_l)    with `sandwich_norm`
+    h = Norm(x; w_b); x <- x + sum of Mixer_l(h) over the block's mixers
+                                                         with `parallel_block`
+
+Norm is RMSNorm, or with `norm_kind` "layer" a LayerNorm (`layer_norm`:
+the mean taken off first, float32, a weight and no bias), the final norm
+too.
 
 With granitemoehybrid's multipliers (each a `ModelConfig` field at its
 default for every other family, where nothing of it is traced):
@@ -36,7 +46,9 @@ own [rows, h] array contracted over h (`_head`: no transposed copy).
   *  attention: grouped-query, causal softmax; with `qk_norm` q and k
      are RMS-normed per head (a learned gain of head_dim) BEFORE rope;
      rope where `use_rope` (YaRN where `rope_yarn_factor`), none for
-     nemotron_h and granitemoehybrid
+     nemotron_h and granitemoehybrid, none on a kind `rope_kinds` leaves
+     out (cohere2_moe's full layers); on lane pairs (2i, 2i+1) with
+     `rope_interleaved`, else on (i, i + head_dim/2)
   W  the same over the last `sliding_window` positions, default rope:
      scores masked to q_pos - window < kv_pos <= q_pos
   E  routed experts: sigmoid scores, top-k of scores + bias (of the raw
@@ -45,7 +57,9 @@ own [rows, h] array contracted over h (`_head`: no transposed copy).
      float32 softmax's top-k, renormalised);
      expert = W_down relu(W_up x)^2, or with `mlp_act` swiglu
      W_down (silu(W_gate x) * W_up x) from one fused [gate | up] matrix;
-     a shared expert of the same form where the model has one
+     a shared expert of the same form where the model has one (with
+     `shared_expert_mean` its output x 1/`n_shared_experts`: the mean of
+     that many experts stored as one matrix)
   D  a dense SwiGLU `mlp_hidden` wide, one fused [gate | up] matrix
   L  latent attention: c_q = RMSNorm(x W_dq), q = c_q W_uq -> heads x
      (nope | rope), q_rope roped; c_kv = RMSNorm(x W_dkv), k_r =
@@ -141,7 +155,20 @@ def hybrid_layer_axes(config: ModelConfig, layer_idx: int) -> dict:
     """Logical sharding axes of one layer (parallel.shardings). The Mamba
     and expert leaves are replicated: a sharded state cache and an expert
     exchange are not built (the worker refuses --tp/--sp for this family)."""
-    kind = config.layer_kind(layer_idx)
+    axes = _kind_axes(config, config.layer_kind(layer_idx))
+    if not has_own_norm(config, layer_idx):
+        del axes["norm"]
+    return axes
+
+
+def has_own_norm(config: ModelConfig, layer_idx: int) -> bool:
+    """Whether mixer `layer_idx` norms its own input: every mixer but
+    the later ones of a parallel block, which read the first one's."""
+    return (not config.parallel_block
+            or layer_idx % config.mixers_per_layer == 0)
+
+
+def _kind_axes(config: ModelConfig, kind: str) -> dict:
     if kind == "M":
         return {"norm": ("embed",), "in_proj": ("embed", None),
                 "conv_w": (None, None), "conv_b": (None,),
@@ -200,6 +227,15 @@ BRANCH_GROWTH = 1.23
 FIRST_JUMP, BRANCH_SHARE = 60.0, 0.25
 KIND_SPREAD = {"C": 1.0, "D": 0.6, "E": 0.3, "*": 0.125}
 NORMED_QK_GAIN = 2.0
+# The same for a stack whose attention kinds differ by their positional
+# term (`rope_kinds`) and whose norms take a mean off (`norm_kind`
+# "layer"): wq and wk are drawn SHARP_QK_GAIN times wider (`score_gain`),
+# and a matrix that writes into the residual stream adds STREAM_MEAN
+# times its first output lane's column to every column
+# (`init_hybrid_layer`), so that the stream carries a mean for the
+# LayerNorm to take off.
+SHARP_QK_GAIN = 1.5
+STREAM_MEAN = 0.5
 
 
 def branch_gain(config: ModelConfig, layer_idx: int) -> float:
@@ -226,7 +262,11 @@ def branch_gain(config: ModelConfig, layer_idx: int) -> float:
           / math.sqrt(config.hidden))
     if "C" in config.layer_pattern:
         return _conv_stack_gain(config, layer_idx, s0)
-    return s0 / config.residual_multiplier * BRANCH_GROWTH ** layer_idx
+    # never under 1: an embedding drawn narrower than a unit-gain branch
+    # (no multipliers: s0 = 1 / sqrt(h)) is outweighed from the first
+    # block on, and the growth alone takes its share under the spread
+    return (max(s0 / config.residual_multiplier, 1.0)
+            * BRANCH_GROWTH ** layer_idx)
 
 
 def _conv_stack_gain(config: ModelConfig, layer_idx: int, s0: float):
@@ -267,9 +307,18 @@ def score_gain(config: ModelConfig) -> float:
     which the norms take out again (a trained model's projections have
     no unit scale, which is what its norms are for; without them the
     seeded scores are four times sharper, and a program that forgets
-    the norms is told from one that has them)."""
+    the norms is told from one that has them). A stack whose attention
+    kinds differ by their positional term (`rope_kinds`): SHARP_QK_GAIN,
+    scores of spread 2.25. At spread 1 a softmax over a 4,096-key
+    window weighs some 1,500 keys alike: the attention branch is a
+    hundredth of its block's variance, and rope, no rope, a window or
+    none all read inside bf16's rounding. At 2.25 it weighs some 26, as
+    a trained model's attention does, and the branch is as wide as the
+    experts'."""
     if config.qk_norm and config.is_hybrid:
         return NORMED_QK_GAIN
+    if config.rope_kinds:
+        return SHARP_QK_GAIN
     if not config.attention_multiplier:
         return 1.0
     return (config.attention_multiplier
@@ -337,15 +386,30 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
     that order) from key 0, its taps [K, h] from key 1 (normal /
     sqrt(K), tap K-1 on the current position) and W_out (centred) from
     key 6, the keys a Mamba mixer's three like matrices have. With
-    `qk_norm` an attention mixer has two more gains of head_dim, ones."""
+    `qk_norm` an attention mixer has two more gains of head_dim, ones.
+
+    A stack of LayerNorms (`norm_kind` "layer"; cohere2_moe): a matrix
+    that writes into the residual stream, once centred, adds STREAM_MEAN
+    times its first output lane's column to EVERY column, so that each
+    branch writes a mean over the lanes (half its lane 0's value: as
+    wide as half the branch's spread, different for every token). Every
+    reader of the stream is a LayerNorm, which takes it off again: the
+    model computes what it would without it, and one that norms by the
+    root mean square alone does not. Seeded matrices have lane means of
+    spread 1/sqrt(h) and would not tell the two norms apart; a trained
+    stream has a mean, which is what the subtraction is for. The later
+    mixers of a parallel block have no `norm` (`has_own_norm`)."""
     dtype = jnp.dtype(config.dtype)
     h = config.hidden
     ks = jax.random.split(k, 15)
+    lane_mean = STREAM_MEAN if config.norm_kind == "layer" else 0.0
 
     def dense(key, shape, fan_in, centre=None, gain=1.0):
         w = jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
         if centre is not None:
             w = w - jnp.mean(w, axis=centre, keepdims=True)
+            if lane_mean:
+                w = w + lane_mean * w[..., :1]
         if not (isinstance(gain, float) and gain == 1.0):
             w = w * gain
         return w.astype(dtype)
@@ -353,7 +417,8 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
     if out_gain is None:
         out_gain = branch_gain(config, layer_idx)
     kind = config.layer_kind(layer_idx)
-    p = {"norm": jnp.ones((h,), dtype)}
+    p = ({"norm": jnp.ones((h,), dtype)}
+         if has_own_norm(config, layer_idx) else {})
     if config.sandwich_norm:
         p["post_norm"] = jnp.ones((h,), dtype)
     if kind == "L":
@@ -678,8 +743,10 @@ def rope_tables(config: ModelConfig, kind: str):
     """(inverse frequencies [hd/2] float32, cos/sin factor) of a layer
     kind, or None where attention has no positional term: the default
     table on a window layer, YaRN on a full one where the model states a
-    factor (HF `rope_parameters` keyed by layer type)."""
-    if not config.use_rope:
+    factor (HF `rope_parameters` keyed by layer type), none on a kind
+    that `rope_kinds` leaves out."""
+    if not config.use_rope or (config.rope_kinds
+                               and kind not in config.rope_kinds):
         return None
     if kind == "*" and config.rope_yarn_factor:
         return yarn_rope_tables(config)
@@ -690,15 +757,28 @@ def rope_tables(config: ModelConfig, kind: str):
                     * jnp.arange(0, half, dtype=jnp.float32) / half), 1.0)
 
 
-def apply_rope(x, positions, tables):
-    """Rotate-half rope. x [..., T, H, hd]; positions [..., T], ABSOLUTE
-    (never a page group's own frame)."""
+def apply_rope(x, positions, tables, interleaved: bool = False):
+    """Rotate-half rope: lane i turns with lane i + hd/2. x [..., T, H,
+    hd]; positions [..., T], ABSOLUTE (never a page group's own frame).
+    `interleaved` (GPT-J's convention, `ModelConfig.rope_interleaved`):
+    lane 2i turns with lane 2i + 1 by the same angle i. Computed on whole
+    lanes: the partner of every lane is fetched by two rolls and a
+    select on the lane's parity, so that no [.., hd/2, 2] array exists
+    (two lanes minor would be padded to a tile of 128)."""
     if tables is None:
         return x
     inv_freq, factor = tables
     angles = positions[..., None].astype(jnp.float32) * inv_freq
     cos = (jnp.cos(angles) * factor)[..., None, :]
     sin = (jnp.sin(angles) * factor)[..., None, :]
+    if interleaved:
+        cos, sin = jnp.repeat(cos, 2, axis=-1), jnp.repeat(sin, 2, axis=-1)
+        even = jnp.arange(x.shape[-1]) % 2 == 0
+        # out[2i] = x[2i] cos - x[2i+1] sin; out[2i+1] = x[2i+1] cos +
+        # x[2i] sin
+        partner = jnp.where(even, -jnp.roll(x, -1, axis=-1),
+                            jnp.roll(x, 1, axis=-1))
+        return (x * cos + partner * sin).astype(x.dtype)
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
@@ -714,8 +794,9 @@ def _qkv(h, lp, config: ModelConfig, kind: str, positions):
     if config.qk_norm:  # per head, before rope
         q = rms_norm(q, lp["q_norm"], config.rms_eps)
         k = rms_norm(k, lp["k_norm"], config.rms_eps)
-    return apply_rope(q, positions, tables), apply_rope(k, positions,
-                                                        tables), v
+    pairs = config.rope_interleaved
+    return (apply_rope(q, positions, tables, pairs),
+            apply_rope(k, positions, tables, pairs), v)
 
 
 ATTENTION_SCOPES = {"*": "attn_full", "W": "attn_window", "L": "attn_latent"}
@@ -927,9 +1008,13 @@ def moe_mixer(x, lp, config: ModelConfig, valid, gmm_path: str):
             path=gmm_path)
         shared = None
         if "s_up" in lp:
-            shared = jnp.einsum(
-                "btm,mh->bth",
-                act(jnp.einsum("bth,hm->btm", x, lp["s_up"])), lp["s_down"])
+            with jax.named_scope("moe_shared"):
+                shared = jnp.einsum(
+                    "btm,mh->bth",
+                    act(jnp.einsum("bth,hm->btm", x, lp["s_up"])),
+                    lp["s_down"])
+                if config.shared_expert_mean:
+                    shared = shared / config.n_shared_experts
         stats = jnp.concatenate([
             counts, jnp.stack([dropped, jnp.sum(counts > 0), 1])
         ]).astype(jnp.int32)
@@ -937,8 +1022,27 @@ def moe_mixer(x, lp, config: ModelConfig, valid, gmm_path: str):
         return (out if shared is None else out + shared), stats
 
 
+def layer_norm(x, weight, eps: float):
+    """LayerNorm with a weight and no bias, in float32: the mean over
+    the lanes taken off, then the root mean square of what is left."""
+    orig = x.dtype
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * scale).astype(orig) * weight
+
+
+def stream_norm(x, weight, config: ModelConfig, scope="block_norm"):
+    """The norm a mixer (a parallel block's mixers: ONE) or the head
+    reads the residual stream through."""
+    if config.norm_kind == "layer":
+        with jax.named_scope(scope):
+            return layer_norm(x, weight, config.rms_eps)
+    return rms_norm(x, weight, config.rms_eps)
+
+
 def _head(x, params, config: ModelConfig):
-    x = rms_norm(x, params["final_norm"], config.rms_eps)
+    x = stream_norm(x, params["final_norm"], config, "final_norm")
     if config.tie_embeddings:
         # the embedding's own [rows, h] array, contracted over h: a
         # transposed copy of it (0.41 GB at 50,176 x 4096) is in no step
@@ -964,6 +1068,20 @@ def _branch(out, config: ModelConfig):
     """A mixer's output as it joins the residual stream."""
     return (out if config.residual_multiplier == 1.0
             else out * config.residual_multiplier)
+
+
+def _join(x, pending, out, config: ModelConfig, layer_idx: int):
+    """(stream, branches waiting) once mixer `layer_idx` has written
+    `out`: added at once, or in a parallel block kept until the block's
+    last mixer and added together, so that every mixer of the block read
+    the same stream: x <- x + (a + m)."""
+    if not config.parallel_block:
+        return x + out, None
+    if pending is not None:
+        out = pending + out
+    if (layer_idx + 1) % config.mixers_per_layer:
+        return x, out
+    return x + out, None
 
 
 # ---------------------------------------------------------------------------
@@ -1098,9 +1216,11 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
     conv_out, ssm_out = list(state["conv"]), list(state["ssm"])
     stats = jnp.zeros((moe_stats_size(config),), jnp.int32)
     kv_idx = win_idx = state_idx = ssm_idx = 0
+    pending = None
     for layer_idx, lp in enumerate(params["layers"]):
         kind = config.layer_kind(layer_idx)
-        h = rms_norm(x, lp["norm"], config.rms_eps)
+        if has_own_norm(config, layer_idx):  # else: its block's first
+            h = stream_norm(x, lp["norm"], config)
         if kind == "M":
             conv_all, ssm_all = conv_out[state_idx], ssm_out[ssm_idx]
             conv = jnp.where(fresh[:, None, None], 0, conv_all[slots])
@@ -1149,7 +1269,8 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
             stats = stats + layer_stats
         if config.sandwich_norm:
             out = rms_norm(out, lp["post_norm"], config.rms_eps)
-        x = x + _branch(out, config)
+        x, pending = _join(x, pending, _branch(out, config), config,
+                           layer_idx)
     state = {"conv": conv_out, "ssm": ssm_out}
     if not all_logits:
         x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
@@ -1180,9 +1301,11 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
     stats = jnp.zeros((moe_stats_size(config),), jnp.int32)
     ks, vs, win_ks, win_vs = [], [], [], []
     kv_idx = win_idx = state_idx = ssm_idx = 0
+    pending = None
     for layer_idx, lp in enumerate(params["layers"]):
         kind = config.layer_kind(layer_idx)
-        h = rms_norm(x, lp["norm"], config.rms_eps)
+        if has_own_norm(config, layer_idx):  # else: its block's first
+            h = stream_norm(x, lp["norm"], config)
         if kind == "M":
             out, conv_out[state_idx], ssm_out[ssm_idx] = mamba_decode(
                 h, lp, config, conv_out[state_idx], ssm_out[ssm_idx],
@@ -1229,7 +1352,8 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
             stats = stats + layer_stats
         if config.sandwich_norm:
             out = rms_norm(out, lp["post_norm"], config.rms_eps)
-        x = x + _branch(out, config)
+        x, pending = _join(x, pending, _branch(out, config), config,
+                           layer_idx)
     if config.has_latent_layers:
         kv_cache = write_latent_stack(kv_cache, jnp.stack(ks), block_tables,
                                       positions, active)

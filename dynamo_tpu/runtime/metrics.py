@@ -381,9 +381,14 @@ ENGINE_INFO = Gauge(
     "attention layers of its prefill programs run (prefill_attention: "
     "kernel | xla | mixed over the buckets, the full page group's then "
     "+ the window group's; the geometry decides, program by program; "
-    "custom; none), and whether dynamo_tpu._native loaded",
+    "custom; none; one decode kernel serves both groups, the window's "
+    "lower edge an argument), how a block of the model joins its mixers "
+    "(block: sequential | parallel, all off one norm and added "
+    "together) and through which norm (norm: rms | layer), and whether "
+    "dynamo_tpu._native loaded",
     ["worker", "platform", "device_kind", "devices", "decode_attention",
-     "spec_attention", "prefill_attention", "weight_matmul", "native"],
+     "spec_attention", "prefill_attention", "weight_matmul", "block",
+     "norm", "native"],
     registry=REGISTRY,
 )
 ENGINE_TOKENS = Gauge(

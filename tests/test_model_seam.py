@@ -32,6 +32,7 @@ STACKS = {
     "window+full": ("tiny-mellum-test", {}, {"window_pages": 16}),
     "latent": ("tiny-pangu-test", {}, {}),
     "short-conv": ("tiny-lfm2-test", {}, {}),
+    "parallel-block": ("tiny-cohere2-test", {}, {"window_pages": 16}),
 }
 
 
@@ -175,6 +176,7 @@ def test_a_dense_stacks_plan_is_the_plain_one():
     ("tiny-lfm2-test", ("full",), True, False, "carried"),
     ("tiny-mellum-test", ("full", "window"), False, False, "always"),
     ("tiny-pangu-test", ("full",), False, True, "always"),
+    ("tiny-cohere2-test", ("full", "window"), False, False, "always"),
 ])
 def test_the_plan_says_what_each_kind_of_layer_brings(preset, groups, state,
                                                       prefix, bound):
@@ -249,6 +251,16 @@ REFUSALS = [
      ["--weight-dtype int4", "has expert matrices"]),
     ("tiny-mellum-test", dict(weight_dtype="int8"), ["--weight-dtype int8"]),
     ("tiny-mellum-test", dict(devices=4), ["--tp/--sp/--dp"]),
+    # a parallel block over the same two page groups: nothing new to
+    # refuse, and nothing refused anew (test_cohere2_model.py, PR 49)
+    ("command-a-plus-05-2026", dict(mode="prefill"),
+     ["--mode prefill", "two page groups"]),
+    ("command-a-plus-05-2026", dict(kvbm=True), [*KVBM, "two page groups"]),
+    ("command-a-plus-05-2026", dict(spec=True), [*SPEC, "multi-position"]),
+    ("command-a-plus-05-2026", dict(kv_dtype="int8"), ["--kv-dtype int8"]),
+    ("command-a-plus-05-2026", dict(weight_dtype="int4"),
+     ["--weight-dtype int4", "has expert matrices"]),
+    ("command-a-plus-05-2026", dict(devices=4), ["--tp/--sp/--dp"]),
     # latent attention, a single-stack pool (test_pangu_model.py, PR 38)
     ("tiny-pangu-test", dict(mode="prefill"),
      ["--mode prefill", "single-stack latent pool"]),

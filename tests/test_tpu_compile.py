@@ -649,6 +649,78 @@ def test_the_window_prefill_kernel_compiles_for_mellums_launches(one_chip,
     assert _copies(text, n_pages * PAGE * kh * HEAD_DIM) == []
 
 
+# command-a-plus-05-2026 as the `command-a-plus-ep8` cell serves it: 128
+# query heads over 8 kv heads of 128 (16 queries a kv head, a group no
+# other cell's kernels had seen at 8 kv heads), window 4,096, 32 rows; the
+# window group's 3 layers of 9,280 pages under a 264-column decode table
+# (a row holds 258) and the bucket's window + chunk prefill table, the
+# full group's 1 layer of 24,576 pages under tables of 8 to 768 columns.
+COMMAND_A = {"rows": 32, "qh": 128, "kh": 8, "window": 4096,
+             "window_pages": 9280, "pages": 24576}
+COMMAND_A_CASES = {
+    "decode-window-w264": ("decode", True, 264),
+    "decode-full-w8": ("decode", False, 8),
+    "decode-full-w768": ("decode", False, 768),
+    "prefill-window-1x2048-w400": ("prefill", True, (1, 2048, 400)),
+    "prefill-window-4x512-w304": ("prefill", True, (4, 512, 304)),
+    "prefill-full-1x2048-w768": ("prefill", False, (1, 2048, 768)),
+    "prefill-full-2x1024-w768": ("prefill", False, (2, 1024, 768)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COMMAND_A_CASES))
+def test_the_attention_kernels_compile_at_command_a_plus_shapes(one_chip,
+                                                                case):
+    """No new kernel came with the parallel block; the four that exist
+    run at shapes none had seen. Mosaic takes them all (VMEM decides, and
+    does: 64 query positions x 16 queries a kv head = 1,024 rows a block
+    against 256-key chunks)."""
+    from dynamo_tpu.ops.paged_attention import (
+        paged_decode_attention_pool,
+        paged_decode_attention_window,
+        paged_prefill_attention_pool,
+        paged_prefill_attention_window,
+        prefill_kernel_tiles,
+    )
+
+    phase, windowed, shape = COMMAND_A_CASES[case]
+    z = COMMAND_A
+    layers, n_pages = ((3, z["window_pages"]) if windowed
+                       else (1, z["pages"]))
+    pool = _shape(one_chip, (layers, 2, n_pages, PAGE, z["kh"], HEAD_DIM),
+                  jnp.bfloat16)
+    layer = _shape(one_chip, (), jnp.int32)
+    if phase == "decode":
+        n = z["rows"]
+        args = [_shape(one_chip, (n, z["qh"], HEAD_DIM), jnp.bfloat16), pool,
+                layer, _shape(one_chip, (n, shape), jnp.int32),
+                _shape(one_chip, (n,), jnp.int32)]
+        if windowed:
+            lowered = paged_decode_attention_window.lower(
+                *args, _shape(one_chip, (n,), jnp.int32))
+        else:
+            lowered = paged_decode_attention_pool.lower(*args)
+        name = "paged_decode_attention_" + ("window" if windowed else "pool")
+    else:
+        rows, t, width = shape
+        assert prefill_kernel_tiles(t, z["qh"], z["kh"], HEAD_DIM, PAGE,
+                                    width, jnp.bfloat16) == (64, 256)
+        args = [_shape(one_chip, (rows, t, z["qh"], HEAD_DIM), jnp.bfloat16),
+                pool, layer, _shape(one_chip, (rows, width), jnp.int32),
+                _shape(one_chip, (rows,), jnp.int32),
+                _shape(one_chip, (rows,), jnp.int32)]
+        if windowed:
+            lowered = paged_prefill_attention_window.lower(
+                *args, window=z["window"])
+        else:
+            lowered = paged_prefill_attention_pool.lower(*args)
+        name = "paged_prefill_attention_" + ("window" if windowed else "pool")
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text and name in text
+    # the pool is read in place: nothing the size of a layer's K or V
+    assert _copies(text, n_pages * PAGE * z["kh"] * HEAD_DIM) == []
+
+
 def test_without_a_window_the_prefill_kernel_traces_as_before():
     """`window` is a static branch of `_pool_prefill_kernel`: with 0 the
     dense and the hybrid cells' kernel keeps its six scalar-prefetch
@@ -1447,3 +1519,30 @@ def test_the_runners_programs_are_the_forwards_called_directly(one_chip,
             direct, donate_argnums=(1,), out_shardings=outs).lower(
                 params, unpacked(cache), *leaves).as_text())
         assert text == built[name], (stack, name)
+
+
+def test_the_lowering_tool_compiles_the_programs_its_regex_finds(one_chip):
+    """`tools.lowered_text.lowering(compile_keys=...)` (the tool's
+    `--compile`): a program whose key the regex finds is compiled for the
+    described chip too, and what the compiler says of it is kept: the
+    bytes it takes and its optimised HLO. The others are lowered only."""
+    from dynamo_tpu.engine import ModelRunner, PrefillRow, RunnerConfig
+    from dynamo_tpu.engine.model_runner import IDLE_WINDOW
+    from dynamo_tpu.models import get_config
+    from dynamo_tpu.parallel import MeshConfig, make_mesh
+    from tools.lowered_text import lowering
+
+    (device,) = one_chip.device_set
+    with lowering(device, compile_keys="^step$") as built:
+        runner = ModelRunner(
+            get_config("tiny-cohere2-test"),
+            RunnerConfig(page_size=16, num_pages=32, max_batch=4,
+                         max_pages_per_seq=8, prefill_buckets=(16,),
+                         window_pages=16),
+            make_mesh(MeshConfig(), [device]))
+        row = PrefillRow(np.zeros(9, np.int32), 0, np.zeros(8, np.int32), 9,
+                         (0.0, 1.0, 0, 0), window=IDLE_WINDOW)
+        runner.prefill_chunk_batch([row, row])
+    assert "step" in built and set(built.memory) == {"step"}
+    assert "argument_size_in_bytes" in built.memory["step"]
+    assert "HloModule" in built.compiled["step"]

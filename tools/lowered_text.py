@@ -5,6 +5,13 @@ programs alone (PR 47; the recipe is in .claude/skills/verify/SKILL.md).
 
     python -m tools.lowered_text --cell lfm2-8b-a1b-pp2 --out DIR
     python -m tools.lowered_text --diff DIR_A DIR_B
+    python -m tools.lowered_text --cell <name> --out DIR --compile REGEX
+
+`--compile` also COMPILES the programs whose key the regex finds, for
+the described chip, and prints what the compiler says of each: bytes of
+arguments, temporaries and output, or the refusal in Mosaic's or XLA's
+own words (a kernel over its VMEM, a program over the chip's memory). A
+quarter of a minute to two minutes a program; no chip time.
 
 Nothing runs and nothing is allocated. Inside `lowering(device)` every
 call of a function that `engine/model_runner.py` has jitted lowers it
@@ -94,10 +101,12 @@ class Lowered(dict):
     def __init__(self) -> None:
         super().__init__()
         self.calls: dict[str, tuple] = {}
+        self.memory: dict[str, str] = {}  # what `--compile` was told
+        self.compiled: dict[str, str] = {}  # and the optimised HLO
 
 
 @contextlib.contextmanager
-def lowering(device, key_of=None):
+def lowering(device, key_of=None, compile_keys: str = ""):
     """Inside: a function jitted by `engine/model_runner.py` (the module
     sees a `jax` whose `jit` is ours) is lowered for `device` when it is
     called, and not run. Yields {key: normalised text}; `key_of(name)`
@@ -127,6 +136,14 @@ def lowering(device, key_of=None):
             text = normalise(lowered.as_text())
             while texts.get(key, text) != text:
                 key += "'"
+            if compile_keys and key not in texts and re.search(
+                    compile_keys, key):
+                try:
+                    compiled = lowered.compile()
+                    texts.memory[key] = str(compiled.memory_analysis())
+                    texts.compiled[key] = compiled.as_text()
+                except Exception as exc:  # noqa: BLE001 — the finding
+                    texts.memory[key] = f"REFUSED: {str(exc)[-2000:]}"
             texts[key] = text
             texts.calls[key] = (args, kwargs)
             out = jax.eval_shape(self.fn, *args, **kwargs)
@@ -208,9 +225,13 @@ def cell_runner(config_file: Path, device, buckets=None):
     return runner, int(serve.get("decode_block", 8))
 
 
-def lower_cell(name: str, out: Path, only: str = "", buckets=None) -> dict:
+def lower_cell(name: str, out: Path, only: str = "", buckets=None,
+               compile_keys: str = "") -> dict:
     """Every program `prewarm(launches=True)` walks for the cell, under
-    `out/<key>.mlir`; returns {key: sha256 of the text}."""
+    `out/<key>.mlir`; returns {key: sha256 of the text}. The programs
+    `compile_keys` finds are compiled too, and what the compiler said
+    goes to `out/memory.json` and is printed, the optimised HLO to
+    `out/<key>.hlo`."""
     import dynamo_tpu.engine.model_runner as mr
 
     on_the_chip()
@@ -220,7 +241,7 @@ def lower_cell(name: str, out: Path, only: str = "", buckets=None) -> dict:
         build = getattr(mr._COMPILE_SCOPE, "build", None)
         return f"{build.key}.jit_{name}" if build else f"init.jit_{name}"
 
-    with lowering(device, key_of) as texts:
+    with lowering(device, key_of, compile_keys) as texts:
         runner, block = cell_runner(
             Path("benchmarks/configs") / f"{name}.json", device, buckets)
         runner.prewarm(spec_widths=[], launches=True, block=block)
@@ -232,6 +253,12 @@ def lower_cell(name: str, out: Path, only: str = "", buckets=None) -> dict:
         (out / f"{key}.mlir").write_text(text)
         index[key] = hashlib.sha256(text.encode()).hexdigest()
     (out / "index.json").write_text(json.dumps(index, indent=1))
+    if compile_keys:
+        (out / "memory.json").write_text(json.dumps(texts.memory, indent=1))
+        for key, said in texts.memory.items():
+            print(f"compiled {key}: {said}")
+        for key, hlo in texts.compiled.items():
+            (out / f"{key}.hlo").write_text(hlo)
     return index
 
 
@@ -266,12 +293,16 @@ def main() -> int:
                         help="keep program keys this regex finds")
     parser.add_argument("--buckets", help="--prefill-buckets for a "
                         "configuration whose worker_args name none")
+    parser.add_argument("--compile", default="", metavar="REGEX",
+                        help="compile the programs whose key this finds "
+                        "for the described chip; print the memory each "
+                        "takes, or the compiler's refusal")
     parser.add_argument("--diff", nargs=2, type=Path, metavar="DIR")
     args = parser.parse_args()
     if args.diff:
         return 1 if diff(*args.diff) else 0
     for key, digest in lower_cell(args.cell, args.out, args.only,
-                                   args.buckets).items():
+                                   args.buckets, args.compile).items():
         print(digest[:16], key)
     return 0
 
