@@ -367,7 +367,10 @@ def _mesh_kernels(mesh: Mesh) -> dict:
     stack has a use for (`models.make_steps`).
 
     prefill: `ops.paged_attention.paged_attention`, the blocked prefill
-    kernel over the paged pool wherever it admits the geometry. spec:
+    kernel over the paged pool wherever it admits the geometry;
+    prefill_latent: `paged_attention_latent`, the same for a single
+    stack of latent rows (a `layer_pattern` model with latent
+    attention). spec:
     the whole-pool chunked-DMA kernel with the chunk dim folded into the
     GQA group dim, so one dispatch streams each owned page once for all
     k+1 candidate positions of a speculative verification. Both on a
@@ -377,6 +380,7 @@ def _mesh_kernels(mesh: Mesh) -> dict:
     shard_map over kv heads as the decode kernel has is the mend)."""
     from ..ops.paged_attention import (
         paged_attention,
+        paged_attention_latent,
         paged_attention_spec_pool,
     )
 
@@ -385,6 +389,9 @@ def _mesh_kernels(mesh: Mesh) -> dict:
     return {
         "prefill": (partial(paged_attention, interpret=interpret)
                     if one_device else None),
+        "prefill_latent": (partial(paged_attention_latent,
+                                   interpret=interpret)
+                           if one_device else None),
         "decode": _default_decode_attention_fn(mesh),
         "decode_latent": _default_decode_attention_fn(mesh, latent=True),
         "spec": (partial(paged_attention_spec_pool, interpret=interpret)
@@ -609,10 +616,13 @@ class ModelRunner:
         # (dynamo_prefill_attn_launches_total) and, on the kernel's, the
         # (query block, key chunk) pairs a layer scored and skipped
         # (dynamo_prefill_attn_blocks_total), a page group apart: the
-        # full group's (a model's only one, but for window layers) and
-        # the window group's.
+        # full group's (a model's only one, but for window layers; a
+        # latent stack's goes out as group="latent") and the window
+        # group's.
         self.prefill_attn_launches = {"kernel": 0, "xla": 0}
         self.prefill_attn_blocks = {"live": 0, "skipped": 0}
+        self.prefill_attn_group = ("latent" if model_config.has_latent_layers
+                                   else "full")
         self.prefill_attn_window_blocks = {"live": 0, "skipped": 0}
         # A model with latent attention (dynamo_latent_*): cached
         # positions its decode kernel was asked to read, and positions
@@ -682,12 +692,6 @@ class ModelRunner:
                 steps * hist.sum() + len(hist) * steps * (steps - 1) // 2
             ) * self._latent_layers
 
-    def _count_latent_prefill(self, kv_lens: Sequence[int]) -> None:
-        """Positions whose keys and values one prefill launch rebuilds
-        from latents: every row's context up to its chunk's end."""
-        self.latent_prefill_expand_tokens += int(
-            sum(kv_lens)) * self._latent_layers
-
     def _program(self, fn: str, *shape, tokens: int = 0, cause=None):
         """One launch of entry `fn`'s compiled program for the static
         `shape` (`program_key`): counted under that key unless a warm-up
@@ -744,20 +748,30 @@ class ModelRunner:
         """(query positions a block, key tokens a chunk) where the
         attention layers of a `bucket`-position prefill launch run the
         blocked kernel, None where they run in XLA: `paged_attention`'s
-        own rule on the shapes it will be handed, and only where the
-        step program hands them to it (the default `attention_fn`, a
-        model whose prefill has no attention of its own: latent layers
-        have). `window`: the window layers of a model that has them,
-        over their own page group's table (`window_prefill_width`);
-        else the full group's layers."""
+        own rule on the shapes it will be handed (for latent layers
+        `paged_attention_latent`'s), and only where the step program
+        hands them to it (the default `attention_fn`). `window`: the
+        window layers of a model that has them, over their own page
+        group's table (`window_prefill_width`); else the full group's
+        layers."""
         cfg, rc = self.model_config, self.config
         if (self._user_attention_fn is not None
                 or self._steps.attention_fn is None
                 or ("window" if window else "full")
                 not in self._steps.attention_groups):
             return None
-        from ..ops.paged_attention import prefill_kernel_tiles
+        from ..ops import kernel_path
+        from ..ops.paged_attention import (
+            latent_prefill_tiles,
+            prefill_kernel_tiles,
+        )
 
+        if cfg.has_latent_layers:
+            return latent_prefill_tiles(
+                bucket, cfg.mla_nope_head_dim, cfg.mla_v_head_dim,
+                cfg.mla_kv_lora_rank, cfg.kv_cache_head_dim, rc.page_size,
+                rc.max_pages_per_seq, cfg.dtype,
+                kernel_path("DYNT_ATTENTION") == "interpret")
         return prefill_kernel_tiles(
             bucket, cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim,
             rc.page_size,
@@ -808,12 +822,16 @@ class ModelRunner:
         # a launch is the kernel's where every page group's layers are
         self.prefill_attn_launches[
             "kernel" if tiles and win_tiles else "xla"] += 1
+        ends = [s + n for s, n in zip(starts, lengths)]
+        rebuilt = sum(ends)  # the XLA form: every row's context, once
         if tiles:
             live, skipped = count_prefill_blocks(
-                starts, [s + n for s, n in zip(starts, lengths)], rows,
-                bucket, *tiles, self.config.max_context)
+                starts, ends, rows, bucket, *tiles, self.config.max_context)
             self.prefill_attn_blocks["live"] += live
             self.prefill_attn_blocks["skipped"] += skipped
+            # the kernel: a key chunk for every query block that sees it
+            rebuilt = live * tiles[1]
+        self.latent_prefill_expand_tokens += rebuilt * self._latent_layers
         if windowed and win_tiles:
             # the window group's frame: positions from each row's base
             frame = [s - w[1] for s, w in zip(starts, windows)]
@@ -1538,7 +1556,6 @@ class ModelRunner:
         windows = [r.window for r in rows]
         self._count_prefill([r.start for r in rows],
                             [len(r.tokens) for r in rows], b, bucket, windows)
-        self._count_latent_prefill([r.kv_len_after for r in rows])
         args = [
             jnp.asarray(tok), jnp.asarray(pos),
             (jnp.asarray(tables),), jnp.asarray(kv_lens), jnp.asarray(valid),

@@ -1617,10 +1617,11 @@ class TpuWorker:
         for path, count in getattr(
                 self.runner, "prefill_attn_launches", {}).items():
             PREFILL_ATTN_LAUNCHES.labels(worker=worker, path=path).set(count)
-        for group, attr in (("full", "prefill_attn_blocks"),
+        stack = getattr(self.runner, "prefill_attn_group", "full")
+        for group, attr in ((stack, "prefill_attn_blocks"),
                             ("window", "prefill_attn_window_blocks")):
             blocks = getattr(self.runner, attr, {})
-            if group == "full" or any(blocks.values()):
+            if group == stack or any(blocks.values()):
                 for state, count in blocks.items():
                     PREFILL_ATTN_BLOCKS.labels(
                         worker=worker, state=state, group=group).set(count)
@@ -1660,11 +1661,11 @@ class TpuWorker:
                         self.runner, "ssm_scan_launches", {}).items():
                     SSM_SCAN_LAUNCHES.labels(
                         worker=worker, path=path).set(count)
-        expanded = getattr(self.runner, "latent_prefill_expand_tokens", 0)
-        if expanded:  # only a model with latent attention
+        if stack == "latent":  # only a model with latent attention
             LATENT_DECODE_TOKENS.labels(worker=worker).set(
                 self.runner.latent_decode_tokens)
-            LATENT_PREFILL_EXPAND_TOKENS.labels(worker=worker).set(expanded)
+            LATENT_PREFILL_EXPAND_TOKENS.labels(worker=worker).set(
+                self.runner.latent_prefill_expand_tokens)
         if stats.moe_counts is not None:
             from .model_runner import MOE_PHASES
 

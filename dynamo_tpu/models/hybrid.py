@@ -66,7 +66,10 @@ own [rows, h] array contracted over h (`_head`: no transposed copy).
      RoPE(x W_kr), ONE rope key a token for all heads. A token's cache
      row is [c_kv | k_r | zeros to a lane tile]. Prefill does not
      absorb: k_h = [c_kv W_uk,h | k_r], v_h = c_kv W_uv,h are rebuilt
-     from the cached rows a block of keys at a time (`latent_prefill`).
+     from the cached rows a block of keys at a time (`latent_prefill`:
+     in VMEM by the blocked kernel behind `ops/paged_attention.
+     paged_attention_latent`, in HBM by `latent_prefill_attention`, the
+     XLA form, the CPU path and the oracle).
      Decode absorbs: q~_h = q_nope,h W_uk,h^T, scores q~_h . c_kv +
      q_rope,h . k_r over the rows themselves, context sum_s p_s c_kv,s,
      then W_uv,h (`latent_decode`). Softmax scale 1/sqrt(nope + rope).
@@ -801,10 +804,10 @@ def _qkv(h, lp, config: ModelConfig, kind: str, positions):
 
 ATTENTION_SCOPES = {"*": "attn_full", "W": "attn_window", "L": "attn_latent"}
 
-# Keys a step of a latent layer's prefill attention: their K and V are
-# rebuilt from the cached rows and scored against every query of the
-# launch (at most the 2,048 positions `prefill_launch_fits` allows), so
-# the float32 scores [rows, heads, T, keys] are 134 MB at 128 heads.
+# Keys a step of a latent layer's prefill attention in XLA: their K and
+# V are rebuilt from the cached rows and scored against every query of
+# the launch (at most the 2,048 positions `prefill_launch_fits` allows),
+# so the float32 scores [rows, heads, T, keys] are 134 MB at 128 heads.
 LATENT_KEY_BLOCK = 128
 
 
@@ -842,7 +845,10 @@ def _latent_projections(h, lp, config: ModelConfig, positions):
 def latent_prefill_attention(q_nope, q_rope, kv_cache, layer, block_tables,
                              positions, kv_lens, w_uk, w_uv,
                              config: ModelConfig):
-    """Causal attention of a launch's queries over the rows' cached
+    """The XLA form of a latent layer's prefill attention: the CPU
+    path, the oracle of `ops/paged_attention.paged_attention_latent`
+    (same signature) and what a geometry its kernel refuses takes.
+    Causal attention of a launch's queries over the rows' cached
     latents WITHOUT absorbing: a block of LATENT_KEY_BLOCK keys at a
     time (a loop as long as the longest row's context needs), the
     block's rows are gathered straight from the pool, expanded to
@@ -899,15 +905,19 @@ def latent_prefill_attention(q_nope, q_rope, kv_cache, layer, block_tables,
 
 
 def latent_prefill(h, lp, config: ModelConfig, kv_cache, layer,
-                   block_tables, positions, kv_lens, valid):
+                   block_tables, positions, kv_lens, valid,
+                   attention_fn=None):
     """A latent layer over a prefill chunk a row: the chunk's rows go
-    into the pool first, then attention reads the pool. Returns
+    into the pool first, then attention reads the pool, through
+    `attention_fn` (`ops/paged_attention.paged_attention_latent`: the
+    blocked kernel) or, without one, through
+    `latent_prefill_attention`, whose signature it has. Returns
     (kv_cache, out [B, T, hidden])."""
     with jax.named_scope(ATTENTION_SCOPES["L"]):
         q_nope, q_rope, row = _latent_projections(h, lp, config, positions)
         kv_cache = write_latent_pages(kv_cache, layer, row, block_tables,
                                       positions, valid)
-        attn = latent_prefill_attention(
+        attn = (attention_fn or latent_prefill_attention)(
             q_nope, q_rope, kv_cache, layer, block_tables, positions,
             kv_lens, lp["w_uk"], lp["w_uv"], config)
         return kv_cache, jnp.einsum("btqv,qvh->bth", attn, lp["wo"])
@@ -1260,7 +1270,7 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
         elif kind == "L":
             kv_cache, out = latent_prefill(h, lp, config, kv_cache, kv_idx,
                                            block_tables, positions, kv_lens,
-                                           valid)
+                                           valid, attention_fn)
             kv_idx += 1
         elif kind == "D":
             out = dense_mixer(h, lp)
@@ -1396,14 +1406,14 @@ class HybridSteps:
 
         self.config = config
         user = attention_fn is not None
-        self.attention_fn = attention_fn if user else kernels["prefill"]
-        # a single-stack latent pool has a decode kernel of its own
-        self.decode_attention_fn = None if user else kernels[
-            "decode_latent" if config.has_latent_layers else "decode"]
-        # page groups whose prefill layers run through `attention_fn`: a
-        # latent layer's prefill rebuilds keys and values from the pool
-        # and scores them itself
-        self.attention_groups = () if config.has_latent_layers else tuple(
+        # a single-stack latent pool has kernels of its own
+        latent = "_latent" if config.has_latent_layers else ""
+        self.attention_fn = (attention_fn if user
+                             else kernels["prefill" + latent])
+        self.decode_attention_fn = (None if user
+                                    else kernels["decode" + latent])
+        # page groups whose prefill layers run through `attention_fn`
+        self.attention_groups = tuple(
             group for group, layers in (("full", config.kv_layers),
                                         ("window", config.window_kv_layers))
             if layers)

@@ -1680,7 +1680,8 @@ class DenseSteps:
     the statistics are None: no leaf more than the forwards take.
 
     `kernels`: what the runner's mesh and backend run in each attention
-    slot (prefill, decode, decode_latent, spec; None for the XLA form).
+    slot (prefill, prefill_latent, decode, decode_latent, spec; None for
+    the XLA form).
     A caller's own `attention_fn` takes every path instead."""
 
     stats_size = 0  # no dropless experts

@@ -353,6 +353,62 @@ def _next_chunk(lengths_ref, bi, ci, *, bk: int, n_chunks: int,
     return jax.lax.cond(more, lambda: (bi, ci + 1), advance_b)
 
 
+class _PrefillWalk:
+    """The liveness rule of a prefill kernel's (row, query block, key
+    chunk) grid on its scalar-prefetched `starts` and `lengths`, and the
+    walk to the next live step in grid order: what both prefill kernels
+    (`_pool_prefill_kernel`, `_latent_prefill_kernel`) fetch and score,
+    and what `count_prefill_blocks` counts on the host. `window` > 0: a
+    window layer's lower edge (`_pool_prefill_kernel`)."""
+
+    def __init__(self, starts_ref, lengths_ref, *, block_q: int, bk: int,
+                 batch_size: int, window: int = 0):
+        self.starts_ref, self.lengths_ref = starts_ref, lengths_ref
+        self.block_q, self.bk = block_q, bk
+        self.batch_size, self.window = batch_size, window
+
+    def block_live(self, bi, qi):
+        return qi * self.block_q < self.lengths_ref[bi] - self.starts_ref[bi]
+
+    def key_limit(self, bi, qi):  # keys the block's last query sees
+        return jnp.minimum(self.lengths_ref[bi],
+                           self.starts_ref[bi] + (qi + 1) * self.block_q)
+
+    def first_chunk(self, bi, qi):  # the block's lowest live chunk
+        if not self.window:
+            return jnp.int32(0)
+        edge = self.starts_ref[jnp.minimum(bi, self.batch_size - 1)] + (
+            qi * self.block_q - (self.window - 1))
+        return _div(jnp.maximum(edge, 0), self.bk)
+
+    def next_step(self, b, i, c, n_q, n_chunks):
+        """The next live (row, query block, chunk) after grid step
+        (b, i, c); row == batch_size when nothing is left."""
+        batch_size = self.batch_size
+
+        def next_row():
+            nb = jax.lax.fori_loop(
+                0, batch_size,
+                lambda _, cur: jnp.where(
+                    jnp.logical_and(
+                        cur < batch_size,
+                        jnp.logical_not(self.block_live(
+                            jnp.clip(cur, 0, batch_size - 1), 0))),
+                    cur + 1, cur),
+                b + 1)
+            return nb, jnp.int32(0), self.first_chunk(nb, 0)
+
+        def next_block():
+            more = jnp.logical_and(i + 1 < n_q, self.block_live(b, i + 1))
+            return jax.lax.cond(
+                more, lambda: (b, i + 1, self.first_chunk(b, i + 1)),
+                next_row)
+
+        more = jnp.logical_and(c + 1 < n_chunks,
+                               (c + 1) * self.bk < self.key_limit(b, i))
+        return jax.lax.cond(more, lambda: (b, i, c + 1), next_block)
+
+
 def _token_scale_row(sc, kh: int):
     """[n_tok, lanes] lane-broadcast per-token scales (tokens on sublanes,
     as the pool stores them) -> f32 [1, n_tok * kh] with entry t*kh + h =
@@ -888,6 +944,28 @@ _LATENT_CHUNK_TOKENS = 1024
 _LATENT_BLOCK_TOKENS = 256
 
 
+def _latent_flash_update(s, rows, rank: int, dtype, m_ref, l_ref, o_ref):
+    """One block of cached rows folded into a running softmax, the step
+    both latent kernels share: s [queries, tokens] float32 scores,
+    masked to -inf (every query row's running max is finite once this
+    block is in: the caller's to see to), rows [tokens, width] the
+    block, whose first `rank` lanes are its values; the probabilities
+    are rounded once, to `dtype`, for the MXU. m_ref, l_ref [queries,
+    128] lane-broadcast and o_ref [queries, rank], float32 scratch."""
+    m_prev = m_ref[:, 0:1]
+    l_prev = l_ref[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(dtype), rows[:, :rank], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)  # [queries, rank]
+    o_ref[...] = o_ref[...] * alpha + pv
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
 def _latent_decode_kernel(
     # scalar prefetch
     lengths_ref,  # [B] int32 HISTORY lengths (current token excluded)
@@ -986,19 +1064,9 @@ def _latent_decode_kernel(
                 preferred_element_type=jnp.float32) * sm_scale
             s = jnp.where(col < length - (i * bk + u * block_tok), s,
                           -jnp.inf)
-            m_prev = m_ref[:, 0:1]
-            l_prev = l_ref[:, 0:1]
             # finite: the block's first token is live
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p.astype(q.dtype), rows[:, :rank], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [heads, rank]
-            o_ref[...] = o_ref[...] * alpha + pv
-            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+            _latent_flash_update(s, rows, rank, q.dtype, m_ref, l_ref,
+                                 o_ref)
 
         flash_block(0)
         for u in range(1, n_blocks):
@@ -1118,6 +1186,316 @@ def paged_attention_decode_latent(
         rank=rank, sm_scale=sm_scale, pages_per_chunk=pages_per_chunk,
         interpret=interpret)
     return combine_current_latent(q, acc, m, l, row_cur, rank, sm_scale)
+
+
+# A latent layer's prefill tiles: a grid step scores one key chunk of
+# _LATENT_PREFILL_CHUNK_TOKENS cached rows against a query block of
+# _LATENT_PREFILL_POSITIONS positions, for a group of
+# _LATENT_PREFILL_HEADS heads in turn, whose W_uk and W_uv blocks are in
+# VMEM (2 x 2 MB at the published sizes), _LATENT_PREFILL_UNROLL of them
+# a turn of the loop. At 512 positions a chunk's expansion (262 kFLOP a
+# key a head) is 40% of a step's operations; two heads a turn run in
+# 89-94% of one's time and lower as fast, four in 84-87% and lower 1 s a
+# kernel slower (PERF.md, PR 50: five kernels a program, twelve
+# programs).
+_LATENT_PREFILL_POSITIONS = 512
+_LATENT_PREFILL_CHUNK_TOKENS = 512
+_LATENT_PREFILL_HEADS = 16
+_LATENT_PREFILL_UNROLL = 2
+
+
+def latent_prefill_tiles(t: int, nope: int, v: int, rank: int, width: int,
+                         page_size: int, max_pages: int, pool_dtype,
+                         interpret: bool = False):
+    """(query positions a block, key tokens a chunk) of
+    `paged_prefill_attention_latent` for a launch of `t` positions a row
+    over `max_pages`-wide tables of a single-stack pool of `width`-lane
+    rows (`rank` latent values, the rope key behind them), heads of
+    `nope` + rope query lanes and `v` value lanes; or None where the
+    launch takes `models.hybrid.latent_prefill_attention`, the XLA form:
+    Mosaic wants a bf16 pool, every lane count in whole lane tiles (the
+    published 128 | 128 | 512 | 640: the rope lanes are padded to the
+    rows' own behind the latent), query blocks and pages in whole
+    sublane tiles and a chunk in whole lane tiles; the interpreter takes
+    any geometry."""
+    block_q = _largest_divisor(t, _LATENT_PREFILL_POSITIONS)
+    chunk = page_size * _largest_divisor(
+        max_pages, max(1, _LATENT_PREFILL_CHUNK_TOKENS // page_size))
+    if t < 2 or not 0 < rank < width:
+        return None
+    if not interpret and (
+            jnp.dtype(pool_dtype) != jnp.dtype(jnp.bfloat16)
+            or nope % 128 or v % 128 or rank % 128 or width % 128
+            or block_q % 16 or page_size % 16 or chunk % 128):
+        return None
+    return block_q, chunk
+
+
+def _latent_prefill_kernel(
+    # scalar prefetch
+    starts_ref,  # [B] int32 position of a row's first query
+    lengths_ref,  # [B] int32 keys a row sees, this chunk's included
+    tables_ref,  # [B * max_pages] int32 flattened block tables
+    layer_ref,  # [1] int32
+    buf_idx_ref,  # [1] int32 (double-buffer slot)
+    init_ref,  # [1] int32 (1 where the next live step starts its own DMA)
+    q_ref,  # [1, G, block_q, nope + rope lanes]: [q_nope | q_rope | 0]
+    w_uk_ref,  # [G, nope, rank]: this head group's
+    w_uv_ref,  # [G, rank, v]
+    pool_ref,  # FULL [L, P, ps, width] in HBM (memory_space=ANY)
+    o_ref,  # [1, G, block_q, v]
+    kv_buf,  # [2, C, ps, width] page chunks
+    sems,  # DMA semaphores (2,)
+    m_ref, l_ref,  # [G, block_q, 128] f32
+    acc_ref,  # [G, block_q, v] f32
+    *,
+    block_q: int,
+    pages_per_chunk: int,
+    max_pages: int,
+    batch_size: int,
+    sm_scale: float,
+):
+    """Blocked causal attention of a prefill launch over a single-stack
+    LATENT pool, the launch's own rows included (`write_latent_pages`
+    has put them there), in the form that does NOT absorb: the algebra
+    of `models.hybrid.latent_prefill_attention`, with the keys and
+    values of a chunk rebuilt in VMEM and no score or accumulator in
+    HBM.
+
+    Grid (head group, row, query block, key chunk), run in order. Within
+    a head group the liveness rule and the walk are
+    `_pool_prefill_kernel`'s (`_PrefillWalk`): chunks wholly above a
+    query block's last position, chunks past the row's keys, query
+    blocks past the row's valid positions and rows of length 0 are
+    never fetched or scored; a dead block's output is zeros. A query
+    block is `block_q` consecutive positions of one row for a group of
+    G heads, head-major ([B, heads, T, lanes]: a head is a leading
+    index, so the heads of a group are a loop, not unrolled). A step
+    takes the chunk's cached rows [c_kv | k_r] once for the group and,
+    a head: k_h = c_kv W_uk,h^T and v_h = c_kv W_uv,h (the expansion,
+    rounded to the operand dtype as the XLA form's), S = q_nope k_h^T +
+    q_rope k_r^T, the causal edge a compare a row, and the flash update
+    `_latent_decode_kernel` has, on the head's own float32 state. The
+    pool streams as there: it stays in HBM, a page a DMA through the
+    scalar-prefetched table into a double-buffered chunk, the next live
+    step's chunk started before this one's is awaited (not across a
+    head group's end: its first live step starts its own); the pages of
+    a chunk are a loop. The keys are streamed once a head group (1.28 KB
+    a key against 0.4 MFLOP a key a head)."""
+    b = pl.program_id(1)
+    i = pl.program_id(2)
+    c = pl.program_id(3)
+    n_chunks = pl.num_programs(3)
+    ps, width = kv_buf.shape[2:]
+    group, nope, rank = w_uk_ref.shape
+    bk = pages_per_chunk * ps
+    layer = layer_ref[0]
+    walk = _PrefillWalk(starts_ref, lengths_ref, block_q=block_q, bk=bk,
+                        batch_size=batch_size)
+
+    def chunk_copies(bi, ci, slot, fn):
+        base = bi * max_pages + ci * pages_per_chunk
+
+        @pl.loop(0, pages_per_chunk)
+        def _page(j):
+            fn(pltpu.make_async_copy(
+                pool_ref.at[layer, tables_ref[base + j]],
+                kv_buf.at[slot, j], sems.at[slot]))
+
+    live = walk.block_live(b, i)
+    active = jnp.logical_and(live, c * bk < walk.key_limit(b, i))
+
+    @pl.when(jnp.logical_and(active, init_ref[0] == 1))
+    def _first():
+        chunk_copies(b, c, buf_idx_ref[0], lambda cp: cp.start())
+        init_ref[0] = 0
+
+    @pl.when(jnp.logical_and(c == 0, live))
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(active)
+    def _compute():
+        slot = buf_idx_ref[0]
+        nb, _, nc = walk.next_step(b, i, c, pl.num_programs(2), n_chunks)
+
+        @pl.when(nb < batch_size)
+        def _prefetch():
+            nslot = jnp.where(slot == 0, 1, 0)
+            chunk_copies(nb, nc, nslot, lambda cp: cp.start())
+            buf_idx_ref[0] = nslot
+
+        @pl.when(nb >= batch_size)
+        def _group_ends():
+            init_ref[0] = 1
+
+        chunk_copies(b, c, slot, lambda cp: cp.wait())
+        # Row r of a head's tile is position r of the block; column j is
+        # key c * bk + j. Key 0 is seen by every query of a live row, so
+        # the running max is finite from chunk 0 on.
+        q_pos = (starts_ref[b] + i * block_q
+                 + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0))
+        k_pos = c * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        seen = jnp.logical_and(k_pos <= q_pos, k_pos < lengths_ref[b])
+        rows = kv_buf[slot].reshape(bk, width)
+        c_kv, k_r = rows[:, :rank], rows[:, rank:]
+        nt = (((1,), (1,)), ((), ()))
+
+        def flash_head(h):
+            q = q_ref[0, h]  # [block_q, nope + rope lanes]
+            k = jax.lax.dot_general(
+                c_kv, w_uk_ref[h], nt,
+                preferred_element_type=jnp.float32).astype(q.dtype)
+            v = jnp.dot(c_kv, w_uv_ref[h],
+                        preferred_element_type=jnp.float32).astype(q.dtype)
+            s = (jax.lax.dot_general(q[:, :nope], k, nt,
+                                     preferred_element_type=jnp.float32)
+                 + jax.lax.dot_general(q[:, nope:], k_r, nt,
+                                       preferred_element_type=jnp.float32)
+                 ) * sm_scale
+            _latent_flash_update(jnp.where(seen, s, -jnp.inf), v,
+                                 v.shape[1], q.dtype, m_ref.at[h],
+                                 l_ref.at[h], acc_ref.at[h])
+
+        # _LATENT_PREFILL_UNROLL heads a turn: independent chains, so
+        # that one head's softmax overlaps another's matmuls
+        unroll = _largest_divisor(group, _LATENT_PREFILL_UNROLL)
+
+        @pl.loop(0, group // unroll)
+        def _heads(g):
+            for u in range(unroll):
+                flash_head(g * unroll + u)
+
+    @pl.when(jnp.logical_and(c == n_chunks - 1, live))
+    def _finish():
+        o_ref[0] = (acc_ref[...] / l_ref[:, :, 0:1]).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_and(c == n_chunks - 1, jnp.logical_not(live)))
+    def _padding():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"),
+                   donate_argnums=())  # read-only on the whole pool
+def paged_prefill_attention_latent(
+    q: jax.Array,  # [B, heads, T, nope + rope lanes]: [q_nope | q_rope | 0]
+    w_uk: jax.Array,  # [heads, nope, rank]
+    w_uv: jax.Array,  # [heads, rank, v]
+    kv_pool: jax.Array,  # [L, 1, P, ps, 1, width]: the WHOLE latent cache
+    layer: jax.Array,  # scalar int32
+    block_tables: jax.Array,  # [B, max_pages] int32
+    starts: jax.Array,  # [B] int32 position of each row's first query
+    kv_lens: jax.Array,  # [B] int32 keys a row sees, this chunk's included
+    *,
+    sm_scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal attention of a prefill launch over a latent pool,
+    `_latent_prefill_kernel`: row b holds the consecutive positions
+    starts[b].. of which the first kv_lens[b] - starts[b] are real; the
+    queries head-major with the rope lanes padded to the rows' own
+    (`width - rank`); returns [B, heads, T, v] in their dtype. The
+    caller (`paged_attention_latent`) has checked the geometry with
+    `latent_prefill_tiles`."""
+    b, heads, t, lanes = q.shape
+    nope, rank = w_uk.shape[1:]
+    vd = w_uv.shape[-1]
+    n_layers, _, n_pages, ps = kv_pool.shape[:4]
+    width = kv_pool.shape[-1]
+    max_pages = block_tables.shape[1]
+    block_q, chunk = latent_prefill_tiles(
+        t, nope, vd, rank, width, ps, max_pages, kv_pool.dtype, interpret)
+    group = _largest_divisor(heads, _LATENT_PREFILL_HEADS)
+    ppc = chunk // ps
+    assert t % block_q == 0 and max_pages % ppc == 0
+    assert lanes - nope == width - rank
+
+    def q_map(hg, bi, qi, ci, *refs):
+        del ci, refs
+        return (bi, hg, qi, 0)
+
+    def w_map(hg, bi, qi, ci, *refs):
+        del bi, qi, ci, refs
+        return (hg, 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_latent_prefill_kernel, block_q=block_q,
+                          pages_per_chunk=ppc, max_pages=max_pages,
+                          batch_size=b, sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(heads // group, b, t // block_q, max_pages // ppc),
+            in_specs=[pl.BlockSpec((1, group, block_q, lanes), q_map),
+                      pl.BlockSpec((group, nope, rank), w_map),
+                      pl.BlockSpec((group, rank, vd), w_map),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, group, block_q, vd), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((2, ppc, ps, width), kv_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((group, block_q, 128), jnp.float32),
+                pltpu.VMEM((group, block_q, 128), jnp.float32),
+                pltpu.VMEM((group, block_q, vd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, heads, t, vd), q.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 4,
+            vmem_limit_bytes=_PREFILL_VMEM_BYTES),
+        name="paged_prefill_attention_latent",
+    )(starts.astype(jnp.int32), kv_lens.astype(jnp.int32),
+      block_tables.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32),
+      q, w_uk, w_uv, kv_pool.reshape(n_layers, n_pages, ps, width))
+
+
+def paged_attention_latent(
+    q_nope: jax.Array,  # [B, T, heads, nope]
+    q_rope: jax.Array,  # [B, T, heads, rope] roped
+    kv_cache: jax.Array,  # [L, 1, P, ps, 1, width]
+    layer,
+    block_tables: jax.Array,
+    positions: jax.Array,  # [B, T]: a row's positions are consecutive
+    kv_lens: jax.Array,
+    w_uk: jax.Array,  # [heads, nope, rank]
+    w_uv: jax.Array,  # [heads, rank, v]
+    config,
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """Drop-in for `models.hybrid.latent_prefill_attention`, a latent
+    layer's `attention_fn`: wherever `latent_prefill_tiles` admits the
+    geometry the launch runs the blocked kernel over the latent pool
+    (`paged_prefill_attention_latent`), the same algebra with nothing of
+    it in HBM: the XLA form wrote float32 scores [rows, heads, T, 128]
+    four or five times a key block and an accumulator as large beside
+    them, a third of the pangu cell's device time (PERF.md, PR 50). The
+    relayout to head-major and back is XLA's, under 0.1 ms a layer. A row's
+    first query position is `positions[:, 0]` and its valid count
+    `kv_lens - positions[:, 0]`, as every prefill launch lays its rows
+    out. Everything else takes the XLA form, the CPU path and the
+    oracle. Returns [B, T, heads, v] in q's dtype."""
+    from ..models.hybrid import _latent_sizes, latent_prefill_attention
+
+    rank, _, scale = _latent_sizes(config)
+    nope, width = q_nope.shape[-1], kv_cache.shape[-1]
+    if latent_prefill_tiles(
+            q_nope.shape[1], nope, w_uv.shape[-1], rank, width,
+            kv_cache.shape[3], block_tables.shape[1], kv_cache.dtype,
+            interpret) is None:
+        return latent_prefill_attention(
+            q_nope, q_rope, kv_cache, layer, block_tables, positions,
+            kv_lens, w_uk, w_uv, config)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    q = jnp.pad(q, [(0, 0)] * 3 + [(0, nope + width - rank - q.shape[-1])])
+    out = paged_prefill_attention_latent(
+        jnp.moveaxis(q, 2, 1), w_uk, w_uv, kv_cache, layer, block_tables,
+        positions[:, 0], kv_lens, sm_scale=scale, interpret=interpret)
+    return jnp.moveaxis(out, 1, 2)
 
 
 def make_paged_attention_decode_pool_tp(mesh, *,
@@ -1472,19 +1850,10 @@ def _pool_prefill_kernel(
     if sm_scale is None:  # a model that states no scale of its own
         sm_scale = 1.0 / math.sqrt(hd)
 
-    def block_live(bi, qi):
-        return qi * block_q < lengths_ref[bi] - starts_ref[bi]
-
-    def key_limit(bi, qi):  # keys the block's last query sees
-        return jnp.minimum(lengths_ref[bi],
-                           starts_ref[bi] + (qi + 1) * block_q)
-
-    def first_chunk(bi, qi):  # the block's lowest live chunk
-        if not window:
-            return jnp.int32(0)
-        edge = starts_ref[jnp.minimum(bi, batch_size - 1)] + (
-            qi * block_q - (window - 1))
-        return _div(jnp.maximum(edge, 0), bk)
+    walk = _PrefillWalk(starts_ref, lengths_ref, block_q=block_q, bk=bk,
+                        batch_size=batch_size, window=window)
+    block_live, key_limit = walk.block_live, walk.key_limit
+    first_chunk = walk.first_chunk
 
     def chunk_copies(bi, ci, slot, fn):
         base = bi * max_pages + ci * pages_per_chunk
@@ -1499,30 +1868,6 @@ def _pool_prefill_kernel(
                 fn(pltpu.make_async_copy(
                     scale_ref.at[layer, :, page], sc_buf.at[slot, :, j],
                     sems.at[slot]))
-
-    def next_step():
-        """The next live (row, query block, chunk) in grid order; row ==
-        batch_size when nothing is left."""
-        def next_row():
-            nb = jax.lax.fori_loop(
-                0, batch_size,
-                lambda _, cur: jnp.where(
-                    jnp.logical_and(
-                        cur < batch_size,
-                        jnp.logical_not(block_live(
-                            jnp.clip(cur, 0, batch_size - 1), 0))),
-                    cur + 1, cur),
-                b + 1)
-            return nb, jnp.int32(0), first_chunk(nb, 0)
-
-        def next_block():
-            more = jnp.logical_and(i + 1 < n_q, block_live(b, i + 1))
-            return jax.lax.cond(
-                more, lambda: (b, i + 1, first_chunk(b, i + 1)), next_row)
-
-        more = jnp.logical_and(c + 1 < n_chunks,
-                               (c + 1) * bk < key_limit(b, i))
-        return jax.lax.cond(more, lambda: (b, i, c + 1), next_block)
 
     live = block_live(b, i)
     active = jnp.logical_and(live, c * bk < key_limit(b, i))
@@ -1544,7 +1889,7 @@ def _pool_prefill_kernel(
     @pl.when(active)
     def _compute():
         slot = buf_idx_ref[0]
-        nb, _, nc = next_step()
+        nb, _, nc = walk.next_step(b, i, c, n_q, n_chunks)
 
         @pl.when(nb < batch_size)
         def _prefetch():

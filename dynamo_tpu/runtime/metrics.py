@@ -444,8 +444,8 @@ PREFILL_ATTN_LAUNCHES = Gauge(
     "dynamo_prefill_attn_launches_total",
     "Prefill launches since start by the path their attention layers "
     "took: kernel (the blocked Pallas prefill kernel over the paged "
-    "pool, in every page group) | xla (gathered pages, a full float32 "
-    "score tensor)",
+    "pool, in every page group; a latent stack's over its latent rows) "
+    "| xla (gathered pages, a full float32 score tensor)",
     ["worker", "path"], registry=REGISTRY,
 )
 PREFILL_ATTN_BLOCKS = Gauge(
@@ -457,7 +457,8 @@ PREFILL_ATTN_BLOCKS = Gauge(
     "holds both), and by page group: full (a full-attention layer over "
     "the sequence's table: every model's but for its window layers) | "
     "window (a window layer over its own group's table, a model with "
-    "window layers only)",
+    "window layers only) | latent (a latent layer over the single stack "
+    "of latent rows, in place of full)",
     ["worker", "state", "group"], registry=REGISTRY,
 )
 LATENT_DECODE_TOKENS = Gauge(
@@ -473,8 +474,10 @@ LATENT_PREFILL_EXPAND_TOKENS = Gauge(
     "dynamo_latent_prefill_expand_tokens_total",
     "Model with latent attention: cached positions whose keys and "
     "values prefill launches have rebuilt from their latents since "
-    "start (every row's context up to its chunk's end, x latent "
-    "layers). Over the growth of dynamo_engine_tokens{kind=prefill} x "
+    "start (the XLA form: every row's context up to its chunk's end, "
+    "once a launch; the kernel: a key chunk for every query block that "
+    "sees it; x latent layers). Over the growth of "
+    "dynamo_engine_tokens{kind=prefill} x "
     "latent layers it is what chunked prefill that does not absorb "
     "pays again: 1 for a prompt prefilled in one launch",
     ["worker"], registry=REGISTRY,

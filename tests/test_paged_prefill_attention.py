@@ -15,6 +15,7 @@ from dynamo_tpu.models.transformer import (
 )
 from dynamo_tpu.ops.paged_attention import (
     count_prefill_blocks,
+    latent_prefill_tiles,
     paged_attention,
     prefill_kernel_tiles,
     prefill_table_pages,
@@ -217,3 +218,89 @@ def test_the_kernel_admits_the_mellum_cells_window_tables(bucket, width):
     assert prefill_table_pages(-(-(1024 + bucket) // PAGE) + 1, PAGE) == width
     assert prefill_kernel_tiles(bucket, 32, 4, HEAD_DIM, PAGE, width,
                                 jnp.bfloat16) == (128, 256)
+
+
+# -- a latent layer's kernel (`paged_prefill_attention_latent`) ---------------
+# latent_prefill_tiles(t, nope, v, rank, width, page, table pages, pool)
+
+PANGU = (128, 128, 512, 640, PAGE, 384, jnp.bfloat16)  # the cell's geometry
+
+
+@pytest.mark.parametrize("bucket", [256, 512, 1024, 1536, 2048])
+def test_the_pangu_cells_buckets_take_the_latent_kernel(bucket):
+    # query blocks of at most 512 positions, key chunks of 32 pages
+    assert latent_prefill_tiles(bucket, *PANGU) == (min(bucket, 512), 512)
+
+
+@pytest.mark.parametrize("why,args", [
+    ("one token", (1, *PANGU)),
+    ("a float32 pool", (256, *PANGU[:-1], jnp.float32)),
+    ("an int8 pool", (256, *PANGU[:-1], jnp.int8)),
+    ("rows that are all latent: a two-stack pool's", (256, 128, 128, 512,
+                                                      512, PAGE, 384,
+                                                      jnp.bfloat16)),
+    ("the loader's unpadded rows of 576 lanes", (256, 128, 128, 512, 576,
+                                                 PAGE, 384, jnp.bfloat16)),
+    ("nope lanes under a lane tile", (256, 64, 128, 512, 640, PAGE, 384,
+                                      jnp.bfloat16)),
+    ("value lanes under a lane tile", (256, 128, 64, 512, 640, PAGE, 384,
+                                       jnp.bfloat16)),
+    ("a rank that is no lane tile", (256, 128, 128, 448, 640, PAGE, 384,
+                                     jnp.bfloat16)),
+    ("pages under a sublane tile", (256, 128, 128, 512, 640, 8, 384,
+                                    jnp.bfloat16)),
+    ("a key chunk under a lane tile", (256, 128, 128, 512, 640, PAGE, 4,
+                                       jnp.bfloat16)),
+    ("a query block under a sublane tile", (250, *PANGU)),
+])
+def test_latent_geometries_the_kernel_leaves_to_xla(why, args):
+    assert latent_prefill_tiles(*args) is None, why
+    # the interpreter has no tiles to fill: any single stack of rows
+    # with something behind the latent, two positions or more
+    took = latent_prefill_tiles(*args, interpret=True)
+    assert (took is None) == (args[0] < 2 or args[3] >= args[4]), why
+
+
+def test_the_latent_kernel_is_the_slot_of_one_device_off_the_xla_path(
+        monkeypatch):
+    """Where a latent layer's prefill goes: the runner's mesh and
+    backend fill the slot (`_mesh_kernels`), the geometry rule decides a
+    launch. On the CPU the slot is empty unless DYNT_ATTENTION asks for
+    the interpreter; with more than one device it is empty."""
+    import jax
+
+    from dynamo_tpu.engine.model_runner import _mesh_kernels
+    from dynamo_tpu.parallel import MeshConfig, make_mesh
+
+    one = make_mesh(MeshConfig(), jax.devices()[:1])
+    two = make_mesh(MeshConfig(tp=2), jax.devices()[:2])
+    assert _mesh_kernels(one)["prefill_latent"] is None  # the CPU: XLA
+    monkeypatch.setenv("DYNT_ATTENTION", "pallas")
+    slot = _mesh_kernels(one)["prefill_latent"]
+    assert slot.func.__name__ == "paged_attention_latent"
+    assert slot.keywords == {"interpret": True}
+    assert _mesh_kernels(two)["prefill_latent"] is None
+    assert _mesh_kernels(two)["decode_latent"] is None
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_block_counts_with_the_latent_kernels_tiles_match_brute_force(seed):
+    """`count_prefill_blocks` on the tiles the latent kernel runs the
+    cell's launches with (rows x bucket = 2,048 positions over tables of
+    6,144 tokens) against the pairs that hold a (query, key) the causal
+    mask admits."""
+    rng = np.random.default_rng(seed)
+    table = 384 * PAGE
+    for rows, bucket in ((1, 2048), (2, 1024), (4, 512), (8, 256)):
+        block_q, chunk = latent_prefill_tiles(bucket, *PANGU)
+        used = int(rng.integers(1, rows + 1))
+        starts = [int(s) for s in rng.integers(0, table - bucket, used)]
+        lens = [s + int(n)
+                for s, n in zip(starts, rng.integers(1, bucket + 1, used))]
+        assert count_prefill_blocks(
+            starts, lens, rows, bucket, block_q, chunk, table
+        ) == _brute_force_blocks(starts, lens, rows, bucket, block_q, chunk,
+                                 table, 0)
+    # a fresh 2,048-token prompt: 1 + 2 + 3 + 4 of 4 x 12 pairs
+    assert count_prefill_blocks([0], [2048], 1, 2048, 512, 512,
+                                table) == (10, 38)

@@ -173,6 +173,9 @@ def test_chunked_prefill_then_decode_through_the_latent_pool(runner,
     # five latent layers: a decode step at context c reads c cached rows
     assert runner.latent_decode_tokens - before[0] == 5 * sum(
         range(119, 159))
+    # and on the XLA path, which this runner's launches take, a launch
+    # rebuilds its row's whole context from the latents
+    assert runner.prefill_attention_path() == "xla"
     assert runner.latent_prefill_expand_tokens - before[1] == 5 * (
         32 + 64 + 96 + 119)
     # the same comparison with a piece of the mathematics left out FAILS
@@ -305,6 +308,122 @@ def test_prefill_attention_by_key_blocks_equals_one_pass(monkeypatch):
                                    atol=2e-4, rtol=2e-4)
 
 
+# name: (positions a row (the bucket), table width in pages, rows as (first
+# position, valid positions)); query blocks of 16 positions, chunks of 32
+# keys, head groups of 2 of the 4 heads
+LATENT_LAUNCHES = {
+    "fresh": (32, 16, [(0, 32)]),
+    "second-chunk-over-cached-history": (32, 16, [(32, 32)]),
+    "two-rows-of-unequal-length-and-padding": (32, 16, [(0, 20), (37, 27)]),
+    "a-row-of-length-0": (16, 8, [(0, 0), (5, 16)]),
+    # 13 pages, a prime: no chunk of two pages divides the table, so
+    # its chunks are one page, and the row's keys end inside the last
+    "ragged-table-of-one-page-chunks": (32, 13, [(170, 30)]),
+    "eight-short-rows": (16, 16, [(0, 16), (0, 9), (64, 16), (128, 5),
+                                  (32, 16), (0, 16), (230, 16), (0, 0)]),
+}
+
+
+def attention_ops():
+    """The module (`dynamo_tpu.ops` exports a function of its name)."""
+    import importlib
+
+    return importlib.import_module("dynamo_tpu.ops.paged_attention")
+
+
+@pytest.fixture
+def latent_tiles(monkeypatch):
+    """The latent prefill kernel's tiles for one test: small enough that
+    a tiny launch walks several query blocks, key chunks and head
+    groups. The jitted entry reads them while it traces, so its traces
+    go before the next test's."""
+    ops = attention_ops()
+
+    def set_tiles(positions, chunk, heads=16):
+        monkeypatch.setattr(ops, "_LATENT_PREFILL_POSITIONS", positions)
+        monkeypatch.setattr(ops, "_LATENT_PREFILL_CHUNK_TOKENS", chunk)
+        monkeypatch.setattr(ops, "_LATENT_PREFILL_HEADS", heads)
+        ops.paged_prefill_attention_latent.clear_cache()
+
+    yield set_tiles
+    ops.paged_prefill_attention_latent.clear_cache()
+
+
+def latent_launch(case, dtype, seed=0):
+    """`latent_prefill_attention`'s arguments for a launch over a pool
+    of rows 40 wide padded to 128 lanes, 32 of them values."""
+    t, width, rows = LATENT_LAUNCHES[case]
+    rng = np.random.default_rng(seed)
+    c = CONFIG
+    heads, nope, rd = c.n_q_heads, c.mla_nope_head_dim, c.mla_rope_head_dim
+    rank = c.mla_kv_lora_rank
+    b, n_pages = len(rows), len(rows) * width + 1
+    cache = np.zeros((2, 1, n_pages, PAGE, 1, 128), np.float32)
+    cache[..., :rank + rd] = rng.normal(size=cache.shape[:-1] + (rank + rd,))
+    q_nope = jnp.asarray(rng.normal(size=(b, t, heads, nope)), dtype)
+    q_rope = jnp.asarray(rng.normal(size=(b, t, heads, rd)), dtype)
+    w_uk = jnp.asarray(rng.normal(size=(heads, nope, rank))
+                       / math.sqrt(nope), dtype)
+    w_uv = jnp.asarray(rng.normal(size=(heads, rank, c.mla_v_head_dim))
+                       / math.sqrt(rank), dtype)
+    tables = np.zeros((b, width), np.int32)  # a padded row: the scratch page
+    positions = np.zeros((b, t), np.int32)
+    kv_lens = np.zeros(b, np.int32)
+    pages = rng.permutation(np.arange(1, n_pages))
+    for i, (start, n) in enumerate(rows):
+        if n:
+            tables[i] = pages[i * width:(i + 1) * width]
+            positions[i, :n] = np.arange(start, start + n)
+            kv_lens[i] = start + n
+    return (q_nope, q_rope, jnp.asarray(cache, dtype), 1,
+            jnp.asarray(tables), jnp.asarray(positions),
+            jnp.asarray(kv_lens), w_uk, w_uv, c)
+
+
+@pytest.mark.parametrize("case", sorted(LATENT_LAUNCHES))
+def test_the_latent_prefill_kernel_equals_the_xla_form(case, latent_tiles):
+    """`paged_prefill_attention_latent` (interpreted, behind
+    `paged_attention_latent`: a chunk's keys and values rebuilt a head
+    in the kernel, flash state in scratch) against
+    `latent_prefill_attention`, the same algebra in XLA, at bf16 inputs;
+    query blocks of 16 and key chunks of 32, so every launch walks
+    several of each, for two head groups. The output's spread is ~1; on
+    the chip the two differ by 0.004-0.008 at the cell's shapes
+    (PERF.md, PR 50). A row's padding is nobody's; a row of length 0
+    comes back zeros."""
+    from dynamo_tpu.models.hybrid import latent_prefill_attention
+
+    ops = attention_ops()
+    latent_tiles(16, 32, heads=2)
+    _, width, rows = LATENT_LAUNCHES[case]
+    args = latent_launch(case, jnp.bfloat16)
+    want = np.asarray(latent_prefill_attention(*args), np.float32)
+    got = np.asarray(ops.paged_attention_latent(*args, interpret=True),
+                     np.float32)
+    assert got.shape == want.shape
+    for i, (_, n) in enumerate(rows):
+        if n:
+            assert np.abs(want[i, :n]).max() > 0.5
+            assert np.abs(got[i, :n] - want[i, :n]).max() < 0.01
+            assert np.abs(got[i, :n] - want[i, :n]).mean() < 0.001
+        else:
+            assert not got[i].any()
+    # float32 inputs: the order of the running softmax's sums alone
+    args = latent_launch(case, jnp.float32)
+    want = np.asarray(latent_prefill_attention(*args))
+    got = np.asarray(ops.paged_attention_latent(*args, interpret=True))
+    for i, (_, n) in enumerate(rows):
+        np.testing.assert_allclose(got[i, :n], want[i, :n], atol=2e-5)
+
+
+def test_the_other_layers_rows_would_have_given_another_answer():
+    ops = attention_ops()
+    args = latent_launch("second-chunk-over-cached-history", jnp.float32)
+    got = [np.asarray(ops.paged_attention_latent(
+        *args[:3], layer, *args[4:], interpret=True)) for layer in (0, 1)]
+    assert np.abs(got[0] - got[1]).max() > 1e-2
+
+
 # -- the expert layer and the cut -----------------------------------------------
 
 
@@ -435,6 +554,8 @@ def test_the_engine_serves_rows_at_different_contexts(reference):
         gap = want.max(-1) - want[np.arange(40), c.tokens()]
         assert gap.max() < VS_REFERENCE
     assert sched.runner.latent_decode_tokens > 0
+    # the XLA path's launches: 13 of them, none the kernel's
+    assert sched.runner.prefill_attn_launches["kernel"] == 0
     assert sched.runner.latent_prefill_expand_tokens > 0
     assert sched.pool.free_count() + sched.pool.cached_count() == 63
 
@@ -471,9 +592,53 @@ def test_the_runner_takes_the_latent_kernel_where_it_is_asked_to(
     kernel = make_runner()
     assert kernel.kernel_paths()["decode_attention"] == "interpret"
     assert runner.kernel_paths()["decode_attention"] == "xla"
+    assert kernel.kernel_paths()["prefill_attention"] == "kernel"
+    assert runner.kernel_paths()["prefill_attention"] == "xla"
     prompt = prompt_of(37, seed=11)
     rows = [Row(r, 1, prompt) for r in (runner, kernel)]
     assert rows[0].prefill([32, 5]) == rows[1].prefill([32, 5])
     for _ in range(2):
         np.testing.assert_allclose(rows[1].decode(), rows[0].decode(),
                                    atol=1e-4)
+
+
+def test_chunked_prefill_then_decode_reaches_the_same_tokens_on_both_paths(
+        runner, monkeypatch, latent_tiles):
+    """A 119-token prompt in chunks of 32 and 24 decode steps through a
+    runner whose latent layers prefill in the kernel (interpreted) and
+    through the XLA form's: the same tokens, logits within float32's
+    order of sums; and the counters say which path a launch took: the
+    kernel's launches count their (query block, key chunk) pairs and the
+    positions those rebuild (a chunk a pair), the XLA form's the rows'
+    contexts."""
+    from dynamo_tpu.ops.paged_attention import count_prefill_blocks
+
+    monkeypatch.setenv("DYNT_ATTENTION", "pallas")
+    latent_tiles(8, 64)
+    kernel = make_runner()
+    assert kernel.prefill_attention_path() == "kernel"
+    assert kernel.prefill_attention_tiles(32) == (8, 64)
+    before = (dict(runner.prefill_attn_launches),
+              runner.latent_prefill_expand_tokens)
+    prompt = prompt_of(119, seed=5)
+    rows = [Row(r, 1, prompt) for r in (runner, kernel)]
+    chunks = [32, 32, 32, 23]
+    assert rows[0].prefill(chunks) == rows[1].prefill(chunks)
+    for _ in range(24):
+        np.testing.assert_allclose(rows[1].decode(), rows[0].decode(),
+                                   atol=1e-4)
+    assert rows[0].tokens == rows[1].tokens
+    assert kernel.prefill_attn_launches == {"kernel": 4, "xla": 0}
+    assert kernel.latent_decode_tokens == 5 * sum(range(119, 143))
+    live = sum(count_prefill_blocks([s], [e], 1, 32, 8, 64, PAGE * WIDTH)[0]
+               for s, e in ((0, 32), (32, 64), (64, 96), (96, 119)))
+    # four query blocks a launch (the last launch's fourth is padding)
+    # over one and two key chunks of the table's four
+    assert live == 4 * 1 + 4 * 1 + 4 * 2 + 3 * 2
+    assert kernel.prefill_attn_blocks == {"live": live,
+                                          "skipped": 4 * 4 * 4 - live}
+    assert kernel.latent_prefill_expand_tokens == 5 * live * 64
+    assert runner.prefill_attn_launches["xla"] - before[0]["xla"] == 4
+    assert runner.prefill_attn_launches["kernel"] == before[0]["kernel"] == 0
+    assert runner.latent_prefill_expand_tokens - before[1] == 5 * (
+        32 + 64 + 96 + 119)

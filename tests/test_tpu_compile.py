@@ -470,8 +470,8 @@ def test_pangus_step_programs_fit_the_chip_and_copy_no_pool(one_chip,
         moe_stats_size,
     )
     from dynamo_tpu.ops.paged_attention import (
-        paged_attention,
         paged_attention_decode_latent,
+        paged_attention_latent,
     )
 
     cfg, params, cache = _pangu_programs(one_chip)
@@ -504,7 +504,7 @@ def test_pangus_step_programs_fit_the_chip_and_copy_no_pool(one_chip,
         kv, state, last, stats = forward_hybrid(
             params, cfg, tokens, positions, kv, state, slots, tables,
             kv_lens, valid, last_idx, gmm_path="pallas",
-            attention_fn=functools.partial(paged_attention,
+            attention_fn=functools.partial(paged_attention_latent,
                                            interpret=False))
         return ((kv, state), *sample_with_logprobs(
             last, temperature, top_p, top_k, seeds, jnp.int32(0)), stats)
@@ -530,15 +530,23 @@ def test_pangus_step_programs_fit_the_chip_and_copy_no_pool(one_chip,
             chunk(jnp.bool_), vec(1, jnp.int32), vec(1, jnp.float32),
             vec(1, jnp.float32), vec(1, jnp.int32), vec(1, jnp.uint32),
             vec(1, jnp.int32)).compile()
+        # a latent layer's prefill is the latent pool's own kernel, five
+        # calls of it, and the float32 scores and accumulator of the XLA
+        # form ([1, 128 heads, 2048, 128 keys | values]: 134 MB each,
+        # written and read a key block) are in no array of the program
+        assert len(re.findall(
+            r" custom-call\(.*paged_prefill_attention_latent",
+            compiled.as_text())) == 5
+        assert not re.search(r"f32\[(1,)?128,2048,128\]", compiled.as_text())
     text, memory = compiled.as_text(), compiled.memory_analysis()
     assert "tpu_custom_call" in text  # the experts' grouped matmul at least
-    # a latent layer's prefill is its own: `attention_fn` never sees it
     assert "paged_prefill_attention_pool" not in text
     assert _copies(text, pool_bytes // 5) == []  # not even one layer's
     _assert_the_experts_combine_is_two_passes(
         text, n if program == "decode-block" else 2048, 8, 7680, 4)
+    # 1.38 GB at [1, 2048] (the experts' float32 rows, not attention)
     assert memory.temp_size_in_bytes < (1.0e9 if program == "decode-block"
-                                        else 2.0e9)
+                                        else 1.45e9)
     assert memory.argument_size_in_bytes < 12.4e9
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 15.75 * 2 ** 30)
@@ -559,6 +567,66 @@ def test_the_latent_decode_kernel_compiles_for_v5e(one_chip, width):
         rank=512, sm_scale=1 / math.sqrt(192)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # the pool is read in place: no row-major copy in front of the call
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# sha256 of the latent decode kernel's normalised lowered text
+# (`tools.lowered_text.normalise`: locations stripped, the Mosaic body as
+# the digest of its assembly) at the cell's shapes as the parent of PR 50
+# lowered it (60a4137), by table width: PR 50 moved its flash update into
+# a helper the prefill kernel shares, and the decode side of the cell may
+# not move with it.
+LATENT_DECODE_TEXT = {
+    8: "0f81d68b005b5281cb95043775c307238d60c929c61f3003f9ded9bbe68a41ea",
+    384: "89d6078015dee28370ca9bda0aaa059e7e3161dcec5c3e9b71cb86ac64b7ee76",
+}
+
+
+@pytest.mark.parametrize("width", sorted(LATENT_DECODE_TEXT))
+def test_the_latent_decode_kernel_lowers_to_the_text_it_had(one_chip, width):
+    import hashlib
+
+    from dynamo_tpu.ops.paged_attention import paged_decode_attention_latent
+    from tools.lowered_text import normalise
+
+    text = normalise(paged_decode_attention_latent.lower(
+        _shape(one_chip, (PANGU["rows"], 128, 640), jnp.bfloat16),
+        _shape(one_chip, (5, 1, PANGU["pages"], PAGE, 1, 640), jnp.bfloat16),
+        _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (PANGU["rows"], width), jnp.int32),
+        _shape(one_chip, (PANGU["rows"],), jnp.int32),
+        rank=512, sm_scale=1 / math.sqrt(192)).as_text())
+    assert "mosaic:" in text  # the kernel's body, as its digest
+    assert hashlib.sha256(text.encode()).hexdigest() == LATENT_DECODE_TEXT[
+        width]
+
+
+@pytest.mark.parametrize("rows,t", [(1, 2048), (2, 1024), (8, 256)])
+def test_the_latent_prefill_kernel_compiles_for_v5e(one_chip, rows, t):
+    """`paged_prefill_attention_latent` at the pangu cell's widest
+    launches (rows x bucket = 2,048 positions over 384-page tables; 128
+    heads of 128 + 64 query lanes and 128 value lanes, rows of 512 + 128
+    lanes): Mosaic takes the geometry the rule admits (query blocks of
+    at most 512 positions, chunks of 512 keys), the pool is read in
+    place, and the only temporaries are the kernel's own."""
+    from dynamo_tpu.ops.paged_attention import (
+        latent_prefill_tiles,
+        paged_prefill_attention_latent,
+    )
+
+    assert latent_prefill_tiles(t, 128, 128, 512, 640, PAGE, PANGU["width"],
+                                jnp.bfloat16) == (min(t, 512), 512)
+    compiled = paged_prefill_attention_latent.lower(
+        _shape(one_chip, (rows, 128, t, 256), jnp.bfloat16),
+        _shape(one_chip, (128, 128, 512), jnp.bfloat16),
+        _shape(one_chip, (128, 512, 128), jnp.bfloat16),
+        _shape(one_chip, (5, 1, PANGU["pages"], PAGE, 1, 640), jnp.bfloat16),
+        _shape(one_chip, (), jnp.int32),
+        _shape(one_chip, (rows, PANGU["width"]), jnp.int32),
+        _shape(one_chip, (rows,), jnp.int32),
+        _shape(one_chip, (rows,), jnp.int32),
+        sm_scale=1 / math.sqrt(192)).compile()
+    assert "paged_prefill_attention_latent" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
@@ -846,9 +914,14 @@ def _lowered_hybrid_prefill(one_chip, name, layers, experts, rows, t,
     from dynamo_tpu.models.config import cut_config, get_config
     from dynamo_tpu.models.hybrid import forward_hybrid, make_state_cache
     from dynamo_tpu.models.transformer import init_params, make_kv_cache
-    from dynamo_tpu.ops.paged_attention import paged_attention
+    from dynamo_tpu.ops.paged_attention import (
+        paged_attention,
+        paged_attention_latent,
+    )
 
     cfg = cut_config(get_config(name), layers, experts)
+    attention = (paged_attention_latent if cfg.has_latent_layers
+                 else paged_attention)
 
     def on_chip(make):
         return jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype),
@@ -869,8 +942,8 @@ def _lowered_hybrid_prefill(one_chip, name, layers, experts, rows, t,
         return forward_hybrid(
             params, cfg, tokens, positions, kv, state, slots, tables,
             kv_lens, valid, last_idx, window=window,
-            attention_fn=functools.partial(paged_attention,
-                                           interpret=False), **paths)
+            attention_fn=functools.partial(attention, interpret=False),
+            **paths)
 
     def vec(dtype):
         return _shape(one_chip, (rows,), dtype)
