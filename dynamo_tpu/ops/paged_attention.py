@@ -935,13 +935,26 @@ def paged_attention_decode_pool(
     return _combine_current(q, acc, m, l, k_cur, v_cur, sm_scale)
 
 
-# The latent pool's kernel streams chunks of this many tokens (a grid
-# step a chunk a row: 128 rows x a 384-page table are 768 steps at 1024
-# tokens, 1536 at the pool kernel's 512) and scores them in blocks of
-# _LATENT_BLOCK_TOKENS, so that a ragged end copies half a block too
-# many on average and the [heads, block] float32 tile stays 128 KB.
+# The latent pool's kernel walks a row's pages in chunks of
+# _LATENT_CHUNK_TOKENS, _LATENT_SLOTS chunks in VMEM (1.3 MB each at the
+# published row): one is scored while the next one's pages land. A chunk's
+# pages are copied in blocks of _LATENT_BLOCK_TOKENS, each block on its
+# own semaphore and awaited once, so a ragged end copies half a block too
+# many on average; a chunk's live blocks are scored in ONE straight line,
+# _LATENT_SCORE_BLOCKS of them a flash update (a [heads, 512] float32
+# score tile), so that Mosaic runs one update's Q K^T under another's
+# softmax. The copies are started _LATENT_START_PAGES a turn of a loop.
+# Timed alone at the pangu cell's shapes (PERF.md section 6, PR 51; ms a
+# layer a step at a table of 384 pages, the grid of (row, chunk) it
+# replaces 1.37): 1,024 / 256 / 2 / 8 is 0.72; blocks of 512 copied
+# whole 0.79, one 512-token update a line 0.88, chunks of 512 0.88, of
+# 2,048 0.87; 16 pages a turn are level and lower slower; 3 and 4 slots
+# are level (0.717, 0.712).
 _LATENT_CHUNK_TOKENS = 1024
 _LATENT_BLOCK_TOKENS = 256
+_LATENT_SCORE_BLOCKS = 2
+_LATENT_START_PAGES = 8
+_LATENT_SLOTS = 2
 
 
 def _latent_flash_update(s, rows, rank: int, dtype, m_ref, l_ref, o_ref):
@@ -971,16 +984,14 @@ def _latent_decode_kernel(
     lengths_ref,  # [B] int32 HISTORY lengths (current token excluded)
     tables_ref,  # [B * max_pages] int32 flattened block tables
     layer_ref,  # [1] int32
-    buf_idx_ref,  # [1] int32 (double-buffer slot)
-    init_ref,  # [1] int32 (1 until the first DMA was issued)
+    first_ref,  # [B] int32: a row's first chunk in the order of the walk
+    row_ref,  # [G] int32: the row of the walk's g-th live chunk, else B
     q_ref,  # [1, heads, width]: absorbed queries [q W_uk^T | q_rope | 0]
     pool_ref,  # FULL [L, P, ps, width] in HBM (memory_space=ANY)
     acc_ref,  # [1, heads, rank] f32 unnormalized sum of p x latent
-    m_out_ref, l_out_ref,  # [1, heads, 128] f32
-    kv_buf,  # [2, C, ps, width] page chunks
-    sems,  # DMA semaphores (2,)
-    m_ref, l_ref,  # [heads, 128] f32
-    o_ref,  # [heads, rank] f32
+    m_ref, l_ref,  # [1, heads, 128] f32
+    kv_buf,  # [slots, C, ps, width] page chunks
+    sems,  # DMA semaphores (slots, blocks a chunk)
     *,
     pages_per_chunk: int,
     block_pages: int,
@@ -996,88 +1007,132 @@ def _latent_decode_kernel(
     padding adds nothing), and the values are the same rows' first
     `rank` lanes: P K[:, :rank]. So a page is read once for all 128
     heads, where the pool kernel above reads one K and one V row a kv
-    head. `_pool_decode_kernel` has the why of everything else here: the
-    pool stays in HBM, a row's pages come through its scalar-prefetched
-    table in double-buffered chunks, only blocks that hold history are
-    copied and scored, a row of length 0 costs its grid steps and
-    nothing more, and (acc, m, l) leave unnormalized for the current
-    token's combine."""
+    head. The pool stays in HBM and (acc, m, l) leave unnormalized for
+    the current token's combine, as in `_pool_decode_kernel`; the walk
+    is this kernel's own:
+
+    - A grid step is a ROW, and its live chunks are a loop inside. The
+      caller numbers the live chunks of all rows in the order they are
+      scored (`first_ref`, `row_ref`); chunk g lands in slot g mod
+      `slots`, and while it is scored chunk g + slots - 1 is started,
+      whichever row's it is. No step and no turn is spent on a chunk
+      without history, and nothing here looks for the next row.
+    - A chunk's copies are started in a loop, `_LATENT_START_PAGES` a
+      turn, each on the semaphore of its BLOCK; a block is awaited once,
+      by a descriptor of the block's size, so block 0 is scored while
+      the later ones are landing.
+    - A chunk's live blocks are scored in one straight line (a path a
+      count of live blocks), `_LATENT_SCORE_BLOCKS` of them a flash
+      update, so that Mosaic runs one update's Q K^T under another's
+      softmax and no update waits on a `pl.when`.
+    - A ragged last block is copied whole, so that the wait's size is
+      static. The last update of a line masks its scores, and it zeroes
+      the rows behind the history, which may be pages nobody has written
+      (0 x NaN is NaN in P V).
+
+    The call is compiled without Mosaic's bounds checks on its copies
+    (two a descriptor; 0.17 of 0.97 ms a layer at the cell's shapes):
+    the caller clips lengths to the table, and a table holds page
+    numbers of the pool."""
     b = pl.program_id(0)
-    i = pl.program_id(1)
-    n_chunks = pl.num_programs(1)
-    ps, width = kv_buf.shape[2:]
-    rank = o_ref.shape[1]
+    slots, _, ps, width = kv_buf.shape
+    rank = acc_ref.shape[2]
     bk = pages_per_chunk * ps
     block_tok = block_pages * ps
     n_blocks = pages_per_chunk // block_pages
-    layer = layer_ref[0]
-    length = lengths_ref[b]
+    length = lengths_ref[b]  # <= max_pages * ps
+    per_turn = _largest_divisor(block_pages, _LATENT_START_PAGES)
+    pool_layer = pool_ref.at[layer_ref[0]]
 
-    def chunk_copies(bi, ci, slot, fn):
+    def blocks_of(left):  # a chunk's live blocks, `left` tokens to go
+        return jnp.minimum(n_blocks, _div(left + block_tok - 1, block_tok))
+
+    def start_chunk(bi, ci, slot):
         base = bi * max_pages + ci * pages_per_chunk
-        left = lengths_ref[bi] - ci * bk
+        blocks = blocks_of(lengths_ref[bi] - ci * bk)
 
-        def block(u):
-            for j in range(u * block_pages, (u + 1) * block_pages):
-                fn(pltpu.make_async_copy(
-                    pool_ref.at[layer, tables_ref[base + j]],
-                    kv_buf.at[slot, j], sems.at[slot]))
+        def turn(t, _):
+            first = t * per_turn
+            into = kv_buf.at[slot, pl.ds(first, per_turn)]
+            sem = sems.at[slot, _div(first, block_pages)]
+            for j in range(per_turn):
+                pltpu.make_async_copy(
+                    pool_layer.at[tables_ref[base + first + j]],
+                    into.at[j], sem).start()
 
-        block(0)
-        for u in range(1, n_blocks):
-            pl.when(u * block_tok < left)(functools.partial(block, u))
+        jax.lax.fori_loop(0, blocks * (block_pages // per_turn), turn, None)
 
-    active = i * bk < length
+    def start_nth(g):
+        """Start the copies of the walk's g-th live chunk, if there is
+        one, into the slot that is its turn."""
+        bi = row_ref[g]
 
-    @pl.when(jnp.logical_and(active, init_ref[0] == 1))
+        @pl.when(bi < batch_size)
+        def _():
+            start_chunk(bi, g - first_ref[bi], g % slots)
+
+    @pl.when(b == 0)
     def _first():
-        chunk_copies(b, i, buf_idx_ref[0], lambda c: c.start())
-        init_ref[0] = 0
+        for g in range(slots - 1):
+            start_nth(g)
 
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
+    m_view, l_view, o_view = m_ref.at[0], l_ref.at[0], acc_ref.at[0]
+    m_view[...] = jnp.full_like(m_view, -jnp.inf)
+    l_view[...] = jnp.zeros_like(l_view)
+    o_view[...] = jnp.zeros_like(o_view)
+    n_live = _div(length + bk - 1, bk)
 
-    @pl.when(active)
-    def _compute():
-        slot = buf_idx_ref[0]
-        nb, ni = _next_chunk(lengths_ref, b, i, bk=bk, n_chunks=n_chunks,
-                             batch_size=batch_size)
+    def chunk(ci, _):
+        g = first_ref[b] + ci
+        start_nth(g + slots - 1)
+        slot = g % slots
+        left = length - ci * bk  # > 0
 
-        @pl.when(nb < batch_size)
-        def _prefetch():
-            nslot = jnp.where(slot == 0, 1, 0)
-            chunk_copies(nb, ni, nslot, lambda c: c.start())
-            buf_idx_ref[0] = nslot
+        def landed(first: int, count: int, live):
+            """Blocks first .. first + count as rows, once they are in;
+            `live`: the rows from there on are zeroed."""
+            for u in range(first, first + count):
+                at = kv_buf.at[slot, pl.ds(u * block_pages, block_pages)]
+                pltpu.make_async_copy(at, at, sems.at[slot, u]).wait()
+            rows = kv_buf[slot, pl.ds(first * block_pages,
+                                      count * block_pages)]
+            rows = rows.reshape(count * block_tok, width)
+            if live is not None:
+                tok = jax.lax.broadcasted_iota(jnp.int32, (rows.shape[0], 1),
+                                               0)
+                rows = jnp.where(tok < live, rows, jnp.zeros_like(rows))
+            return rows
 
-        chunk_copies(b, i, slot, lambda c: c.wait())
-        q = q_ref[0]  # [heads, width]
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, block_tok), 1)
-
-        def flash_block(u):
-            rows = kv_buf[slot, pl.ds(u * block_pages, block_pages)]
-            rows = rows.reshape(block_tok, width)
+        def score(q, rows, live):
             s = jax.lax.dot_general(
                 q, rows, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale
-            s = jnp.where(col < length - (i * bk + u * block_tok), s,
-                          -jnp.inf)
-            # finite: the block's first token is live
-            _latent_flash_update(s, rows, rank, q.dtype, m_ref, l_ref,
-                                 o_ref)
+            if live is not None:
+                col = jax.lax.broadcasted_iota(jnp.int32, (1, rows.shape[0]),
+                                               1)
+                s = jnp.where(col < live, s, -jnp.inf)
+            return s
 
-        flash_block(0)
-        for u in range(1, n_blocks):
-            pl.when(i * bk + u * block_tok < length)(
-                functools.partial(flash_block, u))
+        def path(k: int):
+            """A chunk of k live blocks, the last one maybe ragged, in
+            one straight line."""
+            q = q_ref[0]  # [heads, width]
+            rows, scores = [], []
+            for first in range(0, k, _LATENT_SCORE_BLOCKS):
+                count = min(_LATENT_SCORE_BLOCKS, k - first)
+                live = (left - first * block_tok if first + count == k
+                        else None)
+                rows.append(landed(first, count, live))
+                # finite: a unit's first token is live
+                scores.append(score(q, rows[-1], live))
+            for s, r in zip(scores, rows):
+                _latent_flash_update(s, r, rank, q.dtype, m_view, l_view,
+                                     o_view)
 
-    @pl.when(i == n_chunks - 1)
-    def _finish():
-        acc_ref[0] = o_ref[...]
-        m_out_ref[0] = m_ref[...]
-        l_out_ref[0] = l_ref[...]
+        for k in range(1, n_blocks + 1):
+            pl.when(blocks_of(left) == k)(functools.partial(path, k))
+
+    jax.lax.fori_loop(0, n_live, chunk, None)
 
 
 @functools.partial(jax.jit,
@@ -1098,7 +1153,8 @@ def paged_decode_attention_latent(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Flash partials over the paged history of a latent pool
     (`_latent_decode_kernel`): (acc [B, heads, rank], m, l [B, heads])
-    float32, unnormalized. A row with history 0 is skipped."""
+    float32, unnormalized. A row with history 0 is skipped; a length
+    past the table's width (a stale slot) reads the table's width."""
     b, heads, width = q.shape
     n_layers, _, n_pages, ps = kv_pool.shape[:4]
     max_pages = block_tables.shape[1]
@@ -1106,25 +1162,31 @@ def paged_decode_attention_latent(
         max_pages, pages_per_chunk or max(1, _LATENT_CHUNK_TOKENS // ps))
     block_pages = _largest_divisor(
         ppc, max(1, _LATENT_BLOCK_TOKENS // ps))
+    lengths = jnp.clip(kv_lens_hist.astype(jnp.int32), 0, max_pages * ps)
+    # the walk: live chunks in the order of rows; the g-th one's row (b
+    # past the last, far enough for the deepest prefetch to read)
+    per_row = (lengths + ppc * ps - 1) // (ppc * ps)
+    ends = jnp.cumsum(per_row)
+    row_of = jnp.searchsorted(
+        ends, jnp.arange(b * (max_pages // ppc) + _LATENT_SLOTS,
+                         dtype=jnp.int32), side="right",
+        method="compare_all").astype(jnp.int32)
 
-    def q_map(bi, ci, *refs):
-        del ci, refs
+    def row_map(bi, *refs):
+        del refs
         return (bi, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(b, max_pages // ppc),
-        in_specs=[pl.BlockSpec((1, heads, width), q_map),
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, heads, width), row_map),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=[pl.BlockSpec((1, heads, rank), q_map),
-                   pl.BlockSpec((1, heads, 128), q_map),
-                   pl.BlockSpec((1, heads, 128), q_map)],
+        out_specs=[pl.BlockSpec((1, heads, rank), row_map),
+                   pl.BlockSpec((1, heads, 128), row_map),
+                   pl.BlockSpec((1, heads, 128), row_map)],
         scratch_shapes=[
-            pltpu.VMEM((2, ppc, ps, width), kv_pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((heads, 128), jnp.float32),
-            pltpu.VMEM((heads, 128), jnp.float32),
-            pltpu.VMEM((heads, rank), jnp.float32),
+            pltpu.VMEM((_LATENT_SLOTS, ppc, ps, width), kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((_LATENT_SLOTS, ppc // block_pages)),
         ],
     )
     acc, m, l = pl.pallas_call(
@@ -1137,12 +1199,11 @@ def paged_decode_attention_latent(
                    jax.ShapeDtypeStruct((b, heads, 128), jnp.float32)],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",),
+            disable_bounds_checks=True),
         name="paged_decode_attention_latent",
-    )(kv_lens_hist.astype(jnp.int32),
-      block_tables.reshape(-1).astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32),
+    )(lengths, block_tables.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), ends - per_row, row_of,
       # the two unit dimensions are the dense pool's (k|v, kv heads): a
       # row-major bitcast
       q, kv_pool.reshape(n_layers, n_pages, ps, width))

@@ -220,43 +220,94 @@ def test_the_fused_block_equals_single_steps(runner):
 # -- the kernel ------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("chunk", [None, 2])
-def test_the_latent_kernel_equals_the_xla_oracle(chunk):
-    """`paged_decode_attention_latent` (interpreted) against the oracle
-    that gathers the whole table: ragged lengths, an inactive row, a row
-    with the current token alone, a table wider than any context; one
-    chunk a row and, with pages_per_chunk 2, several with a ragged last
-    block. Rows 40 wide padded to 128 lanes, 32 of them values."""
-    from dynamo_tpu.models.hybrid import paged_attention_decode_latent_xla
-    from dynamo_tpu.ops.paged_attention import paged_attention_decode_latent
-
-    rng = np.random.default_rng(3)
-    b, heads, width, rank, pages = 5, 4, 128, 32, 8
-    cache = np.zeros((2, 1, 48, PAGE, 1, width), np.float32)
+def _latent_case(lens, pages, seed=3, behind=None):
+    """(args of the oracle, kw, the rows' own tokens) for rows of history
+    + current token `lens` over tables `pages` wide: rows 40 wide padded
+    to 128 lanes, 32 of them values, 4 heads, two layers. `behind`: the
+    value of every table entry past a row's last live page (the pool's
+    last page, filled with it)."""
+    rng = np.random.default_rng(seed)
+    b, heads, width = len(lens), 4, 128
+    n_pages = b * pages + 2
+    cache = np.zeros((2, 1, n_pages, PAGE, 1, width), np.float32)
     cache[..., :40] = rng.normal(size=cache.shape[:-1] + (40,))
     q = np.zeros((b, heads, width), np.float32)
     q[..., :40] = rng.normal(size=(b, heads, 40))
     cur = np.zeros((b, width), np.float32)
     cur[:, :40] = rng.normal(size=(b, 40))
-    tables = jnp.asarray(rng.permutation(47)[:b * pages].reshape(b, pages)
-                         + 1, jnp.int32)
-    lens = jnp.asarray([75, 0, 33, 1, 128], jnp.int32)
-    args = (jnp.asarray(q), jnp.asarray(cache), 1, tables, lens,
-            jnp.asarray(cur))
-    kw = {"rank": rank, "sm_scale": 1 / math.sqrt(24)}
-    want = np.asarray(paged_attention_decode_latent_xla(*args, **kw))
+    tables = (rng.permutation(n_pages - 2)[:b * pages].reshape(b, pages)
+              + 1).astype(np.int32)
+    if behind is not None:
+        cache[:, :, n_pages - 1] = behind
+        for i, n in enumerate(lens):
+            tables[i, -(-max(n - 1, 0) // PAGE):] = n_pages - 1
+    return ((jnp.asarray(q), jnp.asarray(cache), 1, jnp.asarray(tables),
+             jnp.asarray(lens, jnp.int32), jnp.asarray(cur)),
+            {"rank": 32, "sm_scale": 1 / math.sqrt(24)}, cur)
+
+
+# The walk's edges, at the kernel's own tiles (blocks of 256 tokens, and
+# with pages_per_chunk 64 chunks of 1,024: four blocks a chunk, two a
+# flash update): (lengths, table pages, pages_per_chunk[, what lies
+# behind a row's last live page]). Lengths INCLUDE the current token, so
+# a history is one less.
+_BLOCK, _CHUNK, _WIDE = 256, 1024, 128  # tokens, tokens, pages
+LATENT_WALKS = {
+    "one-chunk-a-row": ([75, 0, 33, 1, 128], 8, None),
+    "a-page-pair-a-chunk": ([75, 0, 33, 1, 128], 8, 2),
+    "around-a-block": ([2, _BLOCK, _BLOCK + 1, _BLOCK + 2], _WIDE, 64),
+    "around-a-chunk": ([_CHUNK, _CHUNK + 1, _CHUNK + 2, _BLOCK // 2],
+                       _WIDE, 64),
+    "the-whole-table": ([_WIDE * PAGE + 1, _CHUNK + _BLOCK + 1,
+                         _WIDE * PAGE - 5], _WIDE, 64),
+    "first-row-empty": ([1, 300, 20, 700], 64, 64),
+    "last-row-empty": ([300, 20, 700, 0], 64, 64),
+    "two-empty-rows-in-a-row": ([300, 0, 1, 700], 64, 64),
+    "every-row-empty": ([0, 1, 0, 1], 64, 64),
+    "a-stale-length-past-the-table": ([300, 5000, 20], 64, 64),
+    "nan-pages-behind-a-ragged-end": (
+        [300, 2, _BLOCK + 1, _BLOCK + 18, 0], 64, 64, np.nan),
+}
+
+
+@pytest.mark.parametrize("walk", sorted(LATENT_WALKS))
+def test_the_latent_kernel_equals_the_xla_oracle(walk):
+    """`paged_decode_attention_latent` (interpreted) against the oracle
+    that gathers the whole table: ragged lengths, inactive rows wherever
+    the walk to the next row with a history can meet them, a row with
+    the current token alone, a table wider than any context, histories
+    one under, at and one over a block and a chunk and as wide as the
+    table, a length past the table (which reads the table's width, as
+    the oracle does). `behind`: every table entry past a row's last live
+    page names a page nobody has written, NaN all over; a ragged block
+    copies such pages whole, and the answer is the one over zeros there
+    (0 x NaN would be NaN in P V; the oracle's own would be)."""
+    from dynamo_tpu.models.hybrid import paged_attention_decode_latent_xla
+    from dynamo_tpu.ops.paged_attention import paged_attention_decode_latent
+
+    ops = importlib.import_module(paged_attention_decode_latent.__module__)
+    assert (ops._LATENT_BLOCK_TOKENS, ops._LATENT_CHUNK_TOKENS) == (
+        _BLOCK, _CHUNK)  # the edges above are the kernel's own
+    lens, pages, chunk, behind = (*LATENT_WALKS[walk], None)[:4]
+    args, kw, cur = _latent_case(lens, pages, behind=behind)
+    clean = (args if behind is None
+             else _latent_case(lens, pages, behind=0.0)[0])
+    want = np.asarray(paged_attention_decode_latent_xla(*clean, **kw))
     got = np.asarray(paged_attention_decode_latent(
         *args, **kw, pages_per_chunk=chunk, interpret=True))
-    assert got.shape == (b, heads, rank)
-    live = [0, 2, 3, 4]
+    assert got.shape == (len(lens), 4, 32)
+    live = [i for i, n in enumerate(lens) if n > 0]
+    assert np.isfinite(got[live]).all()
     np.testing.assert_allclose(got[live], want[live], atol=2e-5)
-    # the row with no history attends to its own token alone
-    np.testing.assert_allclose(
-        got[3], np.broadcast_to(cur[3, :rank], (heads, rank)), atol=1e-6)
+    # a row with no history attends to its own token alone
+    for i in (i for i, n in enumerate(lens) if n == 1):
+        np.testing.assert_allclose(
+            got[i], np.broadcast_to(cur[i, :32], (4, 32)), atol=1e-6)
     # and the other layer's rows would have given another answer
     other = np.asarray(paged_attention_decode_latent_xla(
-        args[0], args[1], 0, *args[3:], **kw))
-    assert np.abs(other[0] - want[0]).max() > 1e-2
+        clean[0], clean[1], 0, *clean[3:], **kw))
+    deep = max(live, key=lambda i: lens[i])
+    assert lens[deep] < 2 or np.abs(other[deep] - want[deep]).max() > 1e-3
 
 
 def test_prefill_attention_by_key_blocks_equals_one_pass(monkeypatch):

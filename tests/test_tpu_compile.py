@@ -572,13 +572,17 @@ def test_the_latent_decode_kernel_compiles_for_v5e(one_chip, width):
 
 # sha256 of the latent decode kernel's normalised lowered text
 # (`tools.lowered_text.normalise`: locations stripped, the Mosaic body as
-# the digest of its assembly) at the cell's shapes as the parent of PR 50
-# lowered it (60a4137), by table width: PR 50 moved its flash update into
-# a helper the prefill kernel shares, and the decode side of the cell may
-# not move with it.
+# the digest of its assembly) at the cell's shapes, by table width, as
+# PR 51 lowers it: the kernel that walks a row's pages by the row (a grid
+# over rows, one wait a block, a chunk's live blocks in one straight
+# line). PR 50 pinned the text of its parent (60a4137) here while it
+# moved the flash update into a helper the prefill kernel shares; PR 51
+# is the one that moved the kernel, and re-took both. A PR that edits
+# `_latent_flash_update` or `_latent_decode_kernel` for another kernel's
+# sake must leave these as they are.
 LATENT_DECODE_TEXT = {
-    8: "0f81d68b005b5281cb95043775c307238d60c929c61f3003f9ded9bbe68a41ea",
-    384: "89d6078015dee28370ca9bda0aaa059e7e3161dcec5c3e9b71cb86ac64b7ee76",
+    8: "25986d0cecd839fe29087d897c043fc45a9e2ea76bad4de1403f2f87b110b96b",
+    384: "aff12820dc049c37e9bfc2bbdf1e562acee2bcd2b5a31de1018b1065c50d7e77",
 }
 
 
