@@ -632,6 +632,20 @@ class ModelRunner:
                                if model_config.has_latent_layers else 0)
         self.latent_decode_tokens = 0
         self.latent_prefill_expand_tokens = 0
+        # A model whose layers read pages they do not own
+        # (dynamo_kv_page_layer_reads_total): active rows x layers that
+        # read a page group's pages over decode steps, by whether the
+        # layer wrote them (owner: `cache_plan.group_layers`) or reads
+        # another layer's (shared: the rest of `group_readers`).
+        plan = self.cache_plan
+        self._page_readers = {
+            "owner": sum(plan.group_layers),
+            "shared": sum(plan.group_readers) - sum(plan.group_layers)}
+        self.page_layer_reads = {"owner": 0, "shared": 0}
+        # whether prefill runs a tail of layers on one position a row
+        # (dynamo_prefill_cross_decoder_rows_total, the scheduler's count)
+        self.runs_cross_decoder = (model_config.cross_decoder_start
+                                   < model_config.n_layers)
         # A model with recurrent state (dynamo_ssm_prefill_*): valid
         # positions x state layers (Mamba-2 and short-conv alike) its
         # prefill launches carried a state over and the rows they held,
@@ -685,7 +699,13 @@ class ModelRunner:
 
     def _count_latent_decode(self, kv_lens, active, steps: int) -> None:
         """Cached positions `steps` decode steps ask the latent kernel
-        for: each active row's history, one longer a step, x layers."""
+        for: each active row's history, one longer a step, x layers.
+        And, for a stack with layers that read pages they do not own,
+        the rows x layers that read pages, owners and sharers apart."""
+        if self._page_readers["shared"]:
+            rows = int(np.count_nonzero(np.asarray(active, bool)))
+            for by, layers in self._page_readers.items():
+                self.page_layer_reads[by] += rows * steps * layers
         if self._latent_layers:
             hist = np.asarray(kv_lens, np.int64)[np.asarray(active, bool)] - 1
             self.latent_decode_tokens += int(
@@ -773,8 +793,7 @@ class ModelRunner:
                 rc.max_pages_per_seq, cfg.dtype,
                 kernel_path("DYNT_ATTENTION") == "interpret")
         return prefill_kernel_tiles(
-            bucket, cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim,
-            rc.page_size,
+            bucket, *cfg.attn_geometry, rc.page_size,
             (self.window_prefill_width(bucket) if window
              else rc.max_pages_per_seq),
             jnp.int8 if self._kv_quantized else cfg.dtype,
@@ -981,6 +1000,30 @@ class ModelRunner:
             gains = [branch_gain(cfg, i) for i in range(cfg.n_layers)]
         keys = jax.random.split(jax.random.PRNGKey(seed), cfg.n_layers + 2)
         params = top_init_fn()(keys[0], keys[-1])
+        if cfg.layer_sections:
+            # a stack with rolled sections: an entry of the `layers` list
+            # a launch, a rolled entry's mixers drawn stacked; ONE
+            # program for the entries of a kind and a number of repeats
+            # (the keys and gains of its mixers are operands)
+            from ..models.hybrid import init_hybrid_entry
+
+            def entry_init_fn(e: int, entry):
+                return jax.jit(
+                    lambda k, g: init_hybrid_entry(k, cfg, entry, g),
+                    out_shardings=shard["layers"][e])
+
+            gains = jnp.asarray(gains, jnp.float32)
+            entry_fns: dict = {}
+            params["layers"] = []
+            for e, entry in enumerate(cfg.layer_entries):
+                first, repeats, stride = entry
+                at = first + stride * np.arange(repeats)
+                kind = (cfg.layer_kind(first), repeats)
+                if kind not in entry_fns:
+                    entry_fns[kind] = entry_init_fn(e, entry)
+                params["layers"].append(
+                    entry_fns[kind](keys[at + 1], gains[at]))
+            return params
         layer_fns: dict = {}  # one program per layer kind
         params["layers"] = []
         for i in range(cfg.n_layers):

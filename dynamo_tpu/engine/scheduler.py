@@ -230,6 +230,12 @@ class SchedulerStats:
     # held expert, dropped slots, experts touched, calls; None without one.
     state_slot_ms: float = 0.0
     moe_counts: Optional[np.ndarray] = None
+    # Rows of prefill launches by whether the row's chunk was its
+    # prompt's last (dynamo_prefill_cross_decoder_rows_total: a stack
+    # whose tail runs on one position a row runs it for every row, and
+    # only a last chunk's logits are read)
+    prefill_rows_last: int = 0
+    prefill_rows_earlier: int = 0
     # Speculative decoding (dynamo_spec_* metrics; docs/metrics.md):
     # proposed/accepted count MINED drafts only (static-shape padding is
     # excluded), spec_ema is the mean acceptance EMA over the slots that
@@ -1509,6 +1515,7 @@ class InferenceScheduler:
             np.int32,
         )
         is_final = seq.prefill_pos + chunk >= seq.prompt_len
+        self._count_prefill_rows([is_final])
         sampling = seq.request.sampling
         chunk_embeds = None
         if seq.media_embeds is not None:
@@ -1587,6 +1594,11 @@ class InferenceScheduler:
                 get_recorder().event(rid, "program_built", fn=rec["fn"],
                                      key=rec["key"], seconds=seconds)
 
+    def _count_prefill_rows(self, finals: list) -> None:
+        last = sum(finals)
+        self.stats.prefill_rows_last += last
+        self.stats.prefill_rows_earlier += len(finals) - last
+
     def _prefill_batch(self, work: list) -> int:
         """Dispatch several sequences' prefill chunks in ONE compiled
         call (ModelRunner.prefill_chunk_batch). Per-row results are
@@ -1595,6 +1607,7 @@ class InferenceScheduler:
         _prefill_single exactly."""
         finals = [seq.prefill_pos + chunk >= seq.prompt_len
                   for seq, chunk in work]
+        self._count_prefill_rows(finals)
         rows = []
         with _section("sched.prefill_prep"):
             for seq, chunk in work:

@@ -1561,6 +1561,8 @@ class TpuWorker:
         dynamo_engine_positions, dynamo_prefill_row_blocks_total,
         dynamo_prefill_attn_launches_total, dynamo_prefill_attn_blocks_total,
         dynamo_kv_reserved_page_ms, dynamo_kv_window_*, dynamo_latent_*,
+        dynamo_kv_page_layer_reads_total,
+        dynamo_prefill_cross_decoder_rows_total,
         dynamo_ssm_prefill_*, dynamo_ssm_scan_launches_total,
         dynamo_program_launches,
         dynamo_program_tokens,
@@ -1572,6 +1574,7 @@ class TpuWorker:
             ENGINE_TOKENS,
             EMIT_FRAMES,
             EMIT_HANDOVERS,
+            KV_PAGE_LAYER_READS,
             KV_RESERVED_PAGE_MS,
             KV_WINDOW_ALLOC_FAIL,
             KV_WINDOW_EDGE_TOKENS,
@@ -1585,6 +1588,7 @@ class TpuWorker:
             MOE_EXPERTS_TOUCHED,
             PREFILL_ATTN_BLOCKS,
             PREFILL_ATTN_LAUNCHES,
+            PREFILL_CROSS_DECODER_ROWS,
             PREFILL_ROW_BLOCKS,
             PROGRAM_LAUNCHES,
             PROGRAM_TOKENS,
@@ -1656,11 +1660,20 @@ class TpuWorker:
                 SSM_PREFILL_LAUNCH_ROWS.labels(
                     worker=worker, carry=carry).set(
                         self.runner.ssm_prefill_rows[carry])
-            if self.model_config.ssm_layers:  # only Mamba-2 layers scan
+            if self.model_config.ssm_layers:  # only Mamba layers scan
                 for path, count in getattr(
                         self.runner, "ssm_scan_launches", {}).items():
                     SSM_SCAN_LAUNCHES.labels(
                         worker=worker, path=path).set(count)
+        reads = getattr(self.runner, "page_layer_reads", {})
+        if any(reads.values()):  # only a model with pages read, not owned
+            for by, count in reads.items():
+                KV_PAGE_LAYER_READS.labels(worker=worker, by=by).set(count)
+        if getattr(self.runner, "runs_cross_decoder", False):
+            for chunk, count in (("last", stats.prefill_rows_last),
+                                 ("earlier", stats.prefill_rows_earlier)):
+                PREFILL_CROSS_DECODER_ROWS.labels(
+                    worker=worker, chunk=chunk).set(count)
         if stack == "latent":  # only a model with latent attention
             LATENT_DECODE_TOKENS.labels(worker=worker).set(
                 self.runner.latent_decode_tokens)

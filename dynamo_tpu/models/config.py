@@ -93,12 +93,14 @@ class ModelConfig:
     # a published model is `mixers_per_layer` of them (attention, then
     # experts: 2). Rope is a kind's: the default table on "W" and "L",
     # YaRN on "*" where `rope_yarn_factor` is set, none on a kind that
-    # `rope_kinds` leaves out. Only attention layers
-    # have KV pages, and each kind has a page group of its own
-    # (`kv_layers` the full group, "*" or "L"; `window_kv_layers` the
-    # window group: a cache layer index counts within its group); "M"
-    # and "C" layers keep a fixed-size state per scheduler slot, "C" the
-    # conv's carry alone (models/hybrid.py).
+    # `rope_kinds` leaves out. Only attention layers that WRITE keys
+    # and values have KV pages, and each such kind has a page group of
+    # its own (`kv_layers` the full group, "*" or "L"; `window_kv_layers`
+    # the window group: a cache layer index counts within its group; an
+    # "X" layer reads a "*" layer's pages and has none); "M", "S" and
+    # "C" layers keep a fixed-size state per scheduler slot, "C" the
+    # conv's carry alone (models/hybrid.py). "S", "G" and "X" are
+    # phi4flash's, further down.
     layer_pattern: str = ""
     mixers_per_layer: int = 1
     # `layer_pattern` stacks: a mixer's OUTPUT is normed too before it
@@ -147,6 +149,33 @@ class ModelConfig:
     # the `n_shared_experts` experts' outputs, not their sum.
     parallel_block: bool = False
     norm_kind: str = "rms"  # rms | layer
+    # phi4flash's (SambaY's) stack, each at its default for every other
+    # family, where nothing of it is traced. Three more mixer kinds of a
+    # `layer_pattern`: "S" Mamba-1 (a selective scan over `mamba_inner`
+    # channels x `ssm_state` columns, a decay a channel AND a column; dt
+    # through a bottleneck of `mamba_dt_rank`), "G" a gated memory unit
+    # (out = (m * silu(h W_1)) W_2, m the pre-gate output of the LAST "S"
+    # mixer before the first "G": `memory_layer`) and "X" cross-attention
+    # onto the keys and values the last "*" layer before it wrote (a
+    # query projection alone; it owns no pages: `shared_kv_layer`).
+    # `diff_attention`: every attention kind is DIFFERENTIAL (heads in
+    # pairs, two softmaxes, a1 - lambda a2, a sub-norm over 2 x head_dim
+    # lanes; models/hybrid.py `_diff_epilogue`). `norm_bias`: a
+    # LayerNorm (`norm_kind` "layer") has a bias beside its weight, and
+    # with `attn_bias` a `layer_pattern` stack's attention projections
+    # have biases too (bqkv, bo).
+    mamba_dt_rank: int = 0
+    diff_attention: bool = False
+    norm_bias: bool = False
+    # `layer_pattern` stacks: (mixers a period, repeats) of consecutive
+    # sections that cover the stack. A section of several repeats is ONE
+    # traced body run as a loop (`lax.scan`) over its repeats, its
+    # weights and per-slot state stored stacked along a leading axis
+    # (`layer_entries`): 64 unrolled mixers trace, lower, compile and
+    # load as 64, and a cell has some twenty programs to build inside
+    # its run's budget. () = every mixer unrolled, each with arrays of
+    # its own (every other family).
+    layer_sections: tuple[tuple[int, int], ...] = ()
     rope_kinds: str = ""
     rope_interleaved: bool = False
     shared_expert_mean: bool = False
@@ -201,12 +230,110 @@ class ModelConfig:
         """Layers that keep a state per scheduler slot: a conv carry
         each, and the "M" ones (`ssm_layers`) an SSM state beside it."""
         return tuple(i for i in range(self.n_layers)
-                     if self.layer_kind(i) in "MC")
+                     if self.layer_kind(i) in "MCS")
 
     @property
     def ssm_layers(self) -> tuple[int, ...]:
+        """Layers with an SSM state beside the conv's carry: Mamba-2
+        ("M": [heads, head_dim, state]) and Mamba-1 ("S": [state,
+        channels])."""
         return tuple(i for i in range(self.n_layers)
-                     if self.layer_kind(i) == "M")
+                     if self.layer_kind(i) in "MS")
+
+    @property
+    def layer_entries(self) -> tuple[tuple[int, int, int], ...]:
+        """(first mixer, repeats, stride) of each entry of a parameter
+        tree's `layers` list: a mixer of its own (repeats 1), or where
+        `layer_sections` rolls a section, position j of its period:
+        mixers first, first + stride, .. share one body, their arrays
+        stacked along a leading axis of `repeats`. A rolled section
+        holds the kinds S, D, W, G and X only, whole blocks, and neither
+        the memory layer nor a layer that writes the full page group
+        (the forwards thread those as values of the step)."""
+        if not self.layer_sections:
+            return tuple((i, 1, 0) for i in range(self.n_layers))
+        entries, start = [], 0
+        for period, repeats in self.layer_sections:
+            body = self.layer_pattern[start:start + period]
+            end = start + period * repeats
+            if (self.layer_pattern[start:end] != body * repeats
+                    or period % self.mixers_per_layer):
+                raise ValueError(
+                    f"{self.name}: layer_sections {self.layer_sections} "
+                    f"do not repeat whole blocks of {self.layer_pattern}")
+            if repeats > 1 and (body.strip("SDWGX") or self.parallel_block
+                                or start <= self.memory_layer < end):
+                raise ValueError(
+                    f"{self.name}: a rolled section of {body!r} (kinds "
+                    "S D W G X, never the memory layer)")
+            entries += [(start + j, repeats, period) for j in range(period)]
+            start = end
+        if start != self.n_layers:
+            raise ValueError(f"{self.name}: layer_sections cover {start} "
+                             f"of {self.n_layers} mixers")
+        return tuple(entries)
+
+    @property
+    def shared_kv_readers(self) -> tuple[int, ...]:
+        """The "X" layers: each reads the pages of the full group's
+        layer `shared_kv_layer` names and writes none."""
+        return tuple(i for i in range(self.n_layers)
+                     if self.layer_kind(i) == "X")
+
+    def shared_kv_layer(self, layer_idx: int) -> int:
+        """The full group's cache layer an "X" mixer reads: that of the
+        last "*" mixer before it."""
+        return self.layer_pattern[:layer_idx].count("*") - 1
+
+    @property
+    def memory_layer(self) -> int:
+        """The "S" mixer whose pre-gate output the "G" mixers read: the
+        last one before the first "G" (-1: the stack has no "G")."""
+        first = self.layer_pattern.find("G")
+        return self.layer_pattern.rfind("S", 0, max(first, 0))
+
+    @property
+    def cross_decoder_start(self) -> int:
+        """The first mixer of the stack's tail that caches nothing and
+        carries nothing in time ("G", "X" and the feed-forwards between
+        them), so that a prefill launch runs it on each row's LAST
+        position alone; `n_layers` where the stack has no such tail."""
+        first = min((i for i in (self.layer_pattern.find("G"),
+                                 self.layer_pattern.find("X")) if i >= 0),
+                    default=-1)
+        if first < 0 or self.layer_pattern[first:].strip("GXDE"):
+            return self.n_layers
+        return first
+
+    @property
+    def diff_rows(self) -> int:
+        """Rows of the pool a token's KV PAIRS of a differential-attention
+        stack lie in: a row's kv heads are one "head" of the kernels,
+        which read a page's heads out of 32-bit words two at a time and
+        whose pool's head axis is tiled by the chip: 2, 4 or 8 rows, or
+        a multiple of 16, are whole tiles, 10 are padded to 16 and
+        Mosaic refuses a page's slice of 10 ("Slice shape along
+        dimension 4 must be aligned to tiling (8), but is 10": the
+        compiler, PR 52). The most of 8, 4, 2 that divide the pairs (10
+        pairs: 2 rows of 5 pairs, 640 lanes), else 1."""
+        pairs = self.n_kv_heads // 2
+        return next(r for r in (8, 4, 2, 1) if pairs % r == 0)
+
+    @property
+    def attn_geometry(self) -> tuple[int, int, int]:
+        """(query heads, kv heads, head_dim) as the attention kernels and
+        the pool see a `layer_pattern` stack's layers: the model's own,
+        or with `diff_attention` `diff_rows` kv "heads", each a row of
+        whole KV pairs side by side, and a row's query heads padded to a
+        power of two, each handed over with its values in the lanes its
+        own kv head has in the row and zeros in the others
+        (models/hybrid.py `_wide_query`)."""
+        if not self.diff_attention:
+            return self.n_q_heads, self.n_kv_heads, self.head_dim
+        rows = self.diff_rows
+        per_row = self.n_q_heads // rows
+        padded = 1 << (per_row - 1).bit_length()
+        return rows * padded, rows, self.n_kv_heads // rows * self.head_dim
 
     @property
     def held_experts(self) -> tuple[int, int]:
@@ -268,9 +395,11 @@ class ModelConfig:
 
     @property
     def kv_heads_per_lane_tile(self) -> int:
-        """kv heads that share one 128-lane row of the pool: 1, or for a
-        `layer_pattern` stack whose head_dim divides a lane tile (64: 2)
-        and whose kv heads fill whole tiles, 128 / head_dim. The TPU
+        """kv heads that share one row of the pool (128 lanes, or with
+        differential attention as many as a row's KV pairs fill:
+        `diff_rows`): 1, or for a `layer_pattern` stack whose head_dim
+        divides a lane tile (64: 2) and whose kv heads fill whole tiles,
+        128 / head_dim. The TPU
         tiles a pool's last two axes as (8 or 16, 128): [.., 8, 64] is
         padded to 128 lanes, twice the memory, and Mosaic refuses a
         page's 64-lane slice ("Slice shape along dimension 5 must be
@@ -278,6 +407,9 @@ class ModelConfig:
         token's row is stored [kv heads / 2, 128], head 2j in lanes
         0..63 and head 2j + 1 in 64..127: the same bytes in the same
         order as [kv heads, 64] row-major, read back by a reshape."""
+        if self.diff_attention:
+            # whole KV pairs side by side, `diff_rows` rows a token
+            return self.n_kv_heads // self.diff_rows
         if (not self.layer_pattern or self.is_mla or self.head_dim >= 128
                 or 128 % self.head_dim):
             return 1
@@ -321,6 +453,16 @@ class CachePlan:
     # page for every 16 positions of a sequence) and, for a stack with
     # window layers, "window" (the last `sliding_window` positions)
     groups: tuple[str, ...] = ("full",)
+    # cache layers each group's pool holds: the layers that WRITE pages
+    # of the group (`kv_layers`, `window_kv_layers`), and the layers
+    # that read them. They differ where layers read pages they do not
+    # own (an "X" layer reads the full group's layer that
+    # `ModelConfig.shared_kv_layer` names): bytes a token and pool
+    # sizes follow `group_layers`, a decode step's page reads
+    # `group_readers`. () for a dense stack: every layer of the model
+    # writes and reads its own.
+    group_layers: tuple[int, ...] = ()
+    group_readers: tuple[int, ...] = ()
     # whether a slot keeps state beside its pages: a conv carry or an
     # SSM state a state layer (`models.hybrid.make_state_cache`)
     state: bool = False
@@ -349,19 +491,26 @@ def cache_plan(config: ModelConfig) -> CachePlan:
     configuration. A dense stack's is one page group and nothing it
     cannot do but hold an int8 pool where it caches latents (MLA). A
     `layer_pattern` stack's says what its kinds of layer bring: window
-    layers a second page group, Mamba-2 or short-conv layers per-slot
-    state, latent layers a single-stack pool; where several would refuse
+    layers a second page group, Mamba-2, Mamba-1 or short-conv layers
+    per-slot state, latent layers a single-stack pool; a page group
+    holds a cache layer for every layer that WRITES it, and a layer that
+    reads another's pages ("X") adds a reader and no pages; where
+    several would refuse
     the same thing, the first of those in that order gives the reason."""
     if not config.layer_pattern:
         return CachePlan(int8_pool=(
             f"int8 KV targets standard-attention models ({config.name}: "
             "MLA's latent cache is already compact)"
             if config.is_mla else ""))
+    writers = (len(config.kv_layers), len(config.window_kv_layers))
+    readers = (writers[0] + len(config.shared_kv_readers), writers[1])
     what = f"{config.name} (layers {config.layer_pattern})"
     windowed, latent = "W" in config.layer_pattern, config.has_latent_layers
     stateful = bool(config.state_layers)
     have = " and ".join(
-        name for kind, name in (("M", "Mamba-2"), ("C", "short-conv"),
+        name for kind, name in (("M", "Mamba-2"), ("S", "Mamba-1"),
+                                ("C", "short-conv"), ("G", "gated-memory"),
+                                ("X", "shared-KV cross-attention"),
                                 ("E", "expert"), ("L", "latent-attention"))
         if kind in config.layer_pattern)
     window_group = (f"{what} keeps two page groups, and the window group "
@@ -371,6 +520,8 @@ def cache_plan(config: ModelConfig) -> CachePlan:
                    f"({config.kv_cache_head_dim} values a token)")
     return CachePlan(
         groups=("full", "window") if windowed else ("full",),
+        group_layers=writers if windowed else writers[:1],
+        group_readers=readers if windowed else readers[:1],
         state=stateful,
         reuse_prefix=(
             f"{window_group}: a prefix hit needs the full group's pages of "
@@ -730,6 +881,50 @@ PRESETS: dict[str, ModelConfig] = {
         n_experts=8, n_experts_active=2, expert_mlp_hidden=48,
         n_shared_experts=2, shared_expert_mean=True, moe_norm_topk=True,
         moe_scoring="sigmoid", moe_selection_bias=False,
+    ),
+    # microsoft/Phi-4-mini-flash-reasoning (config.json, model_type
+    # phi4flash; SambaY, arXiv:2507.06607) at its published sizes: 32
+    # pre-norm blocks, each a token mixer then a SwiGLU 10,240 wide, so
+    # 64 mixers, every norm a LayerNorm with weight AND bias, no
+    # positional term anywhere, a tied head. Blocks 0, 2, .., 16 are
+    # Mamba-1 (5,120 channels = `mamba_heads` x `mamba_head_dim`, which a
+    # Mamba-1 mixer has not: the product alone is read; 16 state
+    # columns, dt rank 160, conv 4 with a bias); blocks 1, 3, .., 15
+    # differential attention over the last 512 positions (40 query
+    # heads, 20 KV heads of 64, biases on the projections); block 17 the
+    # same over everything, the ONE layer of the full page group; blocks
+    # 18, 20, .., 30 gated memory units on block 16's pre-gate scan
+    # output; blocks 19, 21, .., 31 cross-attention (a query projection
+    # alone) onto block 17's pages.
+    "phi4-mini-flash-reasoning": ModelConfig(
+        name="phi4-mini-flash-reasoning", vocab_size=200064, hidden=2560,
+        n_layers=64,
+        layer_pattern="SDWD" * 8 + "SD*D" + "GDXD" * 7, mixers_per_layer=2,
+        norm_kind="layer", norm_bias=True, attn_bias=True,
+        diff_attention=True, use_rope=False,
+        layer_sections=((4, 8), (4, 1), (4, 7)),
+        n_q_heads=40, n_kv_heads=20, head_dim=64, mlp_hidden=10240,
+        rms_eps=1e-5, tie_embeddings=True, max_context=262144,
+        sliding_window=512,
+        mamba_heads=40, mamba_head_dim=128, ssm_state=16, mamba_dt_rank=160,
+        conv_kernel=4,
+    ),
+    # CPU sibling: the same rule at 12 blocks (Mamba-1 at 0, 2, 4 and,
+    # the memory, 6; window layers 1, 3, 5; the full one 7; memory units
+    # 8, 10; cross-attention 9, 11), head_dim 64 as published (a KV pair
+    # fills a lane tile), window 32
+    "tiny-phi4flash-test": ModelConfig(
+        name="tiny-phi4flash-test", vocab_size=512, hidden=256,
+        n_layers=24,
+        layer_pattern="SDWD" * 3 + "SD*D" + "GDXD" * 2, mixers_per_layer=2,
+        norm_kind="layer", norm_bias=True, attn_bias=True,
+        diff_attention=True, use_rope=False,
+        layer_sections=((4, 3), (4, 1), (4, 2)),
+        n_q_heads=4, n_kv_heads=2, head_dim=64, mlp_hidden=512,
+        rms_eps=1e-5, tie_embeddings=True, max_context=1024,
+        sliding_window=32,
+        mamba_heads=4, mamba_head_dim=128, ssm_state=16, mamba_dt_rank=16,
+        conv_kernel=4,
     ),
     "tiny-mla-test": ModelConfig(
         name="tiny-mla-test", vocab_size=512, hidden=64, n_layers=2,

@@ -61,6 +61,18 @@ own [rows, h] array contracted over h (`_head`: no transposed copy).
      `shared_expert_mean` its output x 1/`n_shared_experts`: the mean of
      that many experts stored as one matrix)
   D  a dense SwiGLU `mlp_hidden` wide, one fused [gate | up] matrix
+  S  Mamba-1 (phi4flash): [u | z] = x W_in; u = silu(conv(u) + b); [dt_r |
+     B | C] = u W_x; dt = softplus(dt_r W_dt + b_dt); s_t = exp(dt_t (x)
+     A) * s_{t-1} + (dt_t u_t) (x) B_t with A [N, D], a decay a channel
+     AND a column; y = s C + D u; out = (y * silu(z)) W_out. The LAST S
+     before the first G hands its y, before the gate, to the G mixers
+  G  a gated memory unit: (m * silu(x W_1)) W_2, m that y at the same
+     position: no state, no cache
+  X  cross-attention onto the keys and values the last `*` mixer before
+     it wrote: a query projection alone, no pages of its own
+     With `diff_attention` every attention kind is differential (the
+     comment above `_diff_layout` has the form it runs in); with
+     `layer_sections` a run of identical blocks is one `lax.scan`
   L  latent attention: c_q = RMSNorm(x W_dq), q = c_q W_uq -> heads x
      (nope | rope), q_rope roped; c_kv = RMSNorm(x W_dkv), k_r =
      RoPE(x W_kr), ONE rope key a token for all heads. A token's cache
@@ -116,6 +128,8 @@ from ..ops.ssm import (
     causal_conv,
     expand_groups,
     scan_kernel_tiles,
+    selective_scan,
+    selective_state_update,
     ssm_chunk_scan,
     ssm_chunk_scan_kernel,
     ssm_state_update,
@@ -154,13 +168,21 @@ FULL_TABLE_PAGES = (64, 128, 256)
 # ---------------------------------------------------------------------------
 
 
-def hybrid_layer_axes(config: ModelConfig, layer_idx: int) -> dict:
+def hybrid_layer_axes(config: ModelConfig, layer_idx: int,
+                      repeats: int = 1) -> dict:
     """Logical sharding axes of one layer (parallel.shardings). The Mamba
     and expert leaves are replicated: a sharded state cache and an expert
-    exchange are not built (the worker refuses --tp/--sp for this family)."""
+    exchange are not built (the worker refuses --tp/--sp for this family).
+    `repeats` > 1: an entry of a rolled section
+    (`ModelConfig.layer_entries`), every leaf stacked along a leading
+    axis."""
     axes = _kind_axes(config, config.layer_kind(layer_idx))
     if not has_own_norm(config, layer_idx):
         del axes["norm"]
+    elif config.norm_bias:
+        axes["norm_b"] = ("embed",)
+    if repeats > 1:
+        axes = {name: (None, *ax) for name, ax in axes.items()}
     return axes
 
 
@@ -177,18 +199,37 @@ def _kind_axes(config: ModelConfig, kind: str) -> dict:
                 "conv_w": (None, None), "conv_b": (None,),
                 "dt_bias": (None,), "a_log": (None,), "d_skip": (None,),
                 "ssm_norm": (None,), "out_proj": (None, "embed")}
+    if kind == "S":  # replicated, as the state it carries
+        return {"norm": ("embed",), "in_proj": ("embed", None),
+                "conv_w": (None, None), "conv_b": (None,),
+                "x_proj": (None, None), "dt_proj": (None, None),
+                "dt_bias": (None,), "a_log": (None, None),
+                "d_skip": (None,), "out_proj": (None, "embed")}
+    if kind == "G":
+        return {"norm": ("embed",), "g_in": ("embed", None),
+                "g_out": (None, "embed")}
     if kind == "C":  # replicated, as the state it carries
         return {"norm": ("embed",), "in_proj": ("embed", None),
                 "conv_w": (None, None), "out_proj": (None, "embed")}
     post = {"post_norm": ("embed",)} if config.sandwich_norm else {}
-    if kind in "*W":
+    if kind in "*WX":
         qk = ({"q_norm": ("head_dim",), "k_norm": ("head_dim",)}
               if config.qk_norm else {})
-        return {"norm": ("embed",),
+        axes = {"norm": ("embed",),
                 "wq": ("embed", "q_heads", "head_dim"),
                 "wk": ("embed", "kv_heads", "head_dim"),
                 "wv": ("embed", "kv_heads", "head_dim"),
                 "wo": ("q_heads", "head_dim", "embed"), **qk, **post}
+        if config.attn_bias:
+            axes.update({"bq": ("q_heads", "head_dim"),
+                         "bk": ("kv_heads", "head_dim"),
+                         "bv": ("kv_heads", "head_dim"), "bo": ("embed",)})
+        if config.diff_attention:
+            axes.update({name: (None,) for name in DIFF_PARAMS})
+        if kind == "X":  # a query projection alone
+            for name in ("wk", "wv", "bk", "bv"):
+                axes.pop(name, None)
+        return axes
     if kind == "L":  # replicated: the worker refuses --tp for this family
         return {"norm": ("embed",), "w_dq": ("embed", None),
                 "q_norm": (None,), "w_uq": (None, None),
@@ -229,6 +270,19 @@ BRANCH_GROWTH = 1.23
 # the norms take out again.
 FIRST_JUMP, BRANCH_SHARE = 60.0, 0.25
 KIND_SPREAD = {"C": 1.0, "D": 0.6, "E": 0.3, "*": 0.125}
+# The same for a stack with Mamba-1 mixers (phi4flash: 64 mixers, every
+# one gated or bilinear: u * silu(z), silu(g) * u, m * silu(.), a1 -
+# lambda a2 under a norm): under the exponential recipe a feed-forward
+# writes a branch as wide as the stream it joins, a relative error grows
+# 1.6 times a block, and bf16 matmul inputs ANYWHERE in the first 18
+# blocks leave no token in common with float32 at the published sizes
+# (gap_mean 3.8 where a token at random reads 4.2: the chip and the
+# reference with its inputs rounded agree on that; PERF.md, PR 52). The
+# spreads are a unit-gain mixer's at the published widths (the float32
+# reference over 256 positions: a Mamba-1 mixer 0.42, a SwiGLU 10,240
+# wide 0.72, differential attention 0.34 to 0.45, a memory unit 0.41).
+SELECTIVE_KIND_SPREAD = {"S": 0.42, "D": 0.72, "W": 0.4, "*": 0.34,
+                         "G": 0.41, "X": 0.34}
 NORMED_QK_GAIN = 2.0
 # The same for a stack whose attention kinds differ by their positional
 # term (`rope_kinds`) and whose norms take a mean off (`norm_kind`
@@ -239,6 +293,21 @@ NORMED_QK_GAIN = 2.0
 # LayerNorm to take off.
 SHARP_QK_GAIN = 1.5
 STREAM_MEAN = 0.5
+# The same for phi4flash's stack. Biases (`attn_bias`, `norm_bias`) are
+# drawn BIAS_SPREAD wide beside projections and normed lanes of spread 1
+# (an output bias beside its branch: x `branch_gain`); the differential
+# sub-norm's gain 1 + SUBLN_SPREAD x normal; the four lambda vectors
+# LAMBDA_SPREAD wide, so that exp(lq1 . lk1) - exp(lq2 . lk2) moves
+# lambda by some 0.1 about `lambda_init`, as the published
+# initialisation does. Zeros and ones (what a fresh model has) would let
+# a program that drops a bias or the gain pass. With differential
+# attention wq and wk take SHARP_QK_GAIN too: two softmaxes that weigh
+# hundreds of keys alike read the same mean of the values, a1 - lambda
+# a2 is (1 - lambda) times it, and the sub-norm takes lambda out again.
+BIAS_SPREAD = 0.25
+SUBLN_SPREAD = 0.25
+LAMBDA_SPREAD = 0.1
+DIFF_PARAMS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2", "subln")
 
 
 def branch_gain(config: ModelConfig, layer_idx: int) -> float:
@@ -263,7 +332,7 @@ def branch_gain(config: ModelConfig, layer_idx: int) -> float:
         return 1.0
     s0 = (config.embedding_multiplier * config.logits_scaling
           / math.sqrt(config.hidden))
-    if "C" in config.layer_pattern:
+    if "C" in config.layer_pattern or "S" in config.layer_pattern:
         return _conv_stack_gain(config, layer_idx, s0)
     # never under 1: an embedding drawn narrower than a unit-gain branch
     # (no multipliers: s0 = 1 / sqrt(h)) is outweighed from the first
@@ -273,8 +342,9 @@ def branch_gain(config: ModelConfig, layer_idx: int) -> float:
 
 
 def _conv_stack_gain(config: ModelConfig, layer_idx: int, s0: float):
-    """`branch_gain` for a stack with gated short convolutions. A conv
-    mixer is CUBIC in its input (B * u, gated by C), so a branch as wide
+    """`branch_gain` for a stack with gated short convolutions, and for
+    one with Mamba-1 mixers (its own spreads: SELECTIVE_KIND_SPREAD). A
+    conv mixer is CUBIC in its input (B * u, gated by C), so a branch as wide
     as the stream it joins multiplies a relative error by 2.2, and nine
     such mixers by a thousand: under the exponential recipe float32 and
     bf16 share no token at the published sizes (gap_mean 3.3 on the
@@ -293,13 +363,15 @@ def _conv_stack_gain(config: ModelConfig, layer_idx: int, s0: float):
         if m < layer_idx:
             stream = math.hypot(stream, branch)
     kind = config.layer_kind(layer_idx)
-    if kind not in KIND_SPREAD:
+    spreads = (KIND_SPREAD if "C" in config.layer_pattern
+               else SELECTIVE_KIND_SPREAD)
+    if kind not in spreads:
         raise ValueError(
             f"{config.name} (layers {config.layer_pattern}): no seeded "
             f"recipe for a stack that mixes gated short convolutions "
             f"with layer kind {kind!r}; it has one for "
-            f"{' '.join(KIND_SPREAD)}")
-    return branch / KIND_SPREAD[kind] / config.residual_multiplier
+            f"{' '.join(spreads)}")
+    return branch / spreads[kind] / config.residual_multiplier
 
 
 def score_gain(config: ModelConfig) -> float:
@@ -320,7 +392,7 @@ def score_gain(config: ModelConfig) -> float:
     experts'."""
     if config.qk_norm and config.is_hybrid:
         return NORMED_QK_GAIN
-    if config.rope_kinds:
+    if config.rope_kinds or config.diff_attention:
         return SHARP_QK_GAIN
     if not config.attention_multiplier:
         return 1.0
@@ -332,8 +404,12 @@ def attention_scale(config: ModelConfig) -> dict:
     """The keyword a model that states its own score scale hands every
     attention function (kernels and XLA forms alike); nothing for the
     others, whose programs trace 1/sqrt(head_dim) as they always did."""
-    return ({"sm_scale": config.attention_multiplier}
-            if config.attention_multiplier else {})
+    if config.attention_multiplier:
+        return {"sm_scale": config.attention_multiplier}
+    # differential attention is handed to the kernels two heads wide
+    # (`_diff_inputs`): the scale is the single head's
+    return ({"sm_scale": config.head_dim ** -0.5}
+            if config.diff_attention else {})
 
 
 def init_hybrid_layer(k: jax.Array, config: ModelConfig,
@@ -401,11 +477,34 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
     root mean square alone does not. Seeded matrices have lane means of
     spread 1/sqrt(h) and would not tell the two norms apart; a trained
     stream has a mean, which is what the subtraction is for. The later
-    mixers of a parallel block have no `norm` (`has_own_norm`)."""
+    mixers of a parallel block have no `norm` (`has_own_norm`).
+
+    phi4flash's kinds and fields draw what they add from keys of their
+    own, `fold_in(k, 100 + j)` (`extra`), so that no other draw moves:
+    a norm's bias (`norm_bias`, extra 0) and the attention biases
+    (`attn_bias`: bq, bk, bv, bo, extra 1..4) BIAS_SPREAD x normal, bo x
+    `out_gain` beside the branch it joins; the differential parameters
+    (extra 5..9): four lambda vectors LAMBDA_SPREAD x normal, float32,
+    and the sub-norm's gain 1 + SUBLN_SPREAD x normal. A cross-attention
+    mixer ("X") draws wq, wo and their biases as an attention mixer does
+    and nothing else. A gated memory unit ("G") draws W_1 [h, D] from
+    key 0 and W_2 [D, h] (centred) from key 6. A Mamba-1 mixer ("S")
+    draws W_in [h, 2D] = [u | z] from key 0, its taps from key 1, the
+    conv's bias from key 2, dt's bias from key 3 and A from key 4 as a
+    Mamba-2 mixer does (A uniform in [-16, -1] for every (column,
+    channel), stored [N, D] as the state is), W_out from key 6, and
+    W_x [D, R + 2N] from extra 10, W_dt [R, D] from extra 11."""
     dtype = jnp.dtype(config.dtype)
     h = config.hidden
     ks = jax.random.split(k, 15)
     lane_mean = STREAM_MEAN if config.norm_kind == "layer" else 0.0
+
+    def extra(j):
+        return jax.random.fold_in(k, 100 + j)
+
+    def bias(j, shape, gain=1.0):
+        return (BIAS_SPREAD * gain * jax.random.normal(
+            extra(j), shape, jnp.float32)).astype(dtype)
 
     def dense(key, shape, fan_in, centre=None, gain=1.0):
         w = jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
@@ -424,7 +523,36 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
          if has_own_norm(config, layer_idx) else {})
     if config.sandwich_norm:
         p["post_norm"] = jnp.ones((h,), dtype)
-    if kind == "L":
+    if config.norm_bias and "norm" in p:
+        p["norm_b"] = bias(0, (h,))
+    if kind == "S":
+        inner, kw = config.mamba_inner, config.conv_kernel
+        n, rank = config.ssm_state, config.mamba_dt_rank
+        u = jax.random.uniform(ks[3], (inner,), jnp.float32)
+        dt = jnp.exp(u * (math.log(config.ssm_dt_max)
+                          - math.log(config.ssm_dt_min))
+                     + math.log(config.ssm_dt_min))
+        dt = jnp.maximum(dt, config.ssm_dt_floor)
+        p.update({
+            "in_proj": dense(ks[0], (h, 2 * inner), h),
+            "conv_w": dense(ks[1], (kw, inner), kw),
+            "conv_b": (0.1 * jax.random.normal(
+                ks[2], (inner,), jnp.float32)).astype(dtype),
+            "x_proj": dense(extra(10), (inner, rank + 2 * n), inner),
+            "dt_proj": dense(extra(11), (rank, inner), rank),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "a_log": jnp.log(jax.random.uniform(
+                ks[4], (n, inner), jnp.float32, 1.0, 16.0)),
+            "d_skip": jnp.ones((inner,), jnp.float32),
+            "out_proj": dense(ks[6], (inner, h), inner, 0, out_gain),
+        })
+    elif kind == "G":
+        inner = config.mamba_inner
+        p.update({
+            "g_in": dense(ks[0], (h, inner), h),
+            "g_out": dense(ks[6], (inner, h), inner, 0, out_gain),
+        })
+    elif kind == "L":
         qh, rank, q_rank = (config.n_q_heads, config.mla_kv_lora_rank,
                             config.mla_q_lora_rank)
         nope, rd, vd = (config.mla_nope_head_dim, config.mla_rope_head_dim,
@@ -481,7 +609,7 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
             "conv_w": dense(ks[1], (kw, h), kw),
             "out_proj": dense(ks[6], (h, h), h, 0, out_gain),
         })
-    elif kind in "*W":
+    elif kind in "*WX":
         qh, kh, hd = config.n_q_heads, config.n_kv_heads, config.head_dim
         qk_gain = score_gain(config)
         p.update({
@@ -493,6 +621,19 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
         if config.qk_norm:
             p["q_norm"] = jnp.ones((hd,), dtype)
             p["k_norm"] = jnp.ones((hd,), dtype)
+        if config.attn_bias:
+            p.update({"bq": bias(1, (qh, hd)), "bk": bias(2, (kh, hd)),
+                      "bv": bias(3, (kh, hd)),
+                      "bo": bias(4, (h,), out_gain)})
+        if config.diff_attention:
+            for j, name in enumerate(DIFF_PARAMS[:4]):
+                p[name] = LAMBDA_SPREAD * jax.random.normal(
+                    extra(5 + j), (hd,), jnp.float32)
+            p["subln"] = (1.0 + SUBLN_SPREAD * jax.random.normal(
+                extra(9), (2 * hd,), jnp.float32)).astype(dtype)
+        if kind == "X":  # reads the "*" layer's keys and values
+            for name in ("wk", "wv", "bk", "bv"):
+                p.pop(name, None)
     else:
         m = config.expert_mlp_hidden
         sm = _shared_width(config)
@@ -525,27 +666,67 @@ def init_hybrid_layer(k: jax.Array, config: ModelConfig,
     return p
 
 
+def init_hybrid_entry(keys, config: ModelConfig, entry, gains=None) -> dict:
+    """Seeded weights of one entry of the `layers` list
+    (`ModelConfig.layer_entries`): mixer `first`'s, or for a rolled
+    section the mixers first, first + stride, .. drawn each from its own
+    key and gain as `init_hybrid_layer` draws it, stacked along a
+    leading axis. `keys`: the model's (`init_params`: mixer i has
+    keys[i + 1]) and the gains computed here; or, with `gains` (float32
+    [repeats]), the keys [repeats] and `branch_gain`s of the entry's OWN
+    mixers in order, so that one compiled program draws every entry of
+    a kind (`ModelRunner._init_random_params`)."""
+    first, repeats, stride = entry
+    if gains is None:
+        at = [first + stride * r for r in range(repeats)]
+        gains = jnp.asarray([branch_gain(config, i) for i in at],
+                            jnp.float32)
+        keys = keys[jnp.asarray(at) + 1]
+    if repeats == 1:
+        return init_hybrid_layer(keys[0], config, first, gains[0])
+    return jax.vmap(lambda k, gain: init_hybrid_layer(
+        k, config, first, gain))(keys, gains)
+
+
 def conv_channels(config: ModelConfig, layer_idx: int) -> int:
     """Width of a state layer's conv carry: Mamba-2's xBC, or the gated
     short convolution's B * u."""
-    return (config.mamba_conv_dim if config.layer_kind(layer_idx) == "M"
-            else config.hidden)
+    return {"M": config.mamba_conv_dim, "S": config.mamba_inner}.get(
+        config.layer_kind(layer_idx), config.hidden)
+
+
+def _ssm_state_shape(config: ModelConfig, layer_idx: int) -> tuple:
+    """A slot's SSM state of Mamba layer `layer_idx`: Mamba-2's [heads,
+    head_dim, state], Mamba-1's [state, channels] (channels along the
+    lanes: ops/ssm.py)."""
+    if config.layer_kind(layer_idx) == "S":
+        return (config.ssm_state, config.mamba_inner)
+    return (config.mamba_heads, config.mamba_head_dim, config.ssm_state)
 
 
 def make_state_cache(config: ModelConfig, slots: int) -> dict:
     """The per-slot recurrent state: one `conv` array per state layer
-    ("M" or "C", in order) and one `ssm` array per Mamba layer (lists, so
-    each layer's update aliases its own buffer). A stack of "C" layers
-    alone has an empty `ssm` list."""
+    ("M", "S" or "C", in order) and one `ssm` array per Mamba layer ("M"
+    or "S": `_ssm_state_shape`; lists, so each layer's update aliases
+    its own buffer). A stack of "C" layers alone has an empty `ssm`
+    list. An entry of a rolled section (`ModelConfig.layer_entries`)
+    holds its layers' arrays stacked along a leading axis."""
+    def stacked(repeats):  # a rolled section's layers along an axis
+        return (repeats,) if repeats > 1 else ()
+
+    entries = config.layer_entries
     return {
-        "conv": [jnp.zeros((slots, config.conv_kernel - 1,
+        "conv": [jnp.zeros((*stacked(repeats), slots,
+                            config.conv_kernel - 1,
                             conv_channels(config, i)),
                            jnp.dtype(config.dtype))
-                 for i in config.state_layers],
-        "ssm": [jnp.zeros((slots, config.mamba_heads, config.mamba_head_dim,
-                           config.ssm_state),
+                 for i, repeats, _ in entries
+                 if config.layer_kind(i) in "MCS"],
+        "ssm": [jnp.zeros((*stacked(repeats), slots,
+                           *_ssm_state_shape(config, i)),
                           jnp.dtype(config.ssm_state_dtype))
-                for _ in config.ssm_layers],
+                for i, repeats, _ in entries
+                if config.layer_kind(i) in "MS"],
     }
 
 
@@ -555,7 +736,7 @@ def state_slot_bytes(config: ModelConfig) -> int:
     conv = ((config.conv_kernel - 1)
             * sum(conv_channels(config, i) for i in config.state_layers)
             * jnp.dtype(config.dtype).itemsize)
-    ssm = (config.mamba_heads * config.mamba_head_dim * config.ssm_state
+    ssm = (config.mamba_inner * config.ssm_state
            * jnp.dtype(config.ssm_state_dtype).itemsize)
     return conv + len(config.ssm_layers) * ssm
 
@@ -613,8 +794,10 @@ def scan_head_block(config: ModelConfig, t: int, ssm_path: str):
     """Heads a grid step where the Mamba mixers of a launch of `t`
     positions a row run the chunked-scan kernel, None where they run the
     XLA form: the slot says `xla`, or `scan_kernel_tiles` refuses the
-    shapes. `mamba_prefill`'s rule, and what `ModelRunner` counts by."""
-    if ssm_path == "xla":
+    shapes. `mamba_prefill`'s rule, and what `ModelRunner` counts by. A
+    stack whose Mamba layers are Mamba-1 has no scan kernel: its
+    `selective_scan` is the XLA form."""
+    if ssm_path == "xla" or "M" not in config.layer_pattern:
         return None
     return scan_kernel_tiles(t, config.mamba_heads, config.mamba_head_dim,
                              config.ssm_groups, config.ssm_state,
@@ -695,6 +878,78 @@ def mamba_decode(x, lp, config: ModelConfig, conv, ssm, active,
         y = _gated_group_norm(y.reshape(*z.shape), z, lp["ssm_norm"],
                               config.ssm_groups, config.rms_eps)
         return jnp.einsum("sm,mh->sh", y, lp["out_proj"]), conv, ssm
+
+
+def _selective_inputs(u, lp, config: ModelConfig):
+    """u [..., D] (after the conv and its silu) -> (dt [..., D] float32
+    after softplus, B [..., N], C [..., N])."""
+    rank, n = config.mamba_dt_rank, config.ssm_state
+    dbc = jnp.einsum("...d,dr->...r", u, lp["x_proj"])
+    dt = jnp.einsum("...r,rd->...d", dbc[..., :rank], lp["dt_proj"])
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+    return dt, dbc[..., rank:rank + n], dbc[..., rank + n:]
+
+
+def _selective_out(y, u, z, lp):
+    """y [..., D] float32 (the scan's read-out) -> (the mixer's output,
+    the memory m: y + D u BEFORE the gate, in the model dtype)."""
+    y = y + lp["d_skip"] * u.astype(jnp.float32)
+    gated = (y * jax.nn.silu(z.astype(jnp.float32))).astype(u.dtype)
+    return (jnp.einsum("...m,mh->...h", gated, lp["out_proj"]),
+            y.astype(u.dtype))
+
+
+def mamba1_prefill(x, lp, config: ModelConfig, conv, ssm, valid):
+    """A Mamba-1 mixer over a prefill chunk a row. x [B, T, h] (normed);
+    conv [B, K-1, D], ssm [B, N, D]: the rows' state going in. Returns
+    (out [B, T, h], conv, ssm coming out, m [B, T, D]: the scan's output
+    before the gate, what a gated memory unit reads)."""
+    with jax.named_scope("mamba1_mixer"):
+        inner = config.mamba_inner
+        uz = jnp.einsum("bth,hm->btm", x, lp["in_proj"])
+        u, z = uz[..., :inner], uz[..., inner:]
+        n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+        u_dtype = u.dtype
+        u, conv = causal_conv(conv, u, lp["conv_w"], n_valid)
+        u = jax.nn.silu(u + lp["conv_b"].astype(jnp.float32)
+                        ).astype(u_dtype)
+        dt, b, c = _selective_inputs(u, lp, config)
+        dt = jnp.where(valid[:, :, None], dt, 0.0)
+        ssm, y = selective_scan(ssm, dt, -jnp.exp(lp["a_log"]), u, b, c)
+        out, memory = _selective_out(y, u, z, lp)
+        return out, conv, ssm, memory
+
+
+def mamba1_decode(x, lp, config: ModelConfig, conv, ssm, active):
+    """One token a slot. x [S, h]; conv, ssm: the WHOLE cache of this
+    layer (row i = slot i). Inactive rows keep their state. Returns
+    (out [S, h], conv, ssm, m [S, D])."""
+    with jax.named_scope("mamba1_mixer"):
+        inner = config.mamba_inner
+        uz = jnp.einsum("sh,hm->sm", x, lp["in_proj"])
+        u, z = uz[..., :inner], uz[..., inner:]
+        window = jnp.concatenate([conv.astype(u.dtype), u[:, None]],
+                                 axis=1)  # [S, K, D]
+        u = jnp.einsum("skc,kc->sc", window.astype(jnp.float32),
+                       lp["conv_w"].astype(jnp.float32))
+        u = jax.nn.silu(u + lp["conv_b"].astype(jnp.float32)
+                        ).astype(x.dtype)
+        conv = jnp.where(active[:, None, None],
+                         window[:, 1:].astype(conv.dtype), conv)
+        dt, b, c = _selective_inputs(u, lp, config)
+        ssm, y = selective_state_update(ssm, dt, -jnp.exp(lp["a_log"]), u,
+                                        b, c, active)
+        out, memory = _selective_out(y, u, z, lp)
+        return out, conv, ssm, memory
+
+
+def memory_gate_mixer(x, memory, lp):
+    """A gated memory unit: x [..., h] (normed), memory [..., D] (the
+    memory layer's pre-gate scan output AT THE SAME POSITION) ->
+    (m * silu(x W_1)) W_2. No state, no cache, elementwise in time."""
+    with jax.named_scope("memory_gate"):
+        gate = jax.nn.silu(jnp.einsum("...h,hm->...m", x, lp["g_in"]))
+        return jnp.einsum("...m,mh->...h", memory * gate, lp["g_out"])
 
 
 def _split_bcu(bcu, config: ModelConfig):
@@ -794,15 +1049,140 @@ def _qkv(h, lp, config: ModelConfig, kind: str, positions):
     q = jnp.einsum("bth,hqd->btqd", h, lp["wq"])
     k = jnp.einsum("bth,hkd->btkd", h, lp["wk"])
     v = jnp.einsum("bth,hkd->btkd", h, lp["wv"])
+    if config.attn_bias:
+        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
     if config.qk_norm:  # per head, before rope
         q = rms_norm(q, lp["q_norm"], config.rms_eps)
         k = rms_norm(k, lp["k_norm"], config.rms_eps)
     pairs = config.rope_interleaved
-    return (apply_rope(q, positions, tables, pairs),
-            apply_rope(k, positions, tables, pairs), v)
+    q, k = (apply_rope(q, positions, tables, pairs),
+            apply_rope(k, positions, tables, pairs))
+    if config.diff_attention:
+        return (_wide_query(q, config), _kv_rows(k, config),
+                _kv_rows(v, config))
+    return q, k, v
 
 
-ATTENTION_SCOPES = {"*": "attn_full", "W": "attn_window", "L": "attn_latent"}
+def _cross_query(h, lp, config: ModelConfig):
+    """h [B, T, hidden] -> the query of a cross-attention ("X") mixer,
+    which has no keys or values of its own, as `_qkv` gives a "*"
+    mixer's."""
+    q = jnp.einsum("bth,hqd->btqd", h, lp["wq"])
+    if config.attn_bias:
+        q = q + lp["bq"]
+    return _wide_query(q, config) if config.diff_attention else q
+
+
+# Differential attention (arXiv:2410.05258, as phi4flash states it).
+# Heads are paired: query pair p = (q_2p, q_2p+1) reads KV pair g = p //
+# (query pairs a KV pair) = (k_2g, k_2g+1; v_2g, v_2g+1):
+#
+#     a1 = softmax(q_2p   k_2g^T   / sqrt(hd)) [v_2g | v_2g+1]
+#     a2 = softmax(q_2p+1 k_2g+1^T / sqrt(hd)) [v_2g | v_2g+1]
+#     o_p = (1 - lambda_init) RMSNorm(a1 - lambda a2; subln)   [2 hd]
+#
+# A KV pair side by side, (k_2g | k_2g+1), is 2 hd lanes of a token's row
+# of the pool, and so is (v_2g | v_2g+1); a row holds `pairs a row` of
+# them (`ModelConfig.diff_rows`: 10 pairs lie in 2 rows of 640 lanes, the
+# same bytes as [kv heads, 64] row-major). To the kernels a row is ONE kv
+# head as wide as the row. Query head j is handed over as wide as the row
+# with its values in the lanes ITS kv head (2g for even j, 2g + 1 for odd)
+# has there and zeros in the others: q . row = q_j . k exactly (every
+# other head's lanes meet zeros), and P V comes back as wide as the row,
+# of which the pair's 2 hd lanes are a1 (even j) or a2 (odd j). So both
+# softmaxes are plain grouped-query attention at the row's width and
+# scale 1/sqrt(hd) (`attention_scale`), which the accepted kernels and the
+# XLA forms run as they stand; a row's query heads are padded with zero
+# heads to a power of two (the prefill kernel's tiles), and the
+# subtraction, the sub-norm and the scale are the epilogue
+# `_attention_out` adds before W_o.
+
+
+def _diff_layout(config: ModelConfig):
+    """(rows, pairs a row, query heads a pair, query heads a row, the
+    same padded to a power of two)."""
+    rows = config.diff_rows
+    pairs = config.n_kv_heads // 2 // rows
+    per_pair = config.n_q_heads // (rows * pairs)
+    per_row = pairs * per_pair
+    return rows, pairs, per_pair, per_row, config.attn_geometry[0] // rows
+
+
+def _wide_query(q, config: ModelConfig):
+    """q [..., heads, hd] -> [..., rows x padded heads a row, row lanes]:
+    head j's values in the lanes of its kv head within its row (kv head
+    2g + j % 2 of pair g = j // (query heads a pair)), zeros elsewhere."""
+    rows, pairs, per_pair, per_row, padded = _diff_layout(config)
+    hd = q.shape[-1]
+    local = jnp.arange(per_row)
+    block = 2 * (local // per_pair) + local % 2  # kv head within the row
+    own = (block[:, None] == jnp.arange(2 * pairs)[None, :]).astype(q.dtype)
+    wide = (q.reshape(*q.shape[:-2], rows, per_row, 1, hd)
+            * own[:, :, None]).reshape(*q.shape[:-2], rows, per_row,
+                                       2 * pairs * hd)
+    wide = jnp.pad(wide, [(0, 0)] * (wide.ndim - 2)
+                   + [(0, padded - per_row), (0, 0)])
+    return wide.reshape(*q.shape[:-2], rows * padded, 2 * pairs * hd)
+
+
+def _kv_rows(k, config: ModelConfig):
+    """k or v [..., kv heads, hd] -> [..., rows, row lanes]: a row's kv
+    heads side by side, the same values row-major."""
+    rows = config.diff_rows
+    return k.reshape(*k.shape[:-2], rows, k.shape[-2] // rows * k.shape[-1])
+
+
+def _pair_values(attn, config: ModelConfig):
+    """attn [..., rows x padded heads a row, row lanes] as the attention
+    function gave it -> [..., heads, 2 hd]: each real head's own pair's
+    lanes (a1 at an even head, a2 at an odd one)."""
+    rows, pairs, per_pair, per_row, padded = _diff_layout(config)
+    lead = attn.shape[:-2]
+    attn = attn.reshape(*lead, rows, padded, pairs, -1)[..., :per_row, :, :]
+    own = (jnp.arange(per_row)[:, None] // per_pair
+           == jnp.arange(pairs)[None, :]).astype(attn.dtype)
+    picked = jnp.sum(attn * own[:, :, None], axis=-2)  # one term is not 0
+    return picked.reshape(*lead, rows * per_row, picked.shape[-1])
+
+
+def diff_lambda(lp, config: ModelConfig, layer_idx: int):
+    """(lambda, lambda_init) of attention mixer `layer_idx`: lambda_init
+    = 0.8 - 0.6 exp(-0.3 l) for its BLOCK's index l; lambda float32."""
+    block = layer_idx // config.mixers_per_layer
+    # a rolled section's repeat is traced, and so then is its block
+    init = 0.8 - 0.6 * (math.exp(-0.3 * block) if isinstance(block, int)
+                        else jnp.exp(-0.3 * block.astype(jnp.float32)))
+    return (jnp.exp(jnp.sum(lp["lambda_q1"] * lp["lambda_k1"]))
+            - jnp.exp(jnp.sum(lp["lambda_q2"] * lp["lambda_k2"]))
+            + init), init
+
+
+def _attention_out(attn, lp, config: ModelConfig, layer_idx: int):
+    """attn [..., query heads, width] as the attention function gave it
+    -> the mixer's output [..., hidden]: with `diff_attention` the
+    epilogue first (heads 2p and 2p + 1 hold a1 and a2 of pair p), then
+    W_o and with `attn_bias` its bias."""
+    if config.diff_attention:
+        lam, init = diff_lambda(lp, config, layer_idx)
+        attn = _pair_values(attn, config)
+        pair = attn.reshape(*attn.shape[:-2], attn.shape[-2] // 2, 2,
+                            attn.shape[-1]).astype(jnp.float32)
+        diff = pair[..., 0, :] - lam * pair[..., 1, :]
+        diff = diff * jax.lax.rsqrt(
+            jnp.mean(diff * diff, axis=-1, keepdims=True) + config.rms_eps)
+        # (a rolled section's traced `init` rounds as a Python float's
+        # product with the model dtype does)
+        diff = (diff.astype(attn.dtype) * lp["subln"]) * jnp.asarray(
+            1.0 - init, attn.dtype)
+        # [.., pairs, 2 hd] row-major is [.., heads, hd]: pair p's first
+        # half is head 2p's lanes of W_o
+        attn = diff.reshape(*diff.shape[:-2], *lp["wo"].shape[:2])
+    out = jnp.einsum("...qd,qdh->...h", attn, lp["wo"])
+    return out + lp["bo"] if config.attn_bias else out
+
+
+ATTENTION_SCOPES = {"*": "attn_full", "W": "attn_window", "L": "attn_latent",
+                    "X": "attn_cross"}
 
 # Keys a step of a latent layer's prefill attention in XLA: their K and
 # V are rebuilt from the cached rows and scored against every query of
@@ -1032,27 +1412,33 @@ def moe_mixer(x, lp, config: ModelConfig, valid, gmm_path: str):
         return (out if shared is None else out + shared), stats
 
 
-def layer_norm(x, weight, eps: float):
-    """LayerNorm with a weight and no bias, in float32: the mean over
-    the lanes taken off, then the root mean square of what is left."""
+def layer_norm(x, weight, eps: float, bias=None):
+    """LayerNorm in float32: the mean over the lanes taken off, then the
+    root mean square of what is left, a weight and, where the model has
+    one (`norm_bias`), a bias."""
     orig = x.dtype
     x32 = x.astype(jnp.float32)
     x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
     scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * scale).astype(orig) * weight
+    out = (x32 * scale).astype(orig) * weight
+    return out if bias is None else out + bias
 
 
-def stream_norm(x, weight, config: ModelConfig, scope="block_norm"):
+def stream_norm(x, weight, config: ModelConfig, scope="block_norm",
+                bias=None):
     """The norm a mixer (a parallel block's mixers: ONE) or the head
     reads the residual stream through."""
     if config.norm_kind == "layer":
         with jax.named_scope(scope):
-            return layer_norm(x, weight, config.rms_eps)
+            if bias is None:
+                return layer_norm(x, weight, config.rms_eps)
+            return layer_norm(x, weight, config.rms_eps, bias)
     return rms_norm(x, weight, config.rms_eps)
 
 
 def _head(x, params, config: ModelConfig):
-    x = stream_norm(x, params["final_norm"], config, "final_norm")
+    x = stream_norm(x, params["final_norm"], config, "final_norm",
+                    params.get("final_norm_b"))
     if config.tie_embeddings:
         # the embedding's own [rows, h] array, contracted over h: a
         # transposed copy of it (0.41 GB at 50,176 x 4096) is in no step
@@ -1110,7 +1496,7 @@ def _window_frame(window, positions, kv_lens):
 
 
 def prefill_attention(q, kv_cache, layer, block_tables, positions, kv_lens,
-                      window: int = 0):
+                      window: int = 0, sm_scale=None):
     """`paged_attention_xla` for a model with window layers where no
     kernel runs (`_group_attention`: the CPU, a geometry the kernel
     refuses), a block of query positions at a time (`lax.map`), each
@@ -1127,6 +1513,8 @@ def prefill_attention(q, kv_cache, layer, block_tables, positions, kv_lens,
     block = max(1, min(PREFILL_Q_BLOCK, PREFILL_SCORE_POSITIONS // b))
     if t % block:
         block = t
+    # a model that states its score scale; nothing for the others
+    scaled = {} if sm_scale is None else {"sm_scale": sm_scale}
     if window:
         pages = min(width, (window + block) // ps + 1)
     else:
@@ -1143,11 +1531,12 @@ def prefill_attention(q, kv_cache, layer, block_tables, positions, kv_lens,
             return paged_attention_xla(qb, kv_cache, layer, tables, pb,
                                        kv_lens, window=window,
                                        kv_offset=first * ps,
-                                       flat_gather=True)
+                                       flat_gather=True, **scaled)
         need = jnp.max(jnp.minimum(kv_lens, jnp.max(pb, axis=1) + 1))
         return jax.lax.switch(
             sum((need > w * ps).astype(jnp.int32) for w in widths[:-1]),
-            [functools.partial(_prefix_attention, w, layer) for w in widths],
+            [functools.partial(_prefix_attention, w, layer, **scaled)
+             for w in widths],
             qb, kv_cache, block_tables, pb, kv_lens)
 
     if block == t:
@@ -1160,9 +1549,10 @@ def prefill_attention(q, kv_cache, layer, block_tables, positions, kv_lens,
 
 
 def _prefix_attention(pages, layer, q, kv_cache, block_tables, positions,
-                      kv_lens):
+                      kv_lens, **scaled):
     return paged_attention_xla(q, kv_cache, layer, block_tables[:, :pages],
-                               positions, kv_lens, flat_gather=True)
+                               positions, kv_lens, flat_gather=True,
+                               **scaled)
 
 
 def _as_stored(x, cache):
@@ -1188,11 +1578,30 @@ def _group_attention(attention_fn, q_shape, cache, tables):
     return prefill_attention
 
 
+def _group_index(config: ModelConfig, kinds: str, first: int, stride: int,
+                 r):
+    """The cache layer, within its page group, of mixer first + r x
+    stride: the layers of `kinds` before it. `r` is a rolled section's
+    repeat (traced), or 0 for a mixer of its own."""
+    def count(part):
+        return sum(part.count(kind) for kind in kinds)
+
+    base = count(config.layer_pattern[:first])
+    return base + r * count(config.layer_pattern[first:first + stride]) \
+        if stride else base
+
+
+def _state_counts(kinds: str) -> tuple[int, int]:
+    """(conv arrays, ssm arrays) the mixers `kinds` keep."""
+    return (sum(kinds.count(k) for k in "MCS"),
+            sum(kinds.count(k) for k in "MS"))
+
+
 def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
                    state, slots, block_tables, kv_lens, valid, last_idx,
                    attention_fn=None, gmm_path: str = "xla",
                    all_logits: bool = False, window=None,
-                   ssm_path: str = "xla"):
+                   ssm_path: str = "xla", decode_attention_fn=None):
     """A prefill chunk a row. tokens, positions, valid [B, T]; slots [B]:
     each row's state slot (>= the cache's size for an empty row: its
     write is dropped); last_idx [B]: the row's last valid position in
@@ -1204,9 +1613,27 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
     (full group, window group); its window layers read their short
     table (window + chunk keys) in that group's frame, its full layers
     the sequence's, each group through `_group_attention`. `ssm_path`:
-    the Mamba mixers' scan (`mamba_prefill`); no other mixer reads it."""
+    the Mamba mixers' scan (`mamba_prefill`); no other mixer reads it.
+
+    A stack with a tail that caches nothing and carries nothing in time
+    (`ModelConfig.cross_decoder_start`: gated memory units and
+    cross-attention) runs the tail on each row's `last_idx` ALONE, every
+    row of every launch (one program a launch shape, whether or not a
+    row's chunk is its last): the stream, the memory and the shared
+    layer's key and value are cut to that position, and a cross-attention
+    mixer reads the pages through `decode_attention_fn`, one query a row
+    over the history plus the position's own key and value in registers,
+    as a decode step does. The logits at `last_idx` are the same;
+    `all_logits` runs the tail on every position instead.
+
+    The mixers are walked by the entries of `params["layers"]`
+    (`ModelConfig.layer_entries`): one at a time, or a rolled section's
+    period as the body of ONE `lax.scan` over its repeats, which carries
+    the stream, the pools and the section's stacked state."""
     attention = win_attention = attention_fn or paged_attention_xla
-    q_shape = (*tokens.shape, config.n_q_heads, config.head_dim)
+    qh, _, hd = config.attn_geometry
+    q_shape = (*tokens.shape, qh, hd)
+    win_cache = None
     if window is not None:
         win_cache, win_tables, win_pos, win_lens = _window_frame(
             window, positions, kv_lens)
@@ -1220,33 +1647,50 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
         # longest context served (float32 [rows, T, heads, keys])
         attention = _group_attention(attention_fn, q_shape, kv_cache,
                                      block_tables)
+    decode_attention = decode_attention_fn or paged_attention_decode_xla
     fresh = positions[:, 0] == 0  # a row at position 0 starts from zero
     scaled = attention_scale(config)
     x = _embed(params, config, tokens)
     conv_out, ssm_out = list(state["conv"]), list(state["ssm"])
     stats = jnp.zeros((moe_stats_size(config),), jnp.int32)
-    kv_idx = win_idx = state_idx = ssm_idx = 0
-    pending = None
-    for layer_idx, lp in enumerate(params["layers"]):
-        kind = config.layer_kind(layer_idx)
-        if has_own_norm(config, layer_idx):  # else: its block's first
-            h = stream_norm(x, lp["norm"], config)
+    tail = config.n_layers if all_logits else config.cross_decoder_start
+    # what later mixers read of an earlier one, values of this step: the
+    # memory layer's scan output and the shared layer's keys and values
+    memory = shared = None
+
+    def at_last(a):  # [B, T, ...] -> [B, 1, ...]: each row's `last_idx`
+        return jnp.take_along_axis(
+            a, last_idx[(slice(None),) + (None,) * (a.ndim - 1)], axis=1)
+
+    def mixer(first, layer_idx, lp, h, carry, conv_all, ssm_all, kv_idx,
+              win_idx):
+        """Mixer `layer_idx` (= `first`, or a later repeat of it in a
+        rolled section: then traced, as `kv_idx` and `win_idx` are) on
+        `carry` = (x, pending, kv_cache, win_cache, moe stats) and its
+        own state arrays. Returns (h, carry, conv_all, ssm_all, kept)."""
+        x, pending, kv_cache, win_cache, stats = carry
+        kind = config.layer_kind(first)
+        kept = None
+        if has_own_norm(config, first):  # else: its block's first
+            h = stream_norm(x, lp["norm"], config, bias=lp.get("norm_b"))
         if kind == "M":
-            conv_all, ssm_all = conv_out[state_idx], ssm_out[ssm_idx]
             conv = jnp.where(fresh[:, None, None], 0, conv_all[slots])
             ssm = jnp.where(fresh[:, None, None, None], 0, ssm_all[slots])
             out, conv, ssm = mamba_prefill(h, lp, config, conv, ssm, valid,
                                            ssm_path)
-            conv_out[state_idx] = conv_all.at[slots].set(conv, mode="drop")
-            ssm_out[ssm_idx] = ssm_all.at[slots].set(ssm, mode="drop")
-            state_idx += 1
-            ssm_idx += 1
+            conv_all = conv_all.at[slots].set(conv, mode="drop")
+            ssm_all = ssm_all.at[slots].set(ssm, mode="drop")
+        elif kind == "S":
+            conv = jnp.where(fresh[:, None, None], 0, conv_all[slots])
+            ssm = jnp.where(fresh[:, None, None], 0, ssm_all[slots])
+            out, conv, ssm, kept = mamba1_prefill(h, lp, config, conv, ssm,
+                                                  valid)
+            conv_all = conv_all.at[slots].set(conv, mode="drop")
+            ssm_all = ssm_all.at[slots].set(ssm, mode="drop")
         elif kind == "C":
-            conv_all = conv_out[state_idx]
             conv = jnp.where(fresh[:, None, None], 0, conv_all[slots])
             out, conv = short_conv_prefill(h, lp, config, conv, valid)
-            conv_out[state_idx] = conv_all.at[slots].set(conv, mode="drop")
-            state_idx += 1
+            conv_all = conv_all.at[slots].set(conv, mode="drop")
         elif kind == "*":
             with jax.named_scope(ATTENTION_SCOPES[kind]):
                 q, k, v = _qkv(h, lp, config, kind, positions)
@@ -1255,8 +1699,8 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
                     _as_stored(v, kv_cache), block_tables, positions, valid)
                 attn = attention(q, kv_cache, kv_idx, block_tables,
                                  positions, kv_lens, **scaled)
-                out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])
-            kv_idx += 1
+                out = _attention_out(attn, lp, config, layer_idx)
+            kept = (k, v)
         elif kind == "W":
             with jax.named_scope(ATTENTION_SCOPES[kind]):
                 q, k, v = _qkv(h, lp, config, kind, positions)
@@ -1264,14 +1708,25 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
                                            win_tables, win_pos, valid)
                 attn = win_attention(q, win_cache, win_idx, win_tables,
                                      win_pos, win_lens,
-                                     window=config.sliding_window)
-                out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])
-            win_idx += 1
+                                     window=config.sliding_window, **scaled)
+                out = _attention_out(attn, lp, config, layer_idx)
+        elif kind == "X":
+            with jax.named_scope(ATTENTION_SCOPES[kind]):
+                q = _cross_query(h, lp, config)
+                at = config.shared_kv_layer(first)
+                if first >= tail:
+                    attn = decode_attention(q, kv_cache, at, block_tables,
+                                            kv_lens, *shared, **scaled)
+                else:  # `all_logits`: every position reads the pages
+                    attn = attention(q, kv_cache, at, block_tables,
+                                     positions, kv_lens, **scaled)
+                out = _attention_out(attn, lp, config, layer_idx)
+        elif kind == "G":
+            out = memory_gate_mixer(h, memory, lp)
         elif kind == "L":
             kv_cache, out = latent_prefill(h, lp, config, kv_cache, kv_idx,
                                            block_tables, positions, kv_lens,
                                            valid, attention_fn)
-            kv_idx += 1
         elif kind == "D":
             out = dense_mixer(h, lp)
         else:
@@ -1279,11 +1734,79 @@ def forward_hybrid(params, config: ModelConfig, tokens, positions, kv_cache,
             stats = stats + layer_stats
         if config.sandwich_norm:
             out = rms_norm(out, lp["post_norm"], config.rms_eps)
-        x, pending = _join(x, pending, _branch(out, config), config,
-                           layer_idx)
+        x, pending = _join(x, pending, _branch(out, config), config, first)
+        return (h, (x, pending, kv_cache, win_cache, stats), conv_all,
+                ssm_all, kept)
+
+    carry = (x, None, kv_cache, win_cache, stats)
+    entries, layers = config.layer_entries, params["layers"]
+    h = None
+    e = conv_at = ssm_at = 0
+    while e < len(entries):
+        first, repeats, stride = entries[e]
+        if first == tail:
+            carry = (at_last(carry[0]), *carry[1:])
+            memory = at_last(memory)
+            shared = tuple(at_last(a) for a in shared)
+        if repeats == 1:
+            kind = config.layer_kind(first)
+            n_conv, n_ssm = _state_counts(kind)
+            h, carry, conv_all, ssm_all, kept = mixer(
+                first, first, layers[e], h, carry,
+                conv_out[conv_at] if n_conv else None,
+                ssm_out[ssm_at] if n_ssm else None,
+                _group_index(config, "*L", first, 0, 0),
+                _group_index(config, "W", first, 0, 0))
+            if n_conv:
+                conv_out[conv_at] = conv_all
+            if n_ssm:
+                ssm_out[ssm_at] = ssm_all
+            if first == config.memory_layer:
+                memory = kept
+            elif kind == "*":
+                shared = kept
+            e, conv_at, ssm_at = e + 1, conv_at + n_conv, ssm_at + n_ssm
+            continue
+        # a rolled section: its period is the body of one scan
+        period = config.layer_pattern[first:first + stride]
+        n_conv, n_ssm = _state_counts(period)
+
+        def body(walk, xs, first=first, stride=stride, period=period):
+            (x, kv_cache, win_cache), convs, ssms = walk
+            r, lps = xs
+            convs, ssms = list(convs), list(ssms)
+            # no expert layer in a rolled section: no statistics
+            inner, ci, si = (x, None, kv_cache, win_cache, None), 0, 0
+            for j, kind in enumerate(period):
+                has_conv, has_ssm = _state_counts(kind)
+                _, inner, conv_all, ssm_all, _ = mixer(
+                    first + j, first + j + r * stride, lps[j], None, inner,
+                    convs[ci][r] if has_conv else None,
+                    ssms[si][r] if has_ssm else None,
+                    _group_index(config, "*L", first + j, stride, r),
+                    _group_index(config, "W", first + j, stride, r))
+                if has_conv:
+                    convs[ci] = convs[ci].at[r].set(conv_all)
+                if has_ssm:
+                    ssms[si] = ssms[si].at[r].set(ssm_all)
+                ci, si = ci + has_conv, si + has_ssm
+            x, _, kv_cache, win_cache, _ = inner
+            return ((x, kv_cache, win_cache), tuple(convs), tuple(ssms)), None
+
+        (walked, convs, ssms), _ = jax.lax.scan(
+            body,
+            (carry[0:1] + carry[2:4],
+             tuple(conv_out[conv_at:conv_at + n_conv]),
+             tuple(ssm_out[ssm_at:ssm_at + n_ssm])),
+            (jnp.arange(repeats), tuple(layers[e:e + stride])))
+        carry = (walked[0], None, *walked[1:], carry[4])
+        conv_out[conv_at:conv_at + n_conv] = convs
+        ssm_out[ssm_at:ssm_at + n_ssm] = ssms
+        e, conv_at, ssm_at = e + stride, conv_at + n_conv, ssm_at + n_ssm
+    x, _, kv_cache, win_cache, stats = carry
     state = {"conv": conv_out, "ssm": ssm_out}
     if not all_logits:
-        x = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
+        x = (x if tail < config.n_layers else at_last(x))[:, 0]
     if window is not None:
         kv_cache = (kv_cache, win_cache)
     return kv_cache, state, _head(x, params, config), stats
@@ -1296,7 +1819,12 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
     """One token for every slot (row i = slot i), KV writes deferred to
     one scatter a page group for all its attention layers as in
     `forward_decode`. Returns (kv_cache, state, logits [S, 1, vocab], moe
-    stats); `window` as in `forward_hybrid`."""
+    stats); `window` as in `forward_hybrid`. A cross-attention mixer
+    reads the shared layer's pages and this step's key and value of that
+    layer, and adds nothing to the scatter. The mixers are walked by
+    entries as in `forward_hybrid`; a rolled section's scan carries the
+    stream, the section's stacked state and, where it has window layers,
+    their pool, which each of them writes its own token into."""
     attn_fn = decode_attention_fn or (
         paged_attention_decode_latent_xla if config.has_latent_layers
         else paged_attention_decode_xla)
@@ -1310,49 +1838,60 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
     conv_out, ssm_out = list(state["conv"]), list(state["ssm"])
     stats = jnp.zeros((moe_stats_size(config),), jnp.int32)
     ks, vs, win_ks, win_vs = [], [], [], []
-    kv_idx = win_idx = state_idx = ssm_idx = 0
-    pending = None
-    for layer_idx, lp in enumerate(params["layers"]):
-        kind = config.layer_kind(layer_idx)
-        if has_own_norm(config, layer_idx):  # else: its block's first
-            h = stream_norm(x, lp["norm"], config)
+    memory = None
+
+    def mixer(first, layer_idx, lp, h, carry, conv_all, ssm_all, kv_idx,
+              win_idx, win_pool=None):
+        """As `forward_hybrid`'s, on `carry` = (x, pending, moe stats);
+        `win_pool`: the window group's pool as a rolled section carries
+        it (None: the step's own, which the deferred scatter writes).
+        Returns (h, carry, conv_all, ssm_all, kept): `kept` the keys and
+        values (or latent row) an attention mixer has for the scatter,
+        or the memory layer's scan output."""
+        x, pending, stats = carry
+        kind = config.layer_kind(first)
+        kept = None
+        if has_own_norm(config, first):  # else: its block's first
+            h = stream_norm(x, lp["norm"], config, bias=lp.get("norm_b"))
         if kind == "M":
-            out, conv_out[state_idx], ssm_out[ssm_idx] = mamba_decode(
-                h, lp, config, conv_out[state_idx], ssm_out[ssm_idx],
-                active, ssm_path)
-            state_idx += 1
-            ssm_idx += 1
+            out, conv_all, ssm_all = mamba_decode(
+                h, lp, config, conv_all, ssm_all, active, ssm_path)
+        elif kind == "S":
+            out, conv_all, ssm_all, kept = mamba1_decode(
+                h, lp, config, conv_all, ssm_all, active)
         elif kind == "C":
-            out, conv_out[state_idx] = short_conv_decode(
-                h, lp, config, conv_out[state_idx], active)
-            state_idx += 1
+            out, conv_all = short_conv_decode(h, lp, config, conv_all,
+                                              active)
         elif kind == "*":
             with jax.named_scope(ATTENTION_SCOPES[kind]):
                 q, k, v = _qkv(h[:, None, :], lp, config, kind,
                                positions[:, None])
                 attn = attn_fn(q, kv_cache, kv_idx, block_tables, attn_lens,
                                k, v, **scaled)
-                out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])[:, 0]
-            ks.append(k)
-            vs.append(v)
-            kv_idx += 1
+                out = _attention_out(attn, lp, config, layer_idx)[:, 0]
+            kept = (k, v)
         elif kind == "W":
             with jax.named_scope(ATTENTION_SCOPES[kind]):
                 q, k, v = _qkv(h[:, None, :], lp, config, kind,
                                positions[:, None])
-                attn = attn_fn(q, win_cache, win_idx, win_tables,
-                               win_attn_lens, k, v,
-                               window=config.sliding_window)
-                out = jnp.einsum("btqd,qdh->bth", attn, lp["wo"])[:, 0]
-            win_ks.append(k)
-            win_vs.append(v)
-            win_idx += 1
+                attn = attn_fn(q, win_cache if win_pool is None else win_pool,
+                               win_idx, win_tables, win_attn_lens, k, v,
+                               window=config.sliding_window, **scaled)
+                out = _attention_out(attn, lp, config, layer_idx)[:, 0]
+            kept = (k, v)
+        elif kind == "X":
+            with jax.named_scope(ATTENTION_SCOPES[kind]):
+                at = config.shared_kv_layer(first)
+                attn = attn_fn(_cross_query(h[:, None, :], lp, config),
+                               kv_cache, at, block_tables, attn_lens,
+                               ks[at], vs[at], **scaled)
+                out = _attention_out(attn, lp, config, layer_idx)[:, 0]
+        elif kind == "G":
+            out = memory_gate_mixer(h, memory, lp)
         elif kind == "L":
-            out, row = latent_decode(h, lp, config, kv_cache, kv_idx,
-                                     block_tables, positions, attn_lens,
-                                     attn_fn)
-            ks.append(row)
-            kv_idx += 1
+            out, kept = latent_decode(h, lp, config, kv_cache, kv_idx,
+                                      block_tables, positions, attn_lens,
+                                      attn_fn)
         elif kind == "D":
             out = dense_mixer(h, lp)
         else:
@@ -1362,8 +1901,85 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
             stats = stats + layer_stats
         if config.sandwich_norm:
             out = rms_norm(out, lp["post_norm"], config.rms_eps)
-        x, pending = _join(x, pending, _branch(out, config), config,
-                           layer_idx)
+        x, pending = _join(x, pending, _branch(out, config), config, first)
+        return h, (x, pending, stats), conv_all, ssm_all, kept
+
+    carry = (x, None, stats)
+    entries, layers = config.layer_entries, params["layers"]
+    h = None
+    e = conv_at = ssm_at = 0
+    while e < len(entries):
+        first, repeats, stride = entries[e]
+        if repeats == 1:
+            kind = config.layer_kind(first)
+            n_conv, n_ssm = _state_counts(kind)
+            h, carry, conv_all, ssm_all, kept = mixer(
+                first, first, layers[e], h, carry,
+                conv_out[conv_at] if n_conv else None,
+                ssm_out[ssm_at] if n_ssm else None,
+                _group_index(config, "*L", first, 0, 0),
+                _group_index(config, "W", first, 0, 0))
+            if n_conv:
+                conv_out[conv_at] = conv_all
+            if n_ssm:
+                ssm_out[ssm_at] = ssm_all
+            if first == config.memory_layer:
+                memory = kept
+            elif kind == "*":
+                ks.append(kept[0])
+                vs.append(kept[1])
+            elif kind == "L":
+                ks.append(kept)
+            elif kind == "W":
+                win_ks.append(kept[0])
+                win_vs.append(kept[1])
+            e, conv_at, ssm_at = e + 1, conv_at + n_conv, ssm_at + n_ssm
+            continue
+        period = config.layer_pattern[first:first + stride]
+        n_conv, n_ssm = _state_counts(period)
+
+        def body(walk, xs, first=first, stride=stride, period=period):
+            x, pool, convs, ssms = walk
+            r, lps = xs
+            convs, ssms = list(convs), list(ssms)
+            inner, ci, si = (x, None, None), 0, 0
+            for j, kind in enumerate(period):
+                has_conv, has_ssm = _state_counts(kind)
+                win_idx = _group_index(config, "W", first + j, stride, r)
+                _, inner, conv_all, ssm_all, kept = mixer(
+                    first + j, first + j + r * stride, lps[j], None, inner,
+                    convs[ci][r] if has_conv else None,
+                    ssms[si][r] if has_ssm else None, None, win_idx, pool)
+                if has_conv:
+                    convs[ci] = convs[ci].at[r].set(conv_all)
+                if has_ssm:
+                    ssms[si] = ssms[si].at[r].set(ssm_all)
+                ci, si = ci + has_conv, si + has_ssm
+                if kind == "W":
+                    # a rolled section's window layers write their own
+                    # token here, a layer a scatter on the carried pool:
+                    # ONE scatter over a stack of layers makes XLA lay
+                    # the pool out with the layers next to the lanes
+                    # ([8 layers, .., 2 rows, 640]: whole (8, 128)
+                    # tiles), and every step copies 2.1 GB in and out of
+                    # the layout the kernels read (the compiler, PR 52)
+                    pool = write_kv_pages(pool, win_idx, *kept, win_tables,
+                                          win_pos[:, None], active[:, None])
+            return (inner[0], pool, tuple(convs), tuple(ssms)), None
+
+        (x, pool, convs, ssms), _ = jax.lax.scan(
+            body,
+            (carry[0], win_cache if "W" in period else None,
+             tuple(conv_out[conv_at:conv_at + n_conv]),
+             tuple(ssm_out[ssm_at:ssm_at + n_ssm])),
+            (jnp.arange(repeats), tuple(layers[e:e + stride])))
+        if "W" in period:
+            win_cache = pool
+        carry = (x, None, carry[2])
+        conv_out[conv_at:conv_at + n_conv] = convs
+        ssm_out[ssm_at:ssm_at + n_ssm] = ssms
+        e, conv_at, ssm_at = e + stride, conv_at + n_conv, ssm_at + n_ssm
+    x, _, stats = carry
     if config.has_latent_layers:
         kv_cache = write_latent_stack(kv_cache, jnp.stack(ks), block_tables,
                                       positions, active)
@@ -1373,9 +1989,10 @@ def forward_hybrid_decode(params, config: ModelConfig, tokens, positions,
             _as_stored(jnp.stack(vs), kv_cache), block_tables,
             positions[:, None], active[:, None])
     if window is not None:
-        win_cache = write_kv_stack(win_cache, jnp.stack(win_ks),
-                                   jnp.stack(win_vs), win_tables,
-                                   win_pos[:, None], active[:, None])
+        if win_ks:  # of mixers of their own: one scatter for them all
+            win_cache = write_kv_stack(win_cache, jnp.stack(win_ks),
+                                       jnp.stack(win_vs), win_tables,
+                                       win_pos[:, None], active[:, None])
         kv_cache = (kv_cache, win_cache)
     state = {"conv": conv_out, "ssm": ssm_out}
     return kv_cache, state, _head(x, params, config)[:, None, :], stats
@@ -1457,7 +2074,8 @@ class HybridSteps:
         kv, state, last, stats = forward_hybrid(
             params, self.config, tokens, positions, kv, state, slots, table,
             kv_lens, valid, last_idx, attention_fn=self.attention_fn,
-            gmm_path=self.gmm_path, window=window, ssm_path=self.ssm_path)
+            gmm_path=self.gmm_path, window=window, ssm_path=self.ssm_path,
+            decode_attention_fn=self.decode_attention_fn)
         return (kv if window is not None else (kv,), state), last, stats
 
     def decode(self, params, cache, tokens, positions, tables, kv_lens,

@@ -42,10 +42,12 @@ def param_axes(config: ModelConfig) -> dict:
 
         head = ({} if config.tie_embeddings
                 else {"lm_head": ("embed", "vocab")})
+        if config.norm_bias:
+            head["final_norm_b"] = ("embed",)
         return {"embed": ("vocab", "embed"), "final_norm": ("embed",),
                 **head,
-                "layers": [hybrid_layer_axes(config, i)
-                           for i in range(config.n_layers)]}
+                "layers": [hybrid_layer_axes(config, first, repeats)
+                           for first, repeats, _ in config.layer_entries]}
     _refuse_multipliers(config)
     layer = {
         "attn_norm": ("embed",),
@@ -245,12 +247,23 @@ def init_top_params(k_embed: jax.Array, k_head: jax.Array,
     if not tied:
         params["lm_head"] = _init_dense(k_head, (h, config.vocab_size), h,
                                         dtype, config.logits_scaling)
+    if config.norm_bias:  # a LayerNorm with a bias (models/hybrid.py)
+        from .hybrid import BIAS_SPREAD
+
+        params["final_norm_b"] = (BIAS_SPREAD * jax.random.normal(
+            jax.random.fold_in(k_embed, 1), (h,), jnp.float32)).astype(dtype)
     return params
 
 
 def init_params(key: jax.Array, config: ModelConfig) -> dict:
     keys = jax.random.split(key, config.n_layers + 2)
     params = init_top_params(keys[0], keys[-1], config)
+    if config.is_hybrid:
+        from .hybrid import init_hybrid_entry
+
+        params["layers"] = [init_hybrid_entry(keys, config, entry)
+                            for entry in config.layer_entries]
+        return params
     params["layers"] = [init_layer_params(keys[i + 1], config, i)
                         for i in range(config.n_layers)]
     return params

@@ -764,7 +764,8 @@ def paged_decode_attention_pool(
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("pages_per_chunk", "interpret"),
+                   static_argnames=("pages_per_chunk", "interpret",
+                                    "sm_scale"),
                    donate_argnums=())
 def paged_decode_attention_window(
     q: jax.Array,  # [B, qh, hd]
@@ -776,6 +777,7 @@ def paged_decode_attention_window(
     *,
     pages_per_chunk: int | None = None,
     interpret: bool = False,
+    sm_scale: float | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """`paged_decode_attention_pool` over a window layer's page group,
     under a name of its own so that a device trace tells the window
@@ -784,7 +786,7 @@ def paged_decode_attention_window(
     and masks the tokens before `starts`."""
     return _pool_flash_partials(q, kv_pool, layer, block_tables,
                                 kv_lens_hist, None, starts,
-                                pages_per_chunk, interpret)
+                                pages_per_chunk, interpret, sm_scale)
 
 
 def paged_attention_decode_fused(
@@ -898,14 +900,13 @@ def paged_attention_decode_pool(
     to the scores and the probabilities. `window` > 0: a window layer's
     page group (its own table and lengths; bf16 pool only), through
     `paged_decode_attention_window`. `sm_scale`: the score scale of a
-    model that states one (a full layer's bf16 pool only; None:
-    1/sqrt(hd), and nothing of it is traced). A pool whose rows pack
+    model that states one (a bf16 pool only; None: 1/sqrt(hd), and
+    nothing of it is traced). A pool whose rows pack
     several kv heads into a lane tile (head_dim 64) runs the same kernel
     through `_packed_pool_partials`; the geometry decides."""
     values, scales = (kv_cache if isinstance(kv_cache, tuple)
                       else (kv_cache, None))
-    assert sm_scale is None or (scales is None and not window), (
-        "sm_scale: a bf16 full group")
+    assert sm_scale is None or scales is None, "sm_scale: a bf16 pool"
     packed = values.shape[5] != q.shape[-1]  # kv heads share lane tiles
     if _q8_needs_xla(values, scales, interpret) or (
             packed and (window or scales is not None)):
@@ -913,7 +914,7 @@ def paged_attention_decode_pool(
 
         return paged_attention_decode_xla(q, kv_cache, layer, block_tables,
                                           kv_lens, k_cur, v_cur,
-                                          window=window)
+                                          window=window, sm_scale=sm_scale)
     if packed:
         acc, m, l = _packed_pool_partials(
             q[:, 0], values, layer, block_tables,
@@ -924,8 +925,9 @@ def paged_attention_decode_pool(
         acc, m, l = paged_decode_attention_window(
             q[:, 0], values, layer, block_tables,
             jnp.maximum(kv_lens - 1, 0), jnp.maximum(kv_lens - window, 0),
-            pages_per_chunk=pages_per_chunk, interpret=interpret)
-        return _combine_current(q, acc, m, l, k_cur, v_cur)
+            pages_per_chunk=pages_per_chunk, interpret=interpret,
+            sm_scale=sm_scale)
+        return _combine_current(q, acc, m, l, k_cur, v_cur, sm_scale)
     acc, m, l = paged_decode_attention_pool(
         q[:, 0], values, layer, block_tables,
         jnp.maximum(kv_lens - 1, 0), kv_scales=scales,
@@ -2127,7 +2129,8 @@ def paged_prefill_attention_pool(
                               kv_lens, kv_scales, 0, interpret, sm_scale)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"),
+@functools.partial(jax.jit,
+                   static_argnames=("window", "interpret", "sm_scale"),
                    donate_argnums=())
 def paged_prefill_attention_window(
     q: jax.Array,  # [B, T, qh, hd]
@@ -2140,6 +2143,7 @@ def paged_prefill_attention_window(
     *,
     window: int,
     interpret: bool = False,
+    sm_scale: float | None = None,
 ) -> jax.Array:
     """`paged_prefill_attention_pool` for a window layer: a query sees
     the last `window` keys up to its own, and the chunks below a query
@@ -2147,7 +2151,8 @@ def paged_prefill_attention_window(
     decode kernels are, so that a device trace tells the window layers'
     events from the full layers'."""
     return _pool_prefill_call(q, kv_pool, layer, block_tables, starts,
-                              kv_lens, kv_scales, window, interpret)
+                              kv_lens, kv_scales, window, interpret,
+                              sm_scale)
 
 
 def paged_attention(
@@ -2177,8 +2182,8 @@ def paged_attention(
     every prefill launch lays its rows out. One token (T == 1) over a
     bf16 pool runs the per-layer flash decode kernel. Everything else
     takes `paged_attention_xla`, the CPU path and the oracle of both.
-    `sm_scale`: the score scale of a model that states one (the full
-    layers' kernel and the XLA form; None: 1/sqrt(hd), nothing traced)."""
+    `sm_scale`: the score scale of a model that states one (None:
+    1/sqrt(hd), nothing traced)."""
     from ..models.transformer import paged_attention_xla
 
     values, scales = (kv_cache if isinstance(kv_cache, tuple)
@@ -2195,10 +2200,10 @@ def paged_attention(
             block_tables.shape[1], values.dtype,
             None if scales is None else scales.shape[-1]) is not None:
         if window:
-            assert sm_scale is None, "sm_scale: a full group's layers"
             return paged_prefill_attention_window(
                 q, values, layer, block_tables, positions[:, 0], kv_lens,
-                kv_scales=scales, window=window, interpret=interpret)
+                kv_scales=scales, window=window, interpret=interpret,
+                sm_scale=sm_scale)
         return paged_prefill_attention_pool(
             q, values, layer, block_tables, positions[:, 0], kv_lens,
             kv_scales=scales, interpret=interpret, sm_scale=sm_scale)

@@ -1,4 +1,6 @@
-"""Mamba-2 (SSD) state ops: the decode state update and the chunked scan.
+"""State-space mixers' ops: Mamba-2 (SSD)'s decode state update and
+chunked scan, and behind them Mamba-1's (`selective_scan`,
+`selective_state_update`).
 
     S_t = exp(dt_t A) S_{t-1} + dt_t (x_t outer B_t)        S: [H, P, N]
     y_t = S_t C_t                                           (D x_t is the caller's)
@@ -454,6 +456,67 @@ def ssm_chunk_scan_kernel(state, dt, a, xbc, *, chunk: int,
                 vmem_limit_bytes=64 * 1024 * 1024),
         )(rows, xbc, xbc, xbc, state)
     return final, y
+
+
+# ---------------------------------------------------------------------------
+# Mamba-1: a decay a channel AND a state column
+# ---------------------------------------------------------------------------
+#
+#     s_t = exp(dt_t (x) A) * s_{t-1} + (dt_t * u_t) (x) B_t    s: [N, D]
+#     y_t = s_t^T C_t                               (D * u_t is the caller's)
+#
+# A is [N, D]: the decay of s[n, d] differs by column n, so Mamba-2's
+# chunked matmul form (one decay a head: a chunk's [L, L] product is
+# shared by a head's lanes) does not apply; its segment matrix would be
+# [L, L, D, N]. The state is kept [N, D], channels along lanes: N = 16
+# rows of 5,120 lanes are whole (8, 128) tiles, where [D, N] pads 16
+# lanes to 128 and holds eight times the bytes.
+
+
+def selective_state_update(state, dt, a, u, b, c, active):
+    """One token a row. state [S, N, D] (all slots; row i is slot i), dt
+    [S, D] f32 (after softplus), a [N, D] f32 (negative), u [S, D], b, c
+    [S, N], active [S] bool. Returns (new state, y [S, D] f32); inactive
+    rows keep their state and read y = 0. One fusion over the donated
+    state: 2 x 4 N D bytes a live row."""
+    with jax.named_scope("selective_update"):
+        f32 = jnp.float32
+        decay = jnp.exp(dt[:, None, :] * a[None])
+        dtu = (dt * u.astype(f32))[:, None, :]
+        new = decay * state.astype(f32) + dtu * b.astype(f32)[:, :, None]
+        new = new.astype(state.dtype)  # read out of the state AS STORED
+        y = jnp.sum(new.astype(f32) * c.astype(f32)[:, :, None], axis=1)
+        return (jnp.where(active[:, None, None], new, state),
+                jnp.where(active[:, None], y, 0.0))
+
+
+# Positions a turn of `selective_scan`'s loop: the recurrence is
+# sequential in time, and a turn's work ([rows, N, D] floats) is small
+# beside what a turn of a loop costs, so a turn does several.
+SELECTIVE_SCAN_UNROLL = 8
+
+
+def selective_scan(state, dt, a, u, b, c):
+    """The recurrence over T positions a row, in time order. state [B, N,
+    D] (the rows' state going in, float32), dt [B, T, D] f32 (0 at
+    padding: such a position leaves the state as it was), a [N, D] f32,
+    u [B, T, D], b, c [B, T, N]. Returns (state coming out, y [B, T, D]
+    f32). Nothing over (T, N, D) exists: the carry is [B, N, D] and a
+    turn of the loop reads a position's dt, u, B, C and writes its y."""
+    f32 = jnp.float32
+    with jax.named_scope("selective_scan"):
+        def step(s, xs):
+            dt_t, u_t, b_t, c_t = xs  # [B, D], [B, D], [B, N], [B, N]
+            s = (jnp.exp(dt_t[:, None, :] * a[None]) * s
+                 + (dt_t * u_t.astype(f32))[:, None, :]
+                 * b_t.astype(f32)[:, :, None])
+            return s, jnp.sum(s * c_t.astype(f32)[:, :, None], axis=1)
+
+        final, y = jax.lax.scan(
+            step, state.astype(f32),
+            tuple(jnp.swapaxes(x, 0, 1) for x in (dt, u, b, c)),
+            unroll=min(SELECTIVE_SCAN_UNROLL, dt.shape[1]))
+    return final.astype(state.dtype), jnp.swapaxes(y, 0, 1)
 
 
 # ---------------------------------------------------------------------------

@@ -20,7 +20,7 @@ import dataclasses
 
 import numpy as np
 
-from ..models.config import ModelConfig
+from ..models.config import ModelConfig, cache_plan
 from .chips import ChipSpec
 
 
@@ -40,7 +40,13 @@ def param_count(cfg: ModelConfig) -> int:
 
 
 def kv_bytes_per_token(cfg: ModelConfig, dtype_bytes: int = 2) -> int:
-    return 2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * dtype_bytes
+    """Bytes a cached token holds while every layer still sees it: the
+    cache layers of the plan's page groups (the layers that WRITE pages:
+    a `layer_pattern` stack's mixers are not all attention, and a layer
+    that reads another's pages holds none), each a row of the pool."""
+    layers = sum(cache_plan(cfg).group_layers) or cfg.n_layers
+    return (layers * cfg.kv_cache_kv_dims
+            * cfg.kv_cache_heads * cfg.kv_cache_head_dim * dtype_bytes)
 
 
 @dataclasses.dataclass

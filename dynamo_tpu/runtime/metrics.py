@@ -482,6 +482,29 @@ LATENT_PREFILL_EXPAND_TOKENS = Gauge(
     "pays again: 1 for a prompt prefilled in one launch",
     ["worker"], registry=REGISTRY,
 )
+KV_PAGE_LAYER_READS = Gauge(
+    "dynamo_kv_page_layer_reads_total",
+    "Model with layers that read pages they do not own (cross-attention "
+    "onto another layer's keys and values): active rows x layers that "
+    "read a page group's pages, summed over decode steps since start, "
+    "by: owner (the layer wrote the pages it reads: the full and window "
+    "groups' cache layers) | shared (the layer reads another layer's "
+    "pages and caches nothing). Times a row's live tokens and the bytes "
+    "of a cached token a layer, owner + shared is what a step's "
+    "attention kernels move; owner alone what the pools hold",
+    ["worker", "by"], registry=REGISTRY,
+)
+PREFILL_CROSS_DECODER_ROWS = Gauge(
+    "dynamo_prefill_cross_decoder_rows_total",
+    "Model whose tail of layers caches nothing and carries nothing in "
+    "time (gated memory units, cross-attention): rows of prefill "
+    "launches since start, on each of which the tail ran at ONE "
+    "position, by chunk: last (the row's chunk ended its prompt: the "
+    "logits are read) | earlier (they are not: what running the tail "
+    "for every row of every launch, one program a launch shape, "
+    "spends). earlier over both is the share wasted",
+    ["worker", "chunk"], registry=REGISTRY,
+)
 KV_RESERVED_PAGE_MS = Gauge(
     "dynamo_kv_reserved_page_ms",
     "Sum over committed steps of (pages allocated to sequences that "
@@ -524,17 +547,18 @@ KV_WINDOW_ALLOC_FAIL = Gauge(
 SSM_STATE_SLOT_MS = Gauge(
     "dynamo_ssm_state_slot_ms",
     "Model with recurrent state: sum over committed steps of (scheduler "
-    "slots held, each with one fixed-size state: a Mamba-2 layer's conv "
-    "carry and SSM state, a gated short-convolution layer's conv carry "
-    "alone) x the step's wall ms. Over the growth of "
+    "slots held, each with one fixed-size state: a Mamba-2 or Mamba-1 "
+    "layer's conv carry and SSM state, a gated short-convolution "
+    "layer's conv carry alone) x the step's wall ms. Over the growth of "
     "dynamo_step_part_ms_total{part=wall} and --max-batch it is the "
     "share of the state cache that is live",
     ["worker"], registry=REGISTRY,
 )
 SSM_PREFILL_POSITIONS = Gauge(
     "dynamo_ssm_prefill_positions_total",
-    "Model with recurrent state: valid positions x state layers (Mamba-2 "
-    "and short-convolution alike) that prefill launches have carried a "
+    "Model with recurrent state: valid positions x state layers (Mamba-2, "
+    "Mamba-1 and short-convolution alike) that prefill launches have "
+    "carried a "
     "state over since start, by carry: fresh (the row began at position "
     "0, from zero state) | continued (the row took up the state its "
     "slot kept from the launch before). continued over both is the "
@@ -553,7 +577,9 @@ SSM_SCAN_LAUNCHES = Gauge(
     "Model with recurrent state: prefill launches since start by the "
     "path their Mamba layers' chunked scan took: kernel (the Pallas "
     "kernel ssm_chunk_scan, x and y in the projection's own layout) | "
-    "xla (the XLA form: [chunk, chunk] products and relaid copies in HBM)",
+    "xla (the XLA form: [chunk, chunk] products and relaid copies in "
+    "HBM; a Mamba-1 layer's selective scan, a loop over positions, is "
+    "always this)",
     ["worker", "path"], registry=REGISTRY,
 )
 MOE_EXPERT_TOKENS = Gauge(

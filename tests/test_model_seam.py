@@ -33,6 +33,7 @@ STACKS = {
     "latent": ("tiny-pangu-test", {}, {}),
     "short-conv": ("tiny-lfm2-test", {}, {}),
     "parallel-block": ("tiny-cohere2-test", {}, {"window_pages": 16}),
+    "shared-kv+mamba1": ("tiny-phi4flash-test", {}, {"window_pages": 16}),
 }
 
 
@@ -177,6 +178,7 @@ def test_a_dense_stacks_plan_is_the_plain_one():
     ("tiny-mellum-test", ("full", "window"), False, False, "always"),
     ("tiny-pangu-test", ("full",), False, True, "always"),
     ("tiny-cohere2-test", ("full", "window"), False, False, "always"),
+    ("tiny-phi4flash-test", ("full", "window"), True, False, "always"),
 ])
 def test_the_plan_says_what_each_kind_of_layer_brings(preset, groups, state,
                                                       prefix, bound):
@@ -187,6 +189,32 @@ def test_the_plan_says_what_each_kind_of_layer_brings(preset, groups, state,
     for trait in ("move_pages", "score_positions", "shard", "int8_pool",
                   "quantized_weights"):
         assert preset in getattr(plan, trait), trait
+
+
+@pytest.mark.parametrize("preset,layers,readers", [
+    ("tiny-test", (), ()),  # a dense stack: every layer its own
+    ("tiny-mellum-test", (2, 6), (2, 6)),
+    ("tiny-granite-test", (1,), (1,)),
+    ("tiny-pangu-test", (5,), (5,)),
+    # ONE full layer that three read; three window layers
+    ("tiny-phi4flash-test", (1, 3), (3, 3)),
+    ("phi4-mini-flash-reasoning", (1, 8), (8, 8)),
+])
+def test_a_page_group_holds_its_writers_and_counts_its_readers(preset,
+                                                               layers,
+                                                               readers):
+    """A pool has a cache layer for every layer that WRITES its group
+    (bytes a token, pool sizes, the wire layout); a layer that reads
+    another's pages adds a reader and no pages."""
+    from dynamo_tpu.profiler import kv_bytes_per_token
+
+    config = get_config(preset)
+    plan = cache_plan(config)
+    assert (plan.group_layers, plan.group_readers) == (layers, readers)
+    assert kv_bytes_per_token(config) == (
+        (sum(layers) or config.n_layers) * config.kv_cache_kv_dims
+        * config.kv_cache_heads
+        * config.kv_cache_head_dim * 2)
 
 
 def test_the_scheduler_reads_the_plan_and_a_stub_runner_has_the_plain_one():
@@ -261,6 +289,20 @@ REFUSALS = [
     ("command-a-plus-05-2026", dict(weight_dtype="int4"),
      ["--weight-dtype int4", "has expert matrices"]),
     ("command-a-plus-05-2026", dict(devices=4), ["--tp/--sp/--dp"]),
+    # Mamba-1 state beside two page groups, one of whose layers seven
+    # others read: the window group refuses first, as for mellum
+    # (test_phi4flash_model.py, PR 52)
+    ("phi4-mini-flash-reasoning", dict(mode="prefill"),
+     ["--mode prefill", "two page groups"]),
+    ("phi4-mini-flash-reasoning", dict(kvbm=True),
+     [*KVBM, "two page groups"]),
+    ("phi4-mini-flash-reasoning", dict(spec=True),
+     [*SPEC, "multi-position"]),
+    ("phi4-mini-flash-reasoning", dict(kv_dtype="int8"),
+     ["--kv-dtype int8"]),
+    ("phi4-mini-flash-reasoning", dict(weight_dtype="int4"),
+     ["--weight-dtype int4", "Mamba-1", "shared-KV cross-attention"]),
+    ("phi4-mini-flash-reasoning", dict(devices=4), ["--tp/--sp/--dp"]),
     # latent attention, a single-stack pool (test_pangu_model.py, PR 38)
     ("tiny-pangu-test", dict(mode="prefill"),
      ["--mode prefill", "single-stack latent pool"]),

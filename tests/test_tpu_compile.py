@@ -1623,3 +1623,146 @@ def test_the_lowering_tool_compiles_the_programs_its_regex_finds(one_chip):
     assert "step" in built and set(built.memory) == {"step"}
     assert "argument_size_in_bytes" in built.memory["step"]
     assert "HloModule" in built.compiled["step"]
+
+
+# phi4-mini-flash-reasoning as the `phi4-mini-flash` cell serves it: the
+# whole model (7.71 GB of bf16 weights), 64 rows; differential attention
+# handed to the kernels a ROW of five KV pairs wide (64 query rows, 40 of
+# them real, over 2 rows of 640 lanes, scale 1/8: 10 pairs of 128 lanes
+# would be padded to 16 and Mosaic refuses a page's slice of 10); the
+# full group's ONE layer of 35,856 pages under
+# tables of 8 to 560 columns, the window group's 8 layers of 3,200 pages
+# (window 512: a row holds 34).
+PHI4 = {"rows": 64, "pages": 35856, "window_pages": 3200, "width": 560}
+
+
+def _phi4_programs(one_chip):
+    """(config, params, caches) as shapes on the described chip."""
+    from dynamo_tpu.models.config import get_config
+    from dynamo_tpu.models.hybrid import make_state_cache
+    from dynamo_tpu.models.transformer import init_params, make_kv_cache
+
+    cfg = get_config("phi4-mini-flash-reasoning")
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pools = tuple(on_chip(jax.eval_shape(
+        lambda group=group, pages=pages: make_kv_cache(
+            cfg, pages, PAGE, group=group)))
+        for group, pages in (("full", PHI4["pages"]),
+                             ("window", PHI4["window_pages"])))
+    state = on_chip(jax.eval_shape(
+        lambda: make_state_cache(cfg, PHI4["rows"])))
+    return cfg, params, (pools, state)
+
+
+@pytest.mark.parametrize("program", ["decode-block", "prefill-1x2048"])
+def test_phi4flashs_step_programs_fit_the_chip_and_copy_no_pool(one_chip,
+                                                                program):
+    """The fused 4-step decode block at the widest table and the widest
+    prefill launch at the cell's sizes, as `HybridSteps` builds them,
+    compile for a described v5e: sixteen attention layers through the
+    accepted head_dim-128 kernels (nine of them in prefill: the tail's
+    seven cross-attention layers read one position a row through the
+    DECODE kernel), the rolled sections as loops, nothing the size of a
+    pool's layer copied, and weights (7.71 GB) + pools (2.94 + 2.10 GB)
+    + state (0.21 GB) + the program's temporaries under the 15.75 GiB
+    the compiler gives a v5e."""
+    import functools
+
+    from dynamo_tpu.engine.sampler import sample, sample_with_logprobs
+    from dynamo_tpu.models.hybrid import HybridSteps
+    from dynamo_tpu.ops.paged_attention import (
+        paged_attention,
+        paged_attention_decode_pool,
+    )
+
+    cfg, params, cache = _phi4_programs(one_chip)
+    # the kernels a one-chip mesh hands the stack (`_mesh_kernels`)
+    steps = HybridSteps(cfg, {
+        "prefill": functools.partial(paged_attention, interpret=False),
+        "decode": functools.partial(paged_attention_decode_pool,
+                                    interpret=False)})
+    n, width = PHI4["rows"], PHI4["width"]
+    (full, window), state = cache
+    assert full.shape == (1, 2, PHI4["pages"], PAGE, 2, 640)
+    assert window.shape == (8, 2, PHI4["window_pages"], PAGE, 2, 640)
+    assert state["ssm"][0].shape == (8, n, 16, 5120)  # a rolled section's
+
+    def tables(rows):
+        return (_shape(one_chip, (rows, width), jnp.int32),
+                _shape(one_chip, (rows, 40), jnp.int32),
+                _shape(one_chip, (rows,), jnp.int32))
+
+    def decode(params, cache, tokens, positions, tables, kv_lens, active,
+               temperature, top_p, top_k, seeds, step_idx):
+        def body(carry, _):
+            cache, toks, pos, lens, sidx = carry
+            cache, logits, _ = steps.decode(params, cache, toks, pos, tables,
+                                            lens, active)
+            nxt = sample(logits[:, 0, :], temperature, top_p, top_k, seeds,
+                         sidx)
+            return (cache, nxt, pos + 1, lens + 1, sidx + 1), nxt
+
+        (cache, *_), toks = jax.lax.scan(
+            body, (cache, tokens, positions, kv_lens, step_idx), None,
+            length=4)
+        return cache, toks
+
+    def prefill(params, cache, tokens, positions, tables, kv_lens, valid,
+                last_idx, temperature, top_p, top_k, seeds, slots):
+        cache, last, _ = steps.prefill(params, cache, tokens, positions,
+                                       tables, kv_lens, valid, last_idx,
+                                       slots)
+        return (cache, *sample_with_logprobs(
+            last, temperature, top_p, top_k, seeds, jnp.int32(0)))
+
+    def vec(rows, dtype):
+        return _shape(one_chip, (rows,), dtype)
+
+    if program == "decode-block":
+        compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+            params, cache, vec(n, jnp.int32), vec(n, jnp.int32), tables(n),
+            vec(n, jnp.int32), vec(n, jnp.bool_), vec(n, jnp.float32),
+            vec(n, jnp.float32), vec(n, jnp.int32), vec(n, jnp.uint32),
+            vec(n, jnp.int32)).compile()
+    else:
+        def chunk(dtype):
+            return _shape(one_chip, (1, 2048), dtype)
+
+        # the window group's prefill table: window + chunk keys in whole
+        # key chunks of the kernel (`ModelRunner.window_prefill_width`)
+        win = (_shape(one_chip, (1, 176), jnp.int32), vec(1, jnp.int32))
+        compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+            params, cache, chunk(jnp.int32), chunk(jnp.int32),
+            (_shape(one_chip, (1, width), jnp.int32), *win),
+            vec(1, jnp.int32), chunk(jnp.bool_), vec(1, jnp.int32),
+            vec(1, jnp.float32), vec(1, jnp.float32), vec(1, jnp.int32),
+            vec(1, jnp.uint32), vec(1, jnp.int32)).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    for kernel in ("paged_decode_attention_pool",) + (
+            ("paged_decode_attention_window",) if program == "decode-block"
+            else ("paged_prefill_attention_pool",
+                  "paged_prefill_attention_window")):
+        assert kernel in text, kernel
+    # no float32 [rows, T, channels, columns] of the scan anywhere
+    assert not re.search(r"f32\[(1,)?2048,(16,5120|5120,16)\]", text)
+    layer_bytes = PHI4["window_pages"] * PAGE * 2 * 640 * 2
+    # nothing of a pool: not even one layer's K and V. (What a prefill
+    # launch does copy, 105 and 168 MB an attention layer, are the wide
+    # queries and the kernel's output relaid between the kernel's
+    # [rows, T, 64, 640] and the epilogue's: left by PR 52, PERF.md 7.)
+    assert [c for c in _copies(text, layer_bytes)
+            if "16,2,640]" in c] == []
+    assert _copies(text, 2 * layer_bytes) == []
+    assert memory.argument_size_in_bytes < 13.2e9
+    # 0.25 GB the decode block, 0.45 GB the launch (with ONE scatter over
+    # the window group's stacked layers the block copied that pool in
+    # and out of a layout with the layers next to the lanes: 2.4 GB)
+    assert memory.temp_size_in_bytes < 0.6e9
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
