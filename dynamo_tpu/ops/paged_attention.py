@@ -298,16 +298,36 @@ def paged_decode_attention_partial(
     return acc, m[..., 0], l[..., 0]
 
 
-# A DMA chunk holds at most this many tokens of one sequence: a 64-page
-# table of 16-token pages is two grid steps, a 128-page one four. The
-# flash update takes the chunk whole while its score tile [kh*g, tok*kh]
-# stays within _SCORE_ELEMS (mistral's 32 rows x 8 kv heads: 512 tokens),
-# and in equal blocks where the spec fold's rows would outgrow it. Large
-# blocks are what the chip rewards (my chip run, PR 27: 185 us a layer at
-# 128-token blocks, 147 us at 512), the scheduler overlapping one part's
-# matmuls with another's conversions.
-_CHUNK_TOKENS = 512
-_SCORE_ELEMS = 32 * 4096
+# The pool kernel walks a row's pages in chunks of at most
+# _POOL_CHUNK_TOKENS, _POOL_SLOTS chunks in VMEM: one is scored while the
+# next one's pages land. A chunk's pages are copied in blocks, each block
+# on its own semaphore and awaited once, so a ragged end copies half a
+# block too many on average; a chunk's live blocks are scored in ONE
+# straight line, a flash update a block. A block is the tokens whose
+# score tile [kh*g, tok*kh] holds _SCORE_ELEMS float32 (lfm2's and
+# mellum's 32 rows x 4 kv heads: 256 tokens; mistral's 32 x 8: 128),
+# between _POOL_BLOCK_TOKENS' fewest and most (the spec fold's 160 rows
+# x 8: 64), and a chunk holds at most _POOL_CHUNK_BLOCKS of them (a path
+# a count of live blocks is compiled) and _POOL_SLOT_BYTES. The copies
+# are started _POOL_START_PAGES a turn of a loop.
+# Timed alone at the cells' shapes (PERF.md section 6, PR 53; ms a layer
+# a step, the grid of (row, chunk) it replaces beside it): lfm2's 256
+# rows at tables of 192 | 64 | 8 pages 0.89 | 0.66 | 0.24 (1.96 | 1.60 |
+# 1.06), blocks of 128 level (0.89) and of 512 slower (0.96, with chunks
+# of 2,048 0.95), a third slot 0.85, 16 pages a turn 0.885, a block
+# scored one block before its update and not all of a chunk's first
+# 0.88; mistral's 32 rows of int8 at 64 pages 0.154 at blocks of 128,
+# 0.167 at 256, 0.165 at 512 (0.188); the hybrid's 128 rows 0.20 (0.57);
+# mellum's window layers 0.23 (0.36) and full layers at 512 pages 0.92
+# (1.23); phi4's 640-lane rows at 560 pages (not merged: two blocks of
+# 224 a chunk) 2.74 (2.79), four blocks of 160 and slots of 5 MiB level.
+_POOL_CHUNK_TOKENS = 1024
+_POOL_BLOCK_TOKENS = (64, 256)
+_POOL_CHUNK_BLOCKS = 4
+_POOL_START_PAGES = 8
+_POOL_SLOTS = 2
+_POOL_SLOT_BYTES = 4 * 1024 * 1024
+_SCORE_ELEMS = 32 * 1024
 
 
 def _largest_divisor(n: int, cap: int) -> int:
@@ -329,28 +349,49 @@ def _mod(x, d: int):
     return x & (d - 1) if d & (d - 1) == 0 else x % d
 
 
-def _next_chunk(lengths_ref, bi, ci, *, bk: int, n_chunks: int,
-                batch_size: int):
-    """The (row, chunk) whose DMA the grid step (bi, ci) starts: the next
-    chunk of this row while it lies inside the history AND inside the
-    grid, else chunk 0 of the next row with a history; row == batch_size
-    when nothing is left. Bounded by the grid as well as by the length, a
-    length past the table width in use (a stale slot) cannot start a copy
-    that no grid step awaits."""
-    def advance_b():
-        nb = jax.lax.fori_loop(
-            0, batch_size,
-            lambda _, cur: jnp.where(
-                jnp.logical_and(
-                    cur < batch_size,
-                    lengths_ref[jnp.clip(cur, 0, batch_size - 1)] == 0),
-                cur + 1, cur),
-            bi + 1)
-        return nb, jnp.int32(0)
+def _pool_tiles(max_pages: int, page_bytes: int, ps: int, rows: int,
+                kh: int, pages_per_chunk: int | None,
+                chunk_blocks: int) -> tuple[int, int]:
+    """(pages a chunk, pages a block) of the pool decode kernel for a
+    table of `max_pages`: the largest chunk that divides the table, stays
+    within `pages_per_chunk` (None: `_POOL_CHUNK_TOKENS`) and
+    `_POOL_SLOT_BYTES`, and is at most `chunk_blocks` blocks of its
+    largest divisor within a `_SCORE_ELEMS` score tile's tokens."""
+    fewest, most = _POOL_BLOCK_TOKENS
+    block_cap = max(1, min(most, max(fewest, _SCORE_ELEMS // (rows * kh)))
+                    // ps)
+    cap = min(pages_per_chunk or max(1, _POOL_CHUNK_TOKENS // ps),
+              max(1, _POOL_SLOT_BYTES // page_bytes))
+    for ppc in range(max(1, min(max_pages, cap)), 0, -1):
+        block_pages = _largest_divisor(ppc, block_cap)
+        if max_pages % ppc == 0 and ppc <= block_pages * chunk_blocks:
+            return ppc, block_pages
+    raise AssertionError("unreachable: one page a chunk divides any table")
 
-    more = jnp.logical_and(ci + 1 < n_chunks,
-                           (ci + 1) * bk < lengths_ref[bi])
-    return jax.lax.cond(more, lambda: (bi, ci + 1), advance_b)
+
+def _chunk_walk(kv_lens_hist, block_tables, layer, page_size: int,
+                pages_per_chunk: int, slots: int):
+    """The first five scalar-prefetch operands of a decode kernel whose
+    grid step is a ROW (`_pool_decode_kernel`, `_latent_decode_kernel`):
+    the live chunks of all rows numbered in the order they are scored, so
+    that chunk g lands in slot g mod `slots` and nothing in the kernel
+    looks for the next row. (lengths [B], clipped to the table: a stale
+    slot reads its table's width; tables [B * max_pages]; layer [1];
+    first [B], a row's first chunk in that order; row_of [B * chunks a
+    table + slots], the g-th live chunk's row and B past the last one,
+    far enough for the deepest prefetch to read.)"""
+    b, max_pages = block_tables.shape
+    chunk = pages_per_chunk * page_size
+    lengths = jnp.clip(kv_lens_hist.astype(jnp.int32), 0,
+                       max_pages * page_size)
+    per_row = (lengths + chunk - 1) // chunk
+    ends = jnp.cumsum(per_row)
+    row_of = jnp.searchsorted(
+        ends, jnp.arange(b * (max_pages // pages_per_chunk) + slots,
+                         dtype=jnp.int32), side="right",
+        method="compare_all").astype(jnp.int32)
+    return (lengths, block_tables.reshape(-1).astype(jnp.int32),
+            jnp.asarray(layer, jnp.int32).reshape(1), ends - per_row, row_of)
 
 
 class _PrefillWalk:
@@ -433,19 +474,29 @@ def _token_scale_row(sc, kh: int):
 
 
 def _pool_decode_kernel(
-    # scalar prefetch
+    # scalar prefetch (`_chunk_walk`)
     lengths_ref,  # [B] int32 HISTORY lengths (current token excluded)
     tables_ref,  # [B * max_pages] int32 flattened block tables
     layer_ref,  # [1] int32
-    buf_idx_ref,  # [1] int32 (mutable scalar-prefetch: double-buffer slot)
-    init_ref,  # [1] int32 (1 until the first DMA was issued)
+    first_ref,  # [B] int32: a row's first chunk in the order of the walk
+    row_ref,  # [G] int32: the row of the walk's g-th live chunk, else B
     # `windowed`: one more scalar prefetch, then inputs + outputs +
-    # scratch, order depending on `quantized` — unpacked below (Pallas
-    # passes refs positionally)
+    # scratch, `quantized` adding two — unpacked below (Pallas passes
+    # refs positionally)
     #   starts_ref  [B] int32: the first history token a row still sees
     #   q_ref       [1, kh*g, hd] (block for this b; rows head-major)
-    #   pool_ref    FULL [L, 2, P, ps, kh, hd] in HBM (memory_space=ANY)
+    #   pool_ref    FULL [L, 2, P, ps*kh, hd] in HBM (memory_space=ANY),
+    #               or [L, 2, P, ps, kh, hd] where that is no bitcast
+    #   scale_ref   FULL bf16 [L, 2, P, ps, LANES] in HBM (ANY)
+    #   acc_ref     [1, kh*g, hd] f32 unnormalized accumulator
+    #   m_ref, l_ref [1, kh*g, 128] f32
+    #   kv_buf      [slots, 2, C*ps*kh, hd] (slot, K|V) page chunks, or
+    #               [slots, 2, C*ps, kh, hd]
+    #   sc_buf      [slots, 2, C*ps, LANES] lane-broadcast scales
+    #   sems        DMA semaphores (slots, blocks a chunk)
     *rest,
+    page_size: int,
+    kv_heads: int,
     pages_per_chunk: int,
     block_pages: int,
     max_pages: int,
@@ -463,14 +514,10 @@ def _pool_decode_kernel(
         fuse the slice), and a row whose length is 0 (the caller masks
         every inactive slot to 0) costs a grid step that does nothing;
       * one DMA moves a page's K and V for ALL kv heads (the pool's
-        page-major layout), into a double-buffered chunk of
-        `pages_per_chunk` pages; within a chunk only the blocks of
-        `block_pages` pages that hold history are copied, awaited and
-        computed, so a large chunk (few grid steps) streams no more than
-        a small one would;
-      * all heads in one MXU pass, no per-head gather: the block
-        [tok, kh, hd] is read as [tok*kh, hd] (free: a token's kh rows
-        are consecutive) and scored against all kh*g query rows at once,
+        page-major layout);
+      * all heads in one MXU pass, no per-head gather: a block
+        [tok, kh, hd] is read as [tok*kh, hd] (a token's kh rows are
+        consecutive) and scored against all kh*g query rows at once,
         S = Q K^T [kh*g, tok*kh]. Entries whose row and column belong to
         different kv heads are masked to -inf together with the length
         mask, so softmax and P V give each head exactly its own result.
@@ -481,9 +528,36 @@ def _pool_decode_kernel(
         unnormalized (acc, m, l) partials in f32. The probabilities are
         rounded once, to the operand dtype, for P V.
 
+    The walk (the latent kernel's, `_latent_decode_kernel`):
+      * a grid step is a ROW, and its live chunks are a loop inside. The
+        caller numbers the live chunks of all rows in the order they are
+        scored (`_chunk_walk`); chunk g lands in slot g mod `slots`, and
+        while it is scored chunk g + slots - 1 is started, whichever
+        row's it is. No step and no turn is spent on a chunk without
+        history, and nothing here looks for the next row;
+      * a chunk's copies are started in a loop, `_POOL_START_PAGES` a
+        turn, each on the semaphore of its BLOCK of `block_pages` pages;
+        a block is awaited once, by a descriptor of the block's size, so
+        block 0 is scored while the later ones are landing. A block past
+        the row's history is neither started nor awaited;
+      * a chunk's live blocks are scored in one straight line (a path a
+        count of live blocks), a flash update a block, so that Mosaic
+        runs one block's Q K^T under another's softmax and no update
+        waits on a `pl.when`;
+      * a ragged last block is copied whole, so that the wait's size is
+        static. The last update of a line masks its scores, and it
+        zeroes the values behind the history (an int8 pool's: their
+        scales), which may be pages nobody has written (0 x NaN is NaN
+        in P V).
+
+    The call is compiled without Mosaic's bounds checks on its copies
+    (two a descriptor): the caller clips lengths to the table, and a
+    table holds page numbers of the pool.
+
     `quantized` (static): pages stream as int8 (half the bytes of bf16)
     plus per-token head-shared bf16 scale rows ([ps, LANES],
-    lane-broadcast so a page's DMA slice is tiling-aligned). The codes
+    lane-broadcast so a page's DMA slice is tiling-aligned), a page's on
+    the semaphore of its block, whose wait is for both. The codes
     convert to the operand dtype exactly; the K scale multiplies the f32
     scores and the V scale the f32 probabilities (`_token_scale_row`),
     never the [tok*kh, hd] tiles.
@@ -495,159 +569,186 @@ def _pool_decode_kernel(
     is left of the oldest page below the window's lower edge. The copies
     and their order are the unwindowed kernel's: a start past the first
     block would leave that block without a live token, and is the
-    caller's to rule out (it holds one block of slack at most).
+    caller's to rule out (it holds one page of slack at most).
     """
-    starts_ref = None
+    starts_ref = scale_ref = sc_buf = None
     if windowed:
         starts_ref, *rest = rest
     q_ref, pool_ref, *rest = rest
     if quantized:
-        (scale_ref,  # FULL bf16 [L, 2, P, ps, LANES] in HBM (ANY)
-         acc_ref, m_out_ref, l_out_ref,
-         kv_buf,  # [2, 2, C, ps, kh, hd] (slot, K|V) page chunks
-         sc_buf,  # [2, 2, C, ps, LANES] lane-broadcast scales
-         sems, m_ref, l_ref, o_ref) = rest
-    else:
-        scale_ref = sc_buf = None
-        (acc_ref,  # [1, kh*g, hd] f32 unnormalized accumulator
-         m_out_ref,  # [1, kh*g, 128] f32
-         l_out_ref,  # [1, kh*g, 128] f32
-         kv_buf,  # [2, 2, C, ps, kh, hd]
-         sems,  # DMA semaphores (2,): one per slot
-         m_ref, l_ref,  # [kh*g, 128] f32
-         o_ref) = rest  # [kh*g, hd] f32
+        scale_ref, *rest = rest
+    acc_ref, m_ref, l_ref, kv_buf, *rest = rest
+    if quantized:
+        sc_buf, *rest = rest
+    (sems,) = rest
     b = pl.program_id(0)
-    i = pl.program_id(1)
-    n_chunks = pl.num_programs(1)
-    ps, kh, hd = kv_buf.shape[3:]
+    slots, hd = kv_buf.shape[0], kv_buf.shape[-1]
+    ps, kh = page_size, kv_heads
+    # buffer rows a token: its kh rows where the caller merged a page's
+    # (token, kv head) dimensions (`_pool_flash_partials`), else one
+    unit = kh if len(kv_buf.shape) == 4 else 1
     rows = q_ref.shape[1]
     g = rows // kh
     bk = pages_per_chunk * ps
     block_tok = block_pages * ps
+    n_blocks = pages_per_chunk // block_pages
+    cols = block_tok * kh
     if sm_scale is None:  # a model that states no scale of its own
         sm_scale = 1.0 / math.sqrt(hd)
-    n_blocks = pages_per_chunk // block_pages
-    layer = layer_ref[0]
-    length = lengths_ref[b]
+    length = lengths_ref[b]  # <= max_pages * ps
+    per_turn = _largest_divisor(block_pages, _POOL_START_PAGES)
+    pool_layer = pool_ref.at[layer_ref[0]]
+    scale_layer = scale_ref.at[layer_ref[0]] if quantized else None
 
-    def chunk_copies(bi, ci, slot, fn):
-        # Chunk ci of row bi <-> buffer `slot`: one copy per page for K
-        # and V together (and one for both scale rows), block by block;
-        # a block past the row's history is neither started nor awaited.
-        # Start and wait rebuild the same descriptors under the same
-        # predicates (the wait consumes the slot semaphore's byte count).
+    def blocks_of(left):  # a chunk's live blocks, `left` tokens to go
+        return jnp.minimum(n_blocks, _div(left + block_tok - 1, block_tok))
+
+    def start_chunk(bi, ci, slot):
         base = bi * max_pages + ci * pages_per_chunk
-        left = lengths_ref[bi] - ci * bk
+        blocks = blocks_of(lengths_ref[bi] - ci * bk)
 
-        def block(u):
-            for j in range(u * block_pages, (u + 1) * block_pages):
-                page = tables_ref[base + j]
-                fn(pltpu.make_async_copy(
-                    pool_ref.at[layer, :, page], kv_buf.at[slot, :, j],
-                    sems.at[slot]))
-                if quantized:
-                    fn(pltpu.make_async_copy(
-                        scale_ref.at[layer, :, page], sc_buf.at[slot, :, j],
-                        sems.at[slot]))
-
-        block(0)  # the chunk is only touched when its first block is live
-        for u in range(1, n_blocks):
-            pl.when(u * block_tok < left)(functools.partial(block, u))
-
-    def start_copy(bi, ci, slot):
-        chunk_copies(bi, ci, slot, lambda c: c.start())
-
-    def wait_copy(bi, ci, slot):
-        chunk_copies(bi, ci, slot, lambda c: c.wait())
-
-    active = i * bk < length
-
-    @pl.when(jnp.logical_and(active, init_ref[0] == 1))
-    def _first():
-        start_copy(b, i, buf_idx_ref[0])
-        init_ref[0] = 0
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(active)
-    def _compute():
-        slot = buf_idx_ref[0]
-        nb, ni = _next_chunk(lengths_ref, b, i, bk=bk, n_chunks=n_chunks,
-                             batch_size=batch_size)
-
-        @pl.when(nb < batch_size)
-        def _prefetch():
-            nslot = jnp.where(slot == 0, 1, 0)
-            start_copy(nb, ni, nslot)
-            buf_idx_ref[0] = nslot
-
-        wait_copy(b, i, slot)
-        q = q_ref[0]  # [kh*g, hd], the matmul operand dtype
-        cols = block_tok * kh
-        # Column c of a score tile is (token c // kh, kv head c % kh);
-        # row r belongs to kv head r // g. Static but for the length.
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-        row_head = sum((row >= h * g).astype(jnp.int32)
-                       for h in range(1, kh))  # r // g without a division
-        same_head = _mod(col, kh) == row_head  # [kh*g, cols]
-        col_tok = _div(col, kh)  # [1, cols]
-
-        def flat(x):
-            # [pages, ps, kh, hd] -> [tok*kh, hd] in the operand dtype: a
-            # token's kh rows are consecutive, so merging the leading
-            # dims moves nothing; int8 codes convert exactly.
-            return x.reshape(cols, hd).astype(q.dtype)
-
-        def flash_block(u):
-            pages = pl.ds(u * block_pages, block_pages)
-            k = flat(kv_buf[slot, 0, pages])
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [kh*g, cols]
+        def turn(t, _):
+            first = t * per_turn
+            into = kv_buf.at[slot, :, pl.ds(first * ps * unit,
+                                            per_turn * ps * unit)]
+            sem = sems.at[slot, _div(first, block_pages)]
             if quantized:
-                ks = _token_scale_row(
-                    sc_buf[slot, 0, pages].reshape(block_tok, -1), kh)
-                s = s * (ks * sm_scale)
+                sc_into = sc_buf.at[slot, :, pl.ds(first * ps,
+                                                   per_turn * ps)]
+            for j in range(per_turn):
+                page = tables_ref[base + first + j]
+                pltpu.make_async_copy(
+                    pool_layer.at[:, page],
+                    into.at[:, pl.ds(j * ps * unit, ps * unit)],
+                    sem).start()
+                if quantized:
+                    pltpu.make_async_copy(
+                        scale_layer.at[:, page],
+                        sc_into.at[:, pl.ds(j * ps, ps)], sem).start()
+
+        jax.lax.fori_loop(0, blocks * (block_pages // per_turn), turn, None)
+
+    def start_nth(n):
+        """Start the copies of the walk's n-th live chunk, if there is
+        one, into the slot that is its turn."""
+        bi = row_ref[n]
+
+        @pl.when(bi < batch_size)
+        def _():
+            start_chunk(bi, n - first_ref[bi], n % slots)
+
+    @pl.when(b == 0)
+    def _first():
+        for n in range(slots - 1):
+            start_nth(n)
+
+    m_view, l_view, o_view = m_ref.at[0], l_ref.at[0], acc_ref.at[0]
+    m_view[...] = jnp.full_like(m_view, -jnp.inf)
+    l_view[...] = jnp.zeros_like(l_view)
+    o_view[...] = jnp.zeros_like(o_view)
+    # Column c of a score tile is (token c // kh, kv head c % kh); row r
+    # belongs to kv head r // g. Static but for the length.
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    row_head = sum((row >= h * g).astype(jnp.int32)
+                   for h in range(1, kh))  # r // g without a division
+    same_head = _mod(col, kh) == row_head  # [kh*g, cols]
+    col_tok = _div(col, kh)  # [1, cols]
+
+    def chunk(ci, _):
+        n = first_ref[b] + ci
+        start_nth(n + slots - 1)
+        slot = n % slots
+        left = length - ci * bk  # > 0
+
+        def rows_of(u: int):  # block u's rows of a slot's K or V
+            return pl.ds(u * block_tok * unit, block_tok * unit)
+
+        def landed(u: int):
+            """Block u's pages (and scale rows), once they are in."""
+            at = kv_buf.at[slot, :, rows_of(u)]
+            pltpu.make_async_copy(at, at, sems.at[slot, u]).wait()
+            if quantized:
+                at = sc_buf.at[slot, :, pl.ds(u * block_tok, block_tok)]
+                pltpu.make_async_copy(at, at, sems.at[slot, u]).wait()
+
+        def tile(which: int, u: int):
+            # block u's K or V rows [tok*kh, hd] in the operand dtype
+            # (int8 codes convert exactly). Where the buffer keeps kv
+            # heads apart, merging them here relays every tile.
+            return kv_buf[slot, which, rows_of(u)].reshape(cols, hd).astype(
+                q_ref.dtype)
+
+        def scale_row(which: int, u: int, live):
+            # zeros behind the history: the selector matmul would spread
+            # one token's NaN over the row
+            sc = sc_buf[slot, which, pl.ds(u * block_tok, block_tok)]
+            if live is not None:
+                tok = jax.lax.broadcasted_iota(jnp.int32, (block_tok, 1), 0)
+                sc = jnp.where(tok < live, sc, jnp.zeros_like(sc))
+            return _token_scale_row(sc, kh)
+
+        def score(q, u: int, live):
+            """Block u's masked scores [kh*g, cols], once its pages are
+            in; `live`: the tokens of a ragged block inside the
+            history."""
+            landed(u)
+            s = jax.lax.dot_general(
+                q, tile(0, u), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if quantized:
+                s = s * (scale_row(0, u, live) * sm_scale)
             else:
                 s = s * sm_scale
-            live = col_tok < length - (i * bk + u * block_tok)
+            seen = same_head
+            if live is not None:
+                seen = jnp.logical_and(seen, col_tok < live)
             if windowed:
-                live = jnp.logical_and(
-                    live, col_tok >= starts_ref[b] - (i * bk + u * block_tok))
-            s = jnp.where(jnp.logical_and(same_head, live), s, -jnp.inf)
-            m_prev = m_ref[:, 0:1]  # [kh*g, 1]
-            l_prev = l_ref[:, 0:1]
+                seen = jnp.logical_and(
+                    seen,
+                    col_tok >= starts_ref[b] - (ci * bk + u * block_tok))
+            return jnp.where(seen, s, -jnp.inf)
+
+        def update(q, s, u: int, live, m_prev, l_prev, o):
+            """Block u folded into the running softmax (m, l [kh*g, 1],
+            o [kh*g, hd])."""
             # finite: the block's first token is live for every head
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
             l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            v = tile(1, u)
             if quantized:
-                p = p * _token_scale_row(
-                    sc_buf[slot, 1, pages].reshape(block_tok, -1), kh)
+                p = p * scale_row(1, u, live)
+            elif live is not None:
+                tok = _div(jax.lax.broadcasted_iota(jnp.int32, (cols, 1), 0),
+                           kh)
+                v = jnp.where(tok < live, v, jnp.zeros_like(v))
             pv = jax.lax.dot_general(
-                p.astype(q.dtype), flat(kv_buf[slot, 1, pages]),
-                (((1,), (0,)), ((), ())),
+                p.astype(q.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)  # [kh*g, hd]
-            o_ref[...] = o_ref[...] * alpha + pv
-            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+            return m_new, l_new, o * alpha + pv
 
-        flash_block(0)
-        for u in range(1, n_blocks):
-            pl.when(i * bk + u * block_tok < length)(
-                functools.partial(flash_block, u))
+        def path(k: int):
+            """A chunk of k live blocks, the last one maybe ragged, in
+            one straight line: every block's scores, then the updates
+            (Mosaic's scheduler orders a straight line itself)."""
+            q = q_ref[0]  # [kh*g, hd], the matmul operand dtype
+            # tokens of a block inside the history: the last one's
+            lives = [None] * (k - 1) + [left - (k - 1) * block_tok]
+            scores = [score(q, u, live) for u, live in enumerate(lives)]
+            state = (m_view[:, 0:1], l_view[:, 0:1], o_view[...])
+            for u, (s, live) in enumerate(zip(scores, lives)):
+                state = update(q, s, u, live, *state)
+            m_new, l_new, o = state
+            o_view[...] = o
+            m_view[...] = jnp.broadcast_to(m_new, m_view.shape)
+            l_view[...] = jnp.broadcast_to(l_new, l_view.shape)
 
-    @pl.when(i == n_chunks - 1)
-    def _finish():
-        acc_ref[0] = o_ref[...]
-        m_out_ref[0] = m_ref[...]
-        l_out_ref[0] = l_ref[...]
+        for k in range(1, n_blocks + 1):
+            pl.when(blocks_of(left) == k)(functools.partial(path, k))
+
+    jax.lax.fori_loop(0, _div(length + bk - 1, bk), chunk, None)
 
 
 def _pool_flash_partials(q, kv_pool, layer, block_tables, kv_lens_hist,
@@ -661,58 +762,65 @@ def _pool_flash_partials(q, kv_pool, layer, block_tables, kv_lens_hist,
     b, qh, hd = q.shape
     ps, kh = kv_pool.shape[3], kv_pool.shape[4]
     max_pages = block_tables.shape[1]
-    ppc = _largest_divisor(
-        max_pages, pages_per_chunk or max(1, _CHUNK_TOKENS // ps))
-    block_pages = _largest_divisor(
-        ppc, max(1, _SCORE_ELEMS // (qh * kh * ps)))
-    n_chunks = max_pages // ppc
+    # A page is copied and read AS [ps * kh, hd] rows where handing the
+    # pool in with the two dimensions merged is a bitcast of its tiled
+    # HBM layout: rows one lane tile wide whose kh heads fill whole
+    # 32-bit words (merging them on the loaded value is a relayout of
+    # every K and V tile: 510 of a block's 700 bundles at lfm2's shape).
+    # A wider row's lane tiles lie a token apart, and XLA would copy the
+    # pool: such a kernel keeps the relayout, and two blocks a chunk
+    # (three paths of it, not ten: phi4's 640-lane rows, whose chunk is
+    # the 448 tokens it was).
+    merged = hd == 128 and kh * kv_pool.dtype.itemsize % 4 == 0
+    page_bytes = 2 * ps * kh * hd * kv_pool.dtype.itemsize
+    ppc, block_pages = _pool_tiles(
+        max_pages, page_bytes, ps, qh, kh, pages_per_chunk,
+        _POOL_CHUNK_BLOCKS if merged else 2)
 
-    def q_map(bi, ci, *refs):
-        del ci, refs
+    def row_map(bi, *refs):
+        del refs
         return (bi, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, qh, hd), q_map),
+        pl.BlockSpec((1, qh, hd), row_map),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
-    scratch = [pltpu.VMEM((2, 2, ppc, ps, kh, hd), kv_pool.dtype)]
+    if merged:
+        kv_pool = kv_pool.reshape(*kv_pool.shape[:3], ps * kh, hd)
+        scratch = [pltpu.VMEM((_POOL_SLOTS, 2, ppc * ps * kh, hd),
+                              kv_pool.dtype)]
+    else:
+        scratch = [pltpu.VMEM((_POOL_SLOTS, 2, ppc * ps, kh, hd),
+                              kv_pool.dtype)]
     operands = [q, kv_pool]
     if quantized:
         in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        scratch.append(pltpu.VMEM((2, 2, ppc, ps, kv_scales.shape[-1]),
-                                  kv_scales.dtype))
+        scratch.append(pltpu.VMEM(
+            (_POOL_SLOTS, 2, ppc * ps, kv_scales.shape[-1]),
+            kv_scales.dtype))
         operands.append(kv_scales)
-    scratch += [
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.VMEM((qh, 128), jnp.float32),
-        pltpu.VMEM((qh, 128), jnp.float32),
-        pltpu.VMEM((qh, hd), jnp.float32),
-    ]
-    prefetch = [kv_lens_hist.astype(jnp.int32),
-                block_tables.reshape(-1).astype(jnp.int32),
-                jnp.asarray(layer, jnp.int32).reshape(1),
-                jnp.zeros((1,), jnp.int32),  # double-buffer slot
-                jnp.ones((1,), jnp.int32)]  # init flag
-    kernel = functools.partial(_pool_decode_kernel, pages_per_chunk=ppc,
-                               block_pages=block_pages, max_pages=max_pages,
-                               batch_size=b, quantized=quantized,
-                               sm_scale=sm_scale)
+    scratch.append(pltpu.SemaphoreType.DMA((_POOL_SLOTS, ppc // block_pages)))
+    prefetch = list(_chunk_walk(kv_lens_hist, block_tables, layer, ps, ppc,
+                                _POOL_SLOTS))
     if windowed:
         prefetch.append(starts.astype(jnp.int32))
-        kernel = functools.partial(kernel, windowed=True)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(b, n_chunks),
+        grid=(b,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, qh, hd), q_map),
-            pl.BlockSpec((1, qh, 128), q_map),
-            pl.BlockSpec((1, qh, 128), q_map),
+            pl.BlockSpec((1, qh, hd), row_map),
+            pl.BlockSpec((1, qh, 128), row_map),
+            pl.BlockSpec((1, qh, 128), row_map),
         ],
         scratch_shapes=scratch,
     )
     acc, m, l = pl.pallas_call(
-        kernel,
+        functools.partial(_pool_decode_kernel, page_size=ps, kv_heads=kh,
+                          pages_per_chunk=ppc,
+                          block_pages=block_pages, max_pages=max_pages,
+                          batch_size=b, quantized=quantized,
+                          windowed=windowed, sm_scale=sm_scale),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, qh, hd), jnp.float32),
@@ -721,8 +829,8 @@ def _pool_flash_partials(q, kv_pool, layer, block_tables, kv_lens_hist,
         ],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-        ),
+            dimension_semantics=("arbitrary",),
+            disable_bounds_checks=True),
         **({"name": "paged_decode_attention_window"} if windowed else {}),
     )(*prefetch, *operands)
     group = qh // kh
@@ -753,11 +861,11 @@ def paged_decode_attention_pool(
     _pool_decode_kernel for what it streams and how it scores. Returns
     (acc, m, l) unnormalized for the deferred current-token combine. With
     `kv_scales` the pool is int8 (the q8 path). A row with history 0 is
-    skipped: callers pass 0 for every slot that is not active.
+    skipped: callers pass 0 for every slot that is not active; a length
+    past the table's width (a stale slot) reads the table's width.
 
     `pages_per_chunk` is a cap on the DMA chunk; left None it follows
-    from the static table width (`_CHUNK_TOKENS`), so a sequence is a
-    few grid steps whatever width the scheduler bucketed it to."""
+    from the static table width and the geometry (`_pool_tiles`)."""
     return _pool_flash_partials(q, kv_pool, layer, block_tables,
                                 kv_lens_hist, kv_scales, None,
                                 pages_per_chunk, interpret, sm_scale)
@@ -1164,15 +1272,6 @@ def paged_decode_attention_latent(
         max_pages, pages_per_chunk or max(1, _LATENT_CHUNK_TOKENS // ps))
     block_pages = _largest_divisor(
         ppc, max(1, _LATENT_BLOCK_TOKENS // ps))
-    lengths = jnp.clip(kv_lens_hist.astype(jnp.int32), 0, max_pages * ps)
-    # the walk: live chunks in the order of rows; the g-th one's row (b
-    # past the last, far enough for the deepest prefetch to read)
-    per_row = (lengths + ppc * ps - 1) // (ppc * ps)
-    ends = jnp.cumsum(per_row)
-    row_of = jnp.searchsorted(
-        ends, jnp.arange(b * (max_pages // ppc) + _LATENT_SLOTS,
-                         dtype=jnp.int32), side="right",
-        method="compare_all").astype(jnp.int32)
 
     def row_map(bi, *refs):
         del refs
@@ -1204,8 +1303,8 @@ def paged_decode_attention_latent(
             dimension_semantics=("arbitrary",),
             disable_bounds_checks=True),
         name="paged_decode_attention_latent",
-    )(lengths, block_tables.reshape(-1).astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), ends - per_row, row_of,
+    )(*_chunk_walk(kv_lens_hist, block_tables, layer, ps, ppc,
+                   _LATENT_SLOTS),
       # the two unit dimensions are the dense pool's (k|v, kv heads): a
       # row-major bitcast
       q, kv_pool.reshape(n_layers, n_pages, ps, width))

@@ -531,34 +531,57 @@ class TestPoolKernelStaleSlots:
         ([100, 5000, 0, 700], 512, 2),
         ([0, 0, 0, 0], 128, 8),
         ([1024, 1024, 1024, 1024], 512, 2),
+        ([0, 1, 0, 129, 128, 0], 128, 2),  # empty rows in every place
     ])
-    def test_every_started_chunk_is_awaited_inside_the_grid(
-            self, lengths, bk, n_chunks):
-        """Walk the grid as the kernel does: a grid step (b, i) is active
-        when chunk i holds history, waits for its own chunk and starts
-        the copy `_next_chunk` names. The copies started must be exactly
-        the chunks the active steps wait for, in order, and none may lie
-        past the grid, whatever the lengths say (2000 and 5000 here are
-        past the table width, n_chunks * bk)."""
-        from dynamo_tpu.ops.paged_attention import _next_chunk
+    @pytest.mark.parametrize("slots", [2, 3])
+    def test_every_live_chunk_is_numbered_once_in_row_order(
+            self, lengths, bk, n_chunks, slots):
+        """Walk the rows as the kernel does on what `_chunk_walk` hands
+        it: row b scores its live chunks first[b] .. in turn, chunk n out
+        of slot n mod slots, and starts chunk n + slots - 1, whichever
+        row's it is (the first row starts the first slots - 1 itself).
+        The copies started must be exactly the chunks scored, in order,
+        each into a slot whose last chunk has been scored, and none may
+        lie past the table, whatever the lengths say (2000 and 5000 here
+        are past the table width, n_chunks * bk)."""
+        from dynamo_tpu.ops.paged_attention import _chunk_walk
 
-        lens = jnp.asarray(lengths, jnp.int32)
-        batch = len(lengths)
-        started, awaited = [], []
+        page, batch = 16, len(lengths)
+        lens, tables, layer, first, row_of = (np.asarray(x) for x in (
+            _chunk_walk(jnp.asarray(lengths, jnp.int32),
+                        jnp.zeros((batch, n_chunks * bk // page), jnp.int32),
+                        3, page, bk // page, slots)))
+        np.testing.assert_array_equal(
+            lens, np.minimum(lengths, n_chunks * bk))
+        assert tables.shape == (batch * n_chunks * bk // page,)
+        assert layer.tolist() == [3]
+        live = [(b, c) for b in range(batch)
+                for c in range(-(-int(lens[b]) // bk))]
+        # every live chunk once, in row order; `slots` entries past the
+        # last one, and every entry behind them, name no row
+        assert [(int(row_of[n]), n - int(first[row_of[n]]))
+                for n in range(len(live))] == live
+        assert len(row_of) == batch * n_chunks + slots
+        assert (row_of[len(live):] == batch).all()
+        started, scored = [], []
+
+        def start(n):
+            if row_of[n] < batch:
+                # the slot's last tenant has been scored
+                assert n < slots or len(scored) > n - slots
+                started.append((int(row_of[n]), n - int(first[row_of[n]]),
+                                n % slots))
+
+        for n in range(slots - 1):
+            start(n)
         for b in range(batch):
-            for i in range(n_chunks):
-                if i * bk >= lengths[b]:
-                    continue
-                if not started:
-                    started.append((b, i))  # the kernel's `_first`
-                awaited.append((b, i))
-                nb, ni = _next_chunk(lens, jnp.int32(b), jnp.int32(i),
-                                     bk=bk, n_chunks=n_chunks,
-                                     batch_size=batch)
-                if int(nb) < batch:
-                    assert 0 <= int(ni) < n_chunks
-                    started.append((int(nb), int(ni)))
-        assert started == awaited
+            for c in range(-(-int(lens[b]) // bk)):
+                n = int(first[b]) + c
+                start(n + slots - 1)
+                assert (b, c, n % slots) in started  # started before
+                scored.append((b, c, n % slots))
+        assert started == scored
+        assert all(0 <= c < n_chunks for _, c, _ in started)
 
     @pytest.mark.parametrize("kind", ["int8", "bf16"])
     def test_length_past_the_table_width(self, kind):
@@ -582,6 +605,148 @@ class TestPoolKernelStaleSlots:
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32),
             rtol=_POOL_TOL[kind], atol=_POOL_TOL[kind])
+
+
+# The walk's edges at the pool kernel's own tiles (pages of 16: blocks of
+# 256 tokens, chunks of 1,024, so four blocks a chunk): (HISTORY lengths,
+# table pages, pages_per_chunk[, what lies behind a row's last live
+# page]).
+_WALK_PAGE, _WALK_BLOCK, _WALK_CHUNK, _WALK_WIDE = 16, 256, 1024, 128
+POOL_WALKS = {
+    "one-chunk-a-row": ([74, 0, 32, 1, 127, 128], 8, None),
+    "a-page-pair-a-chunk": ([74, 0, 32, 1, 127, 128], 8, 2),
+    # one token; a block's last token, the next one's first and second
+    "around-a-block": ([1, _WALK_BLOCK, _WALK_BLOCK + 1, _WALK_BLOCK + 2],
+                       _WALK_WIDE, None),
+    "around-a-chunk": ([_WALK_CHUNK - 1, _WALK_CHUNK, _WALK_CHUNK + 1,
+                        _WALK_BLOCK // 2], _WALK_WIDE, None),
+    "the-whole-table": ([_WALK_WIDE * _WALK_PAGE,
+                         _WALK_CHUNK + _WALK_BLOCK, 3], _WALK_WIDE, None),
+    "empty-rows-between-live-rows": ([0, 300, 0, 0, 700, 0], 64, None),
+    "every-row-empty": ([0, 0, 0], 64, None),
+    "one-live-row-of-256": ([0] * 200 + [700] + [0] * 55, 64, None),
+    "a-stale-length-past-the-table": ([300, 5000, 0, 20], 64, None),
+    "nan-pages-behind-a-ragged-end": (
+        [300, 1, _WALK_BLOCK + 1, _WALK_BLOCK + 18, 0], 64, None, np.nan),
+}
+
+
+def _pool_walk_case(lens, pages, variant, behind=None):
+    """A float32 pool of four kv heads 128 lanes wide with distinct
+    pages per live row (the operands are the queries' float32, so every
+    variant compares tightly; the geometry is one whose pages the kernel
+    copies as merged rows, as every cell's but phi4's); `int8` quantises
+    it and compares against the dequantised values; `packed` has heads
+    of 64 lanes and is handed over two a 128-lane row; `window` draws a
+    lower edge inside each row's first page. `behind`: table entries
+    past a row's last live page name a page holding that (for the int8
+    pool: in its scales) all over."""
+    from dynamo_tpu.models.transformer import quantize_kv
+
+    rng = np.random.default_rng(len(lens) * 1000 + pages)
+    kh, g, hd = 4, 2, 64 if variant == "packed" else 128
+    used = [-(-min(n, pages * _WALK_PAGE) // _WALK_PAGE) for n in lens]
+    n_pages = 2 + sum(used)
+    values = rng.normal(size=(2, 2, n_pages, _WALK_PAGE, kh, hd)).astype(
+        np.float32)
+    tables = np.zeros((len(lens), pages), np.int32)
+    at = 1
+    for i, n in enumerate(used):
+        tables[i, :n] = np.arange(at, at + n)
+        at += n
+        if behind is not None:
+            tables[i, n:] = n_pages - 1
+    scales = None
+    if variant == "int8":
+        codes, scales = quantize_kv(jnp.asarray(values))
+        values = np.asarray(codes.astype(jnp.float32)
+                            * scales[..., :1, None].astype(jnp.float32))
+        if behind is not None:
+            scales = scales.at[:, :, n_pages - 1].set(behind)
+        pool = codes
+    else:
+        if behind is not None:
+            values[:, :, n_pages - 1] = behind
+        pool = jnp.asarray(values)
+    starts = np.zeros(len(lens), np.int32)
+    if variant == "window":
+        starts = np.minimum(rng.integers(0, _WALK_PAGE, len(lens)),
+                            np.maximum(np.asarray(lens) - 1, 0)).astype(
+                                np.int32)
+    q = rng.normal(size=(len(lens), kh * g, hd)).astype(np.float32)
+    return q, pool, scales, values, tables, starts
+
+
+def _history_oracle(q, values, layer, tables, lens, starts, kh):
+    """The unnormalised flash partials (acc, m, l) of each row over its
+    history [start, length) in float64, [B, kh, g, hd] and [B, kh, g]."""
+    b, qh, hd = q.shape
+    g = qh // kh
+    acc = np.zeros((b, kh, g, hd))
+    m = np.full((b, kh, g), -np.inf)
+    l = np.zeros((b, kh, g))
+    for i in range(b):
+        n = min(int(lens[i]), tables.shape[1] * _WALK_PAGE)
+        if n == 0:
+            continue
+        k, v = (values[layer, w][tables[i]].reshape(-1, kh, hd)[
+            starts[i]:n].astype(np.float64) for w in (0, 1))
+        s = np.einsum("kgh,tkh->kgt", q[i].reshape(kh, g, hd).astype(
+            np.float64), k) / np.sqrt(hd)
+        m[i] = s.max(-1)
+        p = np.exp(s - m[i][..., None])
+        l[i] = p.sum(-1)
+        acc[i] = np.einsum("kgt,tkh->kgh", p, v)
+    return acc, m, l
+
+
+@pytest.mark.parametrize("variant", ["bf16", "int8", "window", "packed"])
+@pytest.mark.parametrize("walk", sorted(POOL_WALKS))
+def test_the_pool_kernel_walks_every_edge_as_the_oracle_does(walk, variant):
+    """`_pool_decode_kernel` (interpreted) through each of its entries
+    against a float64 oracle over the whole table: histories of one
+    token, one under, at and one over a block and a chunk and as wide as
+    the table, rows without a history wherever the walk can meet them
+    (first, last, two in a row, all but one of 256, all), a length past
+    the table (which reads the table's width), and table entries behind
+    a ragged end that name a page of NaN: a ragged block copies such
+    pages whole, and the answer is the one over zeros there (0 x NaN
+    would be NaN in P V)."""
+    import importlib
+
+    ops = importlib.import_module("dynamo_tpu.ops.paged_attention")
+    assert (ops._POOL_BLOCK_TOKENS[1], ops._POOL_CHUNK_TOKENS) == (
+        _WALK_BLOCK, _WALK_CHUNK)  # the edges above are the kernel's own
+    lens, pages, chunk, behind = (*POOL_WALKS[walk], None)[:4]
+    q, pool, scales, values, tables, starts = _pool_walk_case(
+        lens, pages, variant, behind)
+    if behind is not None:  # the oracle's pool holds zeros there
+        values = _pool_walk_case(lens, pages, variant, 0.0)[3]
+    hist = jnp.asarray(lens, jnp.int32)
+    args = (jnp.asarray(q), pool, jnp.int32(1), jnp.asarray(tables), hist)
+    kw = {"pages_per_chunk": chunk, "interpret": True}
+    if variant == "window":
+        got = ops.paged_decode_attention_window(
+            *args, jnp.asarray(starts), **kw)
+    elif variant == "packed":  # two kv heads of 64 lanes a 128-lane row
+        got = ops._packed_pool_partials(
+            args[0], pool.reshape(*pool.shape[:4], 2, 128), *args[2:], 4,
+            chunk, True, None)
+    else:
+        got = ops.paged_decode_attention_pool(*args, scales, **kw)
+    want = _history_oracle(q, values, 1, tables, lens, starts, 4)
+    live = [i for i, n in enumerate(lens) if n > 0]
+    for name, g_, w_ in zip(("acc", "m", "l"), got, want):
+        g_ = np.asarray(g_)
+        assert g_.shape == w_.shape, name
+        assert np.isfinite(g_[live]).all(), name
+        np.testing.assert_allclose(g_[live], w_[live], rtol=2e-5, atol=2e-5,
+                                   err_msg=name)
+    # a row without a history leaves the identity of the combine
+    empty = [i for i, n in enumerate(lens) if n == 0]
+    assert (np.asarray(got[0])[empty] == 0).all()
+    assert (np.asarray(got[1])[empty] == -np.inf).all()
+    assert (np.asarray(got[2])[empty] == 0).all()
 
 
 class TestPagedAttentionDecodePoolTp:

@@ -50,10 +50,13 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-# (kv heads, query rows a kv head, int8 pool, table width): the benchmark
-# cell (mistral-7b: 8 kv heads, group 4, int8, 64-page tables), its
-# narrowest and widest tables, a bf16 pool, the spec fold (group 4 x 5
-# positions), and one shard of `--tp 4` / `--tp 2`.
+# (kv heads, query rows a kv head, int8 pool, table width[, rows]): the
+# benchmark cell (mistral-7b: 8 kv heads, group 4, int8, 64-page tables),
+# its narrowest and widest tables, a bf16 pool, the spec fold (group 4 x
+# 5 positions), one shard of `--tp 4` / `--tp 2`, and lfm2's geometry as
+# the kernel sees it (256 rows; 8 kv heads of 64 packed two a lane tile
+# are 4 "kv heads" 128 wide with 8 wide query rows each; the cell's
+# narrowest and widest tables).
 CASES = {
     "q8-w64": (8, 4, True, 64),
     "q8-w8": (8, 4, True, 8),
@@ -62,6 +65,8 @@ CASES = {
     "q8-spec5-w64": (8, 20, True, 64),
     "bf16-tp4-shard-w64": (2, 4, False, 64),
     "q8-tp2-shard-w64": (4, 4, True, 64),
+    "bf16-lfm2-packed-w8": (4, 8, False, 8, 256),
+    "bf16-lfm2-packed-w192": (4, 8, False, 192, 256),
 }
 
 
@@ -69,24 +74,31 @@ CASES = {
 def test_pool_decode_kernel_compiles_for_v5e(one_chip, case):
     from dynamo_tpu.ops.paged_attention import paged_decode_attention_pool
 
-    kh, g, quantized, width = CASES[case]
-    n_pages = ROWS * width + 1
+    kh, g, quantized, width, rows = (*CASES[case], ROWS)[:5]
+    n_pages = rows * width + 1
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
+    pool = (LAYERS, 2, n_pages, PAGE, kh, HEAD_DIM)
     args = [
-        shape((ROWS, kh * g, HEAD_DIM), jnp.bfloat16),
-        shape((LAYERS, 2, n_pages, PAGE, kh, HEAD_DIM),
-              jnp.int8 if quantized else jnp.bfloat16),
+        shape((rows, kh * g, HEAD_DIM), jnp.bfloat16),
+        shape(pool, jnp.int8 if quantized else jnp.bfloat16),
         shape((), jnp.int32),
-        shape((ROWS, width), jnp.int32),
-        shape((ROWS,), jnp.int32),
+        shape((rows, width), jnp.int32),
+        shape((rows,), jnp.int32),
     ]
     if quantized:
         args.append(shape((LAYERS, 2, n_pages, PAGE, LANES), jnp.bfloat16))
     compiled = paged_decode_attention_pool.lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "paged_decode_attention_pool" in text  # the trace's name
+    # the kernel reads the pool where it lies: handing it in with a
+    # page's (token, kv head) dimensions merged is a bitcast, not a copy
+    assert compiled.memory_analysis().temp_size_in_bytes < math.prod(
+        pool) // LAYERS // 4
+    assert not re.search(rf"\[{LAYERS},2,{n_pages},[0-9,]*\][^ ]* copy\(", text)
 
 
 # The hybrid stack's kernels (models/hybrid.py) at the published widths of
